@@ -17,7 +17,7 @@ pass generalizes both:
 2. **Runtime** (opt-in, `--spmd-barrier`): `fingerprint_barrier` —
    before the first step, every process hashes the ingredients of its
    step executable (plan fingerprint + strategy, donation registry and
-   the REALIZED donation probe verdict, update-spec layout, mesh axes,
+   whether the backend donates, update-spec layout, mesh axes,
    numerics policy) and compares against the coordinator's over the
    `broadcast_json` channel. A mismatch raises `SPMDDivergenceError` on
    every process in lockstep — a structured abort at t=0 instead of a
@@ -63,8 +63,8 @@ class SPMDDivergenceError(RuntimeError):
                 f"coordinator. Diverging component(s): {diverged}. "
                 "Typical causes: per-host control flow on time/RNG/env "
                 "(fflint host_divergent_branch), a plan adopted on one "
-                "host only, or a donation probe succeeding on some "
-                "hosts only.")
+                "host only, or backends that differ in whether they "
+                "donate.")
         super().__init__(msg)
 
 
@@ -108,9 +108,8 @@ def fingerprint_payload(model) -> dict:
         "strategy": digest(Strategy(model._strategy or {}).to_json()),
         "mesh_axes": digest({k: int(v)
                              for k, v in dict(model.mesh.shape).items()}),
-        # the donation registry AND the probe's realized verdict: a
-        # backend honoring donation on some hosts only compiles
-        # different executables
+        # the donation registry AND whether this process's backend
+        # donates: hosts that disagree compile different executables
         "donation": digest({
             "registry": {k: list(v) for k, v in DONATED_CALLEES.items()},
             "supported": _donation_supported()}),

@@ -261,7 +261,7 @@ class FFConfig:
     # SPMD fingerprint barrier (analysis/spmd.py): before the first
     # step, every process cross-checks a digest of its step-executable
     # ingredients (plan fingerprint, strategy, donation registry +
-    # realized probe verdict, update-spec layout, numerics policy)
+    # whether the backend donates, update-spec layout, numerics policy)
     # against the coordinator's over broadcast_json; a mismatch raises
     # SPMDDivergenceError on every process in lockstep. One small
     # broadcast when on; nothing when off.

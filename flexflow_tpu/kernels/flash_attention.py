@@ -42,11 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Older jax spells pltpu.CompilerParams as TPUCompilerParams (same
-# dimension_semantics field); resolve once so the kernels — and the
-# interpret-mode CPU test suite — run on both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+from .dispatch import warn_reference
 
 NEG_INF = -1e30
 # Minor dim of the (seq,) row-stat tensors (lse/delta): Mosaic wants
@@ -65,6 +61,23 @@ def _attn_reference(q, k, v, causal: bool, scale: float):
     from ..ops.attention import sdpa_xla
 
     return sdpa_xla(q, k, v, causal=causal, scale=scale)
+
+
+def _shape_gate(s_q: int, s_k: int, d: int, causal: bool) -> str | None:
+    """The training kernels' shape gate, by name (None = the kernel
+    tiles). Tiny/ragged shapes go to the XLA path (still fused by XLA).
+    Causal with s_q > s_k also routes there: rows with zero live keys
+    (q_pos + offset < 0) would read m = -inf and p = exp(0) = 1 in the
+    multi-kv online softmax — averaging V over live tiles only and
+    emitting a bogus lse — instead of sdpa_xla's uniform-over-all-keys
+    convention for that degenerate shape."""
+    if s_q < 128 or s_k < 128:
+        return f"seq {min(s_q, s_k)} < 128"
+    if d % 8 != 0:
+        return f"head_dim {d} % 8 != 0"
+    if causal and s_q > s_k:
+        return f"causal with s_q {s_q} > s_k {s_k}"
+    return None
 
 
 def _tile_classes(i, j, *, causal, block_q, block_k, causal_offset,
@@ -261,7 +274,7 @@ def _flash_fwd(q, k, v, causal: bool, scale: float,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=jax.default_backend() != "tpu",
@@ -494,7 +507,7 @@ def _flash_bwd_single_tile(qf, kf, vf, gf, lse, delta, causal, scale,
             jax.ShapeDtypeStruct((bh, s_k, d), kf.dtype),
             jax.ShapeDtypeStruct((bh, s_k, d), vf.dtype),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=jax.default_backend() != "tpu",
@@ -550,7 +563,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -573,7 +586,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -664,8 +677,9 @@ def flash_attention_with_lse(
     the shared FA2 backward). Same shape gates as flash_attention."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    s_q, s_k, d = q.shape[2], k.shape[2], q.shape[3]
-    if s_q < 128 or s_k < 128 or d % 8 != 0 or (causal and s_q > s_k):
+    gate = _shape_gate(q.shape[2], k.shape[2], q.shape[3], causal)
+    if gate is not None:
+        warn_reference("flash_attention_with_lse", (q.shape, k.shape), gate)
         return _attn_reference_lse(q, k, v, causal, scale)
     return _flash_lse(q, k, v, causal, scale, block_q, block_k)
 
@@ -950,7 +964,7 @@ def _flash_fwd_packed_grouped(q, k, v, num_heads, causal, scale,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -1006,7 +1020,7 @@ def _flash_fwd_packed(q, k, v, num_heads, causal, scale,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -1049,7 +1063,7 @@ def _flash_bwd_packed_grouped(q, k, v, g, lse, delta, num_heads, causal,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((b, s_q, e), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, w), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -1074,7 +1088,7 @@ def _flash_bwd_packed_grouped(q, k, v, g, lse, delta, num_heads, causal,
             pltpu.VMEM((bk, w), jnp.float32),
             pltpu.VMEM((bk, w), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -1131,7 +1145,7 @@ def _flash_bwd_packed(q, k, v, out, lse, g, num_heads, causal, scale,
                 jax.ShapeDtypeStruct((b, s_k, e), k.dtype),
                 jax.ShapeDtypeStruct((b, s_k, e), v.dtype),
             ],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel"),
             ),
             interpret=interpret,
@@ -1149,7 +1163,7 @@ def _flash_bwd_packed(q, k, v, out, lse, g, num_heads, causal, scale,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((b, s_q, e), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -1175,7 +1189,7 @@ def _flash_bwd_packed(q, k, v, out, lse, g, num_heads, causal, scale,
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -1231,9 +1245,10 @@ def flash_attention_packed(
     # too via head-GROUP blocks (hpb heads per 128-lane stripe, or the
     # full array width) with an in-kernel static head loop — so head_dim
     # 64 models run relayout-free where they previously paid the
-    # transposed-layout copies (PERF.md ~0.8 ms/step). Only sub-sublane
-    # head dims (d % 8 != 0) still fall back to the transposed path.
-    if s_q < 128 or s_k < 128 or (causal and s_q > s_k) or d % 8 != 0:
+    # transposed-layout copies. Shapes the gate refuses take the
+    # transposed entry point, whose identical gate names itself and runs
+    # the XLA reference.
+    if _shape_gate(s_q, s_k, d, causal) is not None:
         def split(t, s):
             return t.reshape(b, s, num_heads, d).transpose(0, 2, 1, 3)
 
@@ -1258,14 +1273,14 @@ def flash_attention_packed(
 # by lane-offset block index maps, no head transpose touches HBM.
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, *refs,
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *refs,
                    scale: float, block_k: int, seq_k: int, nj: int):
     if nj == 1:
         m_ref = l_ref = acc_ref = None
     else:
         m_ref, l_ref, acc_ref = refs
     j = pl.program_id(2)
-    length = len_ref[0, 0]
+    length = len_ref[pl.program_id(0)]
 
     @pl.when(j == 0)
     def _init():
@@ -1368,16 +1383,29 @@ def decode_attention_reference(q, k, v, positions, *, num_heads: int,
     return out.transpose(0, 2, 1, 3).reshape(slots, q_len, e)
 
 
+def _decode_gate(rows: int, d: int, num_heads: int,
+                 interpret: bool) -> str | None:
+    """The decode kernels' shared shape gate, by name. Heads are selected
+    by lane offset, which Mosaic allows only for head_dim % 128 == 0 (see
+    flash_attention_packed; the interpreter has no such rule), and small
+    caches aren't worth a kernel launch anywhere."""
+    if rows < 128:
+        return f"cache rows {rows} < 128"
+    if d % 128 != 0 and num_heads != 1 and not interpret:
+        return f"head_dim {d} % 128 != 0"
+    return None
+
+
 def flash_decode_attention(
     q, k, v, lengths, *, num_heads: int, scale: float | None = None,
-    block_k: int = 512, interpret: bool | None = None,
+    block_k: int = 512,
 ):
     """Single-query decode attention on the packed layout. q: (slots, 1,
     H·hd), k/v: (slots, S, H·hd) cache, lengths: (slots,) int32 live-key
     counts (query at position p attends p+1 keys). Shapes the kernel can't
-    tile on hardware (head_dim not lane-aligned, tiny caches) fall back to
-    the reference einsum — the serving op routes CPU meshes there
-    directly, so tier-1 exercises serving without Pallas."""
+    tile on hardware (_decode_gate) take the reference einsum, with a
+    KernelFallbackWarning on a TPU — the serving op routes CPU meshes
+    there directly, so tier-1 exercises serving without Pallas."""
     slots, q_len, e = q.shape
     if q_len != 1:
         raise ValueError(f"decode kernel is single-query (got q_len={q_len})")
@@ -1387,25 +1415,20 @@ def flash_decode_attention(
         raise ValueError(f"embed dim {e} % heads {num_heads} != 0")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    # Mosaic lane rule (see flash_attention_packed): head selection by lane
-    # offset needs head_dim % 128 == 0 on hardware; small caches aren't
-    # worth a kernel launch anywhere
-    lane_ok = d % 128 == 0 or num_heads == 1 or interpret
-    if s_k < 128 or not lane_ok:
+    interpret = jax.default_backend() != "tpu"
+    gate = _decode_gate(s_k, d, num_heads, interpret)
+    if gate is not None:
+        warn_reference("flash_decode_attention", (q.shape, k.shape), gate)
         positions = (lengths.astype(jnp.int32) - 1)[:, None]
         return decode_attention_reference(q, k, v, positions,
                                           num_heads=num_heads, scale=scale)
     bk = min(block_k, s_k)
     nj = pl.cdiv(s_k, bk)
-    # scalar per-slot length rides a lane-aligned stripe, like the row
-    # stats in the training kernels (LSE_LANES trick)
-    len_b = jnp.broadcast_to(
-        lengths.astype(jnp.int32)[:, None], (slots, LSE_LANES))
-    qspec = pl.BlockSpec((1, 1, d), lambda s, h, j: (s, 0, h))
-    kspec = pl.BlockSpec((1, bk, d), lambda s, h, j: (s, j, h))
-    lspec = pl.BlockSpec((1, LSE_LANES), lambda s, h, j: (s, 0))
+    # the per-slot lengths ride scalar prefetch (SMEM), like the paged
+    # kernel's: a (1, lanes) stripe block over a (slots, lanes) array has
+    # a second-minor block of 1, which Mosaic refuses
+    qspec = pl.BlockSpec((1, 1, d), lambda s, h, j, ln: (s, 0, h))
+    kspec = pl.BlockSpec((1, bk, d), lambda s, h, j, ln: (s, j, h))
     scratch_shapes = []
     if nj > 1:
         scratch_shapes = [
@@ -1413,20 +1436,24 @@ def flash_decode_attention(
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1, d), jnp.float32),
         ]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(slots, num_heads, nj),
+        in_specs=[qspec, kspec, kspec],
+        out_specs=qspec,
+        scratch_shapes=scratch_shapes,
+    )
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, block_k=bk,
                           seq_k=s_k, nj=nj),
-        grid=(slots, num_heads, nj),
-        in_specs=[qspec, kspec, kspec, lspec],
-        out_specs=qspec,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((slots, 1, e), q.dtype),
-        scratch_shapes=scratch_shapes,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="flash_attention_decode",
-    )(q, k, v, len_b)
+    )(lengths.astype(jnp.int32), q, k, v)
     return out
 
 
@@ -1538,16 +1565,16 @@ def paged_decode_attention_reference(q, pool_k, pool_v, page_table,
 
 def paged_flash_decode_attention(
     q, pool_k, pool_v, page_table, lengths, *, num_heads: int,
-    scale: float | None = None, interpret: bool | None = None,
+    scale: float | None = None,
 ):
     """Single-query decode attention over a paged KV pool. q: (slots, 1,
     H·hd); pool_k/v: (num_blocks, block_size, H·hd); page_table: (slots,
     W) int32 logical→physical block map; lengths: (slots,) int32 live-key
     counts. The kv grid walks the page table via scalar prefetch — one
     (1, block_size, head) K/V block DMA per live logical block, dead
-    blocks skipped. Shapes the kernel can't tile on hardware fall back to
-    the gather + einsum reference (the CPU serving path routes there
-    directly)."""
+    blocks skipped. Shapes the kernel can't tile on hardware take the
+    gather + einsum reference, with a KernelFallbackWarning on a TPU (the
+    CPU serving path routes there directly)."""
     slots, q_len, e = q.shape
     if q_len != 1:
         raise ValueError(f"decode kernel is single-query (got q_len={q_len})")
@@ -1558,13 +1585,16 @@ def paged_flash_decode_attention(
         raise ValueError(f"embed dim {e} % heads {num_heads} != 0")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    # Mosaic gates (see flash_decode_attention) + the paged-specific one:
-    # a block must be a legal (sublane, lane) tile, so tiny block sizes
-    # route to the reference
-    lane_ok = d % 128 == 0 or num_heads == 1 or interpret
-    if W * bs < 128 or bs % 8 != 0 or not lane_ok:
+    interpret = jax.default_backend() != "tpu"
+    # the contiguous kernel's gate + the paged-specific one: a block must
+    # be a legal (sublane, lane) tile, so tiny block sizes route to the
+    # reference
+    gate = _decode_gate(W * bs, d, num_heads, interpret)
+    if gate is None and bs % 8 != 0:
+        gate = f"block_size {bs} % 8 != 0"
+    if gate is not None:
+        warn_reference("paged_flash_decode_attention",
+                       (q.shape, pool_k.shape), gate)
         positions = (lengths.astype(jnp.int32) - 1)[:, None]
         return paged_decode_attention_reference(
             q, pool_k, pool_v, page_table, positions,
@@ -1596,7 +1626,7 @@ def paged_flash_decode_attention(
                           block_size=bs, nj=nj),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((slots, 1, e), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -1618,13 +1648,8 @@ def flash_attention(
     online-softmax path takes over."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    s_q, s_k, d = q.shape[2], k.shape[2], q.shape[3]
-    # shape gate: tiny/ragged shapes go to the XLA path (still fused by XLA).
-    # causal with s_q > s_k also routes there: rows with zero live keys
-    # (q_pos + offset < 0) would read m = -inf and p = exp(0) = 1 in the
-    # multi-kv online softmax — averaging V over live tiles only and
-    # emitting a bogus lse — instead of sdpa_xla's uniform-over-all-keys
-    # convention for that degenerate shape.
-    if s_q < 128 or s_k < 128 or d % 8 != 0 or (causal and s_q > s_k):
+    gate = _shape_gate(q.shape[2], k.shape[2], q.shape[3], causal)
+    if gate is not None:
+        warn_reference("flash_attention", (q.shape, k.shape), gate)
         return _attn_reference(q, k, v, causal, scale)
     return _flash(q, k, v, causal, scale, block_q, block_k)

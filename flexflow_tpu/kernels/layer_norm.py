@@ -22,11 +22,14 @@ non-last-axis normalization) fall back to the jnp path in ops/core.py.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec
+
+from .dispatch import per_shard, shards_of, spec_entries, warn_reference
 
 _ROW_BLOCK = 256
 
@@ -137,23 +140,43 @@ def _fused_ln_bwd(eps, res, dy):
 _fused_ln.defvjp(_fused_ln_fwd, _fused_ln_bwd)
 
 
-def fused_layer_norm_or_none(x, scale, bias, axes, eps):
+def fused_layer_norm_or_none(x, scale, bias, axes, eps, mesh=None,
+                             spec=None):
     """Fused path when the shape tiles: last-axis-only normalization,
     feature dim a multiple of 128, rows divisible by the row block.
-    Returns None when the caller should use the jnp fallback."""
+    Returns None when the caller should use the jnp fallback (with a
+    KernelFallbackWarning on a TPU). On a multi-device `mesh` the kernels
+    run per shard of `spec` (x's placement), so the gates read the
+    per-shard shape."""
     ndim = x.ndim
-    if tuple(a % ndim for a in axes) != (ndim - 1,):
-        return None
-    d = x.shape[-1]
-    n = 1
-    for s in x.shape[:-1]:
-        n *= s
+    entries = spec_entries(spec, ndim)
+    shards = [shards_of(mesh, e) for e in entries]
+    d = x.shape[-1] // shards[-1]
+    n = math.prod(s // k for s, k in zip(x.shape[:-1], shards))
     # rows must divide into 8-sublane-aligned blocks: `n % rb` alone is
     # vacuous for n < rb (n % n == 0) and a 12-row or 100-row block would
     # fail Mosaic's 8-sublane tiling on real TPU (interpret-mode CPU tests
     # can't catch that)
     rb = _row_block(n, d)
-    if d % 128 != 0 or n < 8 or rb % 8 != 0 or n % rb != 0:
+    gate = None
+    if tuple(a % ndim for a in axes) != (ndim - 1,):
+        gate = f"normalized axes {tuple(axes)} are not the last axis"
+    elif any(s % k for s, k in zip(x.shape, shards)):
+        gate = f"shape does not divide over {spec}"
+    elif entries[-1] is not None:
+        gate = f"normalized axis sharded over {entries[-1]!r}"
+    elif d % 128 != 0:
+        gate = f"feature dim {d} % 128 != 0"
+    elif n < 8 or rb % 8 != 0 or n % rb != 0:
+        gate = f"{n} rows do not tile into 8-aligned blocks of {rb}"
+    if gate is not None:
+        warn_reference("layer_norm", x.shape, gate)
         return None
-    y2 = _fused_ln(x.reshape(n, d), scale, bias, float(eps))
-    return y2.reshape(x.shape)
+
+    def run(xl, sc, bi):
+        return _fused_ln(xl.reshape(n, d), sc, bi, float(eps)).reshape(
+            xl.shape)
+
+    p_x = PartitionSpec(*entries)
+    return per_shard(run, mesh, (p_x, PartitionSpec(), PartitionSpec()),
+                     p_x)(x, scale, bias)

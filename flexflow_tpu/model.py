@@ -1282,38 +1282,31 @@ class FFModel:
         self._counters = self.executor.replicate(self.metrics.zero_counters())
         # --- ffpulse goodput anchor: cost-model forward FLOPs summed over
         # the compiled graph (x3 for fwd+bwd, the standard training
-        # estimate) against the machine model's aggregate chip peak — the
-        # two MFU factors record_step divides by measured step time. Best
-        # effort: an op without a flops estimate just undercounts.
-        self._goodput_anchor = None
-        try:
-            from .search.cost_model import _NON_COMPUTE
-            from .search.machine_model import detect_chip
+        # estimate) against the aggregate peak of the mesh's chips (the
+        # machine model's table; an unknown device raises) — the two MFU
+        # factors record_step divides by measured step time.
+        from .search.cost_model import _NON_COMPUTE
+        from .search.machine_model import chip_for
 
-            fwd = 0.0
-            for node in self.graph.topo_order():
-                if (node.op_type in _NON_COMPUTE or not node.outputs
-                        or not node.inputs):
-                    continue
-                try:
-                    shapes_in = [pt.shape.logical_shape
-                                 for pt in node.inputs]
-                    shapes_out = [pt.shape.logical_shape
-                                  for pt in node.outputs]
-                    fwd += node.op_def.flops(node.params, shapes_in,
-                                             shapes_out)
-                except Exception:
-                    continue
-            if fwd > 0:
-                num_chips = int(self.mesh.devices.size)
-                self._goodput_anchor = {
-                    "flops_per_step": 3.0 * fwd,
-                    "peak_flops": detect_chip().peak_flops * num_chips,
-                    "num_chips": num_chips,
-                }
-                telemetry.event("goodput_anchor", **self._goodput_anchor)
-        except Exception:
-            pass
+        fwd = 0.0
+        for node in self.graph.topo_order():
+            if (node.op_type in _NON_COMPUTE or not node.outputs
+                    or not node.inputs):
+                continue
+            fwd += node.op_def.flops(
+                node.params,
+                [pt.shape.logical_shape for pt in node.inputs],
+                [pt.shape.logical_shape for pt in node.outputs])
+        self._goodput_anchor = None
+        if fwd > 0:
+            num_chips = int(self.mesh.devices.size)
+            self._goodput_anchor = {
+                "flops_per_step": 3.0 * fwd,
+                "peak_flops": (chip_for(self.mesh.devices.flat[0]).peak_flops
+                               * num_chips),
+                "num_chips": num_chips,
+            }
+            telemetry.event("goodput_anchor", **self._goodput_anchor)
         self._compiled = True
 
     def _assign_strategy(self):
@@ -2203,7 +2196,10 @@ class FFModel:
         init_operators → per-op INIT tasks) has no analog — jit handles it."""
 
     def reset_metrics(self):
-        self._counters = self.metrics.zero_counters()
+        # placed on the mesh as compile places them: counters that arrive
+        # unplaced give the next train step a second argument signature,
+        # and with it a second compilation of the whole step
+        self._counters = self.executor.replicate(self.metrics.zero_counters())
 
     def set_learning_rate(self, lr: float):
         """Change the optimizer's learning rate mid-training (the keras

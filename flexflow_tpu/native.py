@@ -1,14 +1,21 @@
 """ctypes bindings for the native PCG core (native/src/pcg_core.cc).
 
 The reference keeps its graph/search core in C++ (SURVEY §2.1); this module
-loads our C++ equivalent, building it with make on first use (g++ is baked
-into the image; pybind11 is not, hence ctypes). Every entry point has a
-pure-Python fallback so the framework works without a toolchain.
+loads our C++ equivalent, building it with make on first use (pybind11 is
+not installed, hence ctypes). Every entry point has a pure-Python fallback
+so the framework works without a toolchain — the failed build is logged
+once, so a run says which core served it.
+
+The library's file name carries a digest of the source it was built from,
+so a binary left on disk by another version of pcg_core.cc (native/build/
+is not under git, and a copied tree keeps it) is never loaded: a source
+without its binary is simply built.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import Optional
@@ -17,7 +24,7 @@ import numpy as np
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(__file__)),
                            "native")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "build", "libpcg_core.so")
+_SOURCE = os.path.join(_NATIVE_DIR, "src", "pcg_core.cc")
 
 _lib = None
 _lib_tried = False
@@ -28,34 +35,45 @@ def _load() -> Optional[ctypes.CDLL]:
     if _lib is not None or _lib_tried:
         return _lib
     _lib_tried = True
+    from .telemetry import log as fflog
+
     try:
-        # make's own dependency check rebuilds iff pcg_core.cc is newer
-        subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
-                       capture_output=True)
-        lib = ctypes.CDLL(_LIB_PATH)
-        i32p = ctypes.POINTER(ctypes.c_int32)
-        lib.ff_topo_order.restype = ctypes.c_int
-        lib.ff_topo_order.argtypes = [ctypes.c_int32, ctypes.c_int32,
-                                      i32p, i32p, i32p]
-        lib.ff_bottlenecks.restype = ctypes.c_int
-        lib.ff_bottlenecks.argtypes = lib.ff_topo_order.argtypes
-        lib.ff_transitive_reduction.restype = ctypes.c_int
-        lib.ff_transitive_reduction.argtypes = lib.ff_topo_order.argtypes
-        lib.ff_idominators.restype = ctypes.c_int
-        lib.ff_idominators.argtypes = lib.ff_topo_order.argtypes
-        lib.ff_eval_makespan.restype = ctypes.c_double
-        lib.ff_eval_makespan.argtypes = [
-            ctypes.c_int32, ctypes.POINTER(ctypes.c_double),
-            ctypes.POINTER(ctypes.c_double),
-            ctypes.c_int32, i32p, i32p]
-        lib.ff_eval_makespan_axes.restype = ctypes.c_double
-        lib.ff_eval_makespan_axes.argtypes = [
-            ctypes.c_int32, ctypes.POINTER(ctypes.c_double),
-            ctypes.POINTER(ctypes.c_double), i32p,
-            ctypes.c_int32, i32p, i32p]
-        _lib = lib
-    except Exception:
-        _lib = None
+        with open(_SOURCE, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:12]
+        lib_name = f"libpcg_core.{digest}.so"
+        lib_path = os.path.join(_NATIVE_DIR, "build", lib_name)
+        if not os.path.exists(lib_path):
+            subprocess.run(
+                ["make", "-C", _NATIVE_DIR, f"LIB={lib_name}"],
+                check=True, capture_output=True, text=True)
+        lib = ctypes.CDLL(lib_path)
+    except (OSError, subprocess.CalledProcessError) as e:
+        detail = (getattr(e, "stderr", "") or "").strip()
+        fflog.warning(
+            "native PCG core unavailable (%s%s) — the Python fallback "
+            "serves", e, f": {detail}" if detail else "")
+        return None
+    i32p = ctypes.POINTER(ctypes.c_int32)
+    lib.ff_topo_order.restype = ctypes.c_int
+    lib.ff_topo_order.argtypes = [ctypes.c_int32, ctypes.c_int32,
+                                  i32p, i32p, i32p]
+    lib.ff_bottlenecks.restype = ctypes.c_int
+    lib.ff_bottlenecks.argtypes = lib.ff_topo_order.argtypes
+    lib.ff_transitive_reduction.restype = ctypes.c_int
+    lib.ff_transitive_reduction.argtypes = lib.ff_topo_order.argtypes
+    lib.ff_idominators.restype = ctypes.c_int
+    lib.ff_idominators.argtypes = lib.ff_topo_order.argtypes
+    lib.ff_eval_makespan.restype = ctypes.c_double
+    lib.ff_eval_makespan.argtypes = [
+        ctypes.c_int32, ctypes.POINTER(ctypes.c_double),
+        ctypes.POINTER(ctypes.c_double),
+        ctypes.c_int32, i32p, i32p]
+    lib.ff_eval_makespan_axes.restype = ctypes.c_double
+    lib.ff_eval_makespan_axes.argtypes = [
+        ctypes.c_int32, ctypes.POINTER(ctypes.c_double),
+        ctypes.POINTER(ctypes.c_double), i32p,
+        ctypes.c_int32, i32p, i32p]
+    _lib = lib
     return _lib
 
 

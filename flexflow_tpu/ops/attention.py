@@ -20,11 +20,13 @@ dim of the projection weights over the `model` axis.
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from ..fftype import DataType, OperatorType as OT
 from .base import OpDef, WeightSpec, matmul_cast, register_op
@@ -106,17 +108,33 @@ def _mha_forward(p: MultiHeadAttentionParams, inputs, weights, state, ctx):
     v = proj(v_in, weights["wv"], weights.get("bv"))
     scale = 1.0 / math.sqrt(hd)
 
+    if p.impl == "flash":
+        # the plan's shards of the kernel's operands: batch as the output
+        # places it, heads as wq's columns are placed (head-parallel);
+        # each shard runs the kernel on its own batch rows and heads
+        from ..kernels.dispatch import per_shard, shards_of, spec_entries
+
+        batch_ax = spec_entries(ctx.out_spec, 1)[0]
+        head_ax = spec_entries((ctx.weight_axes or {}).get("wq"), 2)[1]
+        if q.shape[0] % shards_of(ctx.mesh, batch_ax):
+            batch_ax = None
+        if H % shards_of(ctx.mesh, head_ax):
+            head_ax = None
+        h_local = H // shards_of(ctx.mesh, head_ax)
+
     if p.impl == "flash" and getattr(ctx, "flash_packed", True):
         # packed layout: the kernel selects heads with lane-offset block
         # index maps, so the projections' (b, s, H·hd) output feeds it
-        # directly — no (b,s,h,d)→(b,h,s,d) HBM relayout in fwd OR bwd
-        # (PERF.md measured those copies at ~0.8 ms per flagship step).
+        # directly — no (b,s,h,d)→(b,h,s,d) HBM relayout in fwd OR bwd.
         # ctx.flash_packed=False (--flash-transposed) forces the
         # head-transposed kernels below — the relayout ablation baseline.
         from ..kernels.flash_attention import flash_attention_packed
 
-        out = flash_attention_packed(q, k, v, num_heads=H, causal=p.causal,
-                                     scale=scale)
+        spec = PartitionSpec(batch_ax, None, head_ax)
+        out = per_shard(
+            functools.partial(flash_attention_packed, num_heads=h_local,
+                              causal=p.causal, scale=scale),
+            ctx.mesh, (spec, spec, spec), spec)(q, k, v)
         y = proj(out, weights["wo"], weights.get("bo"))
         return [y], state
 
@@ -138,7 +156,10 @@ def _mha_forward(p: MultiHeadAttentionParams, inputs, weights, state, ctx):
         # (b,s,h,d)↔(b,h,s,d) relayouts the packed path avoids
         from ..kernels.flash_attention import flash_attention
 
-        out = flash_attention(q, k, v, causal=p.causal, scale=scale)
+        spec = PartitionSpec(batch_ax, head_ax)
+        out = per_shard(
+            functools.partial(flash_attention, causal=p.causal, scale=scale),
+            ctx.mesh, (spec, spec, spec), spec)(q, k, v)
     else:
         out = sdpa_xla(q, k, v, causal=p.causal, scale=scale)
 

@@ -47,6 +47,11 @@ class OpContext:
     seq_length: int = -1
     profiling: bool = False
     mesh: Any = None  # global jax Mesh (for ops lowering to shard_map)
+    # the plan's placement of this node — PartitionSpec of output 0 and
+    # {weight name: PartitionSpec} — for ops that run a Pallas kernel per
+    # shard (kernels/dispatch.per_shard) instead of leaving it to GSPMD
+    out_spec: Any = None
+    weight_axes: Any = None
     # MXU input dtype for matmul/conv when activations are fp32 — the TPU
     # analog of the reference's cublas tensor-op math mode
     # (allow_tensor_op_math_conversion, include/flexflow/config.h): inputs

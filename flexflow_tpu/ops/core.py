@@ -298,7 +298,8 @@ def _ln_forward(p: LayerNormParams, inputs, weights, state, ctx):
         from ..kernels.layer_norm import fused_layer_norm_or_none
 
         fused = fused_layer_norm_or_none(
-            x, weights["scale"], weights["bias"], axes, p.eps)
+            x, weights["scale"], weights["bias"], axes, p.eps,
+            mesh=ctx.mesh, spec=ctx.out_spec)
         if fused is not None:
             return [fused], state
     xf = x.astype(jnp.float32)  # fp32 statistics under mixed precision

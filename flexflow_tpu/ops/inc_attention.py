@@ -61,6 +61,29 @@ class IncMultiHeadAttentionParams:
     impl: str = "auto"  # auto: flash decode on TPU (q_len=1), einsum else
 
 
+def _use_decode_kernel(op: str, impl: str, q_shape, ctx) -> bool:
+    """Whether this call runs the Pallas decode kernel. impl "flash"
+    always asks for it, "auto" asks on a TPU; of those asks, the two the
+    kernels cannot serve yet take the reference einsum, audibly on a TPU:
+    multi-query calls (prefill chunks, speculative verify — the kernels
+    are single-query) and multi-device meshes (GSPMD cannot partition a
+    Mosaic kernel, and the decode kernels are not run per shard yet)."""
+    if not (impl == "flash"
+            or (impl == "auto" and jax.default_backend() == "tpu")):
+        return False
+    gate = None
+    if q_shape[1] != 1:
+        gate = f"q_len {q_shape[1]} > 1 has no kernel"
+    elif ctx.mesh is not None and ctx.mesh.size > 1:
+        gate = f"{ctx.mesh.size}-device mesh: kernel not run per shard"
+    if gate is not None:
+        from ..kernels.dispatch import warn_reference
+
+        warn_reference(op, tuple(q_shape), gate)
+        return False
+    return True
+
+
 def _inc_mha_infer(p: IncMultiHeadAttentionParams, in_shapes):
     x, positions = in_shapes
     return [(x[0], x[1], p.embed_dim)]
@@ -96,7 +119,7 @@ def _inc_mha_weights(p: IncMultiHeadAttentionParams, in_shapes):
 def _inc_mha_forward(p: IncMultiHeadAttentionParams, inputs, weights,
                      state, ctx):
     x, positions = inputs
-    slots, q_len, _ = x.shape
+    slots = x.shape[0]
     H, E = p.num_heads, p.embed_dim
     hd = E // H
 
@@ -129,9 +152,7 @@ def _inc_mha_forward(p: IncMultiHeadAttentionParams, inputs, weights,
     ck = ck.at[slot_idx, write_pos].set(kw.astype(ck.dtype))
     cv = cv.at[slot_idx, write_pos].set(vw.astype(cv.dtype))
 
-    use_flash = (p.impl == "flash"
-                 or (p.impl == "auto" and jax.default_backend() == "tpu"))
-    if use_flash and q_len == 1:
+    if _use_decode_kernel("inc_multihead_attention", p.impl, q.shape, ctx):
         from ..kernels.flash_attention import flash_decode_attention
 
         out = flash_decode_attention(
@@ -240,7 +261,7 @@ def _paged_mha_weights(p: PagedIncMultiHeadAttentionParams, in_shapes):
 def _paged_mha_forward(p: PagedIncMultiHeadAttentionParams, inputs, weights,
                        state, ctx):
     x, positions, page_table = inputs
-    slots, q_len, _ = x.shape
+    slots = x.shape[0]
     H, E = p.num_heads, p.embed_dim
     hd = E // H
     bs = p.block_size
@@ -276,9 +297,8 @@ def _paged_mha_forward(p: PagedIncMultiHeadAttentionParams, inputs, weights,
     pk = pk.at[phys, offset].set(kw.astype(pk.dtype))
     pv = pv.at[phys, offset].set(vw.astype(pv.dtype))
 
-    use_flash = (p.impl == "flash"
-                 or (p.impl == "auto" and jax.default_backend() == "tpu"))
-    if use_flash and q_len == 1:
+    if _use_decode_kernel("paged_inc_multihead_attention", p.impl, q.shape,
+                          ctx):
         from ..kernels.flash_attention import paged_flash_decode_attention
 
         out = paged_flash_decode_attention(

@@ -264,12 +264,11 @@ def allgather_matmul(x, w, *, mesh=None, axis_name: str | None = None,
     block-matmul + ppermute steps. Falls back to a plain dot when there is
     no mesh / the axis has size 1. `overlap=False` is the serial ablation
     baseline (hop after each block's matmul)."""
+    import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
     from ..machine import AXIS_DATA, AXIS_MODEL
-    from .smap import shard_map
-
     axis_name = axis_name or AXIS_MODEL
     batch_axis = batch_axis or AXIS_DATA
     if mesh is None or mesh.shape.get(axis_name, 1) == 1:
@@ -286,7 +285,7 @@ def allgather_matmul(x, w, *, mesh=None, axis_name: str | None = None,
     b_entry = batch_axis if mesh.shape.get(batch_axis, 1) > 1 else None
     xspec = P(b_entry, *([None] * (nd - 2)), axis_name)
     ospec = P(b_entry, *([None] * (nd - 1)))
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ag_matmul_local, axis_name=axis_name, n=n,
                           overlap=overlap),
         mesh=mesh,
@@ -433,11 +432,10 @@ def ring_reduce_scatter(x, *, mesh=None, axis_name: str | None = None,
     identity when there is no mesh / the axis has size 1."""
     import functools
 
+    import jax
     from jax.sharding import PartitionSpec as P
 
     from ..machine import AXIS_DATA
-    from .smap import shard_map
-
     axis_name = axis_name or AXIS_DATA
     if mesh is None or mesh.shape.get(axis_name, 1) == 1:
         return x
@@ -447,7 +445,7 @@ def ring_reduce_scatter(x, *, mesh=None, axis_name: str | None = None,
             f"ring_reduce_scatter: dim 0 of {x.shape} must divide by "
             f"{axis_name!r} size {n} twice (local chunking)")
     nd = x.ndim
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_rs_local, axis_name=axis_name, n=n,
                           overlap=overlap),
         mesh=mesh,
@@ -508,11 +506,10 @@ def ring_all_gather(x, *, mesh=None, axis_name: str | None = None,
     there is no mesh / the axis has size 1."""
     import functools
 
+    import jax
     from jax.sharding import PartitionSpec as P
 
     from ..machine import AXIS_DATA
-    from .smap import shard_map
-
     axis_name = axis_name or AXIS_DATA
     if mesh is None or mesh.shape.get(axis_name, 1) == 1:
         return x
@@ -522,7 +519,7 @@ def ring_all_gather(x, *, mesh=None, axis_name: str | None = None,
         in_spec = P(*([None] * dim), axis_name, *([None] * (nd - dim - 1)))
     if out_spec is None:
         out_spec = P(*([None] * nd))
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ag_local, axis_name=axis_name, n=n, dim=dim,
                           overlap=overlap),
         mesh=mesh,

@@ -33,8 +33,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..machine import AXIS_DATA, AXIS_PIPE
-from .smap import shard_map
-
 
 def _sequential(stacked, x, block_fn):
     """Reference semantics: apply the L stacked blocks in order."""
@@ -119,7 +117,7 @@ def pipeline_apply(
 
     w_spec = jax.tree.map(lambda _: P(axis_name), stacked)
     x_spec = P(batch_axis if mesh.shape.get(batch_axis, 1) > 1 else None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _pipelined_local, block_fn=block_fn, axis_name=axis_name,
             num_stages=p, num_micro=m,

@@ -50,8 +50,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..machine import AXIS_DATA, AXIS_MODEL, AXIS_SEQ
-from .smap import shard_map
-
 
 def _block_attention(q, k_blk, v_blk, *, causal: bool, scale: float):
     """One ring block's attention: (out f32, lse f32) via the flash
@@ -168,7 +166,7 @@ def ring_attention(
         axis_name,
         None,
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ring_local, axis_name=axis_name, n=n,
             causal=causal, scale=scale, overlap=overlap,

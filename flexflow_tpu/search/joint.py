@@ -232,18 +232,24 @@ def _split_at(g: Graph, split: OpNode) -> tuple[Graph, Graph, OpNode, str]:
 def _join(pre: Graph, post: Graph, boundary_in: OpNode, token: str) -> Graph:
     """Merge optimized halves back into one graph: post's synthetic input
     collapses onto pre's (possibly rewritten) boundary node, found by its
-    token."""
+    token. The synthetic input is found by its name, which is unique to
+    the split: a half that was itself split or rewritten comes back as a
+    graph of clones, so the node is no longer `boundary_in` itself — and
+    one missed here stays in the joined graph as an input nothing feeds."""
     out = Graph()
     clone: dict[int, OpNode] = {}
 
+    def is_boundary_in(n: OpNode) -> bool:
+        return n.op_type == OT.OP_INPUT and n.name == boundary_in.name
+
     def copy_graph(g: Graph):
         for n in g.topo_order():
-            if n is boundary_in:
+            if is_boundary_in(n):
                 continue
             clone[n.guid] = _clone_basic(out, n)
         for n in g.topo_order():
             for e in g.in_edges[n.guid]:
-                if g.nodes[e.src] is boundary_in:
+                if is_boundary_in(g.nodes[e.src]):
                     continue  # rewired below
                 out.add_edge(clone[e.src], clone[e.dst],
                              e.src_idx, e.dst_idx)
@@ -254,7 +260,7 @@ def _join(pre: Graph, post: Graph, boundary_in: OpNode, token: str) -> Graph:
     copy_graph(post)
     for n in post.topo_order():
         for e in post.in_edges[n.guid]:
-            if post.nodes[e.src] is boundary_in:
+            if is_boundary_in(post.nodes[e.src]):
                 out.add_edge(clone[boundary.guid], clone[e.dst],
                              0, e.dst_idx)
     # this split's token is spent; nested splits' tokens stay intact

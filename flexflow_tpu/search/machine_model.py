@@ -37,34 +37,50 @@ class ChipSpec:
     dcn_latency: float = 10e-6
 
 
+# Peaks of one chip. The one table every consumer reads — the search's
+# roofline, the goodput anchor (model.py) and bench.py's MFU. v5e: Google
+# Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s of interconnect over 4 links); the other rows
+# are the same pages' figures for their chips. The `cpu` row is for the
+# search tests on the virtual CPU mesh, not a device anyone measures.
 CHIPS = {
-    "v5e": ChipSpec("v5e", 197e12, 8.1e11, 16e9, 4.5e10, 4),
+    "v5e": ChipSpec("v5e", 197e12, 8.19e11, 16e9, 4.5e10, 4),
     "v5p": ChipSpec("v5p", 459e12, 2.765e12, 95e9, 9e10, 6),
     "v4": ChipSpec("v4", 275e12, 1.2e12, 32e9, 4.5e10, 6),
     "v6e": ChipSpec("v6e", 918e12, 1.64e12, 32e9, 9e10, 4),
     "cpu": ChipSpec("cpu", 2e11, 5e10, 32e9, 1e10, 2),
 }
 
+# `device_kind` as JAX reports it -> row of CHIPS.
+DEVICE_KINDS = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e",
+    "TPU v5e": "v5e",
+    "TPU v5": "v5p",
+    "TPU v5p": "v5p",
+    "TPU v6 lite": "v6e",
+    "TPU v6e": "v6e",
+    "cpu": "cpu",
+}
+
+
+def chip_for(device) -> ChipSpec:
+    """The table's row for a JAX device. A device that is not in the
+    table is an error, not a default: every number priced or normalised
+    against the wrong chip's peaks would be wrong without a sign of it."""
+    kind = device.device_kind
+    if kind not in DEVICE_KINDS:
+        raise ValueError(
+            f"unknown device_kind {kind!r} (platform {device.platform!r}): "
+            f"add its peaks to search/machine_model.CHIPS; have "
+            f"{sorted(DEVICE_KINDS)}")
+    return CHIPS[DEVICE_KINDS[kind]]
+
 
 def detect_chip() -> ChipSpec:
-    try:
-        import jax
+    import jax
 
-        dev = jax.devices()[0]
-        kind = getattr(dev, "device_kind", "").lower()
-        if "v5 lite" in kind or "v5e" in kind:
-            return CHIPS["v5e"]
-        if "v5" in kind:
-            return CHIPS["v5p"]
-        if "v4" in kind:
-            return CHIPS["v4"]
-        if "v6" in kind:
-            return CHIPS["v6e"]
-        if dev.platform == "cpu":
-            return CHIPS["cpu"]
-    except Exception:
-        pass
-    return CHIPS["v5p"]
+    return chip_for(jax.devices()[0])
 
 
 @dataclass

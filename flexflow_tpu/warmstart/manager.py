@@ -35,46 +35,43 @@ from .fingerprint import (
 )
 from .plan_cache import PlanCache
 
-# process-wide: jax's compilation-cache dir is global config, set once
+# process-wide: jax's compilation-cache dir is global config. The last
+# directory THIS module pointed it at (None: never).
 _exec_cache_dir: Optional[str] = None
 
 
-def enable_executable_cache(directory: str) -> bool:
-    """Point JAX's persistent compilation cache under `directory`
-    (idempotent; re-pointing to a different dir follows the newest
-    request). Returns whether the cache is on. Never raises — an
-    unsupported backend/jax version just leaves the layer off."""
+def enable_executable_cache(directory: str) -> str:
+    """Turn on JAX's persistent compilation cache for a warm-start
+    directory. Where `JAX_COMPILATION_CACHE_DIR` is set, the cache has
+    been placed from outside and stays there: this sets no directory.
+    Otherwise it goes under `<directory>/xla_cache` (idempotent;
+    re-pointing to a different dir follows the newest request). Either
+    way every executable is cached, small and fast-compiling ones too:
+    jax's default thresholds protect long-lived shared caches, and a
+    restart wants all of its executables back. Returns the directory the
+    cache is in."""
     global _exec_cache_dir
-    cache_dir = os.path.join(os.path.abspath(directory), "xla_cache")
-    if _exec_cache_dir == cache_dir:
-        return True
     import jax
 
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        if _exec_cache_dir is not None:
-            # jax materializes the cache object lazily from the config and
-            # then pins it — re-pointing an already-initialized cache to a
-            # new directory needs an explicit reset
-            try:
-                from jax._src import compilation_cache
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    cache_dir = os.path.join(os.path.abspath(directory), "xla_cache")
+    if _exec_cache_dir == cache_dir:
+        return cache_dir
+    os.makedirs(cache_dir, exist_ok=True)
+    if _exec_cache_dir is not None:
+        # jax materializes the cache object lazily from the config and
+        # then pins it — re-pointing an already-initialized cache to a
+        # new directory needs an explicit reset
+        from jax.experimental.compilation_cache import compilation_cache
 
-                compilation_cache.reset_cache()
-            except Exception:
-                pass
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # CI-scale executables are small and fast to compile — cache them
-        # all; the default thresholds exist to protect long-lived prod
-        # caches, and ours lives inside the run's own warm-start dir
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        _exec_cache_dir = cache_dir
-        return True
-    except Exception as e:  # unsupported backend / jax version
-        fflog.warning(
-            "warmstart: persistent executable cache unavailable (%s) — "
-            "plan/calibration layers still active", e)
-        return False
+        compilation_cache.reset_cache()
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    _exec_cache_dir = cache_dir
+    return cache_dir
 
 
 class WarmStartManager:
@@ -85,7 +82,7 @@ class WarmStartManager:
         self.directory = os.path.abspath(directory)
         self.plan_cache = PlanCache(self.directory)
         self.calibration_db = CalibrationDB(self.directory)
-        self.executable_cache_on = enable_executable_cache(self.directory)
+        self.executable_cache_dir = enable_executable_cache(self.directory)
         self.structural_fp: Optional[str] = None
         self.full_fp: Optional[str] = None
         self.calibration_loaded = 0
@@ -272,7 +269,7 @@ def restore_plan(model, graph, cost_model, calibrate_fn):
             calibration_loaded=warm.calibration_loaded,
             calibration_measured=stats.get("measured", 0),
             calibration_cache_hits=stats.get("cache_hits", 0),
-            executable_cache=warm.executable_cache_on)
+            executable_cache=warm.executable_cache_dir)
         return None
     overrides, mesh_axes = hit
     telemetry.instant("warmstart.plan_hit", source="cache")
@@ -284,7 +281,7 @@ def restore_plan(model, graph, cost_model, calibrate_fn):
         calibration_loaded=warm.calibration_loaded,
         calibration_measured=stats.get("measured", 0),
         calibration_cache_hits=stats.get("cache_hits", 0),
-        executable_cache=warm.executable_cache_on)
+        executable_cache=warm.executable_cache_dir)
     fflog.info("warmstart: plan cache hit %s — search skipped",
                warm.full_fp[:16])
     return overrides, mesh_axes, "cache"

@@ -1,0 +1,223 @@
+"""Ahead-of-time compiles for a described TPU v5e (no chip attached).
+
+Interpret mode on the CPU cannot show what the TPU's compiler refuses: a
+block shape Mosaic cannot tile, a kernel GSPMD cannot partition. The TPU
+compiler is installed with JAX and compiles for a chip that is described,
+not attached (`topologies.get_topology_desc`), so the main path's kernels
+are compiled here at real widths: about two seconds each, no chip time.
+Nothing runs — these say a program lowers and which kernels it holds,
+never how fast it is.
+
+The code under test asks `jax.default_backend()` to choose between Mosaic
+and interpret mode and would take its CPU branch here; the `tpu` fixture
+steers it (in the test, not through an option of the program). The
+persistent compilation cache is off around the module: such a compile can
+be written to it but not read back without a chip.
+"""
+
+import importlib
+import os
+import subprocess
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from flexflow_tpu.kernels import layer_norm
+from flexflow_tpu.kernels.dispatch import KernelFallbackWarning, pallas_kernels
+
+fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
+
+
+@pytest.fixture(scope="module")
+def topology():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def tpu(topology, monkeypatch):
+    """The described devices, with the code under test told it is on a
+    TPU so that it lowers through Mosaic."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return topology.devices
+
+
+def _kernels(fn, *shapes):
+    return pallas_kernels(jax.jit(fn).lower(*shapes).compile().as_text())
+
+
+def _on(device):
+    one = SingleDeviceSharding(device)
+    return lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one)
+
+
+def _with_grads(fn):
+    def run(*args):
+        return jax.value_and_grad(
+            lambda *a: fn(*a).astype(jnp.float32).sum(),
+            argnums=tuple(range(len(args))))(*args)
+    return run
+
+
+@pytest.mark.parametrize("batch,seq", [(8, 512), (1, 4096)])
+def test_packed_flash_fwd_bwd_head_dim_64(tpu, batch, seq):
+    """lm-base's attention: 16 heads of 64 on the packed (b, s, h·d)
+    layout, forward and both backward kernels."""
+    s = _on(tpu[0])
+    x = s((batch, seq, 1024))
+    kernels = _kernels(_with_grads(
+        lambda q, k, v: fa.flash_attention_packed(
+            q, k, v, num_heads=16, causal=True)), x, x, x)
+    assert kernels == {"flash_attention_fwd_packed_grouped": 1,
+                       "flash_attention_bwd_dq_packed_grouped": 1,
+                       "flash_attention_bwd_dkv_packed_grouped": 1}
+
+
+def test_fused_layer_norm_fwd_bwd(tpu):
+    s = _on(tpu[0])
+    kernels = _kernels(_with_grads(
+        lambda x, sc, b: layer_norm.fused_layer_norm_or_none(
+            x, sc, b, (1,), 1e-5)),
+        s((4096, 1024)), s((1024,), jnp.float32), s((1024,), jnp.float32))
+    assert kernels == {"layer_norm_fwd": 1, "layer_norm_bwd": 1}
+
+
+def test_contiguous_decode_head_dim_128(tpu):
+    """The contiguous decode kernel at the engine's real cache shape
+    (slots, max_seq + 1, E): max_seq + 1 is odd, so the last kv block is
+    ragged. The lengths ride scalar prefetch — as a (1, lanes) stripe
+    block Mosaic refused them, and the kernel had only ever run in
+    interpret mode."""
+    s = _on(tpu[0])
+    slots, heads, e = 8, 32, 32 * 128
+    kernels = _kernels(
+        lambda q, k, v, n: fa.flash_decode_attention(
+            q, k, v, n, num_heads=heads),
+        s((slots, 1, e)), s((slots, 1025, e)), s((slots, 1025, e)),
+        s((slots,), jnp.int32))
+    assert kernels == {"flash_attention_decode": 1}
+
+
+@pytest.mark.parametrize("block_size", [16, 128])
+def test_paged_decode_head_dim_128(tpu, block_size):
+    s = _on(tpu[0])
+    slots, heads, e = 8, 32, 32 * 128
+    width = 1024 // block_size
+    pool = s((slots * width + 1, block_size, e))
+    kernels = _kernels(
+        lambda q, pk, pv, tbl, n: fa.paged_flash_decode_attention(
+            q, pk, pv, tbl, n, num_heads=heads),
+        s((slots, 1, e)), pool, pool, s((slots, width), jnp.int32),
+        s((slots,), jnp.int32))
+    assert kernels == {"flash_attention_paged_decode": 1}
+
+
+@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+def test_decode_head_dim_64_takes_the_reference_and_says_so(tpu, layout):
+    """Every zoo tier but lm-xxl-fsdp has head_dim 64, where heads cannot
+    be selected by lane offset: the decode kernels give way to the
+    reference einsum. On a TPU that is said, not hidden."""
+    s = _on(tpu[0])
+    slots, heads, e = 4, 16, 16 * 64
+    q, n = s((slots, 1, e)), s((slots,), jnp.int32)
+    if layout == "contiguous":
+        fn = lambda q, k, v, n: fa.flash_decode_attention(  # noqa: E731
+            q, k, v, n, num_heads=heads)
+        args = (q, s((slots, 513, e)), s((slots, 513, e)), n)
+    else:
+        fn = lambda q, pk, pv, tbl, n: fa.paged_flash_decode_attention(  # noqa: E731
+            q, pk, pv, tbl, n, num_heads=heads)
+        pool = s((slots * 32 + 1, 16, e))
+        args = (q, pool, pool, s((slots, 32), jnp.int32), n)
+    with pytest.warns(KernelFallbackWarning, match=r"head_dim 64 % 128"):
+        kernels = _kernels(fn, *args)
+    assert not kernels
+
+
+@pytest.mark.parametrize("mesh_flag,megatron", [("4,1,1,1", False),
+                                                ("2,2,1,1", True)])
+def test_train_step_lowers_for_four_chips(topology, monkeypatch, mesh_flag,
+                                          megatron):
+    """A whole train step with flash attention and the fused LayerNorm
+    for a four-chip mesh. GSPMD cannot partition a Mosaic kernel, so the
+    ops run them per shard (kernels/dispatch.per_shard); left to GSPMD
+    this step does not lower at all — which the virtual CPU mesh, where
+    the kernels are interpreted, cannot show."""
+    from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
+    from flexflow_tpu.fftype import DataType
+    from flexflow_tpu.models import TransformerLMConfig, build_transformer_lm
+    from flexflow_tpu.parallel import megatron_transformer
+
+    sys.argv = ["test", "--mesh", mesh_flag]
+    config = FFConfig()
+    config.batch_size = 8
+    config.computation_dtype = DataType.DT_BFLOAT16
+    ff = FFModel(config)
+    cfg = TransformerLMConfig(
+        vocab_size=512, hidden_size=256, num_heads=4, num_layers=1,
+        sequence_length=128, attention_impl="flash")
+    build_transformer_lm(ff, cfg, batch_size=8)
+    if megatron:
+        ff.set_strategy(megatron_transformer(ff))
+    ff.compile(optimizer=SGDOptimizer(lr=0.01),
+               loss_type=LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY)
+
+    # the same step, its arguments described on the four described chips
+    ex = ff.executor
+    mesh = Mesh(np.array(topology.devices).reshape(ff.mesh.devices.shape),
+                ff.mesh.axis_names)
+
+    def described(x):
+        spec = (x.sharding.spec if isinstance(x.sharding, NamedSharding)
+                else PartitionSpec())
+        return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    toks = np.zeros((8, 128), np.int32)
+    batch = ff._make_batch({"tokens": toks, "positions": toks},
+                           np.zeros((8, 128, 1), np.int32))
+    rng = jax.device_put(jax.random.key(0),
+                         NamedSharding(ff.mesh, PartitionSpec()))
+    args = jax.tree.map(described, (
+        ff._params, ff._state, ff._opt_slots, ff._step, ff._counters, rng,
+        batch))
+    monkeypatch.setattr(ex, "mesh", mesh)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = jax.jit(ex._train_step_body).lower(*args).compile().as_text()
+    kernels = pallas_kernels(text)
+    assert sum(v for k, v in kernels.items()
+               if k.startswith("flash_attention")) == 3, kernels
+    assert kernels["layer_norm_fwd"] == 3 and kernels["layer_norm_bwd"] == 3
+    assert "all-reduce" in text
+
+
+def test_chip_smoke_refuses_to_run_without_a_tpu():
+    """chip_smoke.py has no CPU mode: with JAX held to the CPU it exits
+    non-zero and prints no result line."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, "chip_smoke.py")],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=120)
+    assert proc.returncode != 0
+    assert "needs a TPU" in proc.stderr
+    assert '"ok"' not in proc.stdout

@@ -403,3 +403,64 @@ def test_health_sample_every_default_keeps_per_step_records(tmp_path):
     ff.fit(x, y, epochs=1, batch_size=8, shuffle=True)
     assert [s for s, _ in spy.records] == list(range(1, 9))
     assert all(l is not None for _, l in spy.records)
+
+
+# ===================================================================
+# one compilation per step program: what the steps hand back
+# ===================================================================
+
+def test_reset_metrics_does_not_recompile_the_train_step():
+    """reset_metrics places the fresh counters as compile placed them.
+    Unplaced counters would give the next step a second argument
+    signature — a second compilation of the whole train step."""
+    ff = _mlp()
+    x, y = _data()
+    fit = dict(epochs=1, batch_size=8, shuffle=False, verbose=False)
+    ff.fit(x, y, **fit)
+    step = ff.executor._train_step
+    compiled = step._cache_size()
+    ff.reset_metrics()
+    ff.fit(x, y, **fit)
+    assert step._cache_size() == compiled
+
+
+def test_train_step_returns_weights_in_their_planned_placement():
+    """A searched plan can shard an op's output where its weight is
+    replicated (a LayerNorm under a feature-sharded activation). Left to
+    GSPMD the updated weight comes back in its gradient's layout, and the
+    second step compiles again for arguments the first was not compiled
+    for; the step pins its outputs to the at-rest placement instead."""
+    import jax
+    from jax.sharding import PartitionSpec
+
+    from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
+    from flexflow_tpu.models import TransformerLMConfig, build_transformer_lm
+    from flexflow_tpu.parallel.strategies import Strategy
+
+    sys.argv = ["test", "--mesh", "2,2,1,1"]
+    config = FFConfig()
+    config.batch_size = 4
+    ff = FFModel(config)
+    cfg = TransformerLMConfig(vocab_size=64, hidden_size=32, num_heads=2,
+                              num_layers=1, sequence_length=8,
+                              attention_impl="xla")
+    build_transformer_lm(ff, cfg, batch_size=4)
+    plan = Strategy()
+    plan.set_output("l0_ln2", 0, (("data",), (), ("model",)))
+    # a trailing None places like its trimmed form but does not compare
+    # equal to it — and a step's outputs come back trimmed
+    plan.set_weight("l0_ffn2", "kernel", PartitionSpec("model", None))
+    ff.set_strategy(plan)
+    ff.compile(optimizer=SGDOptimizer(lr=0.01),
+               loss_type=LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY)
+    rs = np.random.RandomState(0)
+    x = {"tokens": rs.randint(0, 64, (8, 8)).astype(np.int32),
+         "positions": np.tile(np.arange(8, dtype=np.int32), (8, 1))}
+    y = rs.randint(0, 64, (8, 8, 1)).astype(np.int32)
+    placed = jax.tree.map(lambda leaf: leaf.sharding, ff._params)
+    ff.fit(x, y, epochs=1, batch_size=4, shuffle=False, verbose=False)
+    assert ff.executor._train_step._cache_size() == 1
+    assert jax.tree.map(lambda leaf: leaf.sharding, ff._params) == placed
+    assert ff._params["l0_ln2"]["scale"].sharding.spec == PartitionSpec()
+    assert (ff._params["l0_ffn2"]["kernel"].sharding.spec
+            == PartitionSpec("model"))

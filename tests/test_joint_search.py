@@ -200,6 +200,14 @@ def test_joint_search_bounded_on_bench_scale_lm():
     # cache this takes many minutes
     assert elapsed < 120, f"joint search took {elapsed:.1f}s"
     assert best_g is not None and choice
+    # nested splits collapse every synthetic boundary input: the joined
+    # graph reads the model's inputs and nothing else (a boundary left in
+    # is an input no batch feeds, and the first train step dies on it)
+    from flexflow_tpu.fftype import OperatorType as OT
+
+    assert (sorted(n.name for n in best_g.topo_order()
+                   if n.op_type == OT.OP_INPUT)
+            == sorted(t.name for t in ff._input_tensors))
     # repeated transformer blocks must hit the shared segment cache
     assert us.cache_hits > 0 or len(us._segment_cache) > 0
 

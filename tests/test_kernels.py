@@ -305,3 +305,52 @@ def test_flash_packed_grad(s, block):
     for a, b_ in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("mesh_flag,megatron", [("4,1,1,1", False),
+                                                ("2,2,1,1", True)])
+def test_flash_and_layer_norm_per_shard_match_one_device(mesh_flag,
+                                                         megatron):
+    """On a multi-device mesh the ops run the flash and LayerNorm kernels
+    once per shard (batch rows over `data`, heads over `model`) inside
+    shard_map; the losses of a few train steps match the one-device run."""
+    import sys
+
+    from flexflow_tpu import (
+        FFConfig, FFModel, LossType, MetricsType, SGDOptimizer,
+    )
+    from flexflow_tpu.models import TransformerLMConfig, build_transformer_lm
+    from flexflow_tpu.parallel import megatron_transformer
+
+    cfg = TransformerLMConfig(vocab_size=128, hidden_size=128, num_heads=4,
+                              num_layers=1, sequence_length=128,
+                              attention_impl="flash")
+    rs = np.random.RandomState(0)
+    x = {"tokens": rs.randint(0, 128, (4, 128)).astype(np.int32),
+         "positions": np.tile(np.arange(128, dtype=np.int32), (4, 1))}
+    y = rs.randint(0, 128, (4, 128, 1)).astype(np.int32)
+
+    def losses(flag, strategy_fn):
+        sys.argv = ["test", "--mesh", flag]
+        config = FFConfig()
+        config.batch_size = 4
+        ff = FFModel(config)
+        build_transformer_lm(ff, cfg, batch_size=4)
+        if strategy_fn is not None:
+            ff.set_strategy(strategy_fn(ff))
+        ff.compile(
+            optimizer=SGDOptimizer(lr=0.05),
+            loss_type=LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY,
+            metrics=[MetricsType.METRICS_SPARSE_CATEGORICAL_CROSSENTROPY])
+        out = []
+        for _ in range(3):
+            ff.reset_metrics()
+            ff.fit(x, y, epochs=1, batch_size=4, shuffle=False,
+                   verbose=False)
+            out.append(ff.get_perf_metrics().get_mean_loss())
+        return out
+
+    one = losses("1,1,1,1", None)
+    many = losses(mesh_flag, megatron_transformer if megatron else None)
+    assert one[-1] < one[0]
+    np.testing.assert_allclose(many, one, rtol=1e-5)

@@ -3,8 +3,7 @@
 The acceptance surface of the decode-graph + continuous-batching
 subsystem, on the CPU mesh (the decode attention op routes through the
 reference einsum there, so everything below is Pallas-free except the
-kernel-parity test, which the conftest capability probe converts to a
-clean skip on environment gaps):
+kernel-parity tests, which run the kernels in interpret mode):
 
   - greedy decode is token-identical to the teacher-forced training
     forward's argmax at every generated position;
@@ -620,11 +619,12 @@ def test_paged_analysis_coverage():
         pool_bytes - c.num_layers * 2 * 2 * bs * c.hidden_size * 4
 
 
-def test_flash_decode_kernel_matches_reference():
+@pytest.mark.parametrize("S", [256, 257])
+def test_flash_decode_kernel_matches_reference(S):
     """The Pallas single-query decode kernel (interpret mode on CPU)
     matches the einsum reference across partial/full/one-token cache
-    fills. Converted to a clean skip by the conftest capability probe
-    when the environment lacks the Pallas APIs."""
+    fills — also at an odd row count (the engine's cache holds
+    max_seq + 1 rows), where the last kv block is ragged."""
     import jax.numpy as jnp
 
     from flexflow_tpu.kernels.flash_attention import (
@@ -633,16 +633,16 @@ def test_flash_decode_kernel_matches_reference():
     )
 
     rs = np.random.RandomState(0)
-    slots, S, H, hd = 3, 256, 2, 64
+    slots, H, hd = 3, 2, 64
     E = H * hd
     q = jnp.asarray(rs.randn(slots, 1, E), jnp.float32)
     k = jnp.asarray(rs.randn(slots, S, E), jnp.float32)
     v = jnp.asarray(rs.randn(slots, S, E), jnp.float32)
-    lengths = jnp.asarray([1, 100, 256], jnp.int32)
+    lengths = jnp.asarray([1, 100, S], jnp.int32)
     ref = decode_attention_reference(q, k, v, (lengths - 1)[:, None],
                                      num_heads=H)
     out = flash_decode_attention(q, k, v, lengths, num_heads=H,
-                                 block_k=128, interpret=True)
+                                 block_k=128)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -651,8 +651,7 @@ def test_paged_flash_decode_kernel_matches_reference():
     """The PAGED Pallas decode kernel — kv grid walking the page table
     via scalar prefetch — matches the gather + einsum oracle across
     partial/full/one-token fills, scrambled tables, and blocks shared
-    between slots. Converted to a clean skip by the conftest capability
-    probe when the environment lacks the Pallas APIs."""
+    between slots."""
     import jax.numpy as jnp
 
     from flexflow_tpu.kernels.flash_attention import (
@@ -677,6 +676,6 @@ def test_paged_flash_decode_kernel_matches_reference():
     ref = paged_decode_attention_reference(
         q, pool_k, pool_v, table, (lengths - 1)[:, None], num_heads=H)
     out = paged_flash_decode_attention(
-        q, pool_k, pool_v, table, lengths, num_heads=H, interpret=True)
+        q, pool_k, pool_v, table, lengths, num_heads=H)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)

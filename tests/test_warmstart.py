@@ -405,17 +405,35 @@ def test_warm_strategy_report_describes_adopted_plan(tmp_path):
     assert t == pytest.approx(warm["total_predicted_s"], rel=1e-9)
 
 
-def test_executable_cache_populated(tmp_path):
-    """When the persistent XLA cache is available on this backend, the
-    warm-start dir accumulates executable entries during compile. The
-    model dims are unique to this test: jax memoizes compilation
-    per-process by HLO hash, so an already-compiled model would never
-    reach the persistent-cache layer again."""
+def test_executable_cache_populated(tmp_path, monkeypatch):
+    """With no cache placed from outside, the warm-start dir accumulates
+    executable entries during compile. The model dims are unique to this
+    test: jax memoizes compilation per-process by HLO hash, so an
+    already-compiled model would never reach the persistent-cache layer
+    again."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     ws = str(tmp_path / "ws")
     ff = _build(["--mesh", "2,4,1,1", "--only-data-parallel",
                  "--warmstart-dir", ws], hidden=192, in_dim=48)
-    if not ff._warmstart.executable_cache_on:
-        pytest.skip("persistent compilation cache unsupported here")
     cache_dir = os.path.join(ws, "xla_cache")
+    assert ff._warmstart.executable_cache_dir == cache_dir
     assert os.path.isdir(cache_dir)
     assert len(os.listdir(cache_dir)) > 0
+
+
+def test_executable_cache_placed_from_outside_is_not_overridden(
+        tmp_path, monkeypatch):
+    """Where JAX_COMPILATION_CACHE_DIR is set, the warm-start manager
+    keeps its plan cache and calibration DB under --warmstart-dir and
+    sets no compile-cache directory of its own."""
+    import jax
+
+    outside = str(tmp_path / "outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+    before = jax.config.jax_compilation_cache_dir
+    ws = str(tmp_path / "ws")
+    ff = _build(["--mesh", "2,4,1,1", "--only-data-parallel",
+                 "--warmstart-dir", ws], hidden=176, in_dim=40)
+    assert ff._warmstart.executable_cache_dir == outside
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not os.path.exists(os.path.join(ws, "xla_cache"))
