@@ -56,12 +56,11 @@ class SingleDataLoader:
         self.next_index = idx
 
     def next_batch(self, ffmodel=None) -> np.ndarray:
-        with telemetry.span("data.next_batch"):
-            if self.next_index + self.batch_size > self.num_samples:
-                self.next_index = 0
-            sl = slice(self.next_index, self.next_index + self.batch_size)
-            self.next_index += self.batch_size
-            return self.full_array[sl]
+        if self.next_index + self.batch_size > self.num_samples:
+            self.next_index = 0
+        sl = slice(self.next_index, self.next_index + self.batch_size)
+        self.next_index += self.batch_size
+        return self.full_array[sl]
 
     def _resolve_sharding(self):
         """The input node's NamedSharding, cached at first use (False

@@ -199,9 +199,6 @@ def _finalize_artifacts(model, decision: dict, *, rolled_back: bool):
         telemetry.inc("elastic_replan_decisions_total",
                       decision=str(decision.get("decision", "unknown")),
                       trigger=str(decision.get("trigger", "unknown")))
-        if decision.get("research_s") is not None:
-            telemetry.observe("elastic_research_s",
-                              decision["research_s"])
         telemetry.event("replan", **decision)
     else:
         # direct replan() call outside a fit window: land the event in

@@ -1874,6 +1874,10 @@ class FFModel:
                 # step right where the host-side trace shows its dispatch
                 stack.enter_context(
                     jax.profiler.trace(self.config.xprof_dir))
+            fit_span = telemetry.span(
+                "fit", steps=max(0, epochs - start_epoch) * num_batches,
+                batch_size=batch_size)
+            fit_span.__enter__()
             try:
                 for epoch in range(start_epoch, epochs):
                     abs_e = self._epoch_base + epoch
@@ -2052,7 +2056,8 @@ class FFModel:
                                 "committed, stopping fit", py_step)
                             flightrec.dump("sigterm")
                             return
-                    jax.block_until_ready(self._params)
+                    with telemetry.span("fit.drain"):
+                        jax.block_until_ready(self._params)
                     dt = time.time() - t0
                     thru = (num_batches - b0) * batch_size / dt
                     epoch_log(
@@ -2097,6 +2102,9 @@ class FFModel:
                 if resil is not None:
                     resil.finalize()
             finally:
+                # closed by hand, not by the stack: the session's trace is
+                # written below and has to hold this span
+                fit_span.__exit__(None, None, None)
                 if watchdog is not None:
                     watchdog.stop()
                 if scope_prof is not None:

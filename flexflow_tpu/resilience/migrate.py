@@ -147,7 +147,6 @@ def _migrate_impl(old, new, *, plan, donate: bool) -> dict:
 
         record_fidelity(new, measured_s / plan.predicted_s)
     new._transition = plan_json
-    telemetry.inc("migrations_total")
     telemetry.observe("migration_s", measured_s)
     telemetry.event(
         "migrate", predicted_s=plan.predicted_s, measured_s=measured_s,

@@ -2,7 +2,7 @@
 
 A fixed-capacity in-memory ring of the last N telemetry events (spans,
 instants, counters, step boundaries).  Recording follows the same
-one-global-read no-op discipline as ``telemetry.span``: when disabled
+one-global-read no-op discipline as ``telemetry.instant``: when disabled
 (``FF_FLIGHT_RECORDER=0``) every hook is a single global load plus an
 ``is None`` test.  When enabled, a record is index assignments into
 preallocated mutable slots — no objects are allocated per event in the

@@ -60,6 +60,19 @@ from .paged import SCRATCH_BLOCK, BlockManager
 from .scheduler import ContinuousBatchingScheduler, Request
 
 
+def _kv_read_itemsize(decode_model) -> int:
+    """Bytes of one K or V element as a step's attention reads it: the
+    pool's (or the contiguous cache's) dtype, or the compute dtype where
+    that is narrower (the op casts the cache to the query's dtype before
+    it attends)."""
+    stored = next(
+        (ws[name].dtype.itemsize for ws in decode_model._state.values()
+         for name in ("pool_k", "cache_k") if name in ws), 0)
+    compute = decode_model.executor.compute_dtype
+    return int(stored if compute is None
+               else min(stored, np.dtype(compute).itemsize))
+
+
 class ServingEngine:
     def __init__(self, model, **overrides):
         import jax
@@ -167,6 +180,8 @@ class ServingEngine:
         # merged metrics plane counts every request exactly once
         self._pre_release_hook = None
         self._suppress_completion_events = False
+        self._iterations = 0  # step() calls that found work, ever
+        self._kv_itemsize = _kv_read_itemsize(self.decode_model)
         # run accounting (stats())
         self._decode_iterations = 0
         self._decode_tokens = 0
@@ -420,21 +435,26 @@ class ServingEngine:
         import jax.numpy as jnp
 
         dec = self.decode_model
-        xs = self._stage_inputs(tokens, positions)
-        if self._rng is None:
-            self._rng = jax.random.key(dec.config.seed)
-        self._rng, sub = jax.random.split(self._rng)
-        temp = np.zeros((self.spec.slots,), np.float32)
-        for s in self.scheduler.active_slots:
-            temp[s.index] = s.request.temperature
+        with telemetry.span("serve.stage"):
+            xs = self._stage_inputs(tokens, positions)
+            if self._rng is None:
+                self._rng = jax.random.key(dec.config.seed)
+            self._rng, sub = jax.random.split(self._rng)
+            temp = np.zeros((self.spec.slots,), np.float32)
+            for s in self.scheduler.active_slots:
+                temp[s.index] = s.request.temperature
+            read_idx = jnp.asarray(read_idx, jnp.int32)
+            temp = jnp.asarray(temp)
         t0 = time.perf_counter()
-        dec._state, next_tok = self._step_fn(
-            dec._params, dec._state, xs,
-            jnp.asarray(read_idx, jnp.int32), sub,
-            jnp.asarray(temp))
-        out = np.asarray(jax.device_get(next_tok))
-        # this pair IS the serve_step_device_s measurement (observed
-        # below) — a span here would double-record every decode step
+        with telemetry.span("serve.dispatch"):
+            dec._state, next_tok = self._step_fn(
+                dec._params, dec._state, xs, read_idx, sub, temp)
+        with telemetry.span("serve.fetch"):
+            out = np.asarray(jax.device_get(next_tok))
+        # dispatch plus the blocking fetch on the host's clock (not a
+        # device time): the serve_step_device_s observation and the
+        # speculative decoder's cost feed; the spans above put the same
+        # interval on the profiler's clock
         dt = time.perf_counter() - t0  # fflint: ok raw_timer_in_hot_path
         self._device_s += dt
         self._last_step_device_s = dt  # speculative decode-cost EMA feed
@@ -507,10 +527,12 @@ class ServingEngine:
         and apply the copies to the device pools BEFORE the step runs."""
         if self.block_manager is None:
             return
-        copies = []
-        for idx, positions in slot_positions.items():
-            copies.extend(self.block_manager.ensure_writable(idx, positions))
-        self._apply_copies(copies)
+        with telemetry.span("serve.prepare_writes"):
+            copies = []
+            for idx, positions in slot_positions.items():
+                copies.extend(
+                    self.block_manager.ensure_writable(idx, positions))
+            self._apply_copies(copies)
 
     def _note_completion(self, slot, req: Request):
         hook = self._pre_release_hook
@@ -696,9 +718,26 @@ class ServingEngine:
         done_before = len(sched.completed)
         self._maybe_autoscale()
         with self._active():
+            if sched.drained:
+                self._publish_slot_gauges([], [])
+            else:
+                self._iterations += 1
+                with telemetry.span("serve.iteration",
+                                    iteration=self._iterations):
+                    self._iterate()
+        return sched.completed[done_before:]
+
+    def _iterate(self):
+        """step()'s work, in the phases the profiler's trace shows
+        (docs/observability.md): serve.schedule, serve.prepare_writes,
+        the device call (serve.prefill or serve.step, with serve.stage,
+        serve.dispatch and serve.fetch inside it), serve.bookkeep."""
+        sched = self.scheduler
+        with telemetry.span("serve.schedule"):
             gate = (self._can_admit
                     if self.block_manager is not None else None)
-            for slot, req in sched.admissions(can_admit=gate):
+            admitted = sched.admissions(can_admit=gate)
+            for slot, req in admitted:
                 if self.block_manager is not None:
                     self.block_manager.bind_reservation(
                         req.request_id, slot.index)
@@ -710,7 +749,7 @@ class ServingEngine:
             decoding = [s for s in sched.slots if s.decoding]
             self._publish_slot_gauges(prefilling, decoding)
             if not prefilling and not decoding:
-                return sched.completed[done_before:]
+                return
 
             # ---- choose this iteration's single prefill chunk (FCFS)
             pre = min(prefilling, key=lambda s: s.admit_seq) \
@@ -752,6 +791,8 @@ class ServingEngine:
                                 np.int32)
             read_idx = np.zeros((self.spec.slots,), np.int32)
             writes: dict[int, range] = {}
+            # context rows this step's attention must read
+            kv_rows = sum(s.length + 1 for s in decoding)
             if pre is not None:
                 prompt = pre.request.prompt
                 tokens[pre.index, :n] = prompt[start:start + n]
@@ -759,22 +800,28 @@ class ServingEngine:
                     start, start + n, dtype=np.int32)
                 read_idx[pre.index] = n - 1
                 writes[pre.index] = range(start, start + n)
+                kv_rows += start + n
             for s in decoding:
                 tokens[s.index, 0] = s.last_token
                 positions[s.index, 0] = s.length
                 writes[s.index] = range(s.length, s.length + 1)
-            self._prepare_writes(writes)
+        self._prepare_writes(writes)
 
-            span = telemetry.span(
-                "serve.prefill", slot=pre.index,
-                trace=pre.request.trace_id,
-                start=start, tokens=n,
-                prompt_tokens=len(pre.request.prompt),
-                decoding=len(decoding)) if pre is not None else \
-                telemetry.span("serve.step", active=len(decoding))
-            with span:
-                next_tok = self._run_step(tokens, positions, read_idx)
+        # counts ride on the span that opens after they are known: an
+        # annotation takes its arguments when it is entered
+        load = dict(kv_rows=int(kv_rows), kv_itemsize=self._kv_itemsize,
+                    admitted=len(admitted), pending=sched.queue_depth)
+        span = telemetry.span(
+            "serve.prefill", slot=pre.index,
+            trace=pre.request.trace_id,
+            start=start, tokens=n,
+            prompt_tokens=len(pre.request.prompt),
+            decoding=len(decoding), **load) if pre is not None else \
+            telemetry.span("serve.step", active=len(decoding), **load)
+        with span:
+            next_tok = self._run_step(tokens, positions, read_idx)
 
+        with telemetry.span("serve.bookkeep"):
             # ---- prefill bookkeeping (the chunk's writes landed)
             if pre is not None:
                 self._prefill_tokens += n
@@ -806,7 +853,6 @@ class ServingEngine:
                 if sched.note_token(s, int(next_tok[s.index])):
                     self._note_completion(s, req)
                 self._observe_token(req, prev_t)
-        return sched.completed[done_before:]
 
     def _observe_token(self, req: Request, prev_t):
         """Latency bookkeeping for one sampled token: the request's first

@@ -12,8 +12,8 @@ Design constraints:
 - low overhead ON: one `perf_counter` pair + one dict append per span, no
   I/O until `dump()`;
 - near-zero overhead OFF: callers go through `telemetry.span(...)` which
-  short-circuits to a shared no-op context manager before any Tracer code
-  runs (see __init__.py);
+  with no session returns the profiler's annotation alone, before any
+  Tracer code runs (see __init__.py);
 - thread-safe: the resilience writer thread emits serialize/commit spans
   concurrently with the train loop's step spans; events carry the emitting
   thread's id and the buffer append happens under a lock;

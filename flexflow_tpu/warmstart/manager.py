@@ -236,8 +236,6 @@ def restore_plan(model, graph, cost_model, calibrate_fn):
     if ck is not None:
         overrides, mesh_axes = ck
         telemetry.instant("warmstart.plan_hit", source="checkpoint")
-        telemetry.inc("warmstart_plan_lookups_total", result="hit",
-                      source="checkpoint")
         telemetry.event("warmstart", plan="hit", source="checkpoint",
                         fingerprint=sfp)
         fflog.info("warmstart: plan restored from checkpoint manifest "
@@ -261,9 +259,6 @@ def restore_plan(model, graph, cost_model, calibrate_fn):
         "measured": stats.get("measured", 0),
         "cache_hits": stats.get("cache_hits", 0)})
     if hit is None:
-        telemetry.instant("warmstart.plan_miss")
-        telemetry.inc("warmstart_plan_lookups_total", result="miss",
-                      source="cache")
         telemetry.event(
             "warmstart", plan="miss", fingerprint=warm.full_fp,
             calibration_loaded=warm.calibration_loaded,
@@ -273,8 +268,6 @@ def restore_plan(model, graph, cost_model, calibrate_fn):
         return None
     overrides, mesh_axes = hit
     telemetry.instant("warmstart.plan_hit", source="cache")
-    telemetry.inc("warmstart_plan_lookups_total", result="hit",
-                  source="cache")
     telemetry.event(
         "warmstart", plan="hit", source="cache",
         fingerprint=warm.full_fp,

@@ -151,11 +151,14 @@ def test_logger_levels(capsys, monkeypatch):
 
 
 @pytest.mark.quick
-def test_disabled_telemetry_is_noop():
+def test_disabled_telemetry_is_noop(monkeypatch):
     telemetry.deactivate()
-    s1 = telemetry.span("anything", a=1)
-    s2 = telemetry.span("else")
-    assert s1 is s2  # the shared no-op singleton: no allocation per call
+    # no session: a span is the profiler's annotation alone (inert while
+    # no trace runs) and no Tracer is made or touched
+    monkeypatch.setattr(Tracer, "__init__", None)
+    monkeypatch.setattr(Tracer, "span", None)
+    s1 = telemetry.span("anything", a=1, skipped=[1, 2])
+    assert type(s1).__name__ == "TraceAnnotation"
     with s1:
         pass
     telemetry.instant("x")

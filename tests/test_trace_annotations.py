@@ -1,0 +1,197 @@
+"""telemetry.span on the profiler's clock (docs/observability.md, "XProf
+handoff"): every span is a `jax.profiler.TraceAnnotation` named
+`ff/<name>`, so a profiler trace (`--xprof-dir`, the benchmark's
+`--trace 1`) holds the program's spans on its host plane beside the device
+lines. Under a CPU trace with the Python tracer off (as the benchmark
+sets it): the fit loop's and the engine step's spans are there, children
+inside parents, with the arguments the benchmark's readers use.
+"""
+
+import glob
+import json
+import os
+
+import jax
+import pytest
+
+from flexflow_tpu import telemetry
+from flexflow_tpu.telemetry.tracer import Tracer
+
+from test_serving import _build_lm
+from test_telemetry import _build_mlp, _train_data
+
+ENGINE_PHASES = ("ff/serve.schedule", "ff/serve.prepare_writes",
+                 "ff/serve.stage", "ff/serve.dispatch", "ff/serve.fetch",
+                 "ff/serve.bookkeep")
+
+
+@pytest.fixture(autouse=True)
+def _no_session_leak():
+    yield
+    telemetry.deactivate()
+
+
+def program_spans(trace_dir):
+    """[(name, start_ns, end_ns, stats)] of the host plane's `ff/`
+    events, by start."""
+    (path,) = glob.glob(os.path.join(
+        str(trace_dir), "plugins", "profile", "*", "*.xplane.pb"))
+    profile = jax.profiler.ProfileData.from_file(path)
+    (host,) = [p for p in profile.planes if p.name == "/host:CPU"]
+    return sorted(
+        ((e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+         for line in host.lines for e in line.events
+         if e.name.startswith("ff/")), key=lambda s: (s[1], -s[2]))
+
+
+class traced:
+    """`with traced(dir):` a profiler trace as the benchmark takes one."""
+
+    def __init__(self, trace_dir):
+        self.trace_dir = str(trace_dir)
+
+    def __enter__(self):
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(self.trace_dir, profiler_options=options)
+
+    def __exit__(self, *exc):
+        jax.profiler.stop_trace()
+
+
+def inside(child, parent) -> bool:
+    return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+def named(spans, *names):
+    return [s for s in spans if s[0] in names]
+
+
+def test_fit_spans_reach_the_profilers_host_plane(tmp_path):
+    ff = _build_mlp(tmp_path)
+    x, y = _train_data(n=128)
+    ff.fit(x, y, epochs=1, batch_size=32, verbose=False)   # compiles
+    with traced(tmp_path / "trace"):
+        ff.fit(x, y, epochs=1, batch_size=32, verbose=False)
+    spans = program_spans(tmp_path / "trace")
+    (fit,) = named(spans, "ff/fit")
+    assert fit[3] == {"steps": 4, "batch_size": 32}
+    steps, waits = named(spans, "ff/step"), named(spans, "ff/data_wait")
+    (drain,) = named(spans, "ff/fit.drain")
+    assert [s[3]["step"] for s in steps] == [5, 6, 7, 8]
+    assert len(waits) == 4
+    for step, wait in zip(steps, waits):
+        assert inside(step, fit) and inside(wait, step)
+    for a, b in zip(steps, steps[1:]):
+        assert a[2] <= b[1]
+    assert inside(drain, fit) and drain[1] >= steps[-1][2]
+
+
+def test_engine_phases_reach_the_profilers_host_plane(tmp_path):
+    """Two requests on two slots, prompts of 5 and 2 tokens, 3 new tokens
+    each, chunks of 4: five iterations, whose `kv_rows` (the context rows
+    the step's attention reads) are counted by hand below."""
+    ff = _build_lm(batch=1)
+    eng = ff.serve(slots=2, max_new_tokens=3, prefill_chunk=4,
+                   kv_block_size=4, prefix_sharing=False)
+    eng.generate([[9, 8, 7]])                       # compiles
+    first = eng._iterations
+    with traced(tmp_path / "trace"):
+        eng.generate([[3, 7, 11, 2, 5], [5, 2]])
+    spans = program_spans(tmp_path / "trace")
+    iterations = named(spans, "ff/serve.iteration")
+    assert [s[3]["iteration"] for s in iterations] == [
+        first + i for i in range(1, 6)]
+    calls = named(spans, "ff/serve.prefill", "ff/serve.step")
+    assert [c[0] for c in calls] == ["ff/serve.prefill"] * 3 + [
+        "ff/serve.step"] * 2
+    # chunk 0-4 of the first prompt; its last token; the second prompt
+    # beside the first's 5 + 1 rows; 7 + 3; the second alone, 4
+    assert [c[3]["kv_rows"] for c in calls] == [4, 5, 8, 10, 4]
+    assert [c[3]["admitted"] for c in calls] == [2, 0, 0, 0, 0]
+    assert {c[3]["pending"] for c in calls} == {0}
+    assert {c[3]["kv_itemsize"] for c in calls} == {4}   # an fp32 pool
+    # a request's chunks share its id (the one its instants carry too)
+    traces = [c[3]["trace"] for c in calls[:3]]
+    assert traces[0] == traces[1] != traces[2]
+    assert all(t.startswith("req-") for t in traces)
+    assert [c[3]["tokens"] for c in calls[:3]] == [4, 1, 2]
+    assert calls[3][3]["active"] == 2
+    for it, call in zip(iterations, calls):
+        phases = [s for s in named(spans, *ENGINE_PHASES) if inside(s, it)]
+        assert [p[0] for p in phases] == list(ENGINE_PHASES)
+        for a, b in zip(phases, phases[1:]):        # disjoint, in order
+            assert a[2] <= b[1]
+        assert inside(call, it)
+        stage, dispatch, fetch = phases[2:5]
+        assert all(inside(p, call) for p in (stage, dispatch, fetch))
+        assert not inside(phases[0], call) and not inside(phases[5], call)
+    # the copy-on-write dispatch sits inside its phase
+    prepare = named(spans, "ff/serve.prepare_writes")
+    for copy in named(spans, "ff/serve.cow_copy"):
+        assert any(inside(copy, p) for p in prepare)
+
+
+def test_kv_itemsize_is_what_attention_reads():
+    """The pool stays fp32 under --dtype bf16 and the op casts it to the
+    queries' dtype before it attends: two bytes an element are read."""
+    ff = _build_lm(batch=1, argv=["--dtype", "bf16"])
+    eng = ff.serve(slots=2, max_new_tokens=2, prefill_chunk=4)
+    (pool,) = {ws["pool_k"].dtype.itemsize
+               for ws in eng.decode_model._state.values() if "pool_k" in ws}
+    assert (pool, eng._kv_itemsize) == (4, 2)
+
+
+def test_an_idle_step_opens_no_iteration(tmp_path):
+    ff = _build_lm(batch=1)
+    eng = ff.serve(slots=2, max_new_tokens=2, prefill_chunk=4)
+    before = eng._iterations
+    with traced(tmp_path / "trace"):
+        assert eng.step() == []
+    assert eng._iterations == before
+    assert not named(program_spans(tmp_path / "trace"),
+                     "ff/serve.iteration")
+
+
+def test_a_session_records_what_it_did_and_the_annotation_takes_scalars(
+        tmp_path, monkeypatch):
+    """With a session on, trace.json holds the span with all its
+    arguments, as before; the profiler's annotation gets the int, float,
+    bool and str ones only."""
+    seen = []
+    real = telemetry.TraceAnnotation
+
+    def spy(name, **kwargs):
+        seen.append((name, kwargs))
+        return real(name, **kwargs)
+
+    monkeypatch.setattr(telemetry, "TraceAnnotation", spy)
+    session = telemetry.TelemetrySession(str(tmp_path / "tel"))
+    telemetry.activate(session)
+    with telemetry.span("outer", step=3, rate=0.5, on=True, tag="a",
+                        shape=(2, 3)):
+        with telemetry.span("inner"):
+            pass
+    telemetry.deactivate(session)
+    session.flush()
+    assert seen == [("ff/outer", {"step": 3, "rate": 0.5, "on": True,
+                                  "tag": "a"}), ("ff/inner", {})]
+    with open(tmp_path / "tel" / "trace.json") as f:
+        events = {e["name"]: e for e in json.load(f)["traceEvents"]
+                  if e["ph"] == "X"}
+    assert set(events) == {"outer", "inner"}
+    assert events["outer"]["args"] == {
+        "step": 3, "rate": 0.5, "on": True, "tag": "a", "shape": [2, 3]}
+    assert "args" not in events["inner"]
+    outer, inner = events["outer"], events["inner"]
+    assert outer["ts"] <= inner["ts"]
+    assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1e-3
+
+
+def test_no_profiler_and_no_session_touches_no_tracer(monkeypatch):
+    monkeypatch.setattr(Tracer, "span", None)
+    monkeypatch.setattr(Tracer, "_complete", None)
+    assert telemetry.active_session() is None
+    with telemetry.span("serve.stage", rows=123, decoding=16):
+        with telemetry.span("serve.fetch"):
+            pass
