@@ -53,6 +53,9 @@ _WIDTH = {DataType.DT_HALF: 16, DataType.DT_BFLOAT16: 16,
 F32_INTERNAL = {
     OT.OP_SOFTMAX: "ops/core.py _softmax_forward astype(float32)",
     OT.OP_LAYERNORM: "ops/core.py _ln_forward fp32 statistics",
+    OT.OP_RMSNORM: "ops/core.py rms_norm fp32 statistics",
+    OT.OP_MOE_MLP: "ops/moe.py float32 router softmax, "
+                   "kernels/grouped_matmul.py float32 accumulation",
     OT.OP_BATCHNORM: "ops/core.py _bn_forward fp32 statistics",
     OT.OP_LINEAR: "ops/core.py preferred_element_type=float32",
     OT.OP_BATCHMATMUL: "ops/core.py preferred_element_type=float32",

@@ -219,6 +219,11 @@ class OperatorType(enum.IntEnum):
     OP_PIPELINE = enum.auto()
     OP_FUSED_PARALLEL = enum.auto()
     OP_INVALID = enum.auto()
+    # the post-2020 LM block's vocabulary, appended so the members above
+    # keep their values: RMSNorm, and the token-routed expert layer
+    # (router, dropless top-k dispatch, SiLU-gated experts, combine)
+    OP_RMSNORM = enum.auto()
+    OP_MOE_MLP = enum.auto()
 
 
 PARALLEL_OP_TYPES = frozenset(

@@ -69,6 +69,7 @@ from .ops import (
     GatherParams,
     GroupByParams,
     LayerNormParams,
+    RMSNormParams,
     LinearParams,
     MultiHeadAttentionParams,
     Pool2DParams,
@@ -366,6 +367,12 @@ class FFModel:
         return self._add_layer(OT.OP_LAYERNORM, p, [input], name,
                                data_type=input.dtype).outputs[0]
 
+    def rms_norm(self, input: Tensor, eps: float = 1e-5,
+                 name: str = "") -> Tensor:
+        """RMSNorm over the last dim with a learned scale, no bias."""
+        return self._add_layer(OT.OP_RMSNORM, RMSNormParams(eps), [input],
+                               name, data_type=input.dtype).outputs[0]
+
     def batch_matmul(
         self,
         A: Tensor,
@@ -421,19 +428,31 @@ class FFModel:
         causal: bool = False,
         impl: str = "xla",
         name: str = "",
+        positions: Optional[Tensor] = None,
+        rope_theta: float = 0.0,
+        qk_norm: bool = False,
+        qk_norm_eps: float = 1e-5,
     ) -> Tensor:
+        """`rope_theta` > 0 rotates q and k by the (batch, seq) int
+        `positions`; `qk_norm` RMS-normalises the q and k projections."""
         if impl not in ("xla", "flash", "ring"):
             raise ValueError(
                 f"multihead_attention impl must be xla|flash|ring, got {impl!r}"
             )
+        if bool(rope_theta) != (positions is not None):
+            raise ValueError(
+                "multihead_attention: rope_theta and positions go together")
         p = MultiHeadAttentionParams(embed_dim, num_heads, kdim, vdim, dropout,
                                      bias, add_bias_kv, add_zero_attn, causal,
-                                     impl)
+                                     impl, rope_theta, qk_norm, qk_norm_eps)
         inits = {}
         if kernel_initializer is not None:
             for w in ("wq", "wk", "wv", "wo"):
                 inits[w] = kernel_initializer
-        return self._add_layer(OT.OP_MULTIHEAD_ATTENTION, p, [query, key, value],
+        inputs = [query, key, value]
+        if positions is not None:
+            inputs.append(positions)
+        return self._add_layer(OT.OP_MULTIHEAD_ATTENTION, p, inputs,
                                name, inits, query.dtype).outputs[0]
 
     def inc_multihead_attention(
@@ -602,6 +621,26 @@ class FFModel:
                           use_bias, activation)
         return self._add_layer(OT.OP_EXPERTS, p,
                                [input, gate_values, gate_assign], name,
+                               data_type=input.dtype).outputs[0]
+
+    def moe_mlp(
+        self,
+        input: Tensor,
+        num_experts: int,
+        num_experts_per_tok: int,
+        intermediate_size: int,
+        aux_loss_coef: float = 0.0,
+        name: str = "",
+    ) -> Tensor:
+        """The token-routed expert layer of an LM block on (.., hidden):
+        router, the k largest of a softmax over all experts (not
+        renormalised), SiLU-gated
+        experts, gate-weighted sum; dropless (ops/moe.py)."""
+        from .ops import MoEMLPParams
+
+        p = MoEMLPParams(num_experts, num_experts_per_tok, intermediate_size,
+                         aux_loss_coef)
+        return self._add_layer(OT.OP_MOE_MLP, p, [input], name,
                                data_type=input.dtype).outputs[0]
 
     def moe(

@@ -19,6 +19,7 @@ from .transformer import (
     build_transformer_lm,
     build_transformer_lm_decode,
     build_transformer_lm_pipelined,
+    olmoe_lm_config,
     transformer_lm_param_count,
     transformer_lm_state_bytes_per_chip,
 )

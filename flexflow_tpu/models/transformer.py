@@ -75,27 +75,86 @@ class TransformerLMConfig:
     sequence_length: int = 512
     dtype: DataType = DataType.DT_FLOAT
     attention_impl: str = "flash"  # xla | flash | ring
+    # The block as data. The defaults are the GPT-2 block (pre-LN, learned
+    # positions, biased attention, GELU MLP of mlp_ratio x); OLMoE is
+    # norm="rmsnorm", position="rope", attention_bias=False, qk_norm=True,
+    # mlp="moe" (`olmoe_lm_config`).
+    norm: str = "layernorm"        # layernorm | rmsnorm
+    norm_eps: float = 1e-5
+    position: str = "learned"      # learned (a wpe table) | rope
+    rope_theta: float = 10000.0
+    attention_bias: bool = True
+    qk_norm: bool = False
+    mlp: str = "gelu"              # gelu | moe (SiLU-gated routed experts)
+    num_experts: int = 0
+    num_experts_per_tok: int = 0
+    moe_intermediate_size: int = 0
+    router_aux_loss_coef: float = 0.0
+
+    def __post_init__(self):
+        for field, allowed in (("norm", ("layernorm", "rmsnorm")),
+                               ("position", ("learned", "rope")),
+                               ("mlp", ("gelu", "moe"))):
+            if getattr(self, field) not in allowed:
+                raise ValueError(
+                    f"TransformerLMConfig.{field} must be one of "
+                    f"{allowed}, got {getattr(self, field)!r}")
+
+
+def olmoe_lm_config(**sizes) -> TransformerLMConfig:
+    """The OLMoE block (arXiv:2409.02060, transformers' modeling_olmoe):
+    RMSNorm, RoPE, bias-free attention with QK-norm over the whole
+    projections, routed SiLU-gated experts; `sizes` are the widths."""
+    return TransformerLMConfig(
+        norm="rmsnorm", position="rope", attention_bias=False, qk_norm=True,
+        mlp="moe", **sizes)
+
+
+def _lm_norm(ff, c: TransformerLMConfig, h, name: str):
+    if c.norm == "rmsnorm":
+        return ff.rms_norm(h, c.norm_eps, name=name)
+    return ff.layer_norm(h, [2], eps=c.norm_eps, name=name)
 
 
 def _lm_trunk(ff, c: TransformerLMConfig, h, attention):
-    """The pre-LN block stack + final norm + vocab head, shared between
+    """The pre-norm block stack + final norm + vocab head, shared between
     the training builder and the causal-decode builder — ONE graph
     definition, two attention lowerings (`attention(x, name)` supplies
     either training MHA or incremental KV-cache attention). Layer names
     are identical on both paths, so trained parameters transfer to the
-    decode graph by name (serving/decode_graph.adopt_params)."""
+    decode graph by name (serving/decode_graph.adopt_params). What the
+    block is made of is the config's (norm, mlp; the attention closure
+    reads position, attention_bias and qk_norm)."""
     for i in range(c.num_layers):
         p = f"l{i}_"
-        a = ff.layer_norm(h, [2], name=f"{p}ln1")
+        a = _lm_norm(ff, c, h, f"{p}ln1")
         a = attention(a, f"{p}attn")
         h = ff.add(h, a, name=f"{p}res1")
-        m = ff.layer_norm(h, [2], name=f"{p}ln2")
-        m = ff.dense(m, c.mlp_ratio * c.hidden_size, name=f"{p}ffn1")
-        m = ff.gelu(m, name=f"{p}gelu")
-        m = ff.dense(m, c.hidden_size, name=f"{p}ffn2")
+        m = _lm_norm(ff, c, h, f"{p}ln2")
+        if c.mlp == "moe":
+            # the objective carries the mean over the layers of each
+            # router's load-balancing term, so a layer adds coef / layers
+            m = ff.moe_mlp(m, c.num_experts, c.num_experts_per_tok,
+                           c.moe_intermediate_size,
+                           c.router_aux_loss_coef / c.num_layers,
+                           name=f"{p}moe")
+        else:
+            m = ff.dense(m, c.mlp_ratio * c.hidden_size, name=f"{p}ffn1")
+            m = ff.gelu(m, name=f"{p}gelu")
+            m = ff.dense(m, c.hidden_size, name=f"{p}ffn2")
         h = ff.add(h, m, name=f"{p}res2")
-    h = ff.layer_norm(h, [2], name="ln_f")
+    h = _lm_norm(ff, c, h, "ln_f")
     return ff.dense(h, c.vocab_size, use_bias=False, name="lm_head")
+
+
+def _gpt2_block_only(c: TransformerLMConfig, what: str):
+    if (c.norm, c.position, c.mlp, c.qk_norm) != ("layernorm", "learned",
+                                                  "gelu", False):
+        raise NotImplementedError(
+            f"{what} builds the GPT-2 block only (rotary positions "
+            f"through the KV cache and the expert op in the decode graph "
+            f"are not there yet); got norm={c.norm!r} "
+            f"position={c.position!r} mlp={c.mlp!r} qk_norm={c.qk_norm}")
 
 
 def build_transformer_lm(ff, config: TransformerLMConfig | None = None,
@@ -109,13 +168,18 @@ def build_transformer_lm(ff, config: TransformerLMConfig | None = None,
     h = ff.embedding(tokens, c.vocab_size, c.hidden_size, name="wte")
     pos = ff.create_tensor((bs, c.sequence_length), DataType.DT_INT32,
                            name="positions")
-    hp = ff.embedding(pos, c.sequence_length, c.hidden_size, name="wpe")
-    h = ff.add(h, hp, name="embed_add")
+    rope = c.position == "rope"
+    if not rope:  # rotary positions go to the attention ops instead
+        hp = ff.embedding(pos, c.sequence_length, c.hidden_size, name="wpe")
+        h = ff.add(h, hp, name="embed_add")
 
     def attention(a, name):
         return ff.multihead_attention(
-            a, a, a, c.hidden_size, c.num_heads, causal=True,
-            impl=c.attention_impl, name=name,
+            a, a, a, c.hidden_size, c.num_heads, bias=c.attention_bias,
+            causal=True, impl=c.attention_impl, name=name,
+            positions=pos if rope else None,
+            rope_theta=c.rope_theta if rope else 0.0,
+            qk_norm=c.qk_norm, qk_norm_eps=c.norm_eps,
         )
 
     logits = _lm_trunk(ff, c, h, attention)
@@ -140,6 +204,7 @@ def build_transformer_lm_decode(ff, config: TransformerLMConfig | None = None,
     per-slot region. Returns (tokens, positions, logits); compile with
     CompMode.COMP_MODE_INFERENCE."""
     c = config or TransformerLMConfig()
+    _gpt2_block_only(c, "build_transformer_lm_decode")
     n = slots or ff.config.serve_slots
     max_seq = max_seq_len or c.sequence_length
     layout = kv_layout or ff.config.serve_kv_layout
@@ -186,6 +251,7 @@ def build_transformer_lm_pipelined(ff, config: TransformerLMConfig | None = None
     reference's enum-only OP_PIPELINE never implements. Identical numerics
     to a sequential block stack by construction (same op, pipe axis 1)."""
     c = config or TransformerLMConfig()
+    _gpt2_block_only(c, "build_transformer_lm_pipelined")
     bs = batch_size or ff.config.batch_size
     tokens = ff.create_tensor((bs, c.sequence_length), DataType.DT_INT32,
                               name="tokens")

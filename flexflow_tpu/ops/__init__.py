@@ -18,6 +18,7 @@ from .core import (
     LayerNormParams,
     LinearParams,
     Pool2DParams,
+    RMSNormParams,
     SoftmaxParams,
 )
 from .attention import MultiHeadAttentionParams
@@ -32,6 +33,7 @@ from .moe import (
     CacheParams,
     ExpertsParams,
     GroupByParams,
+    MoEMLPParams,
 )
 from .pipeline_blocks import PipelineBlocksParams
 from .shape_ops import (

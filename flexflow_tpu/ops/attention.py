@@ -44,6 +44,13 @@ class MultiHeadAttentionParams:
     add_zero_attn: bool = False
     causal: bool = False  # TPU-native addition (reference cuDNN op is unmasked)
     impl: str = "xla"  # xla | flash | ring
+    # rotary positions on q and k (half-rotation form) from a fourth input
+    # of positions (batch, seq) int; 0 = none
+    rope_theta: float = 0.0
+    # RMSNorm over the whole q and k projections (all heads together, as
+    # OLMoE does), with learned scales `q_norm` / `k_norm`
+    qk_norm: bool = False
+    qk_norm_eps: float = 1e-5
 
 
 def _mha_dims(p: MultiHeadAttentionParams):
@@ -53,12 +60,12 @@ def _mha_dims(p: MultiHeadAttentionParams):
 
 
 def _mha_infer(p: MultiHeadAttentionParams, in_shapes):
-    q, k, v = in_shapes
+    q, k, v = in_shapes[:3]
     return [(q[0], q[1], p.embed_dim)]
 
 
 def _mha_weights(p: MultiHeadAttentionParams, in_shapes):
-    q, k, v = in_shapes
+    q, k, v = in_shapes[:3]
     kdim, vdim = _mha_dims(p)
     # per-head projection sizes follow attention.cc:70-80 (qProjSize = kdim/heads)
     ws = [
@@ -74,7 +81,35 @@ def _mha_weights(p: MultiHeadAttentionParams, in_shapes):
             WeightSpec("bv", (p.embed_dim,), DataType.DT_FLOAT, "zeros"),
             WeightSpec("bo", (p.embed_dim,), DataType.DT_FLOAT, "zeros"),
         ]
+    if p.qk_norm:
+        ws += [
+            WeightSpec("q_norm", (p.embed_dim,), DataType.DT_FLOAT, "ones"),
+            WeightSpec("k_norm", (p.embed_dim,), DataType.DT_FLOAT, "ones"),
+        ]
     return ws
+
+
+def rope_cos_sin(positions, head_dim: int, theta: float):
+    """cos and sin (batch, seq, head_dim) float32 of the half-rotation
+    form: frequencies theta^(-2i/head_dim), i < head_dim/2, each used for
+    lanes i and i + head_dim/2."""
+    inv_freq = theta ** (-jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                         / head_dim)
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq
+    angles = jnp.concatenate([angles, angles], axis=-1)
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def apply_rope(x, cos, sin, num_heads: int):
+    """x * cos + rotate_half(x) * sin on the packed layout (batch, seq,
+    heads * head_dim): per head, rotate_half(x) = concat(-x2, x1) of its
+    two halves. float32 arithmetic, one cast back."""
+    b, s, e = x.shape
+    xf = x.astype(jnp.float32).reshape(b, s, num_heads, e // num_heads)
+    x1, x2 = jnp.split(xf, 2, axis=-1)
+    rot = jnp.concatenate([-x2, x1], axis=-1)
+    y = xf * cos[:, :, None, :] + rot * sin[:, :, None, :]
+    return y.reshape(b, s, e).astype(x.dtype)
 
 
 def sdpa_xla(q, k, v, *, causal: bool, scale: float):
@@ -91,7 +126,7 @@ def sdpa_xla(q, k, v, *, causal: bool, scale: float):
 
 
 def _mha_forward(p: MultiHeadAttentionParams, inputs, weights, state, ctx):
-    q_in, k_in, v_in = inputs
+    q_in, k_in, v_in = inputs[:3]
     H = p.num_heads
     E = p.embed_dim
     hd = E // H
@@ -107,6 +142,16 @@ def _mha_forward(p: MultiHeadAttentionParams, inputs, weights, state, ctx):
     k = proj(k_in, weights["wk"], weights.get("bk"))
     v = proj(v_in, weights["wv"], weights.get("bv"))
     scale = 1.0 / math.sqrt(hd)
+    # between the projections and the kernel, on the packed layout
+    if p.qk_norm:
+        from .core import rms_norm
+
+        q = rms_norm(q, weights["q_norm"], p.qk_norm_eps)
+        k = rms_norm(k, weights["k_norm"], p.qk_norm_eps)
+    if p.rope_theta:
+        cos, sin = rope_cos_sin(inputs[3], hd, p.rope_theta)
+        q = apply_rope(q, cos, sin, H)
+        k = apply_rope(k, cos, sin, H)
 
     if p.impl == "flash":
         # the plan's shards of the kernel's operands: batch as the output
@@ -170,7 +215,7 @@ def _mha_forward(p: MultiHeadAttentionParams, inputs, weights, state, ctx):
 
 
 def _mha_flops(p: MultiHeadAttentionParams, in_shapes, out_shapes):
-    q, k, v = in_shapes
+    q, k, v = in_shapes[:3]
     b, sq, dq = q
     sk = k[1]
     E = p.embed_dim

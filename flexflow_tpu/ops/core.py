@@ -318,6 +318,33 @@ def _ln_forward(p: LayerNormParams, inputs, weights, state, ctx):
 register_op(OpDef(OT.OP_LAYERNORM, _ln_infer, _ln_forward, _ln_weights))
 
 
+# ---------------------------------------------------------------- RMSNorm
+
+@dataclass(frozen=True)
+class RMSNormParams:
+    eps: float = 1e-5
+
+
+def rms_norm(x, scale, eps: float):
+    """x * rsqrt(mean(x^2) + eps) * scale over the last dim: fp32
+    statistics and affine, one cast back to the activation dtype."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def _rms_weights(p: RMSNormParams, in_shapes):
+    return [WeightSpec("scale", (in_shapes[0][-1],), DataType.DT_FLOAT,
+                       "ones")]
+
+
+def _rms_forward(p: RMSNormParams, inputs, weights, state, ctx):
+    return [rms_norm(inputs[0], weights["scale"], p.eps)], state
+
+
+register_op(OpDef(OT.OP_RMSNORM, _ln_infer, _rms_forward, _rms_weights))
+
+
 # ---------------------------------------------------------------- Softmax
 
 @dataclass(frozen=True)

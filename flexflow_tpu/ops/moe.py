@@ -1,4 +1,6 @@
-"""Mixture-of-Experts operators: Group_by, Aggregate, AggregateSpec, Cache.
+"""Mixture-of-Experts operators: Group_by, Aggregate, AggregateSpec, Cache,
+the fused Experts op of the MNIST classifier, and, at the end, MoEMLP: the
+dropless token-routed expert layer of an LM block (OLMoE).
 
 Reference: src/ops/group_by.cc/.cu (token→expert scatter with capacity factor
 alpha), src/ops/aggregate.cc/.cu (gate-weighted combine + load-balance term in
@@ -333,3 +335,174 @@ register_op(
         _experts_flops,
     )
 )
+
+
+# ---------------------------------------------------------------- MoE MLP
+# The token-routed expert layer of an LM block (OLMoE, and the models of
+# ROADMAP Queue 2 after it): router, softmax over all experts, the k
+# largest, SiLU-gated experts, gate-weighted sum. Dropless: the tokens x k
+# assignments are sorted by expert and the experts run as grouped matmuls
+# over the sorted rows with group sizes from the counts. There is no
+# buffer of a fixed size per expert, so every assignment is computed
+# however skewed the router is.
+
+@dataclass(frozen=True)
+class MoEMLPParams:
+    num_experts: int
+    num_experts_per_tok: int
+    intermediate_size: int
+    # multiplies the load-balancing term this layer adds to the objective
+    # (transformers' load_balancing_loss_func for one layer)
+    aux_loss_coef: float = 0.0
+
+
+def _moe_mlp_infer(p: MoEMLPParams, in_shapes):
+    return [in_shapes[0]]
+
+
+def _moe_mlp_weights(p: MoEMLPParams, in_shapes):
+    d, n, f = in_shapes[0][-1], p.num_experts, p.intermediate_size
+    tokens = math.prod(in_shapes[0][:-1])
+    # "normal" is N(0, 0.02), transformers' initializer_range: glorot over
+    # a stacked (n, d, f) weight would count the experts into the fans
+    return [
+        WeightSpec("router", (d, n), DataType.DT_FLOAT, "normal"),
+        WeightSpec("gate", (n, d, f), DataType.DT_FLOAT, "normal"),
+        WeightSpec("up", (n, d, f), DataType.DT_FLOAT, "normal"),
+        WeightSpec("down", (n, f, d), DataType.DT_FLOAT, "normal"),
+        # the step's counters, beside the loss (read them from the model's
+        # state after a step): assignments no expert computed, and the
+        # largest expert's load over the mean load
+        WeightSpec("dropped_tokens", (), DataType.DT_FLOAT, "zeros",
+                   trainable=False),
+        WeightSpec("load_max_over_mean", (), DataType.DT_FLOAT, "zeros",
+                   trainable=False),
+        # the experts the last forward chose for each token: routing is
+        # discontinuous, so whoever compares this layer with another
+        # implementation needs the choice itself
+        WeightSpec("expert_ids", (tokens, p.num_experts_per_tok),
+                   DataType.DT_INT32, "zeros", trainable=False),
+    ]
+
+
+def moe_route(x, router, k: int):
+    """(gate weights (t, k) float32, expert ids (t, k) int32, router
+    probabilities (t, n) float32) of tokens x (t, d): softmax over all the
+    experts in float32, then the k largest, not renormalised (OLMoE's
+    norm_topk_prob false)."""
+    logits = jnp.dot(x, router.astype(x.dtype),
+                     preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, ids = jax.lax.top_k(probs, k)
+    return weights, ids.astype(jnp.int32), probs
+
+
+def load_balancing_loss(probs, group_sizes):
+    """transformers' load_balancing_loss_func for one layer: num_experts x
+    the sum over experts of (share of the tokens that chose the expert,
+    summed over the k choices) x (mean router probability of the
+    expert). `group_sizes` (n,) are the assignments each expert got."""
+    t, n = probs.shape
+    return n * jnp.sum((group_sizes.astype(jnp.float32) / t)
+                       * jnp.mean(probs, axis=0))
+
+
+def moe_sort(ids, num_experts: int):
+    """The dispatch's bookkeeping for expert ids (t, k): `order` (t*k,),
+    the flat assignments sorted by expert (stable, so token order holds
+    inside an expert); `position` (t, k), each assignment's row in the
+    sorted order; `group_sizes` (n,), the assignments of each expert."""
+    flat = ids.reshape(-1)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    position = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=jnp.int32)).reshape(ids.shape)
+    group_sizes = jnp.zeros((num_experts,), jnp.int32).at[flat].add(1)
+    return order, position, group_sizes
+
+
+@jax.custom_vjp
+def _gather_sorted(x, order, position):
+    """Rows of tokens x (t, d) in sorted-assignment order (t*k, d). The
+    backward is a gather too (each token's k rows by `position`, summed),
+    not a scatter-add over repeated rows."""
+    return x[order // position.shape[1]]
+
+
+def _gather_sorted_fwd(x, order, position):
+    return _gather_sorted(x, order, position), (order, position)
+
+
+def _gather_sorted_bwd(res, g):
+    order, position = res
+    return (jnp.sum(g[position].astype(jnp.float32), axis=1).astype(g.dtype),
+            None, None)
+
+
+_gather_sorted.defvjp(_gather_sorted_fwd, _gather_sorted_bwd)
+
+
+@jax.custom_vjp
+def _gather_back(y, order, position):
+    """Each token's k expert outputs (t, k, d) from the sorted rows y
+    (t*k, d); the backward gathers the cotangent into sorted order."""
+    return y[position]
+
+
+def _gather_back_fwd(y, order, position):
+    return y[position], (order, position)
+
+
+def _gather_back_bwd(res, g):
+    order, position = res
+    return g.reshape(-1, g.shape[-1])[order], None, None
+
+
+_gather_back.defvjp(_gather_back_fwd, _gather_back_bwd)
+
+
+def _moe_mlp_forward(p: MoEMLPParams, inputs, weights, state, ctx):
+    from ..kernels.grouped_matmul import grouped_matmul
+
+    (x,) = inputs
+    shape = x.shape
+    x = x.reshape(-1, shape[-1])
+    n, k = p.num_experts, p.num_experts_per_tok
+    with jax.named_scope("moe.route"):
+        gates, ids, probs = moe_route(x, weights["router"], k)
+    with jax.named_scope("moe.dispatch"):
+        order, position, group_sizes = moe_sort(ids, n)
+        rows = _gather_sorted(x, order, position)
+    with jax.named_scope("moe.experts"):
+        gate = grouped_matmul(rows, weights["gate"].astype(x.dtype),
+                              group_sizes, ctx.mesh)
+        up = grouped_matmul(rows, weights["up"].astype(x.dtype),
+                            group_sizes, ctx.mesh)
+        hidden = (jax.nn.silu(gate.astype(jnp.float32))
+                  * up.astype(jnp.float32)).astype(x.dtype)
+        out = grouped_matmul(hidden, weights["down"].astype(x.dtype),
+                             group_sizes, ctx.mesh)
+    with jax.named_scope("moe.combine"):
+        picked = _gather_back(out, order, position)
+        y = jnp.sum(gates[..., None] * picked.astype(jnp.float32), axis=1)
+    state = dict(state or {})
+    computed = jnp.sum(group_sizes)
+    state["dropped_tokens"] = (ids.size - computed).astype(jnp.float32)
+    state["load_max_over_mean"] = (
+        jnp.max(group_sizes) * (n / computed.astype(jnp.float32)))
+    state["expert_ids"] = ids
+    if p.aux_loss_coef:
+        state["aux_loss"] = p.aux_loss_coef * load_balancing_loss(
+            probs, group_sizes)
+    return [y.astype(x.dtype).reshape(shape)], state
+
+
+def _moe_mlp_flops(p: MoEMLPParams, in_shapes, out_shapes):
+    d = in_shapes[0][-1]
+    tokens = math.prod(in_shapes[0][:-1])
+    return 2.0 * tokens * d * (p.num_experts
+                               + 3 * p.num_experts_per_tok
+                               * p.intermediate_size)
+
+
+register_op(OpDef(OT.OP_MOE_MLP, _moe_mlp_infer, _moe_mlp_forward,
+                  _moe_mlp_weights, _moe_mlp_flops))

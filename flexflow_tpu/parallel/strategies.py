@@ -305,9 +305,11 @@ def expert_parallel_moe(model, expert_axis: str = AXIS_MODEL) -> Strategy:
     s = Strategy()
     layers = getattr(model, "layers", model)
     for l in layers:
-        if l.op_type == OT.OP_EXPERTS:
+        if l.op_type in (OT.OP_EXPERTS, OT.OP_MOE_MLP):
             for ws in _weight_specs(l):
                 nd = len(ws.shape)
+                if nd < 2 or ws.name == "router":
+                    continue  # the router and the counters stay replicated
                 s.set_weight(
                     l.name, ws.name,
                     PartitionSpec(expert_axis, *([None] * (nd - 1))),

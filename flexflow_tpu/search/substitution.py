@@ -291,6 +291,7 @@ _PASSTHROUGH = frozenset({
     OT.OP_IDENTITY, OT.OP_DROPOUT, OT.OP_SCALAR_MULTIPLY, OT.OP_SCALAR_ADD,
     OT.OP_SCALAR_SUB, OT.OP_SCALAR_TRUE_DIV, OT.OP_EXP, OT.OP_SIN, OT.OP_COS,
     OT.OP_RSQRT, OT.OP_POW, OT.OP_LAYERNORM, OT.OP_SOFTMAX, OT.OP_CAST,
+    OT.OP_RMSNORM,
 })
 
 # single source of truth for the parallel-op type set (also used by

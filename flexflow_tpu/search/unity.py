@@ -848,7 +848,7 @@ _FEATURE_ELEMENTWISE = frozenset({
     OT.OP_RELU, OT.OP_GELU, OT.OP_SIGMOID, OT.OP_TANH, OT.OP_ELU,
     OT.OP_IDENTITY, OT.OP_DROPOUT, OT.OP_SCALAR_MULTIPLY, OT.OP_SCALAR_ADD,
     OT.OP_SCALAR_SUB, OT.OP_SCALAR_TRUE_DIV, OT.OP_LAYERNORM, OT.OP_SOFTMAX,
-    OT.OP_EW_ADD, OT.OP_EW_MUL,
+    OT.OP_EW_ADD, OT.OP_EW_MUL, OT.OP_RMSNORM,
 })
 
 

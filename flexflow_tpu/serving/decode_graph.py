@@ -236,6 +236,11 @@ def build_decode_model(model, spec: ServingSpec):
                 raise ValueError(
                     f"{layer.name}: kdim/vdim != embed_dim not supported "
                     f"in the decode graph")
+            if p.rope_theta or p.qk_norm:
+                raise NotImplementedError(
+                    f"{layer.name}: rotary positions and QK-norm are not "
+                    f"in the incremental attention ops yet, so this model "
+                    f"trains but does not serve")
             if paged:
                 from ..ops import PagedIncMultiHeadAttentionParams
 

@@ -101,6 +101,28 @@ def test_fused_layer_norm_fwd_bwd(tpu):
     assert kernels == {"layer_norm_fwd": 1, "layer_norm_bwd": 1}
 
 
+def test_grouped_matmul_fwd_bwd_at_olmoe_widths(tpu):
+    """The expert layer's up and down projections at OLMoE's widths, 64
+    experts over 131,072 sorted rows: the Pallas grouped matmul forward,
+    for dX and transposed for dW; and float32 operands take the reference
+    and say so."""
+    from flexflow_tpu.kernels import grouped_matmul as gm
+
+    s = _on(tpu[0])
+    sizes = s((64,), jnp.int32)
+    for k, n in ((2048, 1024), (1024, 2048)):
+        kernels = _kernels(
+            lambda x, w, g: jax.value_and_grad(
+                lambda x, w: gm.grouped_matmul(x, w, g).astype(
+                    jnp.float32).sum(), argnums=(0, 1))(x, w),
+            s((131072, k)), s((64, k, n)), sizes)
+        assert kernels == {"gmm": 2, "tgmm": 1}
+    with pytest.warns(KernelFallbackWarning, match="not bfloat16"):
+        kernels = _kernels(gm.grouped_matmul, s((1024, 256), jnp.float32),
+                           s((8, 256, 128), jnp.float32), s((8,), jnp.int32))
+    assert not kernels
+
+
 def test_contiguous_decode_head_dim_128(tpu):
     """The contiguous decode kernel at the engine's real cache shape
     (slots, max_seq + 1, E): max_seq + 1 is odd, so the last kv block is
