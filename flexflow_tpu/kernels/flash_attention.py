@@ -1358,9 +1358,11 @@ def decode_attention_reference(q, k, v, positions, *, num_heads: int,
     multi-query path at q_len=K+1 — each proposal row's logits equal
     what plain decode would compute after the rows before it, which is
     the whole bit-identity argument; the Pallas kernels below stay
-    q_len=1, so multi-query calls (prefill chunks and verify alike)
-    take this einsum on every backend — a multi-query Pallas decode
-    kernel is the ROADMAP item that would close the gap."""
+    q_len=1, so multi-query calls (verify, and prefill chunks laid out
+    as a rectangle) take this einsum on every backend; where the paged
+    kernel serves, the engine hands it a chunk as single-query rows
+    instead (serving/engine.py) — a multi-query Pallas decode kernel is
+    the ROADMAP item that would read a chunk's context once."""
     slots, q_len, e = q.shape
     s_k = k.shape[1]
     h = num_heads
@@ -1630,41 +1632,60 @@ def _paged_decode_call(table, lengths, q, pool_k, pool_v, *, num_heads: int,
     )(table, lengths, q, pool_k, pool_v)
 
 
+def _paged_round_pages(block_size: int) -> int:
+    return max(1, _PAGED_ROUND_ROWS // block_size)
+
+
+def paged_decode_gate(cache_rows: int, block_size: int, embed: int,
+                      num_heads: int, itemsize: int,
+                      interpret: bool) -> str | None:
+    """Why the paged decode kernel cannot take a pool of this geometry, or
+    None where it can: the contiguous kernel's gate plus the paged ones. A
+    block must be a legal (sublane, lane) tile, so tiny block sizes route
+    to the reference, and so does a row too wide for two rounds to sit in
+    VMEM. Nothing here depends on how many rows a call has: the serving
+    engine lays a chunk step out by the same answer
+    (ops/inc_attention.paged_rows_run_kernel)."""
+    gate = _decode_gate(cache_rows, embed // num_heads, num_heads, interpret)
+    if gate is None and block_size % 8 != 0:
+        gate = f"block_size {block_size} % 8 != 0"
+    if gate is None:
+        rows = _paged_round_pages(block_size) * block_size
+        round_bytes = 4 * rows * embed * itemsize
+        if round_bytes > _PAGED_ROUND_VMEM:
+            gate = (f"two rounds of {rows} rows x {embed} lanes take "
+                    f"{round_bytes} bytes of VMEM > {_PAGED_ROUND_VMEM}")
+    return gate
+
+
 def paged_flash_decode_attention(
     q, pool_k, pool_v, page_table, lengths, *, num_heads: int,
     scale: float | None = None,
 ):
-    """Single-query decode attention over a paged KV pool. q: (slots, 1,
-    H·hd); pool_k/v: (num_blocks, block_size, H·hd); page_table: (slots,
-    W) int32 logical→physical block map; lengths: (slots,) int32 live-key
-    counts. One grid step a slot: the body walks the slot's live pages
-    through the scalar-prefetched table, whole pool rows DMA'd from HBM a
-    round of ~128 cache rows at a time, all heads in one pass (see the
-    section comment). Shapes the kernel can't tile on hardware take the
-    gather + einsum reference, with a KernelFallbackWarning on a TPU (the
-    CPU serving path routes there directly)."""
+    """Single-query decode attention over a paged KV pool. q: (rows, 1,
+    H·hd); pool_k/v: (num_blocks, block_size, H·hd); page_table: (rows,
+    W) int32 logical→physical block map; lengths: (rows,) int32 live-key
+    counts. A row is a slot's one query, or one token of a prefill chunk
+    carrying its slot's page-table row (serving/engine.py): rows may
+    share a table row and outnumber the slots. One grid step a row: the
+    body walks the row's live pages through the scalar-prefetched table,
+    whole pool rows DMA'd from HBM a round of ~128 cache rows at a time,
+    all heads in one pass (see the section comment). Shapes the kernel
+    can't tile on hardware take the gather + einsum reference, with a
+    KernelFallbackWarning on a TPU (the CPU serving path routes there
+    directly)."""
     _, q_len, e = q.shape
     if q_len != 1:
         raise ValueError(f"decode kernel is single-query (got q_len={q_len})")
     bs = pool_k.shape[1]
     W = page_table.shape[1]
-    d = e // num_heads
     if e % num_heads != 0:
         raise ValueError(f"embed dim {e} % heads {num_heads} != 0")
     if scale is None:
-        scale = 1.0 / math.sqrt(d)
+        scale = 1.0 / math.sqrt(e // num_heads)
     interpret = jax.default_backend() != "tpu"
-    pages = max(1, _PAGED_ROUND_ROWS // bs)
-    round_bytes = 4 * pages * bs * e * pool_k.dtype.itemsize
-    # the contiguous kernel's gate + the paged-specific ones: a block must
-    # be a legal (sublane, lane) tile, so tiny block sizes route to the
-    # reference, and so does a row too wide for two rounds to sit in VMEM
-    gate = _decode_gate(W * bs, d, num_heads, interpret)
-    if gate is None and bs % 8 != 0:
-        gate = f"block_size {bs} % 8 != 0"
-    if gate is None and round_bytes > _PAGED_ROUND_VMEM:
-        gate = (f"two rounds of {pages * bs} rows x {e} lanes take "
-                f"{round_bytes} bytes of VMEM > {_PAGED_ROUND_VMEM}")
+    gate = paged_decode_gate(W * bs, bs, e, num_heads,
+                             pool_k.dtype.itemsize, interpret)
     if gate is not None:
         warn_reference("paged_flash_decode_attention",
                        (q.shape, pool_k.shape), gate)
@@ -1674,8 +1695,8 @@ def paged_flash_decode_attention(
             num_heads=num_heads, scale=scale)
     return _paged_decode_call(
         page_table.astype(jnp.int32), lengths.astype(jnp.int32), q, pool_k,
-        pool_v, num_heads=num_heads, scale=scale, pages=pages,
-        interpret=interpret)
+        pool_v, num_heads=num_heads, scale=scale,
+        pages=_paged_round_pages(bs), interpret=interpret)
 
 
 def flash_attention(

@@ -37,7 +37,10 @@ Weight names match OP_MULTIHEAD_ATTENTION's (wq/wk/wv/wo + biases), so a
 trained model's parameters transfer to its decode graph by name. On TPU
 the q_len=1 path routes through the Pallas decode kernel
 (kernels/flash_attention.flash_decode_attention); CPU meshes use the
-reference einsum so tier-1 exercises serving end-to-end.
+reference einsum so tier-1 exercises serving end-to-end. Nothing here
+asks what the leading dimension means: the paged op's rows may be slots,
+or a prefill chunk's tokens one a row beside them, each row with its own
+page-table row (serving/engine.py, paged_rows_run_kernel below).
 """
 
 from __future__ import annotations
@@ -61,21 +64,33 @@ class IncMultiHeadAttentionParams:
     impl: str = "auto"  # auto: flash decode on TPU (q_len=1), einsum else
 
 
+def _kernel_asked(impl: str) -> bool:
+    """impl "flash" always asks for the Pallas decode kernel, "auto" asks
+    on a TPU."""
+    return impl == "flash" or (impl == "auto"
+                               and jax.default_backend() == "tpu")
+
+
+def _call_gate(q_len: int, mesh) -> str | None:
+    """Why a call that asks for the decode kernel cannot have it, or None:
+    multi-query calls (rectangular prefill chunks, speculative verify —
+    the kernels are single-query) and multi-device meshes (GSPMD cannot
+    partition a Mosaic kernel, and the decode kernels are not run per
+    shard yet)."""
+    if q_len != 1:
+        return f"q_len {q_len} > 1 has no kernel"
+    if mesh is not None and mesh.size > 1:
+        return f"{mesh.size}-device mesh: kernel not run per shard"
+    return None
+
+
 def _use_decode_kernel(op: str, impl: str, q_shape, ctx) -> bool:
-    """Whether this call runs the Pallas decode kernel. impl "flash"
-    always asks for it, "auto" asks on a TPU; of those asks, the two the
-    kernels cannot serve yet take the reference einsum, audibly on a TPU:
-    multi-query calls (prefill chunks, speculative verify — the kernels
-    are single-query) and multi-device meshes (GSPMD cannot partition a
-    Mosaic kernel, and the decode kernels are not run per shard yet)."""
-    if not (impl == "flash"
-            or (impl == "auto" and jax.default_backend() == "tpu")):
+    """Whether this call runs the Pallas decode kernel: it is asked for
+    (_kernel_asked) and the call can have it (_call_gate); an ask that
+    takes the reference einsum instead says so on a TPU."""
+    if not _kernel_asked(impl):
         return False
-    gate = None
-    if q_shape[1] != 1:
-        gate = f"q_len {q_shape[1]} > 1 has no kernel"
-    elif ctx.mesh is not None and ctx.mesh.size > 1:
-        gate = f"{ctx.mesh.size}-device mesh: kernel not run per shard"
+    gate = _call_gate(q_shape[1], ctx.mesh)
     if gate is not None:
         from ..kernels.dispatch import warn_reference
 
@@ -220,6 +235,26 @@ class PagedIncMultiHeadAttentionParams:
     def blocks_per_slot(self) -> int:
         """Page-table width: logical blocks covering max_seq_len rows."""
         return -(-self.max_seq_len // self.block_size)
+
+
+def paged_rows_run_kernel(p: PagedIncMultiHeadAttentionParams, mesh,
+                          itemsize: int) -> bool:
+    """Whether a (rows, 1) call of this op is served by the paged Pallas
+    kernel, however many rows it has: the op's own gates (_kernel_asked,
+    _call_gate) and the kernel wrapper's (paged_decode_gate), asked once
+    and without a warning. `itemsize`: bytes of a pool element as the
+    kernel reads it (the op casts the pool to the queries' dtype). The
+    serving engine chooses a chunk step's batch layout by this
+    (serving/engine.py): single-query rows pay only where the kernel
+    walks each row's pages; through the gather-and-einsum reference every
+    row would gather a whole logical cache."""
+    from ..kernels.flash_attention import paged_decode_gate
+
+    return (_kernel_asked(p.impl) and _call_gate(1, mesh) is None
+            and paged_decode_gate(
+                p.blocks_per_slot * p.block_size, p.block_size, p.embed_dim,
+                p.num_heads, itemsize,
+                jax.default_backend() != "tpu") is None)
 
 
 def _paged_mha_infer(p: PagedIncMultiHeadAttentionParams, in_shapes):

@@ -9,11 +9,24 @@ temperature-Gumbel per slot).
 **Chunked prefill, interleaved with decode** (Orca's iteration-level
 scheduling at sub-request grain): every `step()` runs exactly ONE device
 call, carrying at most one prefill CHUNK (engine/chunking.plan_chunks
-buckets, power-of-two widths) in the admitted slot's rows while every
-DECODING slot advances one token in column 0 of the same call — long
-prompts therefore never stall the continuous batch, and a decoding slot's
-token stream is bit-identical either way because slot rows are computed
-independently (padding columns point at the scratch row/block).
+buckets, power-of-two widths) while every DECODING slot advances one
+token in the same call — long prompts therefore never stall the
+continuous batch, and a decoding slot's token stream is bit-identical
+either way because rows are computed independently (dead elements point
+at the scratch row/block).
+
+**A chunk step's two batch layouts** (docs/serving.md). The RECTANGLE,
+`(slots, q)` with q the chunk's bucket: the chunk in the admitted slot's
+row, every decoding slot's token in column 0 of its own, the rest dead.
+ROWS, `(slots + q, 1)`: the slots exactly as a pure-decode step has them,
+then each token of the chunk as a single-query row of its own, at its own
+position, carrying a copy of its slot's page-table row — the op writes
+every row's K and V before any row reads, so row i of a chunk at `start`
+attends `start + i + 1` keys through the paged decode kernel's length
+mask. The engine takes rows where a `(rows, 1)` call is served by that
+kernel (ops/inc_attention.paged_rows_run_kernel, asked when the engine is
+built) and the rectangle everywhere else: through the gather-and-einsum
+reference a row costs a whole logical cache.
 
 **KV layouts** (`--serve-kv-layout`, ServingSpec.kv_layout):
   - "paged" (default): per-layer block POOLS (num_blocks, block_size,
@@ -147,6 +160,8 @@ class ServingEngine:
                 cross_time=bool(spec.prefix_cache))
             self._copy_fn = (
                 self.decode_model.executor.build_block_copy())
+        self._kv_itemsize = _kv_read_itemsize(self.decode_model)
+        self._chunk_rows = self._rows_serve_chunks()
         # graph input roles: exactly one token stream + the positions /
         # page-table feeds (+ constants, which the engine materializes)
         self._token_input = None
@@ -181,12 +196,12 @@ class ServingEngine:
         self._pre_release_hook = None
         self._suppress_completion_events = False
         self._iterations = 0  # step() calls that found work, ever
-        self._kv_itemsize = _kv_read_itemsize(self.decode_model)
         # run accounting (stats())
         self._decode_iterations = 0
         self._decode_tokens = 0
         self._prefill_tokens = 0
         self._prefill_calls = 0
+        self._row_steps = 0  # of those, the ones laid out as rows
         self._device_s = 0.0
         self._last_step_device_s = 0.0  # most recent device call's wall
         # ffpulse metrics plane: engine-owned registry so serving metrics
@@ -245,6 +260,22 @@ class ServingEngine:
         self.replan_decisions: list[dict] = []
         if getattr(cfg, "elastic", False):
             self.enable_autoscale()
+
+    def _rows_serve_chunks(self) -> bool:
+        """Whether a step that carries a prefill chunk is laid out as
+        single-query rows (module docstring): asked of the built decode
+        graph's paged attention op, with the mesh its calls run on."""
+        if self.block_manager is None:
+            return False
+        from ..fftype import OperatorType as OT
+        from ..ops.inc_attention import paged_rows_run_kernel
+
+        dec = self.decode_model
+        return all(
+            paged_rows_run_kernel(n.params, dec.executor.mesh,
+                                  self._kv_itemsize)
+            for n in dec.graph.topo_order()
+            if n.op_type == OT.OP_PAGED_INC_MULTIHEAD_ATTENTION)
 
     def enable_autoscale(self, visible_devices_fn=None,
                          check_every: int = 16):
@@ -341,6 +372,7 @@ class ServingEngine:
             if self.block_manager is not None:
                 self._copy_fn = new_dec.executor.build_block_copy()
             self._inject_fn = None  # rebuilt lazily on the new executor
+            self._chunk_rows = self._rows_serve_chunks()
             self.num_chips = int(new_dec.mesh.devices.size)
             trans = new_dec._transition or {}
             decision.update({
@@ -400,25 +432,29 @@ class ServingEngine:
             b *= 2
         return min(b, self.spec.prefill_chunk)
 
-    def _stage_inputs(self, tokens: np.ndarray,
-                      positions: np.ndarray) -> dict:
+    def _stage_inputs(self, tokens: np.ndarray, positions: np.ndarray,
+                      row_slots=None) -> dict:
         """Stage one decode-graph call's input dict under the searched
         shardings: the token stream, positions, the page tables (paged
         layout), and the graph's constant feeds broadcast to the call's
-        q width. Shared between the decode step and the speculative
-        verify step (serving/speculative.py) so the two calls stage
-        byte-identical feeds."""
-        q = tokens.shape[1]
+        shape. Row i of the call reads and writes through slot
+        `row_slots[i]`'s page table; by default row i is slot i (the
+        rectangle, and a pure-decode step). Shared between the decode
+        step and the speculative verify step (serving/speculative.py) so
+        the two calls stage byte-identical feeds."""
+        rows, q = tokens.shape
         dec = self.decode_model
         xs = {self._token_input: tokens, "positions": positions}
         if self.block_manager is not None:
             mgr = self.block_manager
-            xs["page_table"] = np.asarray(
+            table = np.asarray(
                 [mgr.table(i) for i in range(self.spec.slots)], np.int32)
+            xs["page_table"] = (table if row_slots is None
+                                else table[row_slots])
         for name, (dims, dtype, value) in self._const_inputs.items():
             from ..fftype import dtype_to_jnp
 
-            xs[name] = np.full((dims[0], q) + tuple(dims[2:]), value,
+            xs[name] = np.full((rows, q) + tuple(dims[2:]), value,
                                dtype_to_jnp(dtype))
         specs = {}
         for name in xs:
@@ -428,21 +464,24 @@ class ServingEngine:
         return dec.executor.shard_batch(xs, specs)
 
     def _run_step(self, tokens: np.ndarray, positions: np.ndarray,
-                  read_idx: np.ndarray) -> np.ndarray:
+                  read_idx: np.ndarray, row_slots=None) -> np.ndarray:
         """One decode-graph call: stage inputs with their searched
-        shardings, run the donated step, return the sampled tokens."""
+        shardings, run the donated step, return the sampled token of
+        every row (a row samples at its slot's temperature)."""
         import jax
         import jax.numpy as jnp
 
         dec = self.decode_model
         with telemetry.span("serve.stage"):
-            xs = self._stage_inputs(tokens, positions)
+            xs = self._stage_inputs(tokens, positions, row_slots)
             if self._rng is None:
                 self._rng = jax.random.key(dec.config.seed)
             self._rng, sub = jax.random.split(self._rng)
             temp = np.zeros((self.spec.slots,), np.float32)
             for s in self.scheduler.active_slots:
                 temp[s.index] = s.request.temperature
+            if row_slots is not None:
+                temp = temp[row_slots]
             read_idx = jnp.asarray(read_idx, jnp.int32)
             temp = jnp.asarray(temp)
         t0 = time.perf_counter()
@@ -780,27 +819,42 @@ class ServingEngine:
                 start, n = plan_chunks(
                     pre.prefill_pos, L, self.spec.prefill_chunk)[0]
                 b = self._bucket(n)
-            q = max(b, 1)
-
-            tokens = np.zeros((self.spec.slots, q), np.int32)
+            # a chunk step's layout (module docstring): the rectangle
+            # (slots, q), or the slots' rows then one row a chunk token
+            slots = self.spec.slots
+            by_rows = pre is not None and self._chunk_rows
+            rows, q = (slots + b, 1) if by_rows else (slots, max(b, 1))
+            tokens = np.zeros((rows, q), np.int32)
             # scratch positions everywhere but live elements: no other
             # slot's cache state moves (row max_seq for the contiguous
             # layout; the paged op routes clipped positions to the
             # reserved scratch block)
-            positions = np.full((self.spec.slots, q), self.max_seq_len,
-                                np.int32)
-            read_idx = np.zeros((self.spec.slots,), np.int32)
+            positions = np.full((rows, q), self.max_seq_len, np.int32)
+            read_idx = np.zeros((rows,), np.int32)
+            row_slots = None
             writes: dict[int, range] = {}
-            # context rows this step's attention must read
-            kv_rows = sum(s.length + 1 for s in decoding)
+            # context rows this step's attention must read, and those
+            # it does read where the chunk rides as single-query rows:
+            # chunk row i walks its slot's start + i + 1 rows, the
+            # chunk's earlier ones again
+            kv_rows = kv_rows_walked = sum(s.length + 1 for s in decoding)
             if pre is not None:
-                prompt = pre.request.prompt
-                tokens[pre.index, :n] = prompt[start:start + n]
-                positions[pre.index, :n] = np.arange(
-                    start, start + n, dtype=np.int32)
-                read_idx[pre.index] = n - 1
+                chunk = pre.request.prompt[start:start + n]
+                at = np.arange(start, start + n, dtype=np.int32)
+                if by_rows:
+                    tokens[slots:slots + n, 0] = chunk
+                    positions[slots:slots + n, 0] = at
+                    row_slots = np.r_[np.arange(slots),
+                                      np.full((b,), pre.index)]
+                    first_row = slots + n - 1
+                else:
+                    tokens[pre.index, :n] = chunk
+                    positions[pre.index, :n] = at
+                    read_idx[pre.index] = n - 1
+                    first_row = pre.index
                 writes[pre.index] = range(start, start + n)
                 kv_rows += start + n
+                kv_rows_walked += n * start + n * (n + 1) // 2
             for s in decoding:
                 tokens[s.index, 0] = s.last_token
                 positions[s.index, 0] = s.length
@@ -811,6 +865,8 @@ class ServingEngine:
         # annotation takes its arguments when it is entered
         load = dict(kv_rows=int(kv_rows), kv_itemsize=self._kv_itemsize,
                     admitted=len(admitted), pending=sched.queue_depth)
+        if by_rows:
+            load.update(rows=rows, kv_rows_walked=int(kv_rows_walked))
         span = telemetry.span(
             "serve.prefill", slot=pre.index,
             trace=pre.request.trace_id,
@@ -819,7 +875,8 @@ class ServingEngine:
             decoding=len(decoding), **load) if pre is not None else \
             telemetry.span("serve.step", active=len(decoding), **load)
         with span:
-            next_tok = self._run_step(tokens, positions, read_idx)
+            next_tok = self._run_step(tokens, positions, read_idx,
+                                      row_slots)
 
         with telemetry.span("serve.bookkeep"):
             # ---- prefill bookkeeping (the chunk's writes landed)
@@ -827,6 +884,7 @@ class ServingEngine:
                 self._prefill_tokens += n
                 self._c_prefill_tok.inc(n)
                 self._prefill_calls += 1
+                self._row_steps += by_rows
                 pre.prefill_pos += n
                 req = pre.request
                 if pre.prefill_pos >= len(req.prompt):
@@ -839,7 +897,7 @@ class ServingEngine:
                     # request's first token (TTFT lands here)
                     self._decode_tokens += 1
                     prev_t = req.last_token_t
-                    if sched.note_token(pre, int(next_tok[pre.index])):
+                    if sched.note_token(pre, int(next_tok[first_row])):
                         self._note_completion(pre, req)
                     self._observe_token(req, prev_t)
             # ---- decode bookkeeping
@@ -975,6 +1033,7 @@ class ServingEngine:
         self._decode_tokens = 0
         self._prefill_tokens = 0
         self._prefill_calls = 0
+        self._row_steps = 0
         self._device_s = 0.0
         self._last_wall_s = 0.0
         # zero the serving series (objects survive — the step loop holds
@@ -1025,6 +1084,9 @@ class ServingEngine:
             "decode_tokens": self._decode_tokens,
             "prefill_tokens": self._prefill_tokens,
             "prefill_calls": self._prefill_calls,
+            # of those, the steps laid out as single-query rows: all of
+            # them where the paged kernel serves the rows, else none
+            "row_steps": self._row_steps,
             "wall_s": wall,
             "device_s": self._device_s,
             "plan_source": self.decode_model._plan_source,

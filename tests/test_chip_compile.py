@@ -145,9 +145,15 @@ def test_contiguous_decode_head_dim_128(tpu):
     # c13b-serve-chat's own call: 16 slots x 640 rows in blocks of 16, the
     # pool the engine sizes for one v5e
     (16, 16, 40, 16, 641),
+    # and its step that carries a chunk of 128 tokens, laid out as rows
+    # (serving/engine.py): 16 slots + 128 single-query rows, a (144, 40)
+    # table in SMEM
+    (16 + 128, 16, 40, 16, 641),
 ])
 def test_paged_decode_head_dim_128(tpu, slots, heads, width, block_size,
                                    blocks):
+    """`slots` is the call's rows: a slot's one query each, or more rows
+    than slots where a prefill chunk's tokens ride as rows of their own."""
     s = _on(tpu[0])
     e = heads * 128
     pool = s((blocks, block_size, e))

@@ -25,15 +25,15 @@ import numpy as np
 import pytest
 
 
-def _lm_config():
+def _lm_config(sequence_length=32):
     from flexflow_tpu.models import TransformerLMConfig
 
     return TransformerLMConfig(
         vocab_size=64, hidden_size=32, num_heads=4, num_layers=2,
-        sequence_length=32, attention_impl="xla")
+        sequence_length=sequence_length, attention_impl="xla")
 
 
-def _build_lm(mesh=(1, 1, 1, 1), batch=8, argv=()):
+def _build_lm(mesh=(1, 1, 1, 1), batch=8, argv=(), sequence_length=32):
     sys.argv = ["test"] + list(argv)
     from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
     from flexflow_tpu.models import build_transformer_lm
@@ -43,10 +43,40 @@ def _build_lm(mesh=(1, 1, 1, 1), batch=8, argv=()):
         cfg.mesh_axis_sizes = mesh
     cfg.batch_size = batch
     ff = FFModel(cfg)
-    build_transformer_lm(ff, _lm_config(), batch_size=batch)
+    build_transformer_lm(ff, _lm_config(sequence_length), batch_size=batch)
     ff.compile(optimizer=SGDOptimizer(lr=0.01),
                loss_type=LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY)
     return ff
+
+
+# A chunk step's two batch layouts (docs/serving.md). The engine lays a
+# chunk out as single-query ROWS where the paged decode kernel serves a
+# (rows, 1) call: impl="flash" asks for the kernel on the CPU too (the
+# interpreter runs it), and it takes a cache of at least 128 rows in
+# blocks of a multiple of 8. Everywhere else the RECTANGLE (slots, q)
+# stays, as under the default impl on the CPU.
+ROWS_SEQ = 128
+ROWS = dict(impl="flash", kv_layout="paged", kv_block_size=8)
+
+
+def _build_rows_lm():
+    return _build_lm(batch=1, sequence_length=ROWS_SEQ)
+
+
+def _staged_shapes(eng):
+    """Record the (tokens shape, page-table shape) of every call `eng`
+    stages from here on."""
+    shapes, stage = [], eng._stage_inputs
+
+    def spy(tokens, positions, *rest):
+        xs = stage(tokens, positions, *rest)
+        table = xs.get("page_table")
+        shapes.append((tokens.shape,
+                       None if table is None else table.shape))
+        return xs
+
+    eng._stage_inputs = spy
+    return shapes
 
 
 def _teacher_argmax(ff, sequence):
@@ -80,23 +110,50 @@ def test_greedy_decode_parity_vs_teacher_forced():
         assert gen == want, f"prompt {prompt}: decode {gen} != teacher {want}"
 
 
-def test_continuous_batching_invariance():
+@pytest.mark.parametrize("layout", ["rectangle", "rows"])
+def test_continuous_batching_invariance(layout):
     """Interleaved batch == sequential single-request runs, token for
-    token. Five requests through two slots forces mid-run admission and
+    token. Six requests through two slots forces mid-run admission and
     slot reuse (stale cache rows from the previous resident must never
-    leak into the next request)."""
-    ff = _build_lm(batch=1)
-    prompts = PROMPTS + [[2, 4, 6, 8]]
+    leak into the next request); prompts shorter than, equal to and
+    several times the chunk, so decoding slots ride chunk steps of every
+    bucket. In the rows layout also: every chunk step was laid out as
+    rows, the tokens are the rectangle engine's and the teacher-forced
+    forward's, and the rectangle engine staged (slots, q) calls only."""
+    rows = layout == "rows"
+    ff = _build_rows_lm() if rows else _build_lm(batch=1)
+    kw = dict(slots=2, max_new_tokens=6, prefill_chunk=4,
+              **(ROWS if rows else {}))
+    prompts = PROMPTS + [[2, 4, 6, 8], list(range(1, 17))]
 
-    eng = ff.serve(slots=2, max_new_tokens=6, prefill_chunk=4)
+    eng = ff.serve(**kw)
+    assert eng._chunk_rows == rows
+    shapes = _staged_shapes(eng)
     interleaved = eng.generate(prompts)
     assert eng.scheduler.drained
-    # two slots, five requests: admissions happened while others decoded
-    assert eng.stats()["requests_completed"] == 5
+    # two slots, six requests: admissions happened while others decoded
+    st = eng.stats()
+    assert st["requests_completed"] == len(prompts)
+    W = eng.block_manager.table_width
+    if rows:
+        assert st["row_steps"] == st["prefill_calls"] > 0
+        assert set(shapes) == {((2 + b, 1), (2 + b, W)) for b in (0, 1, 2, 4)}
+    else:
+        assert st["row_steps"] == 0
+        assert set(shapes) == {((2, q), (2, W)) for q in (1, 2, 4)}
 
-    solo_eng = ff.serve(slots=2, max_new_tokens=6, prefill_chunk=4)
+    solo_eng = ff.serve(**kw)
     solo = [solo_eng.generate([p])[0] for p in prompts]
     assert interleaved == solo
+    if rows:
+        rect = ff.serve(**{**kw, "impl": "xla"})
+        assert not rect._chunk_rows
+        assert rect.generate(prompts) == interleaved
+        assert rect.stats()["row_steps"] == 0
+        for prompt, gen in zip(prompts, interleaved):
+            seq = prompt + gen
+            want = _teacher_argmax(ff, seq)[len(prompt) - 1:len(seq) - 1]
+            assert gen == want.tolist()
 
 
 def test_kv_cache_sharding_roundtrip_tp_mesh():
@@ -358,25 +415,36 @@ def test_paged_token_identical_to_contiguous():
     paged.block_manager.check_invariants()
 
 
-def test_paged_cow_divergence_after_shared_prefix():
+@pytest.mark.parametrize("layout", ["rectangle", "rows"])
+def test_paged_cow_divergence_after_shared_prefix(layout):
     """Two prompts sharing a prefix past block granularity: the second
     admission maps the shared blocks (prefix hit), the first divergent
     write copies exactly the block it lands in (COW), and both token
-    streams stay identical to the contiguous engine's."""
-    ff = _build_lm(batch=1)
-    # 6 shared tokens @ bs=4: one full block + a registered PARTIAL tail;
-    # the second prompt extends the prefix INSIDE that partial block, so
-    # its first tail write must COW it
-    shared = [3, 7, 11, 2, 5, 9]
+    streams stay identical to the contiguous engine's. In the rows layout
+    the copy lands before a step whose chunk rows all carry the copied
+    table row."""
+    rows = layout == "rows"
+    ff = _build_rows_lm() if rows else _build_lm(batch=1)
+    bs = ROWS["kv_block_size"] if rows else 4
+    kw = dict(slots=2, max_new_tokens=5, prefill_chunk=4,
+              kv_layout="paged", kv_block_size=bs,
+              **({"impl": "flash"} if rows else {}))
+    base = [3, 7, 11, 2, 5, 9, 13, 1, 17, 40, 6, 22, 8, 51, 33, 4]
+    # a block and a half shared: one full block + a registered PARTIAL
+    # tail; the second prompt extends the prefix INSIDE that partial
+    # block, so its first tail write must COW it
+    shared = base[:bs + bs // 2]
     prompts = [list(shared), shared + [31, 32]]
-    eng = ff.serve(slots=2, max_new_tokens=5, prefill_chunk=4,
-                   kv_layout="paged", kv_block_size=4)
+    eng = ff.serve(**kw)
+    assert eng._chunk_rows == rows
     out = eng.generate(prompts)
     st = eng.block_manager.stats
     assert st.prefix_hits >= 1, "second prompt must share the prefix"
     assert st.shared_tokens >= len(shared)
     assert st.cow_copies >= 1, \
         "divergence inside a shared block must copy-on-write"
+    assert eng.stats()["row_steps"] == (
+        eng.stats()["prefill_calls"] if rows else 0)
     contig = ff.serve(slots=2, max_new_tokens=5, prefill_chunk=4,
                       kv_layout="contiguous")
     assert out == contig.generate(prompts)
@@ -384,14 +452,13 @@ def test_paged_cow_divergence_after_shared_prefix():
     # identical block-aligned prompts too (the N-users-one-system-prompt
     # case): the whole prompt is shared; only the final token is
     # recomputed and its write COWs the one block it lands in
-    shared8 = [3, 7, 11, 2, 5, 9, 13, 1]  # 2 full blocks @ bs=4
-    eng2 = ff.serve(slots=2, max_new_tokens=5, prefill_chunk=4,
-                    kv_layout="paged", kv_block_size=4)
-    same = [list(shared8), list(shared8)]
+    aligned = base[:2 * bs]  # 2 full blocks
+    eng2 = ff.serve(**kw)
+    same = [list(aligned), list(aligned)]
     out2 = eng2.generate(same)
     assert out2[0] == out2[1]
     st2 = eng2.block_manager.stats
-    assert st2.shared_tokens >= len(shared8) - 1
+    assert st2.shared_tokens >= len(aligned) - 1
     assert st2.cow_copies >= 1
     contig2 = ff.serve(slots=2, max_new_tokens=5, prefill_chunk=4,
                        kv_layout="contiguous")
@@ -439,37 +506,81 @@ def test_paged_refcount_exact_reclamation():
     mgr.check_invariants()
 
 
-def test_chunked_prefill_interleaves_with_decode():
+@pytest.mark.parametrize("layout", ["paged", "contiguous", "rows"])
+def test_chunked_prefill_interleaves_with_decode(layout):
     """A long prompt's prefill is spread one chunk per iteration, and the
     in-flight decode advances BETWEEN those chunks — without changing its
-    token stream (both layouts)."""
-    for layout in ("paged", "contiguous"):
-        ff = _build_lm(batch=1)
-        eng = ff.serve(slots=2, max_new_tokens=10, prefill_chunk=4,
-                       kv_layout=layout)
-        short = eng.submit(PROMPTS[0])
-        # drive until the short request is decoding
-        for _ in range(3):
-            eng.step()
-        s_short = next(s for s in eng.scheduler.slots
-                       if s.request is short)
-        assert s_short.decoding
-        gen_before = len(short.generated)
-        long_req = eng.submit(list(range(1, 17)))  # 16 tokens = 4 chunks
-        progressed = []
-        while long_req.first_token_t is None:
-            eng.step()
-            progressed.append(len(short.generated))
-        # the decode moved during the long prefill, one token per
-        # iteration — chunked prefill never stalled the batch
-        assert progressed[0] > gen_before
-        assert len(progressed) >= 4, "16-token prompt needs >= 4 chunks"
-        eng.run_until_drained()
+    token stream (both KV layouts, and the paged one with its chunk steps
+    laid out as rows, where `_prefill_calls` still counts a chunk step
+    once)."""
+    rows = layout == "rows"
+    ff = _build_rows_lm() if rows else _build_lm(batch=1)
+    kw = dict(slots=2, max_new_tokens=10, prefill_chunk=4,
+              **(ROWS if rows else {"kv_layout": layout}))
+    eng = ff.serve(**kw)
+    assert eng._chunk_rows == rows
+    short = eng.submit(PROMPTS[0])
+    # drive until the short request is decoding
+    for _ in range(3):
+        eng.step()
+    s_short = next(s for s in eng.scheduler.slots
+                   if s.request is short)
+    assert s_short.decoding
+    gen_before = len(short.generated)
+    long_req = eng.submit(list(range(1, 17)))  # 16 tokens = 4 chunks
+    progressed = []
+    while long_req.first_token_t is None:
+        calls = eng._prefill_calls
+        eng.step()
+        assert eng._prefill_calls == calls + 1
+        progressed.append(len(short.generated))
+    # the decode moved during the long prefill, one token per
+    # iteration — chunked prefill never stalled the batch
+    assert progressed[0] > gen_before
+    assert len(progressed) >= 4, "16-token prompt needs >= 4 chunks"
+    eng.run_until_drained()
+    assert eng.stats()["row_steps"] == (
+        eng.stats()["prefill_calls"] if rows else 0)
 
-        solo = ff.serve(slots=2, max_new_tokens=10, prefill_chunk=4,
-                        kv_layout=layout)
-        assert solo.generate([PROMPTS[0]])[0] == short.generated
-        assert solo.generate([list(range(1, 17))])[0] == long_req.generated
+    solo = ff.serve(**kw)
+    assert solo.generate([PROMPTS[0]])[0] == short.generated
+    assert solo.generate([list(range(1, 17))])[0] == long_req.generated
+
+
+def test_rows_engine_keeps_the_surface_the_benchmark_calls():
+    """benchmarks/jobs/serve.py reaches into the engine: its reference
+    check stages a rectangle through `_stage_inputs(tokens, positions)`
+    and swaps in a (slots, W) page table of its own, its loop reads
+    `_prefill_calls` round every step, its warm-up calls `_apply_copies`.
+    On an engine that lays its own chunk steps out as rows those answer as
+    on any other."""
+    import jax
+
+    from flexflow_tpu.serving.paged import SCRATCH_BLOCK, CopyPlan
+
+    ff = _build_rows_lm()
+    eng = ff.serve(slots=2, max_new_tokens=2, prefill_chunk=4, **ROWS)
+    assert eng._chunk_rows
+    slots, W = 2, eng.block_manager.table_width
+    for width in (1, 4, 7):
+        xs = eng._stage_inputs(
+            np.zeros((slots, width), np.int32),
+            np.full((slots, width), eng.max_seq_len, np.int32))
+        assert xs["page_table"].shape == (slots, W)
+        assert xs["tokens"].shape == xs["positions"].shape == (slots, width)
+    pools = jax.device_get(eng.decode_model._state)
+    for width in (1, 2):
+        eng._apply_copies(
+            [CopyPlan(src=SCRATCH_BLOCK, dst=SCRATCH_BLOCK)] * width)
+    after = jax.device_get(eng.decode_model._state)
+    jax.tree.map(np.testing.assert_array_equal, pools, after)
+    eng.submit(list(range(1, 10)))      # chunks of 4, 4 and 1, then decode
+    seen = []
+    while not eng.scheduler.drained:
+        before = eng._prefill_calls
+        eng.step()
+        seen.append(eng._prefill_calls - before)
+    assert seen == [1, 1, 1, 0]
 
 
 def test_paged_scratch_block_guard():
@@ -713,3 +824,59 @@ def test_paged_flash_decode_kernel_matches_reference(H, hd, bs, W, dtype):
     # slot 0 is empty: the oracle's softmax over no keys is uniform, the
     # kernel's is zero, and neither is ever consumed
     np.testing.assert_allclose(out[1:], ref[1:], rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("start", [0, 384])
+def test_paged_flash_decode_kernel_rows_outnumber_slots(start, dtype):
+    """A step that carries a prefill chunk as rows (serving/engine.py):
+    16 slot rows, then the 128 rows of a chunk's bucket, all carrying ONE
+    slot's page-table row. Chunk row i attends `start + i + 1` keys, the
+    chunk's own earlier rows among them; the bucket's tail past the
+    chunk's 100 tokens is dead (length 0), as are some of the slots
+    (empty ones, and the prefilling slot's own row). Rows past a cursor
+    hold NaN, as do the blocks no live row maps."""
+    import jax.numpy as jnp
+
+    from flexflow_tpu.kernels.flash_attention import (
+        paged_decode_attention_reference,
+        paged_flash_decode_attention,
+    )
+
+    rs = np.random.RandomState(start)
+    H, hd, bs, W = 2, 128, 16, 32
+    E, slots, bucket, n = H * hd, 16, 128, 100
+    slot_len = rs.randint(1, W * bs + 1, slots).astype(np.int32)
+    slot_len[[2, 7, 11]] = 0            # empty slots, the prefilling slot
+    chunk_len = np.where(np.arange(bucket) < n,
+                         start + 1 + np.arange(bucket), 0).astype(np.int32)
+    lengths = np.concatenate([slot_len, chunk_len])
+    nb = 1 + slots * W
+    pool_k = rs.randn(nb, bs, E).astype(np.float32)
+    pool_v = rs.randn(nb, bs, E).astype(np.float32)
+    pool_k[0] = pool_v[0] = np.nan      # scratch stands for "unmapped"
+    table = np.zeros((slots + bucket, W), np.int32)
+    for s in range(slots):
+        used = -(-int(max(slot_len[s], (start + n) * (s == 7))) // bs)
+        table[s, :used] = rs.permutation(
+            np.arange(1 + s * W, 1 + (s + 1) * W))[:used]
+    table[slots:] = table[7]            # the chunk is slot 7's
+    last = table[7, (start + n - 1) // bs]
+    pool_k[last, (start + n) % bs or bs:] = np.nan
+    pool_v[last, (start + n) % bs or bs:] = np.nan
+    dt = jnp.dtype(dtype)
+    q = jnp.asarray(rs.randn(slots + bucket, 1, E), dt)
+    table, lengths = jnp.asarray(table), jnp.asarray(lengths)
+    ref = paged_decode_attention_reference(
+        q, jnp.asarray(np.nan_to_num(pool_k), dt),
+        jnp.asarray(np.nan_to_num(pool_v), dt), table,
+        (lengths - 1)[:, None], num_heads=H)
+    out = paged_flash_decode_attention(
+        q, jnp.asarray(pool_k, dt), jnp.asarray(pool_v, dt), table, lengths,
+        num_heads=H)
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    assert np.isfinite(out).all()       # the dead rows too
+    live = np.asarray(lengths) > 0
+    assert live.sum() == slots - 3 + n
+    tol = _PAGED_PARITY_TOL[dtype]
+    np.testing.assert_allclose(out[live], ref[live], rtol=tol, atol=tol)

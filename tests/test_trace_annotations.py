@@ -17,7 +17,7 @@ import pytest
 from flexflow_tpu import telemetry
 from flexflow_tpu.telemetry.tracer import Tracer
 
-from test_serving import _build_lm
+from test_serving import ROWS, _build_lm, _build_rows_lm
 from test_telemetry import _build_mlp, _train_data
 
 ENGINE_PHASES = ("ff/serve.schedule", "ff/serve.prepare_writes",
@@ -87,13 +87,19 @@ def test_fit_spans_reach_the_profilers_host_plane(tmp_path):
     assert inside(drain, fit) and drain[1] >= steps[-1][2]
 
 
-def test_engine_phases_reach_the_profilers_host_plane(tmp_path):
+@pytest.mark.parametrize("layout", ["rectangle", "rows"])
+def test_engine_phases_reach_the_profilers_host_plane(tmp_path, layout):
     """Two requests on two slots, prompts of 5 and 2 tokens, 3 new tokens
     each, chunks of 4: five iterations, whose `kv_rows` (the context rows
-    the step's attention reads) are counted by hand below."""
-    ff = _build_lm(batch=1)
+    the step's attention must read) are counted by hand below, the same
+    in both layouts of a chunk step; laid out as rows, a chunk step also
+    says how many rows it ran and how many context rows they walked."""
+    rows = layout == "rows"
+    ff = _build_rows_lm() if rows else _build_lm(batch=1)
     eng = ff.serve(slots=2, max_new_tokens=3, prefill_chunk=4,
-                   kv_block_size=4, prefix_sharing=False)
+                   prefix_sharing=False,
+                   **(ROWS if rows else {"kv_block_size": 4}))
+    assert eng._chunk_rows == rows
     eng.generate([[9, 8, 7]])                       # compiles
     first = eng._iterations
     with traced(tmp_path / "trace"):
@@ -117,6 +123,13 @@ def test_engine_phases_reach_the_profilers_host_plane(tmp_path):
     assert all(t.startswith("req-") for t in traces)
     assert [c[3]["tokens"] for c in calls[:3]] == [4, 1, 2]
     assert calls[3][3]["active"] == 2
+    if rows:
+        # two slots + the chunk's bucket; chunk row i walks start + i + 1
+        # rows: 1+2+3+4; 5; (1+2) beside the first request's 6
+        assert [c[3]["rows"] for c in calls[:3]] == [6, 3, 4]
+        assert [c[3]["kv_rows_walked"] for c in calls[:3]] == [10, 5, 9]
+    assert not any("rows" in c[3] or "kv_rows_walked" in c[3]
+                   for c in calls[0 if not rows else 3:])
     for it, call in zip(iterations, calls):
         phases = [s for s in named(spans, *ENGINE_PHASES) if inside(s, it)]
         assert [p[0] for p in phases] == list(ENGINE_PHASES)
