@@ -266,39 +266,19 @@ def _fit_loop_legs(cfg, batch: int, on_tpu: bool,
 
 
 def _attention_ablation_legs(lcfg, batch: int, steps: int, warmup: int,
-                             on_tpu: bool, packed_tps) -> dict:
-    """seq-4096 attention-ablation legs: attribute the long-context gain
-    to its round-7 components (docs/performance.md "Long-context path").
-
-    - flash_packed vs flash_transposed: the relayout-free packed kernels
-      (lane-offset / head-group BlockSpecs on the (b, s, h·d) projection
-      layout) vs the head-transposed kernels whose (b,s,h,d)↔(b,h,s,d)
-      copies PERF.md measured at ~0.8 ms/step on the flagship.
-    - ring_overlap vs ring_serial: the sequence-parallel ring path with
-      the double-buffered hop-before-compute ppermute pipeline vs the
-      serial compute-then-hop ablation (--no-overlap-collectives), seq
-      axis sharded over every local device. Skipped (null) on one chip —
-      there is no ring to overlap.
-
-    All legs reuse the slope methodology of `_measure_lm`; the packed
-    reading is the already-measured seq-4096 leg, passed in so the
-    number of record and its ablation baseline come from one run."""
+                             on_tpu: bool) -> dict:
+    """seq-4096 attention-ablation legs (docs/performance.md
+    "Long-context path"): ring_overlap vs ring_serial, the
+    sequence-parallel ring path with the double-buffered
+    hop-before-compute ppermute pipeline vs the serial compute-then-hop
+    ablation (--no-overlap-collectives), seq axis sharded over every
+    local device. Skipped (null) on one chip — there is no ring to
+    overlap. Both legs reuse the slope methodology of `_measure_lm`."""
     import dataclasses
 
     import jax
 
-    legs = {
-        "flash_packed_tokens_per_sec":
-            None if packed_tps is None else round(packed_tps, 2),
-    }
-    tps_t, _ = _measure_lm(
-        lcfg, batch, steps, warmup, on_tpu,
-        tune=lambda c: setattr(c, "flash_packed_layout", False))
-    legs["flash_transposed_tokens_per_sec"] = (
-        None if tps_t is None else round(tps_t, 2))
-    if packed_tps and tps_t:
-        legs["packed_vs_transposed"] = round(packed_tps / tps_t, 4)
-
+    legs = {}
     n = jax.local_device_count()
     if n > 1:
         rcfg = dataclasses.replace(lcfg, attention_impl="ring")
@@ -1245,13 +1225,10 @@ def _bench_body(jax, TransformerLMConfig, telemetry, session):
                     "unit": "tokens/s",
                     "vs_baseline": round(mfu4k / 0.35, 4),
                 }
-                # attention-ablation legs (round 7): transposed vs packed
-                # kernel, ring overlap on/off — the BENCH payload must
-                # attribute the long-context number to its components
+                # attention-ablation legs (round 7): ring overlap on/off
                 try:
                     seq4096["ablation"] = _attention_ablation_legs(
-                        lcfg, batch=1, steps=5, warmup=1, on_tpu=on_tpu,
-                        packed_tps=tps4k)
+                        lcfg, batch=1, steps=5, warmup=1, on_tpu=on_tpu)
                 except Exception as e:  # pragma: no cover - defensive
                     print(f"bench: attention ablation failed: {e}",
                           file=sys.stderr)

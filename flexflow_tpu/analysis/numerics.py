@@ -59,12 +59,10 @@ F32_INTERNAL = {
     OT.OP_BATCHNORM: "ops/core.py _bn_forward fp32 statistics",
     OT.OP_LINEAR: "ops/core.py preferred_element_type=float32",
     OT.OP_BATCHMATMUL: "ops/core.py preferred_element_type=float32",
-    OT.OP_MULTIHEAD_ATTENTION:
-        "ops/attention.py preferred_element_type=float32",
-    OT.OP_INC_MULTIHEAD_ATTENTION:
-        "ops/inc_attention.py preferred_element_type=float32",
-    OT.OP_PAGED_INC_MULTIHEAD_ATTENTION:
-        "ops/inc_attention.py (paged) preferred_element_type=float32",
+    **dict.fromkeys(
+        (OT.OP_MULTIHEAD_ATTENTION, OT.OP_INC_MULTIHEAD_ATTENTION,
+         OT.OP_PAGED_INC_MULTIHEAD_ATTENTION),
+        "ops/attention.py proj preferred_element_type=float32"),
 }
 
 # reduce ops that SUM (max/min/argmax are order statistics — no

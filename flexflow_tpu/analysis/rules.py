@@ -220,7 +220,9 @@ def _param_candidates(op_type: OT, env: dict, n_inputs: int,
                       prior_params: list):
     """Candidate param structs for one pattern op, most-common first; the
     synthesizer picks the first satisfying every opaque constraint."""
-    from ..ops.attention import MultiHeadAttentionParams
+    from ..ops.attention import (
+        AttentionFrontEnd, MultiHeadAttentionParams,
+    )
     from ..ops.core import (
         Conv2DParams,
         EmbeddingParams,
@@ -239,8 +241,8 @@ def _param_candidates(op_type: OT, env: dict, n_inputs: int,
             for ub in (True, False):
                 yield LinearParams(env["O"], use_bias=ub, activation=act)
     elif op_type == OT.OP_MULTIHEAD_ATTENTION:
-        yield MultiHeadAttentionParams(embed_dim=env["E"],
-                                       num_heads=env["heads"])
+        yield MultiHeadAttentionParams(
+            AttentionFrontEnd(env["E"], env["heads"]))
     elif op_type == OT.OP_CONV2D:
         for act in (ActiMode.AC_MODE_NONE, ActiMode.AC_MODE_RELU):
             for ub in (True, False):

@@ -68,12 +68,6 @@ class FFConfig:
     # the serial compute+comm pricing and schedule (the ablation
     # baseline, bench.py's ring legs)
     overlap_collectives: bool = True
-    # flash attention layout: True (default) runs the packed relayout-free
-    # kernels on the (b, s, h·d) projection layout; False forces the
-    # head-transposed kernels — the (b,s,h,d)→(b,h,s,d) HBM relayout
-    # ablation baseline (bench.py's seq-4096 kernel legs, PERF.md's
-    # ~0.8 ms/step copies)
-    flash_packed_layout: bool = True
     # weight-update sharding (ZeRO / Xu et al. 2020; FSDP, Zhao et al.
     # 2023): fp32 masters + optimizer slots sharded 1/dp along the
     # gradient-reduction axes (stage 2), and — stage 3 — the trainable
@@ -424,8 +418,6 @@ class FFConfig:
             elif a == "--no-weight-update-sharding":
                 self.weight_update_sharding = False
                 self.weight_update_stage = 0
-            elif a == "--flash-transposed":
-                self.flash_packed_layout = False
             elif a == "--fusion":
                 self.perform_fusion = True
             elif a == "--profiling":

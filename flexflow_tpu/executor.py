@@ -667,7 +667,6 @@ class Executor:
                 weight_axes=node.weight_axes,
                 matmul_dtype=self.matmul_dtype,
                 overlap_collectives=self.config.overlap_collectives,
-                flash_packed=self.config.flash_packed_layout,
             )
             op_state = new_state.get(node.name)
             # named_scope labels the op in XLA profiles (the analog of the

@@ -56,6 +56,7 @@ from .optimizer import Optimizer, SGDOptimizer
 from .ops import (
     AggregateParams,
     AggregateSpecParams,
+    AttentionFrontEnd,
     BatchMatmulParams,
     BatchNormParams,
     CacheParams,
@@ -442,74 +443,17 @@ class FFModel:
         if bool(rope_theta) != (positions is not None):
             raise ValueError(
                 "multihead_attention: rope_theta and positions go together")
-        p = MultiHeadAttentionParams(embed_dim, num_heads, kdim, vdim, dropout,
-                                     bias, add_bias_kv, add_zero_attn, causal,
-                                     impl, rope_theta, qk_norm, qk_norm_eps)
-        inits = {}
-        if kernel_initializer is not None:
-            for w in ("wq", "wk", "wv", "wo"):
-                inits[w] = kernel_initializer
+        front = AttentionFrontEnd(embed_dim, num_heads, bias, rope_theta,
+                                  qk_norm, qk_norm_eps)
+        p = MultiHeadAttentionParams(front, kdim, vdim, dropout, add_bias_kv,
+                                     add_zero_attn, causal, impl)
+        inits = ({} if kernel_initializer is None
+                 else dict.fromkeys(front.kernels, kernel_initializer))
         inputs = [query, key, value]
         if positions is not None:
             inputs.append(positions)
         return self._add_layer(OT.OP_MULTIHEAD_ATTENTION, p, inputs,
                                name, inits, query.dtype).outputs[0]
-
-    def inc_multihead_attention(
-        self,
-        input: Tensor,
-        positions: Tensor,
-        embed_dim: int,
-        num_heads: int,
-        max_seq_len: int,
-        use_bias: bool = True,
-        impl: str = "auto",
-        name: str = "",
-    ) -> Tensor:
-        """Decode-phase self-attention over a per-layer KV cache (serving/):
-        `input` carries q_len new tokens per slot, `positions` their
-        absolute sequence positions (scratch-row convention for padding —
-        ops/inc_attention.py). The cache is a non-trainable stateful
-        weight, placed by the plan like any parameter. Weight names match
-        multihead_attention's, so trained parameters transfer by name."""
-        from .ops import IncMultiHeadAttentionParams
-
-        p = IncMultiHeadAttentionParams(embed_dim, num_heads, max_seq_len,
-                                        use_bias, impl)
-        return self._add_layer(OT.OP_INC_MULTIHEAD_ATTENTION, p,
-                               [input, positions], name,
-                               data_type=input.dtype).outputs[0]
-
-    def paged_inc_multihead_attention(
-        self,
-        input: Tensor,
-        positions: Tensor,
-        page_table: Tensor,
-        embed_dim: int,
-        num_heads: int,
-        max_seq_len: int,
-        block_size: int,
-        num_blocks: int,
-        use_bias: bool = True,
-        impl: str = "auto",
-        name: str = "",
-    ) -> Tensor:
-        """Decode-phase self-attention over a PAGED KV cache (serving/,
-        vLLM-style): per-layer block pools `pool_k`/`pool_v` of shape
-        (num_blocks, block_size, embed_dim) — block 0 reserved as scratch
-        — addressed through the shared `page_table` input ((slots,
-        ceil(max_seq_len/block_size)) int32, logical→physical). Pools are
-        non-trainable stateful weights placed by the plan; weight names
-        match multihead_attention's, so trained parameters transfer by
-        name exactly like the contiguous decode op's."""
-        from .ops import PagedIncMultiHeadAttentionParams
-
-        p = PagedIncMultiHeadAttentionParams(
-            embed_dim, num_heads, max_seq_len, block_size, num_blocks,
-            use_bias, impl)
-        return self._add_layer(OT.OP_PAGED_INC_MULTIHEAD_ATTENTION, p,
-                               [input, positions, page_table], name,
-                               data_type=input.dtype).outputs[0]
 
     def concat(self, tensors: Sequence[Tensor], axis: int, name: str = "") -> Tensor:
         p = ConcatParams(axis, len(tensors))

@@ -17,7 +17,6 @@ from .transformer import (
     TransformerLMConfig,
     build_transformer,
     build_transformer_lm,
-    build_transformer_lm_decode,
     build_transformer_lm_pipelined,
     olmoe_lm_config,
     transformer_lm_param_count,

@@ -116,19 +116,23 @@ def _lm_norm(ff, c: TransformerLMConfig, h, name: str):
     return ff.layer_norm(h, [2], eps=c.norm_eps, name=name)
 
 
-def _lm_trunk(ff, c: TransformerLMConfig, h, attention):
-    """The pre-norm block stack + final norm + vocab head, shared between
-    the training builder and the causal-decode builder — ONE graph
-    definition, two attention lowerings (`attention(x, name)` supplies
-    either training MHA or incremental KV-cache attention). Layer names
-    are identical on both paths, so trained parameters transfer to the
-    decode graph by name (serving/decode_graph.adopt_params). What the
-    block is made of is the config's (norm, mlp; the attention closure
-    reads position, attention_bias and qk_norm)."""
+def _lm_trunk(ff, c: TransformerLMConfig, h, pos):
+    """The pre-norm block stack + final norm + vocab head. What the block
+    is made of is the config's (norm, position, attention_bias, qk_norm,
+    mlp). The decode graph is this graph replayed with the same layer
+    names (serving/decode_graph.py), so trained parameters transfer to it
+    by name."""
+    rope = c.position == "rope"
     for i in range(c.num_layers):
         p = f"l{i}_"
         a = _lm_norm(ff, c, h, f"{p}ln1")
-        a = attention(a, f"{p}attn")
+        a = ff.multihead_attention(
+            a, a, a, c.hidden_size, c.num_heads, bias=c.attention_bias,
+            causal=True, impl=c.attention_impl, name=f"{p}attn",
+            positions=pos if rope else None,
+            rope_theta=c.rope_theta if rope else 0.0,
+            qk_norm=c.qk_norm, qk_norm_eps=c.norm_eps,
+        )
         h = ff.add(h, a, name=f"{p}res1")
         m = _lm_norm(ff, c, h, f"{p}ln2")
         if c.mlp == "moe":
@@ -147,16 +151,6 @@ def _lm_trunk(ff, c: TransformerLMConfig, h, attention):
     return ff.dense(h, c.vocab_size, use_bias=False, name="lm_head")
 
 
-def _gpt2_block_only(c: TransformerLMConfig, what: str):
-    if (c.norm, c.position, c.mlp, c.qk_norm) != ("layernorm", "learned",
-                                                  "gelu", False):
-        raise NotImplementedError(
-            f"{what} builds the GPT-2 block only (rotary positions "
-            f"through the KV cache and the expert op in the decode graph "
-            f"are not there yet); got norm={c.norm!r} "
-            f"position={c.position!r} mlp={c.mlp!r} qk_norm={c.qk_norm}")
-
-
 def build_transformer_lm(ff, config: TransformerLMConfig | None = None,
                          batch_size: int | None = None):
     """Returns (tokens_input, logits). Loss:
@@ -168,78 +162,10 @@ def build_transformer_lm(ff, config: TransformerLMConfig | None = None,
     h = ff.embedding(tokens, c.vocab_size, c.hidden_size, name="wte")
     pos = ff.create_tensor((bs, c.sequence_length), DataType.DT_INT32,
                            name="positions")
-    rope = c.position == "rope"
-    if not rope:  # rotary positions go to the attention ops instead
+    if c.position != "rope":  # rotary positions go to the attention ops
         hp = ff.embedding(pos, c.sequence_length, c.hidden_size, name="wpe")
         h = ff.add(h, hp, name="embed_add")
-
-    def attention(a, name):
-        return ff.multihead_attention(
-            a, a, a, c.hidden_size, c.num_heads, bias=c.attention_bias,
-            causal=True, impl=c.attention_impl, name=name,
-            positions=pos if rope else None,
-            rope_theta=c.rope_theta if rope else 0.0,
-            qk_norm=c.qk_norm, qk_norm_eps=c.norm_eps,
-        )
-
-    logits = _lm_trunk(ff, c, h, attention)
-    return tokens, logits
-
-
-def build_transformer_lm_decode(ff, config: TransformerLMConfig | None = None,
-                                slots: int | None = None,
-                                max_seq_len: int | None = None,
-                                impl: str = "auto",
-                                kv_layout: str | None = None,
-                                kv_block_size: int | None = None,
-                                kv_num_blocks: int = 0):
-    """The flagship LM's *decode* graph, built directly (the model-zoo
-    twin of serving/decode_graph's generic replay): single-token query per
-    continuous-batching slot, per-layer KV caches written at the
-    position-indexed rows the `positions` input names. Same `_lm_trunk`,
-    same layer names — a model trained with `build_transformer_lm` feeds
-    this graph its weights unchanged. `kv_layout` mirrors the serving
-    engine's (default: the config's --serve-kv-layout): "paged" adds the
-    shared `page_table` input and block-pool caches, "contiguous" the
-    per-slot region. Returns (tokens, positions, logits); compile with
-    CompMode.COMP_MODE_INFERENCE."""
-    c = config or TransformerLMConfig()
-    _gpt2_block_only(c, "build_transformer_lm_decode")
-    n = slots or ff.config.serve_slots
-    max_seq = max_seq_len or c.sequence_length
-    layout = kv_layout or ff.config.serve_kv_layout
-    tokens = ff.create_tensor((n, 1), DataType.DT_INT32, create_grad=False,
-                              name="tokens")
-    pos = ff.create_tensor((n, 1), DataType.DT_INT32, create_grad=False,
-                           name="positions")
-    if layout == "paged":
-        bs = kv_block_size or ff.config.serve_kv_block_size
-        table_width = -(-max_seq // bs)
-        # capacity parity + the reserved scratch block — the same default
-        # serving/decode_graph.resolve_pool_blocks lands on when the HBM
-        # budget doesn't bind
-        num_blocks = kv_num_blocks or n * table_width + 1
-        page_table = ff.create_tensor(
-            (n, table_width), DataType.DT_INT32, create_grad=False,
-            name="page_table")
-
-        def attention(a, name):
-            return ff.paged_inc_multihead_attention(
-                a, pos, page_table, c.hidden_size, c.num_heads, max_seq,
-                bs, num_blocks, impl=impl, name=name,
-            )
-    else:
-        def attention(a, name):
-            return ff.inc_multihead_attention(
-                a, pos, c.hidden_size, c.num_heads, max_seq, impl=impl,
-                name=name,
-            )
-
-    h = ff.embedding(tokens, c.vocab_size, c.hidden_size, name="wte")
-    hp = ff.embedding(pos, c.sequence_length, c.hidden_size, name="wpe")
-    h = ff.add(h, hp, name="embed_add")
-    logits = _lm_trunk(ff, c, h, attention)
-    return tokens, pos, logits
+    return tokens, _lm_trunk(ff, c, h, pos)
 
 
 def build_transformer_lm_pipelined(ff, config: TransformerLMConfig | None = None,
@@ -251,7 +177,13 @@ def build_transformer_lm_pipelined(ff, config: TransformerLMConfig | None = None
     reference's enum-only OP_PIPELINE never implements. Identical numerics
     to a sequential block stack by construction (same op, pipe axis 1)."""
     c = config or TransformerLMConfig()
-    _gpt2_block_only(c, "build_transformer_lm_pipelined")
+    if (c.norm, c.position, c.mlp, c.qk_norm) != ("layernorm", "learned",
+                                                  "gelu", False):
+        raise NotImplementedError(
+            f"build_transformer_lm_pipelined builds the GPT-2 block only "
+            f"(ops/pipeline_blocks.py has its own block); got "
+            f"norm={c.norm!r} position={c.position!r} mlp={c.mlp!r} "
+            f"qk_norm={c.qk_norm}")
     bs = batch_size or ff.config.batch_size
     tokens = ff.create_tensor((bs, c.sequence_length), DataType.DT_INT32,
                               name="tokens")

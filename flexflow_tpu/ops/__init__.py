@@ -21,7 +21,7 @@ from .core import (
     RMSNormParams,
     SoftmaxParams,
 )
-from .attention import MultiHeadAttentionParams
+from .attention import AttentionFrontEnd, MultiHeadAttentionParams
 from .inc_attention import (
     IncMultiHeadAttentionParams,
     PagedIncMultiHeadAttentionParams,

@@ -1,21 +1,25 @@
-"""Multi-head attention.
+"""Multi-head attention, and the one definition of an attention layer's
+front end.
 
 Reference: src/ops/attention.cc (926 LoC) + attention.cu wrapping
 `cudnnMultiHeadAttnForward` — a monolithic vendor kernel with weights packed
 into a single tensor. TPU-native design instead expresses attention as
-projections (MXU GEMMs) + a scaled-dot-product core with three interchangeable
-implementations selected per placement:
+projections (MXU GEMMs) + a scaled-dot-product core.
+
+`AttentionFrontEnd` owns what every attention op shares: the trainable
+weights and their names, the input projections with QK-norm and RoPE, the
+output projection, their FLOP count and the head-parallel sharding rule.
+The op here and the two decode ops (ops/inc_attention.py) hold one as
+`params.front`; search/, parallel/ and the decode replay ask it and name
+no weight themselves. What is this op's alone is the core, selected per
+placement:
 
   - "xla":    plain einsum softmax(QK^T)V — XLA fuses well for short seqs
-  - "flash":  Pallas blockwise-softmax kernel (kernels/flash_attention.py) —
-    O(seq) memory, used on the real chip for long sequences
+  - "flash":  the packed Pallas kernels (kernels/flash_attention.py), run
+    per shard of the plan — O(seq) memory, no head-transpose relayout
   - "ring":   shard_map ring attention over the `seq` mesh axis
     (parallel/ring_attention.py) — the long-context path the reference lacks
     (SURVEY §5: no ring/Ulysses in FlexFlow)
-
-Head-parallelism (the reference's attribute-parallel attention rewrite,
-substitution.cc:create_partition_attention_combine) maps to sharding the head
-dim of the projection weights over the `model` axis.
 """
 
 from __future__ import annotations
@@ -23,6 +27,7 @@ from __future__ import annotations
 import functools
 import math
 from dataclasses import dataclass
+from typing import ClassVar
 
 import jax
 import jax.numpy as jnp
@@ -32,31 +37,113 @@ from ..fftype import DataType, OperatorType as OT
 from .base import OpDef, WeightSpec, matmul_cast, register_op
 
 
+def proj(ctx, x, w, b):
+    """x @ w (+ b): operands in the MXU input dtype, float32 accumulation,
+    the result in x's dtype."""
+    xm, wm = matmul_cast(ctx, x, w.astype(x.dtype))
+    y = jnp.dot(xm, wm, preferred_element_type=jnp.float32).astype(x.dtype)
+    if b is not None:
+        y = y + b.astype(y.dtype)
+    return y
+
+
 @dataclass(frozen=True)
-class MultiHeadAttentionParams:
+class AttentionFrontEnd:
+    """What an attention layer is before and after its core, whichever op
+    runs the core: the decode replay hands a trained layer's front end to
+    the decode op as this one value, and weights move by these names."""
+
     embed_dim: int
     num_heads: int
-    kdim: int = 0  # 0 → embed_dim
-    vdim: int = 0
-    dropout: float = 0.0
     use_bias: bool = True
-    add_bias_kv: bool = False
-    add_zero_attn: bool = False
-    causal: bool = False  # TPU-native addition (reference cuDNN op is unmasked)
-    impl: str = "xla"  # xla | flash | ring
-    # rotary positions on q and k (half-rotation form) from a fourth input
-    # of positions (batch, seq) int; 0 = none
+    # rotary positions on q and k (half-rotation form) from positions
+    # (batch, seq) int; 0 = none
     rope_theta: float = 0.0
     # RMSNorm over the whole q and k projections (all heads together, as
     # OLMoE does), with learned scales `q_norm` / `k_norm`
     qk_norm: bool = False
     qk_norm_eps: float = 1e-5
 
+    kernels: ClassVar[tuple] = ("wq", "wk", "wv", "wo")
 
-def _mha_dims(p: MultiHeadAttentionParams):
-    kdim = p.kdim or p.embed_dim
-    vdim = p.vdim or p.embed_dim
-    return kdim, vdim
+    @property
+    def head_dim(self) -> int:
+        return self.embed_dim // self.num_heads
+
+    def weight_specs(self, q_dim: int, k_dim: int, v_dim: int):
+        """The trainable weights, in the order parameters are initialised.
+        Per-head projection sizes follow attention.cc:70-80."""
+        E = self.embed_dim
+        ws = [WeightSpec(name, (d, E), DataType.DT_FLOAT)
+              for name, d in zip(self.kernels, (q_dim, k_dim, v_dim, E))]
+        if self.use_bias:
+            ws += [WeightSpec(b, (E,), DataType.DT_FLOAT, "zeros")
+                   for b in ("bq", "bk", "bv", "bo")]
+        if self.qk_norm:
+            ws += [WeightSpec(g, (E,), DataType.DT_FLOAT, "ones")
+                   for g in ("q_norm", "k_norm")]
+        return ws
+
+    def qkv(self, ctx, weights, q_in, k_in, v_in, positions=None):
+        """The projections, then QK-norm, then RoPE, all on the packed
+        (batch, seq, heads * head_dim) layout."""
+        q = proj(ctx, q_in, weights["wq"], weights.get("bq"))
+        k = proj(ctx, k_in, weights["wk"], weights.get("bk"))
+        v = proj(ctx, v_in, weights["wv"], weights.get("bv"))
+        if self.qk_norm:
+            from .core import rms_norm
+
+            q = rms_norm(q, weights["q_norm"], self.qk_norm_eps)
+            k = rms_norm(k, weights["k_norm"], self.qk_norm_eps)
+        if self.rope_theta:
+            cos, sin = rope_cos_sin(positions, self.head_dim,
+                                    self.rope_theta)
+            q = apply_rope(q, cos, sin, self.num_heads)
+            k = apply_rope(k, cos, sin, self.num_heads)
+        return q, k, v
+
+    def output(self, ctx, weights, x):
+        return proj(ctx, x, weights["wo"], weights.get("bo"))
+
+    def linear_flops(self, batch, q_rows, kv_rows, q_dim, k_dim, v_dim):
+        """FLOPs of the four projections over a batch of q_rows queries
+        and kv_rows keys and values."""
+        E = self.embed_dim
+        return 2.0 * batch * (q_rows * q_dim * E + kv_rows * k_dim * E
+                              + kv_rows * v_dim * E + q_rows * E * E)
+
+    def head_parallel_ok(self, degree: int) -> bool:
+        return self.num_heads % degree == 0 and self.embed_dim % degree == 0
+
+    def head_parallel(self, axis):
+        """(weight name, PartitionSpec) of heads split over mesh axis
+        `axis`: Q, K, V column-parallel with their biases, O row-parallel
+        (its partial sums are the caller's psum) with its bias whole."""
+        return (*((w, PartitionSpec(None, axis)) for w in ("wq", "wk", "wv")),
+                *((b, PartitionSpec(axis)) for b in ("bq", "bk", "bv")),
+                ("wo", PartitionSpec(axis, None)), ("bo", PartitionSpec()))
+
+
+class FrontEndFields:
+    """An attention op's Params hold the front end as `front`; the fields
+    the search, the engine and the analysis passes read stay reachable
+    under their own names."""
+
+    embed_dim = property(lambda self: self.front.embed_dim)
+    num_heads = property(lambda self: self.front.num_heads)
+    use_bias = property(lambda self: self.front.use_bias)
+
+
+@dataclass(frozen=True)
+class MultiHeadAttentionParams(FrontEndFields):
+    front: AttentionFrontEnd
+    kdim: int = 0  # 0 → embed_dim
+    vdim: int = 0
+    dropout: float = 0.0
+    add_bias_kv: bool = False
+    add_zero_attn: bool = False
+    causal: bool = False  # TPU-native addition (reference cuDNN op is unmasked)
+    impl: str = "xla"  # xla | flash | ring
 
 
 def _mha_infer(p: MultiHeadAttentionParams, in_shapes):
@@ -66,27 +153,7 @@ def _mha_infer(p: MultiHeadAttentionParams, in_shapes):
 
 def _mha_weights(p: MultiHeadAttentionParams, in_shapes):
     q, k, v = in_shapes[:3]
-    kdim, vdim = _mha_dims(p)
-    # per-head projection sizes follow attention.cc:70-80 (qProjSize = kdim/heads)
-    ws = [
-        WeightSpec("wq", (q[-1], p.embed_dim), DataType.DT_FLOAT),
-        WeightSpec("wk", (k[-1], p.embed_dim), DataType.DT_FLOAT),
-        WeightSpec("wv", (v[-1], p.embed_dim), DataType.DT_FLOAT),
-        WeightSpec("wo", (p.embed_dim, p.embed_dim), DataType.DT_FLOAT),
-    ]
-    if p.use_bias:
-        ws += [
-            WeightSpec("bq", (p.embed_dim,), DataType.DT_FLOAT, "zeros"),
-            WeightSpec("bk", (p.embed_dim,), DataType.DT_FLOAT, "zeros"),
-            WeightSpec("bv", (p.embed_dim,), DataType.DT_FLOAT, "zeros"),
-            WeightSpec("bo", (p.embed_dim,), DataType.DT_FLOAT, "zeros"),
-        ]
-    if p.qk_norm:
-        ws += [
-            WeightSpec("q_norm", (p.embed_dim,), DataType.DT_FLOAT, "ones"),
-            WeightSpec("k_norm", (p.embed_dim,), DataType.DT_FLOAT, "ones"),
-        ]
-    return ws
+    return p.front.weight_specs(q[-1], k[-1], v[-1])
 
 
 def rope_cos_sin(positions, head_dim: int, theta: float):
@@ -126,38 +193,20 @@ def sdpa_xla(q, k, v, *, causal: bool, scale: float):
 
 
 def _mha_forward(p: MultiHeadAttentionParams, inputs, weights, state, ctx):
-    q_in, k_in, v_in = inputs[:3]
-    H = p.num_heads
-    E = p.embed_dim
-    hd = E // H
-
-    def proj(x, w, b):
-        xm, wm = matmul_cast(ctx, x, w.astype(x.dtype))
-        y = jnp.dot(xm, wm, preferred_element_type=jnp.float32).astype(x.dtype)
-        if b is not None:
-            y = y + b.astype(y.dtype)
-        return y
-
-    q = proj(q_in, weights["wq"], weights.get("bq"))
-    k = proj(k_in, weights["wk"], weights.get("bk"))
-    v = proj(v_in, weights["wv"], weights.get("bv"))
-    scale = 1.0 / math.sqrt(hd)
-    # between the projections and the kernel, on the packed layout
-    if p.qk_norm:
-        from .core import rms_norm
-
-        q = rms_norm(q, weights["q_norm"], p.qk_norm_eps)
-        k = rms_norm(k, weights["k_norm"], p.qk_norm_eps)
-    if p.rope_theta:
-        cos, sin = rope_cos_sin(inputs[3], hd, p.rope_theta)
-        q = apply_rope(q, cos, sin, H)
-        k = apply_rope(k, cos, sin, H)
+    front = p.front
+    H = front.num_heads
+    q, k, v = front.qkv(ctx, weights, *inputs)  # positions fourth, with RoPE
+    scale = 1.0 / math.sqrt(front.head_dim)
 
     if p.impl == "flash":
-        # the plan's shards of the kernel's operands: batch as the output
-        # places it, heads as wq's columns are placed (head-parallel);
-        # each shard runs the kernel on its own batch rows and heads
+        # the packed kernels select heads with lane-offset block index
+        # maps, so the projections' (b, s, H·hd) output feeds them
+        # directly: no (b,s,h,d)→(b,h,s,d) HBM relayout in fwd or bwd.
+        # Each shard of the plan runs the kernel on its own batch rows and
+        # heads: batch as the output places it, heads as wq's columns are
+        # placed (head-parallel)
         from ..kernels.dispatch import per_shard, shards_of, spec_entries
+        from ..kernels.flash_attention import flash_attention_packed
 
         batch_ax = spec_entries(ctx.out_spec, 1)[0]
         head_ax = spec_entries((ctx.weight_axes or {}).get("wq"), 2)[1]
@@ -165,63 +214,37 @@ def _mha_forward(p: MultiHeadAttentionParams, inputs, weights, state, ctx):
             batch_ax = None
         if H % shards_of(ctx.mesh, head_ax):
             head_ax = None
-        h_local = H // shards_of(ctx.mesh, head_ax)
-
-    if p.impl == "flash" and getattr(ctx, "flash_packed", True):
-        # packed layout: the kernel selects heads with lane-offset block
-        # index maps, so the projections' (b, s, H·hd) output feeds it
-        # directly — no (b,s,h,d)→(b,h,s,d) HBM relayout in fwd OR bwd.
-        # ctx.flash_packed=False (--flash-transposed) forces the
-        # head-transposed kernels below — the relayout ablation baseline.
-        from ..kernels.flash_attention import flash_attention_packed
-
         spec = PartitionSpec(batch_ax, None, head_ax)
         out = per_shard(
-            functools.partial(flash_attention_packed, num_heads=h_local,
+            functools.partial(flash_attention_packed,
+                              num_heads=H // shards_of(ctx.mesh, head_ax),
                               causal=p.causal, scale=scale),
             ctx.mesh, (spec, spec, spec), spec)(q, k, v)
-        y = proj(out, weights["wo"], weights.get("bo"))
-        return [y], state
+        return [front.output(ctx, weights, out)], state
 
     def split_heads(x):
         b, s, _ = x.shape
-        return x.reshape(b, s, H, hd).transpose(0, 2, 1, 3)
+        return x.reshape(b, s, H, front.head_dim).transpose(0, 2, 1, 3)
 
     q, k, v = split_heads(q), split_heads(k), split_heads(v)
-
     if p.impl == "ring":
         from ..parallel.ring_attention import ring_attention
 
         out = ring_attention(q, k, v, causal=p.causal, scale=scale,
                              mesh=ctx.mesh,
                              overlap=getattr(ctx, "overlap_collectives", True))
-    elif p.impl == "flash":
-        # transposed-layout flash (flash_packed=False): same kernel math,
-        # but the head split/merge above materializes the
-        # (b,s,h,d)↔(b,h,s,d) relayouts the packed path avoids
-        from ..kernels.flash_attention import flash_attention
-
-        spec = PartitionSpec(batch_ax, head_ax)
-        out = per_shard(
-            functools.partial(flash_attention, causal=p.causal, scale=scale),
-            ctx.mesh, (spec, spec, spec), spec)(q, k, v)
     else:
         out = sdpa_xla(q, k, v, causal=p.causal, scale=scale)
-
     b, _, s, _ = out.shape
-    out = out.transpose(0, 2, 1, 3).reshape(b, s, E)
-    y = proj(out, weights["wo"], weights.get("bo"))
-    return [y], state
+    out = out.transpose(0, 2, 1, 3).reshape(b, s, front.embed_dim)
+    return [front.output(ctx, weights, out)], state
 
 
 def _mha_flops(p: MultiHeadAttentionParams, in_shapes, out_shapes):
     q, k, v = in_shapes[:3]
-    b, sq, dq = q
-    sk = k[1]
-    E = p.embed_dim
-    proj = 2.0 * b * (sq * dq * E + sk * k[2] * E + sk * v[2] * E + sq * E * E)
-    attn = 2.0 * b * p.num_heads * sq * sk * (E // p.num_heads) * 2
-    return proj + attn
+    b, sq, sk = q[0], q[1], k[1]
+    attn = 2.0 * b * p.num_heads * sq * sk * p.front.head_dim * 2
+    return p.front.linear_flops(b, sq, sk, q[2], k[2], v[2]) + attn
 
 
 register_op(

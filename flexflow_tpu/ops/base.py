@@ -62,10 +62,6 @@ class OpContext:
     # the ablation baseline matching the cost model's serial pricing
     # (FFConfig.overlap_collectives)
     overlap_collectives: bool = True
-    # False routes impl="flash" attention through the head-transposed
-    # kernels instead of the packed relayout-free path — the kernel-layout
-    # ablation baseline (FFConfig.flash_packed_layout)
-    flash_packed: bool = True
 
 
 def matmul_cast(ctx: OpContext, *arrays):

@@ -33,8 +33,10 @@ its host cursor: any stale row at or below a later call's query frontier
 is overwritten by that call's own scatter before it becomes readable,
 and rows beyond the frontier stay masked forever.
 
-Weight names match OP_MULTIHEAD_ATTENTION's (wq/wk/wv/wo + biases), so a
-trained model's parameters transfer to its decode graph by name. On TPU
+The front end (weights and their names, projections, head-parallel rule)
+is ops/attention.py's `AttentionFrontEnd`, held as `params.front`: the
+decode replay hands over a trained layer's whole, so its parameters
+transfer to the decode graph by name. On TPU
 the q_len=1 path routes through the Pallas decode kernel
 (kernels/flash_attention.flash_decode_attention); CPU meshes use the
 reference einsum so tier-1 exercises serving end-to-end. Nothing here
@@ -52,15 +54,14 @@ import jax
 import jax.numpy as jnp
 
 from ..fftype import DataType, OperatorType as OT
-from .base import OpDef, WeightSpec, matmul_cast, register_op
+from .attention import AttentionFrontEnd, FrontEndFields
+from .base import OpDef, WeightSpec, register_op
 
 
 @dataclass(frozen=True)
-class IncMultiHeadAttentionParams:
-    embed_dim: int
-    num_heads: int
+class IncMultiHeadAttentionParams(FrontEndFields):
+    front: AttentionFrontEnd
     max_seq_len: int  # real cache rows; row max_seq_len is the scratch row
-    use_bias: bool = True
     impl: str = "auto"  # auto: flash decode on TPU (q_len=1), einsum else
 
 
@@ -106,49 +107,21 @@ def _inc_mha_infer(p: IncMultiHeadAttentionParams, in_shapes):
 
 def _inc_mha_weights(p: IncMultiHeadAttentionParams, in_shapes):
     x = in_shapes[0]
-    slots = x[0]
-    ws = [
-        WeightSpec("wq", (x[-1], p.embed_dim), DataType.DT_FLOAT),
-        WeightSpec("wk", (x[-1], p.embed_dim), DataType.DT_FLOAT),
-        WeightSpec("wv", (x[-1], p.embed_dim), DataType.DT_FLOAT),
-        WeightSpec("wo", (p.embed_dim, p.embed_dim), DataType.DT_FLOAT),
-    ]
-    if p.use_bias:
-        ws += [
-            WeightSpec("bq", (p.embed_dim,), DataType.DT_FLOAT, "zeros"),
-            WeightSpec("bk", (p.embed_dim,), DataType.DT_FLOAT, "zeros"),
-            WeightSpec("bv", (p.embed_dim,), DataType.DT_FLOAT, "zeros"),
-            WeightSpec("bo", (p.embed_dim,), DataType.DT_FLOAT, "zeros"),
-        ]
     # the KV cache: stateful (non-trainable), zero-initialized, threaded
     # functionally through the executor's state dict like BatchNorm stats
-    ws += [
-        WeightSpec("cache_k", (slots, p.max_seq_len + 1, p.embed_dim),
-                   DataType.DT_FLOAT, "zeros", trainable=False),
-        WeightSpec("cache_v", (slots, p.max_seq_len + 1, p.embed_dim),
-                   DataType.DT_FLOAT, "zeros", trainable=False),
-    ]
-    return ws
+    cache = (x[0], p.max_seq_len + 1, p.embed_dim)
+    return p.front.weight_specs(x[-1], x[-1], x[-1]) + [
+        WeightSpec(name, cache, DataType.DT_FLOAT, "zeros", trainable=False)
+        for name in ("cache_k", "cache_v")]
 
 
 def _inc_mha_forward(p: IncMultiHeadAttentionParams, inputs, weights,
                      state, ctx):
     x, positions = inputs
     slots = x.shape[0]
-    H, E = p.num_heads, p.embed_dim
-    hd = E // H
-
-    def proj(t, w, b):
-        tm, wm = matmul_cast(ctx, t, w.astype(t.dtype))
-        y = jnp.dot(tm, wm, preferred_element_type=jnp.float32).astype(t.dtype)
-        if b is not None:
-            y = y + b.astype(y.dtype)
-        return y
-
-    q = proj(x, weights["wq"], weights.get("bq"))
-    k = proj(x, weights["wk"], weights.get("bk"))
-    v = proj(x, weights["wv"], weights.get("bv"))
-    scale = 1.0 / math.sqrt(hd)
+    H = p.num_heads
+    q, k, v = p.front.qkv(ctx, weights, x, x, x)
+    scale = 1.0 / math.sqrt(p.front.head_dim)
 
     ck, cv = weights["cache_k"], weights["cache_v"]
     positions = positions.astype(jnp.int32)
@@ -179,21 +152,21 @@ def _inc_mha_forward(p: IncMultiHeadAttentionParams, inputs, weights,
         out = decode_attention_reference(
             q, ck.astype(q.dtype), cv.astype(q.dtype), write_pos,
             num_heads=H, scale=scale)
-    y = proj(out, weights["wo"], weights.get("bo"))
-    return [y], {"cache_k": ck, "cache_v": cv}
+    return [p.front.output(ctx, weights, out)], {"cache_k": ck, "cache_v": cv}
 
 
 def _inc_mha_flops(p: IncMultiHeadAttentionParams, in_shapes, out_shapes):
-    x = in_shapes[0]
-    slots, q_len = x[0], x[1]
-    E = p.embed_dim
-    # four projections of the q_len new tokens + attention of each query
-    # against the full cache (the serving cost model prices the worst-case
-    # full-cache read; the kernel skips dead blocks at run time)
-    proj = 2.0 * slots * q_len * (3 * x[-1] * E + E * E)
-    attn = 2.0 * slots * p.num_heads * q_len * (p.max_seq_len + 1) * (
-        E // p.num_heads) * 2
-    return proj + attn
+    return _decode_flops(p.front, in_shapes[0], p.max_seq_len + 1)
+
+
+def _decode_flops(front: AttentionFrontEnd, x, cache_rows: int):
+    """Four projections of the q_len new tokens + attention of each query
+    against the full cache: the serving cost model prices the worst-case
+    read, the kernels skip dead blocks at run time."""
+    slots, q_len, d = x
+    attn = (2.0 * slots * front.num_heads * q_len * cache_rows
+            * front.head_dim * 2)
+    return front.linear_flops(slots, q_len, q_len, d, d, d) + attn
 
 
 register_op(OpDef(OT.OP_INC_MULTIHEAD_ATTENTION, _inc_mha_infer,
@@ -222,13 +195,11 @@ register_op(OpDef(OT.OP_INC_MULTIHEAD_ATTENTION, _inc_mha_infer,
 
 
 @dataclass(frozen=True)
-class PagedIncMultiHeadAttentionParams:
-    embed_dim: int
-    num_heads: int
+class PagedIncMultiHeadAttentionParams(FrontEndFields):
+    front: AttentionFrontEnd
     max_seq_len: int    # logical cache rows per slot (capacity)
     block_size: int     # pool rows per block
     num_blocks: int     # physical pool blocks, block 0 = reserved scratch
-    use_bias: bool = True
     impl: str = "auto"  # auto: paged flash decode on TPU (q_len=1)
 
     @property
@@ -268,29 +239,13 @@ def _paged_mha_infer(p: PagedIncMultiHeadAttentionParams, in_shapes):
 
 def _paged_mha_weights(p: PagedIncMultiHeadAttentionParams, in_shapes):
     x = in_shapes[0]
-    ws = [
-        WeightSpec("wq", (x[-1], p.embed_dim), DataType.DT_FLOAT),
-        WeightSpec("wk", (x[-1], p.embed_dim), DataType.DT_FLOAT),
-        WeightSpec("wv", (x[-1], p.embed_dim), DataType.DT_FLOAT),
-        WeightSpec("wo", (p.embed_dim, p.embed_dim), DataType.DT_FLOAT),
-    ]
-    if p.use_bias:
-        ws += [
-            WeightSpec("bq", (p.embed_dim,), DataType.DT_FLOAT, "zeros"),
-            WeightSpec("bk", (p.embed_dim,), DataType.DT_FLOAT, "zeros"),
-            WeightSpec("bv", (p.embed_dim,), DataType.DT_FLOAT, "zeros"),
-            WeightSpec("bo", (p.embed_dim,), DataType.DT_FLOAT, "zeros"),
-        ]
     # the block pool: ONE tensor per layer shared by every slot (a block
     # mapped into N page tables is stored once — the prefix-sharing win),
     # so per-chip accounting counts it once, not per slot
-    ws += [
-        WeightSpec("pool_k", (p.num_blocks, p.block_size, p.embed_dim),
-                   DataType.DT_FLOAT, "zeros", trainable=False),
-        WeightSpec("pool_v", (p.num_blocks, p.block_size, p.embed_dim),
-                   DataType.DT_FLOAT, "zeros", trainable=False),
-    ]
-    return ws
+    pool = (p.num_blocks, p.block_size, p.embed_dim)
+    return p.front.weight_specs(x[-1], x[-1], x[-1]) + [
+        WeightSpec(name, pool, DataType.DT_FLOAT, "zeros", trainable=False)
+        for name in ("pool_k", "pool_v")]
 
 
 def _paged_mha_forward(p: PagedIncMultiHeadAttentionParams, inputs, weights,
@@ -298,21 +253,10 @@ def _paged_mha_forward(p: PagedIncMultiHeadAttentionParams, inputs, weights,
     x, positions, page_table = inputs
     slots = x.shape[0]
     H, E = p.num_heads, p.embed_dim
-    hd = E // H
     bs = p.block_size
     W = p.blocks_per_slot
-
-    def proj(t, w, b):
-        tm, wm = matmul_cast(ctx, t, w.astype(t.dtype))
-        y = jnp.dot(tm, wm, preferred_element_type=jnp.float32).astype(t.dtype)
-        if b is not None:
-            y = y + b.astype(y.dtype)
-        return y
-
-    q = proj(x, weights["wq"], weights.get("bq"))
-    k = proj(x, weights["wk"], weights.get("bk"))
-    v = proj(x, weights["wv"], weights.get("bv"))
-    scale = 1.0 / math.sqrt(hd)
+    q, k, v = p.front.qkv(ctx, weights, x, x, x)
+    scale = 1.0 / math.sqrt(p.front.head_dim)
 
     pk, pv = weights["pool_k"], weights["pool_v"]
     positions = positions.astype(jnp.int32)
@@ -353,22 +297,13 @@ def _paged_mha_forward(p: PagedIncMultiHeadAttentionParams, inputs, weights,
         read_pos = jnp.where(live, pos_c, -1)
         out = decode_attention_reference(
             q, kc, vc, read_pos, num_heads=H, scale=scale)
-    y = proj(out, weights["wo"], weights.get("bo"))
-    return [y], {"pool_k": pk, "pool_v": pv}
+    return [p.front.output(ctx, weights, out)], {"pool_k": pk, "pool_v": pv}
 
 
 def _paged_mha_flops(p: PagedIncMultiHeadAttentionParams, in_shapes,
                      out_shapes):
-    x = in_shapes[0]
-    slots, q_len = x[0], x[1]
-    E = p.embed_dim
-    # same shape as the contiguous op's count: projections of the new
-    # tokens + worst-case full-capacity cache read per query (the kernel
-    # skips dead blocks at run time; the pricer keeps the upper bound)
-    proj = 2.0 * slots * q_len * (3 * x[-1] * E + E * E)
-    attn = 2.0 * slots * p.num_heads * q_len * (
-        p.blocks_per_slot * p.block_size) * (E // p.num_heads) * 2
-    return proj + attn
+    return _decode_flops(p.front, in_shapes[0],
+                         p.blocks_per_slot * p.block_size)
 
 
 register_op(OpDef(OT.OP_PAGED_INC_MULTIHEAD_ATTENTION, _paged_mha_infer,

@@ -180,13 +180,9 @@ def megatron_transformer(model, model_axis: str = AXIS_MODEL) -> Strategy:
 
     for l in layers:
         if l.op_type == OT.OP_MULTIHEAD_ATTENTION:
-            # QKV column-parallel (heads split over model axis), O row-parallel
-            for w in ("wq", "wk", "wv"):
-                s.set_weight(l.name, w, PartitionSpec(None, model_axis))
-            for b in ("bq", "bk", "bv"):
-                s.set_weight(l.name, b, PartitionSpec(model_axis))
-            s.set_weight(l.name, "wo", PartitionSpec(model_axis, None))
-            s.set_weight(l.name, "bo", PartitionSpec())
+            # heads split over the model axis (ops/attention.py's rule)
+            for w, spec in l.params.front.head_parallel(model_axis):
+                s.set_weight(l.name, w, spec)
             # output fully materialized (psum) with batch sharded
             nd = len(l.outputs[0].dims)
             s.set_output(l.name, 0, _act_assignment(nd))

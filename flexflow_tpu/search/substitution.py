@@ -592,14 +592,14 @@ def _attention_parallel(node, in_shapes, wp: dict):
     out_dims = [replace(d) for d in logical[:-1]]
     out_dims.append(ParallelDim(node.params.embed_dim))
     if r > 1:
-        if node.params.num_heads % r != 0:
+        front = node.params.front
+        if not front.head_parallel_ok(r):
             raise ValueError(
-                f"{node.name}: num_heads {node.params.num_heads} % {r} != 0")
-        for w in ("wq", "wk", "wv"):
-            wp[w] = (1, r)
-        for b in ("bq", "bk", "bv"):
-            wp[b] = (0, r)
-        wp["wo"] = (0, r)
+                f"{node.name}: num_heads {front.num_heads} % {r} != 0")
+        # the front end's rule as (sharded dim, degree)
+        for w, spec in front.head_parallel(AXIS_MODEL):
+            if AXIS_MODEL in spec:
+                wp[w] = (spec.index(AXIS_MODEL), r)
         out_dims.append(ParallelDim(r, r, is_replica_dim=True,
                                     axes=replicas[0].axes))
     return ParallelTensorShape(tuple(out_dims), q.dtype)

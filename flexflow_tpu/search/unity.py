@@ -203,19 +203,33 @@ class UnitySearch:
                  ("bias", PartitionSpec())),
                 psum_axes=(AXIS_MODEL,),
             ))
-        elif node.op_type == OT.OP_MULTIHEAD_ATTENTION:
+        elif node.op_type in (OT.OP_MULTIHEAD_ATTENTION,
+                              OT.OP_INC_MULTIHEAD_ATTENTION,
+                              OT.OP_PAGED_INC_MULTIHEAD_ATTENTION):
             p = node.params
-            if allow_attr and p.num_heads % self.model_deg == 0:
-                ws = [(w, PartitionSpec(None, AXIS_MODEL))
-                      for w in ("wq", "wk", "wv")]
-                ws += [(b, PartitionSpec(AXIS_MODEL))
-                       for b in ("bq", "bk", "bv")]
-                ws += [("wo", PartitionSpec(AXIS_MODEL, None)),
-                       ("bo", PartitionSpec())]
+            if allow_attr and p.front.head_parallel_ok(self.model_deg):
+                # head-parallel attention: the front end's rule (QKV
+                # column-parallel, O row-parallel, psum) and, for the
+                # decode ops, the serving-specific dim: the KV cache's
+                # feature axis sharded over `model` so each chip stores
+                # and scans only its own heads' cache rows. The KV-cache
+                # placement is thereby a searched parallel dim priced by
+                # the same cost model as the projections. Contiguous
+                # caches additionally ride the batch axes on their slot
+                # dim; the paged POOL's leading dim is slot-agnostic
+                # physical blocks (shared by prefix reuse), so only its
+                # feature dim shards.
+                paged = node.op_type == OT.OP_PAGED_INC_MULTIHEAD_ATTENTION
+                cache = PartitionSpec(
+                    None if paged else
+                    (self._batch_entry() if batch_ok else None),
+                    None, AXIS_MODEL)
                 out.append(NodeConfig(
                     "tp_attn",
                     _dp_assign(ndim, batch_ok, batch_axes=self.batch_axes),
-                    tuple(ws),
+                    (*p.front.head_parallel(AXIS_MODEL),
+                     *((w.name, cache) for w in node.weight_specs
+                       if not w.trainable)),
                     psum_axes=(AXIS_MODEL,),
                 ))
             if (getattr(p, "impl", "") == "ring" and ndim == 3
@@ -231,39 +245,6 @@ class UnitySearch:
                                          batch_axes=self.batch_axes))
                 assign[1] = (AXIS_SEQ,)
                 out.append(NodeConfig("sp", tuple(assign)))
-        elif node.op_type in (OT.OP_INC_MULTIHEAD_ATTENTION,
-                              OT.OP_PAGED_INC_MULTIHEAD_ATTENTION):
-            p = node.params
-            if (allow_attr and p.num_heads % self.model_deg == 0
-                    and p.embed_dim % self.model_deg == 0):
-                # head-parallel decode attention: QKV column-parallel, O
-                # row-parallel (psum), and — the serving-specific dim —
-                # the KV cache's feature axis sharded over `model` so each
-                # chip stores and scans only its own heads' cache rows.
-                # The KV-cache placement is thereby a searched parallel
-                # dim priced by the same cost model as the projections.
-                # Contiguous caches additionally ride the batch axes on
-                # their slot dim; the paged POOL's leading dim is
-                # slot-agnostic physical blocks (shared by prefix reuse),
-                # so only its feature dim shards.
-                paged = node.op_type == OT.OP_PAGED_INC_MULTIHEAD_ATTENTION
-                ws = [(w, PartitionSpec(None, AXIS_MODEL))
-                      for w in ("wq", "wk", "wv")]
-                ws += [(b, PartitionSpec(AXIS_MODEL))
-                       for b in ("bq", "bk", "bv")]
-                ws += [("wo", PartitionSpec(AXIS_MODEL, None)),
-                       ("bo", PartitionSpec())]
-                ws += [(w.name, PartitionSpec(
-                            None if paged else
-                            (self._batch_entry() if batch_ok else None),
-                            None, AXIS_MODEL))
-                       for w in node.weight_specs if not w.trainable]
-                out.append(NodeConfig(
-                    "tp_attn",
-                    _dp_assign(ndim, batch_ok, batch_axes=self.batch_axes),
-                    tuple(ws),
-                    psum_axes=(AXIS_MODEL,),
-                ))
         elif node.op_type == OT.OP_CONV2D and allow_attr and ndim == 4:
             # channel/attribute-parallel conv (NCHW dim 1 over `model`,
             # OIHW kernel dim 0 sharded) — the conv sibling of tp_attn

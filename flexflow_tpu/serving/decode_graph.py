@@ -3,8 +3,10 @@
 The serving engine does NOT fork the model definition: it replays the
 trained FFModel's layer list into a fresh FFModel whose inputs are
 (slots, 1)-shaped — one new token per continuous-batching slot — and
-whose causal `multihead_attention` layers become `inc_multihead_attention`
-over per-layer KV-cache state (ops/inc_attention.py). Everything else
+whose causal attention layers become the incremental attention ops over
+per-layer KV-cache state (ops/inc_attention.py), each given the trained
+layer's front end (ops/attention.py) as one value. This replay is the one
+way a decode graph is made. Everything else
 (embeddings, norms, MLPs, residuals, tied weights) replays verbatim with
 the SAME layer names, so:
 
@@ -161,6 +163,9 @@ def build_decode_model(model, spec: ServingSpec):
     cross-attention (decode needs self-attention with a causal order), and
     ops whose shape inference rejects (slots, 1, ...) activations."""
     from ..model import FFModel
+    from ..ops import (
+        IncMultiHeadAttentionParams, PagedIncMultiHeadAttentionParams,
+    )
     from ..optimizer import SGDOptimizer
 
     if spec.kv_layout not in ("contiguous", "paged"):
@@ -236,31 +241,27 @@ def build_decode_model(model, spec: ServingSpec):
                 raise ValueError(
                     f"{layer.name}: kdim/vdim != embed_dim not supported "
                     f"in the decode graph")
-            if p.rope_theta or p.qk_norm:
+            if p.front.rope_theta or p.front.qk_norm:
                 raise NotImplementedError(
                     f"{layer.name}: rotary positions and QK-norm are not "
                     f"in the incremental attention ops yet, so this model "
                     f"trains but does not serve")
+            # the trained layer's front end goes to the decode op whole
             if paged:
-                from ..ops import PagedIncMultiHeadAttentionParams
-
-                np_ = PagedIncMultiHeadAttentionParams(
-                    p.embed_dim, p.num_heads, max_seq,
-                    spec.kv_block_size, num_blocks, p.use_bias,
-                    impl=spec.impl)
-                new = dec._add_layer(
-                    OT.OP_PAGED_INC_MULTIHEAD_ATTENTION, np_,
-                    [ins[0], positions, page_table],
-                    name=layer.name, data_type=layer.data_type)
+                op, np_, feeds = (
+                    OT.OP_PAGED_INC_MULTIHEAD_ATTENTION,
+                    PagedIncMultiHeadAttentionParams(
+                        p.front, max_seq, spec.kv_block_size, num_blocks,
+                        impl=spec.impl),
+                    [ins[0], positions, page_table])
             else:
-                from ..ops import IncMultiHeadAttentionParams
-
-                np_ = IncMultiHeadAttentionParams(
-                    p.embed_dim, p.num_heads, max_seq, p.use_bias,
-                    impl=spec.impl)
-                new = dec._add_layer(
-                    OT.OP_INC_MULTIHEAD_ATTENTION, np_, [ins[0], positions],
-                    name=layer.name, data_type=layer.data_type)
+                op, np_, feeds = (
+                    OT.OP_INC_MULTIHEAD_ATTENTION,
+                    IncMultiHeadAttentionParams(p.front, max_seq,
+                                                impl=spec.impl),
+                    [ins[0], positions])
+            new = dec._add_layer(op, np_, feeds, name=layer.name,
+                                 data_type=layer.data_type)
         else:
             new = dec._add_layer(
                 layer.op_type, layer.params, ins, name=layer.name,

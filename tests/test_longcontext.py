@@ -209,14 +209,14 @@ def test_allgather_matmul_parity(overlap):
 
 
 def test_ablation_flags_reach_the_op(monkeypatch):
-    """`--no-overlap-collectives` / `--flash-transposed` must flip the
-    COMPILED schedule, not just the cost model's pricing: the flags flow
-    FFConfig → OpContext → the attention op's kernel/ring dispatch.
-    Captured at the op seam so the test is cheap and pins the plumbing."""
+    """`--no-overlap-collectives` must flip the COMPILED schedule, not
+    just the cost model's pricing: the flag flows FFConfig → OpContext →
+    the attention op's ring dispatch; and impl="flash" runs the packed
+    kernels. Captured at the op seam so the test is cheap and pins the
+    plumbing."""
     from flexflow_tpu.executor import OpContext
-    from flexflow_tpu.ops import attention as attn_mod
     from flexflow_tpu.ops.attention import (
-        MultiHeadAttentionParams, _mha_forward,
+        AttentionFrontEnd, MultiHeadAttentionParams, _mha_forward,
     )
 
     seen = {}
@@ -229,10 +229,6 @@ def test_ablation_flags_reach_the_op(monkeypatch):
         seen["layout"] = "packed"
         return jnp.zeros_like(q)
 
-    def fake_transposed(q, k, v, *, causal, scale):
-        seen["layout"] = "transposed"
-        return jnp.zeros_like(q)
-
     # importlib: the kernels package re-exports `flash_attention` the
     # function, which shadows the submodule on attribute-style imports
     import importlib
@@ -240,35 +236,32 @@ def test_ablation_flags_reach_the_op(monkeypatch):
     fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
     ra = importlib.import_module("flexflow_tpu.parallel.ring_attention")
 
+    # the op imports the seams at call time
     monkeypatch.setattr(ra, "ring_attention", fake_ring)
     monkeypatch.setattr(fa, "flash_attention_packed", fake_packed)
-    monkeypatch.setattr(fa, "flash_attention", fake_transposed)
-    assert attn_mod  # the op imports the seams at call time
 
     E, H = 16, 2
     rs = np.random.RandomState(0)
     x = jnp.asarray(rs.randn(2, 8, E), jnp.float32)
     w = {n: jnp.asarray(rs.randn(E, E), jnp.float32)
-         for n in ("wq", "wk", "wv", "wo")}
+         for n in AttentionFrontEnd.kernels}
     w.update({n: jnp.zeros((E,), jnp.float32)
               for n in ("bq", "bk", "bv", "bo")})
 
     for impl, ctx_kw, expect in (
         ("ring", {"overlap_collectives": False}, ("ring_overlap", False)),
         ("ring", {"overlap_collectives": True}, ("ring_overlap", True)),
-        ("flash", {"flash_packed": True}, ("layout", "packed")),
-        ("flash", {"flash_packed": False}, ("layout", "transposed")),
+        ("flash", {}, ("layout", "packed")),
     ):
         seen.clear()
-        p = MultiHeadAttentionParams(embed_dim=E, num_heads=H, impl=impl)
+        p = MultiHeadAttentionParams(AttentionFrontEnd(E, H), impl=impl)
         _mha_forward(p, (x, x, x), w, None, OpContext(**ctx_kw))
         key, val = expect
         assert seen.get(key) == val, (impl, ctx_kw, seen)
 
-    # and the FFConfig flags parse into the fields the executor forwards
+    # and the FFConfig flag parses into the field the executor forwards
     from flexflow_tpu import FFConfig
 
     c = FFConfig()
-    c.parse_args(["--no-overlap-collectives", "--flash-transposed"])
+    c.parse_args(["--no-overlap-collectives"])
     assert c.overlap_collectives is False
-    assert c.flash_packed_layout is False

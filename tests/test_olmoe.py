@@ -18,7 +18,7 @@ from flexflow_tpu import (
     AdamOptimizer, FFConfig, FFModel, LossType, MetricsType, SGDOptimizer,
 )
 from flexflow_tpu.models import (
-    TransformerLMConfig, build_transformer_lm, build_transformer_lm_decode,
+    TransformerLMConfig, build_transformer_lm,
     olmoe_lm_config, olmoe_reference as ref,
 )
 from flexflow_tpu.ops import attention as attn_ops
@@ -179,8 +179,9 @@ def test_attention_with_qk_norm_and_rope_alone(impl):
     w["q_norm"] = jnp.asarray(rng.uniform(0.5, 1.5, d), jnp.float32)
     w["k_norm"] = jnp.asarray(rng.uniform(0.5, 1.5, d), jnp.float32)
     p = attn_ops.MultiHeadAttentionParams(
-        d, h, use_bias=False, causal=True, impl=impl, rope_theta=10000.0,
-        qk_norm=True)
+        attn_ops.AttentionFrontEnd(d, h, use_bias=False, rope_theta=10000.0,
+                                   qk_norm=True),
+        causal=True, impl=impl)
     (got,), _ = attn_ops._mha_forward(p, [x, x, x, pos], w, None,
                                       OpContext())
     with jax.default_matmul_precision("highest"):
@@ -316,7 +317,7 @@ def test_gpt2_values_give_the_graph_and_weight_names_as_before():
         if n not in ("tokens", "positions", "embed_add")
         and not n.endswith(("res1", "res2", "gelu")))
     attn = ff.layers[4]
-    assert len(attn.inputs) == 3 and not attn.params.rope_theta
+    assert len(attn.inputs) == 3 and not attn.params.front.rope_theta
 
 
 @pytest.mark.parametrize("field", ["norm", "position", "mlp"])
@@ -328,14 +329,6 @@ def test_block_fields_are_checked(field):
 def test_olmoe_trains_but_does_not_serve_yet(olmoe):
     with pytest.raises(NotImplementedError, match="rotary"):
         olmoe.serve(slots=2, max_new_tokens=2)
-    argv = sys.argv
-    sys.argv = ["t"]
-    try:
-        with pytest.raises(NotImplementedError, match="GPT-2 block"):
-            build_transformer_lm_decode(FFModel(FFConfig()),
-                                        olmoe_lm_config(**SIZES), slots=2)
-    finally:
-        sys.argv = argv
 
 
 def test_olmoe_fits_and_the_loss_falls():

@@ -340,53 +340,45 @@ def test_serving_telemetry_artifacts(tmp_path):
 
 
 @pytest.mark.parametrize("layout", ["paged", "contiguous"])
-def test_model_zoo_decode_builder_matches_replay(layout):
-    """models.build_transformer_lm_decode expresses the same decode graph
-    the serving replay derives — for BOTH KV layouts: same node names, op
-    types, and cache/pool shapes — the zoo can build the decode graph
-    without forking the training definition."""
-    sys.argv = ["test"]
-    from flexflow_tpu import CompMode, FFConfig, FFModel, LossType, SGDOptimizer
+def test_decode_replay_signature(layout):
+    """The replay (serving/decode_graph.py, the one way a decode graph is
+    made) keeps the training graph's node names, turns each attention
+    node into the layout's decode op around the trained layer's own front
+    end, and sizes the cache at capacity parity: a scratch row a slot, or
+    slots * ceil(max_seq / block) blocks and the scratch block."""
     from flexflow_tpu.fftype import OperatorType as OT
-    from flexflow_tpu.models import build_transformer_lm_decode
     from flexflow_tpu.serving import ServingSpec, build_decode_model
 
     c = _lm_config()
     ff = _build_lm(batch=1)
     dec, max_seq = build_decode_model(
         ff, ServingSpec(slots=2, kv_layout=layout))
-    assert max_seq == c.sequence_length
+    assert max_seq == c.sequence_length == 32
 
-    cfg = FFConfig()
-    cfg.mesh_axis_sizes = (1, 1, 1, 1)
-    zoo = FFModel(cfg)
-    build_transformer_lm_decode(zoo, c, slots=2, kv_layout=layout)
-    zoo.compile(optimizer=SGDOptimizer(lr=0.0),
-                loss_type=LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY,
-                comp_mode=CompMode.COMP_MODE_INFERENCE)
-
-    def sig(model):
-        return [(n.name, n.op_type.name,
-                 tuple(tuple(ws.shape) for ws in n.weight_specs
-                       if not ws.trainable))
-                for n in model.graph.topo_order()]
-
-    assert sig(zoo) == sig(dec)
-    if layout == "paged":
-        attn = [n for n in zoo.graph.topo_order()
-                if n.op_type == OT.OP_PAGED_INC_MULTIHEAD_ATTENTION]
-        assert len(attn) == c.num_layers
-        pool = next(ws for ws in attn[0].weight_specs if not ws.trainable)
-        # capacity parity + scratch: slots * ceil(max_seq/bs) + 1 blocks
-        bs = cfg.serve_kv_block_size
-        assert pool.shape == (2 * (c.sequence_length // bs) + 1, bs,
-                              c.hidden_size)
-    else:
-        attn = [n for n in zoo.graph.topo_order()
-                if n.op_type == OT.OP_INC_MULTIHEAD_ATTENTION]
-        assert len(attn) == c.num_layers
-        cache = next(ws for ws in attn[0].weight_specs if not ws.trainable)
-        assert cache.shape == (2, c.sequence_length + 1, c.hidden_size)
+    train = ff.graph.topo_order()
+    nodes = dec.graph.topo_order()
+    # the one node the replay adds: the page tables, an input
+    extra = [n.name for n in nodes if n.op_type == OT.OP_INPUT
+             and n.name not in ("tokens", "positions")]
+    assert extra == (["page_table"] if layout == "paged" else [])
+    nodes = [n for n in nodes if n.name not in extra]
+    assert [n.name for n in nodes] == [n.name for n in train]
+    decode_op, cache_shape = {
+        "paged": (OT.OP_PAGED_INC_MULTIHEAD_ATTENTION, (5, 16, 32)),
+        "contiguous": (OT.OP_INC_MULTIHEAD_ATTENTION, (2, 33, 32)),
+    }[layout]
+    assert dec.config.serve_kv_block_size == 16
+    attn = 0
+    for t, d in zip(train, nodes):
+        if t.op_type != OT.OP_MULTIHEAD_ATTENTION:
+            assert d.op_type == t.op_type, d.name
+            continue
+        attn += 1
+        assert d.op_type == decode_op, d.name
+        assert d.params.front is t.params.front
+        assert [tuple(ws.shape) for ws in d.weight_specs
+                if not ws.trainable] == [cache_shape] * 2
+    assert attn == c.num_layers == 2
 
 
 # ===================================================================== paged
@@ -592,11 +584,13 @@ def test_paged_scratch_block_guard():
 
     from flexflow_tpu.ops.base import OpContext, get_op_def
     from flexflow_tpu.fftype import OperatorType as OT
-    from flexflow_tpu.ops import PagedIncMultiHeadAttentionParams
+    from flexflow_tpu.ops import (
+        AttentionFrontEnd, PagedIncMultiHeadAttentionParams,
+    )
 
     E, H, bs, nb, max_seq = 8, 2, 4, 5, 16
-    p = PagedIncMultiHeadAttentionParams(E, H, max_seq, bs, nb,
-                                         use_bias=False, impl="xla")
+    p = PagedIncMultiHeadAttentionParams(
+        AttentionFrontEnd(E, H, use_bias=False), max_seq, bs, nb, impl="xla")
     rs = np.random.RandomState(0)
     weights = {w: jnp.asarray(rs.randn(E, E), jnp.float32)
                for w in ("wq", "wk", "wv", "wo")}
