@@ -175,6 +175,29 @@ class Executor:
             and (jax.default_backend() == "tpu" or config.force_tensor_op_math)
             else None
         )
+        # At-rest dtype of every weight, trainable or state: what
+        # init_variables allocates and what every step leaves —
+        # rest_dtypes[(node, weight)], the WeightSpec's declared dtype.
+        # One exception: an inference compile (a serving decode graph)
+        # under a compute dtype holds its floating parameters in that
+        # dtype. Nothing updates them, so fp32 masters would only be
+        # re-read and re-cast by every step; adopt_params casts each
+        # trained weight once on the way in and _cast_compute at first
+        # use then finds nothing to do. A training compile keeps fp32
+        # masters.
+        narrow_params = (
+            self.compute_dtype is not None
+            and config.computation_mode == CompMode.COMP_MODE_INFERENCE)
+        self.rest_dtypes: dict[tuple[str, str], Any] = {}
+        for node in self.order:
+            if getattr(node, "weight_source", None):
+                continue
+            for ws in node.weight_specs:
+                dt = dtype_to_jnp(ws.dtype)
+                if (narrow_params and ws.trainable
+                        and jnp.issubdtype(dt, jnp.floating)):
+                    dt = self.compute_dtype
+                self.rest_dtypes[(node.name, ws.name)] = dt
         self._train_step = None
         self._eval_step = None
         self._forward_fn = None
@@ -572,15 +595,23 @@ class Executor:
         return jnp.tile(labels, reps)
 
     def _restore_state_dtypes(self, new_state):
-        """Non-trainable state (running stats) is kept fp32 across steps so
-        its dtype — and therefore the jitted step signature — is stable."""
+        """Non-trainable state leaves a step in the dtype it was declared
+        in (rest_dtypes: fp32 for running statistics, the compute dtype
+        for a KV cache a decode graph declared so; fp32 for a floating
+        leaf an op hands back undeclared), so its dtype — and therefore
+        the jitted step signature — is stable."""
         if self.compute_dtype is None:
             return new_state
-        return jax.tree.map(
-            lambda x: x.astype(jnp.float32)
-            if jnp.issubdtype(jnp.asarray(x).dtype, jnp.floating) else x,
-            new_state,
-        )
+
+        def restore(node, wname, leaf):
+            dt = self.rest_dtypes.get((node, wname), jnp.float32)
+            return jax.tree.map(
+                lambda x: x.astype(dt)
+                if jnp.issubdtype(jnp.asarray(x).dtype, jnp.floating) else x,
+                leaf)
+
+        return {node: {w: restore(node, w, leaf) for w, leaf in ws.items()}
+                for node, ws in new_state.items()}
 
     # ------------------------------------------------------------ variables
 
@@ -598,7 +629,8 @@ class Executor:
                     ws.name, initializer_by_name(ws.initializer)
                 )
                 key = _stable_fold(rng, f"{node.name}/{ws.name}")
-                arr = init(key, ws.shape, dtype_to_jnp(ws.dtype))
+                arr = init(key, ws.shape,
+                           self.rest_dtypes[(node.name, ws.name)])
                 # at-rest layout. Under weight-update sharding the fp32
                 # master lives 1/dp-sharded — stage 2: consumers
                 # all-gather at first use (GSPMD, fused with their
@@ -682,7 +714,9 @@ class Executor:
                     # own weights, so XLA fuses the downcast into the
                     # first use instead of writing a model-sized bf16
                     # copy to HBM up front (state stays uncast — ops own
-                    # their fp32-statistics handling)
+                    # their fp32-statistics handling). An inference
+                    # compile's parameters rest in the compute dtype
+                    # (rest_dtypes): nothing is cast
                     weights.update(self._cast_compute(p_own))
                     weights.update(new_state.get(wsrc, {}))
                     outs, op_state = node.op_def.forward(

@@ -63,6 +63,10 @@ class IncMultiHeadAttentionParams(FrontEndFields):
     front: AttentionFrontEnd
     max_seq_len: int  # real cache rows; row max_seq_len is the scratch row
     impl: str = "auto"  # auto: flash decode on TPU (q_len=1), einsum else
+    # what the cache rests in: build_decode_model fills it with the
+    # model's compute dtype, so attention reads the cache as it lies
+    # (K and V leave the projections in that dtype already)
+    cache_dtype: DataType = DataType.DT_FLOAT
 
 
 def _kernel_asked(impl: str) -> bool:
@@ -111,7 +115,7 @@ def _inc_mha_weights(p: IncMultiHeadAttentionParams, in_shapes):
     # functionally through the executor's state dict like BatchNorm stats
     cache = (x[0], p.max_seq_len + 1, p.embed_dim)
     return p.front.weight_specs(x[-1], x[-1], x[-1]) + [
-        WeightSpec(name, cache, DataType.DT_FLOAT, "zeros", trainable=False)
+        WeightSpec(name, cache, p.cache_dtype, "zeros", trainable=False)
         for name in ("cache_k", "cache_v")]
 
 
@@ -201,6 +205,7 @@ class PagedIncMultiHeadAttentionParams(FrontEndFields):
     block_size: int     # pool rows per block
     num_blocks: int     # physical pool blocks, block 0 = reserved scratch
     impl: str = "auto"  # auto: paged flash decode on TPU (q_len=1)
+    cache_dtype: DataType = DataType.DT_FLOAT  # as the contiguous op's
 
     @property
     def blocks_per_slot(self) -> int:
@@ -214,7 +219,8 @@ def paged_rows_run_kernel(p: PagedIncMultiHeadAttentionParams, mesh,
     kernel, however many rows it has: the op's own gates (_kernel_asked,
     _call_gate) and the kernel wrapper's (paged_decode_gate), asked once
     and without a warning. `itemsize`: bytes of a pool element as the
-    kernel reads it (the op casts the pool to the queries' dtype). The
+    kernel reads it (the queries' dtype: a pool declared in it is read
+    as it lies, a wider one is cast first). The
     serving engine chooses a chunk step's batch layout by this
     (serving/engine.py): single-query rows pay only where the kernel
     walks each row's pages; through the gather-and-einsum reference every
@@ -244,7 +250,7 @@ def _paged_mha_weights(p: PagedIncMultiHeadAttentionParams, in_shapes):
     # so per-chip accounting counts it once, not per slot
     pool = (p.num_blocks, p.block_size, p.embed_dim)
     return p.front.weight_specs(x[-1], x[-1], x[-1]) + [
-        WeightSpec(name, pool, DataType.DT_FLOAT, "zeros", trainable=False)
+        WeightSpec(name, pool, p.cache_dtype, "zeros", trainable=False)
         for name in ("pool_k", "pool_v")]
 
 

@@ -27,7 +27,13 @@ import copy
 from dataclasses import dataclass, field
 from typing import Optional
 
-from ..fftype import CompMode, DataType, LossType, OperatorType as OT
+from ..fftype import (
+    CompMode, DataType, LossType, OperatorType as OT, dtype_to_jnp,
+)
+
+
+# the per-layer KV cache's state leaves, paged and contiguous
+KV_LEAVES = ("pool_k", "pool_v", "cache_k", "cache_v")
 
 
 @dataclass
@@ -103,15 +109,16 @@ def _decode_config(model, spec: ServingSpec):
     return cfg
 
 
-def resolve_pool_blocks(model, spec: ServingSpec, max_seq: int) -> int:
+def resolve_pool_blocks(model, spec: ServingSpec, max_seq: int,
+                        at_rest: DataType) -> int:
     """Physical block count for the paged pool (incl. the reserved scratch
     block 0). spec.kv_num_blocks > 0 pins it; 0 sizes the pool from the
     per-chip HBM budget — the machine model's chip capacity minus the
     decode graph's non-pool footprint (the trained weights that transfer
-    by name — the same number the ffcheck liveness pass charges as
-    persistent weight bytes) — capped at contiguous capacity parity
-    (every slot can reach max_seq), floored at one block per slot so the
-    engine can always make progress."""
+    by name, at the `at_rest` dtype the decode graph holds them in) —
+    capped at contiguous capacity parity (every slot can reach max_seq),
+    floored at one block per slot so the engine can always make
+    progress. A block is priced in `at_rest` too."""
     bs = spec.kv_block_size
     if bs < 1:
         raise ValueError(f"kv_block_size must be >= 1, got {bs}")
@@ -124,17 +131,20 @@ def resolve_pool_blocks(model, spec: ServingSpec, max_seq: int) -> int:
         return spec.kv_num_blocks
     capacity = spec.slots * table_width + 1
     try:
-        import numpy as np
+        import jax.numpy as jnp
 
         from ..search.machine_model import machine_model_for_mesh
 
         hbm = machine_model_for_mesh(model.mesh).chip.hbm_bytes
+        itemsize = jnp.dtype(dtype_to_jnp(at_rest)).itemsize
         weight_bytes = sum(
-            np.asarray(w).size * np.asarray(w).dtype.itemsize
+            w.size * (itemsize if jnp.issubdtype(w.dtype, jnp.floating)
+                      else w.dtype.itemsize)
             for ws in (model._params or {}).values() for w in ws.values())
         attn = [l for l in model.layers
                 if l.op_type == OT.OP_MULTIHEAD_ATTENTION]
-        block_bytes = sum(2 * bs * l.params.embed_dim * 4 for l in attn)
+        block_bytes = sum(2 * bs * l.params.embed_dim * itemsize
+                          for l in attn)
         if block_bytes <= 0:
             return capacity
         budget = 0.9 * hbm - weight_bytes
@@ -174,8 +184,15 @@ def build_decode_model(model, spec: ServingSpec):
             f"{spec.kv_layout!r}")
     max_seq = spec.max_seq_len or infer_max_seq_len(model)
     paged = spec.kv_layout == "paged"
-    num_blocks = resolve_pool_blocks(model, spec, max_seq) if paged else 0
     dec = FFModel(_decode_config(model, spec))
+    # what the decode graph's tensors rest in: the compute dtype where
+    # the config sets one, so that no step casts what it reads every
+    # token (the executor holds an inference compile's parameters so;
+    # the KV cache is declared so here), float32 otherwise. The trained
+    # model's fp32 masters are another model's and stay as they are.
+    at_rest = dec.config.computation_dtype or DataType.DT_FLOAT
+    num_blocks = (resolve_pool_blocks(model, spec, max_seq, at_rest)
+                  if paged else 0)
 
     # --- inputs: (batch, seq, ...) → (slots, 1, ...); the `positions`
     # input doubles as every attention layer's position feed
@@ -252,13 +269,14 @@ def build_decode_model(model, spec: ServingSpec):
                     OT.OP_PAGED_INC_MULTIHEAD_ATTENTION,
                     PagedIncMultiHeadAttentionParams(
                         p.front, max_seq, spec.kv_block_size, num_blocks,
-                        impl=spec.impl),
+                        impl=spec.impl, cache_dtype=at_rest),
                     [ins[0], positions, page_table])
             else:
                 op, np_, feeds = (
                     OT.OP_INC_MULTIHEAD_ATTENTION,
                     IncMultiHeadAttentionParams(p.front, max_seq,
-                                                impl=spec.impl),
+                                                impl=spec.impl,
+                                                cache_dtype=at_rest),
                     [ins[0], positions])
             new = dec._add_layer(op, np_, feeds, name=layer.name,
                                  data_type=layer.data_type)
@@ -280,36 +298,39 @@ def build_decode_model(model, spec: ServingSpec):
 
 
 def adopt_params(dec, model) -> int:
-    """Move the trained model's parameters into the decode model by
-    (node, weight) name, re-placed under the decode plan's shardings
-    (set_weight device_puts with the decode-side sharding). Non-trainable
-    state with a matching name/shape (e.g. BatchNorm stats) transfers
-    too; the KV caches keep their zero init. Returns weights adopted."""
-    import numpy as np
+    """Copy the trained model's parameters into the decode model by
+    (node, weight) name, each cast once to the dtype the decode model
+    holds it in (the compute dtype under --dtype bf16: the cast every
+    step made at first use, made here) and re-placed under the decode
+    plan's sharding. The copy is made on the device and is the decode
+    model's own: the trainer's masters stay fp32 and stay its to donate.
+    Non-trainable state with a matching name/shape (e.g. BatchNorm stats)
+    transfers too; the KV caches keep their zero init. Returns weights
+    adopted."""
+    import jax
+    import jax.numpy as jnp
+
+    def adopted(val, old):
+        return jax.device_put(jnp.array(val, old.dtype), old.sharding)
 
     moved = 0
     for node_name, ws in dec._params.items():
-        for wname in ws:
-            val = model.get_weight(node_name, wname)
-            if tuple(val.shape) != tuple(np.asarray(ws[wname]).shape):
+        src = model._params[model._resolve_weight_owner(node_name)]
+        for wname, old in ws.items():
+            val = src[wname]
+            if tuple(val.shape) != tuple(old.shape):
                 raise ValueError(
                     f"{node_name}.{wname}: trained shape {val.shape} != "
-                    f"decode shape {np.asarray(ws[wname]).shape}")
-            dec.set_weight(node_name, wname, val)
+                    f"decode shape {old.shape}")
+            ws[wname] = adopted(val, old)
             moved += 1
     for node_name, ws in (dec._state or {}).items():
         src = (model._state or {}).get(
             model._resolve_weight_owner(node_name), {})
-        for wname in ws:
-            if wname in ("cache_k", "cache_v", "pool_k", "pool_v"):
+        for wname, old in ws.items():
+            if wname in KV_LEAVES:
                 continue
             if wname in src:
-                arr = np.asarray(src[wname])
-                old = ws[wname]
-                import jax
-                import jax.numpy as jnp
-
-                ws[wname] = jax.device_put(
-                    jnp.asarray(arr, old.dtype), old.sharding)
+                ws[wname] = adopted(src[wname], old)
                 moved += 1
     return moved

@@ -68,22 +68,28 @@ import numpy as np
 
 from .. import telemetry
 from ..engine.chunking import plan_chunks
-from .decode_graph import ServingSpec, adopt_params, build_decode_model
+from .decode_graph import (
+    KV_LEAVES, ServingSpec, adopt_params, build_decode_model,
+)
 from .paged import SCRATCH_BLOCK, BlockManager
 from .scheduler import ContinuousBatchingScheduler, Request
 
 
-def _kv_read_itemsize(decode_model) -> int:
-    """Bytes of one K or V element as a step's attention reads it: the
-    pool's (or the contiguous cache's) dtype, or the compute dtype where
-    that is narrower (the op casts the cache to the query's dtype before
-    it attends)."""
-    stored = next(
-        (ws[name].dtype.itemsize for ws in decode_model._state.values()
-         for name in ("pool_k", "cache_k") if name in ws), 0)
-    compute = decode_model.executor.compute_dtype
-    return int(stored if compute is None
-               else min(stored, np.dtype(compute).itemsize))
+def _at_rest(decode_model) -> dict:
+    """What the decode model holds on the device between steps, for the
+    `serve.compile` event: the bytes of its parameters and of its KV
+    cache, and the bytes of one cache element as stored (2 under --dtype
+    bf16, where both rest in the compute dtype; 4 under float32). A
+    step's attention reads the cache as it is stored: the decode graph
+    declares it in the dtype the queries have."""
+    kv = [leaf for ws in decode_model._state.values()
+          for name, leaf in ws.items() if name in KV_LEAVES]
+    return dict(
+        weight_bytes_at_rest=sum(
+            int(w.nbytes) for ws in decode_model._params.values()
+            for w in ws.values()),
+        kv_bytes_at_rest=sum(int(leaf.nbytes) for leaf in kv),
+        kv_stored_itemsize=kv[0].dtype.itemsize if kv else 0)
 
 
 class ServingEngine:
@@ -125,6 +131,7 @@ class ServingEngine:
                 self.adopted = adopt_params(self.decode_model, model)
                 self._step_fn = (
                     self.decode_model.executor.build_decode_step())
+            at_rest = _at_rest(self.decode_model)
             telemetry.event(
                 "serve.compile",
                 duration_s=time.perf_counter() - t0,
@@ -133,6 +140,7 @@ class ServingEngine:
                 kv_layout=spec.kv_layout,
                 plan_source=self.decode_model._plan_source,
                 weights_adopted=self.adopted,
+                **at_rest,
                 mesh_axes={k: int(v) for k, v
                            in self.decode_model.mesh.shape.items()})
             if self.telemetry is not None:
@@ -160,7 +168,7 @@ class ServingEngine:
                 cross_time=bool(spec.prefix_cache))
             self._copy_fn = (
                 self.decode_model.executor.build_block_copy())
-        self._kv_itemsize = _kv_read_itemsize(self.decode_model)
+        self._kv_itemsize = at_rest["kv_stored_itemsize"]
         self._chunk_rows = self._rows_serve_chunks()
         # graph input roles: exactly one token stream + the positions /
         # page-table feeds (+ constants, which the engine materializes)
@@ -1154,18 +1162,19 @@ class ServingEngine:
 
     def kv_bytes_per_layer(self) -> int:
         """Resident KV bytes ONE attention layer holds under this
-        engine's layout (fp32, unsharded): the pool for paged — counted
-        once, however many page tables map its blocks — or the full
-        (slots, max_seq+1) region for contiguous. The serving bench's
-        slots-at-fixed-HBM comparison reads this."""
+        engine's layout (unsharded, in the dtype the cache rests in): the
+        pool for paged — counted once, however many page tables map its
+        blocks — or the full (slots, max_seq+1) region for contiguous.
+        The serving bench's slots-at-fixed-HBM comparison reads this."""
         from ..fftype import OperatorType as OT
 
         for n in self.decode_model.graph.topo_order():
             if n.op_type == OT.OP_PAGED_INC_MULTIHEAD_ATTENTION:
                 p = n.params
-                return 2 * 4 * p.num_blocks * p.block_size * p.embed_dim
+                return (2 * self._kv_itemsize * p.num_blocks * p.block_size
+                        * p.embed_dim)
             if n.op_type == OT.OP_INC_MULTIHEAD_ATTENTION:
                 p = n.params
-                return 2 * 4 * self.spec.slots * (p.max_seq_len + 1) \
-                    * p.embed_dim
+                return 2 * self._kv_itemsize * self.spec.slots \
+                    * (p.max_seq_len + 1) * p.embed_dim
         return 0

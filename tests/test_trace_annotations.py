@@ -146,13 +146,14 @@ def test_engine_phases_reach_the_profilers_host_plane(tmp_path, layout):
 
 
 def test_kv_itemsize_is_what_attention_reads():
-    """The pool stays fp32 under --dtype bf16 and the op casts it to the
-    queries' dtype before it attends: two bytes an element are read."""
+    """Under --dtype bf16 the decode graph declares its pool in the compute
+    dtype (serving/decode_graph.py), so attention reads the pool as it
+    lies: two bytes an element are stored and two are read."""
     ff = _build_lm(batch=1, argv=["--dtype", "bf16"])
     eng = ff.serve(slots=2, max_new_tokens=2, prefill_chunk=4)
     (pool,) = {ws["pool_k"].dtype.itemsize
                for ws in eng.decode_model._state.values() if "pool_k" in ws}
-    assert (pool, eng._kv_itemsize) == (4, 2)
+    assert (pool, eng._kv_itemsize) == (2, 2)
 
 
 def test_an_idle_step_opens_no_iteration(tmp_path):
