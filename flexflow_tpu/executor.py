@@ -615,11 +615,16 @@ class Executor:
 
     # ------------------------------------------------------------ variables
 
-    def init_variables(self, rng):
+    def init_variables(self, rng, shared=None):
         """Initialize params (trainable) and state (non-trainable weights),
         each placed with its searched sharding (replaces weight-region mapping
-        in model.cc map_weight + initializer tasks)."""
+        in model.cc map_weight + initializer tasks). `shared`: {(node,
+        weight): array} of another model's parameters; one that has this
+        weight's shape, dtype and placement is taken as it is instead of
+        a fresh one (a decode graph over an inference compile: the
+        device holds the weights once)."""
         params, state = {}, {}
+        shared = shared or {}
         for node in self.order:
             if getattr(node, "weight_source", None):
                 continue  # tied weights live under the source node's name
@@ -628,16 +633,24 @@ class Executor:
                 init = node.initializers.get(
                     ws.name, initializer_by_name(ws.initializer)
                 )
+                spec = self.rest_specs[(node.name, ws.name)][0]
+                dtype = self.rest_dtypes[(node.name, ws.name)]
+                have = shared.get((node.name, ws.name))
+                if (have is not None and ws.trainable
+                        and have.shape == tuple(ws.shape)
+                        and have.dtype == dtype
+                        and have.sharding.is_equivalent_to(
+                            NamedSharding(self.mesh, spec), have.ndim)):
+                    p[ws.name] = have
+                    continue
                 key = _stable_fold(rng, f"{node.name}/{ws.name}")
-                arr = init(key, ws.shape,
-                           self.rest_dtypes[(node.name, ws.name)])
+                arr = init(key, ws.shape, dtype)
                 # at-rest layout. Under weight-update sharding the fp32
                 # master lives 1/dp-sharded — stage 2: consumers
                 # all-gather at first use (GSPMD, fused with their
                 # compute-dtype cast); stage 3: _apply gathers
                 # just-in-time with the explicit ring all-gather and
                 # drops the copy after last use.
-                spec = self.rest_specs[(node.name, ws.name)][0]
                 arr = jax.device_put(arr, NamedSharding(self.mesh, spec))
                 (p if ws.trainable else s)[ws.name] = arr
             if p:
@@ -938,11 +951,13 @@ class Executor:
         backends with donation — a COW costs one block-sized DMA per
         layer, never a pool-sized allocation."""
 
+        from .serving.decode_graph import POOL_LEAVES
+
         def copy_blocks(state, src, dst):
             new_state = {}
             for name, ws in state.items():
                 nw = dict(ws)
-                for pool in ("pool_k", "pool_v"):
+                for pool in POOL_LEAVES:
                     buf = nw.get(pool)
                     if buf is not None:
                         nw[pool] = buf.at[dst].set(buf[src])

@@ -224,6 +224,11 @@ class OperatorType(enum.IntEnum):
     # (router, dropless top-k dispatch, SiLU-gated experts, combine)
     OP_RMSNORM = enum.auto()
     OP_MOE_MLP = enum.auto()
+    # latent attention with a learned sparse selection (DeepSeek-V3.2):
+    # the training-shaped op in the expanded form, and its decode op in
+    # the absorbed form over a paged latent cache (ops/latent_attention.py)
+    OP_LATENT_ATTENTION = enum.auto()
+    OP_PAGED_LATENT_ATTENTION = enum.auto()
 
 
 PARALLEL_OP_TYPES = frozenset(

@@ -35,10 +35,19 @@ from .dispatch import warn_reference
 TILING = (512, 1024, 1024)
 
 
+def padded_rows(m: int) -> int:
+    """The row count the Pallas kernel runs for m rows: the next multiple
+    of its row tile (of 128 below one tile). A serving step's few hundred
+    assignments are padded with zero rows, which lie past the groups' sum
+    and give zeros."""
+    tile = min(TILING[0], -(-m // 128) * 128)
+    return -(-m // tile) * tile
+
+
 def pallas_tiling(lhs, rhs, mesh=None):
-    """(tiling, None) where the Pallas kernel takes these operands, else
-    (None, why not)."""
-    m, k = lhs.shape
+    """(tiling, None) where the Pallas kernel takes these operands (lhs
+    padded to `padded_rows`), else (None, why not)."""
+    m, k = padded_rows(lhs.shape[0]), lhs.shape[1]
     n = rhs.shape[2]
     if mesh is not None and mesh.size > 1:
         return None, "a mesh of several devices (the kernel is not sharded)"
@@ -75,4 +84,7 @@ def grouped_matmul(lhs, rhs, group_sizes, mesh=None):
     if tiling is None:
         warn_reference("grouped_matmul", (lhs.shape, rhs.shape), gate)
         return grouped_matmul_reference(lhs, rhs, group_sizes)
-    return grouped_matmul_pallas(lhs, rhs, group_sizes, tiling)
+    m = lhs.shape[0]
+    if padded_rows(m) != m:
+        lhs = jnp.pad(lhs, ((0, padded_rows(m) - m), (0, 0)))
+    return grouped_matmul_pallas(lhs, rhs, group_sizes, tiling)[:m]

@@ -101,6 +101,9 @@ class FFModel:
         self.label_tensor: Optional[Tensor] = None
         self.iter_config = FFIterationConfig()
         self._params = None
+        # another model's parameters this compile may take as they lie
+        # (serving/decode_graph.build_decode_model sets it)
+        self._shared_variables = None
         self._state = None
         self._opt_slots = None
         self._step = None
@@ -455,6 +458,21 @@ class FFModel:
         return self._add_layer(OT.OP_MULTIHEAD_ATTENTION, p, inputs,
                                name, inits, query.dtype).outputs[0]
 
+    def latent_attention(self, input: Tensor, positions: Tensor, front,
+                         kernel_initializer: Optional[Initializer] = None,
+                         name: str = "") -> Tensor:
+        """Causal latent self-attention with the lightning indexer's
+        top-k selection on (batch, seq, hidden); `front` is an
+        ops.latent_attention.LatentFrontEnd (ops/latent_attention.py,
+        which this call imports: no other graph pays for it)."""
+        from .ops.latent_attention import LatentAttentionParams
+
+        inits = ({} if kernel_initializer is None
+                 else dict.fromkeys(front.kernels, kernel_initializer))
+        return self._add_layer(
+            OT.OP_LATENT_ATTENTION, LatentAttentionParams(front),
+            [input, positions], name, inits, input.dtype).outputs[0]
+
     def concat(self, tensors: Sequence[Tensor], axis: int, name: str = "") -> Tensor:
         p = ConcatParams(axis, len(tensors))
         return self._add_layer(OT.OP_CONCAT, p, list(tensors), name,
@@ -575,16 +593,23 @@ class FFModel:
         intermediate_size: int,
         aux_loss_coef: float = 0.0,
         name: str = "",
+        kernel_initializer: Optional[Initializer] = None,
+        **routing,
     ) -> Tensor:
         """The token-routed expert layer of an LM block on (.., hidden):
         router, the k largest of a softmax over all experts (not
         renormalised), SiLU-gated
-        experts, gate-weighted sum; dropless (ops/moe.py)."""
+        experts, gate-weighted sum; dropless (ops/moe.py). `routing`:
+        the further fields of MoEMLPParams (DeepSeek-V3's sigmoid
+        group-limited router, a shared expert, the experts held here)."""
         from .ops import MoEMLPParams
 
         p = MoEMLPParams(num_experts, num_experts_per_tok, intermediate_size,
-                         aux_loss_coef)
-        return self._add_layer(OT.OP_MOE_MLP, p, [input], name,
+                         aux_loss_coef, **routing)
+        inits = ({} if kernel_initializer is None else dict.fromkeys(
+            ("router", "gate", "up", "down", "shared_gate", "shared_up",
+             "shared_down"), kernel_initializer))
+        return self._add_layer(OT.OP_MOE_MLP, p, [input], name, inits,
                                data_type=input.dtype).outputs[0]
 
     def moe(
@@ -1252,7 +1277,9 @@ class FFModel:
                 self._spmd_barrier = spmd.fingerprint_barrier(self)
             telemetry.event("spmd_barrier", **self._spmd_barrier)
         self._rng = jax.random.key(self.config.seed)
-        self._params, self._state = self.executor.init_variables(self._rng)
+        self._params, self._state = self.executor.init_variables(
+            self._rng, self._shared_variables)
+        self._shared_variables = None
         # optimizer slots inherit the (possibly update-sharded) param
         # placement via zeros_like; place_update_sharded is the explicit
         # guarantee (momentum-off scalar slots pass through untouched)

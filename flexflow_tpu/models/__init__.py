@@ -18,6 +18,7 @@ from .transformer import (
     build_transformer,
     build_transformer_lm,
     build_transformer_lm_pipelined,
+    deepseek_v32_lm_config,
     olmoe_lm_config,
     transformer_lm_param_count,
     transformer_lm_state_bytes_per_chip,

@@ -17,6 +17,7 @@ parallel + optional seq-parallel shardings apply cleanly.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import Optional
 
 from ..fftype import ActiMode, DataType
 
@@ -85,20 +86,42 @@ class TransformerLMConfig:
     rope_theta: float = 10000.0
     attention_bias: bool = True
     qk_norm: bool = False
-    mlp: str = "gelu"              # gelu | moe (SiLU-gated routed experts)
+    # gelu | swiglu (SiLU-gated, bias-free, of `intermediate_size`) | moe
+    # (SiLU-gated routed experts)
+    mlp: str = "gelu"
     num_experts: int = 0
     num_experts_per_tok: int = 0
     moe_intermediate_size: int = 0
     router_aux_loss_coef: float = 0.0
+    # DeepSeek-V3.2 (`deepseek_v32_lm_config`): attention="latent" builds
+    # latent attention with the lightning indexer from `latent` (an
+    # ops.latent_attention.LatentFrontEnd, positions go to it); the first
+    # `first_k_dense` layers of an mlp="moe" stack are swiglu ones of
+    # `intermediate_size`; `moe_routing` holds the further fields of
+    # ops.moe.MoEMLPParams (the sigmoid group-limited router, the shared
+    # expert, the experts held here); `initializer_range` > 0 draws every
+    # matrix from N(0, that)
+    attention: str = "mha"         # mha | latent
+    latent: Optional[object] = None
+    intermediate_size: int = 0
+    first_k_dense: int = 0
+    moe_routing: Optional[dict] = None
+    initializer_range: float = 0.0
 
     def __post_init__(self):
         for field, allowed in (("norm", ("layernorm", "rmsnorm")),
                                ("position", ("learned", "rope")),
-                               ("mlp", ("gelu", "moe"))):
+                               ("mlp", ("gelu", "swiglu", "moe")),
+                               ("attention", ("mha", "latent"))):
             if getattr(self, field) not in allowed:
                 raise ValueError(
                     f"TransformerLMConfig.{field} must be one of "
                     f"{allowed}, got {getattr(self, field)!r}")
+        if self.attention == "latent" and (self.latent is None
+                                           or self.position != "rope"):
+            raise ValueError(
+                "TransformerLMConfig.attention 'latent' needs `latent` (a "
+                "LatentFrontEnd) and position 'rope'")
 
 
 def olmoe_lm_config(**sizes) -> TransformerLMConfig:
@@ -108,6 +131,68 @@ def olmoe_lm_config(**sizes) -> TransformerLMConfig:
     return TransformerLMConfig(
         norm="rmsnorm", position="rope", attention_bias=False, qk_norm=True,
         mlp="moe", **sizes)
+
+
+def deepseek_v32_lm_config(config: dict, *, sequence_length: int,
+                           attention_impl: str = "xla",
+                           initializer_range: float = 0.006
+                           ) -> TransformerLMConfig:
+    """The DeepSeek-V3.2 block from the keys of its published config.json
+    (`model_type: deepseek_v32`; models/deepseek_v32_reference.py writes
+    the equations out). A cut configuration states the experts one chip
+    holds as `experts_held` = [first id, count] beside `experts_routed`,
+    the router's width (both default to all of `n_routed_experts`)."""
+    from ..ops.latent_attention import LatentFrontEnd
+
+    scaling = config.get("rope_scaling")
+    front = LatentFrontEnd(
+        embed_dim=config["hidden_size"],
+        num_heads=config["num_attention_heads"],
+        q_lora_rank=config["q_lora_rank"],
+        kv_lora_rank=config["kv_lora_rank"],
+        qk_nope_head_dim=config["qk_nope_head_dim"],
+        qk_rope_head_dim=config["qk_rope_head_dim"],
+        v_head_dim=config["v_head_dim"],
+        index_n_heads=config["index_n_heads"],
+        index_head_dim=config["index_head_dim"],
+        index_topk=config["index_topk"],
+        rope_theta=float(config["rope_theta"]),
+        rope_scaling=None if not scaling else (
+            scaling["factor"], scaling["original_max_position_embeddings"],
+            scaling["beta_fast"], scaling["beta_slow"],
+            scaling["mscale_all_dim"]),
+        norm_eps=config["rms_norm_eps"],
+        index_norm_eps=config.get("index_norm_eps", 1e-6))
+    held = config.get("experts_held")
+    routed = config.get("experts_routed", config["n_routed_experts"])
+    return TransformerLMConfig(
+        vocab_size=config["vocab_size"], hidden_size=config["hidden_size"],
+        num_heads=config["num_attention_heads"],
+        num_layers=config["num_hidden_layers"],
+        sequence_length=sequence_length, attention_impl=attention_impl,
+        norm="rmsnorm", norm_eps=config["rms_norm_eps"], position="rope",
+        rope_theta=float(config["rope_theta"]), attention_bias=False,
+        attention="latent", latent=front, mlp="moe",
+        intermediate_size=config["intermediate_size"],
+        first_k_dense=config["first_k_dense_replace"],
+        num_experts=routed,
+        num_experts_per_tok=config["num_experts_per_tok"],
+        moe_intermediate_size=config["moe_intermediate_size"],
+        moe_routing=dict(
+            scoring=config["scoring_func"], n_group=config["n_group"],
+            topk_group=config["topk_group"],
+            norm_topk_prob=config["norm_topk_prob"],
+            routed_scaling_factor=config["routed_scaling_factor"],
+            shared_intermediate_size=(config["n_shared_experts"]
+                                      * config["moe_intermediate_size"]),
+            experts_held=None if held is None else tuple(held)),
+        initializer_range=initializer_range)
+
+
+def _norm_initializer(stddev: float):
+    from ..initializer import NormInitializer
+
+    return NormInitializer(stddev=stddev)
 
 
 def _lm_norm(ff, c: TransformerLMConfig, h, name: str):
@@ -123,32 +208,50 @@ def _lm_trunk(ff, c: TransformerLMConfig, h, pos):
     names (serving/decode_graph.py), so trained parameters transfer to it
     by name."""
     rope = c.position == "rope"
+    init = (_norm_initializer(c.initializer_range)
+            if c.initializer_range else None)
     for i in range(c.num_layers):
         p = f"l{i}_"
         a = _lm_norm(ff, c, h, f"{p}ln1")
-        a = ff.multihead_attention(
-            a, a, a, c.hidden_size, c.num_heads, bias=c.attention_bias,
-            causal=True, impl=c.attention_impl, name=f"{p}attn",
-            positions=pos if rope else None,
-            rope_theta=c.rope_theta if rope else 0.0,
-            qk_norm=c.qk_norm, qk_norm_eps=c.norm_eps,
-        )
+        if c.attention == "latent":
+            a = ff.latent_attention(a, pos, c.latent, kernel_initializer=init,
+                                    name=f"{p}attn")
+        else:
+            a = ff.multihead_attention(
+                a, a, a, c.hidden_size, c.num_heads, bias=c.attention_bias,
+                causal=True, impl=c.attention_impl, name=f"{p}attn",
+                positions=pos if rope else None,
+                rope_theta=c.rope_theta if rope else 0.0,
+                qk_norm=c.qk_norm, qk_norm_eps=c.norm_eps,
+            )
         h = ff.add(h, a, name=f"{p}res1")
         m = _lm_norm(ff, c, h, f"{p}ln2")
-        if c.mlp == "moe":
+        if c.mlp == "moe" and i >= c.first_k_dense:
             # the objective carries the mean over the layers of each
             # router's load-balancing term, so a layer adds coef / layers
             m = ff.moe_mlp(m, c.num_experts, c.num_experts_per_tok,
                            c.moe_intermediate_size,
                            c.router_aux_loss_coef / c.num_layers,
-                           name=f"{p}moe")
+                           name=f"{p}moe", kernel_initializer=init,
+                           **(c.moe_routing or {}))
+        elif c.mlp in ("swiglu", "moe"):
+            g = ff.dense(m, c.intermediate_size, use_bias=False,
+                         kernel_initializer=init, name=f"{p}ffn_gate")
+            u = ff.dense(m, c.intermediate_size, use_bias=False,
+                         kernel_initializer=init, name=f"{p}ffn_up")
+            g = ff.multiply(g, ff.sigmoid(g, name=f"{p}ffn_sigmoid"),
+                            name=f"{p}ffn_silu")
+            m = ff.dense(ff.multiply(g, u, name=f"{p}ffn_gated"),
+                         c.hidden_size, use_bias=False,
+                         kernel_initializer=init, name=f"{p}ffn_down")
         else:
             m = ff.dense(m, c.mlp_ratio * c.hidden_size, name=f"{p}ffn1")
             m = ff.gelu(m, name=f"{p}gelu")
             m = ff.dense(m, c.hidden_size, name=f"{p}ffn2")
         h = ff.add(h, m, name=f"{p}res2")
     h = _lm_norm(ff, c, h, "ln_f")
-    return ff.dense(h, c.vocab_size, use_bias=False, name="lm_head")
+    return ff.dense(h, c.vocab_size, use_bias=False, name="lm_head",
+                    kernel_initializer=init)
 
 
 def build_transformer_lm(ff, config: TransformerLMConfig | None = None,
@@ -159,7 +262,10 @@ def build_transformer_lm(ff, config: TransformerLMConfig | None = None,
     bs = batch_size or ff.config.batch_size
     tokens = ff.create_tensor((bs, c.sequence_length), DataType.DT_INT32,
                               name="tokens")
-    h = ff.embedding(tokens, c.vocab_size, c.hidden_size, name="wte")
+    h = ff.embedding(
+        tokens, c.vocab_size, c.hidden_size, name="wte",
+        kernel_initializer=(None if not c.initializer_range else
+                            _norm_initializer(c.initializer_range)))
     pos = ff.create_tensor((bs, c.sequence_length), DataType.DT_INT32,
                            name="positions")
     if c.position != "rope":  # rotary positions go to the attention ops

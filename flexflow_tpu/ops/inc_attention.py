@@ -124,7 +124,7 @@ def _inc_mha_forward(p: IncMultiHeadAttentionParams, inputs, weights,
     x, positions = inputs
     slots = x.shape[0]
     H = p.num_heads
-    q, k, v = p.front.qkv(ctx, weights, x, x, x)
+    q, k, v = p.front.qkv(ctx, weights, x, x, x, positions)
     scale = 1.0 / math.sqrt(p.front.head_dim)
 
     ck, cv = weights["cache_k"], weights["cache_v"]
@@ -261,7 +261,7 @@ def _paged_mha_forward(p: PagedIncMultiHeadAttentionParams, inputs, weights,
     H, E = p.num_heads, p.embed_dim
     bs = p.block_size
     W = p.blocks_per_slot
-    q, k, v = p.front.qkv(ctx, weights, x, x, x)
+    q, k, v = p.front.qkv(ctx, weights, x, x, x, positions)
     scale = 1.0 / math.sqrt(p.front.head_dim)
 
     pk, pv = weights["pool_k"], weights["pool_v"]

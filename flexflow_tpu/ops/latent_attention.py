@@ -1,0 +1,436 @@
+"""Latent attention with a learned sparse selection (DeepSeek-V2's
+multi-head latent attention under DeepSeek-V3.2's lightning indexer), as
+two ops over one front end.
+
+`LatentFrontEnd` owns what both share: the weights and their names, the
+low-rank query, the compressed key-value row [cKV ; k^R] with its one
+rotary key for all heads, the indexer's queries, key and head weights, the
+YaRN frequencies and the softmax scale. The equations are written out in
+models/deepseek_v32_reference.py.
+
+- OP_LATENT_ATTENTION, the training-shaped op on (batch, seq, hidden): the
+  expanded form. Keys and values of every head are made from cKV, the
+  indexer's top-k is a dense mask. Quadratic in seq: it is the graph a
+  model is built and checked as, not a long-context path.
+- OP_PAGED_LATENT_ATTENTION, the decode op on (rows, 1, hidden): the
+  absorbed form over a paged latent cache. A token's cache is one row of
+  kv_lora_rank + rope numbers in `pool_c` (stored 128-aligned:
+  `LatentFrontEnd.cache_row_widths`) and one indexer key in `pool_i`,
+  both (num_blocks, block_size, width), both under the one page table
+  every layer shares. W_uk is folded into the query and W_uv applied after
+  the weighted sum, so no per-head key or value is ever made. Each row
+  scores its cached indexer keys and attends the index_topk largest only:
+  a slot's row gathers those rows of `pool_c`, a chunk's rows run dense
+  over their shared context under the selection as a mask
+  (kernels/sparse_latent_attention.py).
+
+Rows of the decode op: the first `chunk_from` are the serving engine's
+slots, each with its own page-table row. Rows past them, if a call has
+any, are the tokens of ONE prefill chunk and share the page-table row of
+the first of them (serving/engine.py lays a chunk step out so): their keys
+are gathered once for all of them. Every row's cache rows are written
+before any row reads, so a chunk's row sees the chunk's earlier rows.
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..fftype import DataType, OperatorType as OT
+from .attention import proj
+from .base import OpDef, WeightSpec, register_op
+from .core import rms_norm
+
+
+@dataclass(frozen=True)
+class LatentFrontEnd:
+    embed_dim: int
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    index_n_heads: int
+    index_head_dim: int
+    index_topk: int
+    rope_theta: float = 10000.0
+    # YaRN: (factor, original_max_position_embeddings, beta_fast,
+    # beta_slow, mscale_all_dim), or None for plain frequencies
+    rope_scaling: Optional[tuple] = None
+    norm_eps: float = 1e-6
+    index_norm_eps: float = 1e-6
+
+    # what the block pool holds of a token in a layer
+    @property
+    def latent_row(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def cache_row_widths(self) -> dict:
+        """{pool leaf: width of a token's row in it}. The latent row is
+        stored in a row of the next multiple of 128 (640 for the
+        published 576), zeros behind it: a TPU lays out an array whose
+        last dimension is no multiple of its 128 lanes with another
+        dimension innermost, and every step then copies the whole pool
+        into the row layout and back (15 ms a step of the published
+        configuration: PERF.md section 6, PR 31)."""
+        return {"pool_c": -(-self.latent_row // 128) * 128,
+                "pool_i": self.index_head_dim}
+
+    @property
+    def scale(self) -> float:
+        s = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        if self.rope_scaling:
+            m = (0.1 * self.rope_scaling[4] * math.log(self.rope_scaling[0])
+                 + 1.0)
+            s *= m * m
+        return s
+
+    def inv_freq(self) -> np.ndarray:
+        """The rotary frequencies (rope / 2,), YaRN's where scaled."""
+        dim, theta = self.qk_rope_head_dim, self.rope_theta
+        freqs = 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float64)
+                                 / dim))
+        if not self.rope_scaling:
+            return freqs.astype(np.float32)
+        factor, orig, fast, slow, _ = self.rope_scaling
+
+        def correction_dim(rotations):
+            return (dim * math.log(orig / (rotations * 2 * math.pi))
+                    / (2 * math.log(theta)))
+
+        low = max(math.floor(correction_dim(fast)), 0)
+        high = min(math.ceil(correction_dim(slow)), dim - 1)
+        if low == high:
+            high += 0.001
+        ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                       / (high - low), 0.0, 1.0)
+        return (freqs / factor * ramp + freqs * (1.0 - ramp)).astype(
+            np.float32)
+
+    def weight_specs(self, in_dim: int):
+        H, f = self.num_heads, DataType.DT_FLOAT
+        dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                      self.v_head_dim)
+        nI, dI = self.index_n_heads, self.index_head_dim
+        return [
+            WeightSpec("wq_a", (in_dim, self.q_lora_rank), f, "normal"),
+            WeightSpec("q_norm", (self.q_lora_rank,), f, "ones"),
+            WeightSpec("wq_b", (self.q_lora_rank, H * (dn + dr)), f,
+                       "normal"),
+            WeightSpec("wkv_a", (in_dim, self.latent_row), f, "normal"),
+            WeightSpec("kv_norm", (self.kv_lora_rank,), f, "ones"),
+            WeightSpec("wkv_b", (self.kv_lora_rank, H * (dn + dv)), f,
+                       "normal"),
+            WeightSpec("wo", (H * dv, self.embed_dim), f, "normal"),
+            WeightSpec("wi_q", (self.q_lora_rank, nI * dI), f, "normal"),
+            WeightSpec("wi_k", (in_dim, dI), f, "normal"),
+            WeightSpec("wi_k_norm", (dI,), f, "ones"),
+            WeightSpec("wi_k_bias", (dI,), f, "zeros"),
+            WeightSpec("wi_w", (in_dim, nI), f, "normal"),
+        ]
+
+    kernels = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo", "wi_q", "wi_k",
+               "wi_w")
+
+    def project(self, ctx, weights, x, positions):
+        """Everything a token gives before attention, from x (.., hidden)
+        at positions (..): q_nope (.., H, nope), q_rope (.., H, rope)
+        rotated, ckv (.., latent) normalised, kr (.., rope) rotated, and
+        the indexer's qi (.., nI, dI), ki (.., dI) and wt (.., nI)
+        float32."""
+        H, dn, dr = (self.num_heads, self.qk_nope_head_dim,
+                     self.qk_rope_head_dim)
+        lead = x.shape[:-1]
+        angles = (positions.astype(jnp.float32)[..., None]
+                  * jnp.asarray(self.inv_freq()))
+        with jax.named_scope("mla.q"):
+            cq = rms_norm(proj(ctx, x, weights["wq_a"], None),
+                          weights["q_norm"], self.norm_eps)
+            q = proj(ctx, cq, weights["wq_b"], None).reshape(
+                lead + (H, dn + dr))
+            q_nope = q[..., :dn]
+            q_rope = _rope_interleaved(q[..., dn:], angles[..., None, :])
+        with jax.named_scope("mla.kv"):
+            kv = proj(ctx, x, weights["wkv_a"], None)
+            ckv = rms_norm(kv[..., :self.kv_lora_rank], weights["kv_norm"],
+                           self.norm_eps)
+            kr = _rope_interleaved(kv[..., self.kv_lora_rank:], angles)
+        with jax.named_scope("dsa.index"):
+            nI, dI = self.index_n_heads, self.index_head_dim
+            qi = proj(ctx, cq, weights["wi_q"], None).reshape(
+                lead + (nI, dI))
+            qi = jnp.concatenate(
+                [_rope_half(qi[..., :dr], angles[..., None, :]),
+                 qi[..., dr:]], axis=-1)
+            ki = _layer_norm(proj(ctx, x, weights["wi_k"], None),
+                             weights["wi_k_norm"], weights["wi_k_bias"],
+                             self.index_norm_eps)
+            ki = jnp.concatenate([_rope_half(ki[..., :dr], angles),
+                                  ki[..., dr:]], axis=-1)
+            wt = (proj(ctx, x, weights["wi_w"], None).astype(jnp.float32)
+                  * (nI ** -0.5) * (dI ** -0.5))
+        return q_nope, q_rope, ckv, kr, qi, ki, wt
+
+    def up_weights(self, weights, dtype):
+        """(W_uk (latent, H, nope), W_uv (latent, H, v)) of wkv_b."""
+        w = weights["wkv_b"].astype(dtype).reshape(
+            self.kv_lora_rank, self.num_heads,
+            self.qk_nope_head_dim + self.v_head_dim)
+        return w[..., :self.qk_nope_head_dim], w[..., self.qk_nope_head_dim:]
+
+    def output(self, ctx, weights, o):
+        with jax.named_scope("mla.out"):
+            return proj(ctx, o, weights["wo"], None)
+
+    def linear_flops(self, tokens: int, in_dim: int) -> float:
+        H = self.num_heads
+        per_token = (
+            in_dim * (self.q_lora_rank + self.latent_row
+                      + self.index_head_dim + self.index_n_heads)
+            + self.q_lora_rank * (
+                H * (self.qk_nope_head_dim + self.qk_rope_head_dim)
+                + self.index_n_heads * self.index_head_dim)
+            + H * self.v_head_dim * self.embed_dim)
+        return 2.0 * tokens * per_token
+
+
+def _rope_interleaved(x, angles):
+    """Pairs (x[2i], x[2i+1]) rotated by angles (.., d / 2); float32
+    arithmetic, one cast back."""
+    xf = x.astype(jnp.float32)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    a, b = xf[..., 0::2], xf[..., 1::2]
+    return jnp.stack([a * cos - b * sin, a * sin + b * cos],
+                     axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+def _rope_half(x, angles):
+    """Pairs (x[i], x[i + d/2]) rotated by angles (.., d / 2)."""
+    xf = x.astype(jnp.float32)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    a, b = jnp.split(xf, 2, axis=-1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _layer_norm(x, scale, bias, eps):
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
+    y = (xf - mean) * jax.lax.rsqrt(var + eps)
+    return (y * scale.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+# ------------------------------------------------------------ training-shaped
+
+@dataclass(frozen=True)
+class LatentAttentionParams:
+    front: LatentFrontEnd
+
+    embed_dim = property(lambda self: self.front.embed_dim)
+    num_heads = property(lambda self: self.front.num_heads)
+
+
+def _latent_infer(p: LatentAttentionParams, in_shapes):
+    x = in_shapes[0]
+    return [(x[0], x[1], p.front.embed_dim)]
+
+
+def _latent_weights(p: LatentAttentionParams, in_shapes):
+    return p.front.weight_specs(in_shapes[0][-1])
+
+
+def selection_mask(qi, wt, ki, topk: int):
+    """(batch, seq, seq) bool: the positions s <= t each t attends, the
+    topk of largest index score, all of them while t < topk."""
+    scores = jnp.einsum("btjd,bsd->btjs", qi, ki,
+                        preferred_element_type=jnp.float32)
+    index = jnp.sum(wt[..., None] * jax.nn.relu(scores), axis=2)
+    s = index.shape[-1]
+    causal = jnp.tril(jnp.ones((s, s), bool))
+    if s <= topk:
+        return jnp.broadcast_to(causal, index.shape)
+    # exactly topk of them, ties to the lower position as lax.top_k
+    # breaks them (a ReLU makes exact zeros where indexer heads are few)
+    sel = jax.lax.top_k(jnp.where(causal, index, -1e30), topk)[1]
+    picked = jnp.any(sel[..., None] == jnp.arange(s), axis=-2)
+    return causal & picked
+
+
+def _latent_forward(p: LatentAttentionParams, inputs, weights, state, ctx):
+    f = p.front
+    x, positions = inputs
+    b, s, _ = x.shape
+    q_nope, q_rope, ckv, kr, qi, ki, wt = f.project(ctx, weights, x,
+                                                    positions)
+    with jax.named_scope("dsa.topk"):
+        mask = selection_mask(qi, wt, ki, f.index_topk)
+    w_uk, w_uv = f.up_weights(weights, x.dtype)
+    with jax.named_scope("mla.attend"):
+        k_nope = jnp.einsum("bsc,chn->bshn", ckv, w_uk)
+        scores = (jnp.einsum("bthn,bshn->bhts", q_nope, k_nope,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bthr,bsr->bhts", q_rope, kr,
+                               preferred_element_type=jnp.float32))
+        scores = jnp.where(mask[:, None], scores * f.scale, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        v = jnp.einsum("bsc,chv->bshv", ckv, w_uv)
+        o = jnp.einsum("bhts,bshv->bthv", probs, v)
+    return [f.output(ctx, weights, o.reshape(b, s, -1))], state
+
+
+def _latent_flops(p: LatentAttentionParams, in_shapes, out_shapes):
+    b, s, d = in_shapes[0]
+    f = p.front
+    pairs = b * s * s
+    return (f.linear_flops(b * s, d)
+            + 2.0 * pairs * f.index_n_heads * f.index_head_dim
+            + 2.0 * pairs * f.num_heads
+            * (f.qk_nope_head_dim + f.qk_rope_head_dim + f.v_head_dim))
+
+
+register_op(OpDef(OT.OP_LATENT_ATTENTION, _latent_infer, _latent_forward,
+                  _latent_weights, _latent_flops))
+
+
+# --------------------------------------------------------------------- decode
+
+@dataclass(frozen=True)
+class PagedLatentAttentionParams:
+    front: LatentFrontEnd
+    max_seq_len: int    # logical cache rows per slot
+    block_size: int
+    num_blocks: int     # physical pool blocks, block 0 = reserved scratch
+    chunk_from: int     # rows from here on are one chunk's (module doc)
+    cache_dtype: DataType = DataType.DT_FLOAT
+
+    embed_dim = property(lambda self: self.front.embed_dim)
+    num_heads = property(lambda self: self.front.num_heads)
+
+    @property
+    def blocks_per_slot(self) -> int:
+        return -(-self.max_seq_len // self.block_size)
+
+    @property
+    def selected(self) -> int:
+        """Positions a row attends at the most."""
+        return min(self.front.index_topk,
+                   self.blocks_per_slot * self.block_size)
+
+
+def _paged_latent_infer(p: PagedLatentAttentionParams, in_shapes):
+    x, positions, page_table = in_shapes
+    if x[1] != 1:
+        raise NotImplementedError(
+            f"paged latent attention takes single-query rows (rows, 1, "
+            f"hidden), got q_len {x[1]}: a prefill chunk rides as rows "
+            f"past the slots (speculative verification is not served)")
+    if page_table[-1] != p.blocks_per_slot:
+        raise ValueError(
+            f"page_table width {page_table[-1]} != blocks_per_slot "
+            f"{p.blocks_per_slot}")
+    return [(x[0], 1, p.front.embed_dim)]
+
+
+def _paged_latent_weights(p: PagedLatentAttentionParams, in_shapes):
+    x = in_shapes[0]
+    pools = [
+        WeightSpec(name, (p.num_blocks, p.block_size, width), p.cache_dtype,
+                   "zeros", trainable=False)
+        for name, width in p.front.cache_row_widths.items()]
+    # the positions the slots' rows attended in the last call (-1 where a
+    # row had fewer): selection is discontinuous, so whoever compares this
+    # layer with another implementation needs the choice itself
+    sel = WeightSpec("sel_rows", (p.chunk_from, p.selected),
+                     DataType.DT_INT32, "zeros", trainable=False)
+    return p.front.weight_specs(x[-1]) + pools + [sel]
+
+
+def _paged_latent_forward(p: PagedLatentAttentionParams, inputs, weights,
+                          state, ctx):
+    from ..kernels import sparse_latent_attention as sla
+
+    f = p.front
+    x, positions, page_table = inputs
+    rows = x.shape[0]
+    x = x[:, 0]
+    positions = positions[:, 0].astype(jnp.int32)
+    page_table = page_table.astype(jnp.int32)
+    live = (positions >= 0) & (positions < p.max_seq_len)
+    pos = jnp.where(live, positions, -1)  # -1: attends nothing
+    q_nope, q_rope, ckv, kr, qi, ki, wt = f.project(
+        ctx, weights, x, jnp.maximum(pos, 0))
+
+    # write this call's rows before any row reads; a dead row writes
+    # zeros into the scratch block (ops/inc_attention.py's rule)
+    bs = p.block_size
+    pos_c = jnp.maximum(pos, 0)
+    phys = jnp.take_along_axis(page_table, (pos_c // bs)[:, None],
+                               axis=1)[:, 0]
+    phys = jnp.where(live, phys, 0)
+    offset = jnp.where(live, pos_c % bs, 0)
+    pool_c, pool_i = weights["pool_c"], weights["pool_i"]
+    pad = jnp.zeros((rows, pool_c.shape[-1] - f.latent_row), ckv.dtype)
+    latent = jnp.where(live[:, None], jnp.concatenate([ckv, kr, pad], -1),
+                       0.0)
+    pool_c = pool_c.at[phys, offset].set(latent.astype(pool_c.dtype))
+    pool_i = pool_i.at[phys, offset].set(
+        jnp.where(live[:, None], ki, 0.0).astype(pool_i.dtype))
+
+    w_uk, w_uv = f.up_weights(weights, x.dtype)
+    with jax.named_scope("mla.q"):
+        q = jnp.concatenate(
+            [jnp.einsum("rhn,chn->rhc", q_nope, w_uk), q_rope,
+             jnp.zeros(q_rope.shape[:-1] + pad.shape[-1:], q_rope.dtype)],
+            axis=-1)
+
+    n = min(rows, p.chunk_from)  # the slots' rows; the rest is one chunk
+    chunk = rows > n
+    with jax.named_scope("dsa.index"):
+        index = sla.index_scores_rows(qi[:n], wt[:n], pool_i,
+                                      page_table[:n], pos[:n])
+        if chunk:
+            index_c = sla.index_scores_chunk(qi[n:], wt[n:], pool_i,
+                                             page_table[n], pos[n:])
+    with jax.named_scope("dsa.topk"):
+        sel, valid = sla.select_topk(index, f.index_topk)
+        if chunk:
+            mask = sla.selection_mask(index_c, f.index_topk)
+    with jax.named_scope("mla.attend"):
+        o = sla.attend_selected(q[:n], pool_c, page_table[:n], sel, valid,
+                                latent_dim=f.kv_lora_rank, scale=f.scale)
+        if chunk:
+            o = jnp.concatenate([o, sla.attend_chunk(
+                q[n:], pool_c, page_table[n], mask, pos[n:],
+                latent_dim=f.kv_lora_rank, scale=f.scale)], axis=0)
+        o = jnp.einsum("rhc,chv->rhv", o, w_uv)
+    out = f.output(ctx, weights, o.reshape(rows, 1, -1))
+    new_state = {"pool_c": pool_c, "pool_i": pool_i}
+    if rows >= p.chunk_from:
+        new_state["sel_rows"] = jnp.where(valid, sel, -1)
+    return [out], new_state
+
+
+def _paged_latent_flops(p: PagedLatentAttentionParams, in_shapes,
+                        out_shapes):
+    rows, _, d = in_shapes[0]
+    f = p.front
+    cached = p.blocks_per_slot * p.block_size
+    return (f.linear_flops(rows, d)
+            + 2.0 * rows * cached * f.index_n_heads * f.index_head_dim
+            + 2.0 * rows * p.selected * f.num_heads
+            * (f.latent_row + f.kv_lora_rank))
+
+
+register_op(OpDef(OT.OP_PAGED_LATENT_ATTENTION, _paged_latent_infer,
+                  _paged_latent_forward, _paged_latent_weights,
+                  _paged_latent_flops))

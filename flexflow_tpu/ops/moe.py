@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -348,12 +349,37 @@ register_op(
 
 @dataclass(frozen=True)
 class MoEMLPParams:
-    num_experts: int
+    num_experts: int  # the router's width, whatever is held here
     num_experts_per_tok: int
     intermediate_size: int
     # multiplies the load-balancing term this layer adds to the objective
     # (transformers' load_balancing_loss_func for one layer)
     aux_loss_coef: float = 0.0
+    # how the router scores and chooses. "softmax" is OLMoE's (softmax
+    # over all experts, the k largest, not renormalised). "sigmoid" is
+    # DeepSeek-V3's: sigmoid scores, a correction bias `router_bias` added
+    # for the choice only, the experts in `n_group` groups of which the
+    # `topk_group` with the largest two-best sums are kept, the k largest
+    # among them, gates renormalised over the chosen (`norm_topk_prob`)
+    # and multiplied by `routed_scaling_factor`
+    scoring: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = False
+    routed_scaling_factor: float = 1.0
+    # width of a SiLU-gated expert every token passes through, added to
+    # the routed sum; 0 = none
+    shared_intermediate_size: int = 0
+    # (first expert id, count) of the experts THIS layer holds, on one
+    # chip of a deployment that spreads them: the router keeps its width
+    # and its k, `gate`/`up`/`down` hold these experts only, and the
+    # result is the shared expert's plus the chosen-and-held experts';
+    # what the absent ones would add is left out. None = all of them
+    experts_held: Optional[tuple] = None
+
+    @property
+    def held(self) -> tuple:
+        return self.experts_held or (0, self.num_experts)
 
 
 def _moe_mlp_infer(p: MoEMLPParams, in_shapes):
@@ -363,13 +389,33 @@ def _moe_mlp_infer(p: MoEMLPParams, in_shapes):
 def _moe_mlp_weights(p: MoEMLPParams, in_shapes):
     d, n, f = in_shapes[0][-1], p.num_experts, p.intermediate_size
     tokens = math.prod(in_shapes[0][:-1])
+    held, fs = p.held[1], p.shared_intermediate_size
+    extra = []
+    if p.scoring == "sigmoid":
+        # e_score_correction_bias: the published one is fitted during
+        # training; seeded here, so that it takes part in the choice
+        extra.append(WeightSpec("router_bias", (n,), DataType.DT_FLOAT,
+                                "normal"))
+    if fs:
+        extra += [
+            WeightSpec("shared_gate", (d, fs), DataType.DT_FLOAT, "normal"),
+            WeightSpec("shared_up", (d, fs), DataType.DT_FLOAT, "normal"),
+            WeightSpec("shared_down", (fs, d), DataType.DT_FLOAT, "normal")]
+    if p.experts_held is not None:
+        # running counts since the weights were made: assignments this
+        # layer computed (chosen and held; the padding rows of a serving
+        # step among them) and assignments to held experts it did not
+        extra += [WeightSpec(name, (), DataType.DT_INT32, "zeros",
+                             trainable=False)
+                  for name in ("assignments_total", "dropped_total")]
     # "normal" is N(0, 0.02), transformers' initializer_range: glorot over
     # a stacked (n, d, f) weight would count the experts into the fans
     return [
         WeightSpec("router", (d, n), DataType.DT_FLOAT, "normal"),
-        WeightSpec("gate", (n, d, f), DataType.DT_FLOAT, "normal"),
-        WeightSpec("up", (n, d, f), DataType.DT_FLOAT, "normal"),
-        WeightSpec("down", (n, f, d), DataType.DT_FLOAT, "normal"),
+        WeightSpec("gate", (held, d, f), DataType.DT_FLOAT, "normal"),
+        WeightSpec("up", (held, d, f), DataType.DT_FLOAT, "normal"),
+        WeightSpec("down", (held, f, d), DataType.DT_FLOAT, "normal"),
+        *extra,
         # the step's counters, beside the loss (read them from the model's
         # state after a step): assignments no expert computed, and the
         # largest expert's load over the mean load
@@ -395,6 +441,28 @@ def moe_route(x, router, k: int):
     probs = jax.nn.softmax(logits, axis=-1)
     weights, ids = jax.lax.top_k(probs, k)
     return weights, ids.astype(jnp.int32), probs
+
+
+def moe_route_sigmoid(x, router, bias, p: MoEMLPParams):
+    """(gate weights (t, k) float32, expert ids (t, k) int32, scores (t,
+    n)) of DeepSeek-V3's Gate (MoEMLPParams.scoring)."""
+    n, k, groups = p.num_experts, p.num_experts_per_tok, p.n_group
+    logits = jnp.dot(x, router.astype(x.dtype),
+                     preferred_element_type=jnp.float32)
+    scores = jax.nn.sigmoid(logits)
+    biased = scores + bias.astype(jnp.float32)
+    if groups > 1:
+        grouped = biased.reshape(-1, groups, n // groups)
+        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, kept = jax.lax.top_k(group_score, p.topk_group)
+        keep = jnp.any(jax.nn.one_hot(kept, groups, dtype=bool), axis=1)
+        biased = jnp.where(jnp.repeat(keep, n // groups, axis=1), biased,
+                           -jnp.inf)
+    _, ids = jax.lax.top_k(biased, k)
+    gates = jnp.take_along_axis(scores, ids, axis=-1)
+    if p.norm_topk_prob:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return gates * p.routed_scaling_factor, ids.astype(jnp.int32), scores
 
 
 def load_balancing_loss(probs, group_sizes):
@@ -467,10 +535,24 @@ def _moe_mlp_forward(p: MoEMLPParams, inputs, weights, state, ctx):
     shape = x.shape
     x = x.reshape(-1, shape[-1])
     n, k = p.num_experts, p.num_experts_per_tok
+    first, held = p.held
     with jax.named_scope("moe.route"):
-        gates, ids, probs = moe_route(x, weights["router"], k)
+        if p.scoring == "sigmoid":
+            gates, ids, probs = moe_route_sigmoid(
+                x, weights["router"], weights["router_bias"], p)
+        else:
+            gates, ids, probs = moe_route(x, weights["router"], k)
     with jax.named_scope("moe.dispatch"):
-        order, position, group_sizes = moe_sort(ids, n)
+        if p.experts_held is None:
+            order, position, group_sizes = moe_sort(ids, n)
+        else:
+            # assignments to experts held elsewhere sort behind the last
+            # group, where no expert computes them, and count for nothing
+            here = (ids >= first) & (ids < first + held)
+            gates = jnp.where(here, gates, 0.0)
+            order, position, group_sizes = moe_sort(
+                jnp.where(here, ids - first, held), held + 1)
+            group_sizes = group_sizes[:held]
         rows = _gather_sorted(x, order, position)
     with jax.named_scope("moe.experts"):
         gate = grouped_matmul(rows, weights["gate"].astype(x.dtype),
@@ -483,13 +565,40 @@ def _moe_mlp_forward(p: MoEMLPParams, inputs, weights, state, ctx):
                              group_sizes, ctx.mesh)
     with jax.named_scope("moe.combine"):
         picked = _gather_back(out, order, position)
+        if p.experts_held is not None:
+            # rows past the groups' sum are whatever the kernel left there
+            picked = jnp.where(here[..., None], picked, 0.0)
         y = jnp.sum(gates[..., None] * picked.astype(jnp.float32), axis=1)
+    if p.shared_intermediate_size:
+        with jax.named_scope("moe.shared"):
+            def dot(a, w):
+                return jnp.dot(a, w.astype(a.dtype),
+                               preferred_element_type=jnp.float32)
+
+            h = (jax.nn.silu(dot(x, weights["shared_gate"]))
+                 * dot(x, weights["shared_up"])).astype(x.dtype)
+            y = y + dot(h, weights["shared_down"])
     state = dict(state or {})
     computed = jnp.sum(group_sizes)
-    state["dropped_tokens"] = (ids.size - computed).astype(jnp.float32)
+    wanted = ids.size if p.experts_held is None else jnp.sum(here)
+    state["dropped_tokens"] = (wanted - computed).astype(jnp.float32)
     state["load_max_over_mean"] = (
-        jnp.max(group_sizes) * (n / computed.astype(jnp.float32)))
-    state["expert_ids"] = ids
+        jnp.max(group_sizes)
+        * (held / jnp.maximum(computed, 1).astype(jnp.float32)))
+    declared = weights.get("expert_ids")
+    if declared is None or ids.shape == declared.shape:
+        state["expert_ids"] = ids
+    elif len(shape) == 3 and shape[1] == 1 and shape[0] > declared.shape[0]:
+        # a serving step with a prefill chunk riding as single-query rows
+        # past the slots': the record keeps the slots' rows, which come
+        # first (a state leaf keeps its shape; any other layout leaves
+        # the record as it was)
+        state["expert_ids"] = ids[:declared.shape[0]]
+    if p.experts_held is not None:
+        state["assignments_total"] = (weights.get("assignments_total", 0)
+                                      + computed.astype(jnp.int32))
+        state["dropped_total"] = (weights.get("dropped_total", 0)
+                                  + (wanted - computed).astype(jnp.int32))
     if p.aux_loss_coef:
         state["aux_loss"] = p.aux_loss_coef * load_balancing_loss(
             probs, group_sizes)
@@ -501,7 +610,8 @@ def _moe_mlp_flops(p: MoEMLPParams, in_shapes, out_shapes):
     tokens = math.prod(in_shapes[0][:-1])
     return 2.0 * tokens * d * (p.num_experts
                                + 3 * p.num_experts_per_tok
-                               * p.intermediate_size)
+                               * p.intermediate_size
+                               + 3 * p.shared_intermediate_size)
 
 
 register_op(OpDef(OT.OP_MOE_MLP, _moe_mlp_infer, _moe_mlp_forward,

@@ -32,8 +32,24 @@ from ..fftype import (
 )
 
 
+# the block pool's state leaves: keys and values of multi-head attention,
+# the latent row and the indexer's key of latent attention
+# (ops/latent_attention.py). Every one is (num_blocks, block_size, width)
+# under the one page table; a copy-on-write copies them all
+POOL_LEAVES = ("pool_k", "pool_v", "pool_c", "pool_i")
 # the per-layer KV cache's state leaves, paged and contiguous
-KV_LEAVES = ("pool_k", "pool_v", "cache_k", "cache_v")
+KV_LEAVES = (*POOL_LEAVES, "cache_k", "cache_v")
+
+
+def cache_row_widths(layer) -> dict:
+    """{pool leaf: numbers a token holds in it} of a training-graph layer
+    whose decode op keeps a cache, {} of any other layer."""
+    if layer.op_type == OT.OP_MULTIHEAD_ATTENTION:
+        return {"pool_k": layer.params.embed_dim,
+                "pool_v": layer.params.embed_dim}
+    if layer.op_type == OT.OP_LATENT_ATTENTION:
+        return layer.params.front.cache_row_widths
+    return {}
 
 
 @dataclass
@@ -141,10 +157,10 @@ def resolve_pool_blocks(model, spec: ServingSpec, max_seq: int,
             w.size * (itemsize if jnp.issubdtype(w.dtype, jnp.floating)
                       else w.dtype.itemsize)
             for ws in (model._params or {}).values() for w in ws.values())
-        attn = [l for l in model.layers
-                if l.op_type == OT.OP_MULTIHEAD_ATTENTION]
-        block_bytes = sum(2 * bs * l.params.embed_dim * itemsize
-                          for l in attn)
+        # a block holds, in every cached layer, `bs` rows of each of
+        # that layer's pool leaves
+        block_bytes = sum(bs * width * itemsize for l in model.layers
+                          for width in cache_row_widths(l).values())
         if block_bytes <= 0:
             return capacity
         budget = 0.9 * hbm - weight_bytes
@@ -258,11 +274,6 @@ def build_decode_model(model, spec: ServingSpec):
                 raise ValueError(
                     f"{layer.name}: kdim/vdim != embed_dim not supported "
                     f"in the decode graph")
-            if p.front.rope_theta or p.front.qk_norm:
-                raise NotImplementedError(
-                    f"{layer.name}: rotary positions and QK-norm are not "
-                    f"in the incremental attention ops yet, so this model "
-                    f"trains but does not serve")
             # the trained layer's front end goes to the decode op whole
             if paged:
                 op, np_, feeds = (
@@ -280,6 +291,20 @@ def build_decode_model(model, spec: ServingSpec):
                     [ins[0], positions])
             new = dec._add_layer(op, np_, feeds, name=layer.name,
                                  data_type=layer.data_type)
+        elif layer.op_type == OT.OP_LATENT_ATTENTION:
+            from ..ops.latent_attention import PagedLatentAttentionParams
+
+            if not paged:
+                raise NotImplementedError(
+                    f"{layer.name}: latent attention is served from the "
+                    f"paged pool only (kv_layout='paged')")
+            new = dec._add_layer(
+                OT.OP_PAGED_LATENT_ATTENTION,
+                PagedLatentAttentionParams(
+                    layer.params.front, max_seq, spec.kv_block_size,
+                    num_blocks, chunk_from=spec.slots, cache_dtype=at_rest),
+                [ins[0], positions, page_table], name=layer.name,
+                data_type=layer.data_type)
         else:
             new = dec._add_layer(
                 layer.op_type, layer.params, ins, name=layer.name,
@@ -291,6 +316,14 @@ def build_decode_model(model, spec: ServingSpec):
 
     if spec.strategy is not None:
         dec.set_strategy(spec.strategy)
+    # an inference compile never updates or donates its parameters, so the
+    # decode model takes them as they lie where dtype and placement agree
+    # (executor.init_variables): one copy of the weights on the device
+    if model.config.computation_mode == CompMode.COMP_MODE_INFERENCE:
+        dec._shared_variables = {
+            (model._resolve_weight_owner(node), wname): w
+            for node, ws in (model._params or {}).items()
+            for wname, w in ws.items()}
     dec.compile(optimizer=SGDOptimizer(lr=0.0),
                 loss_type=LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY,
                 comp_mode=CompMode.COMP_MODE_INFERENCE)
@@ -304,6 +337,8 @@ def adopt_params(dec, model) -> int:
     step made at first use, made here) and re-placed under the decode
     plan's sharding. The copy is made on the device and is the decode
     model's own: the trainer's masters stay fp32 and stay its to donate.
+    A weight the decode model already shares with an inference compile
+    (build_decode_model) is that model's array and is not copied.
     Non-trainable state with a matching name/shape (e.g. BatchNorm stats)
     transfers too; the KV caches keep their zero init. Returns weights
     adopted."""
@@ -318,6 +353,9 @@ def adopt_params(dec, model) -> int:
         src = model._params[model._resolve_weight_owner(node_name)]
         for wname, old in ws.items():
             val = src[wname]
+            if val is old:
+                moved += 1
+                continue
             if tuple(val.shape) != tuple(old.shape):
                 raise ValueError(
                     f"{node_name}.{wname}: trained shape {val.shape} != "
@@ -330,7 +368,9 @@ def adopt_params(dec, model) -> int:
         for wname, old in ws.items():
             if wname in KV_LEAVES:
                 continue
-            if wname in src:
+            # a leaf shaped by the graph's rows (the experts a layer chose
+            # for each token) is the decode graph's own
+            if wname in src and tuple(src[wname].shape) == tuple(old.shape):
                 ws[wname] = adopted(src[wname], old)
                 moved += 1
     return moved

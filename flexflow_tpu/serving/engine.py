@@ -73,6 +73,11 @@ from .decode_graph import (
 )
 from .paged import SCRATCH_BLOCK, BlockManager
 from .scheduler import ContinuousBatchingScheduler, Request
+from ..fftype import OperatorType as OT
+
+# the decode ops that read and write the block pool through a page table
+PAGED_OPS = (OT.OP_PAGED_INC_MULTIHEAD_ATTENTION,
+             OT.OP_PAGED_LATENT_ATTENTION)
 
 
 def _at_rest(decode_model) -> dict:
@@ -156,11 +161,9 @@ class ServingEngine:
         self._copy_fn = None
         self._inject_fn = None  # lazily built KV-handoff landing pad
         if spec.kv_layout == "paged":
-            from ..fftype import OperatorType as OT
-
             attn = next(
                 n for n in self.decode_model.graph.topo_order()
-                if n.op_type == OT.OP_PAGED_INC_MULTIHEAD_ATTENTION)
+                if n.op_type in PAGED_OPS)
             p = attn.params
             self.block_manager = BlockManager(
                 p.num_blocks, p.block_size, p.blocks_per_slot,
@@ -170,6 +173,19 @@ class ServingEngine:
                 self.decode_model.executor.build_block_copy())
         self._kv_itemsize = at_rest["kv_stored_itemsize"]
         self._chunk_rows = self._rows_serve_chunks()
+        # what a step's spans say of sparse latent attention and of the
+        # expert layers (docs/observability.md): the positions a row
+        # attends at the most, and the experts a row is sent to summed
+        # over the expert layers
+        nodes = self.decode_model.graph.topo_order()
+        self._sel_cap = next(
+            (n.params.selected for n in nodes
+             if n.op_type == OT.OP_PAGED_LATENT_ATTENTION), 0)
+        self._moe_nodes = [n.name for n in nodes
+                           if n.op_type == OT.OP_MOE_MLP]
+        self._moe_fanout = sum(n.params.num_experts_per_tok for n in nodes
+                               if n.op_type == OT.OP_MOE_MLP)
+        self._moe_base = (0, 0)
         # graph input roles: exactly one token stream + the positions /
         # page-table feeds (+ constants, which the engine materializes)
         self._token_input = None
@@ -275,15 +291,16 @@ class ServingEngine:
         graph's paged attention op, with the mesh its calls run on."""
         if self.block_manager is None:
             return False
-        from ..fftype import OperatorType as OT
         from ..ops.inc_attention import paged_rows_run_kernel
 
         dec = self.decode_model
+        # latent attention takes a chunk as rows only: its op gathers the
+        # chunk's keys once for all of them (ops/latent_attention.py)
         return all(
-            paged_rows_run_kernel(n.params, dec.executor.mesh,
-                                  self._kv_itemsize)
-            for n in dec.graph.topo_order()
-            if n.op_type == OT.OP_PAGED_INC_MULTIHEAD_ATTENTION)
+            n.op_type == OT.OP_PAGED_LATENT_ATTENTION
+            or paged_rows_run_kernel(n.params, dec.executor.mesh,
+                                     self._kv_itemsize)
+            for n in dec.graph.topo_order() if n.op_type in PAGED_OPS)
 
     def enable_autoscale(self, visible_devices_fn=None,
                          check_every: int = 16):
@@ -545,7 +562,8 @@ class ServingEngine:
         exhaust the pool mid-flight. A True answer IS the reservation —
         the scheduler admits exactly when the gate passes."""
         return self.block_manager.reserve(
-            req.request_id, len(req.prompt), req.max_new_tokens)
+            req.request_id, len(req.prompt), req.max_new_tokens,
+            prompt=req.prompt)
 
     def _apply_copies(self, copies):
         """Run this iteration's COW copies on the pool state in one
@@ -875,6 +893,20 @@ class ServingEngine:
                     admitted=len(admitted), pending=sched.queue_depth)
         if by_rows:
             load.update(rows=rows, kv_rows_walked=int(kv_rows_walked))
+        if self._sel_cap:
+            # a layer's indexer scores every cached row of every live
+            # row's context; its attention reads the selected ones
+            ctx = [s.length + 1 for s in decoding]
+            if pre is not None:
+                ctx += range(start + 1, start + n + 1)
+            load.update(ctx_rows=int(sum(ctx)),
+                        sel_rows=int(sum(min(c, self._sel_cap)
+                                         for c in ctx)))
+        if self._moe_fanout:
+            # assignments this step's rows make over all the experts,
+            # padding rows included; those held here are computed
+            # (stats()["moe_assignments"] counts them on the device)
+            load["moe_rows"] = rows * self._moe_fanout
         span = telemetry.span(
             "serve.prefill", slot=pre.index,
             trace=pre.request.trace_id,
@@ -1044,6 +1076,7 @@ class ServingEngine:
         self._row_steps = 0
         self._device_s = 0.0
         self._last_wall_s = 0.0
+        self._moe_base = self._moe_totals()
         # zero the serving series (objects survive — the step loop holds
         # references); the stats_reset event marks the window boundary so
         # doctor's TTFT identity counts serve.request events after it
@@ -1061,6 +1094,22 @@ class ServingEngine:
             self.block_manager.stats = fresh
             # the eviction-delta poll restarts from the fresh counter
             self._evictions_seen = 0
+
+    def _moe_totals(self) -> tuple:
+        """(assignments computed, assignments dropped) by the expert
+        layers that hold a share of their experts, since the engine was
+        built: running counts the op keeps in its state on the device
+        (ops/moe.py), fetched here and nowhere in the step loop."""
+        import jax
+
+        st = self.decode_model._state
+        leaves = [(st[n]["assignments_total"], st[n]["dropped_total"])
+                  for n in self._moe_nodes
+                  if "assignments_total" in st.get(n, {})]
+        if not leaves:
+            return (0, 0)
+        got = np.asarray(jax.device_get(leaves)).astype(np.int64)
+        return (int(got[:, 0].sum()), int(got[:, 1].sum()))
 
     def stats(self) -> dict:
         """Aggregate run metrics; rates are per chip of the decode mesh
@@ -1101,9 +1150,16 @@ class ServingEngine:
             "kv_layout": self.spec.kv_layout,
         }
         out["kv_hbm_bytes_per_layer"] = self.kv_bytes_per_layer()
+        if self._moe_nodes:
+            done, dropped = self._moe_totals()
+            out["moe_assignments"] = done - self._moe_base[0]
+            out["moe_dropped"] = dropped - self._moe_base[1]
         if self.block_manager is not None:
             mgr = self.block_manager
             out.update({
+                "prompt_tokens": mgr.stats.prompt_tokens,
+                "prefix_hit_tokens": mgr.stats.shared_tokens,
+                "evictions": mgr.stats.radix_evictions,
                 "kv_block_size": mgr.block_size,
                 "kv_pool_blocks": mgr.num_blocks,
                 "kv_blocks_in_use_peak": mgr.stats.blocks_in_use_peak,
@@ -1166,13 +1222,15 @@ class ServingEngine:
         pool for paged — counted once, however many page tables map its
         blocks — or the full (slots, max_seq+1) region for contiguous.
         The serving bench's slots-at-fixed-HBM comparison reads this."""
-        from ..fftype import OperatorType as OT
-
         for n in self.decode_model.graph.topo_order():
             if n.op_type == OT.OP_PAGED_INC_MULTIHEAD_ATTENTION:
                 p = n.params
                 return (2 * self._kv_itemsize * p.num_blocks * p.block_size
                         * p.embed_dim)
+            if n.op_type == OT.OP_PAGED_LATENT_ATTENTION:
+                p = n.params
+                return (self._kv_itemsize * p.num_blocks * p.block_size
+                        * sum(p.front.cache_row_widths.values()))
             if n.op_type == OT.OP_INC_MULTIHEAD_ATTENTION:
                 p = n.params
                 return 2 * self._kv_itemsize * self.spec.slots \

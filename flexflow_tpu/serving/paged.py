@@ -118,6 +118,10 @@ class BlockManager:
         # decode write can NEVER exhaust the pool mid-flight — admission
         # is the only place pool pressure is felt (FCFS head-blocking)
         self._reserved: dict = {}
+        # cached blocks an admission counted on not having to draw
+        # (reserve with a prompt), keyed like the reservation until the
+        # slot maps them: eviction passes them over
+        self._held: dict = {}
         # slot index -> logical->physical list (allocated prefix only)
         self._tables: dict[int, list[int]] = {}
         self.cache = RadixPrefixCache(block_size) if self.sharing else None
@@ -180,18 +184,33 @@ class BlockManager:
         return sum(self._reserved.values())
 
     def reserve(self, request_id, prompt_len: int,
-                max_new_tokens: int) -> bool:
+                max_new_tokens: int, prompt=None) -> bool:
         """Admission gate: reserve the request's worst case against the
         pool, evicting cold cache leaves first when the free list alone
         cannot cover it. False = not enough headroom even after eviction
         (the caller keeps the request queued — FCFS head-blocking, so
         admission order never depends on pool pressure in a way that
-        could reorder token streams)."""
+        could reorder token streams).
+
+        With the `prompt` itself (and a cross-time cache) the worst case
+        leaves out the cached blocks the request will never write: those
+        wholly before its first write, which is at the end of the cached
+        extent. What it matched is held against eviction until the slot
+        maps it, so the draw cannot come out higher. A 30,000-token history in the
+        cache then costs its follow-up the blocks of the new turn and the
+        reply, not a second history's worth of free pool."""
         needed = self.blocks_needed(prompt_len, max_new_tokens)
+        held = []
+        if prompt is not None and self.cache is not None and self.cross_time:
+            covered, blocks = self.cache.match(prompt, peek=True)
+            needed -= min(covered, prompt_len - 1) // self.block_size
+            held = blocks  # the tail it will copy on its first write too
+        self._held[("req", request_id)] = held
         headroom = self.free_blocks - self.reserved_total
         if headroom < needed:
             self._evict_blocks(needed - headroom)
         if self.free_blocks - self.reserved_total < needed:
+            del self._held[("req", request_id)]
             return False
         self._reserved[("req", request_id)] = needed
         return True
@@ -202,6 +221,9 @@ class BlockManager:
         n = self._reserved.pop(("req", request_id), None)
         if n is not None:
             self._reserved[slot] = n
+        held = self._held.pop(("req", request_id), None)
+        if held:
+            self._held[slot] = held
 
     # --------------------------------------------------------- refcounts
 
@@ -239,11 +261,13 @@ class BlockManager:
         unblocks a freeable ancestor)."""
         if self.cache is None or need <= 0:
             return 0
+        held = {b for blocks in self._held.values() for b in blocks}
         freed = 0
         while freed < need:
             before = len(self._free)
             blk = self.cache.evict_lru(
-                lambda b: self._refcount.get(b, 0) == 1)
+                lambda b: self._refcount.get(b, 0) == 1 and b not in held,
+                keep=held)
             if blk is None:
                 break
             self._unpin_free(blk)
@@ -291,6 +315,7 @@ class BlockManager:
             self._map(blk)
             table.append(blk)
         self._tables[slot] = table
+        self._held.pop(slot, None)  # mapped now: a live reference holds them
         skip = min(covered, L - 1)
         self.stats.prompt_tokens += L
         self.stats.shared_tokens += skip
@@ -376,6 +401,7 @@ class BlockManager:
         pin is dropped from the cache and freed immediately (the old
         live-residents-only semantics)."""
         self._reserved.pop(slot, None)
+        self._held.pop(slot, None)
         table = self._tables.pop(slot, None)
         if table is None:
             return

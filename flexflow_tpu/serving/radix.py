@@ -56,6 +56,8 @@ class RadixNode:
 
 def _common_len(a, b) -> int:
     n = min(len(a), len(b))
+    if a[:n] == b[:n]:  # the whole run, at C speed: the usual case
+        return n
     for i in range(n):
         if a[i] != b[i]:
             return i
@@ -179,15 +181,16 @@ class RadixPrefixCache:
         self._pinned.pop(node.block, None)
         return node.block
 
-    def evict_lru(self, freeable) -> int | None:
+    def evict_lru(self, freeable, keep=frozenset()) -> int | None:
         """Evict one leaf, LRU-first, and return its block (pin dropped —
         the caller decrements the refcount). Prefers leaves whose block
         `freeable(block)` says would actually free (refcount == pin);
         falls back to the globally-LRU leaf only when a freeable block
         exists deeper in the tree blocked behind non-freeable leaves
         (evicting the leaf frees nothing now but unblocks the ancestor).
+        Blocks in `keep` stay in the cache whatever else is true of them.
         Returns None when nothing can be evicted."""
-        leaves = self._leaves()
+        leaves = [n for n in self._leaves() if n.block not in keep]
         if not leaves:
             return None
         free_leaves = [n for n in leaves if freeable(n.block)]

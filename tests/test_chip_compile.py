@@ -123,6 +123,56 @@ def test_grouped_matmul_fwd_bwd_at_olmoe_widths(tpu):
     assert not kernels
 
 
+@pytest.mark.parametrize("rows", [16, 272])
+def test_sparse_latent_attention_at_deepseek_v32_widths(tpu, rows):
+    """A layer's indexer scores, exact top-2,048 and selected-row
+    attention over the paged latent pool of `dsv32-serve-sessions` (1,536
+    blocks of 256, page tables 130 wide): 16 decoding rows, and the same
+    with a 256-token chunk under one page-table row (its selection a mask
+    by bisection, its attention dense over the shared context). XLA's
+    gather, matmul and sort: no Pallas kernel yet, and what the step
+    needs beside its arguments stays under 2 GB."""
+    from flexflow_tpu.kernels import sparse_latent_attention as sla
+
+    s = _on(tpu[0])
+    n = 16
+
+    def layer(qi, wt, q, pool_i, pool_c, table, pos):
+        index = sla.index_scores_rows(qi[:n], wt[:n], pool_i, table[:n],
+                                      pos[:n])
+        sel, valid = sla.select_topk(index, 2048)
+        out = sla.attend_selected(q[:n], pool_c, table[:n], sel, valid,
+                                  latent_dim=512, scale=0.1)
+        if rows > n:
+            index = sla.index_scores_chunk(qi[n:], wt[n:], pool_i, table[n],
+                                           pos[n:])
+            out = jnp.concatenate([out, sla.attend_chunk(
+                q[n:], pool_c, table[n], sla.selection_mask(index, 2048),
+                pos[n:], latent_dim=512, scale=0.1)], 0)
+        return out
+
+    compiled = jax.jit(layer).lower(
+        s((rows, 64, 128)), s((rows, 64), jnp.float32), s((rows, 128, 576)),
+        s((1536, 256, 128)), s((1536, 256, 576)), s((rows, 130), jnp.int32),
+        s((rows,), jnp.int32)).compile()
+    assert not pallas_kernels(compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
+@pytest.mark.parametrize("rows", [16, 144, 272])
+def test_grouped_matmul_at_a_serving_steps_rows(tpu, rows):
+    """16 held experts of DeepSeek-V3.2's widths at a decode step's 8
+    assignments a row: the rows are padded to the kernel's row tile, so
+    the Pallas grouped matmul serves every step shape."""
+    from flexflow_tpu.kernels import grouped_matmul as gm
+
+    s = _on(tpu[0])
+    for k, n_out in ((7168, 2048), (2048, 7168)):
+        kernels = _kernels(gm.grouped_matmul, s((rows * 8, k)),
+                           s((16, k, n_out)), s((16,), jnp.int32))
+        assert kernels == {"gmm": 1}
+
+
 def test_contiguous_decode_head_dim_128(tpu):
     """The contiguous decode kernel at the engine's real cache shape
     (slots, max_seq + 1, E): max_seq + 1 is odd, so the last kv block is

@@ -284,7 +284,11 @@ def test_pallas_grouped_matmul_against_ragged_dot(k, n):
               b[:482] if b.shape[0] == m else b, tol=2**-7)
     assert gm.pallas_tiling(x.astype(jnp.float32), w)[1].endswith(
         "not bfloat16")
-    assert "do not divide" in gm.pallas_tiling(x[:500], w)[1]
+    assert "do not divide" in gm.pallas_tiling(x[:, :72], w)[1]
+    # rows are padded to the row tile: a serving step's few assignments
+    assert gm.pallas_tiling(x[:500], w) == ((512, k, n), None)
+    assert [gm.padded_rows(m) for m in (8, 128, 130, 640, 2176, 131072)] == [
+        128, 128, 256, 1024, 2560, 131072]
 
 
 # ------------------------------------------------ the builder
@@ -326,9 +330,11 @@ def test_block_fields_are_checked(field):
         TransformerLMConfig(**{field: "nope"})
 
 
-def test_olmoe_trains_but_does_not_serve_yet(olmoe):
-    with pytest.raises(NotImplementedError, match="rotary"):
-        olmoe.serve(slots=2, max_new_tokens=2)
+def test_olmoe_serves_since_positions_reach_the_decode_ops(olmoe):
+    """Until PR 31 the decode replay refused a RoPE or QK-norm layer."""
+    prompts = [[5, 9, 2, 7], [3, 3, 8]]
+    out = olmoe.serve(slots=2, max_new_tokens=2).generate(prompts)
+    assert [len(o) for o in out] == [2, 2]
 
 
 def test_olmoe_fits_and_the_loss_falls():
