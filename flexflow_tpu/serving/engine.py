@@ -15,6 +15,28 @@ continuous batch, and a decoding slot's token stream is bit-identical
 either way because rows are computed independently (dead elements point
 at the scratch row/block).
 
+**One step in flight.** A call of `step()` schedules, stages and
+dispatches step n+1, THEN fetches step n's tokens, does their bookkeeping
+and returns step n's completions: the host's work of an iteration runs
+beside the device's step, not between two of them. Nothing the host does
+for step n+1 needs the values of step n's tokens. Which slots decode, at
+which positions, which chunk rides along and which blocks are written are
+known when step n is dispatched (`_advance`: `length`, `prefill_pos`, the
+prefill -> decode turn, `register_prompt`, and whether the token in
+flight is the request's last by length); the token ids stay on the device
+(`_build_token_feed`). What needs them waits for the fetch (`_bookkeep`:
+`generated`, EOS, the latency stamps, `decode_tokens`, `prefill_calls`,
+completion, block release). An end by EOS is learnt one step late: the
+row already dispatched for the request writes one cache row inside its
+own reservation, and its token is dropped (`rows_discarded`). The step in
+flight is completed at once where something needs its result now: the
+step function handed back host tokens (a NumPy array), the sanitizer is
+on, or `_complete_in_flight()` is called first by whatever reads or
+replaces decode or scheduler state from outside the loop (`stats`,
+`reset_stats`, `replan_mesh`, `profile_step`, `extract_kv`,
+`admit_prefilled`, `_apply_copies`, a speculative round, the end of
+`run_until_drained`).
+
 **A chunk step's two batch layouts** (docs/serving.md). The RECTANGLE,
 `(slots, q)` with q the chunk's bucket: the chunk in the admitted slot's
 row, every decoding slot's token in column 0 of its own, the rest dead.
@@ -61,6 +83,7 @@ decode tokens/s/chip.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import time
 from typing import Optional, Sequence
 
@@ -95,6 +118,38 @@ def _at_rest(decode_model) -> dict:
             for w in ws.values()),
         kv_bytes_at_rest=sum(int(leaf.nbytes) for leaf in kv),
         kv_stored_itemsize=kv[0].dtype.itemsize if kv else 0)
+
+
+@dataclasses.dataclass
+class _Step:
+    """One device step from its scheduling to the bookkeeping of its
+    tokens. The engine keeps at most one dispatched and unfetched."""
+
+    # what the call is staged from (host arrays)
+    tokens: np.ndarray
+    positions: np.ndarray
+    read_idx: np.ndarray
+    row_slots: Optional[np.ndarray]
+    writes: dict          # {slot index: positions this step writes}
+    # (slots,): the slot's token is the one the step before sampled for
+    # it, still on the device / the row of this step's samples that is
+    # the slot's next token, -1 for none
+    from_sampled: np.ndarray
+    sampled_row: np.ndarray
+    # [(slot, request)] of the decoding slots: row slot.index samples the
+    # request's next token
+    decoding: list
+    # (slot, request, tokens, laid out as rows, the row that samples the
+    # request's first token or None before its last chunk)
+    chunk: Optional[tuple]
+    span: tuple           # (name, arguments) of the step's span
+    spanned: bool = False
+    # set at dispatch
+    step_fn: object = None
+    dispatched_t: float = 0.0
+    sampled: object = None  # (rows,) tokens: the device's, or a host
+    #                         step function's NumPy array
+    at_once: bool = False   # nothing may be dispatched before its fetch
 
 
 class ServingEngine:
@@ -220,7 +275,17 @@ class ServingEngine:
         self._pre_release_hook = None
         self._suppress_completion_events = False
         self._iterations = 0  # step() calls that found work, ever
+        # the step dispatched and not fetched yet (module docstring), and
+        # the requests a completion of it outside step() finished: the
+        # next step() hands them to its caller
+        self._in_flight: Optional[_Step] = None
+        self._settled: list[Request] = []
+        self._fetched_t = 0.0  # when the last fetch returned
+        self._build_token_feed()
         # run accounting (stats())
+        self._steps = 0  # device steps dispatched
+        self._steps_ahead = 0  # of those, while the one before was unfetched
+        self._rows_discarded = 0  # rows whose request had ended by EOS
         self._decode_iterations = 0
         self._decode_tokens = 0
         self._prefill_tokens = 0
@@ -366,6 +431,7 @@ class ServingEngine:
         from ..resilience.migrate import migrate_state
 
         axes = tuple(int(s) for s in mesh_axis_sizes)
+        self._complete_in_flight()
         old_dec = self.decode_model
         with self._active():
             t0 = time.perf_counter()
@@ -397,6 +463,7 @@ class ServingEngine:
             if self.block_manager is not None:
                 self._copy_fn = new_dec.executor.build_block_copy()
             self._inject_fn = None  # rebuilt lazily on the new executor
+            self._build_token_feed()
             self._chunk_rows = self._rows_serve_chunks()
             self.num_chips = int(new_dec.mesh.devices.size)
             trans = new_dec._transition or {}
@@ -488,42 +555,96 @@ class ServingEngine:
                 specs[name] = spec
         return dec.executor.shard_batch(xs, specs)
 
-    def _run_step(self, tokens: np.ndarray, positions: np.ndarray,
-                  read_idx: np.ndarray, row_slots=None) -> np.ndarray:
-        """One decode-graph call: stage inputs with their searched
-        shardings, run the donated step, return the sampled token of
-        every row (a row samples at its slot's temperature)."""
+    def _build_token_feed(self):
+        """The sampled tokens' way from one step to the next without the
+        host: `_sampled`, the last token sampled for each slot, on the
+        device; `_keep`, which files a step's samples into it by slot;
+        `_feed`, which writes them into the next step's token column
+        where the host does not hold them yet. Each program's shape is
+        that of one step (its rows), never of a pair of steps: it is
+        compiled when that shape's step program first runs."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        dec, slots = self.decode_model, self.spec.slots
+        mesh = dec.executor.mesh
+        whole = NamedSharding(mesh, PartitionSpec())
+        # as _stage_inputs places the token stream: the step program
+        # compiled for its arrays is the one these are run by
+        staged = NamedSharding(
+            mesh, dec._input_partition_spec(self._token_input)
+            or PartitionSpec())
+
+        def feed(tokens, sampled, from_sampled):
+            column = jnp.where(from_sampled, sampled, tokens[:slots, 0])
+            return tokens.at[:slots, 0].set(column)
+
+        def keep(sampled, step_sampled, row):
+            return jnp.where(row >= 0, step_sampled[jnp.maximum(row, 0)],
+                             sampled)
+
+        self._feed = jax.jit(feed, out_shardings=staged)
+        self._keep = jax.jit(keep, out_shardings=whole)
+        self._sampled = jax.device_put(np.zeros((slots,), np.int32), whole)
+
+    def _dispatch(self, step: _Step):
+        """Stage one decode-graph call's inputs with their searched
+        shardings and launch the donated step; its samples (a row samples
+        at its slot's temperature) stay where the step function left
+        them until `_fetch`."""
         import jax
         import jax.numpy as jnp
 
         dec = self.decode_model
         with telemetry.span("serve.stage"):
-            xs = self._stage_inputs(tokens, positions, row_slots)
+            xs = self._stage_inputs(step.tokens, step.positions,
+                                    step.row_slots)
+            xs[self._token_input] = self._feed(
+                xs[self._token_input], self._sampled, step.from_sampled)
             if self._rng is None:
                 self._rng = jax.random.key(dec.config.seed)
             self._rng, sub = jax.random.split(self._rng)
             temp = np.zeros((self.spec.slots,), np.float32)
             for s in self.scheduler.active_slots:
                 temp[s.index] = s.request.temperature
-            if row_slots is not None:
-                temp = temp[row_slots]
-            read_idx = jnp.asarray(read_idx, jnp.int32)
+            if step.row_slots is not None:
+                temp = temp[step.row_slots]
+            read_idx = jnp.asarray(step.read_idx, jnp.int32)
             temp = jnp.asarray(temp)
-        t0 = time.perf_counter()
+        step.step_fn = self._step_fn
+        step.dispatched_t = time.perf_counter()
         with telemetry.span("serve.dispatch"):
-            dec._state, next_tok = self._step_fn(
+            dec._state, step.sampled = self._step_fn(
                 dec._params, dec._state, xs, read_idx, sub, temp)
-        with telemetry.span("serve.fetch"):
-            out = np.asarray(jax.device_get(next_tok))
-        # dispatch plus the blocking fetch on the host's clock (not a
-        # device time): the serve_step_device_s observation and the
-        # speculative decoder's cost feed; the spans above put the same
-        # interval on the profiler's clock
-        dt = time.perf_counter() - t0  # fflint: ok raw_timer_in_hot_path
+            # tokens already on the host (a host function in the step's
+            # place reads the scheduler at its next call), or a step the
+            # sanitizer has to drain: completed before anything else runs
+            step.at_once = (isinstance(step.sampled, np.ndarray)
+                            or bool(dec.config.sanitize_numerics))
+            if not step.at_once:
+                self._sampled = self._keep(self._sampled, step.sampled,
+                                           step.sampled_row)
+
+    def _fetch(self, step: _Step, ahead: bool) -> np.ndarray:
+        """The sampled token of every row of a dispatched step, on the
+        host; `ahead`: the step after it is already dispatched."""
+        import jax
+
+        with telemetry.span("serve.fetch", ahead=int(ahead)):
+            out = np.asarray(jax.device_get(step.sampled))
+        # one step's time on the host's clock (not a device time): from
+        # its dispatch, or from the fetch before it where it queued
+        # behind that step, to its fetch. The serve_step_device_s
+        # observation and the speculative decoder's cost feed; the spans
+        # put the same interval on the profiler's clock
+        now = time.perf_counter()
+        dt = now - max(step.dispatched_t, self._fetched_t)
+        self._fetched_t = now
         self._device_s += dt
         self._last_step_device_s = dt  # speculative decode-cost EMA feed
         self._h_step_device.observe(dt)
-        if dec.config.sanitize_numerics:
+        if self.decode_model.config.sanitize_numerics:
             self._check_numerics()
         return out
 
@@ -566,6 +687,12 @@ class ServingEngine:
             prompt=req.prompt)
 
     def _apply_copies(self, copies):
+        """COW copies on the pool state from outside a step (a handoff's
+        admission, a warm-up): the step in flight is completed first."""
+        self._complete_in_flight()
+        self._copy_blocks(copies)
+
+    def _copy_blocks(self, copies):
         """Run this iteration's COW copies on the pool state in one
         donated dispatch, padded to a power-of-two width with
         scratch→scratch no-op pairs (one cached executable per bucket)."""
@@ -597,7 +724,7 @@ class ServingEngine:
             for idx, positions in slot_positions.items():
                 copies.extend(
                     self.block_manager.ensure_writable(idx, positions))
-            self._apply_copies(copies)
+            self._copy_blocks(copies)
 
     def _note_completion(self, slot, req: Request):
         hook = self._pre_release_hook
@@ -655,6 +782,7 @@ class ServingEngine:
         hook — the completing slot's page table still maps the blocks."""
         import jax
 
+        self._complete_in_flight()
         mgr = self.block_manager
         nblk = -(-num_tokens // mgr.block_size)
         idx = np.asarray(mgr.table(slot_index)[:nblk], np.int32)
@@ -681,6 +809,7 @@ class ServingEngine:
         if mgr is None:
             raise ValueError(
                 "disaggregated admission requires the paged KV layout")
+        self._complete_in_flight()
         if not sched.free_slots:
             return None
         if not mgr.reserve(req.request_id, len(req.prompt),
@@ -777,26 +906,92 @@ class ServingEngine:
         (the longest-waiting prefilling slot's next plan_chunks bucket),
         and advance every decoding slot one token in the same call — the
         chunked-prefill interleave that keeps long prompts from stalling
-        the continuous batch. Returns the requests that completed during
-        this iteration."""
+        the continuous batch. The call dispatches that step and THEN
+        fetches the tokens of the step the call before it dispatched
+        (module docstring): it returns the requests that one completed."""
         sched = self.scheduler
-        done_before = len(sched.completed)
         self._maybe_autoscale()
+        done_before = len(sched.completed)
         with self._active():
-            if sched.drained:
+            if sched.drained and self._in_flight is None:
                 self._publish_slot_gauges([], [])
             else:
                 self._iterations += 1
                 with telemetry.span("serve.iteration",
                                     iteration=self._iterations):
                     self._iterate()
-        return sched.completed[done_before:]
+        return self._take_settled() + sched.completed[done_before:]
+
+    def _take_settled(self) -> list[Request]:
+        done, self._settled = self._settled, []
+        return done
+
+    def _complete_in_flight(self):
+        """Fetch the step in flight, if there is one, and do its
+        bookkeeping: what reads or replaces decode state, or the
+        scheduler's, from outside the step loop calls this first."""
+        step, self._in_flight = self._in_flight, None
+        if step is None:
+            return
+        sched = self.scheduler
+        done_before = len(sched.completed)
+        with self._active():
+            self._complete(step)
+        self._settled += sched.completed[done_before:]
+
+    def _complete(self, step: _Step):
+        with self._span_of(step):
+            tokens = self._fetch(step, ahead=False)
+        self._bookkeep(step, tokens)
+
+    def _span_of(self, step: _Step):
+        """The step's `serve.step` / `serve.prefill` span, once: opened
+        by the call that dispatches it where nothing was in flight, else
+        by the call that fetches it."""
+        if step.spanned:
+            return contextlib.nullcontext()
+        step.spanned = True
+        name, args = step.span
+        return telemetry.span(name, **args)
 
     def _iterate(self):
         """step()'s work, in the phases the profiler's trace shows
-        (docs/observability.md): serve.schedule, serve.prepare_writes,
-        the device call (serve.prefill or serve.step, with serve.stage,
-        serve.dispatch and serve.fetch inside it), serve.bookkeep."""
+        (docs/observability.md): the span of the step this call
+        completes (serve.prefill or serve.step) over serve.schedule,
+        serve.prepare_writes, serve.stage and serve.dispatch of the NEXT
+        step and serve.fetch of its own; then serve.bookkeep."""
+        prev, self._in_flight = self._in_flight, None
+        if prev is not None and prev.step_fn is not self._step_fn:
+            # the step function was replaced since: its replacement finds
+            # the scheduler as the fetched tokens leave it
+            self._complete(prev)
+            prev = None
+        fetched = []
+        with contextlib.ExitStack() as span:
+            if prev is not None:
+                span.enter_context(self._span_of(prev))
+            step = self._schedule()
+            if step is not None:
+                self._prepare_writes(step.writes)
+                if prev is None:
+                    span.enter_context(self._span_of(step))
+                self._steps += 1
+                self._steps_ahead += prev is not None
+                self._dispatch(step)
+                self._advance(step)
+            if prev is not None:
+                fetched.append((prev, self._fetch(prev, step is not None)))
+            if step is not None and step.at_once:
+                fetched.append((step, self._fetch(step, ahead=False)))
+        for of, tokens in fetched:
+            self._bookkeep(of, tokens)
+        if step is not None and not step.at_once:
+            self._in_flight = step
+
+    def _schedule(self) -> Optional[_Step]:
+        """Admissions, the chunk choice and the next step's arrays, from
+        the scheduler as the dispatch of the step before left it; None
+        where no slot has a row to run."""
         sched = self.scheduler
         with telemetry.span("serve.schedule"):
             gate = (self._can_admit
@@ -814,7 +1009,7 @@ class ServingEngine:
             decoding = [s for s in sched.slots if s.decoding]
             self._publish_slot_gauges(prefilling, decoding)
             if not prefilling and not decoding:
-                return
+                return None
 
             # ---- choose this iteration's single prefill chunk (FCFS)
             pre = min(prefilling, key=lambda s: s.admit_seq) \
@@ -825,9 +1020,10 @@ class ServingEngine:
                 if mgr is not None and pre.index not in mgr._tables:
                     # LAZY page-table build: matched against the registry
                     # at first-chunk time, so a burst of same-prefix
-                    # requests still shares — the first resident computed
-                    # and registered its blocks by the time the next one
-                    # prefills (one chunk per iteration, FCFS)
+                    # requests still shares — the first resident's last
+                    # chunk was dispatched, and its blocks registered, by
+                    # the time the next one prefills (one chunk per
+                    # iteration, FCFS)
                     matched = mgr.match_prefix(pre.request.prompt)
                     skip = mgr.admit(pre.index, pre.request.prompt)
                     pre.prefill_pos = skip
@@ -859,98 +1055,131 @@ class ServingEngine:
             read_idx = np.zeros((rows,), np.int32)
             row_slots = None
             writes: dict[int, range] = {}
+            from_sampled = np.zeros((slots,), bool)
+            sampled_row = np.full((slots,), -1, np.int32)
+            chunk = None
             # context rows this step's attention must read, and those
             # it does read where the chunk rides as single-query rows:
             # chunk row i walks its slot's start + i + 1 rows, the
             # chunk's earlier ones again
             kv_rows = kv_rows_walked = sum(s.length + 1 for s in decoding)
             if pre is not None:
-                chunk = pre.request.prompt[start:start + n]
+                piece = pre.request.prompt[start:start + n]
                 at = np.arange(start, start + n, dtype=np.int32)
                 if by_rows:
-                    tokens[slots:slots + n, 0] = chunk
+                    tokens[slots:slots + n, 0] = piece
                     positions[slots:slots + n, 0] = at
                     row_slots = np.r_[np.arange(slots),
                                       np.full((b,), pre.index)]
                     first_row = slots + n - 1
                 else:
-                    tokens[pre.index, :n] = chunk
+                    tokens[pre.index, :n] = piece
                     positions[pre.index, :n] = at
                     read_idx[pre.index] = n - 1
                     first_row = pre.index
+                # the final chunk's last live logits row samples the
+                # request's first token
+                if start + n < L:
+                    first_row = None
+                else:
+                    sampled_row[pre.index] = first_row
+                chunk = (pre, pre.request, n, bool(by_rows), first_row)
                 writes[pre.index] = range(start, start + n)
                 kv_rows += start + n
                 kv_rows_walked += n * start + n * (n + 1) // 2
             for s in decoding:
-                tokens[s.index, 0] = s.last_token
+                # the token the step in flight samples for the slot is
+                # not on the host yet: `_feed` takes it from the device
+                if s.ahead:
+                    from_sampled[s.index] = True
+                else:
+                    tokens[s.index, 0] = s.last_token
                 positions[s.index, 0] = s.length
+                sampled_row[s.index] = s.index
                 writes[s.index] = range(s.length, s.length + 1)
-        self._prepare_writes(writes)
 
-        # counts ride on the span that opens after they are known: an
-        # annotation takes its arguments when it is entered
-        load = dict(kv_rows=int(kv_rows), kv_itemsize=self._kv_itemsize,
-                    admitted=len(admitted), pending=sched.queue_depth)
-        if by_rows:
-            load.update(rows=rows, kv_rows_walked=int(kv_rows_walked))
-        if self._sel_cap:
-            # a layer's indexer scores every cached row of every live
-            # row's context; its attention reads the selected ones
-            ctx = [s.length + 1 for s in decoding]
-            if pre is not None:
-                ctx += range(start + 1, start + n + 1)
-            load.update(ctx_rows=int(sum(ctx)),
-                        sel_rows=int(sum(min(c, self._sel_cap)
-                                         for c in ctx)))
-        if self._moe_fanout:
-            # assignments this step's rows make over all the experts,
-            # padding rows included; those held here are computed
-            # (stats()["moe_assignments"] counts them on the device)
-            load["moe_rows"] = rows * self._moe_fanout
-        span = telemetry.span(
-            "serve.prefill", slot=pre.index,
-            trace=pre.request.trace_id,
-            start=start, tokens=n,
-            prompt_tokens=len(pre.request.prompt),
-            decoding=len(decoding), **load) if pre is not None else \
-            telemetry.span("serve.step", active=len(decoding), **load)
-        with span:
-            next_tok = self._run_step(tokens, positions, read_idx,
-                                      row_slots)
+            # the counts of the step's span, which opens when the step is
+            # dispatched or when it is fetched (`_span_of`)
+            load = dict(kv_rows=int(kv_rows),
+                        kv_itemsize=self._kv_itemsize,
+                        admitted=len(admitted), pending=sched.queue_depth)
+            if by_rows:
+                load.update(rows=rows, kv_rows_walked=int(kv_rows_walked))
+            if self._sel_cap:
+                # a layer's indexer scores every cached row of every live
+                # row's context; its attention reads the selected ones
+                ctx = [s.length + 1 for s in decoding]
+                if pre is not None:
+                    ctx += range(start + 1, start + n + 1)
+                load.update(ctx_rows=int(sum(ctx)),
+                            sel_rows=int(sum(min(c, self._sel_cap)
+                                             for c in ctx)))
+            if self._moe_fanout:
+                # assignments this step's rows make over all the experts,
+                # padding rows included; those held here are computed
+                # (stats()["moe_assignments"] counts them on the device)
+                load["moe_rows"] = rows * self._moe_fanout
+            span = ("serve.prefill", dict(
+                slot=pre.index, trace=pre.request.trace_id,
+                start=start, tokens=n,
+                prompt_tokens=len(pre.request.prompt),
+                decoding=len(decoding), **load)) if pre is not None else \
+                ("serve.step", dict(active=len(decoding), **load))
+            return _Step(
+                tokens=tokens, positions=positions, read_idx=read_idx,
+                row_slots=row_slots, writes=writes,
+                from_sampled=from_sampled, sampled_row=sampled_row,
+                decoding=[(s, s.request) for s in decoding], chunk=chunk,
+                span=span)
 
+    def _advance(self, step: _Step):
+        """The bookkeeping of a dispatched step that needs no token
+        value: the scheduler's state now includes the step, and the next
+        one is scheduled from it."""
+        sched = self.scheduler
+        if step.chunk is not None:
+            pre, req, n, _, first_row = step.chunk
+            pre.prefill_pos += n
+            if first_row is not None:  # the prompt's last chunk
+                pre.length = len(req.prompt)
+                pre.prefill_pos = None
+                if self.block_manager is not None:
+                    self.block_manager.register_prompt(
+                        pre.index, req.prompt)
+                sched.note_dispatch(pre)
+        for s, _ in step.decoding:
+            s.length += 1
+            sched.note_dispatch(s)
+
+    def _bookkeep(self, step: _Step, tokens: np.ndarray):
+        """The bookkeeping of a fetched step: what needs its tokens, and
+        the run's counts of completed work."""
         with telemetry.span("serve.bookkeep"):
-            # ---- prefill bookkeeping (the chunk's writes landed)
-            if pre is not None:
+            if step.chunk is not None:
+                pre, req, n, by_rows, first_row = step.chunk
                 self._prefill_tokens += n
                 self._c_prefill_tok.inc(n)
                 self._prefill_calls += 1
                 self._row_steps += by_rows
-                pre.prefill_pos += n
-                req = pre.request
-                if pre.prefill_pos >= len(req.prompt):
-                    pre.length = len(req.prompt)
-                    pre.prefill_pos = None
-                    if self.block_manager is not None:
-                        self.block_manager.register_prompt(
-                            pre.index, req.prompt)
-                    # the final chunk's last live logits row samples the
-                    # request's first token (TTFT lands here)
-                    self._decode_tokens += 1
-                    prev_t = req.last_token_t
-                    if sched.note_token(pre, int(next_tok[first_row])):
-                        self._note_completion(pre, req)
-                    self._observe_token(req, prev_t)
-            # ---- decode bookkeeping
-            if decoding:
+                if first_row is not None:  # TTFT lands here
+                    self._note_token(pre, req, tokens[first_row])
+            if step.decoding:
                 self._decode_iterations += 1
-            for s in decoding:
-                s.length += 1
-                req = s.request
-                self._decode_tokens += 1
-                prev_t = req.last_token_t
-                if sched.note_token(s, int(next_tok[s.index])):
-                    self._note_completion(s, req)
-                self._observe_token(req, prev_t)
+            for s, req in step.decoding:
+                self._note_token(s, req, tokens[s.index])
+
+    def _note_token(self, slot, req: Request, token):
+        if slot.request is not req:
+            # the request ended by EOS in the step before this one, which
+            # was dispatched by then: the row wrote one cache row inside
+            # the request's own reservation, and its token is dropped
+            self._rows_discarded += 1
+            return
+        self._decode_tokens += 1
+        prev_t = req.last_token_t
+        if self.scheduler.note_token(slot, int(token)):
+            self._note_completion(slot, req)
+        self._observe_token(req, prev_t)
 
     def _observe_token(self, req: Request, prev_t):
         """Latency bookkeeping for one sampled token: the request's first
@@ -1000,12 +1229,16 @@ class ServingEngine:
 
         from ..scope.profile import StepProfiler
 
+        self._complete_in_flight()
         prof = StepProfiler()
         it = self._decode_iterations
         if not prof.begin(it):
             return None
         try:
-            self.step()
+            # the whole of the step inside the capture; what it
+            # completes goes to the caller of the next step()
+            self._settled += self.step()
+            self._complete_in_flight()
             jax.effects_barrier()
         except BaseException:
             prof.abandon()
@@ -1037,6 +1270,10 @@ class ServingEngine:
                 it += 1
                 if max_iterations and it >= max_iterations:
                     break
+            # the last step dispatched (one row that outran an EOS, or
+            # the step the iteration bound stopped behind)
+            self._complete_in_flight()
+            done.extend(self._take_settled())
         self.note_drain(time.perf_counter() - t0)
         return done
 
@@ -1068,7 +1305,11 @@ class ServingEngine:
         """Zero the run accounting (and the completed-request list) —
         benchmark drivers call this after a warm-up drain so the measured
         window starts clean. Live slots/queue state is untouched."""
+        self._complete_in_flight()
         self.scheduler.completed.clear()
+        self._steps = 0
+        self._steps_ahead = 0
+        self._rows_discarded = 0
         self._decode_iterations = 0
         self._decode_tokens = 0
         self._prefill_tokens = 0
@@ -1116,7 +1357,9 @@ class ServingEngine:
         over the last drain's WALL-clock window — scheduler and telemetry
         overhead included, since that is the throughput a client sees
         (`device_s` reports the device-busy slice separately;
-        requests/s/chip is the ROADMAP's serving bench target)."""
+        requests/s/chip is the ROADMAP's serving bench target). Counts
+        are of fetched steps: the step in flight is completed first."""
+        self._complete_in_flight()
         completed = self.scheduler.completed
         sched = self.scheduler
         wall = getattr(self, "_last_wall_s", 0.0) or 0.0
@@ -1137,6 +1380,12 @@ class ServingEngine:
             "max_seq_len": self.max_seq_len,
             "num_chips": self.num_chips,
             "requests_completed": len(completed),
+            # device steps dispatched; of those, the ones dispatched
+            # while the step before was unfetched; rows dispatched for a
+            # request that had ended by EOS, their tokens dropped
+            "iterations": self._steps,
+            "steps_ahead": self._steps_ahead,
+            "rows_discarded": self._rows_discarded,
             "decode_iterations": self._decode_iterations,
             "decode_tokens": self._decode_tokens,
             "prefill_tokens": self._prefill_tokens,

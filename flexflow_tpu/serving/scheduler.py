@@ -87,6 +87,13 @@ class Slot:
         # mapped instead of recomputed); None once decoding
         self.prefill_pos: Optional[int] = None
         self.admit_seq = 0  # admission order (prefill scheduling is FCFS)
+        # tokens a dispatched step samples for the request that no
+        # note_token has recorded yet (the engine keeps one step in
+        # flight: 0 or 1 between its calls)
+        self.ahead = 0
+        # the last of them is the request's last whatever it is (by
+        # max_new_tokens or the cache's length): no further row
+        self.closing = False
 
     @property
     def free(self) -> bool:
@@ -98,19 +105,22 @@ class Slot:
 
     @property
     def decoding(self) -> bool:
-        return self.request is not None and self.prefill_pos is None
+        """Takes a decode row in the next step."""
+        return (self.request is not None and self.prefill_pos is None
+                and not self.closing)
 
-    def assign(self, request: Request):
+    def assign(self, request: Request, length: int = 0,
+               last_token: int = 0, prefill_pos: Optional[int] = 0):
         self.request = request
-        self.length = 0
-        self.last_token = 0
-        self.prefill_pos = 0
+        self.length = length
+        self.last_token = last_token
+        self.prefill_pos = prefill_pos
+        self.ahead = 0
+        self.closing = False
 
     def release(self) -> Request:
         req = self.request
-        self.request = None
-        self.length = 0
-        self.prefill_pos = None
+        self.assign(None, prefill_pos=None)
         return req
 
 
@@ -190,10 +200,8 @@ class ContinuousBatchingScheduler:
         if not free:
             return None
         slot = free[0]
-        slot.request = request
-        slot.length = len(request.prompt)
-        slot.last_token = int(first_token)
-        slot.prefill_pos = None
+        slot.assign(request, length=len(request.prompt),
+                    last_token=int(first_token), prefill_pos=None)
         if request.admit_t is None:
             request.admit_t = time.perf_counter()
         self._admit_counter += 1
@@ -201,6 +209,27 @@ class ContinuousBatchingScheduler:
         return slot
 
     # ------------------------------------------------------------ completion
+
+    def _ends_by_length(self, req: Request, n: int) -> str:
+        """Why the request's n-th token is its last whatever it is, or
+        "". Asked of the request alone (the slot has `len(prompt) + n - 1`
+        rows filled when token n is sampled), so that dispatch and fetch,
+        a step apart, give one answer."""
+        if n >= req.max_new_tokens:
+            return "max_tokens"
+        if len(req.prompt) + n - 1 >= self.max_seq_len:
+            return "length"
+        return ""
+
+    def note_dispatch(self, slot: Slot):
+        """The half of `note_token` that needs no token value, at the
+        dispatch of a step that samples `slot`'s request a token: where
+        that token is the request's last by length, the slot takes no row
+        in the step after (an end by EOS is learnt at the fetch)."""
+        slot.ahead += 1
+        req = slot.request
+        slot.closing = bool(self._ends_by_length(
+            req, len(req.generated) + slot.ahead))
 
     def note_token(self, slot: Slot, token: int) -> bool:
         """Record one sampled token for `slot`'s request; apply the
@@ -215,18 +244,18 @@ class ContinuousBatchingScheduler:
             write past the last real cache row
         """
         req = slot.request
+        # 0 already where the caller samples and notes in one go (a
+        # speculative verify round)
+        slot.ahead = max(slot.ahead - 1, 0)
         req.generated.append(int(token))
         now = time.perf_counter()
         if req.first_token_t is None:
             req.first_token_t = now
         req.last_token_t = now
-        reason = ""
         if req.eos_id is not None and int(token) == int(req.eos_id):
             reason = "eos"
-        elif len(req.generated) >= req.max_new_tokens:
-            reason = "max_tokens"
-        elif slot.length >= self.max_seq_len:
-            reason = "length"
+        else:
+            reason = self._ends_by_length(req, len(req.generated))
         if reason:
             req.finished = True
             req.finish_reason = reason

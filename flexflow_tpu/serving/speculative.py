@@ -585,27 +585,39 @@ class SpeculativeServingEngine(ServingEngine):
 
     # ------------------------------------------------------------ iterate
 
+    def _plain_round(self) -> bool:
+        sched = self.scheduler
+        decoding = [s for s in sched.slots if s.decoding]
+        return bool((sched.pending and sched.free_slots)
+                    or any(s.prefilling for s in sched.slots)
+                    or not decoding
+                    or any(s.request.temperature > 0 for s in decoding))
+
     def step(self) -> list:
         """One scheduler iteration: speculative when the batch is an
         all-greedy decode-only round AND the payoff gate approves; the
-        base chunked-prefill/admission/sampling step otherwise."""
+        base chunked-prefill/admission/sampling step otherwise. A round
+        that may speculate reads every slot's last token and prices
+        itself by one step's dispatch-to-fetch time: it starts from an
+        engine with nothing in flight, and leaves nothing in flight."""
+        if self._plain_round():
+            return super().step()
+        self._complete_in_flight()
+        if self._plain_round():  # what the completed step changed
+            return super().step()
         sched = self.scheduler
         decoding = [s for s in sched.slots if s.decoding]
-        plain = ((sched.pending and sched.free_slots)
-                 or any(s.prefilling for s in sched.slots)
-                 or not decoding
-                 or any(s.request.temperature > 0 for s in decoding))
-        if plain:
-            return super().step()
         caps = self._slot_draft_caps(decoding)
         decision = self._decide(max(caps.values()) if caps else 0)
         if decision["chosen"] == "decode":
             out = super().step()
+            self._complete_in_flight()
             # the round we just ran was decode-only at q=1 — exactly the
             # decode_step_s the payoff inequality prices
             self._update_decode_cost(self._last_step_device_s)
-            return out
-        return self._speculative_round(decoding, caps, decision)
+            return out + self._take_settled()
+        return self._take_settled() + self._speculative_round(
+            decoding, caps, decision)
 
     def _run_verify(self, tokens: np.ndarray,
                     positions: np.ndarray) -> np.ndarray:

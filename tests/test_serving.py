@@ -79,6 +79,20 @@ def _staged_shapes(eng):
     return shapes
 
 
+def _complete_every_step_at_once(eng):
+    """Make `eng` the synchronous loop: a step function that hands its
+    tokens back on the host (a NumPy array) has its step completed
+    before the call that dispatched it returns (docs/serving.md)."""
+    step = eng._step_fn
+
+    def on_the_host(*args):
+        state, sampled = step(*args)
+        return state, np.asarray(sampled)
+
+    eng._step_fn = on_the_host
+    return eng
+
+
 def _teacher_argmax(ff, sequence):
     """Training-graph forward over `sequence`; argmax at every position."""
     import jax
@@ -520,10 +534,15 @@ def test_chunked_prefill_interleaves_with_decode(layout):
     assert s_short.decoding
     gen_before = len(short.generated)
     long_req = eng.submit(list(range(1, 17)))  # 16 tokens = 4 chunks
+    # dispatches the first chunk; fetches the decode step before it
+    calls = eng._prefill_calls
+    eng.step()
+    assert eng._prefill_calls == calls
     progressed = []
     while long_req.first_token_t is None:
         calls = eng._prefill_calls
         eng.step()
+        # the count rises in the call that fetches a chunk step
         assert eng._prefill_calls == calls + 1
         progressed.append(len(short.generated))
     # the decode moved during the long prefill, one token per
@@ -572,7 +591,8 @@ def test_rows_engine_keeps_the_surface_the_benchmark_calls():
         before = eng._prefill_calls
         eng.step()
         seen.append(eng._prefill_calls - before)
-    assert seen == [1, 1, 1, 0]
+    # a call fetches the step the call before it dispatched
+    assert seen == [0, 1, 1, 1, 0]
 
 
 def test_paged_scratch_block_guard():

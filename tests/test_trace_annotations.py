@@ -17,7 +17,9 @@ import pytest
 from flexflow_tpu import telemetry
 from flexflow_tpu.telemetry.tracer import Tracer
 
-from test_serving import ROWS, _build_lm, _build_rows_lm
+from test_serving import (
+    ROWS, _build_lm, _build_rows_lm, _complete_every_step_at_once,
+)
 from test_telemetry import _build_mlp, _train_data
 
 ENGINE_PHASES = ("ff/serve.schedule", "ff/serve.prepare_writes",
@@ -87,27 +89,37 @@ def test_fit_spans_reach_the_profilers_host_plane(tmp_path):
     assert inside(drain, fit) and drain[1] >= steps[-1][2]
 
 
+@pytest.mark.parametrize("at_once", [True, False])
 @pytest.mark.parametrize("layout", ["rectangle", "rows"])
-def test_engine_phases_reach_the_profilers_host_plane(tmp_path, layout):
+def test_engine_phases_reach_the_profilers_host_plane(tmp_path, layout,
+                                                      at_once):
     """Two requests on two slots, prompts of 5 and 2 tokens, 3 new tokens
-    each, chunks of 4: five iterations, whose `kv_rows` (the context rows
+    each, chunks of 4: five steps, whose `kv_rows` (the context rows
     the step's attention must read) are counted by hand below, the same
     in both layouts of a chunk step; laid out as rows, a chunk step also
-    says how many rows it ran and how many context rows they walked."""
+    says how many rows it ran and how many context rows they walked.
+    Completed at once, a step is an iteration with all six phases, the
+    device call's three inside the step's span. Left in flight, a step is
+    dispatched by one iteration and fetched by the next, whose span it
+    is: that span covers the next step's schedule, stage and dispatch
+    and its own fetch."""
     rows = layout == "rows"
     ff = _build_rows_lm() if rows else _build_lm(batch=1)
     eng = ff.serve(slots=2, max_new_tokens=3, prefill_chunk=4,
                    prefix_sharing=False,
                    **(ROWS if rows else {"kv_block_size": 4}))
     assert eng._chunk_rows == rows
+    if at_once:
+        _complete_every_step_at_once(eng)
     eng.generate([[9, 8, 7]])                       # compiles
     first = eng._iterations
     with traced(tmp_path / "trace"):
         eng.generate([[3, 7, 11, 2, 5], [5, 2]])
     spans = program_spans(tmp_path / "trace")
     iterations = named(spans, "ff/serve.iteration")
+    # in flight, a sixth call fetches the fifth step
     assert [s[3]["iteration"] for s in iterations] == [
-        first + i for i in range(1, 6)]
+        first + i for i in range(1, 6 if at_once else 7)]
     calls = named(spans, "ff/serve.prefill", "ff/serve.step")
     assert [c[0] for c in calls] == ["ff/serve.prefill"] * 3 + [
         "ff/serve.step"] * 2
@@ -130,15 +142,41 @@ def test_engine_phases_reach_the_profilers_host_plane(tmp_path, layout):
         assert [c[3]["kv_rows_walked"] for c in calls[:3]] == [10, 5, 9]
     assert not any("rows" in c[3] or "kv_rows_walked" in c[3]
                    for c in calls[0 if not rows else 3:])
-    for it, call in zip(iterations, calls):
-        phases = [s for s in named(spans, *ENGINE_PHASES) if inside(s, it)]
-        assert [p[0] for p in phases] == list(ENGINE_PHASES)
+    phases_of = [[s for s in named(spans, *ENGINE_PHASES) if inside(s, it)]
+                 for it in iterations]
+    for phases in phases_of:
         for a, b in zip(phases, phases[1:]):        # disjoint, in order
             assert a[2] <= b[1]
-        assert inside(call, it)
-        stage, dispatch, fetch = phases[2:5]
-        assert all(inside(p, call) for p in (stage, dispatch, fetch))
-        assert not inside(phases[0], call) and not inside(phases[5], call)
+    ahead = [f[3]["ahead"] for f in named(spans, "ff/serve.fetch")]
+    if at_once:
+        assert ahead == [0] * 5
+        for it, call, phases in zip(iterations, calls, phases_of):
+            assert [p[0] for p in phases] == list(ENGINE_PHASES)
+            assert inside(call, it)
+            stage, dispatch, fetch = phases[2:5]
+            assert all(inside(p, call) for p in (stage, dispatch, fetch))
+            assert (not inside(phases[0], call)
+                    and not inside(phases[5], call))
+    else:
+        # every fetch but the last finds the next step dispatched
+        assert ahead == [1, 1, 1, 1, 0]
+        # the first call dispatches and fetches nothing; the last finds
+        # no row to run and fetches
+        assert [[p[0] for p in phases] for phases in phases_of] == [
+            list(ENGINE_PHASES[:4]), *[list(ENGINE_PHASES)] * 4,
+            [ENGINE_PHASES[0], *ENGINE_PHASES[4:]]]
+        # a step has one span: the first step's in the call that
+        # dispatched it (nothing was in flight), every other in the call
+        # that fetches it, the call after the one that dispatched it
+        assert inside(calls[0], iterations[0])
+        assert all(inside(p, calls[0]) for p in phases_of[0][2:4])
+        assert not named([s for s in spans if inside(s, iterations[1])],
+                         "ff/serve.prefill", "ff/serve.step")
+        for it, call, phases in zip(iterations[2:], calls[1:],
+                                    phases_of[2:]):
+            assert inside(call, it)
+            assert all(inside(p, call) for p in phases[:-1])
+            assert not inside(phases[-1], call)
     # the copy-on-write dispatch sits inside its phase
     prepare = named(spans, "ff/serve.prepare_writes")
     for copy in named(spans, "ff/serve.cow_copy"):
