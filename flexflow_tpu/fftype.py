@@ -229,6 +229,10 @@ class OperatorType(enum.IntEnum):
     # the absorbed form over a paged latent cache (ops/latent_attention.py)
     OP_LATENT_ATTENTION = enum.auto()
     OP_PAGED_LATENT_ATTENTION = enum.auto()
+    # gated delta-rule linear attention (ops/delta_attention.py): the
+    # training-shaped op, and its decode op over per-slot recurrent state
+    OP_GATED_DELTA_ATTENTION = enum.auto()
+    OP_GATED_DELTA_ATTENTION_DECODE = enum.auto()
 
 
 PARALLEL_OP_TYPES = frozenset(

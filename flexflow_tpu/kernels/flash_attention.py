@@ -1345,7 +1345,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *refs,
 
 
 def decode_attention_reference(q, k, v, positions, *, num_heads: int,
-                               scale: float | None = None):
+                               scale: float | None = None,
+                               num_kv_heads: int | None = None):
     """Reference einsum attention over a KV cache — the CPU serving path
     and the decode kernel's numerics oracle. q: (slots, q_len, H·hd) new
     queries, k/v: (slots, S, H·hd) cache (new rows already written),
@@ -1371,9 +1372,14 @@ def decode_attention_reference(q, k, v, positions, *, num_heads: int,
         scale = 1.0 / math.sqrt(d)
 
     def split(t, s):
-        return t.reshape(slots, s, h, d).transpose(0, 2, 1, 3)
+        return t.reshape(slots, s, -1, d).transpose(0, 2, 1, 3)
 
     qh, kh, vh = split(q, q_len), split(k, s_k), split(v, s_k)
+    if num_kv_heads not in (None, h):
+        # grouped keys and values (k, v: num_kv_heads * d wide): query
+        # head i reads KV head i // group
+        kh = jnp.repeat(kh, h // num_kv_heads, axis=1)
+        vh = jnp.repeat(vh, h // num_kv_heads, axis=1)
     logits = jnp.einsum("bhqd,bhkd->bhqk", qh, kh,
                         preferred_element_type=jnp.float32)
     logits = logits * scale
@@ -1488,7 +1494,16 @@ _PAGED_ROUND_VMEM = 8 << 20
 
 
 def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-                         k_buf, v_buf, sem, acc_ref, *, scale: float):
+                         k_buf, v_buf, sem, acc_ref, *, scale: float,
+                         group: int = 0):
+    """`group` > 0: grouped keys and values, `group` query heads reading
+    one KV head. The pool's row is then kv_heads * head_dim wide and the
+    queries come as (heads, head_dim): row h of the block-diagonal query
+    holds head h's lanes at its KV head's offset, so one matmul against a
+    round's rows scores every head and one against its values accumulates
+    every head's output at that offset. Rounds, DMAs, masks and the online
+    softmax are one code; `group` is static, and at 0 the body is what it
+    was before there were groups."""
     s = pl.program_id(0)
     length = len_ref[s]
     width = tbl_ref.shape[1]
@@ -1496,8 +1511,8 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     rows = k_buf.shape[1]
     pages = rows // block_size
     n_rounds = pl.cdiv(length, rows)
-    heads, e = acc_ref.shape
-    head_dim = e // heads
+    heads, e = acc_ref.shape        # e: the pool's row
+    head_dim = q_ref.shape[-1] if group else e // heads
 
     def copies(c, buf):
         """The round's 2·pages DMAs: logical pages [c·pages, (c+1)·pages)
@@ -1519,11 +1534,14 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
             dma.start()
 
     # row h of the block-diagonal query holds q's lanes of head h
+    head = jax.lax.broadcasted_iota(jnp.int32, (heads, e), 0)
     own = (jax.lax.broadcasted_iota(jnp.int32, (heads, e), 1) // head_dim
-           == jax.lax.broadcasted_iota(jnp.int32, (heads, e), 0))
+           == (head // group if group else head))
+    q = q_ref[0].astype(jnp.float32)
+    if group:  # (heads, head_dim): a copy at every KV head's lanes
+        q = jnp.concatenate([q] * (e // head_dim), axis=1)
     # (selected in f32: the int32 compare's mask does not relayout to bf16)
-    q_bd = jnp.where(own, q_ref[0].astype(jnp.float32), 0.0).astype(
-        q_ref.dtype)
+    q_bd = jnp.where(own, q, 0.0).astype(q_ref.dtype)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def round_(c, carry):
@@ -1566,14 +1584,19 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
          jnp.zeros((heads, 1), jnp.float32)))
     # length == 0 (empty slot) ⇒ no round ran, l == 0; the clamp keeps the
     # dead row finite without touching live rows, whose l >= exp(0) = 1
-    out = acc_ref[...] / jnp.maximum(l, 1e-30)
-    o_ref[0] = jnp.where(own, out, 0.0).sum(axis=0, keepdims=True).astype(
-        o_ref.dtype)
+    out = jnp.where(own, acc_ref[...] / jnp.maximum(l, 1e-30), 0.0)
+    if group:  # head h's output lies at its KV head's lanes, 0 elsewhere
+        o = sum(out[:, j * head_dim:(j + 1) * head_dim]
+                for j in range(e // head_dim))
+    else:
+        o = out.sum(axis=0, keepdims=True)
+    o_ref[0] = o.astype(o_ref.dtype)
 
 
 def paged_decode_attention_reference(q, pool_k, pool_v, page_table,
                                      positions, *, num_heads: int,
-                                     scale: float | None = None):
+                                     scale: float | None = None,
+                                     num_kv_heads: int | None = None):
     """Einsum oracle for the paged decode kernel (and the CPU serving
     path, via ops/inc_attention.py): gather each slot's logical cache
     view from the pool through its page table, then run the contiguous
@@ -1587,7 +1610,8 @@ def paged_decode_attention_reference(q, pool_k, pool_v, page_table,
     kc = pool_k[page_table].reshape(slots, W * bs, e).astype(q.dtype)
     vc = pool_v[page_table].reshape(slots, W * bs, e).astype(q.dtype)
     return decode_attention_reference(q, kc, vc, positions,
-                                      num_heads=num_heads, scale=scale)
+                                      num_heads=num_heads, scale=scale,
+                                      num_kv_heads=num_kv_heads)
 
 
 @functools.partial(
@@ -1596,9 +1620,12 @@ def _paged_decode_call(table, lengths, q, pool_k, pool_v, *, num_heads: int,
                        scale: float, pages: int, interpret: bool):
     """The kernel launch (shapes already gated). Jitted so that the memory
     space constraint, which has no eager form, also serves a caller outside
-    jit; inside one it is traced inline."""
+    jit; inside one it is traced inline. A pool row narrower than the
+    queries is grouped keys and values (the kernel's `group`)."""
     slots, _, e = q.shape
-    bs = pool_k.shape[1]
+    bs, e_kv = pool_k.shape[1], pool_k.shape[-1]
+    d = e // num_heads
+    group = 0 if e_kv == e else num_heads // (e_kv // d)
     if not interpret:
         # XLA's memory-space assignment otherwise parks the op's bf16 copy
         # of the pool in VMEM (128 MiB on a v5e) for 23 of 24 layers: the
@@ -1606,7 +1633,8 @@ def _paged_decode_call(table, lengths, q, pool_k, pool_v, *, num_heads: int,
         # HBM roofline reads 115 % (PERF.md section 6, PR 26)
         pool_k, pool_v = (pltpu.with_memory_space_constraint(p, pltpu.HBM)
                           for p in (pool_k, pool_v))
-    qspec = pl.BlockSpec((1, 1, e), lambda s, tbl, ln: (s, 0, 0))
+    qshape = (slots, num_heads, d) if group else (slots, 1, e)
+    qspec = pl.BlockSpec((1,) + qshape[1:], lambda s, tbl, ln: (s, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pltpu.HBM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -1614,22 +1642,23 @@ def _paged_decode_call(table, lengths, q, pool_k, pool_v, *, num_heads: int,
         in_specs=[qspec, pool_spec, pool_spec],
         out_specs=qspec,
         scratch_shapes=[
-            pltpu.VMEM((2, pages * bs, e), pool_k.dtype),
-            pltpu.VMEM((2, pages * bs, e), pool_v.dtype),
+            pltpu.VMEM((2, pages * bs, e_kv), pool_k.dtype),
+            pltpu.VMEM((2, pages * bs, e_kv), pool_v.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((num_heads, e), jnp.float32),
+            pltpu.VMEM((num_heads, e_kv), jnp.float32),
         ],
     )
     return pl.pallas_call(
-        functools.partial(_paged_decode_kernel, scale=scale),
+        functools.partial(_paged_decode_kernel, scale=scale, group=group),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((slots, 1, e), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qshape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
-        name="flash_attention_paged_decode",
-    )(table, lengths, q, pool_k, pool_v)
+        name=("flash_attention_paged_decode_grouped" if group
+              else "flash_attention_paged_decode"),
+    )(table, lengths, q.reshape(qshape), pool_k, pool_v).reshape(slots, 1, e)
 
 
 def _paged_round_pages(block_size: int) -> int:
@@ -1660,7 +1689,7 @@ def paged_decode_gate(cache_rows: int, block_size: int, embed: int,
 
 def paged_flash_decode_attention(
     q, pool_k, pool_v, page_table, lengths, *, num_heads: int,
-    scale: float | None = None,
+    scale: float | None = None, num_kv_heads: int | None = None,
 ):
     """Single-query decode attention over a paged KV pool. q: (rows, 1,
     H·hd); pool_k/v: (num_blocks, block_size, H·hd); page_table: (rows,
@@ -1684,7 +1713,8 @@ def paged_flash_decode_attention(
     if scale is None:
         scale = 1.0 / math.sqrt(e // num_heads)
     interpret = jax.default_backend() != "tpu"
-    gate = paged_decode_gate(W * bs, bs, e, num_heads,
+    kv_heads = num_kv_heads or num_heads
+    gate = paged_decode_gate(W * bs, bs, pool_k.shape[-1], kv_heads,
                              pool_k.dtype.itemsize, interpret)
     if gate is not None:
         warn_reference("paged_flash_decode_attention",
@@ -1692,7 +1722,7 @@ def paged_flash_decode_attention(
         positions = (lengths.astype(jnp.int32) - 1)[:, None]
         return paged_decode_attention_reference(
             q, pool_k, pool_v, page_table, positions,
-            num_heads=num_heads, scale=scale)
+            num_heads=num_heads, scale=scale, num_kv_heads=kv_heads)
     return _paged_decode_call(
         page_table.astype(jnp.int32), lengths.astype(jnp.int32), q, pool_k,
         pool_v, num_heads=num_heads, scale=scale,

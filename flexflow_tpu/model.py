@@ -436,9 +436,16 @@ class FFModel:
         rope_theta: float = 0.0,
         qk_norm: bool = False,
         qk_norm_eps: float = 1e-5,
+        num_kv_heads: int = 0,
+        head_dim: int = 0,
+        output_gate: bool = False,
     ) -> Tensor:
         """`rope_theta` > 0 rotates q and k by the (batch, seq) int
-        `positions`; `qk_norm` RMS-normalises the q and k projections."""
+        `positions`; `qk_norm` RMS-normalises the q and k projections;
+        `num_kv_heads` < num_heads groups the query heads over fewer
+        keys and values; `head_dim` is a head's size where it is not
+        embed_dim / num_heads; `output_gate` multiplies the core's output
+        by sigmoid(query @ wg) (ops/attention.AttentionFrontEnd)."""
         if impl not in ("xla", "flash", "ring"):
             raise ValueError(
                 f"multihead_attention impl must be xla|flash|ring, got {impl!r}"
@@ -447,11 +454,12 @@ class FFModel:
             raise ValueError(
                 "multihead_attention: rope_theta and positions go together")
         front = AttentionFrontEnd(embed_dim, num_heads, bias, rope_theta,
-                                  qk_norm, qk_norm_eps)
+                                  qk_norm, qk_norm_eps, num_kv_heads,
+                                  head_dim, output_gate)
         p = MultiHeadAttentionParams(front, kdim, vdim, dropout, add_bias_kv,
                                      add_zero_attn, causal, impl)
         inits = ({} if kernel_initializer is None
-                 else dict.fromkeys(front.kernels, kernel_initializer))
+                 else dict.fromkeys(front.matrices, kernel_initializer))
         inputs = [query, key, value]
         if positions is not None:
             inputs.append(positions)
@@ -472,6 +480,20 @@ class FFModel:
         return self._add_layer(
             OT.OP_LATENT_ATTENTION, LatentAttentionParams(front),
             [input, positions], name, inits, input.dtype).outputs[0]
+
+    def gated_delta_attention(self, input: Tensor, front,
+                              kernel_initializer: Optional[Initializer] = None,
+                              name: str = "") -> Tensor:
+        """Causal gated delta-rule linear attention on (batch, seq,
+        hidden); `front` is an ops.delta_attention.DeltaFrontEnd
+        (ops/delta_attention.py, which this call imports: no other graph
+        pays for it)."""
+        from .ops.delta_attention import GatedDeltaAttentionParams
+
+        return self._add_layer(
+            OT.OP_GATED_DELTA_ATTENTION, GatedDeltaAttentionParams(front),
+            [input], name, front.initializers(kernel_initializer),
+            input.dtype).outputs[0]
 
     def concat(self, tensors: Sequence[Tensor], axis: int, name: str = "") -> Tensor:
         p = ConcatParams(axis, len(tensors))

@@ -20,6 +20,7 @@ from .transformer import (
     build_transformer_lm_pipelined,
     deepseek_v32_lm_config,
     olmoe_lm_config,
+    solar_open2_lm_config,
     transformer_lm_param_count,
     transformer_lm_state_bytes_per_chip,
 )

@@ -82,7 +82,8 @@ class TransformerLMConfig:
     # mlp="moe" (`olmoe_lm_config`).
     norm: str = "layernorm"        # layernorm | rmsnorm
     norm_eps: float = 1e-5
-    position: str = "learned"      # learned (a wpe table) | rope
+    # learned (a wpe table) | rope | none (no position enters anywhere)
+    position: str = "learned"
     rope_theta: float = 10000.0
     attention_bias: bool = True
     qk_norm: bool = False
@@ -107,10 +108,24 @@ class TransformerLMConfig:
     first_k_dense: int = 0
     moe_routing: Optional[dict] = None
     initializer_range: float = 0.0
+    # Solar-Open2 (`solar_open2_lm_config`): grouped keys and values, a
+    # head's size apart from hidden / heads and a sigmoid output gate on
+    # the softmax layers (ops/attention.AttentionFrontEnd's fields);
+    # `layer_pattern`, one kind a layer ("mha" | "delta"; None = every
+    # layer is `attention`), "delta" = gated delta-rule linear attention
+    # built from `delta` (an ops.delta_attention.DeltaFrontEnd)
+    num_kv_heads: int = 0
+    head_dim: int = 0
+    attention_gate: bool = False
+    layer_pattern: Optional[tuple] = None
+    delta: Optional[object] = None
+
+    def layer_kind(self, i: int) -> str:
+        return self.layer_pattern[i] if self.layer_pattern else self.attention
 
     def __post_init__(self):
         for field, allowed in (("norm", ("layernorm", "rmsnorm")),
-                               ("position", ("learned", "rope")),
+                               ("position", ("learned", "rope", "none")),
                                ("mlp", ("gelu", "swiglu", "moe")),
                                ("attention", ("mha", "latent"))):
             if getattr(self, field) not in allowed:
@@ -122,6 +137,18 @@ class TransformerLMConfig:
             raise ValueError(
                 "TransformerLMConfig.attention 'latent' needs `latent` (a "
                 "LatentFrontEnd) and position 'rope'")
+        if self.layer_pattern is not None:
+            self.layer_pattern = tuple(self.layer_pattern)
+            if (len(self.layer_pattern) != self.num_layers
+                    or set(self.layer_pattern) - {"mha", "delta"}):
+                raise ValueError(
+                    f"TransformerLMConfig.layer_pattern names one kind "
+                    f"('mha' | 'delta') for each of the {self.num_layers} "
+                    f"layers, got {self.layer_pattern!r}")
+            if "delta" in self.layer_pattern and self.delta is None:
+                raise ValueError(
+                    "TransformerLMConfig.layer_pattern 'delta' needs "
+                    "`delta` (a DeltaFrontEnd)")
 
 
 def olmoe_lm_config(**sizes) -> TransformerLMConfig:
@@ -189,6 +216,58 @@ def deepseek_v32_lm_config(config: dict, *, sequence_length: int,
         initializer_range=initializer_range)
 
 
+def solar_open2_lm_config(config: dict, *, sequence_length: int,
+                          attention_impl: str = "xla",
+                          initializer_range: float = 0.02
+                          ) -> TransformerLMConfig:
+    """The Solar-Open2 block from the keys of its published config.json
+    (`model_type: solar_open2`; models/solar_open2_reference.py writes the
+    equations out): layers `gqa_layers` are gated softmax attention with
+    grouped keys and values and no positions, the others gated delta-rule
+    linear attention (`linear_attn_config`), every layer has routed
+    experts under a renormalised softmax router and a shared expert. A
+    cut configuration states `experts_held` / `experts_routed` as
+    DeepSeek-V3.2's does."""
+    from ..ops.delta_attention import DeltaFrontEnd
+
+    if config.get("use_rope") or config["first_k_dense_replace"]:
+        raise NotImplementedError(
+            "solar_open2_lm_config builds the published block: use_rope "
+            "false, first_k_dense_replace 0")
+    lin = config["linear_attn_config"]
+    layers = config["num_hidden_layers"]
+    gqa = set(config["gqa_layers"])
+    held = config.get("experts_held")
+    return TransformerLMConfig(
+        vocab_size=config["vocab_size"], hidden_size=config["hidden_size"],
+        num_heads=config["num_attention_heads"], num_layers=layers,
+        sequence_length=sequence_length, attention_impl=attention_impl,
+        norm="rmsnorm", norm_eps=config["rms_norm_eps"], position="none",
+        attention_bias=False,
+        num_kv_heads=config["num_key_value_heads"],
+        head_dim=config["head_dim"],
+        attention_gate=config["use_gqa_gate"],
+        layer_pattern=tuple("mha" if i in gqa else "delta"
+                            for i in range(layers)),
+        delta=DeltaFrontEnd(
+            embed_dim=config["hidden_size"], num_heads=lin["num_heads"],
+            head_dim=lin["head_dim"],
+            conv_kernel=lin["short_conv_kernel_size"],
+            neg_eigval=config["kda_allow_neg_eigval"],
+            norm_eps=config["rms_norm_eps"]),
+        mlp="moe",
+        num_experts=config.get("experts_routed", config["n_routed_experts"]),
+        num_experts_per_tok=config["num_experts_per_tok"],
+        moe_intermediate_size=config["moe_intermediate_size"],
+        moe_routing=dict(
+            norm_topk_prob=config["norm_topk_prob"],
+            routed_scaling_factor=config["routed_scaling_factor"],
+            shared_intermediate_size=(config["n_shared_experts"]
+                                      * config["moe_intermediate_size"]),
+            experts_held=None if held is None else tuple(held)),
+        initializer_range=initializer_range)
+
+
 def _norm_initializer(stddev: float):
     from ..initializer import NormInitializer
 
@@ -213,7 +292,10 @@ def _lm_trunk(ff, c: TransformerLMConfig, h, pos):
     for i in range(c.num_layers):
         p = f"l{i}_"
         a = _lm_norm(ff, c, h, f"{p}ln1")
-        if c.attention == "latent":
+        if c.layer_kind(i) == "delta":
+            a = ff.gated_delta_attention(a, c.delta, kernel_initializer=init,
+                                         name=f"{p}attn")
+        elif c.attention == "latent":
             a = ff.latent_attention(a, pos, c.latent, kernel_initializer=init,
                                     name=f"{p}attn")
         else:
@@ -223,6 +305,9 @@ def _lm_trunk(ff, c: TransformerLMConfig, h, pos):
                 positions=pos if rope else None,
                 rope_theta=c.rope_theta if rope else 0.0,
                 qk_norm=c.qk_norm, qk_norm_eps=c.norm_eps,
+                num_kv_heads=c.num_kv_heads, head_dim=c.head_dim,
+                output_gate=c.attention_gate,
+                kernel_initializer=init,
             )
         h = ff.add(h, a, name=f"{p}res1")
         m = _lm_norm(ff, c, h, f"{p}ln2")
@@ -268,7 +353,7 @@ def build_transformer_lm(ff, config: TransformerLMConfig | None = None,
                             _norm_initializer(c.initializer_range)))
     pos = ff.create_tensor((bs, c.sequence_length), DataType.DT_INT32,
                            name="positions")
-    if c.position != "rope":  # rotary positions go to the attention ops
+    if c.position == "learned":  # rotary positions go to the attention ops
         hp = ff.embedding(pos, c.sequence_length, c.hidden_size, name="wpe")
         h = ff.add(h, hp, name="embed_add")
     return tokens, _lm_trunk(ff, c, h, pos)

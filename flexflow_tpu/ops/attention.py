@@ -63,25 +63,57 @@ class AttentionFrontEnd:
     # OLMoE does), with learned scales `q_norm` / `k_norm`
     qk_norm: bool = False
     qk_norm_eps: float = 1e-5
+    # grouped keys and values: `num_kv_heads` heads of k and v, query head
+    # i reading KV head i // (num_heads // num_kv_heads); 0 = num_heads
+    num_kv_heads: int = 0
+    # a head's size where it is not embed_dim / num_heads (q, the gate
+    # and the core are then num_heads * head_size wide, `wo` brings them
+    # back to embed_dim); 0 = embed_dim // num_heads
+    head_size: int = 0
+    # the core's output times sigmoid(x @ wg), elementwise, before `wo`
+    output_gate: bool = False
 
+    # the four every attention layer has; `wg` joins them under
+    # `output_gate` (`matrices`)
     kernels: ClassVar[tuple] = ("wq", "wk", "wv", "wo")
 
     @property
+    def matrices(self) -> tuple:
+        """The weights a kernel initializer draws."""
+        return self.kernels + (("wg",) if self.output_gate else ())
+
+    @property
     def head_dim(self) -> int:
-        return self.embed_dim // self.num_heads
+        return self.head_size or self.embed_dim // self.num_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads or self.num_heads
+
+    @property
+    def q_width(self) -> int:
+        return self.num_heads * self.head_dim
+
+    @property
+    def kv_width(self) -> int:
+        """Numbers a token's key (and its value) holds: a cache row."""
+        return self.kv_heads * self.head_dim
 
     def weight_specs(self, q_dim: int, k_dim: int, v_dim: int):
         """The trainable weights, in the order parameters are initialised.
         Per-head projection sizes follow attention.cc:70-80."""
-        E = self.embed_dim
-        ws = [WeightSpec(name, (d, E), DataType.DT_FLOAT)
-              for name, d in zip(self.kernels, (q_dim, k_dim, v_dim, E))]
+        E, Q, KV = self.embed_dim, self.q_width, self.kv_width
+        f = DataType.DT_FLOAT
+        ws = [WeightSpec("wq", (q_dim, Q), f), WeightSpec("wk", (k_dim, KV), f),
+              WeightSpec("wv", (v_dim, KV), f), WeightSpec("wo", (Q, E), f)]
+        if self.output_gate:
+            ws.append(WeightSpec("wg", (q_dim, Q), f))
         if self.use_bias:
-            ws += [WeightSpec(b, (E,), DataType.DT_FLOAT, "zeros")
-                   for b in ("bq", "bk", "bv", "bo")]
+            ws += [WeightSpec(b, (n,), f, "zeros")
+                   for b, n in (("bq", Q), ("bk", KV), ("bv", KV), ("bo", E))]
         if self.qk_norm:
-            ws += [WeightSpec(g, (E,), DataType.DT_FLOAT, "ones")
-                   for g in ("q_norm", "k_norm")]
+            ws += [WeightSpec(g, (n,), f, "ones")
+                   for g, n in (("q_norm", Q), ("k_norm", KV))]
         return ws
 
     def qkv(self, ctx, weights, q_in, k_in, v_in, positions=None):
@@ -99,27 +131,36 @@ class AttentionFrontEnd:
             cos, sin = rope_cos_sin(positions, self.head_dim,
                                     self.rope_theta)
             q = apply_rope(q, cos, sin, self.num_heads)
-            k = apply_rope(k, cos, sin, self.num_heads)
+            k = apply_rope(k, cos, sin, self.kv_heads)
         return q, k, v
 
-    def output(self, ctx, weights, x):
-        return proj(ctx, x, weights["wo"], weights.get("bo"))
+    def output(self, ctx, weights, o, x=None):
+        """The output projection of the core's `o`; `x`, the layer's
+        input, feeds the output gate where the front end has one."""
+        if self.output_gate:
+            gate = proj(ctx, x, weights["wg"], None)
+            o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
+        return proj(ctx, o, weights["wo"], weights.get("bo"))
 
     def linear_flops(self, batch, q_rows, kv_rows, q_dim, k_dim, v_dim):
-        """FLOPs of the four projections over a batch of q_rows queries
-        and kv_rows keys and values."""
-        E = self.embed_dim
-        return 2.0 * batch * (q_rows * q_dim * E + kv_rows * k_dim * E
-                              + kv_rows * v_dim * E + q_rows * E * E)
+        """FLOPs of the projections over a batch of q_rows queries and
+        kv_rows keys and values."""
+        E, Q, KV = self.embed_dim, self.q_width, self.kv_width
+        gate = q_rows * q_dim * Q if self.output_gate else 0
+        return 2.0 * batch * (q_rows * q_dim * Q + kv_rows * k_dim * KV
+                              + kv_rows * v_dim * KV + q_rows * Q * E + gate)
 
     def head_parallel_ok(self, degree: int) -> bool:
-        return self.num_heads % degree == 0 and self.embed_dim % degree == 0
+        return (self.num_heads % degree == 0 and self.kv_heads % degree == 0
+                and self.embed_dim % degree == 0)
 
     def head_parallel(self, axis):
         """(weight name, PartitionSpec) of heads split over mesh axis
-        `axis`: Q, K, V column-parallel with their biases, O row-parallel
-        (its partial sums are the caller's psum) with its bias whole."""
-        return (*((w, PartitionSpec(None, axis)) for w in ("wq", "wk", "wv")),
+        `axis`: Q, K, V (and the gate) column-parallel with their biases,
+        O row-parallel (its partial sums are the caller's psum) with its
+        bias whole."""
+        cols = ("wq", "wk", "wv") + (("wg",) if self.output_gate else ())
+        return (*((w, PartitionSpec(None, axis)) for w in cols),
                 *((b, PartitionSpec(axis)) for b in ("bq", "bk", "bv")),
                 ("wo", PartitionSpec(axis, None)), ("bo", PartitionSpec()))
 
@@ -197,8 +238,20 @@ def _mha_forward(p: MultiHeadAttentionParams, inputs, weights, state, ctx):
     H = front.num_heads
     q, k, v = front.qkv(ctx, weights, *inputs)  # positions fourth, with RoPE
     scale = 1.0 / math.sqrt(front.head_dim)
+    group = H // front.kv_heads
+    impl = p.impl
+    if group > 1 and impl != "xla":
+        # the packed and ring kernels select q, k and v heads by one lane
+        # offset: one head count. Grouped keys and values are repeated
+        # and take the einsum (no training cell runs such a layer)
+        from ..kernels.dispatch import warn_reference
 
-    if p.impl == "flash":
+        warn_reference("multihead_attention", tuple(q.shape),
+                       f"{front.kv_heads} KV heads under {H} query heads: "
+                       f"the {impl} kernels keep one head count")
+        impl = "xla"
+
+    if impl == "flash":
         # the packed kernels select heads with lane-offset block index
         # maps, so the projections' (b, s, H·hd) output feeds them
         # directly: no (b,s,h,d)→(b,h,s,d) HBM relayout in fwd or bwd.
@@ -220,24 +273,27 @@ def _mha_forward(p: MultiHeadAttentionParams, inputs, weights, state, ctx):
                               num_heads=H // shards_of(ctx.mesh, head_ax),
                               causal=p.causal, scale=scale),
             ctx.mesh, (spec, spec, spec), spec)(q, k, v)
-        return [front.output(ctx, weights, out)], state
+        return [front.output(ctx, weights, out, inputs[0])], state
 
     def split_heads(x):
         b, s, _ = x.shape
-        return x.reshape(b, s, H, front.head_dim).transpose(0, 2, 1, 3)
+        x = x.reshape(b, s, -1, front.head_dim).transpose(0, 2, 1, 3)
+        # query head i reads KV head i // group
+        return x if x.shape[1] == H else jnp.repeat(x, group, axis=1)
 
     q, k, v = split_heads(q), split_heads(k), split_heads(v)
-    if p.impl == "ring":
+    if impl == "ring":
         from ..parallel.ring_attention import ring_attention
 
         out = ring_attention(q, k, v, causal=p.causal, scale=scale,
                              mesh=ctx.mesh,
                              overlap=getattr(ctx, "overlap_collectives", True))
     else:
-        out = sdpa_xla(q, k, v, causal=p.causal, scale=scale)
+        with jax.named_scope("gqa.attend"):
+            out = sdpa_xla(q, k, v, causal=p.causal, scale=scale)
     b, _, s, _ = out.shape
-    out = out.transpose(0, 2, 1, 3).reshape(b, s, front.embed_dim)
-    return [front.output(ctx, weights, out)], state
+    out = out.transpose(0, 2, 1, 3).reshape(b, s, front.q_width)
+    return [front.output(ctx, weights, out, inputs[0])], state
 
 
 def _mha_flops(p: MultiHeadAttentionParams, in_shapes, out_shapes):

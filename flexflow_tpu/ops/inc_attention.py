@@ -113,7 +113,7 @@ def _inc_mha_weights(p: IncMultiHeadAttentionParams, in_shapes):
     x = in_shapes[0]
     # the KV cache: stateful (non-trainable), zero-initialized, threaded
     # functionally through the executor's state dict like BatchNorm stats
-    cache = (x[0], p.max_seq_len + 1, p.embed_dim)
+    cache = (x[0], p.max_seq_len + 1, p.front.kv_width)
     return p.front.weight_specs(x[-1], x[-1], x[-1]) + [
         WeightSpec(name, cache, p.cache_dtype, "zeros", trainable=False)
         for name in ("cache_k", "cache_v")]
@@ -144,7 +144,18 @@ def _inc_mha_forward(p: IncMultiHeadAttentionParams, inputs, weights,
     ck = ck.at[slot_idx, write_pos].set(kw.astype(ck.dtype))
     cv = cv.at[slot_idx, write_pos].set(vw.astype(cv.dtype))
 
-    if _use_decode_kernel("inc_multihead_attention", p.impl, q.shape, ctx):
+    kv_heads = p.front.kv_heads
+    if kv_heads != H:
+        # the contiguous kernel keeps one head count (grouped keys and
+        # values are served by the paged kernel): the einsum repeats them
+        from ..kernels.flash_attention import decode_attention_reference
+
+        with jax.named_scope("gqa.attend"):
+            out = decode_attention_reference(
+                q, ck.astype(q.dtype), cv.astype(q.dtype), write_pos,
+                num_heads=H, scale=scale, num_kv_heads=kv_heads)
+    elif _use_decode_kernel("inc_multihead_attention", p.impl, q.shape,
+                            ctx):
         from ..kernels.flash_attention import flash_decode_attention
 
         out = flash_decode_attention(
@@ -156,7 +167,8 @@ def _inc_mha_forward(p: IncMultiHeadAttentionParams, inputs, weights,
         out = decode_attention_reference(
             q, ck.astype(q.dtype), cv.astype(q.dtype), write_pos,
             num_heads=H, scale=scale)
-    return [p.front.output(ctx, weights, out)], {"cache_k": ck, "cache_v": cv}
+    return [p.front.output(ctx, weights, out, x)], {"cache_k": ck,
+                                                    "cache_v": cv}
 
 
 def _inc_mha_flops(p: IncMultiHeadAttentionParams, in_shapes, out_shapes):
@@ -229,8 +241,8 @@ def paged_rows_run_kernel(p: PagedIncMultiHeadAttentionParams, mesh,
 
     return (_kernel_asked(p.impl) and _call_gate(1, mesh) is None
             and paged_decode_gate(
-                p.blocks_per_slot * p.block_size, p.block_size, p.embed_dim,
-                p.num_heads, itemsize,
+                p.blocks_per_slot * p.block_size, p.block_size,
+                p.front.kv_width, p.front.kv_heads, itemsize,
                 jax.default_backend() != "tpu") is None)
 
 
@@ -248,7 +260,7 @@ def _paged_mha_weights(p: PagedIncMultiHeadAttentionParams, in_shapes):
     # the block pool: ONE tensor per layer shared by every slot (a block
     # mapped into N page tables is stored once — the prefix-sharing win),
     # so per-chip accounting counts it once, not per slot
-    pool = (p.num_blocks, p.block_size, p.embed_dim)
+    pool = (p.num_blocks, p.block_size, p.front.kv_width)
     return p.front.weight_specs(x[-1], x[-1], x[-1]) + [
         WeightSpec(name, pool, p.cache_dtype, "zeros", trainable=False)
         for name in ("pool_k", "pool_v")]
@@ -258,7 +270,8 @@ def _paged_mha_forward(p: PagedIncMultiHeadAttentionParams, inputs, weights,
                        state, ctx):
     x, positions, page_table = inputs
     slots = x.shape[0]
-    H, E = p.num_heads, p.embed_dim
+    H, E = p.num_heads, p.front.kv_width
+    kv_heads = p.front.kv_heads
     bs = p.block_size
     W = p.blocks_per_slot
     q, k, v = p.front.qkv(ctx, weights, x, x, x, positions)
@@ -286,10 +299,11 @@ def _paged_mha_forward(p: PagedIncMultiHeadAttentionParams, inputs, weights,
                           ctx):
         from ..kernels.flash_attention import paged_flash_decode_attention
 
-        out = paged_flash_decode_attention(
-            q, pk.astype(q.dtype), pv.astype(q.dtype), page_table,
-            jnp.where(live[:, 0], pos_c[:, 0] + 1, 0),
-            num_heads=H, scale=scale)
+        with jax.named_scope("gqa.attend"):
+            out = paged_flash_decode_attention(
+                q, pk.astype(q.dtype), pv.astype(q.dtype), page_table,
+                jnp.where(live[:, 0], pos_c[:, 0] + 1, 0),
+                num_heads=H, scale=scale, num_kv_heads=kv_heads)
     else:
         # reference path (CPU tier-1 + the kernel's numerics oracle):
         # gather each slot's logical cache view from the pool, then run
@@ -301,9 +315,12 @@ def _paged_mha_forward(p: PagedIncMultiHeadAttentionParams, inputs, weights,
         from ..kernels.flash_attention import decode_attention_reference
 
         read_pos = jnp.where(live, pos_c, -1)
-        out = decode_attention_reference(
-            q, kc, vc, read_pos, num_heads=H, scale=scale)
-    return [p.front.output(ctx, weights, out)], {"pool_k": pk, "pool_v": pv}
+        with jax.named_scope("gqa.attend"):
+            out = decode_attention_reference(
+                q, kc, vc, read_pos, num_heads=H, scale=scale,
+                num_kv_heads=kv_heads)
+    return [p.front.output(ctx, weights, out, x)], {"pool_k": pk,
+                                                    "pool_v": pv}
 
 
 def _paged_mha_flops(p: PagedIncMultiHeadAttentionParams, in_shapes,

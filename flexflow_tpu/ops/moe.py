@@ -356,7 +356,9 @@ class MoEMLPParams:
     # (transformers' load_balancing_loss_func for one layer)
     aux_loss_coef: float = 0.0
     # how the router scores and chooses. "softmax" is OLMoE's (softmax
-    # over all experts, the k largest, not renormalised). "sigmoid" is
+    # over all experts, the k largest; renormalised over the chosen and
+    # scaled only where `norm_topk_prob` / `routed_scaling_factor` say
+    # so, as Solar-Open2 does). "sigmoid" is
     # DeepSeek-V3's: sigmoid scores, a correction bias `router_bias` added
     # for the choice only, the experts in `n_group` groups of which the
     # `topk_group` with the largest two-best sums are kept, the k largest
@@ -542,6 +544,10 @@ def _moe_mlp_forward(p: MoEMLPParams, inputs, weights, state, ctx):
                 x, weights["router"], weights["router_bias"], p)
         else:
             gates, ids, probs = moe_route(x, weights["router"], k)
+            if p.norm_topk_prob:
+                gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+            if p.routed_scaling_factor != 1.0:
+                gates = gates * p.routed_scaling_factor
     with jax.named_scope("moe.dispatch"):
         if p.experts_held is None:
             order, position, group_sizes = moe_sort(ids, n)

@@ -39,14 +39,60 @@ from ..fftype import (
 POOL_LEAVES = ("pool_k", "pool_v", "pool_c", "pool_i")
 # the per-layer KV cache's state leaves, paged and contiguous
 KV_LEAVES = (*POOL_LEAVES, "cache_k", "cache_v")
+# what a recurrent layer keeps a SLOT beside the pool: the delta rule's
+# state and its convolution's last inputs (ops/delta_attention.py). Not
+# paged, not shareable block by block, reset when a slot's row starts a
+# request (position 0)
+STATE_LEAVES = ("state_s", "state_conv")
+
+
+def recurrent_layers(model) -> list:
+    """Names of the graph's layers that carry state from token to token
+    (training graph or decode graph): a prefix matched in the pool is
+    useless to them without the state at its end, a rewound cursor does
+    not rewind them, and the KV handoff does not carry them."""
+    return [l.name for l in model.layers
+            if l.op_type in (OT.OP_GATED_DELTA_ATTENTION,
+                             OT.OP_GATED_DELTA_ATTENTION_DECODE)]
+
+
+def refuse_recurrent(model, what: str):
+    """The KV handoff, a rewound cursor and a matched prefix are sound for
+    attention only: a graph with recurrent layers is refused, not served
+    wrong."""
+    recurrent = recurrent_layers(model)
+    if recurrent:
+        raise NotImplementedError(
+            f"{what} cannot serve a graph with recurrent layers (gated "
+            f"delta-rule attention: {recurrent[0]}, ...): their per-slot "
+            f"state is neither rewound nor handed off")
+
+
+def slot_state_bytes(model, slots: int, at_rest: DataType) -> int:
+    """Bytes the recurrent layers of a training graph keep for `slots`
+    slots in its decode graph: priced beside the pool."""
+    import math
+
+    import jax.numpy as jnp
+
+    if not recurrent_layers(model):
+        return 0
+    from ..ops.delta_attention import GatedDeltaDecodeParams
+
+    tail = jnp.dtype(dtype_to_jnp(at_rest)).itemsize
+    shapes = [GatedDeltaDecodeParams(l.params.front, slots, 0).state_leaves
+              for l in model.layers
+              if l.op_type == OT.OP_GATED_DELTA_ATTENTION]
+    return sum(4 * math.prod(leaves["state_s"])
+               + tail * math.prod(leaves["state_conv"]) for leaves in shapes)
 
 
 def cache_row_widths(layer) -> dict:
     """{pool leaf: numbers a token holds in it} of a training-graph layer
     whose decode op keeps a cache, {} of any other layer."""
     if layer.op_type == OT.OP_MULTIHEAD_ATTENTION:
-        return {"pool_k": layer.params.embed_dim,
-                "pool_v": layer.params.embed_dim}
+        return {"pool_k": layer.params.front.kv_width,
+                "pool_v": layer.params.front.kv_width}
     if layer.op_type == OT.OP_LATENT_ATTENTION:
         return layer.params.front.cache_row_widths
     return {}
@@ -163,7 +209,8 @@ def resolve_pool_blocks(model, spec: ServingSpec, max_seq: int,
                           for width in cache_row_widths(l).values())
         if block_bytes <= 0:
             return capacity
-        budget = 0.9 * hbm - weight_bytes
+        budget = (0.9 * hbm - weight_bytes
+                  - slot_state_bytes(model, spec.slots, at_rest))
         fit = int(budget // block_bytes)
         return max(spec.slots + 1, min(capacity, fit))
     except Exception:
@@ -229,6 +276,12 @@ def build_decode_model(model, spec: ServingSpec):
     if positions is None:
         positions = dec.create_tensor((spec.slots, 1), DataType.DT_INT32,
                                       create_grad=False, name="positions")
+    state_slot = None
+    if recurrent_layers(model):
+        # which slot's state a row reads and writes: row i is slot i, but
+        # for a prefill chunk's rows past the slots (ops/delta_attention.py)
+        state_slot = dec.create_tensor((spec.slots, 1), DataType.DT_INT32,
+                                       create_grad=False, name="state_slot")
     page_table = None
     if paged:
         # one page table feeds every attention layer: block ids index the
@@ -291,6 +344,16 @@ def build_decode_model(model, spec: ServingSpec):
                     [ins[0], positions])
             new = dec._add_layer(op, np_, feeds, name=layer.name,
                                  data_type=layer.data_type)
+        elif layer.op_type == OT.OP_GATED_DELTA_ATTENTION:
+            from ..ops.delta_attention import GatedDeltaDecodeParams
+
+            new = dec._add_layer(
+                OT.OP_GATED_DELTA_ATTENTION_DECODE,
+                GatedDeltaDecodeParams(layer.params.front, spec.slots,
+                                       max_seq, cache_dtype=at_rest),
+                [ins[0], positions, state_slot], name=layer.name,
+                initializers=dict(layer.initializers),
+                data_type=layer.data_type)
         elif layer.op_type == OT.OP_LATENT_ATTENTION:
             from ..ops.latent_attention import PagedLatentAttentionParams
 
@@ -366,7 +429,7 @@ def adopt_params(dec, model) -> int:
         src = (model._state or {}).get(
             model._resolve_weight_owner(node_name), {})
         for wname, old in ws.items():
-            if wname in KV_LEAVES:
+            if wname in KV_LEAVES or wname in STATE_LEAVES:
                 continue
             # a leaf shaped by the graph's rows (the experts a layer chose
             # for each token) is the decode graph's own

@@ -83,6 +83,10 @@ class DisaggregatedServingEngine:
                  **overrides):
         import jax
 
+        from .decode_graph import refuse_recurrent
+
+        refuse_recurrent(model, "disaggregated serving (the handoff "
+                          "carries pool blocks)")
         cfg = model.config
         self.model = model
         self._total_chips = len(jax.devices())

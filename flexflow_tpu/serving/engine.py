@@ -92,7 +92,8 @@ import numpy as np
 from .. import telemetry
 from ..engine.chunking import plan_chunks
 from .decode_graph import (
-    KV_LEAVES, ServingSpec, adopt_params, build_decode_model,
+    KV_LEAVES, STATE_LEAVES, ServingSpec, adopt_params, build_decode_model,
+    recurrent_layers, refuse_recurrent,
 )
 from .paged import SCRATCH_BLOCK, BlockManager
 from .scheduler import ContinuousBatchingScheduler, Request
@@ -176,6 +177,19 @@ class ServingEngine:
             setattr(spec, k, v)
         if spec.prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
+        recurrent = recurrent_layers(model)
+        if recurrent:
+            # a prefix found in the pool is useless without the recurrent
+            # state at its end (snapshots at block boundaries are a
+            # ROADMAP item): such a graph matches no prefix
+            for option in ("prefix_cache", "prefix_sharing"):
+                if overrides.get(option):
+                    raise ValueError(
+                        f"serve(): {option}=True cannot serve a graph with "
+                        f"recurrent layers (gated delta-rule attention: "
+                        f"{recurrent[0]}, ...): the state at a cached "
+                        f"prefix's end is not kept")
+            spec.prefix_cache = spec.prefix_sharing = False
         if spec.prefix_cache is None:
             spec.prefix_cache = bool(
                 getattr(cfg, "serve_prefix_cache", 1))
@@ -241,12 +255,20 @@ class ServingEngine:
         self._moe_fanout = sum(n.params.num_experts_per_tok for n in nodes
                                if n.op_type == OT.OP_MOE_MLP)
         self._moe_base = (0, 0)
+        # the recurrent layers' per-slot state (ops/delta_attention.py):
+        # bytes all the layers keep for one slot, and the requests whose
+        # first chunk was dispatched (each resets its slot's state)
+        self._state_bytes_slot = sum(
+            int(leaf.nbytes) for ws in self.decode_model._state.values()
+            for name, leaf in ws.items() if name in STATE_LEAVES
+        ) // spec.slots
+        self._state_resets = 0
         # graph input roles: exactly one token stream + the positions /
         # page-table feeds (+ constants, which the engine materializes)
         self._token_input = None
         self._const_inputs = {}
         for t in self.decode_model._input_tensors:
-            if t.name in ("positions", "page_table"):
+            if t.name in ("positions", "page_table", "state_slot"):
                 continue
             if hasattr(t, "constant_value"):
                 self._const_inputs[t.name] = (
@@ -543,6 +565,10 @@ class ServingEngine:
                 [mgr.table(i) for i in range(self.spec.slots)], np.int32)
             xs["page_table"] = (table if row_slots is None
                                 else table[row_slots])
+        if self._state_bytes_slot:
+            xs["state_slot"] = np.asarray(
+                np.arange(rows) if row_slots is None else row_slots,
+                np.int32)[:, None]
         for name, (dims, dtype, value) in self._const_inputs.items():
             from ..fftype import dtype_to_jnp
 
@@ -782,6 +808,7 @@ class ServingEngine:
         hook — the completing slot's page table still maps the blocks."""
         import jax
 
+        refuse_recurrent(self.decode_model, "extract_kv (the KV handoff)")
         self._complete_in_flight()
         mgr = self.block_manager
         nblk = -(-num_tokens // mgr.block_size)
@@ -809,6 +836,8 @@ class ServingEngine:
         if mgr is None:
             raise ValueError(
                 "disaggregated admission requires the paged KV layout")
+        refuse_recurrent(self.decode_model,
+                         "admit_prefilled (the KV handoff)")
         self._complete_in_flight()
         if not sched.free_slots:
             return None
@@ -1114,6 +1143,13 @@ class ServingEngine:
                 load.update(ctx_rows=int(sum(ctx)),
                             sel_rows=int(sum(min(c, self._sel_cap)
                                              for c in ctx)))
+            if self._state_bytes_slot:
+                # slots whose recurrent state the step updates (the
+                # decoding ones and the chunk's), and the bytes of it:
+                # read once and written once where the kernel serves
+                updated = len(decoding) + (pre is not None)
+                load.update(state_rows=updated,
+                            state_bytes=updated * self._state_bytes_slot)
             if self._moe_fanout:
                 # assignments this step's rows make over all the experts,
                 # padding rows included; those held here are computed
@@ -1139,6 +1175,8 @@ class ServingEngine:
         sched = self.scheduler
         if step.chunk is not None:
             pre, req, n, _, first_row = step.chunk
+            self._state_resets += bool(self._state_bytes_slot
+                                       and pre.prefill_pos == 0)
             pre.prefill_pos += n
             if first_row is not None:  # the prompt's last chunk
                 pre.length = len(req.prompt)
@@ -1315,6 +1353,7 @@ class ServingEngine:
         self._prefill_tokens = 0
         self._prefill_calls = 0
         self._row_steps = 0
+        self._state_resets = 0
         self._device_s = 0.0
         self._last_wall_s = 0.0
         self._moe_base = self._moe_totals()
@@ -1399,6 +1438,12 @@ class ServingEngine:
             "kv_layout": self.spec.kv_layout,
         }
         out["kv_hbm_bytes_per_layer"] = self.kv_bytes_per_layer()
+        if self._state_bytes_slot:
+            # recurrent layers: slots that hold state, its bytes on the
+            # device (all slots, all layers), slots reset for a new request
+            out["state_slots"] = self.spec.slots
+            out["state_bytes"] = self._state_bytes_slot * self.spec.slots
+            out["state_resets"] = self._state_resets
         if self._moe_nodes:
             done, dropped = self._moe_totals()
             out["moe_assignments"] = done - self._moe_base[0]

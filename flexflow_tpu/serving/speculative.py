@@ -351,6 +351,10 @@ class SpeculativeServingEngine(ServingEngine):
             raise ValueError(
                 "serve(speculate=True) needs draft_model=<a compiled "
                 "FFModel sharing the target's tokenizer/vocab>")
+        from .decode_graph import refuse_recurrent
+
+        refuse_recurrent(model, "speculative decoding (rejected "
+                          "proposals are undone by rewinding a cursor)")
         cfg = model.config
         if draft_chips is None:
             draft_chips = int(getattr(cfg, "serve_draft_chips", 0) or 0)

@@ -173,6 +173,72 @@ def test_grouped_matmul_at_a_serving_steps_rows(tpu, rows):
         assert kernels == {"gmm": 1}
 
 
+@pytest.mark.parametrize("rows", [128, 128 + 32, 128 + 256])
+def test_grouped_matmul_at_solar_open2_widths(tpu, rows):
+    """40 held experts 1,280 wide at 8 assignments a row, 128 slots alone
+    and with a chunk riding as rows: tiles of 640 divide 1,280 and the
+    Pallas grouped matmul serves every step shape."""
+    from flexflow_tpu.kernels import grouped_matmul as gm
+
+    s = _on(tpu[0])
+    for k, n_out in ((4096, 1280), (1280, 4096)):
+        kernels = _kernels(gm.grouped_matmul, s((rows * 8, k)),
+                           s((40, k, n_out)), s((40,), jnp.int32))
+        assert kernels == {"gmm": 1}
+
+
+@pytest.mark.parametrize("rows", [128, 128 + 256])
+def test_grouped_paged_decode_at_solar_open2_widths(tpu, rows):
+    """`solar2-serve-reason`'s softmax layer: 64 query heads over 8 KV
+    heads of 128, a pool of 1,600 blocks of 256 rows 1,024 wide, page
+    tables 17 wide; 128 slots, and the same with a chunk of 256 riding as
+    rows. The grouped body of the paged kernel serves both."""
+    s = _on(tpu[0])
+    pool = s((1600, 256, 8 * 128))
+    kernels = _kernels(
+        lambda q, pk, pv, tbl, n: fa.paged_flash_decode_attention(
+            q, pk, pv, tbl, n, num_heads=64, num_kv_heads=8),
+        s((rows, 1, 64 * 128)), pool, pool, s((rows, 17), jnp.int32),
+        s((rows,), jnp.int32))
+    assert kernels == {"flash_attention_paged_decode_grouped": 1}
+
+
+@pytest.mark.parametrize("rows", [128, 128 + 256])
+def test_delta_rule_decode_layer_at_solar_open2_widths(tpu, rows):
+    """One delta-rule layer of `solar2-serve-reason` as the decode graph
+    runs it: 128 slots of 64 heads x 128 x 128 float32 state, one token a
+    slot, and the same with a chunk of 256 as rows of one slot. The state
+    update is the Pallas kernel (twice with a chunk: the slots', then the
+    chunk's from its slot's state), the state aliased in place: the step
+    needs under 1 GB beside its 537 MB of state."""
+    from flexflow_tpu.fftype import DataType, OperatorType as OT
+    from flexflow_tpu.ops import delta_attention as da
+    from flexflow_tpu.ops.base import OpContext, get_op_def
+
+    s = _on(tpu[0])
+    p = da.GatedDeltaDecodeParams(
+        da.DeltaFrontEnd(4096, 64, 128), slots=128, max_seq_len=4352,
+        cache_dtype=DataType.DT_BFLOAT16)
+    op = get_op_def(OT.OP_GATED_DELTA_ATTENTION_DECODE)
+    specs = op.weights(p, [(rows, 1, 4096)])
+    weights = {w.name: s(w.shape) for w in specs if w.trainable}
+    state = {"state_s": s(p.state_leaves["state_s"], jnp.float32),
+             "state_conv": s(p.state_leaves["state_conv"])}
+
+    def layer(state, weights, x, positions, state_slot):
+        (y,), state = op.forward(
+            p, [x, positions, state_slot], {**weights, **state}, None,
+            OpContext(training=False, mesh=None))
+        return y, state
+
+    compiled = jax.jit(layer, donate_argnums=(0,)).lower(
+        state, weights, s((rows, 1, 4096)), s((rows, 1), jnp.int32),
+        s((rows, 1), jnp.int32)).compile()
+    assert pallas_kernels(compiled.as_text()) == {
+        "delta_rule_update": 1 if rows == 128 else 2}
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
 def test_contiguous_decode_head_dim_128(tpu):
     """The contiguous decode kernel at the engine's real cache shape
     (slots, max_seq + 1, E): max_seq + 1 is odd, so the last kv block is

@@ -268,15 +268,18 @@ def test_a_follow_up_reserves_its_own_blocks_not_a_second_history():
     mgr.check_invariants()
 
 
-def test_the_shares_of_all_chips_add_up_to_the_whole_layer():
+@pytest.mark.parametrize("router", ["sigmoid", "softmax"])
+def test_the_shares_of_all_chips_add_up_to_the_whole_layer(router):
     """The expert op under each of the four sets of held experts, the
-    shared expert counted once, sums to the uncut reference's layer."""
+    shared expert counted once, sums to the uncut reference's layer:
+    under DeepSeek-V3.2's sigmoid group-limited router, and under
+    Solar-Open2's softmax router with renormalised gates (PR 33)."""
+    from flexflow_tpu.models import solar_open2_reference as solar
     from flexflow_tpu.ops import MoEMLPParams
     from flexflow_tpu.ops.base import OpContext, get_op_def
 
     rng = np.random.default_rng(5)
     d, n, f, k = 64, 16, 24, 4
-    cfg = ref.model_cfg(dict(TINY))
     w = {"router": rng.normal(size=(d, n)), "router_bias": 0.1 * rng.normal(
         size=(n,)), "gate": 0.2 * rng.normal(size=(n, d, f)),
         "up": 0.2 * rng.normal(size=(n, d, f)),
@@ -286,27 +289,36 @@ def test_the_shares_of_all_chips_add_up_to_the_whole_layer():
         "shared_down": 0.2 * rng.normal(size=(f, d))}
     w = {name: jnp.asarray(a, jnp.float32) for name, a in w.items()}
     x = jnp.asarray(rng.normal(size=(40, d)), jnp.float32)
+    if router == "sigmoid":
+        cfg, layer = ref.model_cfg(dict(TINY)), ref.expert_layer
+        routing_fields = dict(scoring="sigmoid", n_group=4, topk_group=2,
+                              routed_scaling_factor=2.5)
+    else:
+        w.pop("router_bias")
+        cfg, layer = dict(num_experts_per_tok=k, norm_topk_prob=True,
+                          routed_scaling_factor=1), solar.expert_layer
+        routing_fields = dict(scoring="softmax")
+
+    def held(first, count):
+        return {**w, **{name: w[name][first:first + count]
+                        for name in ("gate", "up", "down")}}
+
     with jax.default_matmul_precision("highest"):
-        whole, routing = ref.expert_layer(x, w, cfg, held=(0, n))
-        shared = ref.gated_mlp(x, w["shared_gate"], w["shared_up"],
-                               w["shared_down"])
+        whole, routing = layer(x, w, cfg, held=(0, n))
+        g = x @ w["shared_gate"]
+        shared = (g * jax.nn.sigmoid(g) * (x @ w["shared_up"])
+                  ) @ w["shared_down"]
     fwd = get_op_def(OT.OP_MOE_MLP).forward
     ctx = OpContext(training=False, mesh=None)
     total = np.zeros_like(np.asarray(whole))
     assignments = 0
     for first in range(0, n, 4):
         p = MoEMLPParams(
-            n, k, f, scoring="sigmoid", n_group=4, topk_group=2,
-            norm_topk_prob=True, routed_scaling_factor=2.5,
-            shared_intermediate_size=f, experts_held=(first, 4))
-        mine = {**w, **{name: w[name][first:first + 4]
-                        for name in ("gate", "up", "down")},
-                }
-        (y,), state = fwd(p, [x], mine, None, ctx)
-        share, _ = ref.expert_layer(
-            x, {**w, **{name: w[name][first:first + 4]
-                        for name in ("gate", "up", "down")}},
-            cfg, held=(first, 4))
+            n, k, f, norm_topk_prob=True, shared_intermediate_size=f,
+            experts_held=(first, 4), **routing_fields)
+        (y,), state = fwd(p, [x], held(first, 4), None, ctx)
+        with jax.default_matmul_precision("highest"):
+            share, _ = layer(x, held(first, 4), cfg, held=(first, 4))
         assert error(y, np.asarray(share)) < TOL
         assert np.array_equal(np.asarray(state["expert_ids"]),
                               np.asarray(routing["ids"]))

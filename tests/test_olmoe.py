@@ -291,6 +291,41 @@ def test_pallas_grouped_matmul_against_ragged_dot(k, n):
         128, 128, 256, 1024, 2560, 131072]
 
 
+def test_grouped_matmul_takes_tiles_that_divide():
+    """Experts 1,280 wide (Solar-Open2): 1,024 does not divide it, 640
+    does; a decode step's few rows a group take a row tile of 128. The
+    shapes the other cells run keep the tiling they were measured at. The
+    kernel under such a tiling (interpreted) against ragged_dot."""
+    from flexflow_tpu.kernels import grouped_matmul as gm
+
+    def tiling(m, g, k, n):
+        s = jax.ShapeDtypeStruct
+        return gm.pallas_tiling(s((m, k), jnp.bfloat16),
+                                s((g, k, n), jnp.bfloat16))[0]
+
+    # solar2-serve-reason: 128 slots' 1,024 assignments, and with a chunk
+    assert tiling(1024, 40, 4096, 1280) == (128, 1024, 640)
+    assert tiling(3072, 40, 1280, 4096) == (128, 640, 1024)
+    # many rows a group keep the large row tile under the smaller tiles
+    assert tiling(131072, 40, 4096, 1280) == (512, 1024, 640)
+    # dsv32-serve-sessions (decode, chunks of 64 / 128 / 256), olmoe-train-4k
+    assert [tiling(m, 16, 7168, 2048) for m in (128, 640, 1152, 2176)] == [
+        (128, 1024, 1024), *[(512, 1024, 1024)] * 3]
+    assert tiling(131072, 64, 2048, 1024) == (512, 1024, 1024)
+    assert gm._tile(1024, 1280) == 640 and gm._tile(1024, 72) == 0
+
+    rng = np.random.default_rng(4)
+    m, g, k, n = 384, 6, 128, 1280
+    x = jnp.asarray(rng.normal(size=(m, k)), jnp.bfloat16)
+    w = jnp.asarray(rng.normal(size=(g, k, n)) * 0.1, jnp.bfloat16)
+    sizes = jnp.asarray([40, 0, 101, 7, 128, 61], jnp.int32)   # 337 of 384
+    tiles = gm.pallas_tiling(x, w)[0]
+    assert tiles == (128, 128, 640)
+    got = gm.grouped_matmul_pallas(x, w, sizes, tiles, interpret=True)
+    close(got[:337], gm.grouped_matmul_reference(x, w, sizes)[:337],
+          tol=2**-7)
+
+
 # ------------------------------------------------ the builder
 
 GPT2_NAMES = ["tokens", "wte", "positions", "wpe", "embed_add",
