@@ -1358,12 +1358,13 @@ def decode_attention_reference(q, k, v, positions, *, num_heads: int,
     speculative verify call (serving/speculative.py) rides the SAME
     multi-query path at q_len=K+1 — each proposal row's logits equal
     what plain decode would compute after the rows before it, which is
-    the whole bit-identity argument; the Pallas kernels below stay
-    q_len=1, so multi-query calls (verify, and prefill chunks laid out
-    as a rectangle) take this einsum on every backend; where the paged
-    kernel serves, the engine hands it a chunk as single-query rows
-    instead (serving/engine.py) — a multi-query Pallas decode kernel is
-    the ROADMAP item that would read a chunk's context once."""
+    the whole bit-identity argument; the Pallas decode kernels below
+    stay q_len=1, so multi-query calls (verify, and prefill chunks laid
+    out as a rectangle) take this einsum on every backend; where the
+    paged kernels serve, the engine hands them a chunk as single-query
+    rows instead (serving/engine.py), and those rows go through one
+    multi-query call that reads the chunk's context once
+    (paged_flash_chunk_attention)."""
     slots, q_len, e = q.shape
     s_k = k.shape[1]
     h = num_heads
@@ -1486,11 +1487,38 @@ def flash_decode_attention(
 # 10,240 steps a call at the grid's 0.16-0.2 us a step, two thirds of them
 # past the cursor. c13b-serve-chat, 24 calls an iteration: 39.8 ms at 2 %
 # of the HBM roofline before, 1.5 ms at 51 % after (PERF.md section 6, PR 26).
+#
+# A row is a slot's one query. A prefill chunk's tokens can ride as rows of
+# this kernel too, each under a copy of its slot's table row, and did from
+# PR 28 to PR 33; every such row walks the slot's pages from page 0, so the
+# op now hands a chunk's rows to the multi-query kernel of the next section
+# and this one keeps them only where that kernel's gate refuses the bucket.
 
 _PAGED_ROUND_ROWS = 128  # cache rows a DMA round, whatever the block size
 # the two double-buffered K and V rounds may take this much of the 16 MiB
 # a Mosaic kernel gets by default; the rest is the compiler's temporaries
 _PAGED_ROUND_VMEM = 8 << 20
+
+
+def _paged_round_copies(block_of, k_hbm, v_hbm, k_buf, v_buf, sem, c, buf,
+                        lanes=None):
+    """A round's 2·pages DMAs: logical pages [c·pages, (c+1)·pages) of one
+    page-table row, K and V, into buffer `buf`. `block_of(page)` is the
+    table's entry for a logical page; a tail page past the table's width
+    re-reads the last entry (the caller clamps) and its rows are masked.
+    `lanes`: the slice of a pool row to copy (a KV-head tile of the chunk
+    kernel), the whole row by default."""
+    block_size = k_hbm.shape[1]
+    pages = k_buf.shape[1] // block_size
+    out = []
+    for p in range(pages):
+        blk = block_of(c * pages + p)
+        dst = pl.ds(p * block_size, block_size)
+        for i, (hbm, vmem) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+            src = hbm.at[blk] if lanes is None else hbm.at[blk, :, lanes]
+            out.append(pltpu.make_async_copy(
+                src, vmem.at[buf, dst], sem.at[i, buf]))
+    return out
 
 
 def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
@@ -1507,26 +1535,15 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     s = pl.program_id(0)
     length = len_ref[s]
     width = tbl_ref.shape[1]
-    block_size = k_hbm.shape[1]
     rows = k_buf.shape[1]
-    pages = rows // block_size
     n_rounds = pl.cdiv(length, rows)
     heads, e = acc_ref.shape        # e: the pool's row
     head_dim = q_ref.shape[-1] if group else e // heads
 
     def copies(c, buf):
-        """The round's 2·pages DMAs: logical pages [c·pages, (c+1)·pages)
-        of this slot, K and V, into buffer `buf`. A tail page past the
-        table's width re-reads the last entry; its rows are masked."""
-        out = []
-        for p in range(pages):
-            blk = tbl_ref[s, jnp.minimum(c * pages + p, width - 1)]
-            dst = pl.ds(p * block_size, block_size)
-            out.append(pltpu.make_async_copy(
-                k_hbm.at[blk], k_buf.at[buf, dst], sem.at[0, buf]))
-            out.append(pltpu.make_async_copy(
-                v_hbm.at[blk], v_buf.at[buf, dst], sem.at[1, buf]))
-        return out
+        return _paged_round_copies(
+            lambda page: tbl_ref[s, jnp.minimum(page, width - 1)],
+            k_hbm, v_hbm, k_buf, v_buf, sem, c, buf)
 
     @pl.when(n_rounds > 0)
     def _first():
@@ -1695,8 +1712,9 @@ def paged_flash_decode_attention(
     H·hd); pool_k/v: (num_blocks, block_size, H·hd); page_table: (rows,
     W) int32 logical→physical block map; lengths: (rows,) int32 live-key
     counts. A row is a slot's one query, or one token of a prefill chunk
-    carrying its slot's page-table row (serving/engine.py): rows may
-    share a table row and outnumber the slots. One grid step a row: the
+    carrying its slot's page-table row (a chunk paged_flash_chunk_attention
+    cannot tile): rows may share a table row and outnumber the slots. One
+    grid step a row: the
     body walks the row's live pages through the scalar-prefetched table,
     whole pool rows DMA'd from HBM a round of ~128 cache rows at a time,
     all heads in one pass (see the section comment). Shapes the kernel
@@ -1727,6 +1745,284 @@ def paged_flash_decode_attention(
         page_table.astype(jnp.int32), lengths.astype(jnp.int32), q, pool_k,
         pool_v, num_heads=num_heads, scale=scale,
         pages=_paged_round_pages(bs), interpret=interpret)
+
+
+# ------------------------------------------------- paged chunk (multi-query)
+# A prefill chunk's rows, one call: the b tokens of a chunk all read ONE
+# slot's cache through ONE page-table row, each up to its own position. As b
+# single-query rows of the kernel above, token i walks the slot's pages from
+# page 0 on its own (n·start + n(n+1)/2 context rows for a chunk of n at
+# `start`, through a block-diagonal query that spends the MXU H times over);
+# here the table row is walked once, a round's K and V serve every query row
+# and every head, and head h multiplies its own head_dim lanes of the query
+# tile with its own lanes of the round: (b, hd) x (hd, rows), then (b, rows)
+# x (rows, hd), with a running max and sum a row a head. The mask is
+# key_pos < length[i]: causality inside the chunk (length start + i + 1) and
+# the bucket's dead padding rows (length 0, output 0) are one rule, and
+# nothing assumes the positions are consecutive. The trip count is
+# cdiv(the tile's max length, rows a round): a page past the chunk's end is
+# never a DMA.
+#
+# The grid is (tiles of query rows, tiles of KV heads). A KV-head tile comes
+# with the query heads that read it (contiguous lanes of the query row:
+# query head i reads KV head i // group) and DMAs its own lanes of the
+# pool's rows, so however many head tiles there are, together they read the
+# context ONCE. A query tile is `_PAGED_CHUNK_QUERY_ROWS` rows at the most,
+# which bounds a step's buffers whatever the chunk, so a chunk of 256 reads
+# its context twice, each tile up to its own last row: a few times, not
+# once a token. The head tile is the largest whose buffers fit
+# `_PAGED_CHUNK_VMEM` (_paged_chunk_tile): at c13b's widths (128 rows x 16
+# heads x 128) all 16 heads, one grid step of 0.5 MB of queries, 1 MB of
+# float32 accumulator and 2 MB of rounds; at Solar-Open2's (256 rows x 64
+# query heads over 8 KV heads) 4 KV heads and their 32 query heads, 2 x 2
+# steps, each round a (256, 512) slice of the pool's rows (whole (16, 128)
+# tiles of the bf16 layout, 4 KB contiguous each).
+#
+# The heads of a tile are a LOOP in the body (`pl.loop`, lane slices at a
+# dynamic multiple of head_dim), not 16 or 32 copies of it: unrolled, the
+# body is a tenth faster in the kernel and nothing end to end, and its 24
+# copies a program (one a layer) cost c13b-serve-chat 7 s of a 47 s set-up,
+# every run, compile cache or not (PERF.md section 6, PR 34).
+#
+# The name does not start with `flash_attention_paged_decode`: the slots'
+# rows keep that kernel and that name, in pure-decode steps and in chunk
+# steps alike, and the benchmark's readers of that prefix see them alone.
+
+_PAGED_CHUNK_QUERY_ROWS = 128
+# what a grid step's buffers may take: the query and output tiles (double
+# buffered by the pipeline), the float32 accumulator, two K and two V rounds
+_PAGED_CHUNK_VMEM = 10 << 20
+
+
+def _paged_chunk_kernel(tbl_ref, max_ref, len_ref, q_ref, k_hbm, v_hbm,
+                        o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref, *,
+                        scale: float, head_dim: int, group: int):
+    """One (query tile, KV-head tile) of a chunk (section comment). q_ref /
+    o_ref: (tile's rows, tile's query lanes); len_ref: (tile's rows, 1);
+    max_ref: the largest length of each query tile; k_buf / v_buf: (2,
+    rows a round, tile's KV lanes); m_ref / l_ref: (tile's query heads,
+    tile's rows); `group`: query heads a KV head, 1 without grouped keys
+    and values."""
+    width = tbl_ref.shape[0]
+    rows, kv_lanes = k_buf.shape[1:]
+    b = q_ref.shape[0]
+    heads = m_ref.shape[0]
+    max_len = max_ref[pl.program_id(0)]
+    n_rounds = pl.cdiv(max_len, rows)
+    lanes = None
+    if kv_lanes != k_hbm.shape[-1]:
+        lanes = pl.ds(pl.multiple_of(pl.program_id(1) * kv_lanes, kv_lanes),
+                      kv_lanes)
+
+    def copies(c, buf):
+        return _paged_round_copies(
+            lambda page: tbl_ref[jnp.minimum(page, width - 1)],
+            k_hbm, v_hbm, k_buf, v_buf, sem, c, buf, lanes)
+
+    @pl.when(n_rounds > 0)
+    def _first():
+        for dma in copies(0, 0):
+            dma.start()
+
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    lengths = len_ref[...]  # (b, 1)
+
+    @pl.loop(0, n_rounds)
+    def _round(c):
+        buf = c % 2
+
+        @pl.when(c + 1 < n_rounds)
+        def _next():
+            for dma in copies(c + 1, 1 - buf):
+                dma.start()
+
+        for dma in copies(c, buf):
+            dma.wait()
+        first = c * rows
+        mask = jax.lax.broadcasted_iota(
+            jnp.int32, (b, rows), 1) + first < lengths
+        # zero V rows past the tile's last: they may hold stale pool state
+        # (anything, NaN included) and 0·NaN would poison the contraction;
+        # rows under it are the slot's own, finite, and masked by p = 0
+        v_live = jax.lax.broadcasted_iota(
+            jnp.int32, (rows, head_dim), 0) + first < max_len
+
+        @pl.loop(0, heads)
+        def _head(h):
+            sl = pl.ds(pl.multiple_of(h * head_dim, head_dim), head_dim)
+            kv = pl.ds(pl.multiple_of(h // group * head_dim, head_dim),
+                       head_dim)
+            logits = jax.lax.dot_general(
+                q_ref[:, sl], k_buf[buf, :, kv], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # (b, rows)
+            logits = jnp.where(mask, logits, NEG_INF)
+            v = jnp.where(v_live, v_buf[buf, :, kv], 0.0)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, logits.max(axis=-1))
+            p = jnp.exp(logits - m_new[:, None])
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * alpha + p.sum(axis=-1)
+            acc_ref[:, sl] = (acc_ref[:, sl] * alpha[:, None]
+                              + jax.lax.dot_general(
+                                  p.astype(v.dtype), v,
+                                  (((1,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32))
+            m_ref[h] = m_new
+
+    # a dead row (length 0) met nothing but masked logits, NEG_INF all, so
+    # its p was 1 for every key of every round: it gives 0, as the
+    # single-query kernel does. A live row's l >= exp(0) = 1.
+    alive = lengths > 0
+
+    @pl.loop(0, heads)
+    def _finish(h):
+        sl = pl.ds(pl.multiple_of(h * head_dim, head_dim), head_dim)
+        o_ref[:, sl] = jnp.where(
+            alive, acc_ref[:, sl] / jnp.maximum(l_ref[h], 1e-30)[:, None],
+            0.0).astype(o_ref.dtype)
+
+
+def _paged_chunk_query_tile(b: int) -> tuple[int, int]:
+    """(rows a query tile, tiles) for a chunk of b rows: whole (16, 128)
+    tiles of bf16, `_PAGED_CHUNK_QUERY_ROWS` at the most; the rows this
+    adds are dead (length 0)."""
+    tq = min(-(-b // 16) * 16, _PAGED_CHUNK_QUERY_ROWS)
+    return tq, -(-b // tq)
+
+
+def _paged_chunk_tile(b: int, embed: int, kv_width: int, num_heads: int,
+                      round_rows: int, itemsize: int,
+                      interpret: bool) -> int | None:
+    """KV heads a grid step of the chunk kernel takes for a chunk of b
+    rows: the most, of the divisors of their number, whose buffers fit
+    `_PAGED_CHUNK_VMEM`; None where not even one does. On hardware a tile
+    that is not the whole row is whole 128-lane tiles of it."""
+    head_dim = embed // num_heads
+    kv_heads = kv_width // head_dim
+    tq, _ = _paged_chunk_query_tile(b)
+    for hk in range(kv_heads, 0, -1):
+        if kv_heads % hk or (hk != kv_heads and not interpret
+                             and hk * head_dim % 128):
+            continue
+        need = (tq * hk * (embed // kv_heads) * (4 * itemsize + 4)
+                + 4 * round_rows * hk * head_dim * itemsize)
+        if need <= _PAGED_CHUNK_VMEM:
+            return hk
+    return None
+
+
+def paged_chunk_gate(b: int, cache_rows: int, block_size: int, embed: int,
+                     kv_width: int, num_heads: int, itemsize: int,
+                     interpret: bool) -> str | None:
+    """Why the chunk kernel cannot take b query rows over a pool of this
+    geometry, or None where it can: the single-query kernel's gate
+    (paged_decode_gate) plus what its own tile needs."""
+    kv_heads = kv_width // (embed // num_heads)
+    gate = paged_decode_gate(cache_rows, block_size, kv_width, kv_heads,
+                             itemsize, interpret)
+    if gate is None and _paged_chunk_tile(
+            b, embed, kv_width, num_heads,
+            _paged_round_pages(block_size) * block_size, itemsize,
+            interpret) is None:
+        gate = (f"a query tile of {_paged_chunk_query_tile(b)[0]} rows x "
+                f"{embed // kv_heads} lanes and its rounds take more than "
+                f"{_PAGED_CHUNK_VMEM} bytes of VMEM")
+    return gate
+
+
+@functools.partial(
+    jax.jit, static_argnames=("num_heads", "scale", "pages", "interpret"))
+def _paged_chunk_call(table_row, lengths, q, pool_k, pool_v, *,
+                      num_heads: int, scale: float, pages: int,
+                      interpret: bool):
+    """The kernel launch (shapes already gated), jitted for the memory
+    space constraint as _paged_decode_call is. q: (b, H·hd)."""
+    b, e = q.shape
+    bs, e_kv = pool_k.shape[1], pool_k.shape[-1]
+    d = e // num_heads
+    group = e // e_kv
+    rows = pages * bs
+    tq, q_tiles = _paged_chunk_query_tile(b)
+    hk = _paged_chunk_tile(b, e, e_kv, num_heads, rows,
+                           pool_k.dtype.itemsize, interpret)
+    pad = tq * q_tiles - b
+    if pad:
+        q = jnp.pad(q, ((0, pad), (0, 0)))
+        lengths = jnp.pad(lengths, (0, pad))
+    if not interpret:
+        # as _paged_decode_call: the pool is read from HBM, not from a copy
+        # XLA parks in VMEM (PERF.md section 6, PR 26)
+        pool_k, pool_v = (pltpu.with_memory_space_constraint(p, pltpu.HBM)
+                          for p in (pool_k, pool_v))
+    heads = hk * group
+    qspec = pl.BlockSpec((tq, heads * d), lambda i, j, tbl, mx: (i, j))
+    pool_spec = pl.BlockSpec(memory_space=pltpu.HBM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(q_tiles, e_kv // (hk * d)),
+        in_specs=[pl.BlockSpec((tq, 1), lambda i, j, tbl, mx: (i, 0)),
+                  qspec, pool_spec, pool_spec],
+        out_specs=qspec,
+        scratch_shapes=[
+            pltpu.VMEM((2, rows, hk * d), pool_k.dtype),
+            pltpu.VMEM((2, rows, hk * d), pool_v.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((heads, tq), jnp.float32),
+            pltpu.VMEM((heads, tq), jnp.float32),
+            pltpu.VMEM((tq, heads * d), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_paged_chunk_kernel, scale=scale, head_dim=d,
+                          group=group),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+        ),
+        interpret=interpret,
+        name=("flash_attention_paged_chunk_grouped" if group > 1
+              else "flash_attention_paged_chunk"),
+    )(table_row, lengths.reshape(q_tiles, tq).max(axis=1), lengths[:, None],
+      q, pool_k, pool_v)
+    return out[:b]
+
+
+def paged_flash_chunk_attention(
+    q, pool_k, pool_v, table_row, lengths, *, num_heads: int,
+    scale: float | None = None, num_kv_heads: int | None = None,
+):
+    """Multi-query attention of ONE prefill chunk over a paged KV pool. q:
+    (b, 1, H·hd), the chunk's rows; pool_k/v: (num_blocks, block_size,
+    KV·hd); table_row: (W,) int32, the page-table row they all read
+    through; lengths: (b,) int32 live-key counts a row (`start + i + 1`
+    for the chunk's token i once its K and V are in the pool, 0 for a dead
+    padding row, whose output is 0). Equal to paged_flash_decode_attention
+    on the same rows under b copies of the table row, which is what a
+    shape the kernel cannot tile gets (paged_chunk_gate): never the
+    reference where the single-query kernel would serve, since a row
+    through the reference gathers a whole logical cache."""
+    b, q_len, e = q.shape
+    if q_len != 1:
+        raise ValueError(
+            f"a chunk comes as single-query rows (got q_len={q_len})")
+    bs = pool_k.shape[1]
+    W = table_row.shape[0]
+    if scale is None:
+        scale = 1.0 / math.sqrt(e // num_heads)
+    interpret = jax.default_backend() != "tpu"
+    if paged_chunk_gate(b, W * bs, bs, e, pool_k.shape[-1], num_heads,
+                        pool_k.dtype.itemsize, interpret) is not None:
+        return paged_flash_decode_attention(
+            q, pool_k, pool_v, jnp.broadcast_to(table_row, (b, W)), lengths,
+            num_heads=num_heads, scale=scale, num_kv_heads=num_kv_heads)
+    return _paged_chunk_call(
+        table_row.astype(jnp.int32), lengths.astype(jnp.int32), q[:, 0],
+        pool_k, pool_v, num_heads=num_heads, scale=scale,
+        pages=_paged_round_pages(bs), interpret=interpret)[:, None]
 
 
 def flash_attention(

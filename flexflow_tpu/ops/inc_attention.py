@@ -39,10 +39,14 @@ decode replay hands over a trained layer's whole, so its parameters
 transfer to the decode graph by name. On TPU
 the q_len=1 path routes through the Pallas decode kernel
 (kernels/flash_attention.flash_decode_attention); CPU meshes use the
-reference einsum so tier-1 exercises serving end-to-end. Nothing here
-asks what the leading dimension means: the paged op's rows may be slots,
-or a prefill chunk's tokens one a row beside them, each row with its own
-page-table row (serving/engine.py, paged_rows_run_kernel below).
+reference einsum so tier-1 exercises serving end-to-end. The paged op's
+rows may be slots, or a prefill chunk's tokens one a row beside them,
+each row with its own page-table row (serving/engine.py,
+paged_rows_run_kernel below). What the op knows of them is
+`chunk_from`, a fact of the decode graph: rows past it are ONE chunk
+under one table row, and where the paged kernel serves the call they go
+through one multi-query call that reads the chunk's context once
+(kernels/flash_attention.paged_flash_chunk_attention).
 """
 
 from __future__ import annotations
@@ -218,6 +222,12 @@ class PagedIncMultiHeadAttentionParams(FrontEndFields):
     num_blocks: int     # physical pool blocks, block 0 = reserved scratch
     impl: str = "auto"  # auto: paged flash decode on TPU (q_len=1)
     cache_dtype: DataType = DataType.DT_FLOAT  # as the contiguous op's
+    # rows from here on, where a (rows, 1) call has any, are the tokens of
+    # ONE prefill chunk and share the page-table row of the first of them
+    # (the contract of ops/latent_attention.py's module docstring; a fact
+    # of the decode graph, serving/decode_graph.py sets it to the slots).
+    # None: every row stands alone under its own table row
+    chunk_from: int | None = None
 
     @property
     def blocks_per_slot(self) -> int:
@@ -244,6 +254,34 @@ def paged_rows_run_kernel(p: PagedIncMultiHeadAttentionParams, mesh,
                 p.blocks_per_slot * p.block_size, p.block_size,
                 p.front.kv_width, p.front.kv_heads, itemsize,
                 jax.default_backend() != "tpu") is None)
+
+
+def _chunk_gate(p: PagedIncMultiHeadAttentionParams, b: int,
+                itemsize: int) -> str | None:
+    """Why the b rows past `chunk_from` of a call the paged kernel serves
+    cannot go through the multi-query chunk kernel, or None."""
+    from ..kernels.flash_attention import paged_chunk_gate
+
+    return paged_chunk_gate(
+        b, p.blocks_per_slot * p.block_size, p.block_size,
+        p.num_heads * p.front.head_dim, p.front.kv_width, p.num_heads,
+        itemsize, jax.default_backend() != "tpu")
+
+
+def paged_chunk_query_tile(p: PagedIncMultiHeadAttentionParams, mesh,
+                           itemsize: int, b: int) -> int | None:
+    """Query rows a tile of the chunk kernel takes where it serves a
+    chunk of b rows riding a (rows, 1) call of this op, None where those
+    rows go through the single-query kernel one by one (or no kernel
+    serves the call at all). Each tile reads the chunk's context once, up
+    to its own last row: the serving engine counts the context rows a
+    chunk step reads by this (`kv_rows_walked`, `chunk_kernel_steps`)."""
+    from ..kernels.flash_attention import _paged_chunk_query_tile
+
+    if (p.chunk_from is None or not paged_rows_run_kernel(p, mesh, itemsize)
+            or _chunk_gate(p, b, itemsize) is not None):
+        return None
+    return _paged_chunk_query_tile(b)[0]
 
 
 def _paged_mha_infer(p: PagedIncMultiHeadAttentionParams, in_shapes):
@@ -297,13 +335,27 @@ def _paged_mha_forward(p: PagedIncMultiHeadAttentionParams, inputs, weights,
 
     if _use_decode_kernel("paged_inc_multihead_attention", p.impl, q.shape,
                           ctx):
-        from ..kernels.flash_attention import paged_flash_decode_attention
+        from ..kernels.flash_attention import (
+            paged_flash_chunk_attention, paged_flash_decode_attention,
+        )
 
+        pools = pk.astype(q.dtype), pv.astype(q.dtype)
+        lengths = jnp.where(live[:, 0], pos_c[:, 0] + 1, 0)
+        kw = dict(num_heads=H, scale=scale, num_kv_heads=kv_heads)
+        # the slots' rows, each under its own table row; where rows past
+        # them are a chunk's and its kernel takes them, those under ONE
+        # table row in one multi-query call (every row's K and V are in
+        # the pool by now), else the same single-query kernel, row by row
+        n = slots if p.chunk_from is None else min(slots, p.chunk_from)
+        if n < slots and _chunk_gate(p, slots - n,
+                                     q.dtype.itemsize) is not None:
+            n = slots
         with jax.named_scope("gqa.attend"):
             out = paged_flash_decode_attention(
-                q, pk.astype(q.dtype), pv.astype(q.dtype), page_table,
-                jnp.where(live[:, 0], pos_c[:, 0] + 1, 0),
-                num_heads=H, scale=scale, num_kv_heads=kv_heads)
+                q[:n], *pools, page_table[:n], lengths[:n], **kw)
+            if n < slots:
+                out = jnp.concatenate([out, paged_flash_chunk_attention(
+                    q[n:], *pools, page_table[n], lengths[n:], **kw)])
     else:
         # reference path (CPU tier-1 + the kernel's numerics oracle):
         # gather each slot's logical cache view from the pool, then run

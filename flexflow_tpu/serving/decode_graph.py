@@ -333,7 +333,8 @@ def build_decode_model(model, spec: ServingSpec):
                     OT.OP_PAGED_INC_MULTIHEAD_ATTENTION,
                     PagedIncMultiHeadAttentionParams(
                         p.front, max_seq, spec.kv_block_size, num_blocks,
-                        impl=spec.impl, cache_dtype=at_rest),
+                        impl=spec.impl, cache_dtype=at_rest,
+                        chunk_from=spec.slots),
                     [ins[0], positions, page_table])
             else:
                 op, np_, feeds = (

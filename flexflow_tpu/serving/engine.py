@@ -44,10 +44,17 @@ ROWS, `(slots + q, 1)`: the slots exactly as a pure-decode step has them,
 then each token of the chunk as a single-query row of its own, at its own
 position, carrying a copy of its slot's page-table row — the op writes
 every row's K and V before any row reads, so row i of a chunk at `start`
-attends `start + i + 1` keys through the paged decode kernel's length
-mask. The engine takes rows where a `(rows, 1)` call is served by that
-kernel (ops/inc_attention.paged_rows_run_kernel, asked when the engine is
-built) and the rectangle everywhere else: through the gather-and-einsum
+attends `start + i + 1` keys by a length mask. The decode graph tells its
+paged ops that rows past the slots are one chunk under one table row
+(`chunk_from`), so the chunk's rows go through ONE multi-query call of
+the paged chunk kernel, which walks that table row once for all of them
+(kernels/flash_attention.py); where that kernel cannot tile the bucket
+they go through the single-query paged decode kernel like the slots'
+rows, each walking its slot's pages from the start (`kv_rows_walked`,
+`stats()["chunk_kernel_steps"]`). The engine takes rows where a `(rows,
+1)` call is served by the paged kernels
+(ops/inc_attention.paged_rows_run_kernel, asked when the engine is built)
+and the rectangle everywhere else: through the gather-and-einsum
 reference a row costs a whole logical cache.
 
 **KV layouts** (`--serve-kv-layout`, ServingSpec.kv_layout):
@@ -144,6 +151,8 @@ class _Step:
     # request's first token or None before its last chunk)
     chunk: Optional[tuple]
     span: tuple           # (name, arguments) of the step's span
+    # the chunk's rows are one call of the paged chunk kernel
+    chunk_kernel: bool = False
     spanned: bool = False
     # set at dispatch
     step_fn: object = None
@@ -242,6 +251,7 @@ class ServingEngine:
                 self.decode_model.executor.build_block_copy())
         self._kv_itemsize = at_rest["kv_stored_itemsize"]
         self._chunk_rows = self._rows_serve_chunks()
+        self._chunk_tiles: dict[int, Optional[int]] = {}
         # what a step's spans say of sparse latent attention and of the
         # expert layers (docs/observability.md): the positions a row
         # attends at the most, and the experts a row is sent to summed
@@ -313,6 +323,8 @@ class ServingEngine:
         self._prefill_tokens = 0
         self._prefill_calls = 0
         self._row_steps = 0  # of those, the ones laid out as rows
+        # and of those, the ones whose chunk the paged chunk kernel took
+        self._chunk_kernel_steps = 0
         self._device_s = 0.0
         self._last_step_device_s = 0.0  # most recent device call's wall
         # ffpulse metrics plane: engine-owned registry so serving metrics
@@ -388,6 +400,26 @@ class ServingEngine:
             or paged_rows_run_kernel(n.params, dec.executor.mesh,
                                      self._kv_itemsize)
             for n in dec.graph.topo_order() if n.op_type in PAGED_OPS)
+
+    def _chunk_query_tile(self, b: int) -> Optional[int]:
+        """Query rows a tile of the paged chunk kernel takes of a chunk
+        that rides as `b` rows, None where the chunk's rows go through
+        the single-query kernel (or the graph has another paged op):
+        asked of the built graph's ops once a bucket, as the op asks
+        itself when the bucket's program is traced
+        (ops/inc_attention.paged_chunk_query_tile)."""
+        if b not in self._chunk_tiles:
+            from ..ops.inc_attention import paged_chunk_query_tile
+
+            dec = self.decode_model
+            # one answer for the graph: its paged layers are alike
+            tiles = {
+                n.op_type == OT.OP_PAGED_INC_MULTIHEAD_ATTENTION
+                and paged_chunk_query_tile(n.params, dec.executor.mesh,
+                                           self._kv_itemsize, b) or None
+                for n in dec.graph.topo_order() if n.op_type in PAGED_OPS}
+            self._chunk_tiles[b] = tiles.pop() if len(tiles) == 1 else None
+        return self._chunk_tiles[b]
 
     def enable_autoscale(self, visible_devices_fn=None,
                          check_every: int = 16):
@@ -487,6 +519,7 @@ class ServingEngine:
             self._inject_fn = None  # rebuilt lazily on the new executor
             self._build_token_feed()
             self._chunk_rows = self._rows_serve_chunks()
+            self._chunk_tiles = {}
             self.num_chips = int(new_dec.mesh.devices.size)
             trans = new_dec._transition or {}
             decision.update({
@@ -1088,10 +1121,13 @@ class ServingEngine:
             sampled_row = np.full((slots,), -1, np.int32)
             chunk = None
             # context rows this step's attention must read, and those
-            # it does read where the chunk rides as single-query rows:
-            # chunk row i walks its slot's start + i + 1 rows, the
-            # chunk's earlier ones again
+            # its kernels do read where the chunk rides as rows: a tile
+            # of the chunk kernel reads the context once for its rows, up
+            # to the last of them; through the single-query kernel chunk
+            # row i walks its slot's start + i + 1 rows, the chunk's
+            # earlier ones again
             kv_rows = kv_rows_walked = sum(s.length + 1 for s in decoding)
+            tile = self._chunk_query_tile(b) if by_rows else None
             if pre is not None:
                 piece = pre.request.prompt[start:start + n]
                 at = np.arange(start, start + n, dtype=np.int32)
@@ -1115,7 +1151,9 @@ class ServingEngine:
                 chunk = (pre, pre.request, n, bool(by_rows), first_row)
                 writes[pre.index] = range(start, start + n)
                 kv_rows += start + n
-                kv_rows_walked += n * start + n * (n + 1) // 2
+                kv_rows_walked += (
+                    sum(start + min(n, t + tile) for t in range(0, n, tile))
+                    if tile else n * start + n * (n + 1) // 2)
             for s in decoding:
                 # the token the step in flight samples for the slot is
                 # not on the host yet: `_feed` takes it from the device
@@ -1166,7 +1204,7 @@ class ServingEngine:
                 row_slots=row_slots, writes=writes,
                 from_sampled=from_sampled, sampled_row=sampled_row,
                 decoding=[(s, s.request) for s in decoding], chunk=chunk,
-                span=span)
+                chunk_kernel=tile is not None, span=span)
 
     def _advance(self, step: _Step):
         """The bookkeeping of a dispatched step that needs no token
@@ -1199,6 +1237,7 @@ class ServingEngine:
                 self._c_prefill_tok.inc(n)
                 self._prefill_calls += 1
                 self._row_steps += by_rows
+                self._chunk_kernel_steps += step.chunk_kernel
                 if first_row is not None:  # TTFT lands here
                     self._note_token(pre, req, tokens[first_row])
             if step.decoding:
@@ -1353,6 +1392,7 @@ class ServingEngine:
         self._prefill_tokens = 0
         self._prefill_calls = 0
         self._row_steps = 0
+        self._chunk_kernel_steps = 0
         self._state_resets = 0
         self._device_s = 0.0
         self._last_wall_s = 0.0
@@ -1432,6 +1472,7 @@ class ServingEngine:
             # of those, the steps laid out as single-query rows: all of
             # them where the paged kernel serves the rows, else none
             "row_steps": self._row_steps,
+            "chunk_kernel_steps": self._chunk_kernel_steps,
             "wall_s": wall,
             "device_s": self._device_s,
             "plan_source": self.decode_model._plan_source,

@@ -203,6 +203,74 @@ def test_grouped_paged_decode_at_solar_open2_widths(tpu, rows):
     assert kernels == {"flash_attention_paged_decode_grouped": 1}
 
 
+def _paged_attention_layer(front, max_seq, block_size, blocks, slots):
+    """(forward over (x, positions, page_table) -> y, weight shapes) of
+    the paged attention op as a bf16 decode graph built for `slots` holds
+    it: rows past the slots are one chunk (`chunk_from`)."""
+    from flexflow_tpu.fftype import DataType, OperatorType as OT
+    from flexflow_tpu.ops import inc_attention as inc
+    from flexflow_tpu.ops.base import OpContext, get_op_def
+
+    p = inc.PagedIncMultiHeadAttentionParams(
+        front, max_seq, block_size, blocks, impl="flash",
+        cache_dtype=DataType.DT_BFLOAT16, chunk_from=slots)
+    op = get_op_def(OT.OP_PAGED_INC_MULTIHEAD_ATTENTION)
+
+    def layer(weights, x, positions, page_table):
+        (y,), state = op.forward(p, [x, positions, page_table], weights,
+                                 None, OpContext(training=False, mesh=None))
+        return y, state
+
+    return p, op, layer
+
+
+@pytest.mark.parametrize("chunk", [128, 16])
+def test_paged_chunk_kernel_at_c13b_widths(tpu, chunk):
+    """`c13b-serve-chat`'s chunk step as the decode graph runs a layer of
+    it: 16 slots through the single-query kernel, the chunk's 128 (or 16)
+    rows through ONE call of the multi-query chunk kernel under the table
+    row of the first of them; 16 heads of 128, blocks of 16, tables 40
+    wide. A pure-decode call keeps the single-query kernel alone."""
+    from flexflow_tpu.ops.attention import AttentionFrontEnd
+
+    s = _on(tpu[0])
+    p, op, layer = _paged_attention_layer(
+        AttentionFrontEnd(2048, 16), 640, 16, 641, slots=16)
+
+    def shapes(rows):
+        specs = op.weights(p, [(rows, 1, 2048), (rows, 1), (rows, 40)])
+        return ({w.name: s(w.shape) for w in specs}, s((rows, 1, 2048)),
+                s((rows, 1), jnp.int32), s((rows, 40), jnp.int32))
+
+    assert _kernels(layer, *shapes(16 + chunk)) == {
+        "flash_attention_paged_decode": 1, "flash_attention_paged_chunk": 1}
+    assert _kernels(layer, *shapes(16)) == {
+        "flash_attention_paged_decode": 1}
+
+
+@pytest.mark.parametrize("chunk", [256, 8])
+def test_grouped_paged_chunk_kernel_at_solar_open2_widths(tpu, chunk):
+    """`solar2-serve-reason`'s softmax layer in a chunk step: 128 slots
+    through the grouped single-query kernel, a chunk of 256 (or the
+    smallest bucket, 8) through the grouped chunk kernel: 64 query heads
+    of 128 over 8 KV heads, blocks of 256, tables 17 wide; a query tile
+    is 128 rows and a head tile 4 KV heads with their 32 query heads."""
+    from flexflow_tpu.ops.attention import AttentionFrontEnd
+
+    s = _on(tpu[0])
+    front = AttentionFrontEnd(4096, 64, use_bias=False, num_kv_heads=8,
+                              head_size=128, output_gate=True)
+    p, op, layer = _paged_attention_layer(front, 4352, 256, 1600, slots=128)
+    assert fa._paged_chunk_tile(256, 8192, 1024, 64, 256, 2, False) == 4
+    rows = 128 + chunk
+    specs = op.weights(p, [(rows, 1, 4096), (rows, 1), (rows, 17)])
+    kernels = _kernels(
+        layer, {w.name: s(w.shape) for w in specs}, s((rows, 1, 4096)),
+        s((rows, 1), jnp.int32), s((rows, 17), jnp.int32))
+    assert kernels == {"flash_attention_paged_decode_grouped": 1,
+                       "flash_attention_paged_chunk_grouped": 1}
+
+
 @pytest.mark.parametrize("rows", [128, 128 + 256])
 def test_delta_rule_decode_layer_at_solar_open2_widths(tpu, rows):
     """One delta-rule layer of `solar2-serve-reason` as the decode graph

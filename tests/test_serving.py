@@ -151,6 +151,8 @@ def test_continuous_batching_invariance(layout):
     W = eng.block_manager.table_width
     if rows:
         assert st["row_steps"] == st["prefill_calls"] > 0
+        # and each chunk's rows were one call of the paged chunk kernel
+        assert st["chunk_kernel_steps"] == st["row_steps"]
         assert set(shapes) == {((2 + b, 1), (2 + b, W)) for b in (0, 1, 2, 4)}
     else:
         assert st["row_steps"] == 0
@@ -449,7 +451,7 @@ def test_paged_cow_divergence_after_shared_prefix(layout):
     assert st.shared_tokens >= len(shared)
     assert st.cow_copies >= 1, \
         "divergence inside a shared block must copy-on-write"
-    assert eng.stats()["row_steps"] == (
+    assert eng.stats()["chunk_kernel_steps"] == eng.stats()["row_steps"] == (
         eng.stats()["prefill_calls"] if rows else 0)
     contig = ff.serve(slots=2, max_new_tokens=5, prefill_chunk=4,
                       kv_layout="contiguous")
@@ -550,7 +552,7 @@ def test_chunked_prefill_interleaves_with_decode(layout):
     assert progressed[0] > gen_before
     assert len(progressed) >= 4, "16-token prompt needs >= 4 chunks"
     eng.run_until_drained()
-    assert eng.stats()["row_steps"] == (
+    assert eng.stats()["chunk_kernel_steps"] == eng.stats()["row_steps"] == (
         eng.stats()["prefill_calls"] if rows else 0)
 
     solo = ff.serve(**kw)

@@ -97,7 +97,8 @@ def test_engine_phases_reach_the_profilers_host_plane(tmp_path, layout,
     each, chunks of 4: five steps, whose `kv_rows` (the context rows
     the step's attention must read) are counted by hand below, the same
     in both layouts of a chunk step; laid out as rows, a chunk step also
-    says how many rows it ran and how many context rows they walked.
+    says how many rows it ran and how many context rows its kernels read
+    (`kv_rows` again where the chunk kernel takes the chunk's rows).
     Completed at once, a step is an iteration with all six phases, the
     device call's three inside the step's span. Left in flight, a step is
     dispatched by one iteration and fetched by the next, whose span it
@@ -136,10 +137,12 @@ def test_engine_phases_reach_the_profilers_host_plane(tmp_path, layout,
     assert [c[3]["tokens"] for c in calls[:3]] == [4, 1, 2]
     assert calls[3][3]["active"] == 2
     if rows:
-        # two slots + the chunk's bucket; chunk row i walks start + i + 1
-        # rows: 1+2+3+4; 5; (1+2) beside the first request's 6
+        # two slots + the chunk's bucket; the chunk kernel reads the
+        # chunk's context once for all its rows: 4; 5; 2 beside the first
+        # request's 6 (row by row through the single-query kernel it was
+        # 1+2+3+4; 5; (1+2) + 6: tests/test_paged_chunk_attention.py)
         assert [c[3]["rows"] for c in calls[:3]] == [6, 3, 4]
-        assert [c[3]["kv_rows_walked"] for c in calls[:3]] == [10, 5, 9]
+        assert [c[3]["kv_rows_walked"] for c in calls[:3]] == [4, 5, 8]
     assert not any("rows" in c[3] or "kv_rows_walked" in c[3]
                    for c in calls[0 if not rows else 3:])
     phases_of = [[s for s in named(spans, *ENGINE_PHASES) if inside(s, it)]
