@@ -84,7 +84,10 @@ Telemetry (when the trained model has a session): `serve.compile` /
 `serve.prefill` / `serve.step` spans, per-iteration queue-depth and
 slot-occupancy counters, a `serve.request` event per completion carrying
 time-to-first-token, and a `serve.summary` event with requests/s/chip and
-decode tokens/s/chip.
+decode tokens/s/chip. A step is one record in any trace: it gets an id
+when it is scheduled and every span of it carries the id as `step`;
+`serve.dispatch` also says what the step is and under which name a device
+trace shows its executable (docs/observability.md, "Serving").
 """
 
 from __future__ import annotations
@@ -151,6 +154,13 @@ class _Step:
     # request's first token or None before its last chunk)
     chunk: Optional[tuple]
     span: tuple           # (name, arguments) of the step's span
+    # {"step": id}: what every span of the step carries, the id given out
+    # when the step is scheduled
+    tag: dict
+    # serve.dispatch's arguments: the id, and what finds and sorts the
+    # step on the device (`kind`, `rows`, a chunk's `bucket` and
+    # `chunk_start`, `program`)
+    launch: dict
     # the chunk's rows are one call of the paged chunk kernel
     chunk_kernel: bool = False
     spanned: bool = False
@@ -252,18 +262,15 @@ class ServingEngine:
         self._kv_itemsize = at_rest["kv_stored_itemsize"]
         self._chunk_rows = self._rows_serve_chunks()
         self._chunk_tiles: dict[int, Optional[int]] = {}
-        # what a step's spans say of sparse latent attention and of the
-        # expert layers (docs/observability.md): the positions a row
-        # attends at the most, and the experts a row is sent to summed
-        # over the expert layers
+        # what a step's spans say of sparse latent attention
+        # (docs/observability.md): the positions a row attends at the
+        # most; and the expert layers, whose counts stats() reads
         nodes = self.decode_model.graph.topo_order()
         self._sel_cap = next(
             (n.params.selected for n in nodes
              if n.op_type == OT.OP_PAGED_LATENT_ATTENTION), 0)
         self._moe_nodes = [n.name for n in nodes
                            if n.op_type == OT.OP_MOE_MLP]
-        self._moe_fanout = sum(n.params.num_experts_per_tok for n in nodes
-                               if n.op_type == OT.OP_MOE_MLP)
         self._moe_base = (0, 0)
         # the recurrent layers' per-slot state (ops/delta_attention.py):
         # bytes all the layers keep for one slot, and the requests whose
@@ -307,6 +314,9 @@ class ServingEngine:
         self._pre_release_hook = None
         self._suppress_completion_events = False
         self._iterations = 0  # step() calls that found work, ever
+        # device steps dispatched, ever: step n's id is n, on every span
+        # of the step (reset_stats leaves it, so an id names one step)
+        self._step_ids = 0
         # the step dispatched and not fetched yet (module docstring), and
         # the requests a completion of it outside step() finished: the
         # next step() hands them to its caller
@@ -580,7 +590,7 @@ class ServingEngine:
         return min(b, self.spec.prefill_chunk)
 
     def _stage_inputs(self, tokens: np.ndarray, positions: np.ndarray,
-                      row_slots=None) -> dict:
+                      row_slots=None, tag=None) -> dict:
         """Stage one decode-graph call's input dict under the searched
         shardings: the token stream, positions, the page tables (paged
         layout), and the graph's constant feeds broadcast to the call's
@@ -588,31 +598,39 @@ class ServingEngine:
         `row_slots[i]`'s page table; by default row i is slot i (the
         rectangle, and a pure-decode step). Shared between the decode
         step and the speculative verify step (serving/speculative.py) so
-        the two calls stage byte-identical feeds."""
+        the two calls stage byte-identical feeds. Two spans, with the
+        step's `tag` where a step stages, both named `serve.stage` as the
+        span they lie in (a reader that asks what the host was in finds
+        staging, whichever part): `part` "build" (the host's arrays, the
+        page table from the block manager's lists) and "put" (the
+        host-to-device puts)."""
+        tag = tag or {}
         rows, q = tokens.shape
         dec = self.decode_model
-        xs = {self._token_input: tokens, "positions": positions}
-        if self.block_manager is not None:
-            mgr = self.block_manager
-            table = np.asarray(
-                [mgr.table(i) for i in range(self.spec.slots)], np.int32)
-            xs["page_table"] = (table if row_slots is None
-                                else table[row_slots])
-        if self._state_bytes_slot:
-            xs["state_slot"] = np.asarray(
-                np.arange(rows) if row_slots is None else row_slots,
-                np.int32)[:, None]
-        for name, (dims, dtype, value) in self._const_inputs.items():
-            from ..fftype import dtype_to_jnp
+        with telemetry.span("serve.stage", part="build", **tag):
+            xs = {self._token_input: tokens, "positions": positions}
+            if self.block_manager is not None:
+                mgr = self.block_manager
+                table = np.asarray(
+                    [mgr.table(i) for i in range(self.spec.slots)], np.int32)
+                xs["page_table"] = (table if row_slots is None
+                                    else table[row_slots])
+            if self._state_bytes_slot:
+                xs["state_slot"] = np.asarray(
+                    np.arange(rows) if row_slots is None else row_slots,
+                    np.int32)[:, None]
+            for name, (dims, dtype, value) in self._const_inputs.items():
+                from ..fftype import dtype_to_jnp
 
-            xs[name] = np.full((rows, q) + tuple(dims[2:]), value,
-                               dtype_to_jnp(dtype))
-        specs = {}
-        for name in xs:
-            spec = dec._input_partition_spec(name)
-            if spec is not None:
-                specs[name] = spec
-        return dec.executor.shard_batch(xs, specs)
+                xs[name] = np.full((rows, q) + tuple(dims[2:]), value,
+                                   dtype_to_jnp(dtype))
+            specs = {}
+            for name in xs:
+                spec = dec._input_partition_spec(name)
+                if spec is not None:
+                    specs[name] = spec
+        with telemetry.span("serve.stage", part="put", **tag):
+            return dec.executor.shard_batch(xs, specs)
 
     def _build_token_feed(self):
         """The sampled tokens' way from one step to the next without the
@@ -655,25 +673,29 @@ class ServingEngine:
         import jax
         import jax.numpy as jnp
 
-        dec = self.decode_model
-        with telemetry.span("serve.stage"):
+        dec, tag = self.decode_model, step.tag
+        with telemetry.span("serve.stage", **tag):
             xs = self._stage_inputs(step.tokens, step.positions,
-                                    step.row_slots)
-            xs[self._token_input] = self._feed(
-                xs[self._token_input], self._sampled, step.from_sampled)
-            if self._rng is None:
-                self._rng = jax.random.key(dec.config.seed)
-            self._rng, sub = jax.random.split(self._rng)
+                                    step.row_slots, tag)
+            # the small device programs: the select that takes a decoding
+            # slot's token from the device, the rng split
+            with telemetry.span("serve.stage", part="feed", **tag):
+                xs[self._token_input] = self._feed(
+                    xs[self._token_input], self._sampled, step.from_sampled)
+                if self._rng is None:
+                    self._rng = jax.random.key(dec.config.seed)
+                self._rng, sub = jax.random.split(self._rng)
             temp = np.zeros((self.spec.slots,), np.float32)
             for s in self.scheduler.active_slots:
                 temp[s.index] = s.request.temperature
             if step.row_slots is not None:
                 temp = temp[step.row_slots]
-            read_idx = jnp.asarray(step.read_idx, jnp.int32)
-            temp = jnp.asarray(temp)
+            with telemetry.span("serve.stage", part="put", **tag):
+                read_idx = jnp.asarray(step.read_idx, jnp.int32)
+                temp = jnp.asarray(temp)
         step.step_fn = self._step_fn
         step.dispatched_t = time.perf_counter()
-        with telemetry.span("serve.dispatch"):
+        with telemetry.span("serve.dispatch", **step.launch):
             dec._state, step.sampled = self._step_fn(
                 dec._params, dec._state, xs, read_idx, sub, temp)
             # tokens already on the host (a host function in the step's
@@ -690,7 +712,7 @@ class ServingEngine:
         host; `ahead`: the step after it is already dispatched."""
         import jax
 
-        with telemetry.span("serve.fetch", ahead=int(ahead)):
+        with telemetry.span("serve.fetch", ahead=int(ahead), **step.tag):
             out = np.asarray(jax.device_get(step.sampled))
         # one step's time on the host's clock (not a device time): from
         # its dispatch, or from the fetch before it where it queued
@@ -772,13 +794,14 @@ class ServingEngine:
             dec._state = self._copy_fn(
                 dec._state, jnp.asarray(src), jnp.asarray(dst))
 
-    def _prepare_writes(self, slot_positions: dict[int, range]):
+    def _prepare_writes(self, slot_positions: dict[int, range], tag=None):
         """Paged pre-step bookkeeping: make every block this iteration
         writes slot-owned (allocating / COW-copying via the BlockManager)
-        and apply the copies to the device pools BEFORE the step runs."""
+        and apply the copies to the device pools BEFORE the step runs;
+        `tag` is the step's id, as its spans carry it."""
         if self.block_manager is None:
             return
-        with telemetry.span("serve.prepare_writes"):
+        with telemetry.span("serve.prepare_writes", **(tag or {})):
             copies = []
             for idx, positions in slot_positions.items():
                 copies.extend(
@@ -1020,8 +1043,9 @@ class ServingEngine:
         """step()'s work, in the phases the profiler's trace shows
         (docs/observability.md): the span of the step this call
         completes (serve.prefill or serve.step) over serve.schedule,
-        serve.prepare_writes, serve.stage and serve.dispatch of the NEXT
-        step and serve.fetch of its own; then serve.bookkeep."""
+        serve.prepare_writes, serve.stage, serve.dispatch and
+        serve.advance of the NEXT step and serve.fetch of its own; then
+        serve.bookkeep. Every span says in `step` whose it is."""
         prev, self._in_flight = self._in_flight, None
         if prev is not None and prev.step_fn is not self._step_fn:
             # the step function was replaced since: its replacement finds
@@ -1034,13 +1058,15 @@ class ServingEngine:
                 span.enter_context(self._span_of(prev))
             step = self._schedule()
             if step is not None:
-                self._prepare_writes(step.writes)
+                self._prepare_writes(step.writes, step.tag)
                 if prev is None:
                     span.enter_context(self._span_of(step))
+                self._step_ids += 1
                 self._steps += 1
                 self._steps_ahead += prev is not None
                 self._dispatch(step)
-                self._advance(step)
+                with telemetry.span("serve.advance", **step.tag):
+                    self._advance(step)
             if prev is not None:
                 fetched.append((prev, self._fetch(prev, step is not None)))
             if step is not None and step.at_once:
@@ -1055,7 +1081,10 @@ class ServingEngine:
         the scheduler as the dispatch of the step before left it; None
         where no slot has a row to run."""
         sched = self.scheduler
-        with telemetry.span("serve.schedule"):
+        # the id of the step this makes, if it makes one: an annotation
+        # takes its arguments when it is entered
+        tag = {"step": self._step_ids + 1}
+        with telemetry.span("serve.schedule", **tag):
             gate = (self._can_admit
                     if self.block_manager is not None else None)
             admitted = sched.admissions(can_admit=gate)
@@ -1188,18 +1217,21 @@ class ServingEngine:
                 updated = len(decoding) + (pre is not None)
                 load.update(state_rows=updated,
                             state_bytes=updated * self._state_bytes_slot)
-            if self._moe_fanout:
-                # assignments this step's rows make over all the experts,
-                # padding rows included; those held here are computed
-                # (stats()["moe_assignments"] counts them on the device)
-                load["moe_rows"] = rows * self._moe_fanout
+            load.update(tag)
             span = ("serve.prefill", dict(
                 slot=pre.index, trace=pre.request.trace_id,
                 start=start, tokens=n,
                 prompt_tokens=len(pre.request.prompt),
                 decoding=len(decoding), **load)) if pre is not None else \
                 ("serve.step", dict(active=len(decoding), **load))
+            # a device trace names an execution by its jitted function
+            program = "jit_" + getattr(self._step_fn, "__name__",
+                                       type(self._step_fn).__name__)
+            launch = dict(tag, kind="decode", rows=rows, program=program)
+            if pre is not None:
+                launch.update(kind="chunk", bucket=b, chunk_start=start)
             return _Step(
+                tag=tag, launch=launch,
                 tokens=tokens, positions=positions, read_idx=read_idx,
                 row_slots=row_slots, writes=writes,
                 from_sampled=from_sampled, sampled_row=sampled_row,
@@ -1230,7 +1262,7 @@ class ServingEngine:
     def _bookkeep(self, step: _Step, tokens: np.ndarray):
         """The bookkeeping of a fetched step: what needs its tokens, and
         the run's counts of completed work."""
-        with telemetry.span("serve.bookkeep"):
+        with telemetry.span("serve.bookkeep", **step.tag):
             if step.chunk is not None:
                 pre, req, n, by_rows, first_row = step.chunk
                 self._prefill_tokens += n

@@ -477,7 +477,7 @@ def test_spans_say_what_the_indexer_scored_and_the_experts_computed(
     first, second, *steps = seen
     # chunk of 8 rows at positions 0..7: each scores and reads its prefix
     assert first[0] == "serve.prefill" and first[1]["ctx_rows"] == 36
-    assert first[1]["sel_rows"] == 36 and first[1]["moe_rows"] == 12 * 8
+    assert first[1]["sel_rows"] == 36
     # positions 8..10 score 9, 10, 11 rows and read top-8 of them
     assert (second[1]["ctx_rows"], second[1]["sel_rows"]) == (30, 24)
     assert [s[0] for s in steps] == ["serve.step"] * 2

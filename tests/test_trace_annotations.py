@@ -69,6 +69,12 @@ def named(spans, *names):
     return [s for s in spans if s[0] in names]
 
 
+def whole(spans):
+    """Without the parts of `serve.stage`: spans of its name inside it,
+    with a `part`."""
+    return [s for s in spans if "part" not in s[3]]
+
+
 def test_fit_spans_reach_the_profilers_host_plane(tmp_path):
     ff = _build_mlp(tmp_path)
     x, y = _train_data(n=128)
@@ -145,8 +151,8 @@ def test_engine_phases_reach_the_profilers_host_plane(tmp_path, layout,
         assert [c[3]["kv_rows_walked"] for c in calls[:3]] == [4, 5, 8]
     assert not any("rows" in c[3] or "kv_rows_walked" in c[3]
                    for c in calls[0 if not rows else 3:])
-    phases_of = [[s for s in named(spans, *ENGINE_PHASES) if inside(s, it)]
-                 for it in iterations]
+    phases_of = [[s for s in whole(named(spans, *ENGINE_PHASES))
+                  if inside(s, it)] for it in iterations]
     for phases in phases_of:
         for a, b in zip(phases, phases[1:]):        # disjoint, in order
             assert a[2] <= b[1]
@@ -184,6 +190,73 @@ def test_engine_phases_reach_the_profilers_host_plane(tmp_path, layout,
     prepare = named(spans, "ff/serve.prepare_writes")
     for copy in named(spans, "ff/serve.cow_copy"):
         assert any(inside(copy, p) for p in prepare)
+
+
+@pytest.mark.parametrize("at_once", [True, False])
+@pytest.mark.parametrize("layout", ["rectangle", "rows"])
+def test_every_span_of_a_step_carries_its_id(tmp_path, layout, at_once):
+    """Three requests on two slots (the third is admitted when the first
+    ends, and the first ends by EOS), chunks of 4, then the drain: a step
+    gets its id when it is scheduled, the ids are consecutive, every
+    span of a step carries its step's and no other, the parts of
+    `serve.stage` (spans of its name with a `part`) lie inside it, and
+    `serve.dispatch` says what the step ran."""
+    rows = layout == "rows"
+    ff = _build_rows_lm() if rows else _build_lm(batch=1)
+    eng = ff.serve(slots=2, max_new_tokens=4, prefill_chunk=4,
+                   prefix_sharing=False,
+                   **(ROWS if rows else {"kv_block_size": 4}))
+    if at_once:
+        _complete_every_step_at_once(eng)
+    prompts = [[3, 7, 11, 2, 5], [5, 2], [9, 8, 7]]
+    (alone,) = eng.generate(prompts[:1])            # compiles
+    before = eng._step_ids
+    eng.reset_stats()                               # the ids go on
+    with traced(tmp_path / "trace"):
+        replies = eng.generate(prompts, eos_id=alone[1])
+    assert replies[0] == alone[:2] and eng.stats()["iterations"] > 0
+    spans = program_spans(tmp_path / "trace")
+    dispatches = named(spans, "ff/serve.dispatch")
+    ids = [d[3]["step"] for d in dispatches]
+    assert ids == list(range(before + 1, before + 1 + len(ids)))
+    assert eng._step_ids == ids[-1] == before + eng.stats()["iterations"]
+    once = ("ff/serve.schedule", "ff/serve.prepare_writes", "ff/serve.stage",
+            "ff/serve.dispatch", "ff/serve.advance", "ff/serve.fetch",
+            "ff/serve.bookkeep")
+    of_a_step = named(spans, *once, "ff/serve.step", "ff/serve.prefill")
+    assert all("step" in s[3] for s in of_a_step)
+    for d in dispatches:
+        mine = [s for s in of_a_step if s[3]["step"] == d[3]["step"]]
+        call = named(mine, "ff/serve.step", "ff/serve.prefill")
+        assert sorted(s[0] for s in whole(mine)) == sorted(
+            [*once, call[0][0]])
+        (stage,) = whole(named(mine, "ff/serve.stage"))
+        parts = [s for s in named(mine, "ff/serve.stage") if "part" in s[3]]
+        assert [p[3]["part"] for p in parts] == [
+            "build", "put", "feed", "put"]
+        assert all(inside(p, stage) for p in parts)
+        for a, b in zip(parts, parts[1:]):          # disjoint, in order
+            assert a[2] <= b[1]
+        # what the step is, against its own span's arguments
+        args, (call,) = d[3], call
+        assert args["program"] == (
+            "jit_on_the_host" if at_once else "jit_decode_step")
+        if call[0] == "ff/serve.step":
+            assert (args["kind"], args["rows"]) == ("decode", 2)
+            assert "bucket" not in args and "chunk_start" not in args
+        else:
+            bucket = eng._bucket(call[3]["tokens"])
+            assert (args["kind"], args["bucket"], args["chunk_start"]) == (
+                "chunk", bucket, call[3]["start"])
+            assert args["rows"] == (2 + bucket if rows else 2)
+            assert call[3].get("rows", 2) == args["rows"]
+    # the call that finds no row to run asks for the next id and makes
+    # no step of it
+    scheduled = [s[3]["step"] for s in named(spans, "ff/serve.schedule")]
+    assert scheduled[:len(ids)] == ids
+    assert set(scheduled[len(ids):]) <= {ids[-1] + 1}
+    kinds = [d[3]["kind"] for d in dispatches]
+    assert kinds.count("chunk") == 4 and kinds.count("decode") >= 3
 
 
 def test_kv_itemsize_is_what_attention_reads():
