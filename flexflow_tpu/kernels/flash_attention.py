@@ -22,11 +22,18 @@ body is specialized three ways to do the minimum vector work:
 When the kv axis fits one block, the online-softmax scratch, init and
 rescale passes are statically elided (one-pass softmax).
 
-Backward is the FlashAttention-2 scheme as two Pallas kernels: the forward
-saves per-row logsumexp; `delta = rowsum(dO*O)` is a cheap XLA elementwise
-precompute; the dq kernel iterates kv-blocks per q-block and the dk/dv
-kernel iterates q-blocks per kv-block, both recomputing the probability
-tile from (q, k, lse) with the same three-way tile specialization.
+Backward is the FlashAttention-2 scheme: the forward saves per-row
+logsumexp; `delta = rowsum(dO*O)` is a cheap XLA elementwise precompute; a
+live tile's probabilities are rebuilt from (q, k, lse) with the same
+three-way tile specialization. On the packed training layout ONE kernel
+gives dq, dk and dv: grid (batch, head groups, kv blocks, q blocks), the
+tile and `ds` built once, dk/dv accumulated per kv block and dq into a
+float32 scratch that holds the head group's dq for the whole sequence.
+The gate reads bytes: that scratch and its output block must fit
+`_BWD_FUSED_VMEM` (a sequence of 8,192 at a 128-lane group in bf16); a
+longer sequence, and the head-transposed layout (ring attention's), take
+the split pair, a dq kernel (kv blocks per q block) and a dk/dv kernel (q
+blocks per kv block), each rebuilding the tile, at twice the vector work.
 
 On non-TPU backends (the 8-device CPU test mesh) the kernel runs in Pallas
 interpret mode so tests exercise the same code path.
@@ -365,10 +372,9 @@ def _bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
     *, scale: float, causal: bool, block_q: int, block_k: int,
     seq_q: int, seq_k: int, causal_offset: int, nj: int,
-    i_dim: int = 1, j_dim: int = 2,
 ):
-    i = pl.program_id(i_dim)
-    j = pl.program_id(j_dim)
+    i = pl.program_id(1)
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -407,10 +413,9 @@ def _bwd_dkv_kernel(
     dk_acc, dv_acc,
     *, scale: float, causal: bool, block_q: int, block_k: int,
     seq_q: int, seq_k: int, causal_offset: int, ni: int, nj: int,
-    i_dim: int = 2, j_dim: int = 1,
 ):
-    j = pl.program_id(j_dim)  # kv block
-    i = pl.program_id(i_dim)  # q block (innermost, sequential)
+    j = pl.program_id(1)  # kv block
+    i = pl.program_id(2)  # q block (innermost, sequential)
 
     @pl.when(i == 0)
     def _init():
@@ -689,8 +694,10 @@ def flash_attention_with_lse(
 # layout. Heads are selected by BlockSpec lane-offset index maps — block
 # index h on the last (h·dh)-wide dim — so NO head transpose/relayout ever
 # touches HBM (PERF.md measured the (b,s,h,d)→(b,h,s,d) copies at ~0.8 ms
-# per flagship step). The kernel bodies are shared with the bhsd path; only
-# the grids ((b, h, qi, kj)) and index maps differ.
+# per flagship step). The forward's kernel body is shared with the bhsd
+# path; only the grids ((b, h, qi, kj)) and index maps differ. The backward
+# runs the head-group bodies for both head families (hpb = 1 at head_dim
+# 128).
 #
 # NARROW HEADS (head_dim < 128): Mosaic requires a lane block be a multiple
 # of 128 lanes (or the full array width), so a single head_dim-64 head
@@ -918,6 +925,80 @@ def _bwd_dkv_kernel_grouped(
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
+def _bwd_fused_kernel_grouped(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+    dq_acc, dk_acc, dv_acc,
+    *, scale: float, causal: bool, block_q: int, block_k: int,
+    seq_q: int, seq_k: int, causal_offset: int, ni: int, nj: int,
+    hpb: int, head_dim: int,
+):
+    """dq, dk AND dv of a head group from ONE build of each live tile: the
+    dkv kernel's grid and body, plus `dq[i] += ds·k` into a float32
+    scratch that holds the group's dq for the whole sequence (the dq
+    output block is the whole sequence too, written once after the last
+    kv block). Each gradient sums its tiles in the split pair's order
+    (dq[i] over ascending j, dk/dv[j] over ascending i), so the results
+    are the split pair's bit for bit."""
+    j = pl.program_id(2)  # kv block (sequential: dq accumulates across it)
+    i = pl.program_id(3)  # q block (innermost, sequential)
+    rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+
+    @pl.when(j == 0)
+    def _init_dq():
+        dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[1]), jnp.float32)
+
+    @pl.when(i == 0)
+    def _init_dkv():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def step(masked: bool):
+        for hh in range(hpb):
+            sl = slice(hh * head_dim, (hh + 1) * head_dim)
+            q, k, _, do, p, ds = _bwd_tile_math(
+                q_ref[0][:, sl], k_ref[0][:, sl], v_ref[0][:, sl],
+                do_ref[0][:, sl], lse_ref[hh][:, 0], delta_ref[hh][:, 0],
+                i, j, masked,
+                scale=scale, causal=causal, block_q=block_q,
+                block_k=block_k, seq_q=seq_q, seq_k=seq_k,
+                causal_offset=causal_offset,
+                mask_q_rows=True,  # padded q rows would leak p==1 into dk/dv
+            )
+            ds = ds.astype(q.dtype)
+            dv_acc[:, sl] += jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dk_acc[:, sl] += jax.lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dq_acc[rows, sl] += jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+
+    live, needs_mask = _tile_classes(
+        i, j, causal=causal, block_q=block_q, block_k=block_k,
+        causal_offset=causal_offset, even_k=seq_k % block_k == 0, nj=nj,
+    )
+    if causal or seq_k % block_k != 0:
+        pl.when(jnp.logical_and(live, needs_mask))(lambda: step(True))
+        pl.when(jnp.logical_and(live, jnp.logical_not(needs_mask)))(
+            lambda: step(False))
+    else:
+        step(False)
+
+    @pl.when(i == ni - 1)
+    def _finish_dkv():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(jnp.logical_and(j == nj - 1, i == ni - 1))
+    def _finish_dq():
+        dq_ref[0] = dq_acc[:seq_q].astype(dq_ref.dtype)
+
+
 def _flash_fwd_packed_grouped(q, k, v, num_heads, causal, scale,
                               block_q, block_k, hpb, save_lse=True):
     """Narrow-head forward: head-GROUP lane blocks (hpb heads per block,
@@ -1032,80 +1113,28 @@ def _flash_fwd_packed(q, k, v, num_heads, causal, scale,
     return res[0], None
 
 
-def _flash_bwd_packed_grouped(q, k, v, g, lse, delta, num_heads, causal,
-                              scale, block_q, block_k, hpb):
-    """Narrow-head dq + dkv kernels on head-group lane blocks (the
-    single-tile fused specialization is per-head-only; grouped shapes
-    route through the split FA2 pair even at one tile)."""
-    b, s_q, e = q.shape
-    s_k = k.shape[1]
-    h = num_heads
-    d = e // h
-    ng = h // hpb
-    w = hpb * d
-    bq = min(block_q, s_q)
-    bk = min(block_k, s_k)
-    ni = pl.cdiv(s_q, bq)
-    nj = pl.cdiv(s_k, bk)
-    interpret = jax.default_backend() != "tpu"
-    common = dict(
-        scale=scale, causal=causal, block_q=bq, block_k=bk,
-        seq_q=s_q, seq_k=s_k, causal_offset=s_k - s_q, hpb=hpb, head_dim=d,
-    )
-    qspec = pl.BlockSpec((1, bq, w), lambda bi, gi, i, j: (bi, i, gi))
-    kspec = pl.BlockSpec((1, bk, w), lambda bi, gi, i, j: (bi, j, gi))
-    rowspec = pl.BlockSpec((hpb, bq, LSE_LANES),
-                           lambda bi, gi, i, j: (bi * ng + gi, i, 0))
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel_grouped, nj=nj, **common),
-        grid=(b, ng, ni, nj),
-        in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((b, s_q, e), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, w), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-        ),
-        interpret=interpret,
-        name="flash_attention_bwd_dq_packed_grouped",
-    )(q, k, v, g, lse, delta)
-    # kv-grid kernels: block index maps take (b, group, kv_j, q_i)
-    qspec2 = pl.BlockSpec((1, bq, w), lambda bi, gi, j, i: (bi, i, gi))
-    kspec2 = pl.BlockSpec((1, bk, w), lambda bi, gi, j, i: (bi, j, gi))
-    rowspec2 = pl.BlockSpec((hpb, bq, LSE_LANES),
-                            lambda bi, gi, j, i: (bi * ng + gi, i, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel_grouped, ni=ni, nj=nj, **common),
-        grid=(b, ng, nj, ni),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2],
-        out_specs=[kspec2, kspec2],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, s_k, e), k.dtype),
-            jax.ShapeDtypeStruct((b, s_k, e), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, w), jnp.float32),
-            pltpu.VMEM((bk, w), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-        ),
-        interpret=interpret,
-        name="flash_attention_bwd_dkv_packed_grouped",
-    )(q, k, v, g, lse, delta)
-    return dq, dk, dv
+# What the fused backward may keep resident for dq: the float32 scratch and
+# the output block (counted twice, as the pipeline buffers one), each the
+# whole sequence of one head group. A grid step's other buffers at
+# 512-blocks are 4-5 MiB of the 16 MiB a kernel gets, by the compiler's own
+# count (the (512, 512) float32 tile temporaries, the operand, row-stat and
+# dk/dv blocks, the dk/dv accumulators: a sequence of 14,336 at two heads of
+# 64 compiles, one of 16,384 is refused at 17.05 MiB). 1 KiB a row of a
+# 128-lane group in bf16 is a sequence of 8,192; tens of thousands of tokens
+# take the split pair.
+_BWD_FUSED_VMEM = 8 << 20
 
 
 def _flash_bwd_packed(q, k, v, out, lse, g, num_heads, causal, scale,
                       block_q, block_k):
+    """Packed backward for both head families (heads of 128 lanes are the
+    `hpb == 1` case of the head-group kernels): the fused kernel where a
+    head group's dq for the whole sequence fits `_BWD_FUSED_VMEM`, the dq
+    and dkv kernels, each building every live tile for itself, beyond."""
     b, s_q, e = q.shape
     s_k = k.shape[1]
     h = num_heads
     d = e // h
-    bq = min(block_q, s_q)
-    bk = min(block_k, s_k)
     # delta = rowsum(dO·O) per head: reduce dh inside each head, then a
     # tiny (b, s, h) transpose — no (·, d)-sized relayout
     delta = jnp.sum(
@@ -1115,86 +1144,76 @@ def _flash_bwd_packed(q, k, v, out, lse, g, num_heads, causal, scale,
     ).transpose(0, 2, 1).reshape(b * h, s_q)
     delta = jnp.broadcast_to(delta[..., None], (b * h, s_q, LSE_LANES))
     hpb = _packed_heads_per_block(d, h)
-    if hpb > 1:
-        return _flash_bwd_packed_grouped(q, k, v, g, lse, delta, num_heads,
-                                         causal, scale, block_q, block_k,
-                                         hpb)
-    interpret = jax.default_backend() != "tpu"
+    ng = h // hpb
+    w = hpb * d
+    bq = min(block_q, s_q)
+    bk = min(block_k, s_k)
     ni = pl.cdiv(s_q, bq)
     nj = pl.cdiv(s_k, bk)
+    interpret = jax.default_backend() != "tpu"
+    family = "_packed_grouped" if hpb > 1 else "_packed"
     common = dict(
         scale=scale, causal=causal, block_q=bq, block_k=bk,
-        seq_q=s_q, seq_k=s_k, causal_offset=s_k - s_q,
+        seq_q=s_q, seq_k=s_k, causal_offset=s_k - s_q, hpb=hpb, head_dim=d,
     )
-    if ni == 1 and nj == 1:
-        spec = pl.BlockSpec((1, s_q, d), lambda bi, hi: (bi, 0, hi))
-        kspec = pl.BlockSpec((1, s_k, d), lambda bi, hi: (bi, 0, hi))
-        rowspec = pl.BlockSpec((1, s_q, LSE_LANES),
-                               lambda bi, hi: (bi * h + hi, 0, 0))
-        dq, dk, dv = pl.pallas_call(
-            functools.partial(
-                _bwd_single_tile_kernel, scale=scale, causal=causal,
-                block_q=s_q, block_k=s_k, seq_q=s_q, seq_k=s_k,
-                causal_offset=s_k - s_q,
-            ),
-            grid=(b, h),
-            in_specs=[spec, kspec, kspec, spec, rowspec, rowspec],
-            out_specs=[spec, kspec, kspec],
-            out_shape=[
-                jax.ShapeDtypeStruct((b, s_q, e), q.dtype),
-                jax.ShapeDtypeStruct((b, s_k, e), k.dtype),
-                jax.ShapeDtypeStruct((b, s_k, e), v.dtype),
+    # kv-outer grid of the fused and the dkv kernel: (b, group, kv_j, q_i)
+    qspec2 = pl.BlockSpec((1, bq, w), lambda bi, gi, j, i: (bi, i, gi))
+    kspec2 = pl.BlockSpec((1, bk, w), lambda bi, gi, j, i: (bi, j, gi))
+    rowspec2 = pl.BlockSpec((hpb, bq, LSE_LANES),
+                            lambda bi, gi, j, i: (bi * ng + gi, i, 0))
+    dq_shape = jax.ShapeDtypeStruct((b, s_q, e), q.dtype)
+    dkv_shape = [jax.ShapeDtypeStruct((b, s_k, e), k.dtype),
+                 jax.ShapeDtypeStruct((b, s_k, e), v.dtype)]
+    dkv_scratch = [pltpu.VMEM((bk, w), jnp.float32),
+                   pltpu.VMEM((bk, w), jnp.float32)]
+    if (ni * bq * w * 4 + 2 * s_q * w * q.dtype.itemsize
+            <= _BWD_FUSED_VMEM):
+        return pl.pallas_call(
+            functools.partial(_bwd_fused_kernel_grouped, ni=ni, nj=nj,
+                              **common),
+            grid=(b, ng, nj, ni),
+            in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2],
+            out_specs=[
+                pl.BlockSpec((1, s_q, w), lambda bi, gi, j, i: (bi, 0, gi)),
+                kspec2, kspec2,
             ],
+            out_shape=[dq_shape] + dkv_shape,
+            scratch_shapes=[pltpu.VMEM((ni * bq, w), jnp.float32)]
+            + dkv_scratch,
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel"),
+                dimension_semantics=("parallel", "parallel", "arbitrary",
+                                     "arbitrary"),
             ),
             interpret=interpret,
-            name="flash_attention_bwd_fused_packed",
+            name="flash_attention_bwd" + family,
         )(q, k, v, g, lse, delta)
-        return dq, dk, dv
-    qspec = pl.BlockSpec((1, bq, d), lambda bi, hi, i, j: (bi, i, hi))
-    kspec = pl.BlockSpec((1, bk, d), lambda bi, hi, i, j: (bi, j, hi))
-    rowspec = pl.BlockSpec((1, bq, LSE_LANES),
-                           lambda bi, hi, i, j: (bi * h + hi, i, 0))
+    semantics = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
+    qspec = pl.BlockSpec((1, bq, w), lambda bi, gi, i, j: (bi, i, gi))
+    kspec = pl.BlockSpec((1, bk, w), lambda bi, gi, i, j: (bi, j, gi))
+    rowspec = pl.BlockSpec((hpb, bq, LSE_LANES),
+                           lambda bi, gi, i, j: (bi * ng + gi, i, 0))
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, nj=nj, i_dim=2, j_dim=3, **common),
-        grid=(b, h, ni, nj),
+        functools.partial(_bwd_dq_kernel_grouped, nj=nj, **common),
+        grid=(b, ng, ni, nj),
         in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
         out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((b, s_q, e), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-        ),
+        out_shape=dq_shape,
+        scratch_shapes=[pltpu.VMEM((bq, w), jnp.float32)],
+        compiler_params=semantics,
         interpret=interpret,
-        name="flash_attention_bwd_dq_packed",
+        name="flash_attention_bwd_dq" + family,
     )(q, k, v, g, lse, delta)
-    # kv-grid kernels: block index maps take (b, h, kv_j, q_i)
-    qspec2 = pl.BlockSpec((1, bq, d), lambda bi, hi, j, i: (bi, i, hi))
-    kspec2 = pl.BlockSpec((1, bk, d), lambda bi, hi, j, i: (bi, j, hi))
-    rowspec2 = pl.BlockSpec((1, bq, LSE_LANES),
-                            lambda bi, hi, j, i: (bi * h + hi, i, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, ni=ni, nj=nj, i_dim=3, j_dim=2,
-                          **common),
-        grid=(b, h, nj, ni),
+        functools.partial(_bwd_dkv_kernel_grouped, ni=ni, nj=nj, **common),
+        grid=(b, ng, nj, ni),
         in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2],
         out_specs=[kspec2, kspec2],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, s_k, e), k.dtype),
-            jax.ShapeDtypeStruct((b, s_k, e), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-        ),
+        out_shape=dkv_shape,
+        scratch_shapes=dkv_scratch,
+        compiler_params=semantics,
         interpret=interpret,
-        name="flash_attention_bwd_dkv_packed",
+        name="flash_attention_bwd_dkv" + family,
     )(q, k, v, g, lse, delta)
     return dq, dk, dv
 

@@ -78,18 +78,30 @@ def _with_grads(fn):
     return run
 
 
-@pytest.mark.parametrize("batch,seq", [(8, 512), (1, 4096)])
-def test_packed_flash_fwd_bwd_head_dim_64(tpu, batch, seq):
-    """lm-base's attention: 16 heads of 64 on the packed (b, s, h·d)
-    layout, forward and both backward kernels."""
+@pytest.mark.parametrize("batch,seq,heads,bwd", [
+    (8, 512, 16, ["bwd_packed_grouped"]),
+    (1, 4096, 16, ["bwd_packed_grouped"]),
+    # the training cells' shapes: gpt2-medium's 16 heads of 64 over 1,024
+    # tokens; heads of 128 over c13b's 2,048 and OLMoE's 4,096
+    (8, 1024, 16, ["bwd_packed_grouped"]),
+    (1, 2048, 8, ["bwd_packed"]),
+    (4, 4096, 8, ["bwd_packed"]),
+    # past the fused backward's VMEM gate (8 + 2 x 4 MiB of dq a head
+    # pair): the dq and the dkv kernel
+    (1, 16384, 16, ["bwd_dq_packed_grouped", "bwd_dkv_packed_grouped"]),
+    (1, 16384, 8, ["bwd_dq_packed", "bwd_dkv_packed"]),
+])
+def test_packed_flash_fwd_bwd(tpu, batch, seq, heads, bwd):
+    """Attention 1,024 wide on the packed (b, s, h·d) layout, 16 heads of
+    64 (lm-base, gpt2-medium) or 8 of 128: the forward and the ONE backward
+    kernel, or the split pair where a head group's dq does not fit."""
     s = _on(tpu[0])
     x = s((batch, seq, 1024))
     kernels = _kernels(_with_grads(
         lambda q, k, v: fa.flash_attention_packed(
-            q, k, v, num_heads=16, causal=True)), x, x, x)
-    assert kernels == {"flash_attention_fwd_packed_grouped": 1,
-                       "flash_attention_bwd_dq_packed_grouped": 1,
-                       "flash_attention_bwd_dkv_packed_grouped": 1}
+            q, k, v, num_heads=heads, causal=True)), x, x, x)
+    fwd = "fwd_packed_grouped" if heads == 16 else "fwd_packed"
+    assert kernels == {f"flash_attention_{name}": 1 for name in [fwd] + bwd}
 
 
 def test_fused_layer_norm_fwd_bwd(tpu):
@@ -444,8 +456,9 @@ def test_train_step_lowers_for_four_chips(topology, monkeypatch, mesh_flag,
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     text = jax.jit(ex._train_step_body).lower(*args).compile().as_text()
     kernels = pallas_kernels(text)
+    # the forward and the one backward kernel of the layer
     assert sum(v for k, v in kernels.items()
-               if k.startswith("flash_attention")) == 3, kernels
+               if k.startswith("flash_attention")) == 2, kernels
     assert kernels["layer_norm_fwd"] == 3 and kernels["layer_norm_bwd"] == 3
     assert "all-reduce" in text
 

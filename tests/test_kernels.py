@@ -1,5 +1,8 @@
 """Pallas kernel tests (interpret mode on the CPU test backend)."""
 
+import importlib
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -274,8 +277,8 @@ def test_flash_packed_matches_reference(causal, s, block):
 
 @pytest.mark.parametrize("s,block", [(256, 128), (128, 128)])
 def test_flash_packed_grad(s, block):
-    """Packed-layout backward (single-tile fused and split dq/dkv paths)
-    against the XLA reference."""
+    """Packed-layout backward (the fused kernel over one tile and over
+    several) against the XLA reference."""
     from flexflow_tpu.kernels.flash_attention import (
         _attn_reference,
         flash_attention_packed,
@@ -305,6 +308,118 @@ def test_flash_packed_grad(s, block):
     for a, b_ in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=1e-4, atol=1e-4)
+
+
+def _packed_grads(monkeypatch, budget, h, q, k, v, ct, causal, block):
+    """(dq, dk, dv) of flash_attention_packed and the names of the backward
+    kernels it ran, with the fused backward's VMEM budget at `budget`
+    bytes (None: as shipped)."""
+    fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
+    if budget is not None:
+        monkeypatch.setattr(fa, "_BWD_FUSED_VMEM", budget)
+    _, vjp = jax.vjp(
+        lambda *a: fa.flash_attention_packed(
+            *a, num_heads=h, causal=causal, block_q=block, block_k=block),
+        q, k, v)
+    names = re.findall(r"name=(flash_attention_bwd\w*)",
+                       str(jax.make_jaxpr(vjp)(ct)))
+    return vjp(ct), sorted(names)
+
+
+@pytest.mark.parametrize("h,d,sq,sk,causal,dtype", [
+    # several tiles, head_dim 64 grouped and 128, causal and not
+    (2, 64, 256, 256, True, "float32"),
+    (2, 64, 256, 256, False, "float32"),
+    (1, 128, 256, 256, True, "float32"),
+    (1, 128, 256, 256, False, "float32"),
+    # one tile
+    (2, 64, 128, 128, True, "float32"),
+    (1, 128, 128, 128, True, "float32"),
+    (1, 128, 128, 128, False, "float32"),
+    # a ragged last block, of q and of k
+    (2, 64, 320, 320, True, "float32"),
+    (1, 128, 320, 320, False, "float32"),
+    # s_q != s_k: the causal offset, ragged cross-attention either way
+    (2, 64, 128, 384, True, "float32"),
+    (1, 128, 192, 320, True, "float32"),
+    (2, 64, 320, 192, False, "float32"),
+    # bf16 operands
+    (2, 64, 256, 256, True, "bfloat16"),
+    (1, 128, 256, 256, True, "bfloat16"),
+    (2, 64, 320, 320, True, "bfloat16"),
+    (1, 128, 128, 384, True, "bfloat16"),
+])
+def test_flash_packed_fused_backward(monkeypatch, h, d, sq, sk, causal,
+                                     dtype):
+    """The fused packed backward (one kernel: dq, dk, dv from one build of
+    each live tile) gives the split dq / dkv pair's gradients bit for bit
+    (every sum runs in the pair's order), and both track the XLA
+    reference's autodiff in float32."""
+    from flexflow_tpu.kernels.flash_attention import _attn_reference
+
+    rs = np.random.RandomState(7)
+    b, block = 2, 128
+    q, k, v, ct = (jnp.asarray(rs.randn(b, s, h * d), dtype)
+                   for s in (sq, sk, sk, sq))
+    family = "_packed_grouped" if d < 128 else "_packed"
+
+    fused, names = _packed_grads(monkeypatch, None, h, q, k, v, ct, causal,
+                                 block)
+    assert names == ["flash_attention_bwd" + family]
+    split, names = _packed_grads(monkeypatch, 0, h, q, k, v, ct, causal,
+                                 block)
+    assert names == ["flash_attention_bwd_dkv" + family,
+                     "flash_attention_bwd_dq" + family]
+    for got, want, name in zip(fused, split, "qkv"):
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            err_msg=f"d{name}: fused != split")
+
+    def reference(q_, k_, v_):
+        def heads(t):
+            return (t.astype(jnp.float32)
+                    .reshape(b, t.shape[1], h, d).transpose(0, 2, 1, 3))
+
+        o = _attn_reference(heads(q_), heads(k_), heads(v_), causal,
+                            1.0 / np.sqrt(d))
+        return o.transpose(0, 2, 1, 3).reshape(b, sq, h * d)
+
+    _, vjp_ref = jax.vjp(reference, q, k, v)
+    tol = 1e-4 if dtype == "float32" else 4e-2
+    for got, want, name in zip(fused, vjp_ref(ct.astype(jnp.float32)),
+                               "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            rtol=tol, atol=tol, err_msg=f"d{name} against the reference")
+
+
+@pytest.mark.parametrize("shape,h,dtype,expected", [
+    # one 320-lane head in float32: 2.5 MiB of scratch + 2 x 2.5 of output
+    ((1, 2048, 320), 1, "float32", ["flash_attention_bwd_packed"]),
+    # 256 rows more: 3.1 + 2 x 2.8 MiB
+    ((1, 2304, 320), 1, "float32", ["flash_attention_bwd_dkv_packed",
+                                    "flash_attention_bwd_dq_packed"]),
+    # gpt2-medium's heads over 16,384 tokens: 8 + 2 x 4 MiB a head pair
+    ((1, 16384, 1024), 16, "bfloat16",
+     ["flash_attention_bwd_dkv_packed_grouped",
+      "flash_attention_bwd_dq_packed_grouped"]),
+])
+def test_flash_packed_backward_gate_reads_bytes(shape, h, dtype, expected):
+    """The fused backward runs where a head group's dq for the whole
+    sequence (float32 scratch + the output block twice) fits
+    `_BWD_FUSED_VMEM` (8 MiB); past it the dq and dkv kernels run."""
+    fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
+    x = jax.ShapeDtypeStruct(shape, dtype)
+
+    def bwd(q, k, v, ct):
+        _, vjp = jax.vjp(
+            lambda *a: fa.flash_attention_packed(
+                *a, num_heads=h, causal=True), q, k, v)
+        return vjp(ct)
+
+    names = re.findall(r"name=(flash_attention_bwd\w*)",
+                       str(jax.make_jaxpr(bwd)(x, x, x, x)))
+    assert sorted(names) == expected
 
 
 @pytest.mark.parametrize("mesh_flag,megatron", [("4,1,1,1", False),
