@@ -434,18 +434,22 @@ class FFModel:
         name: str = "",
         positions: Optional[Tensor] = None,
         rope_theta: float = 0.0,
-        qk_norm: bool = False,
+        qk_norm=False,
         qk_norm_eps: float = 1e-5,
         num_kv_heads: int = 0,
         head_dim: int = 0,
         output_gate: bool = False,
+        index=None,
     ) -> Tensor:
         """`rope_theta` > 0 rotates q and k by the (batch, seq) int
-        `positions`; `qk_norm` RMS-normalises the q and k projections;
+        `positions`; `qk_norm` RMS-normalises the q and k projections
+        ("projection" or True: whole; "head": each head);
         `num_kv_heads` < num_heads groups the query heads over fewer
         keys and values; `head_dim` is a head's size where it is not
         embed_dim / num_heads; `output_gate` multiplies the core's output
-        by sigmoid(query @ wg) (ops/attention.AttentionFrontEnd)."""
+        by sigmoid(query @ wg); `index` (an ops.attention.Indexer) has
+        each row attend a learned top-k selection of its past
+        (ops/attention.AttentionFrontEnd)."""
         if impl not in ("xla", "flash", "ring"):
             raise ValueError(
                 f"multihead_attention impl must be xla|flash|ring, got {impl!r}"
@@ -455,7 +459,7 @@ class FFModel:
                 "multihead_attention: rope_theta and positions go together")
         front = AttentionFrontEnd(embed_dim, num_heads, bias, rope_theta,
                                   qk_norm, qk_norm_eps, num_kv_heads,
-                                  head_dim, output_gate)
+                                  head_dim, output_gate, index)
         p = MultiHeadAttentionParams(front, kdim, vdim, dropout, add_bias_kv,
                                      add_zero_attn, causal, impl)
         inits = ({} if kernel_initializer is None

@@ -86,7 +86,9 @@ class TransformerLMConfig:
     position: str = "learned"
     rope_theta: float = 10000.0
     attention_bias: bool = True
-    qk_norm: bool = False
+    # False | "projection" (True: OLMoE's, over the whole q and k) |
+    # "head" (over each head): ops/attention.AttentionFrontEnd.qk_norm
+    qk_norm: object = False
     # gelu | swiglu (SiLU-gated, bias-free, of `intermediate_size`) | moe
     # (SiLU-gated routed experts)
     mlp: str = "gelu"
@@ -101,13 +103,15 @@ class TransformerLMConfig:
     # `intermediate_size`; `moe_routing` holds the further fields of
     # ops.moe.MoEMLPParams (the sigmoid group-limited router, the shared
     # expert, the experts held here); `initializer_range` > 0 draws every
-    # matrix from N(0, that)
+    # matrix from N(0, that), and `embedding_range` > 0 the embedding from
+    # N(0, that) instead
     attention: str = "mha"         # mha | latent
     latent: Optional[object] = None
     intermediate_size: int = 0
     first_k_dense: int = 0
     moe_routing: Optional[dict] = None
     initializer_range: float = 0.0
+    embedding_range: float = 0.0
     # Solar-Open2 (`solar_open2_lm_config`): grouped keys and values, a
     # head's size apart from hidden / heads and a sigmoid output gate on
     # the softmax layers (ops/attention.AttentionFrontEnd's fields);
@@ -119,6 +123,10 @@ class TransformerLMConfig:
     attention_gate: bool = False
     layer_pattern: Optional[tuple] = None
     delta: Optional[object] = None
+    # Keye-VL-2.0 (`keye_vl2_lm_config`): `indexer` (an
+    # ops.attention.Indexer) gives the "mha" layers a learned top-k
+    # selection of the positions a row attends; needs position "rope"
+    indexer: Optional[object] = None
 
     def layer_kind(self, i: int) -> str:
         return self.layer_pattern[i] if self.layer_pattern else self.attention
@@ -268,6 +276,61 @@ def solar_open2_lm_config(config: dict, *, sequence_length: int,
         initializer_range=initializer_range)
 
 
+def keye_vl2_lm_config(config: dict, *, sequence_length: int,
+                       attention_impl: str = "xla",
+                       initializer_range: float = 0.02,
+                       embedding_range: float = 0.0
+                       ) -> TransformerLMConfig:
+    """The language model of Keye-VL-2.0 from the keys of its published
+    config.json (`model_type: KeyeVL2`; models/keye_vl2_reference.py
+    writes the equations out and says what the keys leave open): every
+    layer is softmax attention with grouped keys and values, RMSNorm of
+    each q and k head, RoPE, and over it the `sa_config` indexer's top-k
+    selection; then routed experts under a softmax router renormalised
+    over the chosen, no shared expert. Text positions only (the three
+    components of `mrope_section` are then equal: 1-D RoPE); the vision
+    tower is not built. Random weights are N(0, `initializer_range`), the
+    embedding N(0, `embedding_range`) where that is given: at the
+    matrices' 0.02 a token's embedding is a tenth of the first attention's
+    output, the residual stream of an untrained stack is its context's mean
+    from there on, and every row of a sequence then routes alike."""
+    from ..ops.attention import Indexer
+
+    if (config.get("mlp_only_layers") or config["decoder_sparse_step"] != 1
+            or config.get("use_sliding_window")
+            or config.get("attention_bias")):
+        raise NotImplementedError(
+            "keye_vl2_lm_config builds the published block: every layer "
+            "an expert layer, no sliding window, no attention bias")
+    sa = config["sa_config"]
+    if sa["indexer_num_kv_heads"] != 1:
+        raise NotImplementedError(
+            "keye_vl2_lm_config: the indexer has one key a token")
+    experts = config["num_experts"]
+    return TransformerLMConfig(
+        vocab_size=config["vocab_size"], hidden_size=config["hidden_size"],
+        num_heads=config["num_attention_heads"],
+        num_layers=config["num_hidden_layers"],
+        sequence_length=sequence_length, attention_impl=attention_impl,
+        norm="rmsnorm", norm_eps=config["rms_norm_eps"], position="rope",
+        rope_theta=float(config["rope_theta"]), attention_bias=False,
+        qk_norm="head", num_kv_heads=config["num_key_value_heads"],
+        head_dim=config["head_dim"],
+        indexer=Indexer(
+            n_heads=sa["indexer_num_heads"],
+            head_dim=sa["indexer_head_dim"], topk=sa["topk"],
+            rope_dim=sa["indexer_head_dim"]),
+        mlp="moe", num_experts=experts,
+        num_experts_per_tok=config["num_experts_per_tok"],
+        moe_intermediate_size=config["moe_intermediate_size"],
+        # every expert is held: said so that the layer keeps the counts a
+        # serving graph's `moe_assignments` / `moe_dropped` read
+        moe_routing=dict(norm_topk_prob=config["norm_topk_prob"],
+                         experts_held=(0, experts)),
+        initializer_range=initializer_range,
+        embedding_range=embedding_range)
+
+
 def _norm_initializer(stddev: float):
     from ..initializer import NormInitializer
 
@@ -306,7 +369,7 @@ def _lm_trunk(ff, c: TransformerLMConfig, h, pos):
                 rope_theta=c.rope_theta if rope else 0.0,
                 qk_norm=c.qk_norm, qk_norm_eps=c.norm_eps,
                 num_kv_heads=c.num_kv_heads, head_dim=c.head_dim,
-                output_gate=c.attention_gate,
+                output_gate=c.attention_gate, index=c.indexer,
                 kernel_initializer=init,
             )
         h = ff.add(h, a, name=f"{p}res1")
@@ -347,10 +410,11 @@ def build_transformer_lm(ff, config: TransformerLMConfig | None = None,
     bs = batch_size or ff.config.batch_size
     tokens = ff.create_tensor((bs, c.sequence_length), DataType.DT_INT32,
                               name="tokens")
+    embedding_range = c.embedding_range or c.initializer_range
     h = ff.embedding(
         tokens, c.vocab_size, c.hidden_size, name="wte",
-        kernel_initializer=(None if not c.initializer_range else
-                            _norm_initializer(c.initializer_range)))
+        kernel_initializer=(None if not embedding_range else
+                            _norm_initializer(embedding_range)))
     pos = ff.create_tensor((bs, c.sequence_length), DataType.DT_INT32,
                            name="positions")
     if c.position == "learned":  # rotary positions go to the attention ops

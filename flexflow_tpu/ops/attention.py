@@ -24,10 +24,11 @@ placement:
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from dataclasses import dataclass
-from typing import ClassVar
+from typing import ClassVar, Optional
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +49,26 @@ def proj(ctx, x, w, b):
 
 
 @dataclass(frozen=True)
+class Indexer:
+    """DeepSeek-Sparse-Attention's lightning indexer on a softmax layer
+    with keys and values of its own (Keye-VL-2.0's `sa_config`;
+    models/keye_vl2_reference.py writes the equations out): `n_heads`
+    queries of `head_dim` and their weights projected from the hidden
+    state, one key of `head_dim` a token (LayerNorm with a bias), the
+    first `rope_dim` lanes of each rotated at the layer's `rope_theta`
+    (half-rotation form); a query row attends the `topk` earlier tokens of
+    largest sum_j w_j relu(q_j . k). Latent attention carries its own
+    (ops/latent_attention.LatentFrontEnd, whose indexer queries come from
+    the query latent)."""
+
+    n_heads: int
+    head_dim: int
+    topk: int
+    rope_dim: int
+    norm_eps: float = 1e-6
+
+
+@dataclass(frozen=True)
 class AttentionFrontEnd:
     """What an attention layer is before and after its core, whichever op
     runs the core: the decode replay hands a trained layer's front end to
@@ -59,9 +80,12 @@ class AttentionFrontEnd:
     # rotary positions on q and k (half-rotation form) from positions
     # (batch, seq) int; 0 = none
     rope_theta: float = 0.0
-    # RMSNorm over the whole q and k projections (all heads together, as
-    # OLMoE does), with learned scales `q_norm` / `k_norm`
-    qk_norm: bool = False
+    # RMSNorm of q and k before RoPE, with learned scales `q_norm` /
+    # `k_norm`: "projection" (or True) over the whole projections, all
+    # heads together, as OLMoE does; "head" over each head on its own,
+    # one scale of head_dim for all of them (Keye-VL-2.0's family);
+    # False = none
+    qk_norm: object = False
     qk_norm_eps: float = 1e-5
     # grouped keys and values: `num_kv_heads` heads of k and v, query head
     # i reading KV head i // (num_heads // num_kv_heads); 0 = num_heads
@@ -72,15 +96,46 @@ class AttentionFrontEnd:
     head_size: int = 0
     # the core's output times sigmoid(x @ wg), elementwise, before `wo`
     output_gate: bool = False
+    # a learned selection of the positions a row attends (`Indexer`);
+    # None = all of its past
+    index: Optional[Indexer] = None
 
     # the four every attention layer has; `wg` joins them under
-    # `output_gate` (`matrices`)
+    # `output_gate`, the indexer's three under `index` (`matrices`)
     kernels: ClassVar[tuple] = ("wq", "wk", "wv", "wo")
+
+    def __post_init__(self):
+        if self.qk_norm not in (False, True, "projection", "head"):
+            raise ValueError(
+                f"AttentionFrontEnd.qk_norm is False, 'projection' (True) "
+                f"or 'head', got {self.qk_norm!r}")
+        if self.index is not None and not self.rope_theta:
+            raise ValueError(
+                "AttentionFrontEnd.index rotates its queries and key: it "
+                "needs rope_theta and positions")
 
     @property
     def matrices(self) -> tuple:
         """The weights a kernel initializer draws."""
-        return self.kernels + (("wg",) if self.output_gate else ())
+        return (self.kernels + (("wg",) if self.output_gate else ())
+                + (("wi_q", "wi_k", "wi_w") if self.index else ()))
+
+    @property
+    def qk_norm_width(self) -> tuple:
+        """(numbers `q_norm` scales, numbers `k_norm` scales); (0, 0)
+        without QK-norm."""
+        if not self.qk_norm:
+            return 0, 0
+        if self.qk_norm == "head":
+            return self.head_dim, self.head_dim
+        return self.q_width, self.kv_width
+
+    def scope(self, name: str):
+        """The trace scope of a part of a layer with a learned selection
+        (`gsa.qkv`, `gsa.attend`, `gsa.out`: docs/observability.md); the
+        other layers' parts stay under the names their readers know."""
+        return (jax.named_scope(name) if self.index
+                else contextlib.nullcontext())
 
     @property
     def head_dim(self) -> int:
@@ -99,6 +154,32 @@ class AttentionFrontEnd:
         """Numbers a token's key (and its value) holds: a cache row."""
         return self.kv_heads * self.head_dim
 
+    def selected(self, cached_rows: int) -> int:
+        """Positions a row attends at the most over a cache of
+        `cached_rows` rows a slot under the learned selection; 0 where
+        the layer has none, or the cache holds no more than `topk`: the
+        selection is then everything, and the layer is served as a plain
+        one (the indexer's weights lie unused)."""
+        if self.index and cached_rows > self.index.topk:
+            return self.index.topk
+        return 0
+
+    def cache_row_widths(self, cached_rows: int) -> dict:
+        """{pool leaf: numbers a token holds in it} of the layer's paged
+        decode op over a cache of `cached_rows` rows a slot: keys and
+        values in a pool each, or, under a learned selection, side by
+        side in one row (a selected token is one gathered row:
+        kernels/sparse_grouped_attention.py) beside the indexer's key,
+        which is stored in a row of the next multiple of 128 (zeros
+        behind it): a TPU lays out an array whose last dimension is no
+        multiple of its 128 lanes with another dimension innermost, and
+        every step then copies the whole pool into the row layout and
+        back (ops/latent_attention.LatentFrontEnd.cache_row_widths)."""
+        if self.selected(cached_rows):
+            return {"pool_kv": 2 * self.kv_width,
+                    "pool_i": -(-self.index.head_dim // 128) * 128}
+        return {"pool_k": self.kv_width, "pool_v": self.kv_width}
+
     def weight_specs(self, q_dim: int, k_dim: int, v_dim: int):
         """The trainable weights, in the order parameters are initialised.
         Per-head projection sizes follow attention.cc:70-80."""
@@ -113,40 +194,87 @@ class AttentionFrontEnd:
                    for b, n in (("bq", Q), ("bk", KV), ("bv", KV), ("bo", E))]
         if self.qk_norm:
             ws += [WeightSpec(g, (n,), f, "ones")
-                   for g, n in (("q_norm", Q), ("k_norm", KV))]
+                   for g, n in zip(("q_norm", "k_norm"),
+                                   self.qk_norm_width)]
+        if self.index:
+            nI, dI = self.index.n_heads, self.index.head_dim
+            ws += [WeightSpec("wi_q", (q_dim, nI * dI), f),
+                   WeightSpec("wi_k", (q_dim, dI), f),
+                   WeightSpec("wi_k_norm", (dI,), f, "ones"),
+                   WeightSpec("wi_k_bias", (dI,), f, "zeros"),
+                   WeightSpec("wi_w", (q_dim, nI), f)]
         return ws
 
     def qkv(self, ctx, weights, q_in, k_in, v_in, positions=None):
         """The projections, then QK-norm, then RoPE, all on the packed
         (batch, seq, heads * head_dim) layout."""
-        q = proj(ctx, q_in, weights["wq"], weights.get("bq"))
-        k = proj(ctx, k_in, weights["wk"], weights.get("bk"))
-        v = proj(ctx, v_in, weights["wv"], weights.get("bv"))
-        if self.qk_norm:
-            from .core import rms_norm
+        with self.scope("gsa.qkv"):
+            q = proj(ctx, q_in, weights["wq"], weights.get("bq"))
+            k = proj(ctx, k_in, weights["wk"], weights.get("bk"))
+            v = proj(ctx, v_in, weights["wv"], weights.get("bv"))
+            if self.qk_norm:
+                from .core import rms_norm
 
-            q = rms_norm(q, weights["q_norm"], self.qk_norm_eps)
-            k = rms_norm(k, weights["k_norm"], self.qk_norm_eps)
-        if self.rope_theta:
-            cos, sin = rope_cos_sin(positions, self.head_dim,
-                                    self.rope_theta)
-            q = apply_rope(q, cos, sin, self.num_heads)
-            k = apply_rope(k, cos, sin, self.kv_heads)
+                def norm(x, scale):
+                    if self.qk_norm != "head":
+                        return rms_norm(x, scale, self.qk_norm_eps)
+                    heads = x.reshape(x.shape[:-1] + (-1, self.head_dim))
+                    return rms_norm(heads, scale,
+                                    self.qk_norm_eps).reshape(x.shape)
+
+                q, k = norm(q, weights["q_norm"]), norm(k, weights["k_norm"])
+            if self.rope_theta:
+                cos, sin = rope_cos_sin(positions, self.head_dim,
+                                        self.rope_theta)
+                q = apply_rope(q, cos, sin, self.num_heads)
+                k = apply_rope(k, cos, sin, self.kv_heads)
         return q, k, v
+
+    def index_inputs(self, ctx, weights, x, positions):
+        """What the learned selection of x (batch, seq, hidden) at
+        `positions` (batch, seq) starts from: the indexer's queries qi
+        (batch, seq, n_heads, head_dim) and key ki (batch, seq, head_dim),
+        rotated, and the heads' weights wt (batch, seq, n_heads)
+        float32."""
+        ix = self.index
+        nI, dI, dr = ix.n_heads, ix.head_dim, ix.rope_dim
+        inv_freq = self.rope_theta ** (
+            -jnp.arange(0, dr, 2, dtype=jnp.float32) / dr)
+        angles = positions.astype(jnp.float32)[..., None] * inv_freq
+        with jax.named_scope("dsa.index"):
+            qi = proj(ctx, x, weights["wi_q"], None).reshape(
+                x.shape[:-1] + (nI, dI))
+            qi = jnp.concatenate(
+                [rope_half(qi[..., :dr], angles[..., None, :]),
+                 qi[..., dr:]], axis=-1)
+            ki = layer_norm(proj(ctx, x, weights["wi_k"], None),
+                            weights["wi_k_norm"], weights["wi_k_bias"],
+                            ix.norm_eps)
+            ki = jnp.concatenate([rope_half(ki[..., :dr], angles),
+                                  ki[..., dr:]], axis=-1)
+            wt = (proj(ctx, x, weights["wi_w"], None).astype(jnp.float32)
+                  * (nI ** -0.5) * (dI ** -0.5))
+        return qi, ki, wt
 
     def output(self, ctx, weights, o, x=None):
         """The output projection of the core's `o`; `x`, the layer's
         input, feeds the output gate where the front end has one."""
-        if self.output_gate:
-            gate = proj(ctx, x, weights["wg"], None)
-            o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
-        return proj(ctx, o, weights["wo"], weights.get("bo"))
+        with self.scope("gsa.out"):
+            if self.output_gate:
+                gate = proj(ctx, x, weights["wg"], None)
+                o = o * jax.nn.sigmoid(
+                    gate.astype(jnp.float32)).astype(o.dtype)
+            return proj(ctx, o, weights["wo"], weights.get("bo"))
 
     def linear_flops(self, batch, q_rows, kv_rows, q_dim, k_dim, v_dim):
         """FLOPs of the projections over a batch of q_rows queries and
         kv_rows keys and values."""
         E, Q, KV = self.embed_dim, self.q_width, self.kv_width
         gate = q_rows * q_dim * Q if self.output_gate else 0
+        if self.index:
+            gate += q_rows * q_dim * (
+                (self.index.n_heads + 1) * self.index.head_dim
+                + self.index.n_heads)
         return 2.0 * batch * (q_rows * q_dim * Q + kv_rows * k_dim * KV
                               + kv_rows * v_dim * KV + q_rows * Q * E + gate)
 
@@ -220,12 +348,37 @@ def apply_rope(x, cos, sin, num_heads: int):
     return y.reshape(b, s, e).astype(x.dtype)
 
 
-def sdpa_xla(q, k, v, *, causal: bool, scale: float):
+def rope_half(x, angles):
+    """Pairs (x[i], x[i + d/2]) rotated by angles (.., d / 2); float32
+    arithmetic, one cast back."""
+    xf = x.astype(jnp.float32)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    a, b = jnp.split(xf, 2, axis=-1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def layer_norm(x, scale, bias, eps):
+    """LayerNorm over the last dimension with a scale and a bias, float32
+    arithmetic (the indexers' key norm)."""
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
+    y = (xf - mean) * jax.lax.rsqrt(var + eps)
+    return (y * scale.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+def sdpa_xla(q, k, v, *, causal: bool, scale: float, mask=None):
     """Reference-semantics scaled dot-product attention, einsum form.
-    q,k,v: (batch, heads, seq, head_dim)."""
+    q,k,v: (batch, heads, seq, head_dim); `mask` (batch, seq, seq) bool,
+    where given, is the positions each row attends (a learned selection,
+    causal already)."""
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32)
     logits = logits * scale
-    if causal:
+    if mask is not None:
+        logits = jnp.where(mask[:, None], logits, -1e30)
+    elif causal:
         s_q, s_k = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
         logits = jnp.where(mask, logits, -1e30)
@@ -240,6 +393,22 @@ def _mha_forward(p: MultiHeadAttentionParams, inputs, weights, state, ctx):
     scale = 1.0 / math.sqrt(front.head_dim)
     group = H // front.kv_heads
     impl = p.impl
+    mask = None
+    if front.index:
+        # the training-shaped form of a learned selection: dense
+        # attention under the selection as a mask. Quadratic in seq: the
+        # graph a model is built and checked as, not a long-context path
+        from ..kernels.sparse_selection import causal_selection_mask
+
+        if not p.causal or impl == "ring":
+            raise NotImplementedError(
+                "attention with an indexer is causal self-attention "
+                "through the einsum core (impl 'xla' or 'flash', which "
+                "falls back to it)")
+        qi, ki, wt = front.index_inputs(ctx, weights, inputs[0], inputs[3])
+        with jax.named_scope("dsa.topk"):
+            mask = causal_selection_mask(qi, wt, ki, front.index.topk)
+        impl = "xla"
     if group > 1 and impl != "xla":
         # the packed and ring kernels select q, k and v heads by one lane
         # offset: one head count. Grouped keys and values are repeated
@@ -289,8 +458,8 @@ def _mha_forward(p: MultiHeadAttentionParams, inputs, weights, state, ctx):
                              mesh=ctx.mesh,
                              overlap=getattr(ctx, "overlap_collectives", True))
     else:
-        with jax.named_scope("gqa.attend"):
-            out = sdpa_xla(q, k, v, causal=p.causal, scale=scale)
+        with jax.named_scope("gsa.attend" if front.index else "gqa.attend"):
+            out = sdpa_xla(q, k, v, causal=p.causal, scale=scale, mask=mask)
     b, _, s, _ = out.shape
     out = out.transpose(0, 2, 1, 3).reshape(b, s, front.q_width)
     return [front.output(ctx, weights, out, inputs[0])], state
@@ -300,6 +469,9 @@ def _mha_flops(p: MultiHeadAttentionParams, in_shapes, out_shapes):
     q, k, v = in_shapes[:3]
     b, sq, sk = q[0], q[1], k[1]
     attn = 2.0 * b * p.num_heads * sq * sk * p.front.head_dim * 2
+    if p.front.index:
+        attn += (2.0 * b * sq * sk * p.front.index.n_heads
+                 * p.front.index.head_dim)
     return p.front.linear_flops(b, sq, sk, q[2], k[2], v[2]) + attn
 
 

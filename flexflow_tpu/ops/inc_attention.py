@@ -47,6 +47,15 @@ paged_rows_run_kernel below). What the op knows of them is
 under one table row, and where the paged kernel serves the call they go
 through one multi-query call that reads the chunk's context once
 (kernels/flash_attention.paged_flash_chunk_attention).
+
+A front end with an indexer (ops/attention.Indexer) over a cache of more
+rows a slot than its `topk` turns the paged op into attention under a
+learned selection (`_paged_selected_forward`): the pool's row is a
+token's keys and values side by side (`pool_kv`) beside the indexer's key
+(`pool_i`), every row scores its cached indexer keys and attends the
+`topk` largest only, in ops/latent_attention.py's rows layout
+(kernels/sparse_selection.py, kernels/sparse_grouped_attention.py; XLA's
+gather, matmul and sort, no Pallas kernel yet).
 """
 
 from __future__ import annotations
@@ -234,6 +243,18 @@ class PagedIncMultiHeadAttentionParams(FrontEndFields):
         """Page-table width: logical blocks covering max_seq_len rows."""
         return -(-self.max_seq_len // self.block_size)
 
+    @property
+    def selected(self) -> int:
+        """Positions a row attends at the most under the front end's
+        learned selection, 0 for a plain layer
+        (AttentionFrontEnd.selected)."""
+        return self.front.selected(self.blocks_per_slot * self.block_size)
+
+    @property
+    def cache_row_widths(self) -> dict:
+        return self.front.cache_row_widths(
+            self.blocks_per_slot * self.block_size)
+
 
 def paged_rows_run_kernel(p: PagedIncMultiHeadAttentionParams, mesh,
                           itemsize: int) -> bool:
@@ -286,6 +307,12 @@ def paged_chunk_query_tile(p: PagedIncMultiHeadAttentionParams, mesh,
 
 def _paged_mha_infer(p: PagedIncMultiHeadAttentionParams, in_shapes):
     x, positions, page_table = in_shapes
+    if p.selected and x[1] != 1:
+        raise NotImplementedError(
+            f"paged attention under a learned selection takes "
+            f"single-query rows (rows, 1, hidden), got q_len {x[1]}: a "
+            f"prefill chunk rides as rows past the slots (speculative "
+            f"verification is not served)")
     if page_table[-1] != p.blocks_per_slot:
         raise ValueError(
             f"page_table width {page_table[-1]} != blocks_per_slot "
@@ -298,10 +325,17 @@ def _paged_mha_weights(p: PagedIncMultiHeadAttentionParams, in_shapes):
     # the block pool: ONE tensor per layer shared by every slot (a block
     # mapped into N page tables is stored once — the prefix-sharing win),
     # so per-chip accounting counts it once, not per slot
-    pool = (p.num_blocks, p.block_size, p.front.kv_width)
-    return p.front.weight_specs(x[-1], x[-1], x[-1]) + [
-        WeightSpec(name, pool, p.cache_dtype, "zeros", trainable=False)
-        for name in ("pool_k", "pool_v")]
+    pools = [
+        WeightSpec(name, (p.num_blocks, p.block_size, width), p.cache_dtype,
+                   "zeros", trainable=False)
+        for name, width in p.cache_row_widths.items()]
+    if p.selected:
+        # the positions the slots' rows attended in the last call (-1
+        # where a row had fewer), as ops/latent_attention.py keeps them
+        pools.append(WeightSpec(
+            "sel_rows", (p.chunk_from or x[0], p.selected),
+            DataType.DT_INT32, "zeros", trainable=False))
+    return p.front.weight_specs(x[-1], x[-1], x[-1]) + pools
 
 
 def _paged_mha_forward(p: PagedIncMultiHeadAttentionParams, inputs, weights,
@@ -314,6 +348,9 @@ def _paged_mha_forward(p: PagedIncMultiHeadAttentionParams, inputs, weights,
     W = p.blocks_per_slot
     q, k, v = p.front.qkv(ctx, weights, x, x, x, positions)
     scale = 1.0 / math.sqrt(p.front.head_dim)
+    if p.selected:
+        return _paged_selected_forward(p, x, positions, page_table, q, k, v,
+                                       weights, ctx)
 
     pk, pv = weights["pool_k"], weights["pool_v"]
     positions = positions.astype(jnp.int32)
@@ -375,10 +412,82 @@ def _paged_mha_forward(p: PagedIncMultiHeadAttentionParams, inputs, weights,
                                                     "pool_v": pv}
 
 
+def _paged_selected_forward(p: PagedIncMultiHeadAttentionParams, x,
+                            positions, page_table, q, k, v, weights, ctx):
+    """The paged op under a learned selection (`p.selected` > 0), on
+    single-query rows: every row writes its [k ; v] row and its indexer
+    key, then scores the indexer keys its context cached and attends the
+    `topk` largest only. A slot's row gathers those rows of `pool_kv`; the
+    rows past `chunk_from` are one chunk's and run dense over their shared
+    context under the selection as a mask (ops/latent_attention.py's
+    layout, kernels/sparse_selection.py, sparse_grouped_attention.py)."""
+    from ..kernels import sparse_grouped_attention as sga
+    from ..kernels import sparse_selection as sel
+
+    f, bs = p.front, p.block_size
+    rows = x.shape[0]
+    qi, ki, wt = f.index_inputs(ctx, weights, x, positions)
+    # the pool's indexer row is lane-aligned, zeros behind the key
+    # (AttentionFrontEnd.cache_row_widths): queries and key are filled up
+    # alike, which adds nothing to q . k
+    pad = weights["pool_i"].shape[-1] - f.index.head_dim
+    qi = jnp.pad(qi[:, 0], ((0, 0), (0, 0), (0, pad)))
+    ki, wt = jnp.pad(ki[:, 0], ((0, 0), (0, pad))), wt[:, 0]
+    positions = positions[:, 0].astype(jnp.int32)
+    page_table = page_table.astype(jnp.int32)
+    live = (positions >= 0) & (positions < p.max_seq_len)
+    pos = jnp.where(live, positions, -1)  # -1: attends nothing
+
+    # write this call's rows before any row reads; a dead row writes
+    # zeros into the scratch block (the plain op's rule)
+    pos_c = jnp.maximum(pos, 0)
+    phys = jnp.take_along_axis(page_table, (pos_c // bs)[:, None],
+                               axis=1)[:, 0]
+    phys = jnp.where(live, phys, 0)
+    offset = jnp.where(live, pos_c % bs, 0)
+    pool_kv, pool_i = weights["pool_kv"], weights["pool_i"]
+    kv = jnp.where(live[:, None],
+                   jnp.concatenate([k[:, 0], v[:, 0]], axis=-1), 0.0)
+    pool_kv = pool_kv.at[phys, offset].set(kv.astype(pool_kv.dtype))
+    pool_i = pool_i.at[phys, offset].set(
+        jnp.where(live[:, None], ki, 0.0).astype(pool_i.dtype))
+
+    n = rows if p.chunk_from is None else min(rows, p.chunk_from)
+    chunk = rows > n  # the slots' rows come first; the rest is one chunk
+    with jax.named_scope("dsa.index"):
+        index = sel.index_scores_rows(qi[:n], wt[:n], pool_i,
+                                      page_table[:n], pos[:n])
+        if chunk:
+            index_c = sel.index_scores_chunk(qi[n:], wt[n:], pool_i,
+                                             page_table[n], pos[n:])
+    with jax.named_scope("dsa.topk"):
+        picked, valid = sel.select_topk(index, p.selected)
+        if chunk:
+            mask = sel.selection_mask(index_c, p.selected)
+    qh = q[:, 0].reshape(rows, p.num_heads, f.head_dim)
+    kw = dict(kv_heads=f.kv_heads, scale=1.0 / math.sqrt(f.head_dim))
+    with jax.named_scope("gsa.attend"):
+        o = sga.attend_selected(qh[:n], pool_kv, page_table[:n], picked,
+                                valid, **kw)
+        if chunk:
+            o = jnp.concatenate([o, sga.attend_chunk(
+                qh[n:], pool_kv, page_table[n], mask, pos[n:], **kw)])
+    out = f.output(ctx, weights, o.reshape(rows, 1, -1), x)
+    new_state = {"pool_kv": pool_kv, "pool_i": pool_i}
+    if n == weights["sel_rows"].shape[0]:
+        new_state["sel_rows"] = jnp.where(valid, picked, -1)
+    return [out], new_state
+
+
 def _paged_mha_flops(p: PagedIncMultiHeadAttentionParams, in_shapes,
                      out_shapes):
-    return _decode_flops(p.front, in_shapes[0],
-                         p.blocks_per_slot * p.block_size)
+    cached = p.blocks_per_slot * p.block_size
+    if not p.selected:
+        return _decode_flops(p.front, in_shapes[0], cached)
+    rows, q_len, d = in_shapes[0]
+    ix = p.front.index
+    return (_decode_flops(p.front, in_shapes[0], p.selected)
+            + 2.0 * rows * q_len * cached * ix.n_heads * ix.head_dim)
 
 
 register_op(OpDef(OT.OP_PAGED_INC_MULTIHEAD_ATTENTION, _paged_mha_infer,

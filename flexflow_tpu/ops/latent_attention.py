@@ -43,7 +43,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..fftype import DataType, OperatorType as OT
-from .attention import proj
+from ..kernels.sparse_selection import causal_selection_mask
+from .attention import layer_norm, proj, rope_half
 from .base import OpDef, WeightSpec, register_op
 from .core import rms_norm
 
@@ -168,12 +169,12 @@ class LatentFrontEnd:
             qi = proj(ctx, cq, weights["wi_q"], None).reshape(
                 lead + (nI, dI))
             qi = jnp.concatenate(
-                [_rope_half(qi[..., :dr], angles[..., None, :]),
+                [rope_half(qi[..., :dr], angles[..., None, :]),
                  qi[..., dr:]], axis=-1)
-            ki = _layer_norm(proj(ctx, x, weights["wi_k"], None),
+            ki = layer_norm(proj(ctx, x, weights["wi_k"], None),
                              weights["wi_k_norm"], weights["wi_k_bias"],
                              self.index_norm_eps)
-            ki = jnp.concatenate([_rope_half(ki[..., :dr], angles),
+            ki = jnp.concatenate([rope_half(ki[..., :dr], angles),
                                   ki[..., dr:]], axis=-1)
             wt = (proj(ctx, x, weights["wi_w"], None).astype(jnp.float32)
                   * (nI ** -0.5) * (dI ** -0.5))
@@ -212,24 +213,6 @@ def _rope_interleaved(x, angles):
                      axis=-1).reshape(x.shape).astype(x.dtype)
 
 
-def _rope_half(x, angles):
-    """Pairs (x[i], x[i + d/2]) rotated by angles (.., d / 2)."""
-    xf = x.astype(jnp.float32)
-    cos, sin = jnp.cos(angles), jnp.sin(angles)
-    a, b = jnp.split(xf, 2, axis=-1)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
-
-
-def _layer_norm(x, scale, bias, eps):
-    xf = x.astype(jnp.float32)
-    mean = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
-    y = (xf - mean) * jax.lax.rsqrt(var + eps)
-    return (y * scale.astype(jnp.float32)
-            + bias.astype(jnp.float32)).astype(x.dtype)
-
-
 # ------------------------------------------------------------ training-shaped
 
 @dataclass(frozen=True)
@@ -249,23 +232,6 @@ def _latent_weights(p: LatentAttentionParams, in_shapes):
     return p.front.weight_specs(in_shapes[0][-1])
 
 
-def selection_mask(qi, wt, ki, topk: int):
-    """(batch, seq, seq) bool: the positions s <= t each t attends, the
-    topk of largest index score, all of them while t < topk."""
-    scores = jnp.einsum("btjd,bsd->btjs", qi, ki,
-                        preferred_element_type=jnp.float32)
-    index = jnp.sum(wt[..., None] * jax.nn.relu(scores), axis=2)
-    s = index.shape[-1]
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    if s <= topk:
-        return jnp.broadcast_to(causal, index.shape)
-    # exactly topk of them, ties to the lower position as lax.top_k
-    # breaks them (a ReLU makes exact zeros where indexer heads are few)
-    sel = jax.lax.top_k(jnp.where(causal, index, -1e30), topk)[1]
-    picked = jnp.any(sel[..., None] == jnp.arange(s), axis=-2)
-    return causal & picked
-
-
 def _latent_forward(p: LatentAttentionParams, inputs, weights, state, ctx):
     f = p.front
     x, positions = inputs
@@ -273,7 +239,7 @@ def _latent_forward(p: LatentAttentionParams, inputs, weights, state, ctx):
     q_nope, q_rope, ckv, kr, qi, ki, wt = f.project(ctx, weights, x,
                                                     positions)
     with jax.named_scope("dsa.topk"):
-        mask = selection_mask(qi, wt, ki, f.index_topk)
+        mask = causal_selection_mask(qi, wt, ki, f.index_topk)
     w_uk, w_uv = f.up_weights(weights, x.dtype)
     with jax.named_scope("mla.attend"):
         k_nope = jnp.einsum("bsc,chn->bshn", ckv, w_uk)

@@ -378,6 +378,12 @@ class MoEMLPParams:
     # result is the shared expert's plus the chosen-and-held experts';
     # what the absent ones would add is left out. None = all of them
     experts_held: Optional[tuple] = None
+    # rows of `chunk_expert_ids`, the record of the experts chosen for
+    # the rows of a call that come PAST the rows the graph was built for:
+    # a prefill chunk riding as single-query rows past a decode graph's
+    # slots (serving/decode_graph.py sets it to the engine's prefill
+    # chunk). 0 = no such record
+    chunk_rows: int = 0
 
     @property
     def held(self) -> tuple:
@@ -430,6 +436,12 @@ def _moe_mlp_weights(p: MoEMLPParams, in_shapes):
         # implementation needs the choice itself
         WeightSpec("expert_ids", (tokens, p.num_experts_per_tok),
                    DataType.DT_INT32, "zeros", trainable=False),
+        # the same for a chunk's rows past them (`chunk_rows`): what a
+        # prompt's tokens chose, which no later step computes again
+        *([WeightSpec("chunk_expert_ids",
+                      (p.chunk_rows, p.num_experts_per_tok),
+                      DataType.DT_INT32, "zeros", trainable=False)]
+          if p.chunk_rows else []),
     ]
 
 
@@ -598,8 +610,12 @@ def _moe_mlp_forward(p: MoEMLPParams, inputs, weights, state, ctx):
         # a serving step with a prefill chunk riding as single-query rows
         # past the slots': the record keeps the slots' rows, which come
         # first (a state leaf keeps its shape; any other layout leaves
-        # the record as it was)
+        # the record as it was), and the chunk's rows beside it where the
+        # graph keeps that record
         state["expert_ids"] = ids[:declared.shape[0]]
+        past, n = weights.get("chunk_expert_ids"), declared.shape[0]
+        if past is not None and shape[0] - n <= past.shape[0]:
+            state["chunk_expert_ids"] = past.at[:shape[0] - n].set(ids[n:])
     if p.experts_held is not None:
         state["assignments_total"] = (weights.get("assignments_total", 0)
                                       + computed.astype(jnp.int32))

@@ -24,7 +24,7 @@ the SAME layer names, so:
 from __future__ import annotations
 
 import copy
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Optional
 
 from ..fftype import (
@@ -32,11 +32,13 @@ from ..fftype import (
 )
 
 
-# the block pool's state leaves: keys and values of multi-head attention,
-# the latent row and the indexer's key of latent attention
-# (ops/latent_attention.py). Every one is (num_blocks, block_size, width)
-# under the one page table; a copy-on-write copies them all
-POOL_LEAVES = ("pool_k", "pool_v", "pool_c", "pool_i")
+# the block pool's state leaves: keys and values of multi-head attention
+# (side by side in one row, `pool_kv`, under a learned selection:
+# ops/inc_attention.py), the latent row of latent attention
+# (ops/latent_attention.py), the indexer's key of either. Every one is
+# (num_blocks, block_size, width) under the one page table; a
+# copy-on-write copies them all
+POOL_LEAVES = ("pool_k", "pool_v", "pool_kv", "pool_c", "pool_i")
 # the per-layer KV cache's state leaves, paged and contiguous
 KV_LEAVES = (*POOL_LEAVES, "cache_k", "cache_v")
 # what a recurrent layer keeps a SLOT beside the pool: the delta rule's
@@ -68,6 +70,36 @@ def refuse_recurrent(model, what: str):
             f"state is neither rewound nor handed off")
 
 
+def indexed_layers(model) -> list:
+    """Names of the graph's layers that keep an indexer key a token beside
+    their cache rows (training graph or decode graph): what moves or
+    rewinds the one has to move or rewind the other."""
+    def indexed(l):
+        if l.op_type in (OT.OP_LATENT_ATTENTION,
+                         OT.OP_PAGED_LATENT_ATTENTION):
+            return True
+        return (l.op_type in (OT.OP_MULTIHEAD_ATTENTION,
+                              OT.OP_PAGED_INC_MULTIHEAD_ATTENTION,
+                              OT.OP_INC_MULTIHEAD_ATTENTION)
+                and l.params.front.index is not None)
+
+    return [l.name for l in model.layers if indexed(l)]
+
+
+def refuse_indexed(model, what: str):
+    """The KV handoff carries `pool_k` / `pool_v` blocks and a
+    verification call scores several tokens a slot at once: neither knows
+    the indexer's pool nor the selection, so a graph with a learned
+    selection is refused, not served wrong."""
+    indexed = indexed_layers(model)
+    if indexed:
+        raise NotImplementedError(
+            f"{what} cannot serve a graph with a learned sparse selection "
+            f"(an indexer pool beside the cache rows: {indexed[0]}, ...): "
+            f"the indexer's keys are neither handed off nor scored by a "
+            f"multi-token call")
+
+
 def slot_state_bytes(model, slots: int, at_rest: DataType) -> int:
     """Bytes the recurrent layers of a training graph keep for `slots`
     slots in its decode graph: priced beside the pool."""
@@ -87,12 +119,12 @@ def slot_state_bytes(model, slots: int, at_rest: DataType) -> int:
                + tail * math.prod(leaves["state_conv"]) for leaves in shapes)
 
 
-def cache_row_widths(layer) -> dict:
+def cache_row_widths(layer, cached_rows: int) -> dict:
     """{pool leaf: numbers a token holds in it} of a training-graph layer
-    whose decode op keeps a cache, {} of any other layer."""
+    whose decode op keeps a cache of `cached_rows` rows a slot, {} of any
+    other layer."""
     if layer.op_type == OT.OP_MULTIHEAD_ATTENTION:
-        return {"pool_k": layer.params.front.kv_width,
-                "pool_v": layer.params.front.kv_width}
+        return layer.params.front.cache_row_widths(cached_rows)
     if layer.op_type == OT.OP_LATENT_ATTENTION:
         return layer.params.front.cache_row_widths
     return {}
@@ -205,8 +237,9 @@ def resolve_pool_blocks(model, spec: ServingSpec, max_seq: int,
             for ws in (model._params or {}).values() for w in ws.values())
         # a block holds, in every cached layer, `bs` rows of each of
         # that layer's pool leaves
-        block_bytes = sum(bs * width * itemsize for l in model.layers
-                          for width in cache_row_widths(l).values())
+        block_bytes = sum(
+            bs * width * itemsize for l in model.layers
+            for width in cache_row_widths(l, table_width * bs).values())
         if block_bytes <= 0:
             return capacity
         budget = (0.9 * hbm - weight_bytes
@@ -328,6 +361,11 @@ def build_decode_model(model, spec: ServingSpec):
                     f"{layer.name}: kdim/vdim != embed_dim not supported "
                     f"in the decode graph")
             # the trained layer's front end goes to the decode op whole
+            if p.front.selected(max_seq) and not paged:
+                raise NotImplementedError(
+                    f"{layer.name}: attention under a learned selection "
+                    f"is served from the paged pool only "
+                    f"(kv_layout='paged')")
             if paged:
                 op, np_, feeds = (
                     OT.OP_PAGED_INC_MULTIHEAD_ATTENTION,
@@ -370,8 +408,13 @@ def build_decode_model(model, spec: ServingSpec):
                 [ins[0], positions, page_table], name=layer.name,
                 data_type=layer.data_type)
         else:
+            params = layer.params
+            if layer.op_type == OT.OP_MOE_MLP and paged:
+                # a chunk rides as rows past the slots (engine.py): the
+                # layer records what those rows chose too
+                params = replace(params, chunk_rows=spec.prefill_chunk)
             new = dec._add_layer(
-                layer.op_type, layer.params, ins, name=layer.name,
+                layer.op_type, params, ins, name=layer.name,
                 initializers=dict(layer.initializers),
                 data_type=layer.data_type, shared_op=shared)
         layer_map[layer.layer_guid] = new

@@ -103,7 +103,7 @@ from .. import telemetry
 from ..engine.chunking import plan_chunks
 from .decode_graph import (
     KV_LEAVES, STATE_LEAVES, ServingSpec, adopt_params, build_decode_model,
-    recurrent_layers, refuse_recurrent,
+    recurrent_layers, refuse_indexed, refuse_recurrent,
 )
 from .paged import SCRATCH_BLOCK, BlockManager
 from .scheduler import ContinuousBatchingScheduler, Request
@@ -268,7 +268,7 @@ class ServingEngine:
         nodes = self.decode_model.graph.topo_order()
         self._sel_cap = next(
             (n.params.selected for n in nodes
-             if n.op_type == OT.OP_PAGED_LATENT_ATTENTION), 0)
+             if n.op_type in PAGED_OPS and n.params.selected), 0)
         self._moe_nodes = [n.name for n in nodes
                            if n.op_type == OT.OP_MOE_MLP]
         self._moe_base = (0, 0)
@@ -403,10 +403,11 @@ class ServingEngine:
         from ..ops.inc_attention import paged_rows_run_kernel
 
         dec = self.decode_model
-        # latent attention takes a chunk as rows only: its op gathers the
-        # chunk's keys once for all of them (ops/latent_attention.py)
+        # attention under a learned selection takes a chunk as rows
+        # only: its op gathers the chunk's keys once for all of them
+        # (ops/latent_attention.py, ops/inc_attention.py)
         return all(
-            n.op_type == OT.OP_PAGED_LATENT_ATTENTION
+            n.params.selected
             or paged_rows_run_kernel(n.params, dec.executor.mesh,
                                      self._kv_itemsize)
             for n in dec.graph.topo_order() if n.op_type in PAGED_OPS)
@@ -865,6 +866,8 @@ class ServingEngine:
         import jax
 
         refuse_recurrent(self.decode_model, "extract_kv (the KV handoff)")
+        refuse_indexed(self.decode_model,
+                       "serving/engine.py: extract_kv (the KV handoff)")
         self._complete_in_flight()
         mgr = self.block_manager
         nblk = -(-num_tokens // mgr.block_size)
@@ -894,6 +897,8 @@ class ServingEngine:
                 "disaggregated admission requires the paged KV layout")
         refuse_recurrent(self.decode_model,
                          "admit_prefilled (the KV handoff)")
+        refuse_indexed(self.decode_model, "serving/engine.py: "
+                       "admit_prefilled (the KV handoff)")
         self._complete_in_flight()
         if not sched.free_slots:
             return None
@@ -1203,13 +1208,19 @@ class ServingEngine:
                 load.update(rows=rows, kv_rows_walked=int(kv_rows_walked))
             if self._sel_cap:
                 # a layer's indexer scores every cached row of every live
-                # row's context; its attention reads the selected ones
+                # row's context; its attention reads the selected ones.
+                # `index_rows`: the indexer keys the step reads from the
+                # pool, a slot's context each and a chunk's once for all
+                # of its rows
                 ctx = [s.length + 1 for s in decoding]
+                index_rows = sum(ctx)
                 if pre is not None:
                     ctx += range(start + 1, start + n + 1)
+                    index_rows += start + n
                 load.update(ctx_rows=int(sum(ctx)),
                             sel_rows=int(sum(min(c, self._sel_cap)
-                                             for c in ctx)))
+                                             for c in ctx)),
+                            index_rows=int(index_rows))
             if self._state_bytes_slot:
                 # slots whose recurrent state the step updates (the
                 # decoding ones and the chunk's), and the bytes of it:
@@ -1590,14 +1601,12 @@ class ServingEngine:
         blocks — or the full (slots, max_seq+1) region for contiguous.
         The serving bench's slots-at-fixed-HBM comparison reads this."""
         for n in self.decode_model.graph.topo_order():
-            if n.op_type == OT.OP_PAGED_INC_MULTIHEAD_ATTENTION:
+            if n.op_type in PAGED_OPS:
                 p = n.params
-                return (2 * self._kv_itemsize * p.num_blocks * p.block_size
-                        * p.embed_dim)
-            if n.op_type == OT.OP_PAGED_LATENT_ATTENTION:
-                p = n.params
+                widths = (p.front if n.op_type
+                          == OT.OP_PAGED_LATENT_ATTENTION else p)
                 return (self._kv_itemsize * p.num_blocks * p.block_size
-                        * sum(p.front.cache_row_widths.values()))
+                        * sum(widths.cache_row_widths.values()))
             if n.op_type == OT.OP_INC_MULTIHEAD_ATTENTION:
                 p = n.params
                 return 2 * self._kv_itemsize * self.spec.slots \

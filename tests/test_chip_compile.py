@@ -236,6 +236,59 @@ def _paged_attention_layer(front, max_seq, block_size, blocks, slots):
     return p, op, layer
 
 
+@pytest.mark.parametrize("rows", [16, 16 + 128, 16 + 256])
+def test_grouped_matmul_at_keye_vl2_widths(tpu, rows):
+    """All 128 experts, 768 wide, at 8 assignments a row, 16 slots alone
+    and with a question's chunk riding as rows: tiles of 768 divide 768
+    (six 128s) and the Pallas grouped matmul serves every step shape."""
+    from flexflow_tpu.kernels import grouped_matmul as gm
+
+    s = _on(tpu[0])
+    for k, n_out in ((2048, 768), (768, 2048)):
+        kernels = _kernels(gm.grouped_matmul, s((rows * 8, k)),
+                           s((128, k, n_out)), s((128,), jnp.int32))
+        assert kernels == {"gmm": 1}
+
+
+@pytest.mark.parametrize("rows", [16, 16 + 256])
+def test_selected_grouped_attention_at_keye_vl2_widths(tpu, rows):
+    """A layer of `keye2-serve-mediaqa` as the decode graph runs it: 32
+    query heads over 4 KV heads of 128 with per-head QK-norm and RoPE, the
+    indexer (16 heads of 64) over a pool of 1,440 blocks of 256 rows,
+    [k ; v] 1,024 wide beside an indexer key of 64, page tables 131 wide;
+    16 decoding rows that take 2,048 and gather them, and the same with a
+    chunk of 256 under one page-table row (its selection a mask by
+    bisection, its attention dense over the shared context). XLA's
+    gather, matmul and sort: no Pallas kernel yet, and what the layer
+    needs beside its arguments stays under 2 GB."""
+    from flexflow_tpu.ops.attention import AttentionFrontEnd, Indexer
+
+    s = _on(tpu[0])
+    front = AttentionFrontEnd(
+        2048, 32, use_bias=False, rope_theta=1e7, qk_norm="head",
+        qk_norm_eps=1e-6, num_kv_heads=4, head_size=128,
+        index=Indexer(n_heads=16, head_dim=64, topk=2048, rope_dim=64))
+    p, op, layer = _paged_attention_layer(front, 33536, 256, 1440, slots=16)
+    assert p.selected == 2048
+    # the indexer's key of 64 lies in a lane-aligned row of 128
+    assert p.cache_row_widths == {"pool_kv": 1024, "pool_i": 128}
+    specs = op.weights(p, [(rows, 1, 2048), (rows, 1), (rows, 131)])
+    weights = {w.name: s(w.shape, jnp.int32 if w.name == "sel_rows"
+                         else jnp.bfloat16) for w in specs}
+    # the pools are donated, as the engine's step donates its state
+    compiled = jax.jit(layer, donate_argnums=(0,)).lower(
+        weights, s((rows, 1, 2048)), s((rows, 1), jnp.int32),
+        s((rows, 131), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert not pallas_kernels(text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+    # no step copies a pool (a row of 64 lanes made every step copy the
+    # indexer pool into the row layout and back)
+    import re
+
+    assert not re.findall(r"= bf16\[1440,256,\d+\]\S* copy\(", text)
+
+
 @pytest.mark.parametrize("chunk", [128, 16])
 def test_paged_chunk_kernel_at_c13b_widths(tpu, chunk):
     """`c13b-serve-chat`'s chunk step as the decode graph runs a layer of
