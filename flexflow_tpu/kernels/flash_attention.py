@@ -2,30 +2,45 @@
 
 Replaces the reference's cuDNN `cudnnMultiHeadAttnForward` call
 (src/ops/attention.cu:35) as the fast attention path. Design follows the
-standard flash-attention blocking for TPU: grid over (batch*heads, q-blocks,
-kv-blocks) with the kv axis innermost and sequential ("arbitrary"), a
-(block_q, block_k) logits tile living in VMEM, and online-softmax running
-max/denominator carried in VMEM scratch across kv steps. The MXU sees two
-large matmuls per tile; HBM traffic is O(s*d) instead of the O(s^2)
-materialized-probabilities tensor XLA would allocate at long sequence.
+standard flash-attention blocking for TPU: a (block_q, block_k) logits tile
+living in VMEM, an online softmax whose running max / denominator /
+accumulator never leave the chip. The MXU sees two large matmuls per tile;
+HBM traffic is O(s*d) instead of the O(s^2) materialized-probabilities
+tensor XLA would allocate at long sequence.
 
-At short head_dim the kernel is VPU-bound (exp/mask/select passes over the
-(block_q, block_k) tile dominate the two small MXU matmuls), so the tile
-body is specialized three ways to do the minimum vector work:
-  - dead tiles (strictly above the causal diagonal) are skipped entirely —
+The forward's grid is (batch, head groups, q blocks), all parallel: a head
+group's K and V enter as ONE block of the whole sequence (fetched once a
+(batch, group), resident in VMEM across its q blocks) and the body sweeps
+the q block's kv blocks in a loop that stops at the causal diagonal, the
+statistics and the accumulator updated in place in VMEM scratch. The gate
+reads bytes: K and V of a group, double-buffered, must fit
+`_FWD_RESIDENT_VMEM` (a sequence of 8,192 at a 128-lane group in bf16); a
+longer sequence streams kv blocks through a fourth, sequential grid axis
+(`flash_attention_fwd_streamed*`). One tile serves both, both head
+families and the head-transposed layout.
+
+A (512, 512) tile cannot take less than its two matmuls' 2 x 512 LHS rows
+through the MXUs, and the forward's tile now takes little more (the
+compiler's schedule: scripts/kernel_bundles.py); it got there by keeping
+the vector work, and above all the spills of the 256-vreg score tile,
+under that:
+  - dead tiles (strictly above the causal diagonal) are never visited —
     with block < seq this halves the softmax work for causal attention;
-  - interior tiles (strictly below the diagonal, no key tail) run with no
-    iota/compare/select at all;
-  - only diagonal / ragged-tail tiles pay for mask construction, and the
-    masks that are statically all-true (seq divisible by block) are never
-    built.
-When the kv axis fits one block, the online-softmax scratch, init and
-rescale passes are statically elided (one-pass softmax).
+  - masks that are statically all-true (no causal mask, seq divisible by
+    block) are never built; the backward's interior tiles (strictly below
+    the diagonal, no key tail) skip iota/compare/select too, the forward
+    builds the causal mask on every tile it visits (idle VPU slots of an
+    MXU-bound tile: one body, not two);
+  - the row statistics stay lane-replicated (block_q, 128) columns, the
+    layout the tile's passes consume; the row sum is kept as 128
+    lane-partial sums and reduced across lanes once a q block.
+A sweep's first kv block has nothing to rescale (one-pass softmax where
+the kv axis fits one block).
 
 Backward is the FlashAttention-2 scheme: the forward saves per-row
 logsumexp; `delta = rowsum(dO*O)` is a cheap XLA elementwise precompute; a
-live tile's probabilities are rebuilt from (q, k, lse) with the same
-three-way tile specialization. On the packed training layout ONE kernel
+live tile's probabilities are rebuilt from (q, k, lse), dead tiles
+skipped, interior tiles unmasked, diagonal / ragged-tail tiles masked. On the packed training layout ONE kernel
 gives dq, dk and dv: grid (batch, head groups, kv blocks, q blocks), the
 tile and `ds` built once, dk/dv accumulated per kv block and dq into a
 float32 scratch that holds the head group's dq for the whole sequence.
@@ -134,163 +149,19 @@ def _tile_mask(i, j, *, causal, block_q, block_k, seq_k, causal_offset,
     return mask
 
 
-def _flash_kernel(
-    q_ref, k_ref, v_ref, o_ref, *refs,
-    scale: float, causal: bool, block_q: int, block_k: int, seq_k: int,
-    causal_offset: int, save_lse: bool, nj: int,
-    i_dim: int = 1, j_dim: int = 2,
-):
-    even_k = seq_k % block_k == 0
-    single_kv = nj == 1
-    if save_lse:
-        lse_ref = refs[0]
-        refs = refs[1:]
-    else:
-        lse_ref = None
-    if not single_kv:
-        m_ref, l_ref, acc_ref = refs
-    i = pl.program_id(i_dim)
-    j = pl.program_id(j_dim)
-
-    def step(masked: bool):
-        q = q_ref[0]  # (block_q, d)
-        k = k_ref[0]  # (block_k, d)
-        v = v_ref[0]
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # (block_q, block_k)
-        if masked:
-            mask = _tile_mask(
-                i, j, causal=causal, block_q=block_q, block_k=block_k,
-                seq_k=seq_k, causal_offset=causal_offset, even_k=even_k,
-            )
-            logits = jnp.where(mask, logits, NEG_INF)
-            # Masked logits underflow to p == 0 exactly, so no second
-            # probability mask is needed. A row with zero live keys (only
-            # possible when causal and s_q > s_k) gets uniform p — the same
-            # value sdpa_xla's softmax-of-constant-row produces, so the two
-            # impls agree on that degenerate case.
-            if not even_k:
-                # zero padded V rows: OOB block rows hold garbage (NaN in
-                # interpret mode) and 0·NaN would poison the contraction.
-                v_valid = jax.lax.broadcasted_iota(
-                    jnp.int32, v.shape, 0
-                ) + j * block_k < seq_k
-                v = jnp.where(v_valid, v, 0.0)
-
-        if single_kv:
-            # one-pass softmax: no scratch, no init/rescale passes
-            m = logits.max(axis=-1)
-            p = jnp.exp(logits - m[:, None])
-            l = p.sum(axis=-1)
-            acc = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            o_ref[0] = (acc / l[:, None]).astype(o_ref.dtype)
-            if save_lse:
-                lse = m + jnp.log(l)
-                lse_ref[0] = jnp.broadcast_to(lse[:, None], lse_ref[0].shape)
-        else:
-            m_prev = m_ref[...]
-            m_new = jnp.maximum(m_prev, logits.max(axis=-1))
-            p = jnp.exp(logits - m_new[:, None])
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-            acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            m_ref[...] = m_new
-
-    if single_kv:
-        # masked-ness is static: exactly one body is compiled
-        masked = causal or not even_k
-        step(masked)
-        return
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    live, needs_mask = _tile_classes(
-        i, j, causal=causal, block_q=block_q, block_k=block_k,
-        causal_offset=causal_offset,
-        even_k=seq_k % block_k == 0, nj=nj,
-    )
-    if causal or seq_k % block_k != 0:
-        live_masked = jnp.logical_and(live, needs_mask)
-        live_clear = jnp.logical_and(live, jnp.logical_not(needs_mask))
-        pl.when(live_masked)(lambda: step(True))
-        pl.when(live_clear)(lambda: step(False))
-    else:
-        step(False)
-
-    @pl.when(j == nj - 1)
-    def _finish():
-        o_ref[0] = (acc_ref[...] / l_ref[...][:, None]).astype(o_ref.dtype)
-        if save_lse:
-            # row stats carry a minor dim of LSE_LANES so the block is
-            # tile-legal on TPU (same trick as jax's in-tree flash kernel)
-            lse = m_ref[...] + jnp.log(l_ref[...])
-            lse_ref[0] = jnp.broadcast_to(lse[:, None], lse_ref[0].shape)
-
-
 def _flash_fwd(q, k, v, causal: bool, scale: float,
                block_q: int, block_k: int, save_lse: bool = True):
-    """save_lse=False (the primal / inference path) skips computing and
+    """The head-transposed forward (ring attention's block call, cross
+    attention): (b·h, s, d) is the packed layout of b·h one-head rows, so
+    the packed forward serves it: same body, same gate.
+    save_lse=False (the primal / inference path) skips computing and
     writing the logsumexp residual entirely."""
     b, h, s_q, d = q.shape
     s_k = k.shape[2]
-    bq = min(block_q, s_q)
-    bk = min(block_k, s_k)
-    qf = q.reshape(b * h, s_q, d)
-    kf = k.reshape(b * h, s_k, d)
-    vf = v.reshape(b * h, s_k, d)
-    nj = pl.cdiv(s_k, bk)
-    grid = (b * h, pl.cdiv(s_q, bq), nj)
-    kernel = functools.partial(
-        _flash_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-        seq_k=s_k, causal_offset=s_k - s_q, save_lse=save_lse, nj=nj,
-    )
-    out_specs = [pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0))]
-    out_shape = [jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype)]
-    if save_lse:
-        out_specs.append(
-            pl.BlockSpec((1, bq, LSE_LANES), lambda bh, i, j: (bh, i, 0)))
-        out_shape.append(
-            jax.ShapeDtypeStruct((b * h, s_q, LSE_LANES), jnp.float32))
-    scratch_shapes = []
-    if nj > 1:
-        scratch_shapes = [
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
-        ]
-    res = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch_shapes,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=jax.default_backend() != "tpu",
-        name="flash_attention_fwd",
-    )(qf, kf, vf)
-    if save_lse:
-        out, lse = res
-    else:
-        (out,), lse = res, None
+    out, lse = _flash_fwd_packed(
+        q.reshape(b * h, s_q, d), k.reshape(b * h, s_k, d),
+        v.reshape(b * h, s_k, d), 1, causal, scale, block_q, block_k,
+        save_lse, family="")
     return out.reshape(b, h, s_q, d), lse
 
 
@@ -694,10 +565,9 @@ def flash_attention_with_lse(
 # layout. Heads are selected by BlockSpec lane-offset index maps — block
 # index h on the last (h·dh)-wide dim — so NO head transpose/relayout ever
 # touches HBM (PERF.md measured the (b,s,h,d)→(b,h,s,d) copies at ~0.8 ms
-# per flagship step). The forward's kernel body is shared with the bhsd
-# path; only the grids ((b, h, qi, kj)) and index maps differ. The backward
-# runs the head-group bodies for both head families (hpb = 1 at head_dim
-# 128).
+# per flagship step). Forward and backward run the head-group bodies for
+# both head families (hpb = 1 at head_dim 128); the bhsd path hands the
+# forward its (b·h, s, d) arrays as b·h one-head rows of this layout.
 #
 # NARROW HEADS (head_dim < 128): Mosaic requires a lane block be a multiple
 # of 128 lanes (or the full array width), so a single head_dim-64 head
@@ -723,79 +593,174 @@ def _packed_heads_per_block(head_dim: int, num_heads: int) -> int:
     return num_heads
 
 
-def _flash_kernel_grouped(
+def _fold_lanes(x, op, reduce):
+    """(rows, n) -> (rows, 128): `op` element-wise over x's 128-lane column
+    chunks, VPU work with no cross-lane traffic. A width that is no
+    multiple of 128 lanes (a short or ragged sequence's one block) takes
+    the full `reduce` to one column instead."""
+    n = x.shape[1]
+    if n % LSE_LANES:
+        return reduce(x, axis=-1, keepdims=True)
+    out = x[:, :LSE_LANES]
+    for c in range(1, n // LSE_LANES):
+        out = op(out, x[:, c * LSE_LANES:(c + 1) * LSE_LANES])
+    return out
+
+
+def _across(x, n: int):
+    """A lane-replicated (rows, 1 | 128) row statistic at width n."""
+    rows, sw = x.shape
+    if n <= sw:
+        return x[:, :n]
+    if sw > 1 and n % sw == 0:
+        return jnp.tile(x, (1, n // sw))
+    return jnp.broadcast_to(x[:, :1], (rows, n))
+
+
+def _fwd_tile(q, k, v, carry, mask, v_valid, scale: float):
+    """One (block_q, block_k) tile of the online softmax. The statistics
+    stay in the layout the tile's passes consume: `m` a lane-replicated
+    (block_q, 128) column, `l` 128 lane-PARTIAL row sums (the tile's
+    column chunks added on the VPU, scaled by the same alpha; `_fwd_store`
+    reduces them across lanes once a q block). The row max, which the
+    tile's exp needs at once, is the one cross-lane reduce a tile, taken
+    after an element-wise max over the column chunks. `carry` None: a
+    sweep's first kv block, nothing to rescale."""
+    logits = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale  # (block_q, block_k)
+    if mask is not None:
+        # Masked logits underflow to p == 0 exactly, so no second
+        # probability mask is needed (the shape gate keeps causal
+        # s_q <= s_k: every row has a live key in its first block).
+        logits = jnp.where(mask, logits, NEG_INF)
+    if v_valid is not None:
+        # zero padded V rows: OOB block rows hold garbage (NaN in
+        # interpret mode) and 0·NaN would poison the contraction.
+        v = jnp.where(v_valid, v, 0.0)
+    m_new = _fold_lanes(logits, jnp.maximum, jnp.max).max(
+        axis=-1, keepdims=True)
+    if carry is not None:
+        m_prev, l_prev, acc_prev = carry
+        m_new = jnp.maximum(m_prev, m_new)
+    p = jnp.exp(logits - _across(m_new, logits.shape[1]))
+    l = _fold_lanes(p, jnp.add, jnp.sum)
+    acc = jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    if carry is None:
+        m_new = jnp.broadcast_to(m_new, l.shape)
+    else:
+        alpha = jnp.exp(m_prev - m_new)
+        l = l_prev * alpha + l
+        acc = acc_prev * _across(alpha, acc.shape[1]) + acc
+    return m_new, l, acc
+
+
+def _head_slices(hpb: int, head_dim: int):
+    """(head, its lanes of the group's stripe) for the static head loop."""
+    return [(hh, slice(hh * head_dim, (hh + 1) * head_dim))
+            for hh in range(hpb)]
+
+
+def _fwd_masks(i, j, *, causal, block_q, block_k, seq_k, causal_offset,
+               head_dim):
+    """(score mask, V-row validity) of a diagonal / ragged-tail tile."""
+    even_k = seq_k % block_k == 0
+    mask = _tile_mask(
+        i, j, causal=causal, block_q=block_q, block_k=block_k,
+        seq_k=seq_k, causal_offset=causal_offset, even_k=even_k,
+    )
+    v_valid = None
+    if not even_k:
+        v_valid = jax.lax.broadcasted_iota(
+            jnp.int32, (block_k, head_dim), 0
+        ) + j * block_k < seq_k
+    return mask, v_valid
+
+
+def _fwd_store(o_ref, lse_ref, hh: int, sl: slice, carry):
+    """A head's finished q block: the lane-partial sums meet here."""
+    m, l, acc = carry
+    l = l.sum(axis=-1, keepdims=True)
+    o_ref[0, :, sl] = (acc / l).astype(o_ref.dtype)
+    if lse_ref is not None:
+        # row stats carry a minor dim of LSE_LANES so the block is
+        # tile-legal on TPU (same trick as jax's in-tree flash kernel)
+        lse_ref[hh] = jnp.broadcast_to(m[:, :1] + jnp.log(l),
+                                       lse_ref.shape[1:])
+
+
+def _flash_fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, *refs,
     scale: float, causal: bool, block_q: int, block_k: int, seq_k: int,
-    causal_offset: int, save_lse: bool, nj: int, hpb: int, head_dim: int,
+    causal_offset: int, nj: int, hpb: int, head_dim: int,
 ):
-    """Forward tile for a HEAD GROUP: same online-softmax math as
-    _flash_kernel, looped statically over the hpb heads of the block's
-    lane stripe. Row stats live per head ((hpb, bq) scratch); the
-    accumulator shares the block's (bq, hpb·dh) lane layout."""
-    even_k = seq_k % block_k == 0
-    single_kv = nj == 1
-    if save_lse:
-        lse_ref = refs[0]
-        refs = refs[1:]
-    else:
-        lse_ref = None
-    if not single_kv:
-        m_ref, l_ref, acc_ref = refs
+    """Forward of one q block of a HEAD GROUP against the group's whole
+    sequence of K and V, resident in VMEM: grid (b, groups, q blocks), the
+    sweep over kv blocks a loop in here that ends at the last block on or
+    below the causal diagonal; a block above it is never visited. The
+    first block writes m, l and the accumulator (VMEM scratch: nothing to
+    initialise, no rescale), the loop's blocks update them in place: as a
+    loop's carry 192 vregs of them were copied through memory at each end
+    of every loop. One body serves interior and diagonal blocks: the tile
+    is bound by the MXU's two passes of 512 rows, and the mask's iota /
+    compare / select ride in VPU slots that would idle (the compiler's
+    own schedule for the v5e: 1,080 bundles a (512, 512) tile masked or
+    not). The hpb heads of the lane stripe (1 at head_dim 128) sweep one
+    after the other."""
+    if nj > 1:
+        *refs, m_ref, l_ref, acc_ref = refs
+    lse_ref = refs[0] if refs else None
+    i = pl.program_id(2)
+    masked = causal or seq_k % block_k != 0
+    n_live = nj
+    if causal:
+        last_row = i * block_q + block_q - 1 + causal_offset
+        n_live = jnp.minimum(last_row // block_k + 1, nj)
+    geometry = dict(causal=causal, block_q=block_q, block_k=block_k,
+                    seq_k=seq_k, causal_offset=causal_offset,
+                    head_dim=head_dim)
+
+    for hh, sl in _head_slices(hpb, head_dim):
+
+        def tile(j, carry, sl=sl):
+            rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+            mask, v_valid = (_fwd_masks(i, j, **geometry) if masked
+                             else (None, None))
+            return _fwd_tile(q_ref[0, :, sl], k_ref[0, rows, sl],
+                             v_ref[0, rows, sl], carry, mask, v_valid, scale)
+
+        def step(j, _):
+            m_ref[...], l_ref[...], acc_ref[...] = tile(
+                j, (m_ref[...], l_ref[...], acc_ref[...]))
+            return _
+
+        carry = tile(0, None)
+        if nj > 1:
+            m_ref[...], l_ref[...], acc_ref[...] = carry
+            jax.lax.fori_loop(1, n_live, step, None)
+            carry = m_ref[...], l_ref[...], acc_ref[...]
+        _fwd_store(o_ref, lse_ref, hh, sl, carry)
+
+
+def _flash_fwd_streamed_kernel(
+    q_ref, k_ref, v_ref, o_ref, *refs,
+    scale: float, causal: bool, block_q: int, block_k: int, seq_k: int,
+    causal_offset: int, nj: int, hpb: int, head_dim: int,
+):
+    """The same tile where a head group's K and V do not fit VMEM: the kv
+    axis is the grid's last, the pipeline streams one kv block a step
+    (those above the diagonal too, their body skipped), and every head's
+    m, l and accumulator cross the steps in scratch ((hpb, block_q, 128)
+    statistics, a (block_q, hpb·dh) accumulator)."""
+    *lse_refs, m_ref, l_ref, acc_ref = refs
+    lse_ref = lse_refs[0] if lse_refs else None
     i = pl.program_id(2)
     j = pl.program_id(3)
-
-    def step(masked: bool):
-        mask = v_valid = None
-        if masked:
-            mask = _tile_mask(
-                i, j, causal=causal, block_q=block_q, block_k=block_k,
-                seq_k=seq_k, causal_offset=causal_offset, even_k=even_k,
-            )
-            if not even_k:
-                v_valid = jax.lax.broadcasted_iota(
-                    jnp.int32, (block_k, head_dim), 0
-                ) + j * block_k < seq_k
-        for hh in range(hpb):
-            sl = slice(hh * head_dim, (hh + 1) * head_dim)
-            q = q_ref[0][:, sl]
-            k = k_ref[0][:, sl]
-            v = v_ref[0][:, sl]
-            logits = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale
-            if masked:
-                logits = jnp.where(mask, logits, NEG_INF)
-                if not even_k:
-                    v = jnp.where(v_valid, v, 0.0)
-            if single_kv:
-                m = logits.max(axis=-1)
-                p = jnp.exp(logits - m[:, None])
-                l = p.sum(axis=-1)
-                acc = jax.lax.dot_general(
-                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                o_ref[0, :, sl] = (acc / l[:, None]).astype(o_ref.dtype)
-                if save_lse:
-                    lse_ref[hh] = jnp.broadcast_to(
-                        (m + jnp.log(l))[:, None], lse_ref.shape[1:])
-            else:
-                m_prev = m_ref[hh]
-                m_new = jnp.maximum(m_prev, logits.max(axis=-1))
-                p = jnp.exp(logits - m_new[:, None])
-                alpha = jnp.exp(m_prev - m_new)
-                l_ref[hh] = l_ref[hh] * alpha + p.sum(axis=-1)
-                acc_ref[:, sl] = (acc_ref[:, sl] * alpha[:, None]
-                                  + jax.lax.dot_general(
-                                      p.astype(v.dtype), v,
-                                      (((1,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32))
-                m_ref[hh] = m_new
-
-    if single_kv:
-        step(causal or not even_k)
-        return
+    masked = causal or seq_k % block_k != 0
 
     @pl.when(j == 0)
     def _init():
@@ -803,27 +768,27 @@ def _flash_kernel_grouped(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    live, needs_mask = _tile_classes(
-        i, j, causal=causal, block_q=block_q, block_k=block_k,
-        causal_offset=causal_offset, even_k=even_k, nj=nj,
-    )
-    if causal or not even_k:
-        pl.when(jnp.logical_and(live, needs_mask))(lambda: step(True))
-        pl.when(jnp.logical_and(live, jnp.logical_not(needs_mask)))(
-            lambda: step(False))
+    def step():
+        mask, v_valid = (_fwd_masks(
+            i, j, causal=causal, block_q=block_q, block_k=block_k,
+            seq_k=seq_k, causal_offset=causal_offset, head_dim=head_dim)
+            if masked else (None, None))
+        for hh, sl in _head_slices(hpb, head_dim):
+            m_ref[hh], l_ref[hh], acc_ref[:, sl] = _fwd_tile(
+                q_ref[0, :, sl], k_ref[0, :, sl], v_ref[0, :, sl],
+                (m_ref[hh], l_ref[hh], acc_ref[:, sl]), mask, v_valid, scale)
+
+    if causal:
+        pl.when(j * block_k <= i * block_q + block_q - 1 + causal_offset)(
+            step)
     else:
-        step(False)
+        step()
 
     @pl.when(j == nj - 1)
     def _finish():
-        for hh in range(hpb):
-            sl = slice(hh * head_dim, (hh + 1) * head_dim)
-            o_ref[0, :, sl] = (acc_ref[:, sl]
-                               / l_ref[hh][:, None]).astype(o_ref.dtype)
-            if save_lse:
-                lse_ref[hh] = jnp.broadcast_to(
-                    (m_ref[hh] + jnp.log(l_ref[hh]))[:, None],
-                    lse_ref.shape[1:])
+        for hh, sl in _head_slices(hpb, head_dim):
+            _fwd_store(o_ref, lse_ref, hh, sl,
+                       (m_ref[hh], l_ref[hh], acc_ref[:, sl]))
 
 
 def _bwd_dq_kernel_grouped(
@@ -999,45 +964,76 @@ def _bwd_fused_kernel_grouped(
         dq_ref[0] = dq_acc[:seq_q].astype(dq_ref.dtype)
 
 
-def _flash_fwd_packed_grouped(q, k, v, num_heads, causal, scale,
-                              block_q, block_k, hpb, save_lse=True):
-    """Narrow-head forward: head-GROUP lane blocks (hpb heads per block,
-    width hpb·d = 128-multiple or full array width) over the 3-D packed
-    array, grid (b, head-groups, q-blocks, kv-blocks)."""
+# What the forward may keep resident: a head group's K and V for the whole
+# sequence, each counted twice (the pipeline buffers the next group's while
+# this one's q blocks run). Beside them a grid step holds 5-6 MiB of the
+# 16 MiB a kernel gets: the (512, 512) float32 tile temporaries, the q, o
+# and lse blocks, one head's statistics. 1 KiB a row of a 128-lane group in bf16
+# with both operands double-buffered is a sequence of 8,192; a longer one
+# streams kv blocks through the grid.
+_FWD_RESIDENT_VMEM = 8 << 20
+
+
+def _flash_fwd_packed(q, k, v, num_heads, causal, scale,
+                      block_q, block_k, save_lse=True, family=None):
+    """Packed forward for both head families (heads of 128 lanes are the
+    `hpb == 1` case of the head-group body: hpb heads per lane block, width
+    hpb·d a multiple of 128 or the full array width). Grid (b, head
+    groups, q blocks), all parallel: K and V enter as ONE block of the
+    group's whole sequence, its index map constant in the q-block axis, so
+    they are fetched once a (batch, group) and the body sweeps a q block's
+    kv blocks up to the causal diagonal itself. The gate reads bytes: that
+    block, for K and for V, double-buffered, must fit `_FWD_RESIDENT_VMEM`;
+    a longer sequence takes the streamed kernel (grid (b, groups, q
+    blocks, kv blocks), the last sequential), under a name of its own."""
     b, s_q, e = q.shape
     s_k = k.shape[1]
     h = num_heads
     d = e // h
+    hpb = _packed_heads_per_block(d, h)
     ng = h // hpb
+    w = hpb * d
     bq = min(block_q, s_q)
     bk = min(block_k, s_k)
     nj = pl.cdiv(s_k, bk)
-    grid = (b, ng, pl.cdiv(s_q, bq), nj)
+    if family is None:
+        family = "_packed_grouped" if hpb > 1 else "_packed"
+    resident = 4 * nj * bk * w * k.dtype.itemsize <= _FWD_RESIDENT_VMEM
     kernel = functools.partial(
-        _flash_kernel_grouped, scale=scale, causal=causal, block_q=bq,
-        block_k=bk, seq_k=s_k, causal_offset=s_k - s_q, save_lse=save_lse,
-        nj=nj, hpb=hpb, head_dim=d,
+        _flash_fwd_kernel if resident else _flash_fwd_streamed_kernel,
+        scale=scale, causal=causal, block_q=bq, block_k=bk, seq_k=s_k,
+        causal_offset=s_k - s_q, nj=nj, hpb=hpb, head_dim=d,
     )
-    w = hpb * d
-    qspec = pl.BlockSpec((1, bq, w), lambda bi, gi, i, j: (bi, i, gi))
-    kspec = pl.BlockSpec((1, bk, w), lambda bi, gi, i, j: (bi, j, gi))
+    stat_lanes = LSE_LANES if bk % LSE_LANES == 0 else 1
+    if resident:
+        # a ragged tail's rows past s_k are block padding, masked like the
+        # streamed kernel's last block; one head sweeps at a time, so one
+        # head's statistics and accumulator are the scratch
+        grid = (b, ng, pl.cdiv(s_q, bq))
+        kspec = pl.BlockSpec((1, nj * bk, w), lambda bi, gi, i: (bi, 0, gi))
+        scratch_shapes = [
+            pltpu.VMEM((bq, stat_lanes), jnp.float32),
+            pltpu.VMEM((bq, stat_lanes), jnp.float32),
+            pltpu.VMEM((bq, d), jnp.float32),
+        ] if nj > 1 else []
+    else:
+        grid = (b, ng, pl.cdiv(s_q, bq), nj)
+        kspec = pl.BlockSpec((1, bk, w), lambda bi, gi, i, j: (bi, j, gi))
+        scratch_shapes = [
+            pltpu.VMEM((hpb, bq, stat_lanes), jnp.float32),
+            pltpu.VMEM((hpb, bq, stat_lanes), jnp.float32),
+            pltpu.VMEM((bq, w), jnp.float32),
+        ]
+    qspec = pl.BlockSpec((1, bq, w), lambda bi, gi, i, *_: (bi, i, gi))
     out_specs = [qspec]
     out_shape = [jax.ShapeDtypeStruct((b, s_q, e), q.dtype)]
     if save_lse:
         # per-head row stats in the (b·h, s, LANES) layout; the group's
         # hpb consecutive head rows form one block
         out_specs.append(pl.BlockSpec(
-            (hpb, bq, LSE_LANES),
-            lambda bi, gi, i, j: (bi * ng + gi, i, 0)))
+            (hpb, bq, LSE_LANES), lambda bi, gi, i, *_: (bi * ng + gi, i, 0)))
         out_shape.append(
             jax.ShapeDtypeStruct((b * h, s_q, LSE_LANES), jnp.float32))
-    scratch_shapes = []
-    if nj > 1:
-        scratch_shapes = [
-            pltpu.VMEM((hpb, bq), jnp.float32),
-            pltpu.VMEM((hpb, bq), jnp.float32),
-            pltpu.VMEM((bq, w), jnp.float32),
-        ]
     res = pl.pallas_call(
         kernel,
         grid=grid,
@@ -1046,67 +1042,12 @@ def _flash_fwd_packed_grouped(q, k, v, num_heads, causal, scale,
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
+            dimension_semantics=("parallel",) * 3
+            + (() if resident else ("arbitrary",)),
         ),
         interpret=jax.default_backend() != "tpu",
-        name="flash_attention_fwd_packed_grouped",
-    )(q, k, v)
-    if save_lse:
-        return res[0], res[1]
-    return res[0], None
-
-
-def _flash_fwd_packed(q, k, v, num_heads, causal, scale,
-                      block_q, block_k, save_lse=True):
-    b, s_q, e = q.shape
-    s_k = k.shape[1]
-    h = num_heads
-    d = e // h
-    hpb = _packed_heads_per_block(d, h)
-    if hpb > 1:
-        return _flash_fwd_packed_grouped(q, k, v, num_heads, causal, scale,
-                                         block_q, block_k, hpb, save_lse)
-    bq = min(block_q, s_q)
-    bk = min(block_k, s_k)
-    nj = pl.cdiv(s_k, bk)
-    grid = (b, h, pl.cdiv(s_q, bq), nj)
-    kernel = functools.partial(
-        _flash_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-        seq_k=s_k, causal_offset=s_k - s_q, save_lse=save_lse, nj=nj,
-        i_dim=2, j_dim=3,
-    )
-    qspec = pl.BlockSpec((1, bq, d), lambda bi, hi, i, j: (bi, i, hi))
-    kspec = pl.BlockSpec((1, bk, d), lambda bi, hi, i, j: (bi, j, hi))
-    out_specs = [qspec]
-    out_shape = [jax.ShapeDtypeStruct((b, s_q, e), q.dtype)]
-    if save_lse:
-        # row stats stay in the (b·h, s, LANES) layout the shared kernel
-        # bodies index; the flat block row is computed from (bi, hi)
-        out_specs.append(pl.BlockSpec(
-            (1, bq, LSE_LANES), lambda bi, hi, i, j: (bi * h + hi, i, 0)))
-        out_shape.append(
-            jax.ShapeDtypeStruct((b * h, s_q, LSE_LANES), jnp.float32))
-    scratch_shapes = []
-    if nj > 1:
-        scratch_shapes = [
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
-        ]
-    res = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[qspec, kspec, kspec],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch_shapes,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-        ),
-        interpret=jax.default_backend() != "tpu",
-        name="flash_attention_fwd_packed",
+        name="flash_attention_fwd" + ("" if resident else "_streamed")
+        + family,
     )(q, k, v)
     if save_lse:
         return res[0], res[1]

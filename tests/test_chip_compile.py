@@ -17,6 +17,7 @@ be written to it but not read back without a chip.
 
 import importlib
 import os
+import re
 import subprocess
 import sys
 
@@ -78,30 +79,56 @@ def _with_grads(fn):
     return run
 
 
-@pytest.mark.parametrize("batch,seq,heads,bwd", [
-    (8, 512, 16, ["bwd_packed_grouped"]),
-    (1, 4096, 16, ["bwd_packed_grouped"]),
+@pytest.mark.parametrize("batch,seq,heads,fwd,bwd", [
+    (8, 512, 16, "fwd", ["bwd_packed_grouped"]),
+    (1, 4096, 16, "fwd", ["bwd_packed_grouped"]),
     # the training cells' shapes: gpt2-medium's 16 heads of 64 over 1,024
     # tokens; heads of 128 over c13b's 2,048 and OLMoE's 4,096
-    (8, 1024, 16, ["bwd_packed_grouped"]),
-    (1, 2048, 8, ["bwd_packed"]),
-    (4, 4096, 8, ["bwd_packed"]),
-    # past the fused backward's VMEM gate (8 + 2 x 4 MiB of dq a head
-    # pair): the dq and the dkv kernel
-    (1, 16384, 16, ["bwd_dq_packed_grouped", "bwd_dkv_packed_grouped"]),
-    (1, 16384, 8, ["bwd_dq_packed", "bwd_dkv_packed"]),
+    (8, 1024, 16, "fwd", ["bwd_packed_grouped"]),
+    (1, 2048, 8, "fwd", ["bwd_packed"]),
+    (4, 4096, 8, "fwd", ["bwd_packed"]),
+    # the last sequence whose K and V stay resident in the forward (4 x
+    # 2 MiB a 128-lane group) and whose dq does in the backward
+    (1, 8192, 16, "fwd", ["bwd_packed_grouped"]),
+    (1, 8192, 8, "fwd", ["bwd_packed"]),
+    # past both VMEM gates: kv blocks streamed through the forward's grid,
+    # the dq and the dkv kernel (8 + 2 x 4 MiB of dq a head pair)
+    (1, 16384, 16, "fwd_streamed",
+     ["bwd_dq_packed_grouped", "bwd_dkv_packed_grouped"]),
+    (1, 16384, 8, "fwd_streamed", ["bwd_dq_packed", "bwd_dkv_packed"]),
 ])
-def test_packed_flash_fwd_bwd(tpu, batch, seq, heads, bwd):
+def test_packed_flash_fwd_bwd(tpu, batch, seq, heads, fwd, bwd):
     """Attention 1,024 wide on the packed (b, s, h·d) layout, 16 heads of
-    64 (lm-base, gpt2-medium) or 8 of 128: the forward and the ONE backward
-    kernel, or the split pair where a head group's dq does not fit."""
+    64 (lm-base, gpt2-medium) or 8 of 128: the forward that keeps a head
+    group's K and V resident and the ONE backward kernel, or the streamed
+    forward and the split pair where the sequence does not fit."""
     s = _on(tpu[0])
     x = s((batch, seq, 1024))
-    kernels = _kernels(_with_grads(
-        lambda q, k, v: fa.flash_attention_packed(
-            q, k, v, num_heads=heads, causal=True)), x, x, x)
-    fwd = "fwd_packed_grouped" if heads == 16 else "fwd_packed"
-    assert kernels == {f"flash_attention_{name}": 1 for name in [fwd] + bwd}
+
+    def attend(q, k, v):
+        return fa.flash_attention_packed(q, k, v, num_heads=heads,
+                                         causal=True)
+
+    kernels = _kernels(_with_grads(attend), x, x, x)
+    family = "_packed_grouped" if heads == 16 else "_packed"
+    assert kernels == {f"flash_attention_{name}": 1
+                       for name in [fwd + family] + bwd}
+    # the resident forward's grid has no kv axis and its K / V operand is
+    # the whole sequence of one 128-lane group: a return to a kv block a
+    # grid step under the old name would pass the check above
+    call = re.search(r"grid_mapping=GridMapping\(grid=(\([\d, ]+\)).*?"
+                     r"name=flash_attention_fwd\w*",
+                     str(jax.make_jaxpr(attend)(x, x, x)), re.S).group(0)
+    blocks = [tuple(map(int, re.findall(r"block_size=(\d+)", block)))
+              for block in re.findall(r"BlockMapping\(block_shape=\((.*?)\)\)",
+                                      call)]
+    steps = seq // 512
+    if fwd == "fwd":
+        assert f"grid=({batch}, 8, {steps})" in call
+        assert blocks[:3] == [(1, 512, 128)] + 2 * [(1, seq, 128)]
+    else:
+        assert f"grid=({batch}, 8, {steps}, {steps})" in call
+        assert blocks[:3] == 3 * [(1, 512, 128)]
 
 
 def test_fused_layer_norm_fwd_bwd(tpu):

@@ -245,38 +245,99 @@ def test_fused_layer_norm_gates_to_fallback():
     assert fused_layer_norm_or_none(x2, s2, b2, (1,), 1e-5) is None
 
 
-@pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("s,block", [(256, 128), (128, 128)])
-def test_flash_packed_matches_reference(causal, s, block):
+@pytest.mark.parametrize("h,d,sq,sk,block,causal,resident", [
+    # four heads of 32 a lane block: several kv blocks, and one
+    (4, 32, 256, 256, 128, False, True),
+    (4, 32, 256, 256, 128, True, True),
+    (4, 32, 128, 128, 128, False, True),
+    (4, 32, 128, 128, 128, True, True),
+    # head_dim 64 (two heads a lane block) and 128; under the causal mask
+    # q block 0's sweep ends at the diagonal, before the last kv block
+    (2, 64, 256, 256, 128, True, True),
+    (2, 64, 256, 256, 128, False, True),
+    (1, 128, 384, 384, 128, True, True),
+    (1, 128, 256, 256, 128, False, True),
+    # one kv block, its width a multiple of 128 lanes and not
+    (2, 64, 128, 128, 128, True, True),
+    (1, 128, 128, 128, 128, False, True),
+    (1, 128, 200, 200, 512, True, True),
+    # s_q < s_k: the causal offset (q block 0 sees three of four blocks)
+    (2, 64, 128, 384, 128, True, True),
+    (1, 128, 256, 512, 128, True, True),
+    # a ragged key tail (and a ragged last q block), causal and not
+    (2, 64, 320, 320, 128, True, True),
+    (1, 128, 200, 200, 128, False, True),
+    (1, 128, 192, 320, 128, True, True),
+    # kv blocks of 64: the statistics as single columns, looped
+    (2, 64, 256, 256, 64, True, True),
+    # past the VMEM gate: kv blocks streamed through the grid
+    (2, 64, 256, 256, 128, True, False),
+    (1, 128, 320, 320, 128, True, False),
+    (1, 128, 192, 320, 128, False, False),
+])
+def test_flash_packed_matches_reference(monkeypatch, h, d, sq, sk, block,
+                                        causal, resident):
     """(b, s, h·d) packed layout (head selection via lane-offset index
-    maps): forward must match the transposed-layout reference on both the
-    online-softmax (s > block) and one-pass (s == block) paths."""
-    from flexflow_tpu.kernels.flash_attention import (
-        _attn_reference,
-        flash_attention_packed,
-    )
+    maps): the forward and its `lse` residual against the transposed-layout
+    reference, on the kernel that sweeps a q block's kv blocks itself (K
+    and V resident) and on the one that streams them through the grid."""
+    fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
+    if not resident:
+        monkeypatch.setattr(fa, "_FWD_RESIDENT_VMEM", 0)
 
     rs = np.random.RandomState(0)
-    b, h, d = 2, 4, 32
-    qp = jnp.asarray(rs.randn(b, s, h * d), jnp.float32)
-    kp = jnp.asarray(rs.randn(b, s, h * d), jnp.float32)
-    vp = jnp.asarray(rs.randn(b, s, h * d), jnp.float32)
+    b = 2
+    qp, kp, vp = (jnp.asarray(rs.randn(b, s, h * d), jnp.float32)
+                  for s in (sq, sk, sk))
     scale = 1.0 / np.sqrt(d)
 
     def split(t):
-        return t.reshape(b, s, h, d).transpose(0, 2, 1, 3)
+        return t.reshape(b, t.shape[1], h, d).transpose(0, 2, 1, 3)
 
-    expected = _attn_reference(split(qp), split(kp), split(vp), causal,
-                               scale)
-    expected = expected.transpose(0, 2, 1, 3).reshape(b, s, h * d)
-    got = flash_attention_packed(qp, kp, vp, num_heads=h, causal=causal,
-                                 scale=scale, block_q=block, block_k=block)
+    expected, expected_lse = fa._attn_reference_lse(
+        split(qp), split(kp), split(vp), causal, scale)
+    np.testing.assert_allclose(
+        np.asarray(expected),
+        np.asarray(fa._attn_reference(split(qp), split(kp), split(vp),
+                                      causal, scale)),
+        rtol=2e-5, atol=2e-5)
+    expected = expected.transpose(0, 2, 1, 3).reshape(b, sq, h * d)
+
+    def packed(q, k, v):
+        return fa.flash_attention_packed(
+            q, k, v, num_heads=h, causal=causal, scale=scale,
+            block_q=block, block_k=block)
+
+    names = re.findall(r"name=(flash_attention_fwd\w*)",
+                       str(jax.make_jaxpr(packed)(qp, kp, vp)))
+    assert names == ["flash_attention_fwd"
+                     + ("" if resident else "_streamed")
+                     + ("_packed" if d == 128 else "_packed_grouped")]
+    np.testing.assert_allclose(np.asarray(packed(qp, kp, vp)),
+                               np.asarray(expected), rtol=2e-5, atol=2e-5)
+    got, lse = fa._flash_fwd_packed(qp, kp, vp, h, causal, scale,
+                                    block, block)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
                                rtol=2e-5, atol=2e-5)
+    assert lse.shape == (b * h, sq, fa.LSE_LANES)
+    np.testing.assert_array_equal(np.asarray(lse[:, :, 0]),
+                                  np.asarray(lse[:, :, -1]))
+    np.testing.assert_allclose(
+        np.asarray(lse[:, :, 0]).reshape(b, h, sq),
+        np.asarray(expected_lse), rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("s,block", [(256, 128), (128, 128)])
-def test_flash_packed_grad(s, block):
+@pytest.mark.parametrize("h,d,sq,sk,block", [
+    (2, 16, 256, 256, 128),
+    (2, 16, 128, 128, 128),
+    # the forward's sweep (head_dim 64 and 128, the causal offset, a
+    # ragged key tail) under the unchanged backward
+    (2, 64, 256, 256, 128),
+    (1, 128, 384, 384, 128),
+    (2, 64, 128, 384, 128),
+    (1, 128, 320, 320, 128),
+])
+def test_flash_packed_grad(h, d, sq, sk, block):
     """Packed-layout backward (the fused kernel over one tile and over
     several) against the XLA reference."""
     from flexflow_tpu.kernels.flash_attention import (
@@ -285,10 +346,9 @@ def test_flash_packed_grad(s, block):
     )
 
     rs = np.random.RandomState(1)
-    b, h, d = 1, 2, 16
-    qp = jnp.asarray(rs.randn(b, s, h * d), jnp.float32)
-    kp = jnp.asarray(rs.randn(b, s, h * d), jnp.float32)
-    vp = jnp.asarray(rs.randn(b, s, h * d), jnp.float32)
+    b = 1
+    qp, kp, vp = (jnp.asarray(rs.randn(b, s, h * d), jnp.float32)
+                  for s in (sq, sk, sk))
 
     def f_packed(q, k, v):
         return jnp.sum(flash_attention_packed(
@@ -297,7 +357,7 @@ def test_flash_packed_grad(s, block):
 
     def f_ref(q, k, v):
         def split(t):
-            return t.reshape(b, s, h, d).transpose(0, 2, 1, 3)
+            return t.reshape(b, t.shape[1], h, d).transpose(0, 2, 1, 3)
 
         o = _attn_reference(split(q), split(k), split(v), True,
                             1.0 / np.sqrt(d))
@@ -469,3 +529,33 @@ def test_flash_and_layer_norm_per_shard_match_one_device(mesh_flag,
     many = losses(mesh_flag, megatron_transformer if megatron else None)
     assert one[-1] < one[0]
     np.testing.assert_allclose(many, one, rtol=1e-5)
+
+
+def test_kernel_bundles_reads_a_schedule(tmp_path):
+    """scripts/kernel_bundles.py: bundles by loop depth and what fills
+    their slots, from the text libtpu's LLO dump writes."""
+    import sys
+
+    sys.path.insert(0, "scripts")
+    try:
+        import kernel_bundles
+    finally:
+        sys.path.remove("scripts")
+    dump = tmp_path / "1-flash_attention_fwd_packed.1-71-final_bundles.txt"
+    dump.write_text(
+        "LB: loop body\n"
+        "     0   :  { %s1 = smov 0 }\n"
+        "   0x1 LB: > { %v1 = vld [vmem:[#a] sm:$0xff]  ;;  "
+        "%2 = vst [vmem:[#b] sm:$0xff] %v1 }\n"
+        "   0x2   : >> { %v3 = vmul.f32 %v1, %v1  ;;  "
+        "%v4 = vpop.f32.mrf.mxu0  ;;  %5 = vmatmul.bf16.gmra.mxu1 %v1 }\n"
+        "   0x3   : >> { %v6 = vpow2.f32 %v3  ;;  "
+        "%v7 = vmax.xlane.f32.xlu0 %v3 }\n"
+        "   0x4 LE: > { %8 = vst [vmem:[#c] sm:$0xff] %v6 }\n")
+    assert [(d, n, dict(ops))
+            for d, n, ops in kernel_bundles.segments(str(dump))] == [
+        (0, 1, {}),
+        (1, 1, {"load": 1, "store": 1}),
+        (2, 2, {"valu": 1, "pop": 1, "mxu": 1, "eup": 1, "xlu": 1}),
+        (1, 1, {"store": 1}),
+    ]

@@ -137,6 +137,9 @@ def test_ring_flash_block_path_seq512():
     ((2, 128, 4, 32), False, (512, 512)),  # hpb=4
     ((1, 128, 3, 40), True, (512, 512)),   # 128 % 40 != 0 → full-width
     ((1, 200, 2, 64), True, (128, 128)),   # ragged kv tail
+    ((1, 384, 2, 64), True, (128, 128)),   # three kv blocks to the diagonal
+    ((1, 256, 2, 64), False, (128, 128)),  # the whole sweep, unmasked
+    ((1, 328, 4, 32), True, (128, 128)),   # hpb=4, ragged, several blocks
 ])
 def test_narrow_head_packed_kernel_parity(shape, causal, blocks):
     """The grouped narrow-head packed path (head_dim < 128: head-GROUP
