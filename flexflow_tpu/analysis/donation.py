@@ -44,7 +44,7 @@ BUILDER_CALLEES = {
     # speculative decoding's batched multi-token verification: the
     # target's KV state is donated, so the engine rebinds it per call
     "build_verify_step": ("_verify_fn", "_verify_step"),
-    "build_block_copy": ("_copy_fn",),
+    "build_block_copy": ("_copy_fn", "_copy_fn_w"),
     # disaggregated serving's KV handoff landing: the decode-side pools
     # are donated, so the coordinator rebinds the decode state
     "build_kv_inject": ("_inject_fn",),

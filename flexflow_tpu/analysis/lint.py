@@ -140,6 +140,7 @@ DONATED_CALLEES = {
     "_verify_fn": (1,),               # build_verify_step (speculative)
     "_verify_step": (1,),
     "_copy_fn": (0,),                 # build_block_copy (paged KV pools)
+    "_copy_fn_w": (0,),               # the window group's (serving/paged.py)
     "_inject_fn": (0,),               # build_kv_inject (disagg handoff)
     "_gather_fn": (0,),               # build_param_gather (stage-3 tree)
     "gather_fn": (0,),

@@ -940,11 +940,13 @@ class Executor:
             verify_step, donate_argnums=_donate_argnums((1,)))
         return self._verify_step
 
-    def build_block_copy(self):
+    def build_block_copy(self, only=None, skip=frozenset()):
         """Copy-on-write support for the paged KV layout: duplicate pool
         blocks src[i] → dst[i] across EVERY layer's pool_k/pool_v in one
         donated dispatch (the block ids are layer-uniform, so one (src,
-        dst) vector serves the whole stack). The serving engine pads the
+        dst) vector serves the whole stack). `only` / `skip`: state node
+        names that are / are not copied, where the cache has groups whose
+        block ids are their own (serving/paged.py). The serving engine pads the
         vectors to a power-of-two width with (scratch → scratch) no-op
         pairs, so the executable set stays O(log slots·chunk) like the
         prefill buckets. Donating `state` updates the pools in place on
@@ -957,6 +959,9 @@ class Executor:
             new_state = {}
             for name, ws in state.items():
                 nw = dict(ws)
+                if name in skip or (only is not None and name not in only):
+                    new_state[name] = nw
+                    continue
                 for pool in POOL_LEAVES:
                     buf = nw.get(pool)
                     if buf is not None:
@@ -964,9 +969,10 @@ class Executor:
                 new_state[name] = nw
             return new_state
 
-        self._copy_fn = jax.jit(
-            copy_blocks, donate_argnums=_donate_argnums((0,)))
-        return self._copy_fn
+        fn = jax.jit(copy_blocks, donate_argnums=_donate_argnums((0,)))
+        if only is None:
+            self._copy_fn = fn
+        return fn
 
     def build_kv_inject(self):
         """Disaggregated-serving handoff landing: write externally

@@ -1306,7 +1306,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *refs,
 
 def decode_attention_reference(q, k, v, positions, *, num_heads: int,
                                scale: float | None = None,
-                               num_kv_heads: int | None = None):
+                               num_kv_heads: int | None = None,
+                               window: int = 0, sink=None, key_pos=None):
     """Reference einsum attention over a KV cache — the CPU serving path
     and the decode kernel's numerics oracle. q: (slots, q_len, H·hd) new
     queries, k/v: (slots, S, H·hd) cache (new rows already written),
@@ -1324,32 +1325,47 @@ def decode_attention_reference(q, k, v, positions, *, num_heads: int,
     paged kernels serve, the engine hands them a chunk as single-query
     rows instead (serving/engine.py), and those rows go through one
     multi-query call that reads the chunk's context once
-    (paged_flash_chunk_attention)."""
+    (paged_flash_chunk_attention).
+
+    A value row may be narrower than a key row (v: (slots, S, KV·hd_v);
+    the output is then H·hd_v wide). `window` > 0: a row attends its
+    nearest `window` keys only, its own among them; `sink` (H,): one more
+    logit a head in the denominator, which gives no value. `key_pos`
+    (slots, S): the cache rows' positions where they are not 0 .. S-1 (a
+    slice of the cache)."""
     slots, q_len, e = q.shape
     s_k = k.shape[1]
     h = num_heads
     d = e // h
+    kv = num_kv_heads or h
     if scale is None:
         scale = 1.0 / math.sqrt(d)
 
-    def split(t, s):
-        return t.reshape(slots, s, -1, d).transpose(0, 2, 1, 3)
+    def split(t, s, heads):
+        return t.reshape(slots, s, heads, -1).transpose(0, 2, 1, 3)
 
-    qh, kh, vh = split(q, q_len), split(k, s_k), split(v, s_k)
-    if num_kv_heads not in (None, h):
-        # grouped keys and values (k, v: num_kv_heads * d wide): query
+    qh, kh, vh = split(q, q_len, h), split(k, s_k, kv), split(v, s_k, kv)
+    if kv != h:
+        # grouped keys and values (k, v: num_kv_heads heads wide): query
         # head i reads KV head i // group
-        kh = jnp.repeat(kh, h // num_kv_heads, axis=1)
-        vh = jnp.repeat(vh, h // num_kv_heads, axis=1)
+        kh = jnp.repeat(kh, h // kv, axis=1)
+        vh = jnp.repeat(vh, h // kv, axis=1)
     logits = jnp.einsum("bhqd,bhkd->bhqk", qh, kh,
                         preferred_element_type=jnp.float32)
     logits = logits * scale
-    key_pos = jnp.arange(s_k, dtype=jnp.int32)
-    mask = key_pos[None, None, None, :] <= positions[:, None, :, None]
+    if key_pos is None:
+        key_pos = jnp.arange(s_k, dtype=jnp.int32)[None]
+    key_pos = key_pos[:, None, None, :]
+    mask = key_pos <= positions[:, None, :, None]
+    if window:
+        mask &= key_pos > positions[:, None, :, None] - window
     logits = jnp.where(mask, logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bhqk,bhkd->bhqd", probs, vh)
-    return out.transpose(0, 2, 1, 3).reshape(slots, q_len, e)
+    from ..ops.attention import softmax_with_sink
+
+    probs = softmax_with_sink(
+        logits, None if sink is None else sink[None, :, None, None])
+    out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(q.dtype), vh)
+    return out.transpose(0, 2, 1, 3).reshape(slots, q_len, -1)
 
 
 def _decode_gate(rows: int, d: int, num_heads: int,
@@ -1481,9 +1497,9 @@ def _paged_round_copies(block_of, k_hbm, v_hbm, k_buf, v_buf, sem, c, buf,
     return out
 
 
-def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-                         k_buf, v_buf, sem, acc_ref, *, scale: float,
-                         group: int = 0):
+def _paged_decode_kernel(tbl_ref, len_ref, q_ref, *refs, scale: float,
+                         group: int = 0, window: int = 0, sink: bool = False,
+                         head_dim: int = 0):
     """`group` > 0: grouped keys and values, `group` query heads reading
     one KV head. The pool's row is then kv_heads * head_dim wide and the
     queries come as (heads, head_dim): row h of the block-diagonal query
@@ -1491,14 +1507,39 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     round's rows scores every head and one against its values accumulates
     every head's output at that offset. Rounds, DMAs, masks and the online
     softmax are one code; `group` is static, and at 0 the body is what it
-    was before there were groups."""
+    was before there were groups.
+
+    A value row may be narrower than a key row (the value pool's and the
+    accumulator's width against the key pool's): the two matmuls never
+    meet. `head_dim` > 0 says a key head is that wide and no multiple of
+    the 128 lanes (rows are whole tiles still): the queries come filled up
+    to whole tiles, and two constants come with them, `spread` (padded
+    head_dim, key row), which lays a head's lanes at every KV head's
+    offset by one matmul, and `own` (heads, key row), 1 on a head's own KV
+    head's lanes; lane slices at a multiple of 192 are never taken.
+    `window` > 0: the row attends its nearest `window` keys: the walk
+    starts at the round that holds key `length - window` and earlier keys
+    of that round are masked. `sink`: a (heads, 1) float32 input that
+    joins the running maximum and sum before the first round and never
+    the accumulator. All static; at their defaults the body is what it
+    was."""
+    refs = list(refs)
+    sink_ref = refs.pop(0) if sink else None
+    spread_ref, own_ref = (refs.pop(0), refs.pop(0)) if head_dim else (None,
+                                                                       None)
+    k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, acc_ref = refs
     s = pl.program_id(0)
     length = len_ref[s]
     width = tbl_ref.shape[1]
     rows = k_buf.shape[1]
     n_rounds = pl.cdiv(length, rows)
-    heads, e = acc_ref.shape        # e: the pool's row
-    head_dim = q_ref.shape[-1] if group else e // heads
+    # the round a window's first key lies in
+    c0 = jnp.maximum(length - window, 0) // rows if window else 0
+    heads, e = acc_ref.shape        # e: the value pool's row
+    e_k = k_buf.shape[-1]           # the key pool's
+    if not head_dim:
+        head_dim = q_ref.shape[-1] if group else e // heads
+    v_dim = e // (e_k // head_dim)
 
     def copies(c, buf):
         return _paged_round_copies(
@@ -1507,18 +1548,28 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     @pl.when(n_rounds > 0)
     def _first():
-        for dma in copies(0, 0):
+        for dma in copies(c0, c0 % 2):
             dma.start()
 
     # row h of the block-diagonal query holds q's lanes of head h
     head = jax.lax.broadcasted_iota(jnp.int32, (heads, e), 0)
-    own = (jax.lax.broadcasted_iota(jnp.int32, (heads, e), 1) // head_dim
+    own = (jax.lax.broadcasted_iota(jnp.int32, (heads, e), 1) // v_dim
            == (head // group if group else head))
-    q = q_ref[0].astype(jnp.float32)
-    if group:  # (heads, head_dim): a copy at every KV head's lanes
-        q = jnp.concatenate([q] * (e // head_dim), axis=1)
-    # (selected in f32: the int32 compare's mask does not relayout to bf16)
-    q_bd = jnp.where(own, q, 0.0).astype(q_ref.dtype)
+    if spread_ref is not None:
+        q_bd = (jax.lax.dot_general(
+            q_ref[0], spread_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+            * own_ref[...].astype(jnp.float32)).astype(q_ref.dtype)
+    else:
+        q = q_ref[0].astype(jnp.float32)
+        if group:  # (heads, head_dim): a copy at every KV head's lanes
+            q = jnp.concatenate([q] * (e_k // head_dim), axis=1)
+        own_k = own if e_k == e else (
+            jax.lax.broadcasted_iota(jnp.int32, (heads, e_k), 1) // head_dim
+            == jax.lax.broadcasted_iota(jnp.int32, (heads, e_k), 0) // group)
+        # (selected in f32: the int32 compare's mask does not relayout to
+        # bf16)
+        q_bd = jnp.where(own_k, q, 0.0).astype(q_ref.dtype)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def round_(c, carry):
@@ -1532,7 +1583,7 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         for dma in copies(c, buf):
             dma.wait()
-        k = k_buf[buf]  # (rows, e): logical rows [c·rows, (c+1)·rows)
+        k = k_buf[buf]  # (rows, e_k): logical rows [c·rows, (c+1)·rows)
         v = v_buf[buf]
         logits = jax.lax.dot_general(
             q_bd, k, (((1,), (1,)), ((), ())),
@@ -1540,12 +1591,18 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         ) * scale  # (heads, rows)
         first = c * rows
         key_pos = jax.lax.broadcasted_iota(jnp.int32, (heads, rows), 1) + first
-        logits = jnp.where(key_pos < length, logits, NEG_INF)
+        seen = key_pos < length
+        if window:
+            seen &= key_pos >= length - window
+        logits = jnp.where(seen, logits, NEG_INF)
         # zero masked V rows: rows past the cursor hold stale pool state
-        # (anything, NaN included) and 0·NaN would poison the contraction
-        v = jnp.where(
-            jax.lax.broadcasted_iota(jnp.int32, v.shape, 0) + first < length,
-            v, 0.0)
+        # (anything, NaN included) and 0·NaN would poison the contraction;
+        # so may rows behind a window, whose blocks are another slot's by now
+        v_pos = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0) + first
+        v_live = v_pos < length
+        if window:
+            v_live &= v_pos >= length - window
+        v = jnp.where(v_live, v, 0.0)
         m_new = jnp.maximum(m_prev, logits.max(axis=-1, keepdims=True))
         p = jnp.exp(logits - m_new)
         alpha = jnp.exp(m_prev - m_new)
@@ -1555,16 +1612,19 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         )
         return m_new, l_prev * alpha + p.sum(axis=-1, keepdims=True)
 
-    _, l = jax.lax.fori_loop(
-        0, n_rounds, round_,
-        (jnp.full((heads, 1), -jnp.inf, jnp.float32),
-         jnp.zeros((heads, 1), jnp.float32)))
+    if sink_ref is not None:
+        # exp(sink - m) with m = sink: the sink's share of the sum
+        carry = (sink_ref[...], jnp.ones((heads, 1), jnp.float32))
+    else:
+        carry = (jnp.full((heads, 1), -jnp.inf, jnp.float32),
+                 jnp.zeros((heads, 1), jnp.float32))
+    _, l = jax.lax.fori_loop(c0, n_rounds, round_, carry)
     # length == 0 (empty slot) ⇒ no round ran, l == 0; the clamp keeps the
     # dead row finite without touching live rows, whose l >= exp(0) = 1
     out = jnp.where(own, acc_ref[...] / jnp.maximum(l, 1e-30), 0.0)
     if group:  # head h's output lies at its KV head's lanes, 0 elsewhere
-        o = sum(out[:, j * head_dim:(j + 1) * head_dim]
-                for j in range(e // head_dim))
+        o = sum(out[:, j * v_dim:(j + 1) * v_dim]
+                for j in range(e // v_dim))
     else:
         o = out.sum(axis=0, keepdims=True)
     o_ref[0] = o.astype(o_ref.dtype)
@@ -1573,7 +1633,8 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 def paged_decode_attention_reference(q, pool_k, pool_v, page_table,
                                      positions, *, num_heads: int,
                                      scale: float | None = None,
-                                     num_kv_heads: int | None = None):
+                                     num_kv_heads: int | None = None,
+                                     window: int = 0, sink=None):
     """Einsum oracle for the paged decode kernel (and the CPU serving
     path, via ops/inc_attention.py): gather each slot's logical cache
     view from the pool through its page table, then run the contiguous
@@ -1583,26 +1644,46 @@ def paged_decode_attention_reference(q, pool_k, pool_v, page_table,
     slots = q.shape[0]
     W = page_table.shape[1]
     bs = pool_k.shape[1]
-    e = pool_k.shape[-1]
-    kc = pool_k[page_table].reshape(slots, W * bs, e).astype(q.dtype)
-    vc = pool_v[page_table].reshape(slots, W * bs, e).astype(q.dtype)
+    kc = pool_k[page_table].reshape(slots, W * bs, -1).astype(q.dtype)
+    vc = pool_v[page_table].reshape(slots, W * bs, -1).astype(q.dtype)
     return decode_attention_reference(q, kc, vc, positions,
                                       num_heads=num_heads, scale=scale,
-                                      num_kv_heads=num_kv_heads)
+                                      num_kv_heads=num_kv_heads,
+                                      window=window, sink=sink)
+
+
+def _spread_constants(num_heads: int, head_dim: int, kv_heads: int, dtype):
+    """(`spread`, `own`) of _paged_decode_kernel for key heads of
+    `head_dim` lanes, no multiple of 128: spread[i, j * head_dim + i] = 1
+    for every KV head j (rows past head_dim, the queries' fill, are 0);
+    own[h, l] = 1 where lane l is of KV head h // group."""
+    import numpy as np
+
+    pad = -(-head_dim // 128) * 128
+    lane = np.arange(kv_heads * head_dim)
+    spread = (lane[None] % head_dim == np.arange(pad)[:, None])
+    own = (lane[None] // head_dim
+           == (np.arange(num_heads) // (num_heads // kv_heads))[:, None])
+    return jnp.asarray(spread, dtype), jnp.asarray(own, dtype)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("num_heads", "scale", "pages", "interpret"))
-def _paged_decode_call(table, lengths, q, pool_k, pool_v, *, num_heads: int,
-                       scale: float, pages: int, interpret: bool):
+    jax.jit, static_argnames=("num_heads", "scale", "pages", "interpret",
+                              "window"))
+def _paged_decode_call(table, lengths, q, pool_k, pool_v, sink=None, *,
+                       num_heads: int, scale: float, pages: int,
+                       interpret: bool, window: int = 0):
     """The kernel launch (shapes already gated). Jitted so that the memory
     space constraint, which has no eager form, also serves a caller outside
     jit; inside one it is traced inline. A pool row narrower than the
-    queries is grouped keys and values (the kernel's `group`)."""
+    queries is grouped keys and values (the kernel's `group`); so is a
+    value row narrower than a key row, at whatever group."""
     slots, _, e = q.shape
-    bs, e_kv = pool_k.shape[1], pool_k.shape[-1]
+    bs, e_kv, e_v = pool_k.shape[1], pool_k.shape[-1], pool_v.shape[-1]
     d = e // num_heads
-    group = 0 if e_kv == e else num_heads // (e_kv // d)
+    kv_heads = e_kv // d
+    group = 0 if e_kv == e == e_v else num_heads // kv_heads
+    d_v = e_v // kv_heads
     if not interpret:
         # XLA's memory-space assignment otherwise parks the op's bf16 copy
         # of the pool in VMEM (128 MiB on a v5e) for 23 of 24 layers: the
@@ -1611,31 +1692,56 @@ def _paged_decode_call(table, lengths, q, pool_k, pool_v, *, num_heads: int,
         pool_k, pool_v = (pltpu.with_memory_space_constraint(p, pltpu.HBM)
                           for p in (pool_k, pool_v))
     qshape = (slots, num_heads, d) if group else (slots, 1, e)
+    oshape = (slots, num_heads, d_v) if group else (slots, 1, e)
+    q = q.reshape(qshape)
+    extra, static = [], {}
+
+    def whole(a):  # a constant of the call: one block, fetched once
+        return pl.BlockSpec(a.shape, lambda s, tbl, ln: (0,) * a.ndim)
+
+    if sink is not None:
+        extra.append(sink.astype(jnp.float32).reshape(num_heads, 1))
+        static["sink"] = True
+    if group and d % 128:
+        # key heads that are no whole lane tiles (module comment of
+        # _paged_decode_kernel): the queries filled up to whole tiles
+        spread, own = _spread_constants(num_heads, d, kv_heads, q.dtype)
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, spread.shape[0] - d)))
+        qshape = q.shape
+        extra += [spread, own]
+        static["head_dim"] = d
+    extra_specs = [whole(a) for a in extra]
+    if window:
+        static["window"] = window
     qspec = pl.BlockSpec((1,) + qshape[1:], lambda s, tbl, ln: (s, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pltpu.HBM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(slots,),
-        in_specs=[qspec, pool_spec, pool_spec],
-        out_specs=qspec,
+        in_specs=[qspec, *extra_specs, pool_spec, pool_spec],
+        out_specs=pl.BlockSpec((1,) + oshape[1:],
+                               lambda s, tbl, ln: (s, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, pages * bs, e_kv), pool_k.dtype),
-            pltpu.VMEM((2, pages * bs, e_kv), pool_v.dtype),
+            pltpu.VMEM((2, pages * bs, e_v), pool_v.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((num_heads, e_kv), jnp.float32),
+            pltpu.VMEM((num_heads, e_v), jnp.float32),
         ],
     )
+    name = "flash_attention_paged_decode"
+    if window:  # named apart, as `_grouped` is: a trace tells the kinds
+        name += "_window"
     return pl.pallas_call(
-        functools.partial(_paged_decode_kernel, scale=scale, group=group),
+        functools.partial(_paged_decode_kernel, scale=scale, group=group,
+                          **static),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qshape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(oshape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
-        name=("flash_attention_paged_decode_grouped" if group
-              else "flash_attention_paged_decode"),
-    )(table, lengths, q.reshape(qshape), pool_k, pool_v).reshape(slots, 1, e)
+        name=name + ("_grouped" if group else ""),
+    )(table, lengths, q, *extra, pool_k, pool_v).reshape(slots, 1, -1)
 
 
 def _paged_round_pages(block_size: int) -> int:
@@ -1644,32 +1750,45 @@ def _paged_round_pages(block_size: int) -> int:
 
 def paged_decode_gate(cache_rows: int, block_size: int, embed: int,
                       num_heads: int, itemsize: int,
-                      interpret: bool) -> str | None:
+                      interpret: bool, v_embed: int = 0) -> str | None:
     """Why the paged decode kernel cannot take a pool of this geometry, or
     None where it can: the contiguous kernel's gate plus the paged ones. A
     block must be a legal (sublane, lane) tile, so tiny block sizes route
     to the reference, and so does a row too wide for two rounds to sit in
-    VMEM. Nothing here depends on how many rows a call has: the serving
-    engine lays a chunk step out by the same answer
-    (ops/inc_attention.paged_rows_run_kernel)."""
-    gate = _decode_gate(cache_rows, embed // num_heads, num_heads, interpret)
+    VMEM. `embed`, `num_heads`: the key pool's row and its heads;
+    `v_embed`: the value pool's row where it is another. The kernel takes
+    lane slices at multiples of the VALUE head's size only (a head's output
+    out of the accumulator), so that is what has to be whole lane tiles; a
+    key head of any size is served where both rows are whole tiles
+    (_paged_decode_kernel's `spread`). Nothing here depends on how many
+    rows a call has: the serving engine lays a chunk step out by the same
+    answer (ops/inc_attention.paged_rows_run_kernel)."""
+    v_embed = v_embed or embed
+    gate = _decode_gate(cache_rows, v_embed // num_heads, num_heads,
+                        interpret)
+    if (gate is None and not interpret and num_heads != 1
+            and (embed // num_heads) % 128 and (embed % 128 or v_embed % 128)):
+        gate = (f"key heads of {embed // num_heads} in pool rows of {embed} "
+                f"/ {v_embed} lanes: no whole 128-lane tiles")
     if gate is None and block_size % 8 != 0:
         gate = f"block_size {block_size} % 8 != 0"
     if gate is None:
         rows = _paged_round_pages(block_size) * block_size
-        round_bytes = 4 * rows * embed * itemsize
+        round_bytes = 2 * rows * (embed + v_embed) * itemsize
         if round_bytes > _PAGED_ROUND_VMEM:
-            gate = (f"two rounds of {rows} rows x {embed} lanes take "
-                    f"{round_bytes} bytes of VMEM > {_PAGED_ROUND_VMEM}")
+            gate = (f"two rounds of {rows} rows x {embed} + {v_embed} lanes "
+                    f"take {round_bytes} bytes of VMEM > {_PAGED_ROUND_VMEM}")
     return gate
 
 
 def paged_flash_decode_attention(
     q, pool_k, pool_v, page_table, lengths, *, num_heads: int,
     scale: float | None = None, num_kv_heads: int | None = None,
+    window: int = 0, sink=None,
 ):
     """Single-query decode attention over a paged KV pool. q: (rows, 1,
-    H·hd); pool_k/v: (num_blocks, block_size, H·hd); page_table: (rows,
+    H·hd); pool_k: (num_blocks, block_size, KV·hd), pool_v: (num_blocks,
+    block_size, KV·hd_v); page_table: (rows,
     W) int32 logical→physical block map; lengths: (rows,) int32 live-key
     counts. A row is a slot's one query, or one token of a prefill chunk
     carrying its slot's page-table row (a chunk paged_flash_chunk_attention
@@ -1677,7 +1796,10 @@ def paged_flash_decode_attention(
     grid step a row: the
     body walks the row's live pages through the scalar-prefetched table,
     whole pool rows DMA'd from HBM a round of ~128 cache rows at a time,
-    all heads in one pass (see the section comment). Shapes the kernel
+    all heads in one pass (see the section comment). `window` > 0: a row
+    attends its nearest `window` keys and walks only the pages that hold
+    them; `sink` (H,): one more logit a head in the softmax's denominator.
+    The output is (rows, 1, H·hd_v). Shapes the kernel
     can't tile on hardware take the gather + einsum reference, with a
     KernelFallbackWarning on a TPU (the CPU serving path routes there
     directly)."""
@@ -1693,18 +1815,89 @@ def paged_flash_decode_attention(
     interpret = jax.default_backend() != "tpu"
     kv_heads = num_kv_heads or num_heads
     gate = paged_decode_gate(W * bs, bs, pool_k.shape[-1], kv_heads,
-                             pool_k.dtype.itemsize, interpret)
+                             pool_k.dtype.itemsize, interpret,
+                             pool_v.shape[-1])
     if gate is not None:
         warn_reference("paged_flash_decode_attention",
                        (q.shape, pool_k.shape), gate)
         positions = (lengths.astype(jnp.int32) - 1)[:, None]
         return paged_decode_attention_reference(
             q, pool_k, pool_v, page_table, positions,
-            num_heads=num_heads, scale=scale, num_kv_heads=kv_heads)
+            num_heads=num_heads, scale=scale, num_kv_heads=kv_heads,
+            window=window, sink=sink)
     return _paged_decode_call(
         page_table.astype(jnp.int32), lengths.astype(jnp.int32), q, pool_k,
-        pool_v, num_heads=num_heads, scale=scale,
-        pages=_paged_round_pages(bs), interpret=interpret)
+        pool_v, sink, num_heads=num_heads, scale=scale,
+        pages=_paged_round_pages(bs), interpret=interpret, window=window)
+
+
+def paged_chunk_attention_tiled(q, pool_k, pool_v, table_row, positions, *,
+                                num_heads: int, scale: float,
+                                num_kv_heads: int | None = None,
+                                window: int = 0, sink=None,
+                                tile_rows: int = 2048):
+    """Multi-query attention of ONE prefill chunk over a paged KV pool as
+    XLA ops, for the layers the chunk kernel below cannot tile (a key head
+    of 192 lanes: its head loop takes lane slices at multiples of the head;
+    a window; a sink): the table row is walked once for all rows, a tile of
+    about `tile_rows` cache rows at a time gathered from the pool, with a
+    running maximum and sum a row a head (flash attention, in XLA). The
+    trip count is the chunk's: from the tile that holds the first key any
+    row attends (under a window, `window` - 1 before the chunk's first
+    row) to the tile of its last row; nothing is gathered past it.
+    q: (b, 1, H·hd); positions: (b,) int32, a row's own position (its K
+    and V are in the pool), negative = a dead row, which gives 0;
+    -> (b, 1, H·hd_v)."""
+    b, _, e = q.shape
+    h = num_heads
+    kv = num_kv_heads or h
+    g = h // kv
+    bs, W = pool_k.shape[1], table_row.shape[0]
+    pages = max(1, min(tile_rows // bs, W))
+    rows = pages * bs
+    table = jnp.pad(table_row.astype(jnp.int32), (0, -W % pages))
+    positions = positions.astype(jnp.int32)
+    live = positions >= 0
+    last = jnp.max(jnp.where(live, positions, -1))
+    first = jnp.min(jnp.where(live, positions, jnp.iinfo(jnp.int32).max))
+    lo = jnp.maximum(first - window + 1, 0) // rows if window else 0
+    hi = jnp.where(last >= 0, last // rows + 1, 0)
+    qh = q[:, 0].reshape(b, kv, g, e // h)
+    d_v = pool_v.shape[-1] // kv
+
+    def tile(j, carry):
+        m, l, acc = carry
+        blocks = jax.lax.dynamic_slice_in_dim(table, j * pages, pages)
+        k = pool_k[blocks].reshape(rows, kv, -1).astype(q.dtype)
+        v = pool_v[blocks].reshape(rows, kv, d_v).astype(q.dtype)
+        logits = jnp.einsum("bkgd,rkd->kgbr", qh, k,
+                            preferred_element_type=jnp.float32) * scale
+        at = j * rows + jnp.arange(rows, dtype=jnp.int32)
+        seen = at[None] <= positions[:, None]
+        if window:
+            seen &= at[None] > positions[:, None] - window
+        logits = jnp.where(seen[None, None], logits, NEG_INF)
+        m_new = jnp.maximum(m, logits.max(axis=-1, keepdims=True))
+        p = jnp.where(seen[None, None], jnp.exp(logits - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        acc = acc * alpha + jnp.einsum(
+            "kgbr,rkd->kgbd", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
+        return m_new, l * alpha + p.sum(axis=-1, keepdims=True), acc
+
+    shape = (kv, g, b, 1)
+    if sink is None:
+        m0 = jnp.full(shape, NEG_INF, jnp.float32)
+        l0 = jnp.zeros(shape, jnp.float32)
+    else:  # exp(sink - m) with m = sink: the sink's share of the sum
+        m0 = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(kv, g, 1, 1), shape)
+        l0 = jnp.ones(shape, jnp.float32)
+    _, l, acc = jax.lax.fori_loop(
+        lo, hi, tile, (m0, l0, jnp.zeros((kv, g, b, d_v), jnp.float32)))
+    out = jnp.where(live[None, None, :, None],
+                    acc / jnp.maximum(l, 1e-30), 0.0)
+    return out.transpose(2, 0, 1, 3).reshape(b, 1, h * d_v).astype(q.dtype)
 
 
 # ------------------------------------------------- paged chunk (multi-query)

@@ -440,6 +440,12 @@ class FFModel:
         head_dim: int = 0,
         output_gate: bool = False,
         index=None,
+        v_head_dim: int = 0,
+        rope_dim: int = 0,
+        window: int = 0,
+        sink: bool = False,
+        value_scale: float = 1.0,
+        sink_initializer: Optional[Initializer] = None,
     ) -> Tensor:
         """`rope_theta` > 0 rotates q and k by the (batch, seq) int
         `positions`; `qk_norm` RMS-normalises the q and k projections
@@ -448,7 +454,12 @@ class FFModel:
         keys and values; `head_dim` is a head's size where it is not
         embed_dim / num_heads; `output_gate` multiplies the core's output
         by sigmoid(query @ wg); `index` (an ops.attention.Indexer) has
-        each row attend a learned top-k selection of its past
+        each row attend a learned top-k selection of its past;
+        `v_head_dim` is a value head's size where it is not the key
+        head's; `rope_dim` rotates only the first lanes of a head;
+        `window` keeps a row's nearest keys; `sink` adds a learned bias a
+        head to the softmax's denominator (drawn by `sink_initializer`);
+        `value_scale` multiplies the values
         (ops/attention.AttentionFrontEnd)."""
         if impl not in ("xla", "flash", "ring"):
             raise ValueError(
@@ -459,11 +470,14 @@ class FFModel:
                 "multihead_attention: rope_theta and positions go together")
         front = AttentionFrontEnd(embed_dim, num_heads, bias, rope_theta,
                                   qk_norm, qk_norm_eps, num_kv_heads,
-                                  head_dim, output_gate, index)
+                                  head_dim, output_gate, index, v_head_dim,
+                                  rope_dim, window, sink, value_scale)
         p = MultiHeadAttentionParams(front, kdim, vdim, dropout, add_bias_kv,
                                      add_zero_attn, causal, impl)
         inits = ({} if kernel_initializer is None
                  else dict.fromkeys(front.matrices, kernel_initializer))
+        if sink and sink_initializer is not None:
+            inits["sink"] = sink_initializer
         inputs = [query, key, value]
         if positions is not None:
             inputs.append(positions)
@@ -620,6 +634,7 @@ class FFModel:
         aux_loss_coef: float = 0.0,
         name: str = "",
         kernel_initializer: Optional[Initializer] = None,
+        router_bias_initializer: Optional[Initializer] = None,
         **routing,
     ) -> Tensor:
         """The token-routed expert layer of an LM block on (.., hidden):
@@ -627,7 +642,11 @@ class FFModel:
         renormalised), SiLU-gated
         experts, gate-weighted sum; dropless (ops/moe.py). `routing`:
         the further fields of MoEMLPParams (DeepSeek-V3's sigmoid
-        group-limited router, a shared expert, the experts held here)."""
+        group-limited router, a shared expert, the experts held here).
+        `router_bias_initializer` draws the sigmoid router's correction
+        bias where the layer's own draw, N(0, 0.02), is not wanted: beside
+        scores a few thousandths apart that draw decides which experts are
+        loaded."""
         from .ops import MoEMLPParams
 
         p = MoEMLPParams(num_experts, num_experts_per_tok, intermediate_size,
@@ -635,6 +654,8 @@ class FFModel:
         inits = ({} if kernel_initializer is None else dict.fromkeys(
             ("router", "gate", "up", "down", "shared_gate", "shared_up",
              "shared_down"), kernel_initializer))
+        if router_bias_initializer is not None:
+            inits["router_bias"] = router_bias_initializer
         return self._add_layer(OT.OP_MOE_MLP, p, [input], name, inits,
                                data_type=input.dtype).outputs[0]
 

@@ -20,6 +20,7 @@ from .transformer import (
     build_transformer_lm_pipelined,
     deepseek_v32_lm_config,
     keye_vl2_lm_config,
+    mimo_v2_flash_lm_config,
     olmoe_lm_config,
     solar_open2_lm_config,
     transformer_lm_param_count,

@@ -127,6 +127,22 @@ class TransformerLMConfig:
     # ops.attention.Indexer) gives the "mha" layers a learned top-k
     # selection of the positions a row attends; needs position "rope"
     indexer: Optional[object] = None
+    # MiMo-V2-Flash (`mimo_v2_flash_lm_config`): a value head's size apart
+    # from the key head's, RoPE over a head's first `rope_dim` lanes, the
+    # values' scale; `layer_pattern` "swa" is an "mha" layer with the
+    # arguments of FFModel.multihead_attention in `swa` in place of the
+    # model's (its window, its sink, its KV heads, its theta: what differs
+    # by kind is the layer's front end's, ops/attention.AttentionFrontEnd);
+    # `sink_range` > 0 draws a sink from N(0, that)
+    v_head_dim: int = 0
+    rope_dim: int = 0
+    value_scale: float = 1.0
+    swa: Optional[dict] = None
+    sink_range: float = 0.0
+    # the sigmoid router's correction bias (ops/moe.py `router_bias`) is
+    # drawn from N(0, that), 0.0 = zeros; None leaves the layer's own
+    # draw, N(0, 0.02)
+    router_bias_range: Optional[float] = None
 
     def layer_kind(self, i: int) -> str:
         return self.layer_pattern[i] if self.layer_pattern else self.attention
@@ -148,11 +164,16 @@ class TransformerLMConfig:
         if self.layer_pattern is not None:
             self.layer_pattern = tuple(self.layer_pattern)
             if (len(self.layer_pattern) != self.num_layers
-                    or set(self.layer_pattern) - {"mha", "delta"}):
+                    or set(self.layer_pattern) - {"mha", "delta", "swa"}):
                 raise ValueError(
                     f"TransformerLMConfig.layer_pattern names one kind "
-                    f"('mha' | 'delta') for each of the {self.num_layers} "
-                    f"layers, got {self.layer_pattern!r}")
+                    f"('mha' | 'delta' | 'swa') for each of the "
+                    f"{self.num_layers} layers, got {self.layer_pattern!r}")
+            if "swa" in self.layer_pattern and not (self.swa or {}).get(
+                    "window"):
+                raise ValueError(
+                    "TransformerLMConfig.layer_pattern 'swa' needs `swa` "
+                    "with a window")
             if "delta" in self.layer_pattern and self.delta is None:
                 raise ValueError(
                     "TransformerLMConfig.layer_pattern 'delta' needs "
@@ -331,6 +352,80 @@ def keye_vl2_lm_config(config: dict, *, sequence_length: int,
         embedding_range=embedding_range)
 
 
+def mimo_v2_flash_lm_config(config: dict, *, sequence_length: int,
+                            attention_impl: str = "xla",
+                            initializer_range: float = 0.02,
+                            embedding_range: float = 0.0,
+                            sink_range: float = 0.0) -> TransformerLMConfig:
+    """MiMo-V2-Flash from the keys of its published config.json
+    (`model_type: mimo_v2_flash`; models/mimo_v2_flash_reference.py writes
+    the equations out and says what the keys leave open): layers where
+    `hybrid_layer_pattern` is 0 are global softmax attention, where 1
+    attention over a window of `sliding_window` keys with a learned sink a
+    head, each kind with its own KV heads and RoPE theta; heads of
+    `head_dim` for q and k and `v_head_dim` for v, RoPE over the first
+    int(head_dim x `partial_rotary_factor`) lanes, the values scaled by
+    `attention_value_scale`; a gated MLP where `moe_layer_freq` is 0, else
+    DeepSeek-V3's sigmoid router (`noaux_tc`) over SiLU-gated experts, no
+    shared expert. A cut configuration states `experts_held` /
+    `experts_routed` as DeepSeek-V3.2's does. The three MTP layers are not
+    built. Random weights are N(0, `initializer_range`), the embedding
+    N(0, `embedding_range`) and the sinks N(0, `sink_range`) where those
+    are given; the routers' correction bias is zeros (no key gives the
+    trained one, and a seeded one of the layer's default spread, 0.02
+    beside scores 0.005 apart, decides which experts are loaded: a held
+    sixteenth's share of the assignments then swings by a fifth with the
+    seed)."""
+    layers = config["num_hidden_layers"]
+    pattern = config["hybrid_layer_pattern"][:layers]
+    moe = config["moe_layer_freq"][:layers]
+    dense = moe.index(1) if 1 in moe else layers
+    if (any(moe[:dense]) or not all(moe[dense:])
+            or config.get("attention_bias") or config.get("n_shared_experts")
+            or config["hidden_act"] != "silu"
+            or config["swa_num_attention_heads"]
+            != config["num_attention_heads"]
+            or (config["swa_head_dim"], config["swa_v_head_dim"])
+            != (config["head_dim"], config["v_head_dim"])):
+        raise NotImplementedError(
+            "mimo_v2_flash_lm_config builds the published block: leading "
+            "dense layers then expert layers, no attention bias, no shared "
+            "expert, SiLU, both kinds of layer with the same query heads "
+            "and head sizes")
+    held = config.get("experts_held")
+    return TransformerLMConfig(
+        vocab_size=config["vocab_size"], hidden_size=config["hidden_size"],
+        num_heads=config["num_attention_heads"], num_layers=layers,
+        sequence_length=sequence_length, attention_impl=attention_impl,
+        norm="rmsnorm", norm_eps=config["layernorm_epsilon"],
+        position="rope", rope_theta=float(config["rope_theta"]),
+        attention_bias=False,
+        num_kv_heads=config["num_key_value_heads"],
+        head_dim=config["head_dim"], v_head_dim=config["v_head_dim"],
+        rope_dim=int(config["head_dim"] * config["partial_rotary_factor"]),
+        value_scale=float(config["attention_value_scale"]),
+        layer_pattern=tuple("swa" if kind else "mha" for kind in pattern),
+        swa=dict(num_kv_heads=config["swa_num_key_value_heads"],
+                 rope_theta=float(config["swa_rope_theta"]),
+                 window=config["sliding_window"],
+                 sink=bool(config["add_swa_attention_sink_bias"])),
+        mlp="moe", intermediate_size=config["intermediate_size"],
+        first_k_dense=dense,
+        num_experts=config.get("experts_routed", config["n_routed_experts"]),
+        num_experts_per_tok=config["num_experts_per_tok"],
+        moe_intermediate_size=config["moe_intermediate_size"],
+        moe_routing=dict(
+            scoring=config["scoring_func"], n_group=config["n_group"],
+            topk_group=config["topk_group"],
+            norm_topk_prob=config["norm_topk_prob"],
+            routed_scaling_factor=float(
+                config["routed_scaling_factor"] or 1.0),
+            experts_held=None if held is None else tuple(held)),
+        initializer_range=initializer_range,
+        embedding_range=embedding_range, sink_range=sink_range,
+        router_bias_range=0.0)
+
+
 def _norm_initializer(stddev: float):
     from ..initializer import NormInitializer
 
@@ -362,15 +457,23 @@ def _lm_trunk(ff, c: TransformerLMConfig, h, pos):
             a = ff.latent_attention(a, pos, c.latent, kernel_initializer=init,
                                     name=f"{p}attn")
         else:
+            front = dict(
+                rope_theta=c.rope_theta if rope else 0.0,
+                num_kv_heads=c.num_kv_heads, head_dim=c.head_dim,
+                v_head_dim=c.v_head_dim, rope_dim=c.rope_dim,
+                value_scale=c.value_scale)
+            if c.layer_kind(i) == "swa":
+                front.update(c.swa)
             a = ff.multihead_attention(
                 a, a, a, c.hidden_size, c.num_heads, bias=c.attention_bias,
                 causal=True, impl=c.attention_impl, name=f"{p}attn",
                 positions=pos if rope else None,
-                rope_theta=c.rope_theta if rope else 0.0,
                 qk_norm=c.qk_norm, qk_norm_eps=c.norm_eps,
-                num_kv_heads=c.num_kv_heads, head_dim=c.head_dim,
                 output_gate=c.attention_gate, index=c.indexer,
                 kernel_initializer=init,
+                sink_initializer=(_norm_initializer(c.sink_range)
+                                  if c.sink_range else None),
+                **front,
             )
         h = ff.add(h, a, name=f"{p}res1")
         m = _lm_norm(ff, c, h, f"{p}ln2")
@@ -381,6 +484,9 @@ def _lm_trunk(ff, c: TransformerLMConfig, h, pos):
                            c.moe_intermediate_size,
                            c.router_aux_loss_coef / c.num_layers,
                            name=f"{p}moe", kernel_initializer=init,
+                           router_bias_initializer=(
+                               None if c.router_bias_range is None
+                               else _norm_initializer(c.router_bias_range)),
                            **(c.moe_routing or {}))
         elif c.mlp in ("swiglu", "moe"):
             g = ff.dense(m, c.intermediate_size, use_bias=False,

@@ -99,6 +99,22 @@ class AttentionFrontEnd:
     # a learned selection of the positions a row attends (`Indexer`);
     # None = all of its past
     index: Optional[Indexer] = None
+    # a value head's size where it is not the key head's: `wv`, the value
+    # pool's row and `wo`'s input follow it; 0 = head_dim
+    v_head_size: int = 0
+    # lanes of each q and k head that RoPE rotates, the first of the head
+    # (half-rotation form inside them, frequencies over `rope_dim`); 0 =
+    # the whole head
+    rope_dim: int = 0
+    # keys a row attends, the nearest ones and its own among them (a
+    # sliding window); 0 = all of its past. A layer with a window is in
+    # the serving cache's window group (serving/paged.py)
+    window: int = 0
+    # a learned bias a head, `sink` (num_heads,), that joins the softmax's
+    # denominator and gives no value
+    sink: bool = False
+    # the values times this (equal to scaling the core's output)
+    value_scale: float = 1.0
 
     # the four every attention layer has; `wg` joins them under
     # `output_gate`, the indexer's three under `index` (`matrices`)
@@ -113,6 +129,16 @@ class AttentionFrontEnd:
             raise ValueError(
                 "AttentionFrontEnd.index rotates its queries and key: it "
                 "needs rope_theta and positions")
+        if self.index is not None and (self.window or self.sink
+                                       or self.v_head_size):
+            raise ValueError(
+                "AttentionFrontEnd.index selects over a layer's whole past "
+                "with keys and values of one size: no window, sink or "
+                "v_head_size beside it")
+        if self.rope_dim % 2 or self.rope_dim > self.head_dim:
+            raise ValueError(
+                f"AttentionFrontEnd.rope_dim is an even number of a head's "
+                f"{self.head_dim} lanes, got {self.rope_dim}")
 
     @property
     def matrices(self) -> tuple:
@@ -138,8 +164,29 @@ class AttentionFrontEnd:
                 else contextlib.nullcontext())
 
     @property
+    def attend_scope(self) -> str:
+        """The trace scope of the layer's core: `gsa.attend` under a
+        learned selection, `swa.attend` under a window, `gqa.attend`
+        otherwise (docs/observability.md)."""
+        return ("gsa.attend" if self.index
+                else "swa.attend" if self.window else "gqa.attend")
+
+    @property
     def head_dim(self) -> int:
         return self.head_size or self.embed_dim // self.num_heads
+
+    @property
+    def v_head_dim(self) -> int:
+        return self.v_head_size or self.head_dim
+
+    @property
+    def plain_core(self) -> bool:
+        """The core is softmax(q . k) v over a row's whole past with keys
+        and values of one size: what the packed training kernels, ring
+        attention, the contiguous decode kernel and the paged chunk kernel
+        compute."""
+        return not (self.window or self.sink
+                    or self.v_head_dim != self.head_dim)
 
     @property
     def kv_heads(self) -> int:
@@ -151,8 +198,18 @@ class AttentionFrontEnd:
 
     @property
     def kv_width(self) -> int:
-        """Numbers a token's key (and its value) holds: a cache row."""
+        """Numbers a token's key holds: a row of the key cache."""
         return self.kv_heads * self.head_dim
+
+    @property
+    def v_width(self) -> int:
+        """Numbers a token's value holds: a row of the value cache."""
+        return self.kv_heads * self.v_head_dim
+
+    @property
+    def o_width(self) -> int:
+        """Numbers the core gives a row: `wo`'s input."""
+        return self.num_heads * self.v_head_dim
 
     def selected(self, cached_rows: int) -> int:
         """Positions a row attends at the most over a cache of
@@ -178,20 +235,23 @@ class AttentionFrontEnd:
         if self.selected(cached_rows):
             return {"pool_kv": 2 * self.kv_width,
                     "pool_i": -(-self.index.head_dim // 128) * 128}
-        return {"pool_k": self.kv_width, "pool_v": self.kv_width}
+        return {"pool_k": self.kv_width, "pool_v": self.v_width}
 
     def weight_specs(self, q_dim: int, k_dim: int, v_dim: int):
         """The trainable weights, in the order parameters are initialised.
         Per-head projection sizes follow attention.cc:70-80."""
         E, Q, KV = self.embed_dim, self.q_width, self.kv_width
+        V, O = self.v_width, self.o_width
         f = DataType.DT_FLOAT
         ws = [WeightSpec("wq", (q_dim, Q), f), WeightSpec("wk", (k_dim, KV), f),
-              WeightSpec("wv", (v_dim, KV), f), WeightSpec("wo", (Q, E), f)]
+              WeightSpec("wv", (v_dim, V), f), WeightSpec("wo", (O, E), f)]
         if self.output_gate:
-            ws.append(WeightSpec("wg", (q_dim, Q), f))
+            ws.append(WeightSpec("wg", (q_dim, O), f))
         if self.use_bias:
             ws += [WeightSpec(b, (n,), f, "zeros")
-                   for b, n in (("bq", Q), ("bk", KV), ("bv", KV), ("bo", E))]
+                   for b, n in (("bq", Q), ("bk", KV), ("bv", V), ("bo", E))]
+        if self.sink:
+            ws.append(WeightSpec("sink", (self.num_heads,), f, "zeros"))
         if self.qk_norm:
             ws += [WeightSpec(g, (n,), f, "ones")
                    for g, n in zip(("q_norm", "k_norm"),
@@ -223,12 +283,30 @@ class AttentionFrontEnd:
                                     self.qk_norm_eps).reshape(x.shape)
 
                 q, k = norm(q, weights["q_norm"]), norm(k, weights["k_norm"])
-            if self.rope_theta:
+            if self.rope_theta and self.rope_dim:
+                q = self._rope_leading(q, positions, self.num_heads)
+                k = self._rope_leading(k, positions, self.kv_heads)
+            elif self.rope_theta:
                 cos, sin = rope_cos_sin(positions, self.head_dim,
                                         self.rope_theta)
                 q = apply_rope(q, cos, sin, self.num_heads)
                 k = apply_rope(k, cos, sin, self.kv_heads)
+            if self.value_scale != 1.0:
+                v = (v.astype(jnp.float32)
+                     * self.value_scale).astype(v.dtype)
         return q, k, v
+
+    def _rope_leading(self, x, positions, heads: int):
+        """The first `rope_dim` lanes of every head of x (batch, seq,
+        heads * head_dim) rotated, the others as they are."""
+        dr = self.rope_dim
+        inv_freq = self.rope_theta ** (
+            -jnp.arange(0, dr, 2, dtype=jnp.float32) / dr)
+        angles = positions.astype(jnp.float32)[..., None, None] * inv_freq
+        xh = x.reshape(x.shape[:-1] + (heads, self.head_dim))
+        return jnp.concatenate(
+            [rope_half(xh[..., :dr], angles), xh[..., dr:]],
+            axis=-1).reshape(x.shape)
 
     def index_inputs(self, ctx, weights, x, positions):
         """What the learned selection of x (batch, seq, hidden) at
@@ -270,13 +348,14 @@ class AttentionFrontEnd:
         """FLOPs of the projections over a batch of q_rows queries and
         kv_rows keys and values."""
         E, Q, KV = self.embed_dim, self.q_width, self.kv_width
-        gate = q_rows * q_dim * Q if self.output_gate else 0
+        V, O = self.v_width, self.o_width
+        gate = q_rows * q_dim * O if self.output_gate else 0
         if self.index:
             gate += q_rows * q_dim * (
                 (self.index.n_heads + 1) * self.index.head_dim
                 + self.index.n_heads)
         return 2.0 * batch * (q_rows * q_dim * Q + kv_rows * k_dim * KV
-                              + kv_rows * v_dim * KV + q_rows * Q * E + gate)
+                              + kv_rows * v_dim * V + q_rows * O * E + gate)
 
     def head_parallel_ok(self, degree: int) -> bool:
         return (self.num_heads % degree == 0 and self.kv_heads % degree == 0
@@ -369,20 +448,40 @@ def layer_norm(x, scale, bias, eps):
             + bias.astype(jnp.float32)).astype(x.dtype)
 
 
-def sdpa_xla(q, k, v, *, causal: bool, scale: float, mask=None):
+def softmax_with_sink(logits, sink=None):
+    """softmax over the last dimension; `sink`, where given and
+    broadcastable to logits[..., :1], is one more logit in the
+    denominator that gives no value. float32."""
+    if sink is None:
+        return jax.nn.softmax(logits, axis=-1)
+    sink = sink.astype(jnp.float32)
+    m = jnp.maximum(jnp.max(logits, axis=-1, keepdims=True), sink)
+    e = jnp.exp(logits - m)
+    return e / (jnp.sum(e, axis=-1, keepdims=True) + jnp.exp(sink - m))
+
+
+def sdpa_xla(q, k, v, *, causal: bool, scale: float, mask=None,
+             window: int = 0, sink=None):
     """Reference-semantics scaled dot-product attention, einsum form.
-    q,k,v: (batch, heads, seq, head_dim); `mask` (batch, seq, seq) bool,
-    where given, is the positions each row attends (a learned selection,
-    causal already)."""
+    q, k: (batch, heads, seq, head_dim), v: (batch, heads, seq, its own
+    head_dim); `mask` (batch, seq, seq) bool, where given, is the
+    positions each row attends (a learned selection, causal already);
+    `window` > 0 keeps a causal row's nearest `window` keys, its own among
+    them; `sink` (heads,) joins every row's denominator."""
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32)
     logits = logits * scale
     if mask is not None:
         logits = jnp.where(mask[:, None], logits, -1e30)
     elif causal:
         s_q, s_k = logits.shape[-2], logits.shape[-1]
-        mask = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
+        ones = jnp.ones((s_q, s_k), dtype=bool)
+        mask = jnp.tril(ones, k=s_k - s_q)
+        if window:
+            mask &= ~jnp.tril(ones, k=s_k - s_q - window)
         logits = jnp.where(mask, logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    probs = softmax_with_sink(
+        logits, None if sink is None else sink[None, :, None, None]
+    ).astype(q.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
@@ -409,15 +508,22 @@ def _mha_forward(p: MultiHeadAttentionParams, inputs, weights, state, ctx):
         with jax.named_scope("dsa.topk"):
             mask = causal_selection_mask(qi, wt, ki, front.index.topk)
         impl = "xla"
-    if group > 1 and impl != "xla":
+    if front.window and not p.causal:
+        raise NotImplementedError(
+            "attention with a window is causal self-attention")
+    if (group > 1 or not front.plain_core) and impl != "xla":
         # the packed and ring kernels select q, k and v heads by one lane
-        # offset: one head count. Grouped keys and values are repeated
-        # and take the einsum (no training cell runs such a layer)
+        # offset and attend a row's whole past: one head count, one head
+        # size, no band in their tile classes, no sink. Such a layer takes
+        # the einsum (no training cell runs one)
         from ..kernels.dispatch import warn_reference
 
         warn_reference("multihead_attention", tuple(q.shape),
-                       f"{front.kv_heads} KV heads under {H} query heads: "
-                       f"the {impl} kernels keep one head count")
+                       f"{front.kv_heads} KV heads of {front.head_dim} / "
+                       f"{front.v_head_dim} under {H} query heads, window "
+                       f"{front.window}, sink {front.sink}: the {impl} "
+                       f"kernels keep one head count and size and attend "
+                       f"the whole past")
         impl = "xla"
 
     if impl == "flash":
@@ -444,13 +550,14 @@ def _mha_forward(p: MultiHeadAttentionParams, inputs, weights, state, ctx):
             ctx.mesh, (spec, spec, spec), spec)(q, k, v)
         return [front.output(ctx, weights, out, inputs[0])], state
 
-    def split_heads(x):
-        b, s, _ = x.shape
-        x = x.reshape(b, s, -1, front.head_dim).transpose(0, 2, 1, 3)
+    def split_heads(x, heads):
+        b, s, e = x.shape
+        x = x.reshape(b, s, heads, e // heads).transpose(0, 2, 1, 3)
         # query head i reads KV head i // group
-        return x if x.shape[1] == H else jnp.repeat(x, group, axis=1)
+        return x if heads == H else jnp.repeat(x, group, axis=1)
 
-    q, k, v = split_heads(q), split_heads(k), split_heads(v)
+    q = split_heads(q, H)
+    k, v = split_heads(k, front.kv_heads), split_heads(v, front.kv_heads)
     if impl == "ring":
         from ..parallel.ring_attention import ring_attention
 
@@ -458,17 +565,20 @@ def _mha_forward(p: MultiHeadAttentionParams, inputs, weights, state, ctx):
                              mesh=ctx.mesh,
                              overlap=getattr(ctx, "overlap_collectives", True))
     else:
-        with jax.named_scope("gsa.attend" if front.index else "gqa.attend"):
-            out = sdpa_xla(q, k, v, causal=p.causal, scale=scale, mask=mask)
+        with jax.named_scope(front.attend_scope):
+            out = sdpa_xla(q, k, v, causal=p.causal, scale=scale, mask=mask,
+                           window=front.window,
+                           sink=weights["sink"] if front.sink else None)
     b, _, s, _ = out.shape
-    out = out.transpose(0, 2, 1, 3).reshape(b, s, front.q_width)
+    out = out.transpose(0, 2, 1, 3).reshape(b, s, front.o_width)
     return [front.output(ctx, weights, out, inputs[0])], state
 
 
 def _mha_flops(p: MultiHeadAttentionParams, in_shapes, out_shapes):
     q, k, v = in_shapes[:3]
     b, sq, sk = q[0], q[1], k[1]
-    attn = 2.0 * b * p.num_heads * sq * sk * p.front.head_dim * 2
+    attn = (2.0 * b * p.num_heads * sq * sk
+            * (p.front.head_dim + p.front.v_head_dim))
     if p.front.index:
         attn += (2.0 * b * sq * sk * p.front.index.n_heads
                  * p.front.index.head_dim)

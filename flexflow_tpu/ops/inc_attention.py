@@ -126,10 +126,11 @@ def _inc_mha_weights(p: IncMultiHeadAttentionParams, in_shapes):
     x = in_shapes[0]
     # the KV cache: stateful (non-trainable), zero-initialized, threaded
     # functionally through the executor's state dict like BatchNorm stats
-    cache = (x[0], p.max_seq_len + 1, p.front.kv_width)
     return p.front.weight_specs(x[-1], x[-1], x[-1]) + [
-        WeightSpec(name, cache, p.cache_dtype, "zeros", trainable=False)
-        for name in ("cache_k", "cache_v")]
+        WeightSpec(name, (x[0], p.max_seq_len + 1, width), p.cache_dtype,
+                   "zeros", trainable=False)
+        for name, width in (("cache_k", p.front.kv_width),
+                            ("cache_v", p.front.v_width))]
 
 
 def _inc_mha_forward(p: IncMultiHeadAttentionParams, inputs, weights,
@@ -158,15 +159,20 @@ def _inc_mha_forward(p: IncMultiHeadAttentionParams, inputs, weights,
     cv = cv.at[slot_idx, write_pos].set(vw.astype(cv.dtype))
 
     kv_heads = p.front.kv_heads
-    if kv_heads != H:
-        # the contiguous kernel keeps one head count (grouped keys and
-        # values are served by the paged kernel): the einsum repeats them
+    if kv_heads != H or not p.front.plain_core:
+        # the contiguous kernel keeps one head count and size and attends
+        # a row's whole past (grouped keys and values, a window, a sink
+        # are served by the paged kernel): the einsum repeats the heads
+        # and masks the band. The cache holds every row all the same: a
+        # window saves no memory in this layout
         from ..kernels.flash_attention import decode_attention_reference
 
-        with jax.named_scope("gqa.attend"):
+        with jax.named_scope(p.front.attend_scope):
             out = decode_attention_reference(
                 q, ck.astype(q.dtype), cv.astype(q.dtype), write_pos,
-                num_heads=H, scale=scale, num_kv_heads=kv_heads)
+                num_heads=H, scale=scale, num_kv_heads=kv_heads,
+                window=p.front.window,
+                sink=weights["sink"] if p.front.sink else None)
     elif _use_decode_kernel("inc_multihead_attention", p.impl, q.shape,
                             ctx):
         from ..kernels.flash_attention import flash_decode_attention
@@ -193,8 +199,10 @@ def _decode_flops(front: AttentionFrontEnd, x, cache_rows: int):
     against the full cache: the serving cost model prices the worst-case
     read, the kernels skip dead blocks at run time."""
     slots, q_len, d = x
+    if front.window:
+        cache_rows = min(cache_rows, front.window)
     attn = (2.0 * slots * front.num_heads * q_len * cache_rows
-            * front.head_dim * 2)
+            * (front.head_dim + front.v_head_dim))
     return front.linear_flops(slots, q_len, q_len, d, d, d) + attn
 
 
@@ -274,7 +282,7 @@ def paged_rows_run_kernel(p: PagedIncMultiHeadAttentionParams, mesh,
             and paged_decode_gate(
                 p.blocks_per_slot * p.block_size, p.block_size,
                 p.front.kv_width, p.front.kv_heads, itemsize,
-                jax.default_backend() != "tpu") is None)
+                jax.default_backend() != "tpu", p.front.v_width) is None)
 
 
 def _chunk_gate(p: PagedIncMultiHeadAttentionParams, b: int,
@@ -283,6 +291,11 @@ def _chunk_gate(p: PagedIncMultiHeadAttentionParams, b: int,
     cannot go through the multi-query chunk kernel, or None."""
     from ..kernels.flash_attention import paged_chunk_gate
 
+    if not p.front.plain_core:
+        return (f"key and value heads of {p.front.head_dim} / "
+                f"{p.front.v_head_dim}, window {p.front.window}, sink "
+                f"{p.front.sink}: the chunk kernel's head loop slices one "
+                f"head size and attends the whole past")
     return paged_chunk_gate(
         b, p.blocks_per_slot * p.block_size, p.block_size,
         p.num_heads * p.front.head_dim, p.front.kv_width, p.num_heads,
@@ -299,8 +312,13 @@ def paged_chunk_query_tile(p: PagedIncMultiHeadAttentionParams, mesh,
     chunk step reads by this (`kv_rows_walked`, `chunk_kernel_steps`)."""
     from ..kernels.flash_attention import _paged_chunk_query_tile
 
-    if (p.chunk_from is None or not paged_rows_run_kernel(p, mesh, itemsize)
-            or _chunk_gate(p, b, itemsize) is not None):
+    if p.chunk_from is None or not paged_rows_run_kernel(p, mesh, itemsize):
+        return None
+    if not p.front.plain_core:
+        # the tile loop in XLA reads the chunk's context once for all of
+        # its rows (kernels/flash_attention.paged_chunk_attention_tiled)
+        return b
+    if _chunk_gate(p, b, itemsize) is not None:
         return None
     return _paged_chunk_query_tile(b)[0]
 
@@ -342,7 +360,7 @@ def _paged_mha_forward(p: PagedIncMultiHeadAttentionParams, inputs, weights,
                        state, ctx):
     x, positions, page_table = inputs
     slots = x.shape[0]
-    H, E = p.num_heads, p.front.kv_width
+    H = p.num_heads
     kv_heads = p.front.kv_heads
     bs = p.block_size
     W = p.blocks_per_slot
@@ -370,44 +388,56 @@ def _paged_mha_forward(p: PagedIncMultiHeadAttentionParams, inputs, weights,
     pk = pk.at[phys, offset].set(kw.astype(pk.dtype))
     pv = pv.at[phys, offset].set(vw.astype(pv.dtype))
 
+    window = p.front.window
+    sink = weights["sink"] if p.front.sink else None
     if _use_decode_kernel("paged_inc_multihead_attention", p.impl, q.shape,
                           ctx):
         from ..kernels.flash_attention import (
-            paged_flash_chunk_attention, paged_flash_decode_attention,
+            paged_chunk_attention_tiled, paged_flash_chunk_attention,
+            paged_flash_decode_attention,
         )
 
         pools = pk.astype(q.dtype), pv.astype(q.dtype)
         lengths = jnp.where(live[:, 0], pos_c[:, 0] + 1, 0)
         kw = dict(num_heads=H, scale=scale, num_kv_heads=kv_heads)
+        if not p.front.plain_core:
+            kw.update(window=window, sink=sink)
         # the slots' rows, each under its own table row; where rows past
         # them are a chunk's and its kernel takes them, those under ONE
         # table row in one multi-query call (every row's K and V are in
-        # the pool by now), else the same single-query kernel, row by row
+        # the pool by now); a layer that kernel cannot tile (a window, a
+        # sink, key and value heads of two sizes) takes the chunk through
+        # the tile loop in XLA, which reads its context once as well;
+        # else the same single-query kernel, row by row
         n = slots if p.chunk_from is None else min(slots, p.chunk_from)
-        if n < slots and _chunk_gate(p, slots - n,
-                                     q.dtype.itemsize) is not None:
+        if (n < slots and p.front.plain_core
+                and _chunk_gate(p, slots - n, q.dtype.itemsize) is not None):
             n = slots
-        with jax.named_scope("gqa.attend"):
+        with jax.named_scope(p.front.attend_scope):
             out = paged_flash_decode_attention(
                 q[:n], *pools, page_table[:n], lengths[:n], **kw)
-            if n < slots:
+            if n < slots and p.front.plain_core:
                 out = jnp.concatenate([out, paged_flash_chunk_attention(
                     q[n:], *pools, page_table[n], lengths[n:], **kw)])
+            elif n < slots:
+                out = jnp.concatenate([out, paged_chunk_attention_tiled(
+                    q[n:], *pools, page_table[n], lengths[n:] - 1, **kw)])
     else:
         # reference path (CPU tier-1 + the kernel's numerics oracle):
         # gather each slot's logical cache view from the pool, then run
         # the SAME masked einsum as the contiguous op — token identity
         # between the layouts reduces to the gather being the identity
-        # on live rows
-        kc = pk[page_table].reshape(slots, W * bs, E).astype(q.dtype)
-        vc = pv[page_table].reshape(slots, W * bs, E).astype(q.dtype)
+        # on live rows (a window layer's table holds the scratch block
+        # where its blocks fell behind the window: masked rows)
+        kc = pk[page_table].reshape(slots, W * bs, -1).astype(q.dtype)
+        vc = pv[page_table].reshape(slots, W * bs, -1).astype(q.dtype)
         from ..kernels.flash_attention import decode_attention_reference
 
         read_pos = jnp.where(live, pos_c, -1)
-        with jax.named_scope("gqa.attend"):
+        with jax.named_scope(p.front.attend_scope):
             out = decode_attention_reference(
                 q, kc, vc, read_pos, num_heads=H, scale=scale,
-                num_kv_heads=kv_heads)
+                num_kv_heads=kv_heads, window=window, sink=sink)
     return [p.front.output(ctx, weights, out, x)], {"pool_k": pk,
                                                     "pool_v": pv}
 

@@ -100,6 +100,31 @@ def refuse_indexed(model, what: str):
             f"multi-token call")
 
 
+def window_layers(model) -> list:
+    """Names of the graph's attention layers that attend a window of
+    their past (training graph or decode graph): the serving cache's
+    window group (serving/paged.py)."""
+    return [l.name for l in model.layers
+            if l.op_type in (OT.OP_MULTIHEAD_ATTENTION,
+                             OT.OP_PAGED_INC_MULTIHEAD_ATTENTION,
+                             OT.OP_INC_MULTIHEAD_ATTENTION)
+            and l.params.front.window]
+
+
+def refuse_windowed(model, what: str):
+    """A window layer's pool holds a slot's window and nothing behind it:
+    a prompt's whole extent is not there to hand off, and a block freed
+    behind an advanced cursor is not there to rewind to. A graph with a
+    window group is refused, not served wrong."""
+    windowed = window_layers(model)
+    if windowed:
+        raise NotImplementedError(
+            f"{what} cannot serve a graph with window attention layers "
+            f"({windowed[0]}, ...): their cache group keeps a slot's "
+            f"window only, which is neither handed off whole nor rolled "
+            f"back")
+
+
 def slot_state_bytes(model, slots: int, at_rest: DataType) -> int:
     """Bytes the recurrent layers of a training graph keep for `slots`
     slots in its decode graph: priced beside the pool."""
@@ -149,6 +174,11 @@ class ServingSpec:
     # physical pool blocks incl. the reserved scratch block; 0 → sized
     # from the per-chip HBM budget, capped at contiguous capacity parity
     kv_num_blocks: int = 0
+    # the same for the window group's pool (the layers that attend a
+    # window: serving/paged.py); 0 → twice what the slots can hold at
+    # once, the other half for cached prefixes' windows. Ignored by a
+    # graph without such layers
+    kv_window_blocks: int = 0
     prefix_sharing: bool = True  # COW prompt-prefix reuse (paged only)
     # cross-request radix prefix cache (--serve-prefix-cache): cached
     # prompt blocks survive their residents under LRU eviction; None
@@ -204,26 +234,42 @@ def _decode_config(model, spec: ServingSpec):
 
 
 def resolve_pool_blocks(model, spec: ServingSpec, max_seq: int,
-                        at_rest: DataType) -> int:
-    """Physical block count for the paged pool (incl. the reserved scratch
-    block 0). spec.kv_num_blocks > 0 pins it; 0 sizes the pool from the
-    per-chip HBM budget — the machine model's chip capacity minus the
-    decode graph's non-pool footprint (the trained weights that transfer
-    by name, at the `at_rest` dtype the decode graph holds them in) —
-    capped at contiguous capacity parity (every slot can reach max_seq),
-    floored at one block per slot so the engine can always make
-    progress. A block is priced in `at_rest` too."""
+                        at_rest: DataType) -> tuple:
+    """(blocks of the global group's pool, blocks of the window group's),
+    each with its reserved scratch block 0; 0 window blocks where the graph
+    has no window layer. spec.kv_num_blocks / spec.kv_window_blocks > 0
+    pin them. The window group's default is twice what the slots can hold
+    at once (`paged.window_slot_blocks`), capped at capacity parity. The global
+    group's is sized from the per-chip HBM budget: the machine model's chip
+    capacity minus the decode graph's non-pool footprint (the trained
+    weights that transfer by name, at the `at_rest` dtype the decode graph
+    holds them in, and the window group's pool), capped at contiguous
+    capacity parity (every slot can reach max_seq), floored at one block
+    per slot so the engine can always make progress. A block is priced by
+    group: in every layer of the group, `bs` rows of each of that layer's
+    pool leaves, in `at_rest`."""
     bs = spec.kv_block_size
     if bs < 1:
         raise ValueError(f"kv_block_size must be >= 1, got {bs}")
     table_width = -(-max_seq // bs)
-    if spec.kv_num_blocks:
-        if spec.kv_num_blocks < 2:
-            raise ValueError(
-                f"kv_num_blocks must be >= 2 (scratch + 1), got "
-                f"{spec.kv_num_blocks}")
-        return spec.kv_num_blocks
     capacity = spec.slots * table_width + 1
+    for name in ("kv_num_blocks", "kv_window_blocks"):
+        if getattr(spec, name) and getattr(spec, name) < 2:
+            raise ValueError(
+                f"{name} must be >= 2 (scratch + 1), got "
+                f"{getattr(spec, name)}")
+    windowed = set(window_layers(model))
+    window_blocks = 0
+    if windowed:
+        window = max(l.params.front.window for l in model.layers
+                     if l.name in windowed)
+        from .paged import window_slot_blocks
+
+        window_blocks = spec.kv_window_blocks or min(
+            capacity, 2 * spec.slots * window_slot_blocks(
+                window, spec.prefill_chunk, bs) + 1)
+    if spec.kv_num_blocks:
+        return spec.kv_num_blocks, window_blocks
     try:
         import jax.numpy as jnp
 
@@ -235,20 +281,23 @@ def resolve_pool_blocks(model, spec: ServingSpec, max_seq: int,
             w.size * (itemsize if jnp.issubdtype(w.dtype, jnp.floating)
                       else w.dtype.itemsize)
             for ws in (model._params or {}).values() for w in ws.values())
-        # a block holds, in every cached layer, `bs` rows of each of
-        # that layer's pool leaves
-        block_bytes = sum(
-            bs * width * itemsize for l in model.layers
-            for width in cache_row_widths(l, table_width * bs).values())
-        if block_bytes <= 0:
-            return capacity
+
+        def block_bytes(group) -> int:
+            return sum(
+                bs * width * itemsize for l in model.layers
+                if (l.name in windowed) == group
+                for width in cache_row_widths(l, table_width * bs).values())
+
+        if block_bytes(False) <= 0:
+            return capacity, window_blocks
         budget = (0.9 * hbm - weight_bytes
+                  - window_blocks * block_bytes(True)
                   - slot_state_bytes(model, spec.slots, at_rest))
-        fit = int(budget // block_bytes)
-        return max(spec.slots + 1, min(capacity, fit))
+        fit = int(budget // block_bytes(False))
+        return max(spec.slots + 1, min(capacity, fit)), window_blocks
     except Exception:
         # no machine model / no params yet: capacity parity is always safe
-        return capacity
+        return capacity, window_blocks
 
 
 def infer_max_seq_len(model) -> int:
@@ -287,8 +336,9 @@ def build_decode_model(model, spec: ServingSpec):
     # the KV cache is declared so here), float32 otherwise. The trained
     # model's fp32 masters are another model's and stay as they are.
     at_rest = dec.config.computation_dtype or DataType.DT_FLOAT
-    num_blocks = (resolve_pool_blocks(model, spec, max_seq, at_rest)
-                  if paged else 0)
+    num_blocks, window_blocks = (
+        resolve_pool_blocks(model, spec, max_seq, at_rest) if paged
+        else (0, 0))
 
     # --- inputs: (batch, seq, ...) → (slots, 1, ...); the `positions`
     # input doubles as every attention layer's position feed
@@ -324,6 +374,14 @@ def build_decode_model(model, spec: ServingSpec):
         page_table = dec.create_tensor(
             (spec.slots, table_width), DataType.DT_INT32,
             create_grad=False, name="page_table")
+        # but a group of layers that keeps another extent of a slot's past
+        # has a pool size and a table of its own: the window group
+        # (serving/paged.py), same logical indexing
+        page_table_w = None
+        if window_blocks:
+            page_table_w = dec.create_tensor(
+                (spec.slots, table_width), DataType.DT_INT32,
+                create_grad=False, name="page_table_w")
 
     # --- layers, replayed name-for-name
     layer_map: dict[int, object] = {}  # train layer guid -> decode Layer
@@ -370,10 +428,12 @@ def build_decode_model(model, spec: ServingSpec):
                 op, np_, feeds = (
                     OT.OP_PAGED_INC_MULTIHEAD_ATTENTION,
                     PagedIncMultiHeadAttentionParams(
-                        p.front, max_seq, spec.kv_block_size, num_blocks,
+                        p.front, max_seq, spec.kv_block_size,
+                        window_blocks if p.front.window else num_blocks,
                         impl=spec.impl, cache_dtype=at_rest,
                         chunk_from=spec.slots),
-                    [ins[0], positions, page_table])
+                    [ins[0], positions,
+                     page_table_w if p.front.window else page_table])
             else:
                 op, np_, feeds = (
                     OT.OP_INC_MULTIHEAD_ATTENTION,

@@ -83,12 +83,16 @@ class DisaggregatedServingEngine:
                  **overrides):
         import jax
 
-        from .decode_graph import refuse_indexed, refuse_recurrent
+        from .decode_graph import (
+            refuse_indexed, refuse_recurrent, refuse_windowed,
+        )
 
         refuse_recurrent(model, "disaggregated serving (the handoff "
                           "carries pool blocks)")
         refuse_indexed(model, "serving/disagg.py: disaggregated serving "
                        "(the handoff carries pool_k and pool_v blocks)")
+        refuse_windowed(model, "serving/disagg.py: disaggregated serving "
+                        "(the handoff carries a prompt's whole extent)")
         cfg = model.config
         self.model = model
         self._total_chips = len(jax.devices())

@@ -103,7 +103,8 @@ from .. import telemetry
 from ..engine.chunking import plan_chunks
 from .decode_graph import (
     KV_LEAVES, STATE_LEAVES, ServingSpec, adopt_params, build_decode_model,
-    recurrent_layers, refuse_indexed, refuse_recurrent,
+    recurrent_layers, refuse_indexed, refuse_recurrent, refuse_windowed,
+    window_layers,
 )
 from .paged import SCRATCH_BLOCK, BlockManager
 from .scheduler import ContinuousBatchingScheduler, Request
@@ -248,17 +249,36 @@ class ServingEngine:
         self.block_manager = None
         self._copy_fn = None
         self._inject_fn = None  # lazily built KV-handoff landing pad
+        # the cache's groups (serving/paged.py): the window layers' pools
+        # are one group, every other paged layer's the global one
+        self._window_nodes = (window_layers(self.decode_model)
+                              if spec.kv_layout == "paged" else [])
+        self._copy_fn_w = None
+        self._block_bytes = (0, 0)
         if spec.kv_layout == "paged":
-            attn = next(
-                n for n in self.decode_model.graph.topo_order()
-                if n.op_type in PAGED_OPS)
-            p = attn.params
+            paged = [n for n in self.decode_model.graph.topo_order()
+                     if n.op_type in PAGED_OPS]
+            p = next((n for n in paged if n.name not in self._window_nodes),
+                     paged[0]).params
+            windowed = [n.params for n in paged
+                        if n.name in self._window_nodes]
             self.block_manager = BlockManager(
                 p.num_blocks, p.block_size, p.blocks_per_slot,
                 sharing=spec.prefix_sharing,
-                cross_time=bool(spec.prefix_cache))
-            self._copy_fn = (
-                self.decode_model.executor.build_block_copy())
+                cross_time=bool(spec.prefix_cache),
+                window_blocks=windowed[0].num_blocks if windowed else 0,
+                window=max((w.front.window for w in windowed), default=0),
+                window_span=spec.prefill_chunk)
+            self._build_copy_fns()
+            # bytes one block holds over the layers of (the global group,
+            # the window group), as the pools are stored
+            state = self.decode_model._state
+            self._block_bytes = tuple(
+                sum(int(leaf.nbytes) // leaf.shape[0]
+                    for n in paged if (n.name in self._window_nodes) == group
+                    for name, leaf in state[n.name].items()
+                    if name in KV_LEAVES)
+                for group in (False, True))
         self._kv_itemsize = at_rest["kv_stored_itemsize"]
         self._chunk_rows = self._rows_serve_chunks()
         self._chunk_tiles: dict[int, Optional[int]] = {}
@@ -285,7 +305,8 @@ class ServingEngine:
         self._token_input = None
         self._const_inputs = {}
         for t in self.decode_model._input_tensors:
-            if t.name in ("positions", "page_table", "state_slot"):
+            if t.name in ("positions", "page_table", "page_table_w",
+                          "state_slot"):
                 continue
             if hasattr(t, "constant_value"):
                 self._const_inputs[t.name] = (
@@ -393,6 +414,18 @@ class ServingEngine:
         self.replan_decisions: list[dict] = []
         if getattr(cfg, "elastic", False):
             self.enable_autoscale()
+
+    def _build_copy_fns(self):
+        """The donated copy-on-write programs, one a cache group: block
+        ids are a group's own (executor.build_block_copy)."""
+        ex = self.decode_model.executor
+        if not self._window_nodes:
+            self._copy_fn = ex.build_block_copy()
+            return
+        self._copy_fn = ex.build_block_copy(
+            skip=frozenset(self._window_nodes))
+        self._copy_fn_w = ex.build_block_copy(
+            only=frozenset(self._window_nodes))
 
     def _rows_serve_chunks(self) -> bool:
         """Whether a step that carries a prefill chunk is laid out as
@@ -526,7 +559,7 @@ class ServingEngine:
             self.max_seq_len = max_seq
             self._step_fn = new_dec.executor.build_decode_step()
             if self.block_manager is not None:
-                self._copy_fn = new_dec.executor.build_block_copy()
+                self._build_copy_fns()
             self._inject_fn = None  # rebuilt lazily on the new executor
             self._build_token_feed()
             self._chunk_rows = self._rows_serve_chunks()
@@ -616,6 +649,12 @@ class ServingEngine:
                     [mgr.table(i) for i in range(self.spec.slots)], np.int32)
                 xs["page_table"] = (table if row_slots is None
                                     else table[row_slots])
+                if mgr.window is not None:
+                    table = np.asarray(
+                        [mgr.window_table(i)
+                         for i in range(self.spec.slots)], np.int32)
+                    xs["page_table_w"] = (table if row_slots is None
+                                          else table[row_slots])
             if self._state_bytes_slot:
                 xs["state_slot"] = np.asarray(
                     np.arange(rows) if row_slots is None else row_slots,
@@ -775,25 +814,31 @@ class ServingEngine:
         self._copy_blocks(copies)
 
     def _copy_blocks(self, copies):
-        """Run this iteration's COW copies on the pool state in one
-        donated dispatch, padded to a power-of-two width with
-        scratch→scratch no-op pairs (one cached executable per bucket)."""
+        """Run this iteration's COW copies on the pool state, one donated
+        dispatch a cache group that has any, padded to a power-of-two width
+        with scratch→scratch no-op pairs (one cached executable per
+        bucket)."""
         if not copies:
             return
         import jax.numpy as jnp
 
-        b = 1
-        while b < len(copies):
-            b *= 2
-        src = np.full((b,), SCRATCH_BLOCK, np.int32)
-        dst = np.full((b,), SCRATCH_BLOCK, np.int32)
-        for i, c in enumerate(copies):
-            src[i], dst[i] = c.src, c.dst
         dec = self.decode_model
         self._c_cow_copies.inc(len(copies))
-        with telemetry.span("serve.cow_copy", blocks=len(copies)):
-            dec._state = self._copy_fn(
-                dec._state, jnp.asarray(src), jnp.asarray(dst))
+        for group, fn in ((0, self._copy_fn), (1, self._copy_fn_w)):
+            mine = [c for c in copies if c.group == group]
+            if not mine:
+                continue
+            b = 1
+            while b < len(mine):
+                b *= 2
+            src = np.full((b,), SCRATCH_BLOCK, np.int32)
+            dst = np.full((b,), SCRATCH_BLOCK, np.int32)
+            for i, c in enumerate(mine):
+                src[i], dst[i] = c.src, c.dst
+            with telemetry.span("serve.cow_copy", blocks=len(mine),
+                                group=group):
+                dec._state = fn(dec._state, jnp.asarray(src),
+                                jnp.asarray(dst))
 
     def _prepare_writes(self, slot_positions: dict[int, range], tag=None):
         """Paged pre-step bookkeeping: make every block this iteration
@@ -868,6 +913,8 @@ class ServingEngine:
         refuse_recurrent(self.decode_model, "extract_kv (the KV handoff)")
         refuse_indexed(self.decode_model,
                        "serving/engine.py: extract_kv (the KV handoff)")
+        refuse_windowed(self.decode_model,
+                        "serving/engine.py: extract_kv (the KV handoff)")
         self._complete_in_flight()
         mgr = self.block_manager
         nblk = -(-num_tokens // mgr.block_size)
@@ -899,6 +946,8 @@ class ServingEngine:
                          "admit_prefilled (the KV handoff)")
         refuse_indexed(self.decode_model, "serving/engine.py: "
                        "admit_prefilled (the KV handoff)")
+        refuse_windowed(self.decode_model, "serving/engine.py: "
+                        "admit_prefilled (the KV handoff)")
         self._complete_in_flight()
         if not sched.free_slots:
             return None
@@ -1206,6 +1255,15 @@ class ServingEngine:
                         admitted=len(admitted), pending=sched.queue_depth)
             if by_rows:
                 load.update(rows=rows, kv_rows_walked=int(kv_rows_walked))
+            if self._window_nodes:
+                # rows a window layer's attention reads: a row's window,
+                # or its context where that is shorter
+                window = self.block_manager.window.window
+                load.update(window_rows=int(
+                    sum(min(s.length + 1, window) for s in decoding)
+                    + (sum(min(t + 1, window)
+                           for t in range(start, start + n))
+                       if pre is not None else 0)))
             if self._sel_cap:
                 # a layer's indexer scores every cached row of every live
                 # row's context; its attention reads the selected ones.
@@ -1454,6 +1512,9 @@ class ServingEngine:
             # live blocks carry over — the measured window's peak must
             # still dominate what is resident when it opens
             fresh.blocks_in_use_peak = self.block_manager.blocks_in_use
+            if self.block_manager.window is not None:
+                fresh.window_blocks_in_use_peak = (
+                    self.block_manager.window.blocks_in_use)
             self.block_manager.stats = fresh
             # the eviction-delta poll restarts from the fresh counter
             self._evictions_seen = 0
@@ -1561,6 +1622,34 @@ class ServingEngine:
                     / max(1, mgr.stats.blocks_in_use_peak
                           * mgr.block_size)),
             })
+            # the cache by group (serving/paged.py): blocks a live slot
+            # or the prefix cache holds, their bytes over the group's
+            # layers, and the tokens whose global rows are held (whole
+            # blocks): `kv_pool_bytes` / `kv_cached_tokens` is what a
+            # held token costs, every layer counted
+            held = len(mgr._refcount)
+            w = mgr.window
+            out.update({
+                "kv_blocks_in_use": mgr.blocks_in_use,
+                "kv_blocks_held": held,
+                "kv_pool_bytes": (
+                    held * self._block_bytes[0]
+                    + (w.blocks_held * self._block_bytes[1] if w else 0)),
+                "kv_cached_tokens": held * mgr.block_size,
+            })
+            if w is not None:
+                out.update({
+                    "kv_window_pool_blocks": w.num_blocks,
+                    "kv_window_blocks_in_use": w.blocks_in_use,
+                    "kv_window_blocks_in_use_peak":
+                        mgr.stats.window_blocks_in_use_peak,
+                    "kv_window_blocks_held": w.blocks_held,
+                    "window_blocks_freed": mgr.stats.window_blocks_freed,
+                    "window_cow_copies": mgr.stats.window_cow_copies,
+                    "window_pins_dropped": mgr.stats.window_pins_dropped,
+                    "kv_window_pool_bytes":
+                        w.blocks_held * self._block_bytes[1],
+                })
         if ttfts:
             out["ttft_p50_s"] = float(np.percentile(np.asarray(ttfts), 50))
             out["ttft_max_s"] = float(max(ttfts))

@@ -41,6 +41,27 @@ reference; a block a live slot still maps merely leaves the cache.
 `cross_time=False` reproduces the old live-residents-only sharing (the
 pin is dropped as the last holder releases) — the bench ablation.
 
+**Groups.** Layers that attend a window of their past (ops/attention.
+AttentionFrontEnd.window) keep their rows in a pool of their own, the
+WINDOW GROUP (`WindowGroup`): its own size, free list and references, its
+own page table a slot (the same logical indexing: block j holds rows
+[j * block_size, (j + 1) * block_size)), and a slot holds there only the
+blocks its next rows can still read: those from the block of row
+`position - window + 1` on. What falls behind is unmapped as the slot
+advances, by a decode step and by a chunk step alike, and its table entry
+becomes the scratch block (the device op never reads behind the window).
+The GLOBAL GROUP is what this module was before there were groups. The
+radix cache is one: a cached node's block in the global group may have a
+window block pinned beside it (`BlockManager._wpins`), and a cached extent
+is usable up to a length only where the window group holds the blocks of
+the `window - 1` rows before that length: a continuation reads them, and
+nothing short of the whole forward over the prefix brings them back. A
+copy-on-write of a shared tail block copies it in both groups
+(`CopyPlan.group`). The window group reserves a slot's share at admission
+(`WindowGroup.slot_blocks`: the window and a step's rows, not prompt +
+new), and under pressure gives up the window blocks of the least recently
+used cached nodes, which shortens what can be matched there.
+
 Pure host code (no jax): unit-testable without a mesh.
 """
 
@@ -56,10 +77,13 @@ SCRATCH_BLOCK = 0
 @dataclass
 class CopyPlan:
     """One COW copy the engine must run on the pool state BEFORE the next
-    device step writes: physical block `src` duplicated into `dst`."""
+    device step writes: physical block `src` duplicated into `dst`, in the
+    pools of `group` (0: the global group's layers, 1: the window
+    group's)."""
 
     src: int
     dst: int
+    group: int = 0
 
 
 @dataclass
@@ -75,6 +99,14 @@ class PagedStats:
     #                                after its residents exited
     radix_evictions: int = 0       # nodes evicted (LRU or pin-drop)
     radix_evicted_blocks: int = 0  # blocks actually freed by eviction
+    # the window group (0 where the graph has none): peak blocks its live
+    # slots held, blocks unmapped because they fell behind a slot's
+    # window, copy-on-write copies there, cached nodes whose window block
+    # was given up under pressure
+    window_blocks_in_use_peak: int = 0
+    window_blocks_freed: int = 0
+    window_cow_copies: int = 0
+    window_pins_dropped: int = 0
 
     @property
     def prefix_hit_rate(self) -> float:
@@ -83,6 +115,182 @@ class PagedStats:
         if self.prompt_tokens == 0:
             return 0.0
         return self.shared_tokens / self.prompt_tokens
+
+
+def window_slot_blocks(window: int, span: int, block_size: int) -> int:
+    """Blocks of the window group a slot may hold at once: those of the
+    window before a step's first row and of its `span` rows, one more
+    where a block boundary falls inside, one for a copy-on-write in
+    flight."""
+    return -(-(window - 1 + max(1, span)) // block_size) + 2
+
+
+class WindowGroup:
+    """The pool of the layers that attend a window (module docstring):
+    free list, references (live mappings + cache pins) and a table a slot.
+    Policy that needs the radix cache (which cached nodes hold a window
+    block, whom to take one from) is the BlockManager's, which owns this
+    as `window`."""
+
+    def __init__(self, num_blocks: int, block_size: int, window: int,
+                 span: int, make_room):
+        if num_blocks < 2:
+            raise ValueError(
+                f"need >= 2 window blocks (scratch + 1), got {num_blocks}")
+        self.num_blocks = int(num_blocks)
+        self.block_size = int(block_size)
+        self.window = int(window)
+        self.slot_blocks = window_slot_blocks(self.window, int(span),
+                                              self.block_size)
+        self._free = list(range(self.num_blocks - 1, 0, -1))
+        self._refcount: dict[int, int] = {}   # live mappings + cache pins
+        self._mapped: dict[int, int] = {}     # live mappings alone
+        self._pinned: set[int] = set()
+        self._tables: dict[int, list[int]] = {}
+        self._reserved: dict = {}
+        self._make_room = make_room
+
+    @property
+    def free_blocks(self) -> int:
+        return len(self._free)
+
+    @property
+    def blocks_in_use(self) -> int:
+        """Blocks at least one live slot maps."""
+        return len(self._mapped)
+
+    @property
+    def blocks_held(self) -> int:
+        """Blocks a live slot maps or the cache pins."""
+        return len(self._refcount)
+
+    def first_block(self, position: int) -> int:
+        """The first logical block a row at `position` reads."""
+        return max(position - self.window + 1, 0) // self.block_size
+
+    def table(self, slot: int, width: int) -> list[int]:
+        t = self._tables.get(slot, [])
+        return t + [SCRATCH_BLOCK] * (width - len(t))
+
+    # reservations: a slot's share, whatever its prompt
+    def reserve(self, key) -> bool:
+        if ((len(self._reserved) + 1) * self.slot_blocks
+                > self.num_blocks - 1):
+            return False
+        self._reserved[key] = self.slot_blocks
+        return True
+
+    def bind_slot(self, key, slot: int):
+        """The reservation made under `key` is `slot`'s from now on."""
+        if key in self._reserved:
+            self._reserved[slot] = self._reserved.pop(key)
+
+    def _take(self, block: int):
+        self._refcount[block] = self._refcount.get(block, 0) + 1
+
+    def _drop(self, block: int):
+        n = self._refcount[block] - 1
+        if n == 0:
+            del self._refcount[block]
+            self._free.append(block)
+        else:
+            self._refcount[block] = n
+
+    def _map(self, block: int):
+        """One more live slot maps `block`."""
+        self._mapped[block] = self._mapped.get(block, 0) + 1
+        self._take(block)
+
+    def _unmap(self, block: int):
+        n = self._mapped[block] - 1
+        if n:
+            self._mapped[block] = n
+        else:
+            del self._mapped[block]
+        self._drop(block)
+
+    def pin(self, block: int):
+        self._pinned.add(block)
+        self._take(block)
+
+    def unpin(self, block: int):
+        self._pinned.discard(block)
+        self._drop(block)
+
+    def _alloc(self) -> int:
+        if not self._free:
+            self._make_room()
+        if not self._free:
+            raise RuntimeError(
+                "window-group KV pool exhausted: the admission reservations "
+                "(WindowGroup.reserve) must prevent this")
+        blk = self._free.pop()
+        self._map(blk)
+        return blk
+
+    def admit(self, slot: int, blocks: dict):
+        """Map `blocks` {logical block: cached window block} into the
+        slot's new table."""
+        table = [SCRATCH_BLOCK] * (max(blocks) + 1 if blocks else 0)
+        for lb, blk in blocks.items():
+            table[lb] = blk
+            self._map(blk)
+        self._tables[slot] = table
+
+    def ensure_writable(self, slot: int, positions, stats) -> list:
+        """As BlockManager.ensure_writable, for this group: first unmap
+        what the step's first row no longer reads, then own every block
+        the step writes."""
+        table = self._tables[slot]
+        bs = self.block_size
+        lo, hi = min(positions), max(positions)
+        for lb in range(min(self.first_block(lo), len(table))):
+            if table[lb] != SCRATCH_BLOCK:
+                self._unmap(table[lb])
+                table[lb] = SCRATCH_BLOCK
+                stats.window_blocks_freed += 1
+        copies = []
+        for lb in range(lo // bs, hi // bs + 1):
+            while len(table) <= lb:
+                table.append(SCRATCH_BLOCK)
+            blk = table[lb]
+            if blk == SCRATCH_BLOCK:
+                table[lb] = self._alloc()
+            elif self._refcount[blk] > 1:
+                fresh = self._alloc()
+                self._unmap(blk)
+                table[lb] = fresh
+                copies.append(CopyPlan(src=blk, dst=fresh, group=1))
+                stats.window_cow_copies += 1
+        stats.window_blocks_in_use_peak = max(
+            stats.window_blocks_in_use_peak, self.blocks_in_use)
+        return copies
+
+    def release(self, slot: int):
+        self._reserved.pop(slot, None)
+        for blk in self._tables.pop(slot, []):
+            if blk != SCRATCH_BLOCK:
+                self._unmap(blk)
+
+    def check_invariants(self):
+        free = set(self._free)
+        assert SCRATCH_BLOCK not in free | set(self._refcount)
+        assert not (free & set(self._refcount)), "block both free and held"
+        assert len(free) + len(self._refcount) == self.num_blocks - 1, \
+            "window pool accounting leak"
+        want: dict[int, int] = {}
+        for t in self._tables.values():
+            for b in t:
+                if b != SCRATCH_BLOCK:
+                    want[b] = want.get(b, 0) + 1
+        assert want == self._mapped, "window mappings drifted"
+        for b in self._pinned:
+            want[b] = want.get(b, 0) + 1
+        assert want == self._refcount, "window references drifted"
+        for slot, t in self._tables.items():
+            held = sum(b != SCRATCH_BLOCK for b in t)
+            assert held <= self.slot_blocks, \
+                f"slot {slot} holds {held} window blocks"
 
 
 class BlockManager:
@@ -95,7 +303,13 @@ class BlockManager:
     """
 
     def __init__(self, num_blocks: int, block_size: int, table_width: int,
-                 sharing: bool = True, cross_time: bool = False):
+                 sharing: bool = True, cross_time: bool = False,
+                 window_blocks: int = 0, window: int = 0,
+                 window_span: int = 1):
+        """`window_blocks` > 0: the graph has layers that attend a window
+        of `window` keys; their rows live in a window group of that many
+        blocks (module docstring), a step writing at most `window_span`
+        rows of a slot."""
         if num_blocks < 2:
             raise ValueError(
                 f"need >= 2 blocks (scratch + 1 allocatable), got "
@@ -126,6 +340,14 @@ class BlockManager:
         self._tables: dict[int, list[int]] = {}
         self.cache = RadixPrefixCache(block_size) if self.sharing else None
         self.stats = PagedStats()
+        self.window = None
+        # cached global block -> the window block pinned beside it, and
+        # the cache's clock when it was pinned
+        self._wpins: dict[int, int] = {}
+        self._wpin_tick: dict[int, int] = {}
+        if window_blocks:
+            self.window = WindowGroup(window_blocks, block_size, window,
+                                      window_span, self._drop_window_pin)
 
     # ------------------------------------------------------------ queries
 
@@ -157,6 +379,12 @@ class BlockManager:
         row the engine feeds the device op)."""
         t = self._tables.get(slot, [])
         return t + [SCRATCH_BLOCK] * (self.table_width - len(t))
+
+    def window_table(self, slot: int) -> list[int]:
+        """The slot's page table in the window group, padded like
+        `table`: the scratch block wherever the slot holds nothing (what
+        fell behind its window, what it has not reached)."""
+        return self.window.table(slot, self.table_width)
 
     def _pinned(self, block: int) -> bool:
         return self.cache is not None and block in self.cache.pinned
@@ -202,14 +430,17 @@ class BlockManager:
         needed = self.blocks_needed(prompt_len, max_new_tokens)
         held = []
         if prompt is not None and self.cache is not None and self.cross_time:
-            covered, blocks = self.cache.match(prompt, peek=True)
+            covered, blocks = self._usable(
+                prompt, *self.cache.match(prompt, peek=True))
             needed -= min(covered, prompt_len - 1) // self.block_size
             held = blocks  # the tail it will copy on its first write too
         self._held[("req", request_id)] = held
         headroom = self.free_blocks - self.reserved_total
         if headroom < needed:
             self._evict_blocks(needed - headroom)
-        if self.free_blocks - self.reserved_total < needed:
+        if (self.free_blocks - self.reserved_total < needed
+                or (self.window is not None
+                    and not self.window.reserve(("req", request_id)))):
             del self._held[("req", request_id)]
             return False
         self._reserved[("req", request_id)] = needed
@@ -224,6 +455,8 @@ class BlockManager:
         held = self._held.pop(("req", request_id), None)
         if held:
             self._held[slot] = held
+        if self.window is not None:
+            self.window.bind_slot(("req", request_id), slot)
 
     # --------------------------------------------------------- refcounts
 
@@ -270,6 +503,7 @@ class BlockManager:
                 keep=held)
             if blk is None:
                 break
+            self._unpin_window(blk)
             self._unpin_free(blk)
             self.stats.radix_evictions += 1
             if len(self._free) > before:
@@ -277,14 +511,74 @@ class BlockManager:
                 self.stats.radix_evicted_blocks += 1
         return freed
 
+    # ------------------------------------------------------ window group
+
+    def _unpin_window(self, block: int):
+        """The cached node of global `block` leaves the cache: so does the
+        window block pinned beside it."""
+        wblk = self._wpins.pop(block, None)
+        if wblk is not None:
+            del self._wpin_tick[block]
+            self.window.unpin(wblk)
+
+    def _drop_window_pin(self):
+        """The window group has no free block: a cached node whose window
+        block nothing else holds gives it up, first those no prompt was
+        matched through since they were pinned (a finished request's
+        question, which nothing can match again), leaves before their
+        parents, the least recently used first; then the others likewise.
+        A leaf that nothing else holds leaves the cache with it (a prompt
+        matched into it could not be continued from there, and a sibling
+        that can be would lose the match to it); any other node stays (its
+        global block still encodes its rows), and a prompt is matched
+        through it only as far as `_usable` allows."""
+        held = {b for blocks in self._held.values() for b in blocks}
+        node = min(
+            (self.cache.pinned[g] for g, w in self._wpins.items()
+             if self.window._refcount.get(w, 0) == 1 and g not in held),
+            key=lambda n: (n.last_used > self._wpin_tick[n.block],
+                           bool(n.children), n.last_used), default=None)
+        if node is None:
+            return
+        self._unpin_window(node.block)
+        self.stats.window_pins_dropped += 1
+        if not node.children and self._refcount.get(node.block, 0) == 1:
+            self.cache.drop_block(node.block)
+            self._unpin_free(node.block)
+            self.stats.radix_evictions += 1
+            self.stats.radix_evicted_blocks += 1
+
+    def _usable(self, prompt, covered: int, blocks: list):
+        """(covered, blocks) of a match cut back to the longest extent at
+        which every group holds what a continuation needs: the global
+        group every block (what `blocks` is), the window group the blocks
+        of the `window - 1` rows before that length. Tried: the whole
+        extent (less the prompt's last token, which is always computed),
+        then every block boundary under it."""
+        w = self.window
+        if w is None or not covered:
+            return covered, blocks
+        bs = self.block_size
+        at = min(covered, len(prompt) - 1)
+        for upto in (at, *range((at - 1) // bs * bs, 0, -bs)):
+            if upto > 0 and all(
+                    blocks[lb] in self._wpins
+                    for lb in range(w.first_block(upto),
+                                    (upto - 1) // bs + 1)):
+                if upto == at:
+                    return covered, blocks
+                return upto, blocks[:upto // bs]  # a block boundary
+        return 0, []
+
     # ------------------------------------------------------------ intake
 
     def match_prefix(self, prompt) -> int:
         """Covered token count of the longest cached extent of `prompt`
-        (a pure peek: no stats, no LRU touch)."""
+        that every group can continue from (a pure peek: no stats, no LRU
+        touch)."""
         if self.cache is None:
             return 0
-        return self.cache.match(prompt, peek=True)[0]
+        return self._usable(prompt, *self.cache.match(prompt, peek=True))[0]
 
     def admit(self, slot: int, prompt: list[int]) -> int:
         """Build `slot`'s page table: map every block of the longest
@@ -302,7 +596,7 @@ class BlockManager:
         L = len(prompt)
         self.stats.prefix_queries += 1
         if self.cache is not None:
-            covered, blocks = self.cache.match(prompt)
+            covered, blocks = self._usable(prompt, *self.cache.match(prompt))
         else:
             covered, blocks = 0, []
         # a matched block with no live holder was served across time —
@@ -317,6 +611,12 @@ class BlockManager:
         self._tables[slot] = table
         self._held.pop(slot, None)  # mapped now: a live reference holds them
         skip = min(covered, L - 1)
+        if self.window is not None:
+            self.window.admit(slot, {
+                lb: self._wpins[blocks[lb]]
+                for lb in range(self.window.first_block(skip),
+                                (skip - 1) // self.block_size + 1)
+                if skip > 0})
         self.stats.prompt_tokens += L
         self.stats.shared_tokens += skip
         if skip:
@@ -377,6 +677,9 @@ class BlockManager:
                 copies.append(CopyPlan(src=blk, dst=fresh))
                 self.stats.cow_copies += 1
                 self._maybe_drop_cached(blk)
+        if self.window is not None:
+            copies += self.window.ensure_writable(slot, positions,
+                                                  self.stats)
         return copies
 
     def register_prompt(self, slot: int, prompt: list[int]):
@@ -390,6 +693,18 @@ class BlockManager:
         table = self._tables.get(slot, [])
         for blk in self.cache.insert(prompt, table):
             self._refcount[blk] = self._refcount.get(blk, 0) + 1
+        if self.window is not None:
+            # the window blocks the slot holds at its prompt's end go
+            # beside the nodes of the same rows (new ones and incumbents
+            # that had none): the extent is matchable where they are
+            wtable = self.window.table(slot, self.table_width)
+            for lb, node in enumerate(self.cache.path(prompt)):
+                wblk = wtable[lb]
+                if (wblk != SCRATCH_BLOCK and node.block not in self._wpins
+                        and wblk not in self.window._pinned):
+                    self._wpins[node.block] = wblk
+                    self._wpin_tick[node.block] = node.last_used
+                    self.window.pin(wblk)
 
     # ------------------------------------------------------------ release
 
@@ -402,6 +717,8 @@ class BlockManager:
         live-residents-only semantics)."""
         self._reserved.pop(slot, None)
         self._held.pop(slot, None)
+        if self.window is not None:
+            self.window.release(slot)
         table = self._tables.pop(slot, None)
         if table is None:
             return
@@ -419,6 +736,7 @@ class BlockManager:
             self.cache.drop_block(block)
             self.stats.radix_evictions += 1
             self.stats.radix_evicted_blocks += 1
+            self._unpin_window(block)
             self._unpin_free(block)
 
     def check_invariants(self):
@@ -450,3 +768,8 @@ class BlockManager:
             if not self.cross_time:
                 assert self.cached_only_blocks == 0, \
                     "cross_time off but cache retains resident-free blocks"
+        if self.window is not None:
+            self.window.check_invariants()
+            assert set(self._wpins.values()) == self.window._pinned
+            assert all(g in self.cache.pinned for g in self._wpins), \
+                "window block pinned beside a node that left the cache"

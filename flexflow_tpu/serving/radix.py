@@ -125,6 +125,21 @@ class RadixPrefixCache:
             node = best
         return covered, blocks
 
+    def path(self, prompt) -> list:
+        """The nodes whose runs are exactly `prompt`'s blocks, in logical
+        order, as far as the tree holds them: what `insert(prompt, ..)`
+        made or found (a node of a longer run that merely starts like the
+        prompt's partial tail is another entry and is not among them)."""
+        bs = self.block_size
+        node, out = self.root, []
+        for lo in range(0, len(prompt), bs):
+            run = tuple(prompt[lo:lo + bs])
+            node = next((c for c in node.children if c.run == run), None)
+            if node is None:
+                break
+            out.append(node)
+        return out
+
     # ------------------------------------------------------------ insert
 
     def insert(self, prompt, table) -> list[int]:

@@ -351,12 +351,17 @@ class SpeculativeServingEngine(ServingEngine):
             raise ValueError(
                 "serve(speculate=True) needs draft_model=<a compiled "
                 "FFModel sharing the target's tokenizer/vocab>")
-        from .decode_graph import refuse_indexed, refuse_recurrent
+        from .decode_graph import (
+            refuse_indexed, refuse_recurrent, refuse_windowed,
+        )
 
         refuse_recurrent(model, "speculative decoding (rejected "
                           "proposals are undone by rewinding a cursor)")
         refuse_indexed(model, "serving/speculative.py: speculative "
                        "decoding (a verify call is q_len = K + 1)")
+        refuse_windowed(model, "serving/speculative.py: speculative "
+                        "decoding (a block freed behind the proposals' "
+                        "cursor cannot be rolled back)")
         cfg = model.config
         if draft_chips is None:
             draft_chips = int(getattr(cfg, "serve_draft_chips", 0) or 0)

@@ -316,6 +316,47 @@ def test_selected_grouped_attention_at_keye_vl2_widths(tpu, rows):
     assert not re.findall(r"= bf16\[1440,256,\d+\]\S* copy\(", text)
 
 
+@pytest.mark.parametrize("rows", [32, 32 + 256])
+@pytest.mark.parametrize("kind", ["global", "window"])
+def test_paged_attention_at_mimo_v2_flash_widths(tpu, kind, rows):
+    """A layer of `mimo2f-serve-longdoc` as the decode graph runs it: 64
+    query heads of 192 over 4 (global) or 8 (window) KV heads, values of
+    128, RoPE on 64 lanes, pools of 5,400 (512) blocks of 128 rows, keys
+    768 (1,536) and values 512 (1,024) wide, page tables 262 wide; 32
+    decoding rows through the single-query kernel (grouped; key heads of
+    192 through `spread`; the window walk and the sink named apart), and
+    the same with a chunk of 256 riding as rows, which the tile loop in
+    XLA takes. No step copies a pool."""
+    import re
+
+    from flexflow_tpu.ops.attention import AttentionFrontEnd
+
+    s = _on(tpu[0])
+    window = kind == "window"
+    front = AttentionFrontEnd(
+        4096, 64, use_bias=False, rope_theta=1e4 if window else 5e6,
+        num_kv_heads=8 if window else 4, head_size=192, v_head_size=128,
+        rope_dim=64, window=128 if window else 0, sink=window,
+        value_scale=0.707)
+    blocks = 512 if window else 5400
+    p, op, layer = _paged_attention_layer(front, 33536, 128, blocks,
+                                          slots=32)
+    kv = front.kv_heads
+    assert p.cache_row_widths == {"pool_k": kv * 192, "pool_v": kv * 128}
+    specs = op.weights(p, [(rows, 1, 4096), (rows, 1), (rows, 262)])
+    weights = {w.name: s(w.shape, jnp.float32 if w.name == "sink"
+                         else jnp.bfloat16) for w in specs}
+    compiled = jax.jit(layer, donate_argnums=(0,)).lower(
+        weights, s((rows, 1, 4096)), s((rows, 1), jnp.int32),
+        s((rows, 262), jnp.int32)).compile()
+    text = compiled.as_text()
+    name = ("flash_attention_paged_decode_window_grouped" if window
+            else "flash_attention_paged_decode_grouped")
+    assert pallas_kernels(text) == {name: 1}
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+    assert not re.findall(rf"= bf16\[{blocks},128,\d+\]\S* copy\(", text)
+
+
 @pytest.mark.parametrize("chunk", [128, 16])
 def test_paged_chunk_kernel_at_c13b_widths(tpu, chunk):
     """`c13b-serve-chat`'s chunk step as the decode graph runs a layer of

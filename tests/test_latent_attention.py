@@ -446,7 +446,7 @@ def test_pool_blocks_are_priced_from_the_layers_own_rows(monkeypatch):
         Chip.hbm_bytes = (weights + hbm) / 0.9
         spec = decode_graph.ServingSpec(slots=2, kv_block_size=4)
         return decode_graph.resolve_pool_blocks(ff, spec, 4000,
-                                                DataType.DT_FLOAT)
+                                                DataType.DT_FLOAT)[0]
 
     latent = build()
     # 3 layers x 4 rows x (128 + 16) numbers x 4 bytes a block
