@@ -9,10 +9,11 @@ A slot's row gathers its selected rows of the pool (`attend_selected`); a
 chunk's rows run dense over their shared context under the selection as a
 mask (`attend_chunk`), as the latent pair of
 kernels/sparse_latent_attention.py does. Query head i reads KV head
-i // (heads // kv_heads). jax.numpy and `lax` only: there is no Pallas
-kernel here yet (tests/test_keye_vl2.py holds these functions to the
-float32 reference and, where the selection is everything, to the grouped
-paged kernels).
+i // (heads // kv_heads). jax.numpy and `lax` only: the one Pallas kernel
+of the selection is the decoding rows' indexer (sparse_selection.
+paged_index_scores); the row gather here is XLA's (tests/test_keye_vl2.py
+holds these functions to the float32 reference and, where the selection is
+everything, to the grouped paged kernels).
 """
 
 from __future__ import annotations

@@ -7,8 +7,10 @@ its old names.
 
 A slot's row gathers its selected rows of `pool_c`; a chunk's rows run
 dense over their shared context under the selection as a mask
-(`attend_chunk`). jax.numpy and `lax` only: there is no Pallas kernel here
-yet (tests/test_latent_attention.py holds these functions to the float32
+(`attend_chunk`). jax.numpy and `lax` only: the one Pallas kernel of the
+selection is the decoding rows' indexer (sparse_selection.
+paged_index_scores); the row gather here is XLA's
+(tests/test_latent_attention.py holds these functions to the float32
 reference).
 """
 

@@ -17,16 +17,28 @@ A chunk's rows take no top-k and no gather: their k-th largest score is
 found by bisection and the selection is a mask (`selection_mask`), under
 which the attention runs dense over the shared context.
 
-All of it is jax.numpy and `lax` (XLA's gather, matmul, TopK): there is no
-Pallas kernel here yet. The contract a kernel would have to keep is these
-functions' (tests/test_latent_attention.py and tests/test_keye_vl2.py hold
-them to the float32 references).
+What is a kernel: the decoding rows' scores, `paged_index_scores`, a Pallas
+kernel that walks a row's live pages by DMA and scores them in VMEM
+(`index_scores_rows` takes it on one TPU where `paged_index_gate` passes,
+and XLA's gather and einsum elsewhere; tests/test_paged_index_scores.py
+holds the two to each other). What is still jax.numpy and `lax`: a chunk's
+scores (`index_scores_chunk`: XLA's gather and matmul in a `fori_loop`),
+the top-k (`lax.top_k`, a full sort at k = 2,048), the chunk's mask, and
+the selected rows' gather in the two attention modules. The contract a
+kernel has to keep is these functions' (tests/test_latent_attention.py
+and tests/test_keye_vl2.py hold them to the float32 references).
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .dispatch import warn_reference
 
 NEG = -1e30
 # rows of keys a chunk scores at a time: (chunk rows, indexer heads, this)
@@ -42,16 +54,226 @@ def _weighted_relu(q, w, k):
     return jnp.sum(w[:, :, None] * jax.nn.relu(scores), axis=1)
 
 
-def index_scores_rows(qi, wt, pool_i, page_table, positions):
-    """Index scores (rows, S) of rows that each walk their own page-table
-    row (rows, W), S = W x block: NEG past a row's position and for a dead
-    row (position < 0)."""
+def index_scores_rows_reference(qi, wt, pool_i, page_table, positions):
+    """`index_scores_rows` as XLA ops: every page of a table row gathered,
+    live or not, then one einsum. The CPU serving path and the kernel's
+    oracle."""
     rows, W = page_table.shape
     bs, d = pool_i.shape[1], pool_i.shape[2]
     keys = pool_i[page_table].reshape(rows, W * bs, d)
     index = _weighted_relu(qi, wt, keys.astype(qi.dtype))
     seen = jnp.arange(W * bs)[None, :] <= positions[:, None]
     return jnp.where(seen, index, NEG)
+
+
+# ------------------------------------------------- the paged indexer
+# One grid step a row, as the paged decode kernel walks (flash_attention.py,
+# `_paged_decode_kernel`): the page table and the rows' lengths are scalar-
+# prefetched, the pool stays in HBM, and the body copies the row's LIVE
+# pages, whole pool rows of (block, lanes), a round of 4,096 keys at a time
+# into one of two VMEM buffers, the next round in flight while this one is
+# scored: (heads, d) @ (d, keys) on the MXU, ReLU, the heads' weights and
+# their sum in float32, NEG behind the row's position, one store into the
+# row's output. A page past the length is never a DMA; its scores are the
+# NEG the output is filled with before the walk. The grid runs in order and
+# a row's last round starts the next row's first, so only the call's first
+# round waits for HBM with nothing to score. What this replaced gathered all
+# W pages of every table row, wrote them out and read them back for the
+# einsum: 3.26 ms for six layers of 16 rows at the cells' contexts against
+# 0.72, 75 % of HBM speed for the bytes read (rounds of 2,048 keys 0.75, of
+# 1,024 0.97; without the hand-over between rows 0.78: PERF.md section 6,
+# PR 42).
+
+_INDEX_ROUND_ROWS = 4096  # keys a DMA round: 1 MB of 128-lane bf16 rows
+# two rounds' keys and the row's scores (a (1, S) float32 block lies one row
+# to a tile of 8 sublanes at worst, and the pipeline keeps two) may take
+# this much of the 16 MiB a Mosaic kernel gets by default; the rest is the
+# compiler's: a round's (heads, keys) float32 scores
+_INDEX_VMEM = 8 << 20
+
+
+def _index_round_pages(width: int, block_size: int) -> int:
+    return max(1, min(width, _INDEX_ROUND_ROWS // block_size))
+
+
+def _paged_index_kernel(tbl_ref, len_ref, q_ref, w_ref, pool_hbm, o_ref,
+                        k_buf, sem, base_ref):
+    """One row's walk (the section comment above). q_ref (1, heads, d),
+    w_ref (1, heads, 1) float32, o_ref (1, 1, S) float32; k_buf (2, keys a
+    round, d), one DMA semaphore a buffer, and `base_ref`, the buffer the
+    row's first round lies in, carried from grid step to grid step."""
+    r = pl.program_id(0)
+    rows, width = tbl_ref.shape
+    bs = pool_hbm.shape[1]
+    span = k_buf.shape[1]
+    pages = span // bs
+    length = len_ref[r]
+
+    def live_pages(row):
+        return pl.cdiv(len_ref[row], bs)
+
+    def rounds(row):
+        return pl.cdiv(live_pages(row), pages)
+
+    n_rounds = rounds(r)
+
+    def first_page(c):
+        # a width the round does not divide: its last round ends at the
+        # table's end and scores a few pages again, rather than run past it
+        return jnp.minimum(c * pages, width - pages)
+
+    def copies(row, c, buf, act: str):
+        """Start, or wait for, the DMAs of `row`'s round c: its live pages
+        only (a loop, not `pages` copies of the body: the kernel is lowered
+        once a layer in every bucket program)."""
+        p0 = first_page(c)
+
+        @pl.loop(0, jnp.minimum(live_pages(row) - p0, pages))
+        def _page(p):
+            dma = pltpu.make_async_copy(
+                pool_hbm.at[tbl_ref[row, p0 + p]],
+                k_buf.at[buf, pl.ds(pl.multiple_of(p * bs, bs), bs)],
+                sem.at[buf])
+            getattr(dma, act)()
+
+    # a row's first round is started by the row before it, beside that
+    # row's last round (the grid runs in order): the buffers alternate over
+    # the whole call, and `base` is the one this row's round 0 lies in
+    @pl.when(r == 0)
+    def _origin():
+        base_ref[0] = 0
+
+    base = base_ref[0]
+    nxt = jnp.minimum(r + 1, rows - 1)
+    has_next = (r + 1 < rows) & (rounds(nxt) > 0)
+
+    @pl.when((r == 0) & (n_rounds > 0))
+    def _first():
+        copies(r, 0, base, "start")
+
+    o_ref[...] = jnp.full(o_ref.shape, NEG, o_ref.dtype)
+    q = q_ref[0]   # (heads, d)
+    w = w_ref[0]   # (heads, 1) float32
+
+    @pl.loop(0, n_rounds)
+    def _round(c):
+        buf = (base + c) % 2
+
+        @pl.when(c + 1 < n_rounds)
+        def _next():
+            copies(r, c + 1, 1 - buf, "start")
+
+        @pl.when((c + 1 == n_rounds) & has_next)
+        def _next_row():
+            copies(nxt, 0, 1 - buf, "start")
+
+        copies(r, c, buf, "wait")
+        # rows of a page this round did not copy hold what the buffer held
+        # (anything): a key's score is its own column, and theirs are masked
+        scores = jax.lax.dot_general(
+            q, k_buf[buf].astype(q.dtype), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)  # (heads, span)
+        index = jnp.sum(jax.nn.relu(scores) * w, axis=0, keepdims=True)
+        first = pl.multiple_of(first_page(c) * bs, bs)
+        key_pos = jax.lax.broadcasted_iota(jnp.int32, index.shape, 1) + first
+        o_ref[0, :, pl.ds(first, span)] = jnp.where(key_pos < length, index,
+                                                    NEG)
+
+    @pl.when((n_rounds == 0) & has_next)
+    def _dead_row():  # no last round to start the next row's beside
+        copies(nxt, 0, base, "start")
+
+    base_ref[0] = (base + n_rounds) % 2
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _paged_index_call(table, lengths, qi, wt, pool_i, *, interpret: bool):
+    """The kernel launch (shapes already gated), jitted for the memory
+    space constraint as `flash_attention._paged_decode_call` is."""
+    rows, heads, d = qi.shape
+    W = table.shape[1]
+    bs = pool_i.shape[1]
+    span = _index_round_pages(W, bs) * bs
+    if not interpret:
+        # else XLA may park the pool in VMEM (PERF.md section 6, PR 26)
+        pool_i = pltpu.with_memory_space_constraint(pool_i, pltpu.HBM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(rows,),
+        in_specs=[
+            pl.BlockSpec((1, heads, d), lambda r, tbl, ln: (r, 0, 0)),
+            pl.BlockSpec((1, heads, 1), lambda r, tbl, ln: (r, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.HBM),
+        ],
+        out_specs=pl.BlockSpec((1, 1, W * bs), lambda r, tbl, ln: (r, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, span, pool_i.shape[2]), pool_i.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    return pl.pallas_call(
+        _paged_index_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows, 1, W * bs), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_index_scores",
+    )(table, lengths, qi, wt.astype(jnp.float32)[:, :, None],
+      pool_i).reshape(rows, W * bs)
+
+
+def paged_index_gate(width: int, block_size: int, lanes: int,
+                     itemsize: int) -> str | None:
+    """Why the paged indexer kernel cannot take a pool of this geometry on
+    a TPU, or None where it can: a block must be whole (sublane, lane)
+    tiles in the round's buffer and its scores whole lane tiles of the
+    row's output, the pool's row whole 128-lane tiles, and two rounds of
+    keys beside the row's scores must sit in VMEM. Nothing here depends on
+    how many rows a call has."""
+    if block_size % 128 != 0:
+        return (f"block_size {block_size} % 128 != 0: a page's scores are "
+                f"no whole lane tiles")
+    if lanes % 128 != 0:
+        return f"indexer key rows of {lanes} lanes: no whole 128-lane tiles"
+    span = _index_round_pages(width, block_size) * block_size
+    need = 2 * span * lanes * itemsize + 2 * 8 * width * block_size * 4
+    if need > _INDEX_VMEM:
+        return (f"two rounds of {span} keys x {lanes} lanes and a row's "
+                f"{width * block_size} scores take {need} bytes of VMEM > "
+                f"{_INDEX_VMEM}")
+    return None
+
+
+def paged_index_scores(qi, wt, pool_i, page_table, positions):
+    """`index_scores_rows` by the Pallas kernel, whatever the backend
+    (interpret mode off a TPU: the kernel's own tests)."""
+    W, bs = page_table.shape[1], pool_i.shape[1]
+    lengths = jnp.clip(positions.astype(jnp.int32) + 1, 0, W * bs)
+    return _paged_index_call(
+        page_table.astype(jnp.int32), lengths, qi, wt, pool_i,
+        interpret=jax.default_backend() != "tpu")
+
+
+def index_scores_rows(qi, wt, pool_i, page_table, positions,
+                      call_gate: str | None = None):
+    """Index scores (rows, S) of rows that each walk their own page-table
+    row (rows, W), S = W x block: NEG past a row's position and for a dead
+    row (position < 0). qi (rows, heads, d), wt (rows, heads), pool_i
+    (blocks, block, d). On a TPU the paged kernel, where the call can have
+    one (`call_gate`: why it cannot, as the op's `_call_gate` says for a
+    multi-device mesh) and `paged_index_gate` passes; a geometry it refuses
+    takes the XLA form and says so. Off a TPU the XLA form, as the serving
+    path does for the paged decode kernel."""
+    if jax.default_backend() == "tpu":
+        gate = call_gate or paged_index_gate(
+            page_table.shape[1], pool_i.shape[1], pool_i.shape[2],
+            pool_i.dtype.itemsize)
+        if gate is None:
+            return paged_index_scores(qi, wt, pool_i, page_table, positions)
+        warn_reference("paged_index_scores", (qi.shape, pool_i.shape), gate)
+    return index_scores_rows_reference(qi, wt, pool_i, page_table, positions)
 
 
 def _chunk_blocks(table, bs: int, positions):

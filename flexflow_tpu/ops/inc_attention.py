@@ -54,8 +54,10 @@ learned selection (`_paged_selected_forward`): the pool's row is a
 token's keys and values side by side (`pool_kv`) beside the indexer's key
 (`pool_i`), every row scores its cached indexer keys and attends the
 `topk` largest only, in ops/latent_attention.py's rows layout
-(kernels/sparse_selection.py, kernels/sparse_grouped_attention.py; XLA's
-gather, matmul and sort, no Pallas kernel yet).
+(kernels/sparse_selection.py, kernels/sparse_grouped_attention.py: a
+slot's row scores its keys in the paged indexer kernel, `paged_index_scores`,
+on one TPU; a chunk's scores, the top-k and the selected rows' gather are
+XLA's matmul, sort and gather).
 """
 
 from __future__ import annotations
@@ -486,7 +488,8 @@ def _paged_selected_forward(p: PagedIncMultiHeadAttentionParams, x,
     chunk = rows > n  # the slots' rows come first; the rest is one chunk
     with jax.named_scope("dsa.index"):
         index = sel.index_scores_rows(qi[:n], wt[:n], pool_i,
-                                      page_table[:n], pos[:n])
+                                      page_table[:n], pos[:n],
+                                      call_gate=_call_gate(1, ctx.mesh))
         if chunk:
             index_c = sel.index_scores_chunk(qi[n:], wt[n:], pool_i,
                                              page_table[n], pos[n:])

@@ -20,9 +20,11 @@ models/deepseek_v32_reference.py.
   every layer shares. W_uk is folded into the query and W_uv applied after
   the weighted sum, so no per-head key or value is ever made. Each row
   scores its cached indexer keys and attends the index_topk largest only:
-  a slot's row gathers those rows of `pool_c`, a chunk's rows run dense
-  over their shared context under the selection as a mask
-  (kernels/sparse_latent_attention.py).
+  a slot's row scores them in the paged indexer kernel
+  (kernels/sparse_selection.paged_index_scores, on one TPU) and gathers
+  those rows of `pool_c`, a chunk's rows run dense over their shared
+  context under the selection as a mask (kernels/sparse_latent_attention.py;
+  the chunk's scores, the top-k and the row gather are XLA's).
 
 Rows of the decode op: the first `chunk_from` are the serving engine's
 slots, each with its own page-table row. Rows past them, if a call has
@@ -324,6 +326,7 @@ def _paged_latent_weights(p: PagedLatentAttentionParams, in_shapes):
 def _paged_latent_forward(p: PagedLatentAttentionParams, inputs, weights,
                           state, ctx):
     from ..kernels import sparse_latent_attention as sla
+    from .inc_attention import _call_gate
 
     f = p.front
     x, positions, page_table = inputs
@@ -363,7 +366,8 @@ def _paged_latent_forward(p: PagedLatentAttentionParams, inputs, weights,
     chunk = rows > n
     with jax.named_scope("dsa.index"):
         index = sla.index_scores_rows(qi[:n], wt[:n], pool_i,
-                                      page_table[:n], pos[:n])
+                                      page_table[:n], pos[:n],
+                                      call_gate=_call_gate(1, ctx.mesh))
         if chunk:
             index_c = sla.index_scores_chunk(qi[n:], wt[n:], pool_i,
                                              page_table[n], pos[n:])

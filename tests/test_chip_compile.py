@@ -168,9 +168,10 @@ def test_sparse_latent_attention_at_deepseek_v32_widths(tpu, rows):
     attention over the paged latent pool of `dsv32-serve-sessions` (1,536
     blocks of 256, page tables 130 wide): 16 decoding rows, and the same
     with a 256-token chunk under one page-table row (its selection a mask
-    by bisection, its attention dense over the shared context). XLA's
-    gather, matmul and sort: no Pallas kernel yet, and what the step
-    needs beside its arguments stays under 2 GB."""
+    by bisection, its attention dense over the shared context). The 16
+    rows' scores are the paged indexer kernel's, once a layer whatever
+    rides beside them; the rest is XLA's gather, matmul and sort, and what
+    the step needs beside its arguments stays under 2 GB."""
     from flexflow_tpu.kernels import sparse_latent_attention as sla
 
     s = _on(tpu[0])
@@ -194,7 +195,7 @@ def test_sparse_latent_attention_at_deepseek_v32_widths(tpu, rows):
         s((rows, 64, 128)), s((rows, 64), jnp.float32), s((rows, 128, 576)),
         s((1536, 256, 128)), s((1536, 256, 576)), s((rows, 130), jnp.int32),
         s((rows,), jnp.int32)).compile()
-    assert not pallas_kernels(compiled.as_text())
+    assert pallas_kernels(compiled.as_text()) == {"paged_index_scores": 1}
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
@@ -285,9 +286,11 @@ def test_selected_grouped_attention_at_keye_vl2_widths(tpu, rows):
     [k ; v] 1,024 wide beside an indexer key of 64, page tables 131 wide;
     16 decoding rows that take 2,048 and gather them, and the same with a
     chunk of 256 under one page-table row (its selection a mask by
-    bisection, its attention dense over the shared context). XLA's
-    gather, matmul and sort: no Pallas kernel yet, and what the layer
-    needs beside its arguments stays under 2 GB."""
+    bisection, its attention dense over the shared context). The compiled
+    layer holds the paged indexer kernel once, for the slots' rows: the
+    count that says the mechanism engaged; the rest is XLA's gather,
+    matmul and sort, and what the layer needs beside its arguments stays
+    under 2 GB."""
     from flexflow_tpu.ops.attention import AttentionFrontEnd, Indexer
 
     s = _on(tpu[0])
@@ -307,13 +310,57 @@ def test_selected_grouped_attention_at_keye_vl2_widths(tpu, rows):
         weights, s((rows, 1, 2048)), s((rows, 1), jnp.int32),
         s((rows, 131), jnp.int32)).compile()
     text = compiled.as_text()
-    assert not pallas_kernels(text)
+    assert pallas_kernels(text) == {"paged_index_scores": 1}
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
     # no step copies a pool (a row of 64 lanes made every step copy the
     # indexer pool into the row layout and back)
     import re
 
     assert not re.findall(r"= bf16\[1440,256,\d+\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("heads,width,blocks", [
+    (16, 131, 1440),   # keye2-serve-mediaqa: 16 heads of 64 in 128 lanes
+    (64, 130, 1408),   # dsv32-serve-sessions: 64 heads of 128
+])
+def test_paged_index_scores_at_both_sparse_cells_widths(tpu, heads, width,
+                                                        blocks):
+    """The paged indexer kernel lowers for the v5e at both cells' widths:
+    16 rows, blocks of 256 keys in 128-lane bf16 rows, a round's scores
+    stored into the row's (1, S) float32 block at a page's lane offset."""
+    from flexflow_tpu.kernels import sparse_selection as sel
+
+    s = _on(tpu[0])
+    kernels = _kernels(
+        sel.index_scores_rows, s((16, heads, 128)),
+        s((16, heads), jnp.float32), s((blocks, 256, 128)),
+        s((16, width), jnp.int32), s((16,), jnp.int32))
+    assert kernels == {"paged_index_scores": 1}
+
+
+@pytest.mark.parametrize("why,width,block,lanes,call_gate", [
+    (r"block_size 16 % 128", 8, 16, 128, None),
+    (r"64 lanes", 8, 256, 64, None),
+    (r"bytes of VMEM", 1024, 256, 128, None),
+    (r"4-device mesh", 8, 256, 128,
+     "4-device mesh: kernel not run per shard"),
+])
+def test_paged_index_scores_refused_takes_the_reference_and_says_so(
+        tpu, why, width, block, lanes, call_gate):
+    """A geometry `paged_index_gate` refuses (a block that is no whole
+    lane tiles, a 64-lane pool row, a table row whose scores do not sit
+    in VMEM) and a call the op's own gate refuses (a multi-device mesh)
+    take XLA's gather and einsum; on a TPU that is said, not hidden."""
+    from flexflow_tpu.kernels import sparse_selection as sel
+
+    s = _on(tpu[0])
+    fn = lambda *a: sel.index_scores_rows(*a, call_gate=call_gate)  # noqa: E731
+    with pytest.warns(KernelFallbackWarning, match=why):
+        kernels = _kernels(
+            fn, s((4, 16, lanes)), s((4, 16), jnp.float32),
+            s((64, block, lanes)), s((4, width), jnp.int32),
+            s((4,), jnp.int32))
+    assert not kernels
 
 
 @pytest.mark.parametrize("rows", [32, 32 + 256])
