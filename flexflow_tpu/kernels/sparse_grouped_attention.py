@@ -2,7 +2,7 @@
 (kernels/sparse_selection.py), read from a paged pool whose row is one
 token's keys and values side by side, [k ; v] of 2 x kv_heads x head_dim:
 a selected token is then ONE gathered row (XLA's row gather moves a row in
-25-30 ns whatever its width: PERF.md section 6, PR 31), not one of keys
+14-17 ns whatever its width: PERF.md section 6, PR 44), not one of keys
 and one of values.
 
 A slot's row gathers its selected rows of the pool (`attend_selected`); a
@@ -11,7 +11,9 @@ mask (`attend_chunk`), as the latent pair of
 kernels/sparse_latent_attention.py does. Query head i reads KV head
 i // (heads // kv_heads). jax.numpy and `lax` only: the one Pallas kernel
 of the selection is the decoding rows' indexer (sparse_selection.
-paged_index_scores); the row gather here is XLA's (tests/test_keye_vl2.py
+paged_index_scores); the row gather here is XLA's (sparse_selection.
+gather_selected; a kernel over the gathered rows gave 4 % of a decode step
+and was left out: PERF.md section 6, PR 44) (tests/test_keye_vl2.py
 holds these functions to the float32 reference and, where the selection is
 everything, to the grouped paged kernels).
 """
@@ -21,7 +23,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .sparse_selection import NEG, _chunk_blocks, chunk_mask_blocks
+from .sparse_selection import (
+    NEG, _chunk_blocks, chunk_mask_blocks, gather_selected,
+)
 
 
 def _split(rows, kv_heads: int):
@@ -38,12 +42,10 @@ def attend_selected(q, pool_kv, page_table, sel, valid, *, kv_heads: int,
     (rows, heads, head_dim) against the pool's rows [k ; v], page_table
     (rows, W), sel and valid (rows, K) -> (rows, heads, head_dim) in q's
     dtype. The rows are gathered from the pool as it lies, by (block,
-    offset): a flattened view would cost a copy of the pool."""
+    offset) (`gather_selected`)."""
     rows, heads, hd = q.shape
-    bs = pool_kv.shape[1]
-    block = jnp.take_along_axis(page_table, sel // bs, axis=1)
-    block = jnp.where(valid, block, 0)  # the scratch block: finite zeros
-    k, v = _split(pool_kv[block, sel % bs].astype(q.dtype), kv_heads)
+    picked = gather_selected(pool_kv, page_table, sel, valid)
+    k, v = _split(picked.astype(q.dtype), kv_heads)
     qg = q.reshape(rows, kv_heads, heads // kv_heads, hd)
     scores = jnp.einsum("rgqd,rkgd->rgqk", qg, k,
                         preferred_element_type=jnp.float32) * scale

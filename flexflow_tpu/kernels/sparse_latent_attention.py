@@ -8,8 +8,10 @@ its old names.
 A slot's row gathers its selected rows of `pool_c`; a chunk's rows run
 dense over their shared context under the selection as a mask
 (`attend_chunk`). jax.numpy and `lax` only: the one Pallas kernel of the
-selection is the decoding rows' indexer (sparse_selection.
-paged_index_scores); the row gather here is XLA's
+selection here is the decoding rows' indexer (sparse_selection.
+paged_index_scores); the row gather is XLA's (sparse_selection.
+gather_selected), and at 128 heads a group XLA's einsums over the gathered
+rows are as fast as a kernel over them (PERF.md section 6, PR 44)
 (tests/test_latent_attention.py holds these functions to the float32
 reference).
 """
@@ -20,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from .sparse_selection import (  # noqa: F401  (the ops call them from here)
-    KEY_BLOCK_ROWS, NEG, _chunk_blocks, chunk_mask_blocks,
+    KEY_BLOCK_ROWS, NEG, _chunk_blocks, chunk_mask_blocks, gather_selected,
     index_scores_chunk, index_scores_rows, select_topk, selection_mask,
 )
 
@@ -31,12 +33,8 @@ def attend_selected(q, pool_c, page_table, sel, valid, *, latent_dim: int,
     q (rows, heads, latent + rope) against the pool's rows [cKV ; k^R],
     page_table (rows, W), sel and valid (rows, K). Returns sum_s p_s
     cKV_s, (rows, heads, latent), in q's dtype. The rows are gathered
-    from the pool as it lies, by (block, offset): a flattened view would
-    cost a copy of the pool."""
-    bs = pool_c.shape[1]
-    block = jnp.take_along_axis(page_table, sel // bs, axis=1)
-    block = jnp.where(valid, block, 0)  # the scratch block: finite zeros
-    picked = pool_c[block, sel % bs].astype(q.dtype)
+    from the pool as it lies, by (block, offset) (`gather_selected`)."""
+    picked = gather_selected(pool_c, page_table, sel, valid).astype(q.dtype)
     scores = jnp.einsum("rhc,rkc->rhk", q, picked,
                         preferred_element_type=jnp.float32) * scale
     scores = jnp.where(valid[:, None, :], scores, NEG)

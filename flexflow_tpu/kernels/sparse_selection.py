@@ -24,9 +24,12 @@ and XLA's gather and einsum elsewhere; tests/test_paged_index_scores.py
 holds the two to each other). What is still jax.numpy and `lax`: a chunk's
 scores (`index_scores_chunk`: XLA's gather and matmul in a `fori_loop`),
 the top-k (`lax.top_k`, a full sort at k = 2,048), the chunk's mask, and
-the selected rows' gather in the two attention modules. The contract a
-kernel has to keep is these functions' (tests/test_latent_attention.py
-and tests/test_keye_vl2.py hold them to the float32 references).
+the selected rows' gather (`gather_selected`, for the two attention
+modules: Mosaic cannot address one token's row of a tiled pool, and where
+it can a DMA a row costs what XLA's gather costs, the comment above that
+function). The contract a kernel has to keep is these functions'
+(tests/test_latent_attention.py and tests/test_keye_vl2.py hold them to
+the float32 references).
 """
 
 from __future__ import annotations
@@ -274,6 +277,36 @@ def index_scores_rows(qi, wt, pool_i, page_table, positions,
             return paged_index_scores(qi, wt, pool_i, page_table, positions)
         warn_reference("paged_index_scores", (qi.shape, pool_i.shape), gate)
     return index_scores_rows_reference(qi, wt, pool_i, page_table, positions)
+
+
+# ------------------------------------------ the selected rows' gather
+# A decoding row's selected pool rows are fetched by XLA's row gather, 14-17
+# ns a row whatever its width. Why no kernel fetches them, one DMA a pool
+# row: the pool lies in HBM in (8, 128) tiles, a token's row of it is eight
+# strided pieces that share their 32-bit words with the neighbouring row,
+# and Mosaic refuses a slice of a tiled memref that is no whole tile
+# (tests/test_chip_compile.py keeps the refusal, bf16, float32 and the
+# 32-bit view alike). Where Mosaic can address as many bytes (an (8, 128)
+# tile of 8 tokens by 128 lanes of the pool as it lies: 2 KB, not a row; a
+# row of a pool relaid token-major) a DMA costs 30-36 ns in a plain loop
+# and 17.8 with the starts unrolled by 16 and one wait a round (PERF.md
+# section 6, PR 44).
+
+
+def gather_selected(pool, page_table, sel, valid):
+    """(rows, K, lanes): the pool's rows at each row's selected positions,
+    gathered from the pool as it lies, by (block, offset): a flattened
+    view would cost a copy of the pool. An invalid entry reads the scratch
+    block: finite zeros. A position's block is picked out of the row's
+    page-table row by comparison, W selects an entry in one fusion: as a
+    gather of scalars (`take_along_axis`) XLA looks the entries up one by
+    one, 10 ns each, 0.33 ms a layer at 16 x 2,048 (PERF.md section 6,
+    PR 44)."""
+    bs, W = pool.shape[1], page_table.shape[1]
+    page = jnp.where(valid, sel // bs, -1)[:, None, :]  # -1: no page
+    hit = page == jnp.arange(W, dtype=page.dtype)[None, :, None]
+    block = jnp.sum(jnp.where(hit, page_table[:, :, None], 0), axis=1)
+    return pool[block, sel % bs]
 
 
 def _chunk_blocks(table, bs: int, positions):

@@ -363,6 +363,45 @@ def test_paged_index_scores_refused_takes_the_reference_and_says_so(
     assert not kernels
 
 
+@pytest.mark.parametrize("dtype,lanes", [
+    (jnp.bfloat16, 1024),  # keye2's [k ; v] row as the pool holds it
+    (jnp.float32, 1024),
+    (jnp.uint32, 512),     # the same row as 32-bit words, two bf16 lanes each
+])
+def test_one_token_row_of_a_tiled_pool_is_not_a_dma(tpu, dtype, lanes):
+    """Why the selected rows' read is XLA's gather and no DMA a row inside
+    a kernel (PERF.md section 6, PR 44): a (blocks, block size, lanes) pool
+    lies in HBM in (8, 128) tiles, and Mosaic refuses to slice one token's
+    row out of it, as 16-bit lanes and as their 32-bit view alike. A JAX
+    whose Mosaic lowers this fails here: ROADMAP Queue 1 item 1 (b), the
+    DMA walk over the selected rows, is then open again."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def body(block_ref, offset_ref, pool_hbm, out_ref, sem):
+        copy = pltpu.make_async_copy(
+            pool_hbm.at[block_ref[0], pl.ds(offset_ref[0], 1)],
+            out_ref.at[pl.ds(0, 1)], sem.at[0])
+        copy.start()
+        copy.wait()
+
+    def row(block, offset, pool):
+        return pl.pallas_call(
+            body,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(1,),
+                in_specs=[pl.BlockSpec(memory_space=pltpu.HBM)],
+                out_specs=pl.BlockSpec((8, lanes), lambda i, b, o: (0, 0)),
+                scratch_shapes=[pltpu.SemaphoreType.DMA((1,))]),
+            out_shape=jax.ShapeDtypeStruct((8, lanes), dtype),
+            name="row_dma")(block, offset, pool)
+
+    s = _on(tpu[0])
+    with pytest.raises(Exception, match=r"aligned to tiling \(8\), but is 1"):
+        jax.jit(row).lower(s((1,), jnp.int32), s((1,), jnp.int32),
+                           s((64, 256, lanes), dtype)).compile()
+
+
 @pytest.mark.parametrize("rows", [32, 32 + 256])
 @pytest.mark.parametrize("kind", ["global", "window"])
 def test_paged_attention_at_mimo_v2_flash_widths(tpu, kind, rows):
