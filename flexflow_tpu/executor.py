@@ -940,48 +940,39 @@ class Executor:
             verify_step, donate_argnums=_donate_argnums((1,)))
         return self._verify_step
 
-    def build_block_copy(self, only=None, skip=frozenset()):
+    def build_block_copy(self, pools: dict):
         """Copy-on-write support for the paged KV layout: duplicate pool
-        blocks src[i] → dst[i] across EVERY layer's pool_k/pool_v in one
-        donated dispatch (the block ids are layer-uniform, so one (src,
-        dst) vector serves the whole stack). `only` / `skip`: state node
-        names that are / are not copied, where the cache has groups whose
-        block ids are their own (serving/paged.py). The serving engine pads the
-        vectors to a power-of-two width with (scratch → scratch) no-op
-        pairs, so the executable set stays O(log slots·chunk) like the
-        prefill buckets. Donating `state` updates the pools in place on
-        backends with donation — a COW costs one block-sized DMA per
-        layer, never a pool-sized allocation."""
-
-        from .serving.decode_graph import POOL_LEAVES
+        blocks src[i] → dst[i] across every leaf of `pools`, {state node
+        name: the leaves its op declares by block}, in one donated
+        dispatch (block ids are uniform over the layers of a cache group,
+        so one (src, dst) vector serves the group's stack; a group whose
+        ids are its own has a program of its own: serving/paged.py). The
+        serving engine pads the vectors to a power-of-two width with
+        (scratch → scratch) no-op pairs, so the executable set stays
+        O(log slots·chunk) like the prefill buckets. Donating `state`
+        updates the pools in place on backends with donation — a COW costs
+        one block-sized DMA per layer, never a pool-sized allocation."""
 
         def copy_blocks(state, src, dst):
             new_state = {}
             for name, ws in state.items():
                 nw = dict(ws)
-                if name in skip or (only is not None and name not in only):
-                    new_state[name] = nw
-                    continue
-                for pool in POOL_LEAVES:
-                    buf = nw.get(pool)
-                    if buf is not None:
-                        nw[pool] = buf.at[dst].set(buf[src])
+                for pool in pools.get(name, ()):
+                    nw[pool] = nw[pool].at[dst].set(nw[pool][src])
                 new_state[name] = nw
             return new_state
 
-        fn = jax.jit(copy_blocks, donate_argnums=_donate_argnums((0,)))
-        if only is None:
-            self._copy_fn = fn
-        return fn
+        return jax.jit(copy_blocks, donate_argnums=_donate_argnums((0,)))
 
-    def build_kv_inject(self):
+    def build_kv_inject(self, pools: list):
         """Disaggregated-serving handoff landing: write externally
         computed KV rows (the prefill pool's blocks, host-staged by the
         coordinator) into this engine's pool blocks in one donated
         dispatch. `blocks` is the (B,) physical destination vector,
         `rows_k`/`rows_v` are (layers, B, block_size, embed) stacked in
-        sorted pool-layer-name order — the same order the extraction
-        side reads, so layer i's rows land in layer i's pool. The engine
+        the order of `pools`, [(state node name, its keys' leaf, its
+        values')] — the same order the extraction side reads, so layer
+        i's rows land in layer i's pool. The engine
         pads B to a power of two with (scratch, zero-rows) pairs, so the
         executable set stays O(log blocks-per-prompt) like the COW copy
         buckets. Donating `state` updates the pools in place on backends
@@ -989,22 +980,15 @@ class Executor:
         pool-sized allocation."""
 
         def inject_blocks(state, blocks, rows_k, rows_v):
-            new_state = {}
-            i = 0
-            for name in sorted(state):
-                nw = dict(state[name])
-                if "pool_k" in nw:
-                    nw["pool_k"] = nw["pool_k"].at[blocks].set(
-                        rows_k[i].astype(nw["pool_k"].dtype))
-                    nw["pool_v"] = nw["pool_v"].at[blocks].set(
-                        rows_v[i].astype(nw["pool_v"].dtype))
-                    i += 1
-                new_state[name] = nw
+            new_state = {name: dict(ws) for name, ws in state.items()}
+            for i, (name, *leaves) in enumerate(pools):
+                nw = new_state[name]
+                for leaf, rows in zip(leaves, (rows_k, rows_v)):
+                    nw[leaf] = nw[leaf].at[blocks].set(
+                        rows[i].astype(nw[leaf].dtype))
             return new_state
 
-        self._inject_fn = jax.jit(
-            inject_blocks, donate_argnums=_donate_argnums((0,)))
-        return self._inject_fn
+        return jax.jit(inject_blocks, donate_argnums=_donate_argnums((0,)))
 
     def build_param_gather(self):
         """The stage-3 params' full gather as ONE donated executable:

@@ -35,7 +35,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
 from ..fftype import DataType, OperatorType as OT
-from .base import OpDef, WeightSpec, matmul_cast, register_op
+from .base import (
+    HANDOFF, QUERIES, REWIND, OpDef, WeightSpec, matmul_cast, register_op,
+)
 
 
 def proj(ctx, x, w, b):
@@ -66,6 +68,15 @@ class Indexer:
     topk: int
     rope_dim: int
     norm_eps: float = 1e-6
+
+
+# what a cache with an indexer's keys beside its rows cannot follow
+# (DecodeState.cannot)
+SELECTION_CANNOT = dict.fromkeys(
+    (HANDOFF, QUERIES),
+    "a learned sparse selection (an indexer pool beside the cache rows: "
+    "{layer}, ...): the indexer's keys are neither handed off nor scored by "
+    "a multi-token call")
 
 
 @dataclass(frozen=True)
@@ -220,6 +231,21 @@ class AttentionFrontEnd:
         if self.index and cached_rows > self.index.topk:
             return self.index.topk
         return 0
+
+    @property
+    def cannot_follow(self) -> dict:
+        """What the layer's cache cannot follow (DecodeState.cannot): a
+        prompt's whole extent is not in a window layer's pool to hand off,
+        nor a block freed behind an advanced cursor to rewind to."""
+        if self.index is not None:
+            return SELECTION_CANNOT
+        if self.window:
+            return dict.fromkeys(
+                (HANDOFF, REWIND),
+                "window attention layers ({layer}, ...): their cache group "
+                "keeps a slot's window only, which is neither handed off "
+                "whole nor rolled back")
+        return {}
 
     def cache_row_widths(self, cached_rows: int) -> dict:
         """{pool leaf: numbers a token holds in it} of the layer's paged
@@ -585,6 +611,48 @@ def _mha_flops(p: MultiHeadAttentionParams, in_shapes, out_shapes):
     return p.front.linear_flops(b, sq, sk, q[2], k[2], v[2]) + attn
 
 
+def _mha_decode_layer(layer, ctx):
+    """A causal self-attention layer serves through the incremental op over
+    the paged pool of its group (global or window), or over the contiguous
+    region: the trained layer's front end goes to the decode op whole."""
+    from .inc_attention import (
+        IncMultiHeadAttentionParams, PagedIncMultiHeadAttentionParams,
+    )
+
+    p = layer.params
+    if not p.causal:
+        raise ValueError(
+            f"{layer.name}: serving decode requires causal attention "
+            f"(non-causal layers see future tokens the cache does not hold "
+            f"yet)")
+    if not (layer.inputs[0] is layer.inputs[1] is layer.inputs[2]):
+        raise ValueError(
+            f"{layer.name}: serving decode supports self-attention only "
+            f"(q, k, v must be one tensor)")
+    if p.kdim not in (0, p.embed_dim) or p.vdim not in (0, p.embed_dim):
+        raise ValueError(
+            f"{layer.name}: kdim/vdim != embed_dim not supported in the "
+            f"decode graph")
+    if not ctx.paged:
+        if p.front.selected(ctx.max_seq):
+            raise NotImplementedError(
+                f"{layer.name}: attention under a learned selection is "
+                f"served from the paged pool only (kv_layout='paged')")
+        return (OT.OP_INC_MULTIHEAD_ATTENTION,
+                IncMultiHeadAttentionParams(p.front, ctx.max_seq,
+                                            impl=ctx.impl,
+                                            cache_dtype=ctx.at_rest),
+                ("positions",))
+    windowed = bool(p.front.window)
+    return (OT.OP_PAGED_INC_MULTIHEAD_ATTENTION,
+            PagedIncMultiHeadAttentionParams(
+                p.front, ctx.max_seq, ctx.block_size,
+                ctx.window_blocks if windowed else ctx.blocks, impl=ctx.impl,
+                cache_dtype=ctx.at_rest, chunk_from=ctx.slots),
+            ("positions", "page_table_w" if windowed else "page_table"))
+
+
 register_op(
-    OpDef(OT.OP_MULTIHEAD_ATTENTION, _mha_infer, _mha_forward, _mha_weights, _mha_flops)
+    OpDef(OT.OP_MULTIHEAD_ATTENTION, _mha_infer, _mha_forward, _mha_weights,
+          _mha_flops, decode_layer=_mha_decode_layer)
 )

@@ -24,7 +24,7 @@ import math
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional, Sequence
 
-from ..fftype import DataType, OperatorType
+from ..fftype import DataType, OperatorType, size_of_datatype
 
 
 @dataclass(frozen=True)
@@ -76,6 +76,78 @@ def matmul_cast(ctx: OpContext, *arrays):
     return out if len(out) > 1 else out[0]
 
 
+# how a leaf of a decode op's state is indexed: by block under a page table
+# (blocks, block_size, ..), by slot and then position in the contiguous
+# layout (slots, max_seq_len + 1, ..), by slot (slots, ..); LAST_CALL is a
+# record of the slots' last call (what a row selected) no token carries on
+BY_BLOCK, BY_POSITION, BY_SLOT, LAST_CALL = "block", "position", "slot", "call"
+# what the state can follow: a block-by-block handoff to another engine, a
+# cursor rewound behind written rows, a call of several query tokens a
+# slot, a prefix matched in the pool
+HANDOFF, REWIND, QUERIES, PREFIX = "handoff", "rewind", "queries", "prefix"
+
+
+@dataclass(frozen=True)
+class StateLeaf:
+    name: str
+    index: str    # BY_BLOCK | BY_POSITION | BY_SLOT | LAST_CALL
+    width: tuple  # numbers a token (by block) or a slot (the others) holds
+    dtype: DataType
+
+
+@dataclass(frozen=True)
+class DecodeState:
+    """What a decode op keeps from token to token, as its `OpDef.state`
+    declares it: the op's weights function allocates from it, and serving/
+    and executor.py size, price, copy, hand off and refuse by it."""
+
+    leaves: tuple
+    slots: int = 0       # slots the by-slot leaves hold; 0: a call's rows
+    blocks: int = 0      # blocks of the pool, the scratch block too
+    block_size: int = 0
+    window: int = 0      # > 0: reads the window group's table, this far back
+    selected: int = 0    # positions a row attends at the most; 0: all
+    # {what the state cannot follow: what is said of the first such layer,
+    # which completes "cannot serve a graph with", {layer} its name}
+    cannot: dict = field(default_factory=dict)
+    # whether a prefill chunk rides as single-query rows past the slots',
+    # (mesh, itemsize) -> bool, and the query rows a tile of the chunk
+    # kernel then takes, (mesh, itemsize, rows) -> int or None; None: never
+    chunk_as_rows: Optional[Callable] = None
+    chunk_query_tile: Optional[Callable] = None
+
+    def names(self, *indexes) -> tuple:
+        return tuple(l.name for l in self.leaves if l.index in indexes)
+
+    def weight_specs(self, rows: int) -> list:
+        """The leaves as allocated, for a call of `rows` rows."""
+        return [WeightSpec(
+            l.name, ((self.blocks, self.block_size) if l.index == BY_BLOCK
+                     else (self.slots or rows,)) + l.width,
+            l.dtype, "zeros", trainable=False) for l in self.leaves]
+
+    def bytes_of(self, index: str) -> int:
+        """Bytes one block (BY_BLOCK) or one slot (BY_SLOT) holds."""
+        rows = self.block_size if index == BY_BLOCK else 1
+        return sum(rows * math.prod(l.width) * size_of_datatype(l.dtype)
+                   for l in self.leaves if l.index == index)
+
+
+@dataclass(frozen=True)
+class DecodeContext:
+    """The serving context a training layer's decode layer is made for."""
+
+    slots: int = 1
+    max_seq: int = 1
+    paged: bool = True
+    block_size: int = 1
+    blocks: int = 0         # of the global group's pool
+    window_blocks: int = 0  # of the window group's
+    impl: str = "auto"
+    at_rest: DataType = DataType.DT_FLOAT
+    prefill_chunk: int = 1
+
+
 class OpDef:
     """Registry entry for one OperatorType."""
 
@@ -87,13 +159,30 @@ class OpDef:
         weights: Optional[Callable] = None,  # (params, in_shapes) -> list[WeightSpec]
         flops: Optional[Callable] = None,  # (params, in_shapes, out_shapes) -> float
         num_outputs: int = 1,
+        # how a layer serves, where not as it is: (layer, DecodeContext) ->
+        # (op type, params, the feeds the decode op reads beside the layer's
+        # first input, by name; () = the layer's own inputs)
+        decode_layer: Optional[Callable] = None,
+        # a decode op: (params) -> DecodeState, allocated after `weights`,
+        # and {name: index} of every leaf a declaration of it may hold
+        state: Optional[Callable] = None,
+        state_leaves: Optional[dict] = None,
     ):
         self.op_type = op_type
         self.infer_shapes = infer_shapes
         self.forward = forward
         self.weights = weights or (lambda params, in_shapes: [])
+        if state is not None:
+            own = self.weights
+            self.weights = lambda params, in_shapes: (
+                own(params, in_shapes)
+                + state(params).weight_specs(in_shapes[0][0]))
         self.flops = flops or _default_flops
         self.num_outputs = num_outputs
+        self.decode_layer = decode_layer or (
+            lambda layer, ctx: (layer.op_type, layer.params, ()))
+        self.state = state
+        self.state_leaves = state_leaves or {}
 
 
 def _default_flops(params, in_shapes, out_shapes) -> float:

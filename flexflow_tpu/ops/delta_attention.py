@@ -42,7 +42,10 @@ import jax.numpy as jnp
 
 from ..fftype import DataType, OperatorType as OT
 from .attention import proj
-from .base import OpDef, WeightSpec, register_op
+from .base import (
+    BY_SLOT, HANDOFF, PREFIX, REWIND, DecodeState, OpDef, StateLeaf,
+    WeightSpec, register_op,
+)
 from .core import rms_norm
 
 
@@ -237,8 +240,18 @@ def _delta_flops(p: GatedDeltaAttentionParams, in_shapes, out_shapes):
     return p.front.linear_flops(b * s, d) + p.front.state_flops(b * s)
 
 
+def _delta_decode_layer(layer, ctx):
+    # `state_slot`: which slot's state a row reads and writes: row i is slot
+    # i, but for a prefill chunk's rows past the slots
+    return (OT.OP_GATED_DELTA_ATTENTION_DECODE,
+            GatedDeltaDecodeParams(layer.params.front, ctx.slots,
+                                   ctx.max_seq, cache_dtype=ctx.at_rest),
+            ("positions", "state_slot"))
+
+
 register_op(OpDef(OT.OP_GATED_DELTA_ATTENTION, _delta_infer, _delta_forward,
-                  _delta_weights, _delta_flops))
+                  _delta_weights, _delta_flops,
+                  decode_layer=_delta_decode_layer))
 
 
 # --------------------------------------------------------------------- decode
@@ -256,18 +269,25 @@ class GatedDeltaDecodeParams:
     @property
     def state_leaves(self) -> dict:
         """{state leaf: shape} of what the layer keeps a slot."""
-        f = self.front
-        return {"state_s": (self.slots, f.num_heads, f.head_dim, f.head_dim),
-                "state_conv": (self.slots, f.conv_kernel - 1, 3 * f.width)}
+        return {w.name: w.shape
+                for w in _delta_decode_state(self).weight_specs(self.slots)}
 
 
-def _delta_decode_weights(p: GatedDeltaDecodeParams, in_shapes):
-    shapes = p.state_leaves
-    return p.front.weight_specs(in_shapes[0][-1]) + [
-        WeightSpec("state_s", shapes["state_s"], DataType.DT_FLOAT, "zeros",
-                   trainable=False),
-        WeightSpec("state_conv", shapes["state_conv"], p.cache_dtype,
-                   "zeros", trainable=False)]
+def _delta_decode_state(p: GatedDeltaDecodeParams) -> DecodeState:
+    """The delta rule's state and its convolution's last inputs, a slot:
+    not paged, not shareable block by block, reset when a slot's row starts
+    a request (position 0)."""
+    f = p.front
+    return DecodeState(
+        (StateLeaf("state_s", BY_SLOT, (f.num_heads, f.head_dim, f.head_dim),
+                   DataType.DT_FLOAT),
+         StateLeaf("state_conv", BY_SLOT, (f.conv_kernel - 1, 3 * f.width),
+                   p.cache_dtype)),
+        slots=p.slots, cannot=dict.fromkeys(
+            (HANDOFF, REWIND, PREFIX),
+            "recurrent layers (gated delta-rule attention: {layer}, ...): "
+            "their per-slot state is neither rewound nor handed off, nor "
+            "kept at a cached prefix's end"))
 
 
 def _delta_decode_forward(p: GatedDeltaDecodeParams, inputs, weights, state,
@@ -316,5 +336,6 @@ def _delta_decode_flops(p: GatedDeltaDecodeParams, in_shapes, out_shapes):
 
 
 register_op(OpDef(OT.OP_GATED_DELTA_ATTENTION_DECODE, _delta_infer,
-                  _delta_decode_forward, _delta_decode_weights,
-                  _delta_decode_flops))
+                  _delta_decode_forward, _delta_weights,
+                  _delta_decode_flops, state=_delta_decode_state,
+                  state_leaves=dict(state_s=BY_SLOT, state_conv=BY_SLOT)))

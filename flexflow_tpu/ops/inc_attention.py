@@ -64,13 +64,17 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 
 from ..fftype import DataType, OperatorType as OT
 from .attention import AttentionFrontEnd, FrontEndFields
-from .base import OpDef, WeightSpec, register_op
+from .base import (
+    BY_BLOCK, BY_POSITION, LAST_CALL, DecodeState, OpDef, StateLeaf,
+    register_op,
+)
 
 
 @dataclass(frozen=True)
@@ -124,15 +128,22 @@ def _inc_mha_infer(p: IncMultiHeadAttentionParams, in_shapes):
     return [(x[0], x[1], p.embed_dim)]
 
 
-def _inc_mha_weights(p: IncMultiHeadAttentionParams, in_shapes):
+def _inc_mha_state(p: IncMultiHeadAttentionParams) -> DecodeState:
+    """The KV cache: stateful (non-trainable), zero-initialized, threaded
+    functionally through the executor's state dict like BatchNorm stats. A
+    handoff is asked of the paged layout only: its callers turn this one
+    away before they ask."""
+    return DecodeState(
+        tuple(StateLeaf(name, BY_POSITION, (p.max_seq_len + 1, width),
+                        p.cache_dtype)
+              for name, width in (("cache_k", p.front.kv_width),
+                                  ("cache_v", p.front.v_width))),
+        cannot=p.front.cannot_follow)
+
+
+def _self_attention_weights(p, in_shapes):
     x = in_shapes[0]
-    # the KV cache: stateful (non-trainable), zero-initialized, threaded
-    # functionally through the executor's state dict like BatchNorm stats
-    return p.front.weight_specs(x[-1], x[-1], x[-1]) + [
-        WeightSpec(name, (x[0], p.max_seq_len + 1, width), p.cache_dtype,
-                   "zeros", trainable=False)
-        for name, width in (("cache_k", p.front.kv_width),
-                            ("cache_v", p.front.v_width))]
+    return p.front.weight_specs(x[-1], x[-1], x[-1])
 
 
 def _inc_mha_forward(p: IncMultiHeadAttentionParams, inputs, weights,
@@ -209,7 +220,9 @@ def _decode_flops(front: AttentionFrontEnd, x, cache_rows: int):
 
 
 register_op(OpDef(OT.OP_INC_MULTIHEAD_ATTENTION, _inc_mha_infer,
-                  _inc_mha_forward, _inc_mha_weights, _inc_mha_flops))
+                  _inc_mha_forward, _self_attention_weights, _inc_mha_flops,
+                  state=_inc_mha_state,
+                  state_leaves=dict(cache_k=BY_POSITION, cache_v=BY_POSITION)))
 
 
 # ===================================================================== paged
@@ -340,22 +353,26 @@ def _paged_mha_infer(p: PagedIncMultiHeadAttentionParams, in_shapes):
     return [(x[0], x[1], p.embed_dim)]
 
 
-def _paged_mha_weights(p: PagedIncMultiHeadAttentionParams, in_shapes):
-    x = in_shapes[0]
+def _paged_mha_state(p: PagedIncMultiHeadAttentionParams) -> DecodeState:
     # the block pool: ONE tensor per layer shared by every slot (a block
     # mapped into N page tables is stored once — the prefix-sharing win),
     # so per-chip accounting counts it once, not per slot
-    pools = [
-        WeightSpec(name, (p.num_blocks, p.block_size, width), p.cache_dtype,
-                   "zeros", trainable=False)
-        for name, width in p.cache_row_widths.items()]
+    leaves = [StateLeaf(name, BY_BLOCK, (width,), p.cache_dtype)
+              for name, width in p.cache_row_widths.items()]
     if p.selected:
         # the positions the slots' rows attended in the last call (-1
         # where a row had fewer), as ops/latent_attention.py keeps them
-        pools.append(WeightSpec(
-            "sel_rows", (p.chunk_from or x[0], p.selected),
-            DataType.DT_INT32, "zeros", trainable=False))
-    return p.front.weight_specs(x[-1], x[-1], x[-1]) + pools
+        leaves.append(StateLeaf("sel_rows", LAST_CALL, (p.selected,),
+                                DataType.DT_INT32))
+    # attention under a learned selection takes a chunk as rows only: it
+    # gathers the chunk's keys once for all of them
+    return DecodeState(
+        tuple(leaves), slots=p.chunk_from or 0, blocks=p.num_blocks,
+        block_size=p.block_size, window=p.front.window, selected=p.selected,
+        chunk_as_rows=lambda mesh, itemsize: bool(
+            p.selected or paged_rows_run_kernel(p, mesh, itemsize)),
+        chunk_query_tile=partial(paged_chunk_query_tile, p),
+        cannot=p.front.cannot_follow)
 
 
 def _paged_mha_forward(p: PagedIncMultiHeadAttentionParams, inputs, weights,
@@ -524,4 +541,9 @@ def _paged_mha_flops(p: PagedIncMultiHeadAttentionParams, in_shapes,
 
 
 register_op(OpDef(OT.OP_PAGED_INC_MULTIHEAD_ATTENTION, _paged_mha_infer,
-                  _paged_mha_forward, _paged_mha_weights, _paged_mha_flops))
+                  _paged_mha_forward, _self_attention_weights,
+                  _paged_mha_flops,
+                  state=_paged_mha_state,
+                  state_leaves=dict(pool_k=BY_BLOCK, pool_v=BY_BLOCK,
+                                    pool_kv=BY_BLOCK, pool_i=BY_BLOCK,
+                                    sel_rows=LAST_CALL)))

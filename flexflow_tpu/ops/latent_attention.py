@@ -46,8 +46,11 @@ import numpy as np
 
 from ..fftype import DataType, OperatorType as OT
 from ..kernels.sparse_selection import causal_selection_mask
-from .attention import layer_norm, proj, rope_half
-from .base import OpDef, WeightSpec, register_op
+from .attention import SELECTION_CANNOT, layer_norm, proj, rope_half
+from .base import (
+    BY_BLOCK, LAST_CALL, DecodeState, OpDef, StateLeaf, WeightSpec,
+    register_op,
+)
 from .core import rms_norm
 
 
@@ -266,8 +269,21 @@ def _latent_flops(p: LatentAttentionParams, in_shapes, out_shapes):
             * (f.qk_nope_head_dim + f.qk_rope_head_dim + f.v_head_dim))
 
 
+def _latent_decode_layer(layer, ctx):
+    if not ctx.paged:
+        raise NotImplementedError(
+            f"{layer.name}: latent attention is served from the paged pool "
+            f"only (kv_layout='paged')")
+    return (OT.OP_PAGED_LATENT_ATTENTION,
+            PagedLatentAttentionParams(
+                layer.params.front, ctx.max_seq, ctx.block_size, ctx.blocks,
+                chunk_from=ctx.slots, cache_dtype=ctx.at_rest),
+            ("positions", "page_table"))
+
+
 register_op(OpDef(OT.OP_LATENT_ATTENTION, _latent_infer, _latent_forward,
-                  _latent_weights, _latent_flops))
+                  _latent_weights, _latent_flops,
+                  decode_layer=_latent_decode_layer))
 
 
 # --------------------------------------------------------------------- decode
@@ -309,18 +325,17 @@ def _paged_latent_infer(p: PagedLatentAttentionParams, in_shapes):
     return [(x[0], 1, p.front.embed_dim)]
 
 
-def _paged_latent_weights(p: PagedLatentAttentionParams, in_shapes):
-    x = in_shapes[0]
-    pools = [
-        WeightSpec(name, (p.num_blocks, p.block_size, width), p.cache_dtype,
-                   "zeros", trainable=False)
-        for name, width in p.front.cache_row_widths.items()]
+def _paged_latent_state(p: PagedLatentAttentionParams) -> DecodeState:
+    pools = tuple(StateLeaf(name, BY_BLOCK, (width,), p.cache_dtype)
+                  for name, width in p.front.cache_row_widths.items())
     # the positions the slots' rows attended in the last call (-1 where a
     # row had fewer): selection is discontinuous, so whoever compares this
     # layer with another implementation needs the choice itself
-    sel = WeightSpec("sel_rows", (p.chunk_from, p.selected),
-                     DataType.DT_INT32, "zeros", trainable=False)
-    return p.front.weight_specs(x[-1]) + pools + [sel]
+    sel = StateLeaf("sel_rows", LAST_CALL, (p.selected,), DataType.DT_INT32)
+    return DecodeState(
+        pools + (sel,), slots=p.chunk_from, blocks=p.num_blocks,
+        block_size=p.block_size, selected=p.selected,
+        chunk_as_rows=lambda mesh, itemsize: True, cannot=SELECTION_CANNOT)
 
 
 def _paged_latent_forward(p: PagedLatentAttentionParams, inputs, weights,
@@ -402,5 +417,7 @@ def _paged_latent_flops(p: PagedLatentAttentionParams, in_shapes,
 
 
 register_op(OpDef(OT.OP_PAGED_LATENT_ATTENTION, _paged_latent_infer,
-                  _paged_latent_forward, _paged_latent_weights,
-                  _paged_latent_flops))
+                  _paged_latent_forward, _latent_weights,
+                  _paged_latent_flops, state=_paged_latent_state,
+                  state_leaves=dict(pool_c=BY_BLOCK, pool_i=BY_BLOCK,
+                                    sel_rows=LAST_CALL)))

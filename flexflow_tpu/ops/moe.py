@@ -30,7 +30,7 @@ TPU design notes:
 from __future__ import annotations
 
 import math
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Optional
 
 import jax
@@ -636,5 +636,13 @@ def _moe_mlp_flops(p: MoEMLPParams, in_shapes, out_shapes):
                                + 3 * p.shared_intermediate_size)
 
 
+def _moe_mlp_decode_layer(layer, ctx):
+    # over the paged pool a chunk rides as rows past the slots
+    # (serving/engine.py): the layer records what those rows chose too
+    chunk = ctx.prefill_chunk if ctx.paged else layer.params.chunk_rows
+    return OT.OP_MOE_MLP, replace(layer.params, chunk_rows=chunk), ()
+
+
 register_op(OpDef(OT.OP_MOE_MLP, _moe_mlp_infer, _moe_mlp_forward,
-                  _moe_mlp_weights, _moe_mlp_flops))
+                  _moe_mlp_weights, _moe_mlp_flops,
+                  decode_layer=_moe_mlp_decode_layer))

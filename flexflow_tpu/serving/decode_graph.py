@@ -24,135 +24,61 @@ the SAME layer names, so:
 from __future__ import annotations
 
 import copy
-from dataclasses import dataclass, field, replace
+from dataclasses import dataclass, field
 from typing import Optional
 
-from ..fftype import (
-    CompMode, DataType, LossType, OperatorType as OT, dtype_to_jnp,
+from ..fftype import CompMode, DataType, LossType, size_of_datatype
+from ..ops.base import (  # HANDOFF .. REWIND: for the callers of `refuse`
+    BY_BLOCK, BY_POSITION, BY_SLOT, HANDOFF, PREFIX, QUERIES, REWIND,
+    DecodeContext, get_op_def, registered_ops,
 )
+from .paged import window_slot_blocks
+
+# what a decode op reads beside its input, by the name `OpDef.decode_layer`
+# gives it, in the order the decode graph's inputs are made: every attention
+# layer's position feed; which slot's state a row reads and writes; the ONE
+# page table every paged layer shares (block ids index the same physical
+# slot across all layers' pools, vLLM's layout), and the table of the group
+# of layers that keeps another extent of a slot's past, the window group
+# (serving/paged.py), same logical indexing
+FEEDS = ("positions", "state_slot", "page_table", "page_table_w")
 
 
-# the block pool's state leaves: keys and values of multi-head attention
-# (side by side in one row, `pool_kv`, under a learned selection:
-# ops/inc_attention.py), the latent row of latent attention
-# (ops/latent_attention.py), the indexer's key of either. Every one is
-# (num_blocks, block_size, width) under the one page table; a
-# copy-on-write copies them all
-POOL_LEAVES = ("pool_k", "pool_v", "pool_kv", "pool_c", "pool_i")
-# the per-layer KV cache's state leaves, paged and contiguous
-KV_LEAVES = (*POOL_LEAVES, "cache_k", "cache_v")
-# what a recurrent layer keeps a SLOT beside the pool: the delta rule's
-# state and its convolution's last inputs (ops/delta_attention.py). Not
-# paged, not shareable block by block, reset when a slot's row starts a
-# request (position 0)
-STATE_LEAVES = ("state_s", "state_conv")
+def __getattr__(name):
+    """POOL_LEAVES, the block pool's state leaves (a copy-on-write copies
+    them all), and KV_LEAVES, the KV cache's, paged and contiguous: what
+    the registered decode ops declare (`OpDef.state_leaves`), so an op
+    imported later extends them."""
+    kinds = {"POOL_LEAVES": (BY_BLOCK,),
+             "KV_LEAVES": (BY_BLOCK, BY_POSITION)}.get(name)
+    if kinds is None:
+        raise AttributeError(name)
+    return tuple(dict.fromkeys(
+        leaf for op in registered_ops().values()
+        for leaf, index in op.state_leaves.items() if index in kinds))
 
 
-def recurrent_layers(model) -> list:
-    """Names of the graph's layers that carry state from token to token
-    (training graph or decode graph): a prefix matched in the pool is
-    useless to them without the state at its end, a rewound cursor does
-    not rewind them, and the KV handoff does not carry them."""
-    return [l.name for l in model.layers
-            if l.op_type in (OT.OP_GATED_DELTA_ATTENTION,
-                             OT.OP_GATED_DELTA_ATTENTION_DECODE)]
+def decode_states(model, ctx: DecodeContext = DecodeContext()) -> dict:
+    """{layer name: DecodeState} of the layers that keep state from token
+    to token: a decode graph's own declarations, a training graph's those
+    of the decode layers its ops make for `ctx` (what a state can follow is
+    a fact of its kind, not of sizes: the default is enough to ask that)."""
+    made = (get_op_def(l.op_type).decode_layer(l, ctx) for l in model.layers)
+    return {layer.name: get_op_def(op_type).state(params)
+            for layer, (op_type, params, _) in zip(model.layers, made)
+            if get_op_def(op_type).state}
 
 
-def refuse_recurrent(model, what: str):
-    """The KV handoff, a rewound cursor and a matched prefix are sound for
-    attention only: a graph with recurrent layers is refused, not served
-    wrong."""
-    recurrent = recurrent_layers(model)
-    if recurrent:
-        raise NotImplementedError(
-            f"{what} cannot serve a graph with recurrent layers (gated "
-            f"delta-rule attention: {recurrent[0]}, ...): their per-slot "
-            f"state is neither rewound nor handed off")
-
-
-def indexed_layers(model) -> list:
-    """Names of the graph's layers that keep an indexer key a token beside
-    their cache rows (training graph or decode graph): what moves or
-    rewinds the one has to move or rewind the other."""
-    def indexed(l):
-        if l.op_type in (OT.OP_LATENT_ATTENTION,
-                         OT.OP_PAGED_LATENT_ATTENTION):
-            return True
-        return (l.op_type in (OT.OP_MULTIHEAD_ATTENTION,
-                              OT.OP_PAGED_INC_MULTIHEAD_ATTENTION,
-                              OT.OP_INC_MULTIHEAD_ATTENTION)
-                and l.params.front.index is not None)
-
-    return [l.name for l in model.layers if indexed(l)]
-
-
-def refuse_indexed(model, what: str):
-    """The KV handoff carries `pool_k` / `pool_v` blocks and a
-    verification call scores several tokens a slot at once: neither knows
-    the indexer's pool nor the selection, so a graph with a learned
-    selection is refused, not served wrong."""
-    indexed = indexed_layers(model)
-    if indexed:
-        raise NotImplementedError(
-            f"{what} cannot serve a graph with a learned sparse selection "
-            f"(an indexer pool beside the cache rows: {indexed[0]}, ...): "
-            f"the indexer's keys are neither handed off nor scored by a "
-            f"multi-token call")
-
-
-def window_layers(model) -> list:
-    """Names of the graph's attention layers that attend a window of
-    their past (training graph or decode graph): the serving cache's
-    window group (serving/paged.py)."""
-    return [l.name for l in model.layers
-            if l.op_type in (OT.OP_MULTIHEAD_ATTENTION,
-                             OT.OP_PAGED_INC_MULTIHEAD_ATTENTION,
-                             OT.OP_INC_MULTIHEAD_ATTENTION)
-            and l.params.front.window]
-
-
-def refuse_windowed(model, what: str):
-    """A window layer's pool holds a slot's window and nothing behind it:
-    a prompt's whole extent is not there to hand off, and a block freed
-    behind an advanced cursor is not there to rewind to. A graph with a
-    window group is refused, not served wrong."""
-    windowed = window_layers(model)
-    if windowed:
-        raise NotImplementedError(
-            f"{what} cannot serve a graph with window attention layers "
-            f"({windowed[0]}, ...): their cache group keeps a slot's "
-            f"window only, which is neither handed off whole nor rolled "
-            f"back")
-
-
-def slot_state_bytes(model, slots: int, at_rest: DataType) -> int:
-    """Bytes the recurrent layers of a training graph keep for `slots`
-    slots in its decode graph: priced beside the pool."""
-    import math
-
-    import jax.numpy as jnp
-
-    if not recurrent_layers(model):
-        return 0
-    from ..ops.delta_attention import GatedDeltaDecodeParams
-
-    tail = jnp.dtype(dtype_to_jnp(at_rest)).itemsize
-    shapes = [GatedDeltaDecodeParams(l.params.front, slots, 0).state_leaves
-              for l in model.layers
-              if l.op_type == OT.OP_GATED_DELTA_ATTENTION]
-    return sum(4 * math.prod(leaves["state_s"])
-               + tail * math.prod(leaves["state_conv"]) for leaves in shapes)
-
-
-def cache_row_widths(layer, cached_rows: int) -> dict:
-    """{pool leaf: numbers a token holds in it} of a training-graph layer
-    whose decode op keeps a cache of `cached_rows` rows a slot, {} of any
-    other layer."""
-    if layer.op_type == OT.OP_MULTIHEAD_ATTENTION:
-        return layer.params.front.cache_row_widths(cached_rows)
-    if layer.op_type == OT.OP_LATENT_ATTENTION:
-        return layer.params.front.cache_row_widths
-    return {}
+def refuse(model, what: str, *needs, error=NotImplementedError):
+    """The one refusal: a graph (training graph or decode graph) with a
+    layer whose state cannot follow one of `needs` (HANDOFF, REWIND,
+    QUERIES, PREFIX) is refused by the first such layer's name and its
+    declaration's reason, not served wrong."""
+    for name, state in decode_states(model).items():
+        for need in needs:
+            if need in state.cannot:
+                raise error(f"{what} cannot serve a graph with "
+                            f"{state.cannot[need].format(layer=name)}")
 
 
 @dataclass
@@ -258,46 +184,44 @@ def resolve_pool_blocks(model, spec: ServingSpec, max_seq: int,
             raise ValueError(
                 f"{name} must be >= 2 (scratch + 1), got "
                 f"{getattr(spec, name)}")
-    windowed = set(window_layers(model))
+    states = decode_states(model, DecodeContext(
+        spec.slots, max_seq, True, bs, at_rest=at_rest,
+        prefill_chunk=spec.prefill_chunk)).values()
+    window = max((s.window for s in states), default=0)
     window_blocks = 0
-    if windowed:
-        window = max(l.params.front.window for l in model.layers
-                     if l.name in windowed)
-        from .paged import window_slot_blocks
-
+    if window:
         window_blocks = spec.kv_window_blocks or min(
             capacity, 2 * spec.slots * window_slot_blocks(
                 window, spec.prefill_chunk, bs) + 1)
     if spec.kv_num_blocks:
         return spec.kv_num_blocks, window_blocks
-    try:
-        import jax.numpy as jnp
+    import jax.numpy as jnp
 
-        from ..search.machine_model import machine_model_for_mesh
+    from ..search.machine_model import machine_model_for_mesh
 
-        hbm = machine_model_for_mesh(model.mesh).chip.hbm_bytes
-        itemsize = jnp.dtype(dtype_to_jnp(at_rest)).itemsize
-        weight_bytes = sum(
-            w.size * (itemsize if jnp.issubdtype(w.dtype, jnp.floating)
-                      else w.dtype.itemsize)
-            for ws in (model._params or {}).values() for w in ws.values())
-
-        def block_bytes(group) -> int:
-            return sum(
-                bs * width * itemsize for l in model.layers
-                if (l.name in windowed) == group
-                for width in cache_row_widths(l, table_width * bs).values())
-
-        if block_bytes(False) <= 0:
-            return capacity, window_blocks
-        budget = (0.9 * hbm - weight_bytes
-                  - window_blocks * block_bytes(True)
-                  - slot_state_bytes(model, spec.slots, at_rest))
-        fit = int(budget // block_bytes(False))
-        return max(spec.slots + 1, min(capacity, fit)), window_blocks
-    except Exception:
-        # no machine model / no params yet: capacity parity is always safe
+    # no parameters yet, or no machine model for this device: capacity
+    # parity is always safe (a mistake in a declaration raises)
+    if model._params is None:
         return capacity, window_blocks
+    try:
+        hbm = machine_model_for_mesh(model.mesh).chip.hbm_bytes
+    except ValueError:
+        return capacity, window_blocks
+    itemsize = size_of_datatype(at_rest)
+    weight_bytes = sum(
+        w.size * (itemsize if jnp.issubdtype(w.dtype, jnp.floating)
+                  else w.dtype.itemsize)
+        for ws in model._params.values() for w in ws.values())
+
+    block, block_w = (sum(s.bytes_of(BY_BLOCK) for s in states
+                          if bool(s.window) == group)
+                      for group in (False, True))
+    if block <= 0:
+        return capacity, window_blocks
+    budget = (0.9 * hbm - weight_bytes - window_blocks * block_w
+              - spec.slots * sum(s.bytes_of(BY_SLOT) for s in states))
+    return (max(spec.slots + 1, min(capacity, int(budget // block))),
+            window_blocks)
 
 
 def infer_max_seq_len(model) -> int:
@@ -318,9 +242,6 @@ def build_decode_model(model, spec: ServingSpec):
     cross-attention (decode needs self-attention with a causal order), and
     ops whose shape inference rejects (slots, 1, ...) activations."""
     from ..model import FFModel
-    from ..ops import (
-        IncMultiHeadAttentionParams, PagedIncMultiHeadAttentionParams,
-    )
     from ..optimizer import SGDOptimizer
 
     if spec.kv_layout not in ("contiguous", "paged"):
@@ -339,11 +260,15 @@ def build_decode_model(model, spec: ServingSpec):
     num_blocks, window_blocks = (
         resolve_pool_blocks(model, spec, max_seq, at_rest) if paged
         else (0, 0))
+    ctx = DecodeContext(spec.slots, max_seq, paged, spec.kv_block_size,
+                        num_blocks, window_blocks, spec.impl, at_rest,
+                        spec.prefill_chunk)
+    made = [get_op_def(l.op_type).decode_layer(l, ctx) for l in model.layers]
 
-    # --- inputs: (batch, seq, ...) → (slots, 1, ...); the `positions`
-    # input doubles as every attention layer's position feed
+    # --- inputs: (batch, seq, ...) → (slots, 1, ...), then the feeds a decode
+    # layer reads or the engine always stages (`positions`, the page table)
     tensor_map: dict[int, object] = {}
-    positions = None
+    feeds = {}
     for t in model._input_tensors:
         if len(t.dims) < 2:
             raise ValueError(
@@ -355,37 +280,19 @@ def build_decode_model(model, spec: ServingSpec):
             nt.constant_value = t.constant_value
         tensor_map[t.tensor_guid] = nt
         if t.name == "positions":
-            positions = nt
-    if positions is None:
-        positions = dec.create_tensor((spec.slots, 1), DataType.DT_INT32,
-                                      create_grad=False, name="positions")
-    state_slot = None
-    if recurrent_layers(model):
-        # which slot's state a row reads and writes: row i is slot i, but
-        # for a prefill chunk's rows past the slots (ops/delta_attention.py)
-        state_slot = dec.create_tensor((spec.slots, 1), DataType.DT_INT32,
-                                       create_grad=False, name="state_slot")
-    page_table = None
-    if paged:
-        # one page table feeds every attention layer: block ids index the
-        # same physical slot across all layers' pools (vLLM's layout), so
-        # the host manages ONE table per slot, not one per layer
-        table_width = -(-max_seq // spec.kv_block_size)
-        page_table = dec.create_tensor(
-            (spec.slots, table_width), DataType.DT_INT32,
-            create_grad=False, name="page_table")
-        # but a group of layers that keeps another extent of a slot's past
-        # has a pool size and a table of its own: the window group
-        # (serving/paged.py), same logical indexing
-        page_table_w = None
-        if window_blocks:
-            page_table_w = dec.create_tensor(
-                (spec.slots, table_width), DataType.DT_INT32,
-                create_grad=False, name="page_table_w")
+            feeds[t.name] = nt
+    read = {"positions", *(("page_table",) if paged else ())}.union(
+        *(reads for _, _, reads in made))
+    table_width = -(-max_seq // spec.kv_block_size)
+    for name, width in zip(FEEDS, (1, 1, table_width, table_width)):
+        if name in read and name not in feeds:
+            feeds[name] = dec.create_tensor(
+                (spec.slots, width), DataType.DT_INT32, create_grad=False,
+                name=name)
 
     # --- layers, replayed name-for-name
     layer_map: dict[int, object] = {}  # train layer guid -> decode Layer
-    for layer in model.layers:
+    for layer, (op_type, params, reads) in zip(model.layers, made):
         ins = []
         for t in layer.inputs:
             mapped = tensor_map.get(t.tensor_guid)
@@ -401,82 +308,13 @@ def build_decode_model(model, spec: ServingSpec):
                 raise ValueError(
                     f"{layer.name}: tied-weight source layer not replayed")
             shared = src
-        if layer.op_type == OT.OP_MULTIHEAD_ATTENTION:
-            p = layer.params
-            if not p.causal:
-                raise ValueError(
-                    f"{layer.name}: serving decode requires causal "
-                    f"attention (non-causal layers see future tokens the "
-                    f"cache does not hold yet)")
-            if not (layer.inputs[0] is layer.inputs[1]
-                    is layer.inputs[2]):
-                raise ValueError(
-                    f"{layer.name}: serving decode supports "
-                    f"self-attention only (q, k, v must be one tensor)")
-            if (p.kdim not in (0, p.embed_dim)
-                    or p.vdim not in (0, p.embed_dim)):
-                raise ValueError(
-                    f"{layer.name}: kdim/vdim != embed_dim not supported "
-                    f"in the decode graph")
-            # the trained layer's front end goes to the decode op whole
-            if p.front.selected(max_seq) and not paged:
-                raise NotImplementedError(
-                    f"{layer.name}: attention under a learned selection "
-                    f"is served from the paged pool only "
-                    f"(kv_layout='paged')")
-            if paged:
-                op, np_, feeds = (
-                    OT.OP_PAGED_INC_MULTIHEAD_ATTENTION,
-                    PagedIncMultiHeadAttentionParams(
-                        p.front, max_seq, spec.kv_block_size,
-                        window_blocks if p.front.window else num_blocks,
-                        impl=spec.impl, cache_dtype=at_rest,
-                        chunk_from=spec.slots),
-                    [ins[0], positions,
-                     page_table_w if p.front.window else page_table])
-            else:
-                op, np_, feeds = (
-                    OT.OP_INC_MULTIHEAD_ATTENTION,
-                    IncMultiHeadAttentionParams(p.front, max_seq,
-                                                impl=spec.impl,
-                                                cache_dtype=at_rest),
-                    [ins[0], positions])
-            new = dec._add_layer(op, np_, feeds, name=layer.name,
-                                 data_type=layer.data_type)
-        elif layer.op_type == OT.OP_GATED_DELTA_ATTENTION:
-            from ..ops.delta_attention import GatedDeltaDecodeParams
-
-            new = dec._add_layer(
-                OT.OP_GATED_DELTA_ATTENTION_DECODE,
-                GatedDeltaDecodeParams(layer.params.front, spec.slots,
-                                       max_seq, cache_dtype=at_rest),
-                [ins[0], positions, state_slot], name=layer.name,
-                initializers=dict(layer.initializers),
-                data_type=layer.data_type)
-        elif layer.op_type == OT.OP_LATENT_ATTENTION:
-            from ..ops.latent_attention import PagedLatentAttentionParams
-
-            if not paged:
-                raise NotImplementedError(
-                    f"{layer.name}: latent attention is served from the "
-                    f"paged pool only (kv_layout='paged')")
-            new = dec._add_layer(
-                OT.OP_PAGED_LATENT_ATTENTION,
-                PagedLatentAttentionParams(
-                    layer.params.front, max_seq, spec.kv_block_size,
-                    num_blocks, chunk_from=spec.slots, cache_dtype=at_rest),
-                [ins[0], positions, page_table], name=layer.name,
-                data_type=layer.data_type)
-        else:
-            params = layer.params
-            if layer.op_type == OT.OP_MOE_MLP and paged:
-                # a chunk rides as rows past the slots (engine.py): the
-                # layer records what those rows chose too
-                params = replace(params, chunk_rows=spec.prefill_chunk)
-            new = dec._add_layer(
-                layer.op_type, params, ins, name=layer.name,
-                initializers=dict(layer.initializers),
-                data_type=layer.data_type, shared_op=shared)
+        how = dict(initializers=dict(layer.initializers), shared_op=shared)
+        if reads:
+            # a layer re-made as a decode op takes its weights by adoption
+            # and keeps its own state: no initializer, no tie is replayed
+            ins, how = [ins[0], *(feeds[name] for name in reads)], {}
+        new = dec._add_layer(op_type, params, ins, name=layer.name,
+                             data_type=layer.data_type, **how)
         layer_map[layer.layer_guid] = new
         for t_out, d_out in zip(layer.outputs, new.outputs):
             tensor_map[t_out.tensor_guid] = d_out
@@ -529,12 +367,13 @@ def adopt_params(dec, model) -> int:
                     f"decode shape {old.shape}")
             ws[wname] = adopted(val, old)
             moved += 1
+    declared = decode_states(dec)  # a decode op's state keeps its zero init
     for node_name, ws in (dec._state or {}).items():
+        if node_name in declared:
+            continue
         src = (model._state or {}).get(
             model._resolve_weight_owner(node_name), {})
         for wname, old in ws.items():
-            if wname in KV_LEAVES or wname in STATE_LEAVES:
-                continue
             # a leaf shaped by the graph's rows (the experts a layer chose
             # for each token) is the decode graph's own
             if wname in src and tuple(src[wname].shape) == tuple(old.shape):

@@ -83,16 +83,11 @@ class DisaggregatedServingEngine:
                  **overrides):
         import jax
 
-        from .decode_graph import (
-            refuse_indexed, refuse_recurrent, refuse_windowed,
-        )
+        from .decode_graph import HANDOFF, refuse
 
-        refuse_recurrent(model, "disaggregated serving (the handoff "
-                          "carries pool blocks)")
-        refuse_indexed(model, "serving/disagg.py: disaggregated serving "
-                       "(the handoff carries pool_k and pool_v blocks)")
-        refuse_windowed(model, "serving/disagg.py: disaggregated serving "
-                        "(the handoff carries a prompt's whole extent)")
+        refuse(model, "serving/disagg.py: disaggregated serving (the "
+               "handoff carries a prompt's whole extent, as blocks of keys "
+               "and of values)", HANDOFF)
         cfg = model.config
         self.model = model
         self._total_chips = len(jax.devices())
@@ -309,8 +304,8 @@ class DisaggregatedServingEngine:
                                    in dict(dec.mesh.shape).items()},
                        plan_source=dec._plan_source, kv_block_size=bs,
                        on_device=True, label="decode_kv")
-        for i, name in enumerate(self.decode.kv_pool_layers()):
-            for part in ("pool_k", "pool_v"):
+        for i, (name, *parts) in enumerate(self.decode._handoff_leaves):
+            for part in parts:
                 pool = dec._state[name][part]
                 key = f"['{name}']['{part}']"
                 shape = (int(nblk), int(pool.shape[1]),

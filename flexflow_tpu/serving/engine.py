@@ -101,29 +101,25 @@ import numpy as np
 
 from .. import telemetry
 from ..engine.chunking import plan_chunks
+from ..fftype import OperatorType as OT
+from ..ops.base import BY_BLOCK, BY_POSITION, BY_SLOT
 from .decode_graph import (
-    KV_LEAVES, STATE_LEAVES, ServingSpec, adopt_params, build_decode_model,
-    recurrent_layers, refuse_indexed, refuse_recurrent, refuse_windowed,
-    window_layers,
+    FEEDS, HANDOFF, PREFIX, ServingSpec, adopt_params, build_decode_model,
+    decode_states, refuse,
 )
 from .paged import SCRATCH_BLOCK, BlockManager
 from .scheduler import ContinuousBatchingScheduler, Request
-from ..fftype import OperatorType as OT
-
-# the decode ops that read and write the block pool through a page table
-PAGED_OPS = (OT.OP_PAGED_INC_MULTIHEAD_ATTENTION,
-             OT.OP_PAGED_LATENT_ATTENTION)
 
 
-def _at_rest(decode_model) -> dict:
+def _at_rest(decode_model, states: dict) -> dict:
     """What the decode model holds on the device between steps, for the
     `serve.compile` event: the bytes of its parameters and of its KV
     cache, and the bytes of one cache element as stored (2 under --dtype
     bf16, where both rest in the compute dtype; 4 under float32). A
     step's attention reads the cache as it is stored: the decode graph
     declares it in the dtype the queries have."""
-    kv = [leaf for ws in decode_model._state.values()
-          for name, leaf in ws.items() if name in KV_LEAVES]
+    kv = [decode_model._state[name][leaf] for name, s in states.items()
+          for leaf in s.names(BY_BLOCK, BY_POSITION)]
     return dict(
         weight_bytes_at_rest=sum(
             int(w.nbytes) for ws in decode_model._params.values()
@@ -197,18 +193,14 @@ class ServingEngine:
             setattr(spec, k, v)
         if spec.prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
-        recurrent = recurrent_layers(model)
-        if recurrent:
+        if any(PREFIX in s.cannot for s in decode_states(model).values()):
             # a prefix found in the pool is useless without the recurrent
             # state at its end (snapshots at block boundaries are a
             # ROADMAP item): such a graph matches no prefix
             for option in ("prefix_cache", "prefix_sharing"):
                 if overrides.get(option):
-                    raise ValueError(
-                        f"serve(): {option}=True cannot serve a graph with "
-                        f"recurrent layers (gated delta-rule attention: "
-                        f"{recurrent[0]}, ...): the state at a cached "
-                        f"prefix's end is not kept")
+                    refuse(model, f"serve(): {option}=True", PREFIX,
+                           error=ValueError)
             spec.prefix_cache = spec.prefix_sharing = False
         if spec.prefix_cache is None:
             spec.prefix_cache = bool(
@@ -225,7 +217,10 @@ class ServingEngine:
                 self.adopted = adopt_params(self.decode_model, model)
                 self._step_fn = (
                     self.decode_model.executor.build_decode_step())
-            at_rest = _at_rest(self.decode_model)
+            # what the built graph's layers keep from token to token, as
+            # their ops declare it: sizes, groups and refusals read this
+            states = self._states = decode_states(self.decode_model)
+            at_rest = _at_rest(self.decode_model, states)
             telemetry.event(
                 "serve.compile",
                 duration_s=time.perf_counter() - t0,
@@ -249,64 +244,58 @@ class ServingEngine:
         self.block_manager = None
         self._copy_fn = None
         self._inject_fn = None  # lazily built KV-handoff landing pad
-        # the cache's groups (serving/paged.py): the window layers' pools
-        # are one group, every other paged layer's the global one
-        self._window_nodes = (window_layers(self.decode_model)
-                              if spec.kv_layout == "paged" else [])
-        self._copy_fn_w = None
-        self._block_bytes = (0, 0)
+        # the cache's groups (serving/paged.py), {layer: its declaration}
+        # each: the global one, and the pools of the layers that read the
+        # window group's table; both empty in the contiguous layout
+        self._groups = [{n: s for n, s in states.items()
+                         if s.blocks and bool(s.window) == windowed}
+                        for windowed in (False, True)]
+        self._window_nodes = list(self._groups[1])
+        # bytes one block holds over the layers of (the global group, the
+        # window group), as the pools are stored
+        self._block_bytes = tuple(
+            sum(s.bytes_of(BY_BLOCK) for s in group.values())
+            for group in self._groups)
+        # [(layer, its keys' pool, its values')] of the layers the KV
+        # handoff carries: the two leaves each declares by block
+        self._handoff_leaves = sorted(
+            (n, *s.names(BY_BLOCK)) for group in self._groups
+            for n, s in group.items() if HANDOFF not in s.cannot)
         if spec.kv_layout == "paged":
-            paged = [n for n in self.decode_model.graph.topo_order()
-                     if n.op_type in PAGED_OPS]
-            p = next((n for n in paged if n.name not in self._window_nodes),
-                     paged[0]).params
-            windowed = [n.params for n in paged
-                        if n.name in self._window_nodes]
+            windowed = list(self._groups[1].values())
+            s = next(iter((self._groups[0] or self._groups[1]).values()))
             self.block_manager = BlockManager(
-                p.num_blocks, p.block_size, p.blocks_per_slot,
+                s.blocks, s.block_size, -(-self.max_seq_len // s.block_size),
                 sharing=spec.prefix_sharing,
                 cross_time=bool(spec.prefix_cache),
-                window_blocks=windowed[0].num_blocks if windowed else 0,
-                window=max((w.front.window for w in windowed), default=0),
+                window_blocks=windowed[0].blocks if windowed else 0,
+                window=max((w.window for w in windowed), default=0),
                 window_span=spec.prefill_chunk)
             self._build_copy_fns()
-            # bytes one block holds over the layers of (the global group,
-            # the window group), as the pools are stored
-            state = self.decode_model._state
-            self._block_bytes = tuple(
-                sum(int(leaf.nbytes) // leaf.shape[0]
-                    for n in paged if (n.name in self._window_nodes) == group
-                    for name, leaf in state[n.name].items()
-                    if name in KV_LEAVES)
-                for group in (False, True))
         self._kv_itemsize = at_rest["kv_stored_itemsize"]
         self._chunk_rows = self._rows_serve_chunks()
         self._chunk_tiles: dict[int, Optional[int]] = {}
         # what a step's spans say of sparse latent attention
         # (docs/observability.md): the positions a row attends at the
         # most; and the expert layers, whose counts stats() reads
-        nodes = self.decode_model.graph.topo_order()
         self._sel_cap = next(
-            (n.params.selected for n in nodes
-             if n.op_type in PAGED_OPS and n.params.selected), 0)
-        self._moe_nodes = [n.name for n in nodes
+            (s.selected for s in states.values() if s.selected), 0)
+        self._moe_nodes = [n.name
+                           for n in self.decode_model.graph.topo_order()
                            if n.op_type == OT.OP_MOE_MLP]
         self._moe_base = (0, 0)
         # the recurrent layers' per-slot state (ops/delta_attention.py):
         # bytes all the layers keep for one slot, and the requests whose
         # first chunk was dispatched (each resets its slot's state)
-        self._state_bytes_slot = sum(
-            int(leaf.nbytes) for ws in self.decode_model._state.values()
-            for name, leaf in ws.items() if name in STATE_LEAVES
-        ) // spec.slots
+        self._state_bytes_slot = sum(s.bytes_of(BY_SLOT)
+                                     for s in states.values())
         self._state_resets = 0
         # graph input roles: exactly one token stream + the positions /
         # page-table feeds (+ constants, which the engine materializes)
         self._token_input = None
         self._const_inputs = {}
         for t in self.decode_model._input_tensors:
-            if t.name in ("positions", "page_table", "page_table_w",
-                          "state_slot"):
+            if t.name in FEEDS:
                 continue
             if hasattr(t, "constant_value"):
                 self._const_inputs[t.name] = (
@@ -418,14 +407,10 @@ class ServingEngine:
     def _build_copy_fns(self):
         """The donated copy-on-write programs, one a cache group: block
         ids are a group's own (executor.build_block_copy)."""
-        ex = self.decode_model.executor
-        if not self._window_nodes:
-            self._copy_fn = ex.build_block_copy()
-            return
-        self._copy_fn = ex.build_block_copy(
-            skip=frozenset(self._window_nodes))
-        self._copy_fn_w = ex.build_block_copy(
-            only=frozenset(self._window_nodes))
+        self._copy_fn, self._copy_fn_w = (
+            self.decode_model.executor.build_block_copy(
+                {n: s.names(BY_BLOCK) for n, s in group.items()})
+            for group in self._groups)
 
     def _rows_serve_chunks(self) -> bool:
         """Whether a step that carries a prefill chunk is laid out as
@@ -433,17 +418,10 @@ class ServingEngine:
         graph's paged attention op, with the mesh its calls run on."""
         if self.block_manager is None:
             return False
-        from ..ops.inc_attention import paged_rows_run_kernel
-
-        dec = self.decode_model
-        # attention under a learned selection takes a chunk as rows
-        # only: its op gathers the chunk's keys once for all of them
-        # (ops/latent_attention.py, ops/inc_attention.py)
+        mesh = self.decode_model.executor.mesh
         return all(
-            n.params.selected
-            or paged_rows_run_kernel(n.params, dec.executor.mesh,
-                                     self._kv_itemsize)
-            for n in dec.graph.topo_order() if n.op_type in PAGED_OPS)
+            s.chunk_as_rows and s.chunk_as_rows(mesh, self._kv_itemsize)
+            for group in self._groups for s in group.values())
 
     def _chunk_query_tile(self, b: int) -> Optional[int]:
         """Query rows a tile of the paged chunk kernel takes of a chunk
@@ -453,15 +431,12 @@ class ServingEngine:
         itself when the bucket's program is traced
         (ops/inc_attention.paged_chunk_query_tile)."""
         if b not in self._chunk_tiles:
-            from ..ops.inc_attention import paged_chunk_query_tile
-
-            dec = self.decode_model
+            mesh = self.decode_model.executor.mesh
             # one answer for the graph: its paged layers are alike
             tiles = {
-                n.op_type == OT.OP_PAGED_INC_MULTIHEAD_ATTENTION
-                and paged_chunk_query_tile(n.params, dec.executor.mesh,
-                                           self._kv_itemsize, b) or None
-                for n in dec.graph.topo_order() if n.op_type in PAGED_OPS}
+                s.chunk_query_tile
+                and s.chunk_query_tile(mesh, self._kv_itemsize, b) or None
+                for group in self._groups for s in group.values()}
             self._chunk_tiles[b] = tiles.pop() if len(tiles) == 1 else None
         return self._chunk_tiles[b]
 
@@ -900,8 +875,7 @@ class ServingEngine:
         """Pool-bearing state node names in SORTED order — the layer
         axis of extract_kv / inject rows. Both handoff sides sort, so
         layer i's extracted rows land in layer i's pool."""
-        return sorted(n for n, ws in self.decode_model._state.items()
-                      if "pool_k" in ws)
+        return [name for name, *_ in self._handoff_leaves]
 
     def extract_kv(self, slot_index: int, num_tokens: int):
         """Lift a slot's prompt-extent KV blocks off this engine's
@@ -910,18 +884,15 @@ class ServingEngine:
         hook — the completing slot's page table still maps the blocks."""
         import jax
 
-        refuse_recurrent(self.decode_model, "extract_kv (the KV handoff)")
-        refuse_indexed(self.decode_model,
-                       "serving/engine.py: extract_kv (the KV handoff)")
-        refuse_windowed(self.decode_model,
-                        "serving/engine.py: extract_kv (the KV handoff)")
+        refuse(self.decode_model,
+               "serving/engine.py: extract_kv (the KV handoff)", HANDOFF)
         self._complete_in_flight()
         mgr = self.block_manager
         nblk = -(-num_tokens // mgr.block_size)
         idx = np.asarray(mgr.table(slot_index)[:nblk], np.int32)
         st = self.decode_model._state
-        ks = [st[name]["pool_k"][idx] for name in self.kv_pool_layers()]
-        vs = [st[name]["pool_v"][idx] for name in self.kv_pool_layers()]
+        ks = [st[name][k][idx] for name, k, _ in self._handoff_leaves]
+        vs = [st[name][v][idx] for name, _, v in self._handoff_leaves]
         ks, vs = jax.device_get((ks, vs))
         return (np.stack([np.asarray(k) for k in ks]),
                 np.stack([np.asarray(v) for v in vs]))
@@ -942,12 +913,8 @@ class ServingEngine:
         if mgr is None:
             raise ValueError(
                 "disaggregated admission requires the paged KV layout")
-        refuse_recurrent(self.decode_model,
-                         "admit_prefilled (the KV handoff)")
-        refuse_indexed(self.decode_model, "serving/engine.py: "
-                       "admit_prefilled (the KV handoff)")
-        refuse_windowed(self.decode_model, "serving/engine.py: "
-                        "admit_prefilled (the KV handoff)")
+        refuse(self.decode_model, "serving/engine.py: admit_prefilled "
+               "(the KV handoff)", HANDOFF)
         self._complete_in_flight()
         if not sched.free_slots:
             return None
@@ -995,7 +962,8 @@ class ServingEngine:
 
         if self._inject_fn is None:
             self._inject_fn = (
-                self.decode_model.executor.build_kv_inject())
+                self.decode_model.executor.build_kv_inject(
+                    self._handoff_leaves))
         b = 1
         while b < len(blocks):
             b *= 2
@@ -1689,15 +1657,9 @@ class ServingEngine:
         pool for paged — counted once, however many page tables map its
         blocks — or the full (slots, max_seq+1) region for contiguous.
         The serving bench's slots-at-fixed-HBM comparison reads this."""
-        for n in self.decode_model.graph.topo_order():
-            if n.op_type in PAGED_OPS:
-                p = n.params
-                widths = (p.front if n.op_type
-                          == OT.OP_PAGED_LATENT_ATTENTION else p)
-                return (self._kv_itemsize * p.num_blocks * p.block_size
-                        * sum(widths.cache_row_widths.values()))
-            if n.op_type == OT.OP_INC_MULTIHEAD_ATTENTION:
-                p = n.params
-                return 2 * self._kv_itemsize * self.spec.slots \
-                    * (p.max_seq_len + 1) * p.embed_dim
+        for name, s in self._states.items():
+            kv = s.names(BY_BLOCK, BY_POSITION)
+            if kv:
+                return sum(int(self.decode_model._state[name][leaf].nbytes)
+                           for leaf in kv)
         return 0

@@ -351,17 +351,11 @@ class SpeculativeServingEngine(ServingEngine):
             raise ValueError(
                 "serve(speculate=True) needs draft_model=<a compiled "
                 "FFModel sharing the target's tokenizer/vocab>")
-        from .decode_graph import (
-            refuse_indexed, refuse_recurrent, refuse_windowed,
-        )
+        from .decode_graph import QUERIES, REWIND, refuse
 
-        refuse_recurrent(model, "speculative decoding (rejected "
-                          "proposals are undone by rewinding a cursor)")
-        refuse_indexed(model, "serving/speculative.py: speculative "
-                       "decoding (a verify call is q_len = K + 1)")
-        refuse_windowed(model, "serving/speculative.py: speculative "
-                        "decoding (a block freed behind the proposals' "
-                        "cursor cannot be rolled back)")
+        refuse(model, "serving/speculative.py: speculative decoding (a "
+               "verify call is q_len = K + 1, rejected proposals are undone "
+               "by rewinding a cursor)", QUERIES, REWIND)
         cfg = model.config
         if draft_chips is None:
             draft_chips = int(getattr(cfg, "serve_draft_chips", 0) or 0)

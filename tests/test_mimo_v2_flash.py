@@ -264,14 +264,14 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer(model):
 
 
 def test_what_cannot_carry_a_window_group_refuses_by_name(model):
-    from flexflow_tpu.serving.decode_graph import (
-        refuse_windowed, window_layers,
-    )
+    from flexflow_tpu.ops.base import REWIND
+    from flexflow_tpu.serving.decode_graph import decode_states, refuse
 
-    assert window_layers(model) == ["l1_attn", "l2_attn"]
+    assert [name for name, state in decode_states(model).items()
+            if state.window] == ["l1_attn", "l2_attn"]
     with pytest.raises(NotImplementedError, match="window attention layers "
                        r"\(l1_attn"):
-        refuse_windowed(model, "a test")
+        refuse(model, "a test", REWIND)
     with pytest.raises(NotImplementedError, match="speculative"):
         model.serve(slots=2, max_seq_len=SEQ, speculate=True,
                     draft_model=model)
