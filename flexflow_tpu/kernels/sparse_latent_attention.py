@@ -55,16 +55,22 @@ def attend_chunk(q, pool_c, table, mask, positions, *, latent_dim: int,
     key of a block and the mask picks (an online softmax over the
     blocks). Dense in the context where `attend_selected` is sparse: 256
     rows that share their keys cost less so than 256 x 2,048 gathered
-    rows (PERF.md section 6, PR 31)."""
+    rows (PERF.md section 6, PR 31). `mask` None: no selection, a row
+    attends every position up to its own, and a block's mask is made from
+    `positions` inside the walk (no (rows, S) mask exists)."""
     rows, heads, _ = q.shape
     table, p, span, blocks = _chunk_blocks(table, pool_c.shape[1], positions)
-    mask = chunk_mask_blocks(mask, table, pool_c.shape[1])
+    if mask is not None:
+        mask = chunk_mask_blocks(mask, table, pool_c.shape[1])
 
     def body(i, carry):
         top, total, acc = carry
         pages = jax.lax.dynamic_slice(table, (i * p,), (p,))
         keys = pool_c[pages].reshape(span, -1).astype(q.dtype)
-        picked = jax.lax.dynamic_slice(mask, (0, i * span), (rows, span))
+        if mask is None:  # a dead row's position is -1: it attends nothing
+            picked = (i * span + jnp.arange(span))[None] <= positions[:, None]
+        else:
+            picked = jax.lax.dynamic_slice(mask, (0, i * span), (rows, span))
         scores = jnp.einsum("rhc,sc->rhs", q, keys,
                             preferred_element_type=jnp.float32) * scale
         scores = jnp.where(picked[:, None, :], scores, NEG)

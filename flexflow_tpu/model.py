@@ -487,8 +487,9 @@ class FFModel:
     def latent_attention(self, input: Tensor, positions: Tensor, front,
                          kernel_initializer: Optional[Initializer] = None,
                          name: str = "") -> Tensor:
-        """Causal latent self-attention with the lightning indexer's
-        top-k selection on (batch, seq, hidden); `front` is an
+        """Causal latent self-attention on (batch, seq, hidden), under
+        the lightning indexer's top-k selection where `front` has one
+        (`front.index`), over the whole past where not; `front` is an
         ops.latent_attention.LatentFrontEnd (ops/latent_attention.py,
         which this call imports: no other graph pays for it)."""
         from .ops.latent_attention import LatentAttentionParams

@@ -21,6 +21,7 @@ from .transformer import (
     deepseek_v32_lm_config,
     keye_vl2_lm_config,
     mimo_v2_flash_lm_config,
+    mistral_small4_lm_config,
     olmoe_lm_config,
     solar_open2_lm_config,
     transformer_lm_param_count,

@@ -96,8 +96,9 @@ class TransformerLMConfig:
     num_experts_per_tok: int = 0
     moe_intermediate_size: int = 0
     router_aux_loss_coef: float = 0.0
-    # DeepSeek-V3.2 (`deepseek_v32_lm_config`): attention="latent" builds
-    # latent attention with the lightning indexer from `latent` (an
+    # DeepSeek-V3.2 (`deepseek_v32_lm_config`) and Mistral-Small-4
+    # (`mistral_small4_lm_config`): attention="latent" builds latent
+    # attention, with the lightning indexer or with none, from `latent` (an
     # ops.latent_attention.LatentFrontEnd, positions go to it); the first
     # `first_k_dense` layers of an mlp="moe" stack are swiglu ones of
     # `intermediate_size`; `moe_routing` holds the further fields of
@@ -189,6 +190,27 @@ def olmoe_lm_config(**sizes) -> TransformerLMConfig:
         mlp="moe", **sizes)
 
 
+def _latent_widths(config: dict) -> dict:
+    """LatentFrontEnd's widths from the keys the latent-attention family
+    publishes them under."""
+    return dict(
+        embed_dim=config["hidden_size"],
+        num_heads=config["num_attention_heads"],
+        q_lora_rank=config["q_lora_rank"],
+        kv_lora_rank=config["kv_lora_rank"],
+        qk_nope_head_dim=config["qk_nope_head_dim"],
+        qk_rope_head_dim=config["qk_rope_head_dim"],
+        v_head_dim=config["v_head_dim"])
+
+
+def _yarn(scaling):
+    """LatentFrontEnd.rope_scaling of a config's YaRN group, if any."""
+    return None if not scaling else (
+        scaling["factor"], scaling["original_max_position_embeddings"],
+        scaling["beta_fast"], scaling["beta_slow"],
+        scaling["mscale_all_dim"])
+
+
 def deepseek_v32_lm_config(config: dict, *, sequence_length: int,
                            attention_impl: str = "xla",
                            initializer_range: float = 0.006
@@ -198,27 +220,17 @@ def deepseek_v32_lm_config(config: dict, *, sequence_length: int,
     the equations out). A cut configuration states the experts one chip
     holds as `experts_held` = [first id, count] beside `experts_routed`,
     the router's width (both default to all of `n_routed_experts`)."""
-    from ..ops.latent_attention import LatentFrontEnd
+    from ..ops.latent_attention import LatentFrontEnd, LatentIndexer
 
-    scaling = config.get("rope_scaling")
     front = LatentFrontEnd(
-        embed_dim=config["hidden_size"],
-        num_heads=config["num_attention_heads"],
-        q_lora_rank=config["q_lora_rank"],
-        kv_lora_rank=config["kv_lora_rank"],
-        qk_nope_head_dim=config["qk_nope_head_dim"],
-        qk_rope_head_dim=config["qk_rope_head_dim"],
-        v_head_dim=config["v_head_dim"],
-        index_n_heads=config["index_n_heads"],
-        index_head_dim=config["index_head_dim"],
-        index_topk=config["index_topk"],
+        **_latent_widths(config),
+        index=LatentIndexer(
+            n_heads=config["index_n_heads"],
+            head_dim=config["index_head_dim"], topk=config["index_topk"],
+            norm_eps=config.get("index_norm_eps", 1e-6)),
         rope_theta=float(config["rope_theta"]),
-        rope_scaling=None if not scaling else (
-            scaling["factor"], scaling["original_max_position_embeddings"],
-            scaling["beta_fast"], scaling["beta_slow"],
-            scaling["mscale_all_dim"]),
-        norm_eps=config["rms_norm_eps"],
-        index_norm_eps=config.get("index_norm_eps", 1e-6))
+        rope_scaling=_yarn(config.get("rope_scaling")),
+        norm_eps=config["rms_norm_eps"])
     held = config.get("experts_held")
     routed = config.get("experts_routed", config["n_routed_experts"])
     return TransformerLMConfig(
@@ -243,6 +255,58 @@ def deepseek_v32_lm_config(config: dict, *, sequence_length: int,
                                       * config["moe_intermediate_size"]),
             experts_held=None if held is None else tuple(held)),
         initializer_range=initializer_range)
+
+
+def mistral_small4_lm_config(config: dict, *, sequence_length: int,
+                             attention_impl: str = "xla",
+                             initializer_range: float = 0.02,
+                             embedding_range: float = 1.0
+                             ) -> TransformerLMConfig:
+    """The language model of Mistral-Small-4 from the keys of its
+    published config.json (`model_type: mistral4`;
+    models/mistral_small4_reference.py writes the equations out): latent
+    attention with NO selection (a row attends its whole past), YaRN's
+    numbers and theta under `rope_parameters`, the query of position t
+    scaled by 1 + `llama_4_scaling_beta` ln(1 + floor(t / original)),
+    every layer with routed experts under a renormalised softmax router
+    (no groups, no correction bias) and a shared expert. A cut
+    configuration states `experts_held` / `experts_routed` as
+    DeepSeek-V3.2's does. `embedding_range` 1.0: the embedding alone is
+    N(0, 1), as an untrained stack needs for rows that differ (PERF.md
+    section 6, PR 38)."""
+    from ..ops.latent_attention import LatentFrontEnd
+
+    if config["first_k_dense_replace"] or config["n_group"] != 1:
+        raise NotImplementedError(
+            "mistral_small4_lm_config builds the published block: "
+            "first_k_dense_replace 0, n_group 1")
+    rope = config["rope_parameters"]
+    front = LatentFrontEnd(
+        **_latent_widths(config),
+        rope_theta=float(rope["rope_theta"]), rope_scaling=_yarn(rope),
+        norm_eps=config["rms_norm_eps"],
+        query_scale=(float(rope["llama_4_scaling_beta"]),
+                     int(rope["original_max_position_embeddings"])))
+    held = config.get("experts_held")
+    return TransformerLMConfig(
+        vocab_size=config["vocab_size"], hidden_size=config["hidden_size"],
+        num_heads=config["num_attention_heads"],
+        num_layers=config["num_hidden_layers"],
+        sequence_length=sequence_length, attention_impl=attention_impl,
+        norm="rmsnorm", norm_eps=config["rms_norm_eps"], position="rope",
+        rope_theta=float(rope["rope_theta"]), attention_bias=False,
+        attention="latent", latent=front, mlp="moe", first_k_dense=0,
+        num_experts=config.get("experts_routed", config["n_routed_experts"]),
+        num_experts_per_tok=config["num_experts_per_tok"],
+        moe_intermediate_size=config["moe_intermediate_size"],
+        moe_routing=dict(
+            scoring="softmax", norm_topk_prob=config["norm_topk_prob"],
+            routed_scaling_factor=float(config["routed_scaling_factor"]),
+            shared_intermediate_size=(config["n_shared_experts"]
+                                      * config["moe_intermediate_size"]),
+            experts_held=None if held is None else tuple(held)),
+        initializer_range=initializer_range,
+        embedding_range=embedding_range)
 
 
 def solar_open2_lm_config(config: dict, *, sequence_length: int,

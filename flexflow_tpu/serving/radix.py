@@ -105,7 +105,10 @@ class RadixPrefixCache:
         blocks: list[int] = []
         now = 0 if peek else self._tick()
         while covered < len(prompt):
-            tail = prompt[covered:covered + bs]
+            # a tuple, as a node's run is: a list never equals a tuple, and
+            # `_common_len` would compare a whole block element by element
+            # in Python (2 ms a match of a 60 k-token prompt)
+            tail = tuple(prompt[covered:covered + bs])
             best = None
             best_len = 0
             for child in node.children:

@@ -670,6 +670,80 @@ def test_train_step_lowers_for_four_chips(topology, monkeypatch, mesh_flag,
     assert "all-reduce" in text
 
 
+def _ms4_front():
+    from flexflow_tpu.ops.latent_attention import LatentFrontEnd
+
+    return LatentFrontEnd(
+        embed_dim=4096, num_heads=32, q_lora_rank=1024, kv_lora_rank=256,
+        qk_nope_head_dim=64, qk_rope_head_dim=64, v_head_dim=128,
+        rope_scaling=(128, 8192, 32, 1, 1), query_scale=(0.1, 8192))
+
+
+@pytest.mark.parametrize("rows", [16, 16 + 128, 16 + 256])
+def test_latent_layer_without_a_selection_at_mistral_small4_widths(tpu, rows):
+    """A layer of `ms4-serve-longctx` as the decode graph runs it: 32
+    heads over a latent row of 256 + 64 stored 384 wide, a pool of 2,560
+    blocks of 256 rows, page tables 260 wide; 16 decoding rows that read
+    their whole history in the paged latent kernel, and the same with a
+    question's chunk under one page-table row (dense under the causal
+    mask its positions give). The compiled layer holds the kernel once,
+    for the slots' rows; no step copies the pool, and what the layer needs
+    beside its arguments stays under 1 GB (no (rows, context) mask, no
+    gathered history)."""
+    from flexflow_tpu.fftype import DataType, OperatorType as OT
+    from flexflow_tpu.ops.base import OpContext, get_op_def
+    from flexflow_tpu.ops.latent_attention import PagedLatentAttentionParams
+
+    s = _on(tpu[0])
+    p = PagedLatentAttentionParams(
+        _ms4_front(), 66560, 256, 2560, chunk_from=16,
+        cache_dtype=DataType.DT_BFLOAT16)
+    op = get_op_def(OT.OP_PAGED_LATENT_ATTENTION)
+    state = op.state(p)
+    assert state.selected == 0 and [l.name for l in state.leaves] == [
+        "pool_c", "attended"]
+    specs = op.weights(p, [(rows, 1, 4096), (rows, 1), (rows, 260)])
+    weights = {w.name: s(w.shape, jnp.float32 if w.name == "attended"
+                         else jnp.bfloat16) for w in specs}
+    assert weights["pool_c"].shape == (2560, 256, 384)
+    assert weights["attended"].shape == (16, 4096)
+
+    def layer(weights, x, positions, page_table):
+        (y,), new = op.forward(p, [x, positions, page_table], weights, None,
+                               OpContext(training=False, mesh=None))
+        return y, new
+
+    compiled = jax.jit(layer, donate_argnums=(0,)).lower(
+        weights, s((rows, 1, 4096)), s((rows, 1), jnp.int32),
+        s((rows, 260), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert pallas_kernels(text) == {"paged_latent_decode": 1}
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+    assert not re.findall(r"= bf16\[2560,256,\d+\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("why,width,block,lanes,call_gate", [
+    (r"block_size 4 % 16", 8, 4, 384, None),
+    (r"320 lanes", 8, 256, 320, None),
+    (r"4-device mesh", 8, 256, 384,
+     "4-device mesh: kernel not run per shard"),
+])
+def test_paged_latent_decode_refused_takes_the_reference_and_says_so(
+        tpu, why, width, block, lanes, call_gate):
+    """A geometry `paged_latent_gate` refuses and a call the op's own gate
+    refuses (a multi-device mesh) take XLA's gather and einsums; on a TPU
+    that is said, not hidden."""
+    from flexflow_tpu.kernels import paged_latent_attention as pla
+
+    s = _on(tpu[0])
+    fn = lambda *a: pla.attend_rows(  # noqa: E731
+        *a, latent_dim=256, scale=0.1, call_gate=call_gate)
+    with pytest.warns(KernelFallbackWarning, match=why):
+        kernels = _kernels(fn, s((4, 32, lanes)), s((64, block, lanes)),
+                           s((4, width), jnp.int32), s((4,), jnp.int32))
+    assert not kernels
+
+
 def test_chip_smoke_refuses_to_run_without_a_tpu():
     """chip_smoke.py has no CPU mode: with JAX held to the CPU it exits
     non-zero and prints no result line."""

@@ -52,6 +52,33 @@ def _build_lm(mesh=(8, 1, 1, 1), batch=8, argv=()):
 # --------------------------------------------------------- radix (host-side)
 
 
+@pytest.mark.parametrize("as_type", [list, tuple, np.asarray])
+def test_radix_match_compares_a_block_with_a_nodes_run_as_one(as_type,
+                                                              monkeypatch):
+    """A node's run is a tuple and a prompt comes as a list (or an array):
+    `match` hands `_common_len` two tuples, whose whole-run comparison is
+    one `==`; a list slice never equals a tuple, and every cached block
+    would fall to the element-by-element loop (PERF.md section 7, "From PR
+    46": 2 ms a match of a 60 k-token prompt)."""
+    from flexflow_tpu.serving import radix
+
+    seen = []
+    common_len = radix._common_len
+
+    def watched(a, b):
+        seen.append((type(a), type(b)))
+        return common_len(a, b)
+
+    monkeypatch.setattr(radix, "_common_len", watched)
+    cache = radix.RadixPrefixCache(4)
+    history = list(range(10))
+    assert cache.insert(history, [7, 8, 9]) == [7, 8, 9]
+    assert cache.match(as_type(history + [99]), peek=True) == (10, [7, 8, 9])
+    assert cache.match(as_type(history[:6] + [99, 98]), peek=True) == (
+        6, [7, 8])
+    assert seen and set(seen) == {(tuple, tuple)}
+
+
 def test_radix_lru_eviction_never_frees_live_block():
     """Pool pressure evicts cached-ONLY blocks, never a block a live
     slot maps: fill the pool past its budget with distinct published
