@@ -13,9 +13,12 @@ pages, as far as the chunk reaches (a `fori_loop` whose trip count is the
 chunk's last position), so a chunk costs its context once, not once a
 row.
 
-A chunk's rows take no top-k and no gather: their k-th largest score is
-found by bisection and the selection is a mask (`selection_mask`), under
-which the attention runs dense over the shared context.
+The exact top-k is found with no sort, for both kinds of row: the k-th
+largest score by bisection on the scores' bits and the ties at it by rank
+give a mask (`_top_mask`). A chunk's rows take the mask (`selection_mask`)
+and attend dense over the shared context under it, with no gather; a
+decoding row's mask is compacted into its K positions, ascending
+(`select_topk`), for the gather.
 
 What is a kernel: the decoding rows' scores, `paged_index_scores`, a Pallas
 kernel that walks a row's live pages by DMA and scores them in VMEM
@@ -23,13 +26,14 @@ kernel that walks a row's live pages by DMA and scores them in VMEM
 and XLA's gather and einsum elsewhere; tests/test_paged_index_scores.py
 holds the two to each other). What is still jax.numpy and `lax`: a chunk's
 scores (`index_scores_chunk`: XLA's gather and matmul in a `fori_loop`),
-the top-k (`lax.top_k`, a full sort at k = 2,048), the chunk's mask, and
-the selected rows' gather (`gather_selected`, for the two attention
-modules: Mosaic cannot address one token's row of a tiled pool, and where
-it can a DMA a row costs what XLA's gather costs, the comment above that
-function). The contract a kernel has to keep is these functions'
-(tests/test_latent_attention.py and tests/test_keye_vl2.py hold them to
-the float32 references).
+the selection (a rolled loop of 33 counting passes, compares and two small
+matmuls of 0s and 1s: the scores go out to HBM and come back for it, which
+a bisection inside `paged_index_scores` would save), and the selected rows'
+gather (`gather_selected`, for the two attention modules: Mosaic cannot
+address one token's row of a tiled pool, and where it can a DMA a row costs
+what XLA's gather costs, the comment above that function). The contract a
+kernel has to keep is these functions' (tests/test_latent_attention.py and
+tests/test_keye_vl2.py hold them to numpy and to the float32 references).
 """
 
 from __future__ import annotations
@@ -350,27 +354,45 @@ def chunk_mask_blocks(mask, table, bs: int):
     return jnp.pad(mask, ((0, 0), (0, table.shape[0] * bs - mask.shape[1])))
 
 
-def select_topk(index, k: int):
-    """(positions (rows, K) int32, valid (rows, K)) of each row's K = min(k,
-    S) largest index scores, exact; valid is False where a row has fewer
-    candidates than K (its scores there are NEG)."""
-    S = index.shape[1]
-    if S <= k:
-        sel = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), index.shape)
-        return sel, index > NEG / 2
-    vals, sel = jax.lax.top_k(index, k)
-    return sel.astype(jnp.int32), vals > NEG / 2
+# ------------------------------------------------ the exact top-k
+# One way to select, for decoding rows and chunk rows alike: the k-th
+# largest score by bisection on the scores' bits, the ties at it by rank
+# (`_top_mask`). A chunk's rows take the mask as it is; a decoding row's
+# mask is compacted into its K positions (`_compact`). No sort: `lax.top_k`
+# at k = 2,048 is a full sort of a row's 33 k scores, 0.55 ms a layer for
+# 16 rows, a fifth of a decode step, and the attention over a selected set
+# does not read the set's order. What XLA is slow at is kept out: a running
+# count over 33 k columns (`jnp.cumsum`, a reduce-window: 0.108 ms a layer
+# for 16 rows, three times the bisection) and a scatter or gather of
+# scalars (10 ns each); both are small matmuls of 0s and 1s on the MXU,
+# exact in bfloat16 with a float32 sum (PERF.md section 6, PR 47).
+
+_WORD = 32  # positions a packed word of the mask
+_GROUP = 8  # words a group: an output slot finds its group, then its word
 
 
-def selection_mask(index, k: int):
-    """(rows, S) bool: exactly each row's min(k, candidates) largest index
-    scores, ties to the lower position as `lax.top_k` breaks them, with
-    no sort: the k-th largest value by bisection on the scores' bits (33
-    counting passes), then the ties at it by their rank."""
-    rows, S = index.shape
-    seen = index > NEG / 2
-    if S <= k:
-        return seen
+def _running_count(flags):
+    """(rows, S) int32: how many of a row's flags are set up to and with
+    each position. Inside blocks of 128 a matmul with a triangle of ones,
+    the blocks' totals by a short cumsum."""
+    rows, S = flags.shape
+    blocks = -(-S // 128)
+    x = jnp.pad(flags, ((0, 0), (0, blocks * 128 - S)))
+    inside = jnp.einsum(  # fflint: ok low_precision_accum (float32 sum)
+        "rbl,lm->rbm", x.reshape(rows, blocks, 128).astype(jnp.bfloat16),
+        jnp.triu(jnp.ones((128, 128), jnp.bfloat16)),
+        preferred_element_type=jnp.float32).astype(jnp.int32)
+    total = inside[:, :, -1]
+    before = jnp.cumsum(total, axis=-1) - total
+    return (inside + before[:, :, None]).reshape(rows, blocks * 128)[:, :S]
+
+
+def _top_mask(index, k: int):
+    """(rows, S) bool, S > k: exactly each row's min(k, candidates) largest
+    index scores, ties to the lower position as `lax.top_k` breaks them:
+    the k-th largest value by bisection on the scores' bits (33 counting
+    passes, one rolled loop), then the ties at it by their rank."""
+    rows = index.shape[0]
     bits = jax.lax.bitcast_convert_type(index, jnp.int32)
     keyed = jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)  # order as floats
 
@@ -386,7 +408,84 @@ def selection_mask(index, k: int):
     above = keyed > kth[:, None]
     tied = keyed == kth[:, None]
     room = k - jnp.sum(above, axis=-1, keepdims=True)
-    return seen & (above | (tied & (jnp.cumsum(tied, axis=-1) <= room)))
+    first = tied & (_running_count(tied) <= room)
+    return (index > NEG / 2) & (above | first)
+
+
+def _compact(mask, K: int):
+    """(positions (rows, K) int32, valid (rows, K)) of a mask (rows, S) with
+    at most K set a row: the set positions ascending, `valid` over them, 0
+    behind. The mask is packed 32 positions a word and the words counted
+    in groups of `_GROUP`; output slot j lies in the one group whose running
+    count spans j, and takes that group's words, first count and number by
+    a one-hot matmul (groups x K compares and one small matmul over bytes,
+    as `gather_selected` looks a block up by comparison); then the word and
+    the bit inside it by their counts."""
+    rows, S = mask.shape
+    groups = -(-S // (_WORD * _GROUP))
+    n = groups * _GROUP
+    bits = jnp.pad(mask, ((0, 0), (0, _WORD * n - S))).reshape(rows, n, _WORD)
+    words = jnp.sum(bits.astype(jnp.uint32)
+                    << jnp.arange(_WORD, dtype=jnp.uint32), axis=-1)
+    words = words.reshape(rows, groups, _GROUP)
+    per = jnp.sum(jax.lax.population_count(words).astype(jnp.int32), axis=-1)
+    end = jnp.cumsum(per, axis=-1)  # (rows, groups)
+    start = end - per
+    slot = jnp.arange(K, dtype=jnp.int32)
+    hit = ((start[:, :, None] <= slot) & (slot < end[:, :, None]))
+    table = jnp.concatenate(
+        [words, start.astype(jnp.uint32)[:, :, None],
+         jnp.broadcast_to(jnp.arange(groups, dtype=jnp.uint32)[None, :, None],
+                          (rows, groups, 1))], axis=-1)
+    planes = [table >> shift & 0xFF for shift in (0, 8, 16, 24)]
+    got = jnp.einsum(  # fflint: ok low_precision_accum (float32 sum)
+        "rgc,rgk->rck", jnp.concatenate(planes, -1).astype(jnp.bfloat16),
+        hit.astype(jnp.bfloat16), preferred_element_type=jnp.float32)
+    got = got.astype(jnp.uint32).reshape(rows, 4, _GROUP + 2, K)
+    got = got[:, 0] | got[:, 1] << 8 | got[:, 2] << 16 | got[:, 3] << 24
+    word8 = got[:, :_GROUP]  # (rows, _GROUP, K): the slot's group
+    rank = slot - got[:, _GROUP].astype(jnp.int32)  # among the group's
+    group = got[:, _GROUP + 1].astype(jnp.int32)
+    ones8 = jax.lax.population_count(word8).astype(jnp.int32)
+    before = jnp.cumsum(ones8, axis=1) - ones8
+    mine = (before <= rank[:, None]) & (rank[:, None] < before + ones8)
+    which = jnp.sum(jnp.where(
+        mine, jnp.arange(_GROUP, dtype=jnp.int32)[None, :, None], 0), axis=1)
+    word = jnp.sum(jnp.where(mine, word8, 0), axis=1)
+    rank = rank - jnp.sum(jnp.where(mine, before, 0), axis=1)
+    bit = jnp.zeros_like(rank)
+    for half in (16, 8, 4, 2, 1):  # the rank-th set bit of the word
+        low = word & jnp.uint32((1 << half) - 1)
+        under = jax.lax.population_count(low).astype(jnp.int32)
+        up = rank >= under
+        word = jnp.where(up, word >> half, low)
+        rank = jnp.where(up, rank - under, rank)
+        bit = bit + jnp.where(up, half, 0)
+    valid = slot < end[:, -1:]
+    sel = (group * _GROUP + which) * _WORD + bit
+    return jnp.where(valid, sel, 0), valid
+
+
+def select_topk(index, k: int):
+    """(positions (rows, K) int32, valid (rows, K)) of each row's K = min(k,
+    S) largest index scores, exact: `lax.top_k`'s set, ties to the lower
+    position. `valid` is a prefix of a row, False where the row has fewer
+    candidates than K (its scores there are NEG); the positions behind it
+    are in range. The order inside the prefix is not the scores'."""
+    S = index.shape[1]
+    if S <= k:
+        sel = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), index.shape)
+        return sel, index > NEG / 2
+    return _compact(_top_mask(index, k), k)
+
+
+def selection_mask(index, k: int):
+    """(rows, S) bool: exactly each row's min(k, candidates) largest index
+    scores, ties to the lower position as `lax.top_k` breaks them, with
+    no sort (`_top_mask`)."""
+    if index.shape[1] <= k:
+        return index > NEG / 2
+    return _top_mask(index, k)
 
 
 def causal_selection_mask(qi, wt, ki, topk: int):

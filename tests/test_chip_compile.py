@@ -65,6 +65,21 @@ def _kernels(fn, *shapes):
     return pallas_kernels(jax.jit(fn).lower(*shapes).compile().as_text())
 
 
+def _selection_passes(text, S):
+    """How the top-k under `dsa.topk` was compiled: (sorts over S columns,
+    `while` loops, counting passes in the loops' bodies). A `lax.top_k` at
+    k = 2,048 is a `sort` of (rows, S) floats with their positions; the
+    bisection is one `while` a selection whose body holds ONE count over
+    the scores, where an unrolled loop would be no `while` and 33 counts."""
+    under = [line for line in text.splitlines() if "dsa.topk" in line]
+    sorts = [line for line in under
+             if re.search(r" sort\(", line) and f",{S}]" in line]
+    loops = [line for line in under if re.search(r" while\(", line)]
+    counts = [line for line in under if "dsa.topk/while/body" in line
+              and re.search(r"= s32\[\d+\]\S* reduce\(", line)]
+    return len(sorts), len(loops), len(counts)
+
+
 def _on(device):
     one = SingleDeviceSharding(device)
     return lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
@@ -170,8 +185,9 @@ def test_sparse_latent_attention_at_deepseek_v32_widths(tpu, rows):
     with a 256-token chunk under one page-table row (its selection a mask
     by bisection, its attention dense over the shared context). The 16
     rows' scores are the paged indexer kernel's, once a layer whatever
-    rides beside them; the rest is XLA's gather, matmul and sort, and what
-    the step needs beside its arguments stays under 2 GB."""
+    rides beside them; the rest is XLA's gather, matmuls and one rolled
+    loop a selection (no sort of the 33,280 scores: `_selection_passes`),
+    and what the step needs beside its arguments stays under 2 GB."""
     from flexflow_tpu.kernels import sparse_latent_attention as sla
 
     s = _on(tpu[0])
@@ -180,15 +196,18 @@ def test_sparse_latent_attention_at_deepseek_v32_widths(tpu, rows):
     def layer(qi, wt, q, pool_i, pool_c, table, pos):
         index = sla.index_scores_rows(qi[:n], wt[:n], pool_i, table[:n],
                                       pos[:n])
-        sel, valid = sla.select_topk(index, 2048)
+        with jax.named_scope("dsa.topk"):  # as ops/latent_attention.py does
+            sel, valid = sla.select_topk(index, 2048)
         out = sla.attend_selected(q[:n], pool_c, table[:n], sel, valid,
                                   latent_dim=512, scale=0.1)
         if rows > n:
             index = sla.index_scores_chunk(qi[n:], wt[n:], pool_i, table[n],
                                            pos[n:])
+            with jax.named_scope("dsa.topk"):
+                mask = sla.selection_mask(index, 2048)
             out = jnp.concatenate([out, sla.attend_chunk(
-                q[n:], pool_c, table[n], sla.selection_mask(index, 2048),
-                pos[n:], latent_dim=512, scale=0.1)], 0)
+                q[n:], pool_c, table[n], mask, pos[n:], latent_dim=512,
+                scale=0.1)], 0)
         return out
 
     compiled = jax.jit(layer).lower(
@@ -197,6 +216,9 @@ def test_sparse_latent_attention_at_deepseek_v32_widths(tpu, rows):
         s((rows,), jnp.int32)).compile()
     assert pallas_kernels(compiled.as_text()) == {"paged_index_scores": 1}
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+    selections = 1 + (rows > n)  # the slots' rows, the chunk's mask
+    assert _selection_passes(compiled.as_text(), 33280) == (
+        0, selections, selections)
 
 
 @pytest.mark.parametrize("rows", [16, 144, 272])
@@ -289,8 +311,9 @@ def test_selected_grouped_attention_at_keye_vl2_widths(tpu, rows):
     bisection, its attention dense over the shared context). The compiled
     layer holds the paged indexer kernel once, for the slots' rows: the
     count that says the mechanism engaged; the rest is XLA's gather,
-    matmul and sort, and what the layer needs beside its arguments stays
-    under 2 GB."""
+    matmuls and one rolled loop a selection (no sort of the 33,536 scores
+    under `dsa.topk`: `_selection_passes`), and what the layer needs beside
+    its arguments stays under 2 GB."""
     from flexflow_tpu.ops.attention import AttentionFrontEnd, Indexer
 
     s = _on(tpu[0])
@@ -312,6 +335,8 @@ def test_selected_grouped_attention_at_keye_vl2_widths(tpu, rows):
     text = compiled.as_text()
     assert pallas_kernels(text) == {"paged_index_scores": 1}
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+    selections = 1 + (rows > 16)  # the slots' rows, the chunk's mask
+    assert _selection_passes(text, 33536) == (0, selections, selections)
     # no step copies a pool (a row of 64 lanes made every step copy the
     # indexer pool into the row layout and back)
     import re

@@ -37,8 +37,8 @@ GEOMETRIES = {
     "128x576v512": (_latent, (128, 576), 576),
 }
 # (selected rows K, the rows' valid counts): a prefix is valid, as
-# `select_topk` leaves it (the largest first; a context shorter than the
-# top-k is `arange` with its seen positions first)
+# `select_topk` leaves it (the selected positions first, ascending; a
+# context shorter than the top-k is `arange` with its seen positions first)
 CASES = {
     "all_valid": (256, [256, 256, 256]),
     "fewer_than_k_valid": (256, [255, 1, 130]),
@@ -142,6 +142,31 @@ def test_a_context_shorter_than_the_top_k(geometry):
                             np.asarray(picked) % BS]
     np.testing.assert_array_equal(got, want)
     assert not got[2].any()  # a row that has seen nothing reads zeros
+
+
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_a_context_longer_than_the_top_k(geometry):
+    """`select_topk` over more candidates than k (the bisection and the
+    compaction, no sort) hands `gather_selected` a valid prefix of exactly
+    the k best positions, and a row with fewer candidates a shorter one:
+    the rows gathered are those of numpy's stable argsort, and nothing
+    behind a prefix is read."""
+    _, pool, table, _, _ = _operands(geometry, 128, [1, 1, 1, 1])
+    S, k = W * BS, 200
+    positions = np.array([S - 1, 450, 99, -1])
+    index = np.round(np.random.RandomState(5).randn(4, S), 1)  # ties
+    index = np.where(np.arange(S)[None, :] <= positions[:, None], index,
+                     sel.NEG).astype(np.float32)
+    picked, valid = jax.jit(sel.select_topk, static_argnums=1)(
+        jnp.asarray(index), k)
+    assert picked.shape == (4, k)
+    assert np.asarray(valid).sum(axis=1).tolist() == [k, k, 100, 0]
+    got = np.asarray(sel.gather_selected(pool, table, picked, valid))
+    for r, count in enumerate([k, k, 100, 0]):
+        best = np.sort(np.argsort(-index[r], kind="stable")[:count])
+        want = np.asarray(pool)[np.asarray(table)[r, best // BS], best % BS]
+        np.testing.assert_array_equal(got[r, :count], want)
+        assert not got[r, count:].any()
 
 
 @pytest.mark.parametrize("geometry", sorted(GEOMETRIES))

@@ -359,27 +359,93 @@ def test_a_chunk_step_records_the_slots_experts():
     assert "expert_ids" not in rectangle
 
 
-@pytest.mark.parametrize("k", [1, 5, 16, 40])
-def test_selection_mask_is_the_exact_top_k_without_a_sort(k):
-    """Bisection on the scores' bits and the ties by rank give
-    `lax.top_k`'s set: negative scores, exact zeros in runs (ties to the
-    lower position), rows with fewer candidates than k, dead rows."""
-    from flexflow_tpu.kernels import sparse_latent_attention as sla
+def _cell_scores(rng, S):
+    """A decode step's 16 rows at a cell's width: sums of ReLUs with exact
+    zeros (a run of them; one row's across the k-th place), NEG behind a
+    row's length of 8-33 k."""
+    from flexflow_tpu.kernels.sparse_selection import NEG
 
-    rng = np.random.default_rng(k)
-    index = rng.normal(size=(6, 40)).astype(np.float32)
-    index[1, rng.integers(0, 40, 25)] = 0.0          # a ReLU's zeros
-    index[2] = np.round(index[2])                    # many ties
-    index[3, 7:] = sla.NEG                           # 7 candidates
-    index[4] = sla.NEG                               # a dead row
+    lengths = np.exp(np.linspace(np.log(8555), np.log(S - 1), 16))
+    index = np.maximum(rng.normal(size=(16, 2, S)), 0).sum(axis=1)
+    index[:, S // 30:S // 10] = 0.0
+    index[3] = np.where(rng.random(S) < 0.97, 0.0, index[3])
+    index[np.arange(S)[None, :] >= lengths.astype(int)[:, None]] = NEG
+    return index
+
+
+def _small_scores(rng):
+    from flexflow_tpu.kernels.sparse_selection import NEG
+
+    index = rng.normal(size=(6, 40))
+    index[1, rng.integers(0, 40, 25)] = 0.0  # a ReLU's zeros
+    index[2] = np.round(index[2])            # many ties
+    index[3, 7:] = NEG                       # 7 candidates
+    index[4] = NEG                           # a dead row
     index[5] = -np.abs(index[5])
-    got = np.asarray(sla.selection_mask(jnp.asarray(index), k))
-    sel, valid = sla.select_topk(jnp.asarray(index), k)
-    want = np.zeros_like(got)
-    for r in range(6):
-        want[r, np.asarray(sel)[r][np.asarray(valid)[r]]] = True
-    assert np.array_equal(got, want)
-    assert got[4].sum() == 0 and got[3].sum() == min(k, 7)
+    return index
+
+
+def _seen(rng, S, lengths):
+    """Random scores (rows, S), NEG from each row's length on."""
+    from flexflow_tpu.kernels.sparse_selection import NEG
+
+    return np.where(np.arange(S)[None, :] < np.asarray(lengths)[:, None],
+                    rng.normal(size=(len(lengths), S)), NEG)
+
+
+# name -> rng -> (index scores (rows, S), k)
+SELECTION_CASES = {
+    "small_k1": lambda rng: (_small_scores(rng), 1),
+    "small_k5": lambda rng: (_small_scores(rng), 5),
+    "small_k16": lambda rng: (_small_scores(rng), 16),
+    "small_k40": lambda rng: (_small_scores(rng), 40),
+    "keye2_cell": lambda rng: (_cell_scores(rng, 33536), 2048),
+    "dsv32_cell": lambda rng: (_cell_scores(rng, 33280), 2048),
+    "zeros_across_the_kth_place": lambda rng: (
+        np.where(rng.random((4, 3000)) < 0.9, 0.0, 1.0), 500),
+    "all_scores_equal": lambda rng: (np.full((3, 1000), 0.25), 128),
+    "negative_scores": lambda rng: (-np.abs(rng.normal(size=(4, 700))), 200),
+    "fewer_candidates_than_k": lambda rng: (
+        _seen(rng, 900, [5, 199, 200, 201]), 200),
+    "a_dead_row": lambda rng: (_seen(rng, 600, [600, 0, 600]), 128),
+    "S_at_most_k": lambda rng: (_seen(rng, 300, [300, 41, 0]), 2048),
+    "k_of_no_whole_lane_tile": lambda rng: (
+        np.round(rng.normal(size=(5, 1500)), 1), 200),
+    "one_row": lambda rng: (rng.normal(size=(1, 5000)), 2048),
+    "S_of_no_whole_word": lambda rng: (
+        np.round(rng.normal(size=(3, 1001)), 1), 333),
+}
+
+
+@pytest.mark.parametrize("case", list(SELECTION_CASES))
+def test_selection_mask_is_the_exact_top_k_without_a_sort(case):
+    """`select_topk` and `selection_mask` share their bisection and their
+    ties, so both are held to numpy: a stable argsort of the negated
+    scores, the first K of a row's candidates (`lax.top_k`'s set, ties to
+    the lower position). `select_topk`'s `valid` is a prefix with that
+    count, its positions ascending inside the prefix and in range behind
+    it."""
+    from flexflow_tpu.kernels import sparse_selection as sel
+
+    index, k = SELECTION_CASES[case](
+        np.random.default_rng(sum(map(ord, case))))
+    index = index.astype(np.float32)
+    rows, S = index.shape
+    mask = np.asarray(jax.jit(sel.selection_mask, static_argnums=1)(
+        jnp.asarray(index), k))
+    picked, valid = map(np.asarray, jax.jit(
+        sel.select_topk, static_argnums=1)(jnp.asarray(index), k))
+    K = min(k, S)
+    assert picked.shape == valid.shape == (rows, K) and mask.shape == (rows, S)
+    assert picked.dtype == np.int32 and valid.dtype == bool
+    assert picked.min() >= 0 and picked.max() < S
+    for r in range(rows):
+        order = np.argsort(-index[r], kind="stable")
+        want = order[index[r][order] > sel.NEG / 2][:K]
+        count = int(valid[r].sum())
+        assert count == len(want) and valid[r, :count].all()
+        assert np.array_equal(picked[r, :count], np.sort(want))
+        assert np.array_equal(np.flatnonzero(mask[r]), np.sort(want))
 
 
 def test_a_chunks_dense_attention_is_its_rows_sparse_attention():
