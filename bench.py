@@ -426,24 +426,19 @@ def _ring_rs_microbench(n: int, rows: int = 4096, cols: int = 512,
 def _param_sharding_legs(cfg, batch: int, steps: int, warmup: int,
                          on_tpu: bool) -> dict:
     """ZeRO-3 / FSDP ablation (docs/performance.md "Parameter sharding"):
-    the same LM on a pure-dp mesh over all local devices, measured four
+    the same LM on a pure-dp mesh over all local devices, measured three
     ways —
 
     - replicated: every chip holds the full model + full optimizer state
       (--weight-update-sharding=off);
     - stage2: masters/grads/slots 1/dp, params gathered-and-resident
       (=stage2);
-    - stage3: params sharded at rest, per-layer just-in-time ring
-      all-gather issued one layer ahead, gathered copy dropped after
-      last use (=stage3);
-    - stage3_serial: same layout, --no-overlap-collectives — isolates
-      the one-layer-ahead overlap from the memory win.
+    - stage3: params sharded at rest, each layer's weights all-gathered
+      where the layer uses them, once a step (=stage3).
 
     Each leg reports tokens/s, per-step seconds, ADDRESSABLE param bytes
     on chip 0 at rest (the 1/shards reading), allocator peak HBM (null
-    on XLA:CPU), and the realized update stage. Plus a ring_all_gather
-    overlap-vs-serial microbench — the gather schedule measured in
-    isolation, the AG twin of the grad-sync RS microbench."""
+    on XLA:CPU), and the realized update stage."""
     import jax
 
     n = min(jax.local_device_count(), batch)
@@ -452,22 +447,18 @@ def _param_sharding_legs(cfg, batch: int, steps: int, warmup: int,
         legs["skipped"] = "single device — nothing to shard"
         return legs
 
-    def tune_of(stage, overlap=True):
+    def tune_of(stage):
         def tune(c):
             c.mesh_axis_sizes = (n, 1, 1, 1)
             c.weight_update_sharding = stage >= 2
             c.weight_update_stage = stage
-            c.overlap_collectives = overlap
 
         return tune
 
-    for name, stage, overlap in (("replicated", 0, True),
-                                 ("stage2", 2, True),
-                                 ("stage3", 3, True),
-                                 ("stage3_serial", 3, False)):
+    for name, stage in (("replicated", 0), ("stage2", 2), ("stage3", 3)):
         mem: dict = {}
         tps, _ = _measure_lm(cfg, batch, steps, warmup, on_tpu,
-                             tune=tune_of(stage, overlap), out=mem)
+                             tune=tune_of(stage), out=mem)
         legs[name] = {
             "tokens_per_sec": None if tps is None else round(tps, 2),
             "step_time_s": (None if not tps else
@@ -487,68 +478,7 @@ def _param_sharding_legs(cfg, batch: int, steps: int, warmup: int,
     if rep.get("tokens_per_sec") and s3.get("tokens_per_sec"):
         legs["stage3_vs_replicated"] = round(
             s3["tokens_per_sec"] / rep["tokens_per_sec"], 4)
-    ss = legs["stage3_serial"]
-    if ss.get("tokens_per_sec") and s3.get("tokens_per_sec"):
-        legs["overlap_vs_serial"] = round(
-            s3["tokens_per_sec"] / ss["tokens_per_sec"], 4)
-    try:
-        legs["ag_microbench"] = _ring_ag_microbench(n)
-    except Exception as e:  # pragma: no cover - defensive
-        print(f"bench: ring-AG microbench failed: {e}", file=sys.stderr)
     return legs
-
-
-def _ring_ag_microbench(n: int, rows: int = 4096, cols: int = 512,
-                        iters: int = 8) -> dict:
-    """Seconds per all-gather of a (rows, cols) fp32 buffer sharded over
-    a dp=n mesh: the hop-before-use double-buffered ppermute ring
-    (parallel.ops.ring_all_gather — the stage-3 per-layer gather
-    schedule) vs the serial ablation whose barrier makes every hop wait
-    for the previous local write. Two-point slope over a jitted
-    fori_loop, like every other bench leg."""
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from flexflow_tpu.machine import MeshShape, build_mesh
-    from flexflow_tpu.parallel.ops import ring_all_gather
-
-    rows -= rows % n
-    mesh = build_mesh(MeshShape((n, 1, 1, 1)))
-    sharded = NamedSharding(mesh, P("data", None))
-    x = jax.device_put(
-        jnp.arange(rows * cols, dtype=jnp.float32).reshape(rows, cols),
-        sharded)
-    out = {}
-    for name, overlap in (("overlap", True), ("serial", False)):
-        ag = functools.partial(ring_all_gather, mesh=mesh,
-                               axis_name="data", overlap=overlap)
-
-        @jax.jit
-        def loop(x0, m):
-            def body(_, acc):
-                # gather, rescale, re-slice to the at-rest layout (the
-                # slice is local/free — the gather dominates)
-                full = ag(acc) * 1e-3
-                return jax.lax.with_sharding_constraint(full, sharded)
-
-            return jax.lax.fori_loop(0, m, body, x0)
-
-        jax.block_until_ready(loop(x, jnp.int32(iters)))  # compile + warm
-        t1 = time.perf_counter()
-        jax.block_until_ready(loop(x, jnp.int32(iters)))
-        t1 = time.perf_counter() - t1
-        t2 = time.perf_counter()
-        jax.block_until_ready(loop(x, jnp.int32(3 * iters)))
-        t2 = time.perf_counter() - t2
-        out[f"{name}_s"] = max((t2 - t1) / (2 * iters), 0.0)
-    if out.get("serial_s") and out.get("overlap_s"):
-        out["overlap_vs_serial"] = round(
-            out["serial_s"] / out["overlap_s"], 4)
-    out["bytes"] = rows * cols * 4
-    return out
 
 
 def _rules_leg() -> dict:
@@ -1274,17 +1204,14 @@ def _bench_body(jax, TransformerLMConfig, telemetry, session):
         print(f"bench: grad-sync ablation failed: {e}", file=sys.stderr)
 
     # param-sharding ablation legs (ZeRO-3/FSDP): replicated vs stage-2
-    # vs stage-3 (±overlap) with addressable param bytes/chip at rest,
-    # peak HBM and step time, plus the ring_all_gather microbench
+    # vs stage-3 with addressable param bytes/chip at rest, peak HBM and
+    # step time
     param_sharding = None
     try:
         param_sharding = _param_sharding_legs(cfg, batch, steps, warmup,
                                               on_tpu)
-        print(json.dumps({
-            "metric": "param_sharding_ablation",
-            **{k: v for k, v in param_sharding.items()
-               if k != "ag_microbench"},
-        }))
+        print(json.dumps({"metric": "param_sharding_ablation",
+                          **param_sharding}))
     except Exception as e:  # pragma: no cover - defensive
         print(f"bench: param-sharding ablation failed: {e}",
               file=sys.stderr)

@@ -71,10 +71,9 @@ class FFConfig:
     # weight-update sharding (ZeRO / Xu et al. 2020; FSDP, Zhao et al.
     # 2023): fp32 masters + optimizer slots sharded 1/dp along the
     # gradient-reduction axes (stage 2), and — stage 3 — the trainable
-    # weights themselves sharded at rest with a just-in-time
-    # double-buffered ring all-gather per layer (issued one layer ahead
-    # on the overlappable channel, gathered copy dropped after last use,
-    # backward re-gathers). None (default) = Unity decides by pricing
+    # weights themselves sharded at rest and all-gathered per layer where
+    # the layer uses them (XLA's collective, once a step; the backward
+    # reads the gathered copy). None (default) = Unity decides by pricing
     # replicated vs stage 2 vs stage 3 — sharded is selected exactly
     # when the plan is memory- or grad-sync-bound, and stage 3 exactly
     # when stage 2's resident gathered copies are themselves over the

@@ -446,10 +446,10 @@ def render_markdown(report: dict) -> str:
             f"overlappable channel")
         if stage == 3:
             lines.append(
-                f"- param gather (ZeRO-3/FSDP): just-in-time per-layer "
-                f"ring all-gather, "
+                f"- param gather (ZeRO-3/FSDP): per-layer all-gather, "
                 f"{report.get('param_gather_s', 0.0) * 1e3:.3f} ms "
-                f"issued one layer ahead (fwd + bwd re-gather)")
+                f"priced on the overlappable channel (the price is of "
+                f"two gathers a step; the program runs one)")
     if report.get("profile"):
         p = report["profile"]
         lines += [

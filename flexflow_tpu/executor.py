@@ -25,7 +25,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from .config import FFConfig
@@ -41,16 +40,6 @@ from .pcg.graph import Graph, OpNode
 def _stable_fold(key, name: str):
     h = int.from_bytes(hashlib.md5(name.encode()).digest()[:4], "little")
     return jax.random.fold_in(key, h)
-
-
-# stage-3 (ZeRO-3 / FSDP) residual policy: the jax.checkpoint regions in
-# _forward_gathered save every intermediate EXCEPT the gathered weight
-# copies tagged with this name — so the backward re-gathers them instead
-# of keeping a full per-layer copy live across the whole fwd+bwd, and
-# nothing else is recomputed.
-_GATHER_NAME = "fsdp_gather"
-_FSDP_SAVE_POLICY = (
-    jax.checkpoint_policies.save_anything_except_these_names(_GATHER_NAME))
 
 
 class Executor:
@@ -91,25 +80,21 @@ class Executor:
         self.update_specs: dict[tuple[str, str], tuple] = {}
         # ZeRO-3 / FSDP stage 3 (choose_update_sharding stage == 3): the
         # trainable weights themselves live sharded at rest in the SAME
-        # update_specs layout, and _apply gathers each layer's params
-        # just-in-time with a double-buffered ring all-gather
-        # (parallel/ops.ring_all_gather) issued one layer ahead on the
-        # overlappable channel, the gathered copy dropped after last use
-        # (the backward re-gathers under jax.checkpoint). gather_specs
+        # update_specs layout, and _apply brings each layer's params to
+        # their compute placement where the layer uses them, by XLA's
+        # all-gather (parallel/ops.all_gather), once a step: the
+        # backward reads the forward's gathered copy. gather_specs
         # holds, per sharded weight, what the gather needs: the compute
         # placement it restores, the update axes it unwinds, and the dim
-        # they shard. gather_schedule is the per-layer prefetch schedule
-        # derived from the PCG topological order: entry k's gather is
-        # issued behind entry k-1's compute (XLA's latency-hiding
-        # scheduler realizes the overlap from the ring hops'
-        # data-independence).
+        # they shard. gather_schedule is the order the layers' gathers
+        # enter the step in, from the PCG topological order: (owner, the
+        # owner gathered before it).
         self.update_stage = int(self.update_sharding.get(
             "stage", 2 if self.update_sharding.get("enabled") else 0))
         self.gather_specs: dict[tuple[str, str], tuple] = {}
         self.gather_schedule: list[tuple[str, Optional[str]]] = []
         # custom-VJP gather callables keyed by (owner, wname); built once
-        # per weight at first trace (the overlap flag is read inside
-        # _gather_param at trace time — config is fixed for the compile)
+        # per weight at first trace
         self._gather_fns: dict[tuple[str, str], Any] = {}
         if self.update_sharding.get("enabled"):
             self._build_update_specs()
@@ -293,12 +278,9 @@ class Executor:
                                     sharded_weights=len(self.update_specs),
                                     bytes=total_bytes)
         if self.gather_specs:
-            # one-layer-ahead prefetch schedule from the PCG topological
-            # order: entry k's fwd gather is issued behind entry k-1's
-            # compute (None = the first gather, nothing to hide behind);
-            # the backward walks it in reverse. The ring hops carry no
-            # data dependence on the neighbouring compute, which is what
-            # lets the latency-hiding scheduler realize this schedule.
+            # the order the layers' gathers enter the step in, from the
+            # PCG topological order: entry k names the owner gathered
+            # before it (None for the first)
             owners = []
             for node in self.order:
                 if getattr(node, "weight_source", None):
@@ -309,16 +291,23 @@ class Executor:
             self.gather_schedule = [
                 (name, owners[i - 1] if i > 0 else None)
                 for i, name in enumerate(owners)]
-            gathered_bytes = sum(
-                int(np.prod(shape)) * 4
-                for key, (_spec, shape) in self.update_specs.items()
-                if key in self.gather_specs)
+            # what a step's gathers deliver to a chip's compute
+            # placement, in the dtype the wire carries (XLA hoists the
+            # compute-dtype cast in front of the collective), and how
+            # many collectives the step's graph holds: one an update
+            # axis a weight, the forward's only
+            cd = self.config.computation_dtype
+            itemsize = jnp.dtype(dtype_to_jnp(cd)).itemsize if cd else 4
             telemetry.event(
                 "param_gather",
                 layers=len(owners),
                 sharded_weights=len(self.gather_specs),
-                bytes=gathered_bytes,
-                overlap=bool(self.config.overlap_collectives))
+                bytes=sum(int(np.prod(self.update_specs[key][1])) * itemsize
+                          for key in self.gather_specs),
+                collective="all-gather",
+                gathers_per_step=sum(
+                    len(axes) for _b, _u, axes, _d in
+                    self.gather_specs.values()))
         if self.update_specs:
             # the REALIZED layout can exceed the decision's dp-default
             # guess (a seq-sharded consumer adds `seq` to a weight's
@@ -391,19 +380,20 @@ class Executor:
     # -------------------------------------------------- stage-3 gathers
 
     def _gather_param(self, owner: str, wname: str, arr):
-        """Ring all-gather one stage-3 weight from its at-rest update
-        layout back to its compute placement — exact data movement, so
-        the gathered value is bit-identical to a replicated weight.
-        Multi-axis updates unwind one ring per axis, minor axis first
-        (weight_update_spec appends the update axes onto the dim, so
-        chunks concatenate in ring order within each outer shard). Hops
-        are double-buffered (hop-before-use) when overlap_collectives is
-        on; --no-overlap-collectives is the serial hop-then-write
-        ablation — bit-identical either way."""
-        from .parallel.ops import _spec_assignment, ring_all_gather
+        """All-gather one stage-3 weight from its at-rest update layout
+        back to its compute placement — exact data movement, so the
+        gathered value is bit-identical to a replicated weight. XLA's
+        own collective, one an update axis; multi-axis updates unwind
+        minor axis first (weight_update_spec appends the update axes
+        onto the dim, so chunks concatenate in shard order within each
+        outer shard). XLA runs the matrices' gathers asynchronously
+        beside the work that precedes their reader (the compiled text
+        threads each through the fusions before it) and the vectors' at
+        their reader; what the chip measured is in PERF.md (section 6,
+        PR 48)."""
+        from .parallel.ops import _spec_assignment, all_gather
 
         base, upd, axes, dim = self.gather_specs[(owner, wname)]
-        overlap = bool(self.config.overlap_collectives)
         cur = list(_spec_assignment(upd, arr.ndim))
 
         def to_spec(assignment):
@@ -417,24 +407,23 @@ class Executor:
                 entry = list(nxt[dim])
                 entry.remove(ax)
                 nxt[dim] = tuple(entry)
-                arr = ring_all_gather(
+                arr = all_gather(
                     arr, mesh=self.mesh, axis_name=ax, dim=dim,
-                    overlap=overlap,
                     in_spec=to_spec(cur), out_spec=to_spec(nxt))
                 cur = nxt
         return arr
 
     def _gather_with_vjp(self, owner: str, wname: str):
         """The stage-3 gather as a custom-VJP callable (built once per
-        weight): forward = the explicit ring all-gather; backward = the
+        weight): forward = the all-gather of _gather_param; backward = the
         gathered copy's cotangent pinned to the compute placement
         (replicated over the update axes) — the exact stage-2 gradient
         path, so GSPMD lowers the dp psum into the same reduce-scatter
         and the trajectory stays bit-identical to the replicated
         baseline; _pin_update_sharding then slices the owner's shard.
-        (Autodiff THROUGH the ring would accumulate the grad chunks in
-        ring-arrival order, which is NOT the allreduce's ULP order —
-        measured as ~1e-7 drift on the CI mesh.)"""
+        (Autodiff THROUGH the gather would reduce-scatter the cotangent
+        in the collective's own order, which need not be the allreduce's
+        ULP order; a ring of hops measured ~1e-7 drift on the CI mesh.)"""
         key = (owner, wname)
         fn = self._gather_fns.get(key)
         if fn is not None:
@@ -456,44 +445,6 @@ class Executor:
         gather.defvjp(fwd, bwd)
         self._gather_fns[key] = gather
         return gather
-
-    def _forward_gathered(self, node, wsrc, gathered, p_own, new_state,
-                          ins, op_state, ctx):
-        """Stage-3 forward of one op: gather its sharded-at-rest weights
-        just-in-time inside a jax.checkpoint region whose policy refuses
-        to save the gathered copies — they are DROPPED after the op's
-        last use and the backward re-gathers them (ZeRO-3; the ASPLOS'23
-        decomposition pattern applied to the forward). Everything else
-        the VJP needs (the op's inputs, its saveable internals) is
-        stored as usual, so the only recompute is the re-gather itself.
-        The compute-dtype cast sits inside the region too, so it fuses
-        with the gather exactly as it fused with the implicit stage-2
-        all-gather."""
-        shard_p = {k: p_own[k] for k in gathered}
-        plain_p = {k: v for k, v in p_own.items() if k not in gathered}
-        state_w = new_state.get(wsrc, {})
-
-        def run(shard_p, plain_p, ins_t, op_state_in, state_w):
-            full = {
-                k: checkpoint_name(self._gather_with_vjp(wsrc, k)(v),
-                                   _GATHER_NAME)
-                for k, v in shard_p.items()}
-            weights = {}
-            weights.update(self._cast_compute({**plain_p, **full}))
-            weights.update(state_w)
-            # runs under the forward loop's `with jax.named_scope
-            # (node.name)` — the remat closure is invoked from inside
-            # that scope, so its trace events already carry the label
-            return node.op_def.forward(  # fflint: ok unnamed_op_scope
-                node.params, list(ins_t), weights, op_state_in, ctx)
-
-        # prevent_cse=False: these regions only ever run inside jit
-        # (the documented-safe case), and the CSE barriers would pin the
-        # ring hops behind region boundaries — defeating the one-ahead
-        # overlap the schedule exists for
-        remat = jax.checkpoint(run, policy=_FSDP_SAVE_POLICY,
-                               prevent_cse=False)
-        return remat(shard_p, plain_p, tuple(ins), op_state, state_w)
 
     def _cast_compute(self, tree):
         """Cast float leaves to the compute dtype (inside jit; the VJP of the
@@ -648,9 +599,8 @@ class Executor:
                 # at-rest layout. Under weight-update sharding the fp32
                 # master lives 1/dp-sharded — stage 2: consumers
                 # all-gather at first use (GSPMD, fused with their
-                # compute-dtype cast); stage 3: _apply gathers
-                # just-in-time with the explicit ring all-gather and
-                # drops the copy after last use.
+                # compute-dtype cast); stage 3: _apply all-gathers each
+                # layer's weights where it uses them, once a step.
                 arr = jax.device_put(arr, NamedSharding(self.mesh, spec))
                 (p if ws.trainable else s)[ws.name] = arr
             if p:
@@ -695,12 +645,6 @@ class Executor:
             # then sums every use's gradient into that one set
             wsrc = getattr(node, "weight_source", None) or node.name
             p_own = params.get(wsrc, {})
-            # stage 3 (ZeRO-3/FSDP): this node's sharded-at-rest weights
-            # are ring-gathered just-in-time inside a remat region that
-            # drops the gathered copies after last use (bwd re-gathers)
-            gathered = ([k for k in p_own
-                         if (wsrc, k) in self.gather_specs]
-                        if self.update_stage >= 3 else [])
             ctx = OpContext(
                 training=training,
                 rng=_stable_fold(rng, node.name) if rng is not None else None,
@@ -717,24 +661,27 @@ class Executor:
             # named_scope labels the op in XLA profiles (the analog of the
             # reference's per-op profiling prints, linear_kernels.cu:95-117)
             with jax.named_scope(node.name):
-                if gathered:
-                    outs, op_state = self._forward_gathered(
-                        node, wsrc, gathered, p_own, new_state, ins,
-                        op_state, ctx)
-                else:
-                    weights = {}
-                    # bf16 cast at the consumer: each node casts only its
-                    # own weights, so XLA fuses the downcast into the
-                    # first use instead of writing a model-sized bf16
-                    # copy to HBM up front (state stays uncast — ops own
-                    # their fp32-statistics handling). An inference
-                    # compile's parameters rest in the compute dtype
-                    # (rest_dtypes): nothing is cast
-                    weights.update(self._cast_compute(p_own))
-                    weights.update(new_state.get(wsrc, {}))
-                    outs, op_state = node.op_def.forward(
-                        node.params, ins, weights, op_state, ctx
-                    )
+                if self.gather_specs:
+                    # stage 3 (ZeRO-3/FSDP): the weights that rest sharded
+                    # over the update axes come to their compute placement
+                    # here, once a step; the backward reads the same
+                    # gathered value, in the compute dtype
+                    p_own = {k: (self._gather_with_vjp(wsrc, k)(v)
+                                 if (wsrc, k) in self.gather_specs else v)
+                             for k, v in p_own.items()}
+                weights = {}
+                # bf16 cast at the consumer: each node casts only its
+                # own weights, so XLA fuses the downcast into the
+                # first use instead of writing a model-sized bf16
+                # copy to HBM up front (state stays uncast — ops own
+                # their fp32-statistics handling). An inference
+                # compile's parameters rest in the compute dtype
+                # (rest_dtypes): nothing is cast
+                weights.update(self._cast_compute(p_own))
+                weights.update(new_state.get(wsrc, {}))
+                outs, op_state = node.op_def.forward(
+                    node.params, ins, weights, op_state, ctx
+                )
             if op_state:
                 op_state = dict(op_state)
                 aux = op_state.pop("aux_loss", None)
@@ -992,7 +939,7 @@ class Executor:
 
     def build_param_gather(self):
         """The stage-3 params' full gather as ONE donated executable:
-        every sharded-at-rest leaf ring-gathered back to its compute
+        every sharded-at-rest leaf all-gathered back to its compute
         placement (replicated over the update axes) in a single
         dispatch; non-stage-3 leaves pass through. Consume-point
         semantics: the input tree is donated, so callers REBIND

@@ -456,56 +456,19 @@ def ring_reduce_scatter(x, *, mesh=None, axis_name: str | None = None,
     return fn(x)
 
 
-def _ag_local(x, *, axis_name: str, n: int, dim: int, overlap: bool):
-    """Per-shard ring all-gather body: `x` is this shard's chunk along
-    `dim`; returns the full concatenation of every shard's chunk in
-    shard-index order — the gather twin of `_rs_local`. Hop t+1 has no
-    data dependence on the local chunk write beside it (the write
-    consumes the block that already arrived), so the latency-hiding
-    scheduler issues each hop BEFORE the use of the block it carries —
-    the same double-buffered idiom as ring attention. `overlap=False` is
-    the serial ablation: the barrier makes each hop depend on the
-    previous local write, so the ring serializes hop-then-write."""
-    import jax
-    import jax.numpy as jnp
-
-    idx = jax.lax.axis_index(axis_name)
-    chunk = x.shape[dim]
-    perm = ring_permutation(n)
-    shape = x.shape[:dim] + (n * chunk,) + x.shape[dim + 1:]
-    out = jnp.zeros(shape, x.dtype)
-    out = jax.lax.dynamic_update_slice_in_dim(out, x, idx * chunk, axis=dim)
-    blk = x
-    for t in range(1, n):
-        moved = jax.lax.ppermute(blk, axis_name, perm)
-        if not overlap:
-            moved, out = jax.lax.optimization_barrier((moved, out))
-        src = jax.lax.rem(idx - t + n, n)
-        out = jax.lax.dynamic_update_slice_in_dim(
-            out, moved, src * chunk, axis=dim)
-        blk = moved
-    return out
-
-
-def ring_all_gather(x, *, mesh=None, axis_name: str | None = None,
-                    dim: int = 0, overlap: bool = True,
-                    in_spec=None, out_spec=None):
-    """Decomposed all-gather over `axis_name`: `x` sharded along `dim`
-    over the axis; returns the full array replicated over that axis —
-    the RS twin of `ring_reduce_scatter`, scheduled as n−1
-    double-buffered ppermute hops (hop-before-use). This is the explicit
-    overlappable form of the param gather the ZeRO-3 (stage-3) executor
-    issues per layer; `overlap=False` is the serial ablation
-    (--no-overlap-collectives) and bench.py's microbench baseline.
+def all_gather(x, *, mesh=None, axis_name: str | None = None, dim: int = 0,
+               in_spec=None, out_spec=None):
+    """`x` sharded along `dim` over `axis_name` -> the full array
+    replicated over that axis: XLA's own all-gather, asked for by name in
+    a shard_map so that no choice of GSPMD's stands between a stage-3
+    weight and its compute placement. Exact data movement.
 
     `in_spec`/`out_spec` optionally carry the tensor's OTHER mesh axes
     through the shard_map unchanged (a weight whose update dim merges
     ('model', 'data') gathers only 'data'; the update axes sit minor on
     the dim — weight_update_spec appends them — so chunks concatenate in
-    ring order within each outer shard). Falls back to the identity when
-    there is no mesh / the axis has size 1."""
-    import functools
-
+    shard order within each outer shard). The identity when there is no
+    mesh / the axis has size 1."""
     import jax
     from jax.sharding import PartitionSpec as P
 
@@ -513,15 +476,14 @@ def ring_all_gather(x, *, mesh=None, axis_name: str | None = None,
     axis_name = axis_name or AXIS_DATA
     if mesh is None or mesh.shape.get(axis_name, 1) == 1:
         return x
-    n = mesh.shape[axis_name]
     nd = x.ndim
     if in_spec is None:
         in_spec = P(*([None] * dim), axis_name, *([None] * (nd - dim - 1)))
     if out_spec is None:
         out_spec = P(*([None] * nd))
     fn = jax.shard_map(
-        functools.partial(_ag_local, axis_name=axis_name, n=n, dim=dim,
-                          overlap=overlap),
+        lambda shard: jax.lax.all_gather(shard, axis_name, axis=dim,
+                                         tiled=True),
         mesh=mesh,
         in_specs=(in_spec,),
         out_specs=out_spec,

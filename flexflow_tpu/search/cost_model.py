@@ -66,7 +66,10 @@ class CostMetrics:
     # use) — plus its summed per-hop issue latency, and the FULL gathered
     # bytes of the node's stage-3 weights (the evaluators charge at most
     # two gathered layers in flight, not one per weight). All zero below
-    # stage 3.
+    # stage 3. (The price is the conservative one of a gather that is
+    # dropped and repeated: the executor's step gathers a weight once and
+    # keeps the copy, in the compute dtype, for the backward — PERF.md
+    # section 7, "From PR 48".)
     param_gather_time: float = 0.0
     param_gather_hop_s: float = 0.0
     gather_bytes: float = 0.0

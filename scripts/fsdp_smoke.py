@@ -19,14 +19,14 @@ runs a short fit, then asserts
   - the strategy report prices the per-layer gathers on the overlappable
     channel: update_stage 3, report-level param_gather_s > 0, and every
     op that carries param_gather_s shows overlap_s >= param_gather_s
-    with sync_s == 0 (the gather hides behind the previous layer's
-    compute; only hop latency is exposed);
+    with sync_s == 0 (the gather is priced as hidden behind the
+    previous layer's compute; only its issue latency is exposed);
   - the makespan identity still reproduces with the gather channel in
     play (run_doctor --check covers the same report in CI);
   - the ffcheck memory-liveness pass verified the 1/shards-at-rest +
     transient-gather accounting without tripping the OOM gate on the
     plan the decision made fit;
-  - telemetry carries the param_gather event (layers/bytes/overlap) and
+  - telemetry carries the param_gather event (layers/bytes/collective) and
     the weight_update event with stage 3 — the compiled executable
     really runs the just-in-time gathers;
   - the fit completed (steps recorded) with stage 3 live.
@@ -224,7 +224,8 @@ def main():
     pg = [r for r in recs if r.get("kind") == "param_gather"]
     if not pg:
         fail("no param_gather event in telemetry")
-    if not pg[0].get("layers") or not pg[0].get("bytes"):
+    if not (pg[0].get("layers") and pg[0].get("bytes")
+            and pg[0].get("gathers_per_step")):
         fail(f"param_gather event inconsistent: {pg[0]}")
     wu = [r for r in recs if r.get("kind") == "weight_update"]
     if not wu or wu[0].get("stage") != 3:

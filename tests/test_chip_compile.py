@@ -15,6 +15,7 @@ persistent compilation cache is off around the module: such a compile can
 be written to it but not read back without a chip.
 """
 
+import collections
 import importlib
 import os
 import re
@@ -637,6 +638,32 @@ def test_paged_decode_too_wide_for_vmem_takes_the_reference_and_says_so(tpu):
     assert not kernels
 
 
+def _step_text_for_four_chips(ff, topology, monkeypatch, batch, seq):
+    """The compiled text of a compiled model's train step, its arguments
+    described on the four described chips."""
+    ex = ff.executor
+    mesh = Mesh(np.array(topology.devices).reshape(ff.mesh.devices.shape),
+                ff.mesh.axis_names)
+
+    def described(x):
+        spec = (x.sharding.spec if isinstance(x.sharding, NamedSharding)
+                else PartitionSpec())
+        return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    toks = np.zeros((batch, seq), np.int32)
+    data = ff._make_batch({"tokens": toks, "positions": toks},
+                          np.zeros((batch, seq, 1), np.int32))
+    rng = jax.device_put(jax.random.key(0),
+                         NamedSharding(ff.mesh, PartitionSpec()))
+    args = jax.tree.map(described, (
+        ff._params, ff._state, ff._opt_slots, ff._step, ff._counters, rng,
+        data))
+    monkeypatch.setattr(ex, "mesh", mesh)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return jax.jit(ex._train_step_body).lower(*args).compile().as_text()
+
+
 @pytest.mark.parametrize("mesh_flag,megatron", [("4,1,1,1", False),
                                                 ("2,2,1,1", True)])
 def test_train_step_lowers_for_four_chips(topology, monkeypatch, mesh_flag,
@@ -665,34 +692,99 @@ def test_train_step_lowers_for_four_chips(topology, monkeypatch, mesh_flag,
     ff.compile(optimizer=SGDOptimizer(lr=0.01),
                loss_type=LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY)
 
-    # the same step, its arguments described on the four described chips
-    ex = ff.executor
-    mesh = Mesh(np.array(topology.devices).reshape(ff.mesh.devices.shape),
-                ff.mesh.axis_names)
-
-    def described(x):
-        spec = (x.sharding.spec if isinstance(x.sharding, NamedSharding)
-                else PartitionSpec())
-        return jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                    sharding=NamedSharding(mesh, spec))
-
-    toks = np.zeros((8, 128), np.int32)
-    batch = ff._make_batch({"tokens": toks, "positions": toks},
-                           np.zeros((8, 128, 1), np.int32))
-    rng = jax.device_put(jax.random.key(0),
-                         NamedSharding(ff.mesh, PartitionSpec()))
-    args = jax.tree.map(described, (
-        ff._params, ff._state, ff._opt_slots, ff._step, ff._counters, rng,
-        batch))
-    monkeypatch.setattr(ex, "mesh", mesh)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    text = jax.jit(ex._train_step_body).lower(*args).compile().as_text()
+    text = _step_text_for_four_chips(ff, topology, monkeypatch, 8, 128)
     kernels = pallas_kernels(text)
     # the forward and the one backward kernel of the layer
     assert sum(v for k, v in kernels.items()
                if k.startswith("flash_attention")) == 2, kernels
     assert kernels["layer_norm_fwd"] == 3 and kernels["layer_norm_bwd"] == 3
     assert "all-reduce" in text
+
+
+def _param_gathers(text):
+    """What a compiled step's text holds under the stage-3 gathers' scope
+    `param_gather/<owner>.<weight>`: per weight the all-gathers it EXECUTES
+    (a synchronous `all-gather` outside any fusion, or one asynchronous
+    chain: the TPU compiler writes an asynchronous all-gather as a start
+    fusion (`AsyncCollectiveStart`), continuation fusions that carry its
+    buffers and semaphores beside other work, and a done fusion, and every
+    one of them holds the `all-gather` instruction), the result dtypes on
+    those collectives, the ring's leftovers (collective-permutes and
+    dynamic-update-slices under the scope), and the all-gathers inside a
+    fusion that belongs to no chain: a gather duplicated into a reader."""
+    comp, body = None, collections.defaultdict(list)
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if m:
+            comp = m.group(1)
+        elif comp:
+            body[comp].append(line)
+    fused = set(re.findall(r"calls=%?([\w.\-]+)", text))
+    out = {"executed": collections.Counter(), "dtypes": collections.Counter(),
+           "permutes": 0, "update_slices": 0, "stray": 0, "bytes": 0}
+    for name, lines in body.items():
+        chain = any("AsyncCollectiveStart" in l for l in lines)
+        done = any("AsyncCollectiveDone" in l for l in lines)
+        semaphores = sum(1 for l in lines
+                         if re.search(r"= u32\[\]\S* parameter\(", l))
+        for l in lines:
+            m = re.search(r'op_name="[^"]*param_gather/([\w.\-]+)/', l)
+            if not m:
+                continue
+            if re.search(r" collective-permute(-start)?\(", l):
+                out["permutes"] += 1
+            elif " dynamic-update-slice(" in l:
+                out["update_slices"] += 1
+            elif re.search(r" all-gather(-start)?\(", l):
+                if name not in fused or chain:
+                    out["executed"][m.group(1)] += 1
+                    t = re.search(r"= \(?(\w+)\[([\d,]*)\]", l)
+                    out["dtypes"][t.group(1)] += 1
+                    n = 1
+                    for d in t.group(2).split(","):
+                        n *= int(d) if d else 1
+                    out["bytes"] += n * (2 if t.group(1) == "bf16" else 4)
+                elif not done and semaphores < 2:
+                    out["stray"] += 1
+    return out
+
+
+def test_stage3_step_gathers_each_weight_once_in_bf16(topology, monkeypatch):
+    """A stage-3 Adam step in bf16 for four chips: every weight that rests
+    sharded comes to its compute placement by ONE all-gather a step, the
+    wire carries the compute dtype (XLA hoists the cast in front of the
+    collective), and nothing of a ring is left: no hop, no
+    dynamic-update-slice assembling what arrived. The one-way ring this
+    replaced compiled to 159 hops and 212 update-slices for this model's 53
+    weights; a gather re-run in the backward or copied into its readers
+    would show as a second execution or as a stray."""
+    from flexflow_tpu import AdamOptimizer, FFConfig, FFModel, LossType
+    from flexflow_tpu.models import TransformerLMConfig, build_transformer_lm
+
+    monkeypatch.setattr(sys, "argv", [
+        "test", "--mesh", "4,1,1,1", "--weight-update-sharding", "stage3",
+        "--dtype", "bf16", "-b", "4"])
+    ff = FFModel(FFConfig())
+    cfg = TransformerLMConfig(
+        vocab_size=2048, hidden_size=1024, num_heads=8, num_layers=3,
+        sequence_length=256, attention_impl="xla")
+    build_transformer_lm(ff, cfg, batch_size=4)
+    ff.compile(optimizer=AdamOptimizer(),
+               loss_type=LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY)
+    weights = {f"{node}.{w}" for node, w in ff.executor.gather_specs}
+    assert len(weights) == 53
+
+    found = _param_gathers(
+        _step_text_for_four_chips(ff, topology, monkeypatch, 4, 256))
+    assert set(found["executed"]) == weights
+    assert set(found["executed"].values()) == {1}, found["executed"]
+    assert set(found["dtypes"]) == {"bf16"}, found["dtypes"]
+    assert found["permutes"] == found["update_slices"] == 0, found
+    assert found["stray"] == 0
+    # the gathered weights once, two bytes a parameter
+    assert found["bytes"] == 2 * sum(
+        int(np.prod(shape)) for key, (_spec, shape)
+        in ff.executor.update_specs.items() if key in ff.executor.gather_specs)
 
 
 def _ms4_front():

@@ -377,6 +377,33 @@ def test_weight_update_telemetry_events(tmp_path):
     assert '"grad_sync"' in raw, "no grad_sync span/counter in the trace"
 
 
+@pytest.mark.parametrize("dtype_flags,itemsize", [((), 4),
+                                                  (("--dtype", "bf16"), 2)],
+                         ids=["float32", "bf16"])
+def test_param_gather_event_counts_the_compute_dtype(tmp_path, dtype_flags,
+                                                     itemsize):
+    """A stage-3 compile's param_gather event: the bytes a chip's gathers
+    deliver a step in the dtype the wire carries (the compute dtype), the
+    collective's kind, and one gather a step for every gathered weight."""
+    import os
+
+    from flexflow_tpu.telemetry import read_jsonl
+
+    tdir = str(tmp_path / "telemetry")
+    ff = _mlp(argv=["--weight-update-sharding=stage3", *dtype_flags,
+                    "--telemetry-dir", tdir])
+    ff.get_telemetry().close()
+    (event,) = [r for r in read_jsonl(os.path.join(tdir, "metrics.jsonl"))
+                if r.get("kind") == "param_gather"]
+    specs = ff.executor.gather_specs
+    assert specs and event["sharded_weights"] == len(specs)
+    assert event["collective"] == "all-gather"
+    assert event["gathers_per_step"] == len(specs)   # one update axis each
+    assert event["bytes"] == itemsize * sum(
+        int(np.prod(ff.executor.update_specs[key][1])) for key in specs)
+    assert "overlap" not in event
+
+
 # ===================================================================
 # the explicit ring reduce-scatter (bench ablation substrate)
 # ===================================================================
@@ -475,8 +502,8 @@ def test_inference_and_dp1_stay_replicated():
 @pytest.mark.parametrize("opt", ["adam", "sgd_momentum"])
 def test_stage3_bit_identical_trajectory(opt):
     """2 shuffled epochs under forced stage 3 — params sharded at rest,
-    per-layer ring all-gather just-in-time, gathered copies dropped and
-    re-gathered on the backward — equal the replicated baseline
+    each layer's weights all-gathered where it uses them, once a step —
+    equal the replicated baseline
     bit-for-bit: params, optimizer slots, counters, step, RNG."""
     x, y = _data(64)
 
@@ -500,8 +527,9 @@ def test_stage3_bit_identical_trajectory(opt):
 
 
 def test_stage3_serial_schedule_bit_identical():
-    """--no-overlap-collectives flips the ring bodies to the serial
-    hop-then-write ablation; the values are identical either way."""
+    """--no-overlap-collectives composes with stage 3 (the gather is one
+    all-gather a weight either way; the flag keeps its meaning for the
+    ring reduce-scatter and ring attention): the values are identical."""
     x, y = _data(64)
     rep = _mlp(argv=["--weight-update-sharding=off"])
     rep.fit(x, y, epochs=1, batch_size=8, shuffle=False)
@@ -803,37 +831,41 @@ def test_memory_liveness_verifies_stage3_accounting():
     assert m2["gather_peak_bytes"] == 0.0
 
 
-@pytest.mark.parametrize("overlap", [True, False],
-                         ids=["overlapped", "serial"])
-def test_ring_all_gather_matches_reference(overlap):
-    """ring_all_gather (the double-buffered hop-before-use schedule the
-    stage-3 per-layer gather runs, and bench.py's microbench subject)
-    reproduces the exact concatenation of every shard's chunk, both
-    schedules."""
+@pytest.mark.parametrize("mesh_shape,shape,dim,in_spec,out_spec", [
+    ((4, 1, 1, 1), (16, 6), 0, ("data", None), (None, None)),
+    ((4, 1, 1, 1), (6, 16), 1, (None, "data"), (None, None)),
+    # a merged update: the dim carries ('model', 'data'), the gather
+    # unwinds 'data' (minor) and leaves the weight's own 'model' shards
+    ((2, 2, 1, 1), (16, 6), 0, (("model", "data"), None), ("model", None)),
+    ((2, 2, 1, 1), (6, 16), 1, (None, ("model", "data")), (None, "model")),
+], ids=["dim0", "dim1", "merged_dim0", "merged_dim1"])
+def test_all_gather_matches_reference(mesh_shape, shape, dim, in_spec,
+                                      out_spec):
+    """parallel.ops.all_gather (the collective a stage-3 weight comes to
+    its compute placement by) gives numpy's array back, every device's
+    piece where `out_spec` says, along either dim and with the weight's
+    other mesh axes carried through."""
     import jax
 
     from flexflow_tpu.machine import MeshShape, build_mesh
-    from flexflow_tpu.parallel.ops import ring_all_gather
+    from flexflow_tpu.parallel.ops import all_gather
 
-    if not hasattr(jax.Array, "addressable_shards"):  # pragma: no cover
-        pytest.skip("no shard introspection")
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = build_mesh(MeshShape((4, 1, 1, 1)))
-    rs = np.random.RandomState(0)
-    x = rs.randn(16, 6).astype(np.float32)
-    xs = jax.device_put(x, NamedSharding(mesh, P("data", None)))
+    mesh = build_mesh(MeshShape(mesh_shape))
+    x = np.random.RandomState(0).randn(*shape).astype(np.float32)
+    xs = jax.device_put(x, NamedSharding(mesh, P(*in_spec)))
+    piece = shape[dim] // 4
+    assert {s.data.shape[dim] for s in xs.addressable_shards} == {piece}
 
-    out = np.asarray(jax.device_get(
-        ring_all_gather(xs, mesh=mesh, axis_name="data", dim=0,
-                        overlap=overlap)))
-    np.testing.assert_array_equal(out, x)
-    # and along a non-leading dim
-    ys = jax.device_put(x.T.copy(), NamedSharding(mesh, P(None, "data")))
-    out = np.asarray(jax.device_get(
-        ring_all_gather(ys, mesh=mesh, axis_name="data", dim=1,
-                        overlap=overlap)))
-    np.testing.assert_array_equal(out, x.T)
+    out = jax.jit(lambda a: all_gather(
+        a, mesh=mesh, axis_name="data", dim=dim,
+        in_spec=P(*in_spec), out_spec=P(*out_spec)))(xs)
+    np.testing.assert_array_equal(np.asarray(jax.device_get(out)), x)
+    want = NamedSharding(mesh, P(*out_spec))
+    assert out.sharding.is_equivalent_to(want, x.ndim), out.sharding
+    for s in out.addressable_shards:
+        np.testing.assert_array_equal(np.asarray(s.data), x[s.index])
 
 
 def test_stage3_donated_gather_executable():
