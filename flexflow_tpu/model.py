@@ -198,13 +198,23 @@ class FFModel:
                 raise TypeError(
                     f"shared_op must be a Layer or one of its output "
                     f"tensors, got {type(shared_op).__name__}")
-            if src.op_type != op_type:
-                raise ValueError(
-                    f"shared_op ties a {op_type.name} layer to a "
-                    f"{src.op_type.name} layer")
             layer.shared_layer_guid = src.layer_guid
         op_def = get_op_def(op_type)
         in_shapes = [t.dims for t in inputs]
+        if shared_op is not None:
+            # a tie is between weights, whatever the two operators are (a
+            # head reads an embedding's table): every weight of this layer
+            # is one of the source's, by name and shape
+            theirs = {ws.name: ws.shape for ws in get_op_def(
+                src.op_type).weights(src.params,
+                                     [t.dims for t in src.inputs])}
+            for ws in op_def.weights(params, in_shapes):
+                if theirs.get(ws.name) != ws.shape:
+                    raise ValueError(
+                        f"shared_op ties {op_type.name} layer "
+                        f"{layer.name!r} to {src.op_type.name} layer "
+                        f"{src.name!r}, whose weight {ws.name!r} is "
+                        f"{theirs.get(ws.name)}, not {ws.shape}")
         out_shapes = op_def.infer_shapes(params, in_shapes)
         for i, s in enumerate(out_shapes):
             layer.outputs.append(
@@ -302,7 +312,13 @@ class FFModel:
         kernel_regularizer: RegularizerMode = RegularizerMode.REG_MODE_NONE,
         name: str = "",
     ) -> Tensor:
-        p = LinearParams(out_dim, use_bias, ActiMode(activation), data_type)
+        # tied to an embedding, the layer reads the table as it lies,
+        # (out_dim, in_dim): ops/core.LinearParams.kernel_transposed
+        tied = getattr(shared_op, "owner_layer", shared_op)
+        p = LinearParams(out_dim, use_bias, ActiMode(activation), data_type,
+                         kernel_transposed=(
+                             getattr(tied, "op_type", None)
+                             == OT.OP_EMBEDDING))
         inits = {}
         if kernel_initializer is not None:
             inits["kernel"] = kernel_initializer
@@ -366,8 +382,10 @@ class FFModel:
         elementwise_affine: bool = True,
         eps: float = 1e-5,
         name: str = "",
+        bias: bool = True,
     ) -> Tensor:
-        p = LayerNormParams(tuple(axes), elementwise_affine, eps)
+        """`bias` False: the affine is a learned scale alone."""
+        p = LayerNormParams(tuple(axes), elementwise_affine, eps, bias)
         return self._add_layer(OT.OP_LAYERNORM, p, [input], name,
                                data_type=input.dtype).outputs[0]
 
@@ -446,6 +464,7 @@ class FFModel:
         sink: bool = False,
         value_scale: float = 1.0,
         sink_initializer: Optional[Initializer] = None,
+        rope_interleaved: bool = False,
     ) -> Tensor:
         """`rope_theta` > 0 rotates q and k by the (batch, seq) int
         `positions`; `qk_norm` RMS-normalises the q and k projections
@@ -459,8 +478,9 @@ class FFModel:
         head's; `rope_dim` rotates only the first lanes of a head;
         `window` keeps a row's nearest keys; `sink` adds a learned bias a
         head to the softmax's denominator (drawn by `sink_initializer`);
-        `value_scale` multiplies the values
-        (ops/attention.AttentionFrontEnd)."""
+        `value_scale` multiplies the values; `rope_interleaved` rotates
+        lanes 2j and 2j + 1 as a pair where the default pairs j and
+        j + d / 2 (ops/attention.AttentionFrontEnd)."""
         if impl not in ("xla", "flash", "ring"):
             raise ValueError(
                 f"multihead_attention impl must be xla|flash|ring, got {impl!r}"
@@ -471,7 +491,8 @@ class FFModel:
         front = AttentionFrontEnd(embed_dim, num_heads, bias, rope_theta,
                                   qk_norm, qk_norm_eps, num_kv_heads,
                                   head_dim, output_gate, index, v_head_dim,
-                                  rope_dim, window, sink, value_scale)
+                                  rope_dim, window, sink, value_scale,
+                                  rope_interleaved)
         p = MultiHeadAttentionParams(front, kdim, vdim, dropout, add_bias_kv,
                                      add_zero_attn, causal, impl)
         inits = ({} if kernel_initializer is None
@@ -860,13 +881,8 @@ class FFModel:
                     raise ValueError(
                         f"{layer.name}: shared_op layer must be built "
                         f"before the layer sharing it")
-                src_shapes = {ws.name: ws.shape for ws in src.weight_specs}
-                for ws in node.weight_specs:
-                    if src_shapes.get(ws.name) != ws.shape:
-                        raise ValueError(
-                            f"{layer.name}: shared weight {ws.name!r} shape "
-                            f"{ws.shape} != source {src.name}'s "
-                            f"{src_shapes.get(ws.name)}")
+                # (`_add_layer` held each of its weights to the source's,
+                # by name and shape, when the tie was made)
                 node.weight_source = src.name
                 self._weight_alias[node.name] = src.name
             for i, t_out in enumerate(layer.outputs):

@@ -144,6 +144,22 @@ class TransformerLMConfig:
     # drawn from N(0, that), 0.0 = zeros; None leaves the layer's own
     # draw, N(0, 0.02)
     router_bias_range: Optional[float] = None
+    # Command A+ (`command_a_plus_lm_config`): `parallel_block`: one norm a
+    # layer, n = norm(h), and h + attention(n) + mlp(n), where the default
+    # is h + attention(norm1(h)) and then + mlp(norm2(.)); `norm_bias`
+    # False: LayerNorm with a learned scale and no bias; position is the
+    # layer kind's: `swa` may carry a `rope_theta` of its own (the window
+    # layers then take positions whatever `position` says, and under
+    # position "none" the global layers take none) and the form,
+    # `rope_interleaved` (lanes 2j and 2j + 1 a pair), both keys of `swa`;
+    # `tie_embeddings`: the head reads the embedding's table (one array,
+    # no `lm_head` weight)
+    parallel_block: bool = False
+    norm_bias: bool = True
+    tie_embeddings: bool = False
+    # the embedding is drawn around this mean (a residual stream of zero
+    # mean, which a random stack's is, cannot tell LayerNorm from RMSNorm)
+    embedding_mean: float = 0.0
 
     def layer_kind(self, i: int) -> str:
         return self.layer_pattern[i] if self.layer_pattern else self.attention
@@ -490,35 +506,113 @@ def mimo_v2_flash_lm_config(config: dict, *, sequence_length: int,
         router_bias_range=0.0)
 
 
-def _norm_initializer(stddev: float):
+def command_a_plus_lm_config(config: dict, *, sequence_length: int,
+                             attention_impl: str = "xla",
+                             initializer_range: float = 0.02,
+                             embedding_range: float = 0.0,
+                             embedding_mean: float = 0.0
+                             ) -> TransformerLMConfig:
+    """The language model of Command A+ from the keys of its published
+    config.json (`model_type: cohere2_moe`;
+    models/command_a_plus_reference.py writes the equations out and says
+    what the keys leave open): a parallel block under one LayerNorm with a
+    scale and no bias; `layer_types` three `sliding_attention` layers (a
+    window of `sliding_window` keys, interleaved RoPE over the whole head)
+    to one `full_attention` layer that takes no position; grouped keys and
+    values; every layer with routed experts under a sigmoid router (no
+    correction bias, no groups) renormalised over the chosen, beside
+    `num_shared_experts` shared experts whose outputs are averaged; the
+    head tied to the embedding. A cut configuration states `experts_held`
+    / `experts_routed` as DeepSeek-V3.2's does (`num_experts` then counts
+    the experts held). The vision tower is not built. The embedding is
+    N(`embedding_mean`, `embedding_range` or `initializer_range`): under a
+    tied head an embedding of N(0, 1) beside matrices of 0.02 makes a
+    row's logit of the token it read its largest by far, and a greedy
+    reply that token repeated."""
+    layers = config["num_hidden_layers"]
+    kinds = tuple(config["layer_types"][:layers])
+    period = ("sliding_attention",) * 3 + ("full_attention",)
+    if kinds != (period * -(-layers // 4))[:layers]:
+        raise NotImplementedError(
+            f"command_a_plus_lm_config: layer_types is three "
+            f"sliding_attention layers to one full_attention layer, got "
+            f"{kinds}")
+    for key, built in (("use_qk_norm", False), ("attention_bias", False),
+                       ("first_k_dense_replace", 0),
+                       ("shared_expert_combination_strategy", "average"),
+                       ("expert_selection_fn", "sigmoid"),
+                       ("position_embedding_type", "rope_gptj"),
+                       ("rotary_pct", 1), ("logit_scale", 1),
+                       ("hidden_act", "silu"),
+                       ("use_gated_activation", True)):
+        if config.get(key, built) != built:
+            raise NotImplementedError(
+                f"command_a_plus_lm_config builds {key} {built!r}, got "
+                f"{config[key]!r}")
+    held = config.get("experts_held")
+    shared = config["num_shared_experts"]
+    return TransformerLMConfig(
+        vocab_size=config["vocab_size"], hidden_size=config["hidden_size"],
+        num_heads=config["num_attention_heads"], num_layers=layers,
+        sequence_length=sequence_length, attention_impl=attention_impl,
+        norm="layernorm", norm_eps=config["layer_norm_eps"],
+        norm_bias=False, parallel_block=bool(config["use_parallel_block"]),
+        position="none", attention_bias=False,
+        num_kv_heads=config["num_key_value_heads"],
+        head_dim=config["head_dim"],
+        layer_pattern=tuple("swa" if kind == "sliding_attention" else "mha"
+                            for kind in kinds),
+        swa=dict(window=config["sliding_window"],
+                 rope_theta=float(config["rope_theta"]),
+                 rope_interleaved=True),
+        mlp="moe",
+        num_experts=config.get("experts_routed", config["num_experts"]),
+        num_experts_per_tok=config["num_experts_per_tok"],
+        moe_intermediate_size=config["intermediate_size"],
+        moe_routing=dict(
+            scoring="sigmoid", correction_bias=False,
+            norm_topk_prob=config["norm_topk_prob"],
+            # the shared experts side by side as one gated MLP, its
+            # output times 1 / their number: their average
+            shared_intermediate_size=shared * config["intermediate_size"],
+            shared_scale=1.0 / shared if shared else 1.0,
+            experts_held=None if held is None else tuple(held)),
+        tie_embeddings=bool(config["tie_word_embeddings"]),
+        initializer_range=initializer_range,
+        embedding_range=embedding_range, embedding_mean=embedding_mean)
+
+
+def _norm_initializer(stddev: float, mean: float = 0.0):
     from ..initializer import NormInitializer
 
-    return NormInitializer(stddev=stddev)
+    return NormInitializer(stddev=stddev, mean=mean)
 
 
 def _lm_norm(ff, c: TransformerLMConfig, h, name: str):
     if c.norm == "rmsnorm":
         return ff.rms_norm(h, c.norm_eps, name=name)
-    return ff.layer_norm(h, [2], eps=c.norm_eps, name=name)
+    return ff.layer_norm(h, [2], eps=c.norm_eps, name=name,
+                         bias=c.norm_bias)
 
 
-def _lm_trunk(ff, c: TransformerLMConfig, h, pos):
+def _lm_trunk(ff, c: TransformerLMConfig, h, pos, wte=None):
     """The pre-norm block stack + final norm + vocab head. What the block
     is made of is the config's (norm, position, attention_bias, qk_norm,
-    mlp). The decode graph is this graph replayed with the same layer
-    names (serving/decode_graph.py), so trained parameters transfer to it
-    by name."""
+    mlp, parallel_block). The decode graph is this graph replayed with
+    the same layer names (serving/decode_graph.py), so trained parameters
+    transfer to it by name. `wte`: the embedding's output, which a tied
+    head reads the table of."""
     rope = c.position == "rope"
     init = (_norm_initializer(c.initializer_range)
             if c.initializer_range else None)
     for i in range(c.num_layers):
         p = f"l{i}_"
-        a = _lm_norm(ff, c, h, f"{p}ln1")
+        n = _lm_norm(ff, c, h, f"{p}ln1")
         if c.layer_kind(i) == "delta":
-            a = ff.gated_delta_attention(a, c.delta, kernel_initializer=init,
+            a = ff.gated_delta_attention(n, c.delta, kernel_initializer=init,
                                          name=f"{p}attn")
         elif c.attention == "latent":
-            a = ff.latent_attention(a, pos, c.latent, kernel_initializer=init,
+            a = ff.latent_attention(n, pos, c.latent, kernel_initializer=init,
                                     name=f"{p}attn")
         else:
             front = dict(
@@ -529,9 +623,10 @@ def _lm_trunk(ff, c: TransformerLMConfig, h, pos):
             if c.layer_kind(i) == "swa":
                 front.update(c.swa)
             a = ff.multihead_attention(
-                a, a, a, c.hidden_size, c.num_heads, bias=c.attention_bias,
+                n, n, n, c.hidden_size, c.num_heads, bias=c.attention_bias,
                 causal=True, impl=c.attention_impl, name=f"{p}attn",
-                positions=pos if rope else None,
+                # position is the layer kind's: a kind with a theta
+                positions=pos if front["rope_theta"] else None,
                 qk_norm=c.qk_norm, qk_norm_eps=c.norm_eps,
                 output_gate=c.attention_gate, index=c.indexer,
                 kernel_initializer=init,
@@ -539,8 +634,13 @@ def _lm_trunk(ff, c: TransformerLMConfig, h, pos):
                                   if c.sink_range else None),
                 **front,
             )
-        h = ff.add(h, a, name=f"{p}res1")
-        m = _lm_norm(ff, c, h, f"{p}ln2")
+        if c.parallel_block:
+            # the fork: the MLP reads what the attention read, and the
+            # two join before the residual add
+            m = n
+        else:
+            h = ff.add(h, a, name=f"{p}res1")
+            m = _lm_norm(ff, c, h, f"{p}ln2")
         if c.mlp == "moe" and i >= c.first_k_dense:
             # the objective carries the mean over the layers of each
             # router's load-balancing term, so a layer adds coef / layers
@@ -566,8 +666,13 @@ def _lm_trunk(ff, c: TransformerLMConfig, h, pos):
             m = ff.dense(m, c.mlp_ratio * c.hidden_size, name=f"{p}ffn1")
             m = ff.gelu(m, name=f"{p}gelu")
             m = ff.dense(m, c.hidden_size, name=f"{p}ffn2")
+        if c.parallel_block:
+            m = ff.add(a, m, name=f"{p}join")
         h = ff.add(h, m, name=f"{p}res2")
     h = _lm_norm(ff, c, h, "ln_f")
+    if c.tie_embeddings:
+        return ff.dense(h, c.vocab_size, use_bias=False, name="lm_head",
+                        shared_op=wte)
     return ff.dense(h, c.vocab_size, use_bias=False, name="lm_head",
                     kernel_initializer=init)
 
@@ -581,16 +686,17 @@ def build_transformer_lm(ff, config: TransformerLMConfig | None = None,
     tokens = ff.create_tensor((bs, c.sequence_length), DataType.DT_INT32,
                               name="tokens")
     embedding_range = c.embedding_range or c.initializer_range
-    h = ff.embedding(
+    h = wte = ff.embedding(
         tokens, c.vocab_size, c.hidden_size, name="wte",
         kernel_initializer=(None if not embedding_range else
-                            _norm_initializer(embedding_range)))
+                            _norm_initializer(embedding_range,
+                                              c.embedding_mean)))
     pos = ff.create_tensor((bs, c.sequence_length), DataType.DT_INT32,
                            name="positions")
     if c.position == "learned":  # rotary positions go to the attention ops
         hp = ff.embedding(pos, c.sequence_length, c.hidden_size, name="wpe")
         h = ff.add(h, hp, name="embed_add")
-    return tokens, _lm_trunk(ff, c, h, pos)
+    return tokens, _lm_trunk(ff, c, h, pos, wte=wte)
 
 
 def build_transformer_lm_pipelined(ff, config: TransformerLMConfig | None = None,

@@ -24,7 +24,6 @@ placement:
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import math
 from dataclasses import dataclass
@@ -126,6 +125,10 @@ class AttentionFrontEnd:
     sink: bool = False
     # the values times this (equal to scaling the core's output)
     value_scale: float = 1.0
+    # RoPE turns lanes 2j and 2j + 1 of a head as a pair (GPT-J's form,
+    # `position_embedding_type: rope_gptj`) where the default pairs lanes j
+    # and j + d / 2; the frequencies are the same, theta^(-2j/d)
+    rope_interleaved: bool = False
 
     # the four every attention layer has; `wg` joins them under
     # `output_gate`, the indexer's three under `index` (`matrices`)
@@ -167,20 +170,23 @@ class AttentionFrontEnd:
             return self.head_dim, self.head_dim
         return self.q_width, self.kv_width
 
-    def scope(self, name: str):
-        """The trace scope of a part of a layer with a learned selection
-        (`gsa.qkv`, `gsa.attend`, `gsa.out`: docs/observability.md); the
-        other layers' parts stay under the names their readers know."""
-        return (jax.named_scope(name) if self.index
-                else contextlib.nullcontext())
+    @property
+    def kind(self) -> str:
+        """The prefix of the layer's trace scopes (docs/observability.md):
+        `gsa` under a learned selection, `swa` under a window, `gqa`
+        otherwise."""
+        return "gsa" if self.index else "swa" if self.window else "gqa"
+
+    def scope(self, part: str):
+        """The trace scope of a part of the layer, `qkv` or `out`, under
+        its kind: `gsa.qkv`, `swa.out`, ..."""
+        return jax.named_scope(f"{self.kind}.{part}")
 
     @property
     def attend_scope(self) -> str:
-        """The trace scope of the layer's core: `gsa.attend` under a
-        learned selection, `swa.attend` under a window, `gqa.attend`
-        otherwise (docs/observability.md)."""
-        return ("gsa.attend" if self.index
-                else "swa.attend" if self.window else "gqa.attend")
+        """The trace scope of the layer's core: `gsa.attend`,
+        `swa.attend` or `gqa.attend`."""
+        return f"{self.kind}.attend"
 
     @property
     def head_dim(self) -> int:
@@ -294,7 +300,7 @@ class AttentionFrontEnd:
     def qkv(self, ctx, weights, q_in, k_in, v_in, positions=None):
         """The projections, then QK-norm, then RoPE, all on the packed
         (batch, seq, heads * head_dim) layout."""
-        with self.scope("gsa.qkv"):
+        with self.scope("qkv"):
             q = proj(ctx, q_in, weights["wq"], weights.get("bq"))
             k = proj(ctx, k_in, weights["wk"], weights.get("bk"))
             v = proj(ctx, v_in, weights["wv"], weights.get("bv"))
@@ -309,7 +315,7 @@ class AttentionFrontEnd:
                                     self.qk_norm_eps).reshape(x.shape)
 
                 q, k = norm(q, weights["q_norm"]), norm(k, weights["k_norm"])
-            if self.rope_theta and self.rope_dim:
+            if self.rope_theta and (self.rope_dim or self.rope_interleaved):
                 q = self._rope_leading(q, positions, self.num_heads)
                 k = self._rope_leading(k, positions, self.kv_heads)
             elif self.rope_theta:
@@ -324,14 +330,16 @@ class AttentionFrontEnd:
 
     def _rope_leading(self, x, positions, heads: int):
         """The first `rope_dim` lanes of every head of x (batch, seq,
-        heads * head_dim) rotated, the others as they are."""
-        dr = self.rope_dim
+        heads * head_dim) rotated (the whole head at 0), in the form
+        `rope_interleaved` says, the others as they are."""
+        dr = self.rope_dim or self.head_dim
         inv_freq = self.rope_theta ** (
             -jnp.arange(0, dr, 2, dtype=jnp.float32) / dr)
         angles = positions.astype(jnp.float32)[..., None, None] * inv_freq
         xh = x.reshape(x.shape[:-1] + (heads, self.head_dim))
+        turn = rope_pairs if self.rope_interleaved else rope_half
         return jnp.concatenate(
-            [rope_half(xh[..., :dr], angles), xh[..., dr:]],
+            [turn(xh[..., :dr], angles), xh[..., dr:]],
             axis=-1).reshape(x.shape)
 
     def index_inputs(self, ctx, weights, x, positions):
@@ -363,7 +371,7 @@ class AttentionFrontEnd:
     def output(self, ctx, weights, o, x=None):
         """The output projection of the core's `o`; `x`, the layer's
         input, feeds the output gate where the front end has one."""
-        with self.scope("gsa.out"):
+        with self.scope("out"):
             if self.output_gate:
                 gate = proj(ctx, x, weights["wg"], None)
                 o = o * jax.nn.sigmoid(
@@ -461,6 +469,16 @@ def rope_half(x, angles):
     a, b = jnp.split(xf, 2, axis=-1)
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
                            axis=-1).astype(x.dtype)
+
+
+def rope_pairs(x, angles):
+    """Pairs (x[2i], x[2i + 1]) rotated by angles (.., d / 2); float32
+    arithmetic, one cast back."""
+    xf = x.astype(jnp.float32)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    a, b = xf[..., 0::2], xf[..., 1::2]
+    return jnp.stack([a * cos - b * sin, a * sin + b * cos],
+                     axis=-1).reshape(x.shape).astype(x.dtype)
 
 
 def layer_norm(x, scale, bias, eps):

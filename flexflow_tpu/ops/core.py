@@ -51,6 +51,10 @@ class LinearParams:
     data_type: DataType = DataType.DT_FLOAT
     kernel_reg_type: RegularizerMode = RegularizerMode.REG_MODE_NONE
     kernel_reg_lambda: float = 0.0
+    # the kernel lies (out, in) and is applied as x @ kernel^T: the layout
+    # of an embedding's table, which a head tied to it reads as it lies
+    # (FFModel.dense(shared_op=<the embedding>)); False = (in, out)
+    kernel_transposed: bool = False
 
 
 def _linear_infer(p: LinearParams, in_shapes):
@@ -60,7 +64,9 @@ def _linear_infer(p: LinearParams, in_shapes):
 
 def _linear_weights(p: LinearParams, in_shapes):
     in_dim = in_shapes[0][-1]
-    ws = [WeightSpec("kernel", (in_dim, p.out_channels), p.data_type, "glorot_uniform")]
+    shape = ((p.out_channels, in_dim) if p.kernel_transposed
+             else (in_dim, p.out_channels))
+    ws = [WeightSpec("kernel", shape, p.data_type, "glorot_uniform")]
     if p.use_bias:
         ws.append(WeightSpec("bias", (p.out_channels,), p.data_type, "zeros"))
     return ws
@@ -69,7 +75,12 @@ def _linear_weights(p: LinearParams, in_shapes):
 def _linear_forward(p: LinearParams, inputs, weights, state, ctx):
     (x,) = inputs
     xm, km = matmul_cast(ctx, x, weights["kernel"])
-    y = jnp.dot(xm, km, preferred_element_type=jnp.float32)
+    if p.kernel_transposed:
+        y = jax.lax.dot_general(
+            xm, km, (((xm.ndim - 1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    else:
+        y = jnp.dot(xm, km, preferred_element_type=jnp.float32)
     y = y.astype(x.dtype)
     if p.use_bias:
         y = y + weights["bias"]
@@ -272,6 +283,8 @@ class LayerNormParams:
     axes: tuple[int, ...]
     elementwise_affine: bool = True
     eps: float = 1e-5
+    # False: a learned scale and no bias (the affine is the scale alone)
+    bias: bool = True
 
 
 def _ln_infer(p, in_shapes):
@@ -282,23 +295,25 @@ def _ln_weights(p: LayerNormParams, in_shapes):
     if not p.elementwise_affine:
         return []
     shape = tuple(in_shapes[0][a] for a in p.axes)
-    return [
-        WeightSpec("scale", shape, DataType.DT_FLOAT, "ones"),
-        WeightSpec("bias", shape, DataType.DT_FLOAT, "zeros"),
-    ]
+    return [WeightSpec("scale", shape, DataType.DT_FLOAT, "ones"),
+            *([WeightSpec("bias", shape, DataType.DT_FLOAT, "zeros")]
+              if p.bias else [])]
 
 
 def _ln_forward(p: LayerNormParams, inputs, weights, state, ctx):
     (x,) = inputs
     axes = tuple(a % x.ndim for a in p.axes)
     if p.elementwise_affine:
+        scale = weights["scale"]
+        # without a bias the kernel adds zeros
+        bias = weights["bias"] if p.bias else jnp.zeros_like(scale)
         # fused Pallas kernel for the tiling-friendly common case (one
         # HBM pass instead of XLA's off-roofline convert+reduce fusion;
         # kernels/layer_norm.py)
         from ..kernels.layer_norm import fused_layer_norm_or_none
 
         fused = fused_layer_norm_or_none(
-            x, weights["scale"], weights["bias"], axes, p.eps,
+            x, scale, bias, axes, p.eps,
             mesh=ctx.mesh, spec=ctx.out_spec)
         if fused is not None:
             return [fused], state
@@ -311,7 +326,9 @@ def _ln_forward(p: LayerNormParams, inputs, weights, state, ctx):
         # bf16·f32 product would also silently promote activations), one
         # final cast to the activation dtype
         bshape = [x.shape[a] if a in axes else 1 for a in range(x.ndim)]
-        y = y * weights["scale"].reshape(bshape) + weights["bias"].reshape(bshape)
+        y = y * scale.reshape(bshape)
+        if p.bias:
+            y = y + bias.reshape(bshape)
     return [y.astype(x.dtype)], state
 
 

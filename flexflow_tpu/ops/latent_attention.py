@@ -58,7 +58,9 @@ import numpy as np
 
 from ..fftype import DataType, OperatorType as OT
 from ..kernels.sparse_selection import causal_selection_mask
-from .attention import SELECTION_CANNOT, layer_norm, proj, rope_half
+from .attention import (
+    SELECTION_CANNOT, layer_norm, proj, rope_half, rope_pairs,
+)
 from .base import (
     BY_BLOCK, HANDOFF, LAST_CALL, QUERIES, DecodeState, OpDef, StateLeaf,
     WeightSpec, register_op,
@@ -218,12 +220,12 @@ class LatentFrontEnd:
                 q = (q.astype(jnp.float32)
                      * a[..., None, None]).astype(q.dtype)
             q_nope = q[..., :dn]
-            q_rope = _rope_interleaved(q[..., dn:], angles[..., None, :])
+            q_rope = rope_pairs(q[..., dn:], angles[..., None, :])
         with jax.named_scope("mla.kv"):
             kv = proj(ctx, x, weights["wkv_a"], None)
             ckv = rms_norm(kv[..., :self.kv_lora_rank], weights["kv_norm"],
                            self.norm_eps)
-            kr = _rope_interleaved(kv[..., self.kv_lora_rank:], angles)
+            kr = rope_pairs(kv[..., self.kv_lora_rank:], angles)
         if self.index is None:
             return q_nope, q_rope, ckv, kr, None
         with jax.named_scope("dsa.index"):
@@ -263,16 +265,6 @@ class LatentFrontEnd:
                 + (ix.n_heads * ix.head_dim if ix else 0))
             + H * self.v_head_dim * self.embed_dim)
         return 2.0 * tokens * per_token
-
-
-def _rope_interleaved(x, angles):
-    """Pairs (x[2i], x[2i+1]) rotated by angles (.., d / 2); float32
-    arithmetic, one cast back."""
-    xf = x.astype(jnp.float32)
-    cos, sin = jnp.cos(angles), jnp.sin(angles)
-    a, b = xf[..., 0::2], xf[..., 1::2]
-    return jnp.stack([a * cos - b * sin, a * sin + b * cos],
-                     axis=-1).reshape(x.shape).astype(x.dtype)
 
 
 # ------------------------------------------------------------ training-shaped

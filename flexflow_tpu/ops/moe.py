@@ -372,6 +372,14 @@ class MoEMLPParams:
     # width of a SiLU-gated expert every token passes through, added to
     # the routed sum; 0 = none
     shared_intermediate_size: int = 0
+    # the shared expert's output times this. n shared experts whose
+    # outputs are averaged are ONE gated MLP n times as wide, the experts'
+    # gate and up matrices side by side and their down matrices one under
+    # the other, with its output times 1 / n: the same function
+    shared_scale: float = 1.0
+    # False: the sigmoid router adds no correction bias to the scores it
+    # chooses by, and the layer holds no `router_bias`
+    correction_bias: bool = True
     # (first expert id, count) of the experts THIS layer holds, on one
     # chip of a deployment that spreads them: the router keeps its width
     # and its k, `gate`/`up`/`down` hold these experts only, and the
@@ -399,7 +407,7 @@ def _moe_mlp_weights(p: MoEMLPParams, in_shapes):
     tokens = math.prod(in_shapes[0][:-1])
     held, fs = p.held[1], p.shared_intermediate_size
     extra = []
-    if p.scoring == "sigmoid":
+    if p.scoring == "sigmoid" and p.correction_bias:
         # e_score_correction_bias: the published one is fitted during
         # training; seeded here, so that it takes part in the choice
         extra.append(WeightSpec("router_bias", (n,), DataType.DT_FLOAT,
@@ -464,7 +472,7 @@ def moe_route_sigmoid(x, router, bias, p: MoEMLPParams):
     logits = jnp.dot(x, router.astype(x.dtype),
                      preferred_element_type=jnp.float32)
     scores = jax.nn.sigmoid(logits)
-    biased = scores + bias.astype(jnp.float32)
+    biased = scores if bias is None else scores + bias.astype(jnp.float32)
     if groups > 1:
         grouped = biased.reshape(-1, groups, n // groups)
         group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
@@ -553,7 +561,7 @@ def _moe_mlp_forward(p: MoEMLPParams, inputs, weights, state, ctx):
     with jax.named_scope("moe.route"):
         if p.scoring == "sigmoid":
             gates, ids, probs = moe_route_sigmoid(
-                x, weights["router"], weights["router_bias"], p)
+                x, weights["router"], weights.get("router_bias"), p)
         else:
             gates, ids, probs = moe_route(x, weights["router"], k)
             if p.norm_topk_prob:
@@ -595,7 +603,10 @@ def _moe_mlp_forward(p: MoEMLPParams, inputs, weights, state, ctx):
 
             h = (jax.nn.silu(dot(x, weights["shared_gate"]))
                  * dot(x, weights["shared_up"])).astype(x.dtype)
-            y = y + dot(h, weights["shared_down"])
+            shared = dot(h, weights["shared_down"])
+            if p.shared_scale != 1.0:
+                shared = shared * p.shared_scale
+            y = y + shared
     state = dict(state or {})
     computed = jnp.sum(group_sizes)
     wanted = ids.size if p.experts_held is None else jnp.sum(here)

@@ -186,7 +186,10 @@ class UnitySearch:
         allow_seq = (self.seq_deg > 1
                      and (self.config.enable_sample_parallel
                           or self.config.search_budget > 0))
-        if node.op_type == OT.OP_LINEAR and allow_param:
+        # (a kernel that lies (out, in) is an embedding's table, placed
+        # with the embedding: the column and row rules are not its)
+        if (node.op_type == OT.OP_LINEAR and allow_param
+                and not node.params.kernel_transposed):
             p = node.params
             if p.out_channels % self.model_deg == 0:
                 out.append(NodeConfig(

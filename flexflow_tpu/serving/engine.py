@@ -251,6 +251,8 @@ class ServingEngine:
                          if s.blocks and bool(s.window) == windowed}
                         for windowed in (False, True)]
         self._window_nodes = list(self._groups[1])
+        # decoding slot-steps scheduled with the context inside the window
+        self._under_window = 0
         # bytes one block holds over the layers of (the global group, the
         # window group), as the pools are stored
         self._block_bytes = tuple(
@@ -1227,11 +1229,22 @@ class ServingEngine:
                 # rows a window layer's attention reads: a row's window,
                 # or its context where that is shorter
                 window = self.block_manager.window.window
-                load.update(window_rows=int(
-                    sum(min(s.length + 1, window) for s in decoding)
-                    + (sum(min(t + 1, window)
-                           for t in range(start, start + n))
-                       if pre is not None else 0)))
+                # decoding slots whose context is still inside the
+                # window: a window layer reads all of it, and the slot
+                # has given no block back yet
+                under = sum(s.length + 1 <= window for s in decoding)
+                self._under_window += under
+                load.update(
+                    window_rows=int(
+                        sum(min(s.length + 1, window) for s in decoding)
+                        + (sum(min(t + 1, window)
+                               for t in range(start, start + n))
+                           if pre is not None else 0)),
+                    under_window=under,
+                    # the group's running count as the step is scheduled:
+                    # blocks the steps before it gave back
+                    window_blocks_freed=(
+                        self.block_manager.stats.window_blocks_freed))
             if self._sel_cap:
                 # a layer's indexer scores every cached row of every live
                 # row's context; its attention reads the selected ones.
@@ -1462,6 +1475,7 @@ class ServingEngine:
         self._prefill_calls = 0
         self._row_steps = 0
         self._chunk_kernel_steps = 0
+        self._under_window = 0
         self._state_resets = 0
         self._device_s = 0.0
         self._last_wall_s = 0.0
@@ -1613,6 +1627,7 @@ class ServingEngine:
                         mgr.stats.window_blocks_in_use_peak,
                     "kv_window_blocks_held": w.blocks_held,
                     "window_blocks_freed": mgr.stats.window_blocks_freed,
+                    "under_window": self._under_window,
                     "window_cow_copies": mgr.stats.window_cow_copies,
                     "window_pins_dropped": mgr.stats.window_pins_dropped,
                     "kv_window_pool_bytes":

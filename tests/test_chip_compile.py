@@ -469,6 +469,48 @@ def test_paged_attention_at_mimo_v2_flash_widths(tpu, kind, rows):
     assert not re.findall(rf"= bf16\[{blocks},128,\d+\]\S* copy\(", text)
 
 
+@pytest.mark.parametrize("rows", [32, 32 + 256])
+@pytest.mark.parametrize("kind", ["global", "window"])
+def test_paged_attention_at_command_a_plus_widths(tpu, kind, rows):
+    """A layer of `cmdap-serve-agentmix` as the decode graph runs it: 128
+    query heads of 128 on 8 KV heads (a query projection of 16,384, a group
+    of 16), interleaved RoPE and a window of 4,096 keys in the window
+    layers, no position in the global one; pools of 1,800 (768) blocks of
+    256 rows of 1,024, page tables 131 wide; 32 decoding rows through the
+    single-query kernel (grouped; the window walk named apart), and the
+    same with a chunk of 256 riding as rows: the global layer's through the
+    grouped chunk kernel, the window layers' through the tile loop in XLA.
+    No step copies a pool."""
+    import re
+
+    from flexflow_tpu.ops.attention import AttentionFrontEnd
+
+    s = _on(tpu[0])
+    window = kind == "window"
+    front = AttentionFrontEnd(
+        4096, 128, use_bias=False, rope_theta=5e4 if window else 0.0,
+        num_kv_heads=8, head_size=128, window=4096 if window else 0,
+        rope_interleaved=window)
+    blocks = 768 if window else 1800
+    p, op, layer = _paged_attention_layer(front, 33536, 256, blocks,
+                                          slots=32)
+    assert p.cache_row_widths == {"pool_k": 1024, "pool_v": 1024}
+    specs = op.weights(p, [(rows, 1, 4096), (rows, 1), (rows, 131)])
+    weights = {w.name: s(w.shape, jnp.bfloat16) for w in specs}
+    assert weights["wq"].shape == (4096, 16384)
+    compiled = jax.jit(layer, donate_argnums=(0,)).lower(
+        weights, s((rows, 1, 4096)), s((rows, 1), jnp.int32),
+        s((rows, 131), jnp.int32)).compile()
+    text = compiled.as_text()
+    want = {("flash_attention_paged_decode_window_grouped" if window
+             else "flash_attention_paged_decode_grouped"): 1}
+    if rows > 32 and not window:
+        want["flash_attention_paged_chunk_grouped"] = 1
+    assert pallas_kernels(text) == want
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+    assert not re.findall(rf"= bf16\[{blocks},256,\d+\]\S* copy\(", text)
+
+
 @pytest.mark.parametrize("chunk", [128, 16])
 def test_paged_chunk_kernel_at_c13b_widths(tpu, chunk):
     """`c13b-serve-chat`'s chunk step as the decode graph runs a layer of
