@@ -1838,8 +1838,9 @@ def paged_chunk_attention_tiled(q, pool_k, pool_v, table_row, positions, *,
                                 tile_rows: int = 2048):
     """Multi-query attention of ONE prefill chunk over a paged KV pool as
     XLA ops, for the layers the chunk kernel below cannot tile (a key head
-    of 192 lanes: its head loop takes lane slices at multiples of the head;
-    a window; a sink): the table row is walked once for all rows, a tile of
+    of 192 lanes beside a value head of 128: it slices one head size; a
+    sink), under their window where they have one: the table row is walked
+    once for all rows, a tile of
     about `tile_rows` cache rows at a time gathered from the pool, with a
     running maximum and sum a row a head (flash attention, in XLA). The
     trip count is the chunk's: from the tile that holds the first key any
@@ -1906,15 +1907,21 @@ def paged_chunk_attention_tiled(q, pool_k, pool_v, table_row, positions, *,
 # single-query rows of the kernel above, token i walks the slot's pages from
 # page 0 on its own (n·start + n(n+1)/2 context rows for a chunk of n at
 # `start`, through a block-diagonal query that spends the MXU H times over);
-# here the table row is walked once, a round's K and V serve every query row
-# and every head, and head h multiplies its own head_dim lanes of the query
-# tile with its own lanes of the round: (b, hd) x (hd, rows), then (b, rows)
-# x (rows, hd), with a running max and sum a row a head. The mask is
-# key_pos < length[i]: causality inside the chunk (length start + i + 1) and
-# the bucket's dead padding rows (length 0, output 0) are one rule, and
+# here the table row is walked once and a round's K and V serve every query
+# row and every head, with a running maximum and sum a row a head. The mask
+# is key_pos < length[i]: causality inside the chunk (length start + i + 1)
+# and the bucket's dead padding rows (length 0, output 0) are one rule, and
 # nothing assumes the positions are consecutive. The trip count is
 # cdiv(the tile's max length, rows a round): a page past the chunk's end is
 # never a DMA.
+#
+# A window (`window` > 0, a layer's static parameter) is a first round and a
+# second bound on the same mask: length[i] - window <= key_pos. A query
+# tile's walk starts at the round that holds the lowest key any of its live
+# rows attends (a scalar a tile beside its largest length), so a chunk of
+# 256 under a window of 4,096 reads some 4.5 k rows of a 30 k history, a
+# tile of 128 at a time. The kernel is then named `..._chunk_window`, as the
+# decode kernel's walk is.
 #
 # The grid is (tiles of query rows, tiles of KV heads). A KV-head tile comes
 # with the query heads that read it (contiguous lanes of the query row:
@@ -1927,15 +1934,45 @@ def paged_chunk_attention_tiled(q, pool_k, pool_v, table_row, positions, *,
 # `_PAGED_CHUNK_VMEM` (_paged_chunk_tile): at c13b's widths (128 rows x 16
 # heads x 128) all 16 heads, one grid step of 0.5 MB of queries, 1 MB of
 # float32 accumulator and 2 MB of rounds; at Solar-Open2's (256 rows x 64
-# query heads over 8 KV heads) 4 KV heads and their 32 query heads, 2 x 2
-# steps, each round a (256, 512) slice of the pool's rows (whole (16, 128)
-# tiles of the bf16 layout, 4 KB contiguous each).
+# query heads over 8 KV heads) 4 KV heads and their 32 query heads, at
+# Command A+'s (128 query heads over 8) 2 and their 32, each round a (256,
+# 256 or 512) slice of the pool's rows (whole (16, 128) tiles of the bf16
+# layout).
 #
-# The heads of a tile are a LOOP in the body (`pl.loop`, lane slices at a
-# dynamic multiple of head_dim), not 16 or 32 copies of it: unrolled, the
-# body is a tenth faster in the kernel and nothing end to end, and its 24
-# copies a program (one a layer) cost c13b-serve-chat 7 s of a 47 s set-up,
-# every run, compile cache or not (PERF.md section 6, PR 34).
+# What a round's body does depends on `group`, query heads a KV head.
+# At 1 head h multiplies its own head_dim lanes of the query tile with its
+# own lanes of the round: (b, hd) x (hd, rows), then (b, rows) x (rows, hd),
+# a head at a time in a loop.
+# Above 1 that loop would push a KV head's K and V lanes to the MXU once a
+# query head of its group, each for a product of 128 rows with its own
+# maximum, exponent, sum and rescale (Command A+'s group of 16 over 11 k
+# rows: 17 % of the MXU's peak, PERF.md section 6, PR 50). There the tile's
+# (head, row) pairs are STACKED, head-major, and a pass of the body takes
+# `_PAGED_CHUNK_BLOCK_LANES` of one KV head's at a time TRANSPOSED: the
+# logits are K (rows a round, hd) x stacked queries^T = (rows a round,
+# stacked rows), keys along the sublanes and query rows along the lanes, and
+# the accumulator is V^T x their weights = (hd, stacked rows). A row's
+# maximum, sum and rescale are then one lane of a (1, stacked) vector, four
+# registers a pass and not 64 columns of one valid lane each, the maximum
+# and the sum over keys are register-wise operations and no cross-lane
+# reduction, the mask is one float32 bias a round (0 or NEG_INF, shared by
+# every pass of the round, over K and V zeroed outside the tile's live rows
+# in the walk's two end rounds alone), and the exponent's base is 2 with the
+# scale folded into one multiply. The output is transposed back, 128 stacked
+# rows at a time, when the walk ends. A query tile under grouped heads is a
+# power of two with group x tile whole 128-lane tiles
+# (_paged_chunk_query_tile), so every slice of the stacked order is whole
+# lane tiles and whole heads. 740 VLIW bundles a pass of 4 heads x 128 rows
+# x 256 keys against 4 x 498 of the head loop (scripts/kernel_bundles.py's
+# counter on this kernel), and on the chip 3.3 times the head loop at a
+# group of 16.
+#
+# The heads of a tile, or its passes, are a LOOP in the body (`pl.loop`,
+# slices at a dynamic multiple of head_dim or of a pass), not 16 or 32
+# copies of it: unrolled, the body is a tenth faster in the kernel and
+# nothing end to end, and its 24 copies a program (one a layer) cost
+# c13b-serve-chat 7 s of a 47 s set-up, every run, compile cache or not
+# (PERF.md section 6, PR 34).
 #
 # The name does not start with `flash_attention_paged_decode`: the slots'
 # rows keep that kernel and that name, in pure-decode steps and in chunk
@@ -1944,24 +1981,47 @@ def paged_chunk_attention_tiled(q, pool_k, pool_v, table_row, positions, *,
 _PAGED_CHUNK_QUERY_ROWS = 128
 # what a grid step's buffers may take: the query and output tiles (double
 # buffered by the pipeline), the float32 accumulator, two K and two V rounds
+# and, under grouped heads, the stacked queries
 _PAGED_CHUNK_VMEM = 10 << 20
+# stacked query rows (lanes of the logits) a pass of the grouped body takes
+_PAGED_CHUNK_BLOCK_LANES = 512
 
 
-def _paged_chunk_kernel(tbl_ref, max_ref, len_ref, q_ref, k_hbm, v_hbm,
-                        o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref, *,
-                        scale: float, head_dim: int, group: int):
+def _paged_chunk_block_lanes(group: int, tq: int) -> int:
+    """Stacked rows a pass of the grouped body takes of a KV head's `group`
+    x `tq` (_paged_chunk_query_tile): whole 128-lane tiles and whole
+    heads that divide them, `_PAGED_CHUNK_BLOCK_LANES` at the most."""
+    return max(n for n in range(128, _PAGED_CHUNK_BLOCK_LANES + 1, 128)
+               if group * tq % n == 0 and n % tq == 0)
+
+
+def _paged_chunk_kernel(tbl_ref, max_ref, *refs, scale: float,
+                        head_dim: int, group: int, window: int = 0):
     """One (query tile, KV-head tile) of a chunk (section comment). q_ref /
     o_ref: (tile's rows, tile's query lanes); len_ref: (tile's rows, 1);
     max_ref: the largest length of each query tile; k_buf / v_buf: (2,
-    rows a round, tile's KV lanes); m_ref / l_ref: (tile's query heads,
-    tile's rows); `group`: query heads a KV head, 1 without grouped keys
-    and values."""
+    rows a round, tile's KV lanes). `group`: query heads a KV head. At 1
+    (no grouped keys and values) m_ref / l_ref are (tile's query heads,
+    tile's rows), acc_ref is o_ref's shape and the body takes a head at a
+    time. Above 1 the tile's (head, row) pairs are STACKED, head-major:
+    qs_ref (stacked, head_dim) holds the queries so, m_ref / l_ref are (1,
+    stacked), acc_ref (head_dim, stacked), and lens_t_ref (1, lanes a pass)
+    the rows' lengths along the lanes. `window` > 0: a row attends its
+    nearest `window` keys, and lo_ref (after max_ref) is the lowest key any
+    live row of each query tile attends."""
+    refs = iter(refs)
+    lo_ref = next(refs) if window else None
+    len_ref, q_ref, k_hbm, v_hbm = (next(refs) for _ in range(4))
+    lens_t_ref = next(refs) if group > 1 else None
+    o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref, *qs_ref = refs
     width = tbl_ref.shape[0]
     rows, kv_lanes = k_buf.shape[1:]
     b = q_ref.shape[0]
-    heads = m_ref.shape[0]
+    heads = q_ref.shape[1] // head_dim
     max_len = max_ref[pl.program_id(0)]
     n_rounds = pl.cdiv(max_len, rows)
+    lo = lo_ref[pl.program_id(0)] if window else 0
+    c0 = lo // rows  # the round the tile's lowest attended key lies in
     lanes = None
     if kv_lanes != k_hbm.shape[-1]:
         lanes = pl.ds(pl.multiple_of(pl.program_id(1) * kv_lanes, kv_lanes),
@@ -1974,7 +2034,7 @@ def _paged_chunk_kernel(tbl_ref, max_ref, len_ref, q_ref, k_hbm, v_hbm,
 
     @pl.when(n_rounds > 0)
     def _first():
-        for dma in copies(0, 0):
+        for dma in copies(c0, c0 % 2):
             dma.start()
 
     m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
@@ -1982,7 +2042,23 @@ def _paged_chunk_kernel(tbl_ref, max_ref, len_ref, q_ref, k_hbm, v_hbm,
     acc_ref[...] = jnp.zeros_like(acc_ref)
     lengths = len_ref[...]  # (b, 1)
 
-    @pl.loop(0, n_rounds)
+    def head_lanes(h):
+        return pl.ds(pl.multiple_of(h * head_dim, head_dim), head_dim)
+
+    if group > 1:
+        qs_ref, = qs_ref
+        block = lens_t_ref.shape[-1]
+        lens_t = lens_t_ref[...]
+        # the exponent's base is 2: one multiply makes the logits of a
+        # product, and the maxima and the differences are in its units
+        to_log2 = scale * math.log2(math.e)
+
+        @pl.loop(0, heads)
+        def _stack(h):
+            qs_ref[pl.ds(pl.multiple_of(h * b, b), b), :] = (
+                q_ref[:, head_lanes(h)])
+
+    @pl.loop(c0, n_rounds)
     def _round(c):
         buf = c % 2
 
@@ -1994,55 +2070,127 @@ def _paged_chunk_kernel(tbl_ref, max_ref, len_ref, q_ref, k_hbm, v_hbm,
         for dma in copies(c, buf):
             dma.wait()
         first = c * rows
-        mask = jax.lax.broadcasted_iota(
-            jnp.int32, (b, rows), 1) + first < lengths
-        # zero V rows past the tile's last: they may hold stale pool state
-        # (anything, NaN included) and 0·NaN would poison the contraction;
-        # rows under it are the slot's own, finite, and masked by p = 0
-        v_live = jax.lax.broadcasted_iota(
-            jnp.int32, (rows, head_dim), 0) + first < max_len
+        if group == 1:
+            key_pos = jax.lax.broadcasted_iota(
+                jnp.int32, (b, rows), 1) + first
+            mask = key_pos < lengths
+            if window:
+                mask &= key_pos >= lengths - window
+            # zero V rows past the tile's last: they may hold stale pool
+            # state (anything, NaN included) and 0·NaN would poison the
+            # contraction; so may rows under every live row's window, whose
+            # blocks are the window group's scratch block by now; the rows
+            # between are the slot's own, finite, and masked by p = 0
+            v_pos = jax.lax.broadcasted_iota(
+                jnp.int32, (rows, head_dim), 0) + first
+            v_live = v_pos < max_len
+            if window:
+                v_live &= v_pos >= lo
 
-        @pl.loop(0, heads)
-        def _head(h):
-            sl = pl.ds(pl.multiple_of(h * head_dim, head_dim), head_dim)
-            kv = pl.ds(pl.multiple_of(h // group * head_dim, head_dim),
-                       head_dim)
+            @pl.loop(0, heads)
+            def _head(h):
+                sl = head_lanes(h)
+                kv = head_lanes(h // group)
+                logits = jax.lax.dot_general(
+                    q_ref[:, sl], k_buf[buf, :, kv],
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * scale  # (b, rows)
+                logits = jnp.where(mask, logits, NEG_INF)
+                v = jnp.where(v_live, v_buf[buf, :, kv], 0.0)
+                m_prev = m_ref[h]
+                m_new = jnp.maximum(m_prev, logits.max(axis=-1))
+                p = jnp.exp(logits - m_new[:, None])
+                alpha = jnp.exp(m_prev - m_new)
+                l_ref[h] = l_ref[h] * alpha + p.sum(axis=-1)
+                acc_ref[:, sl] = (acc_ref[:, sl] * alpha[:, None]
+                                  + jax.lax.dot_general(
+                                      p.astype(v.dtype), v,
+                                      (((1,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32))
+                m_ref[h] = m_new
+            return
+
+        # Grouped heads: keys on the sublanes, `block` stacked rows a pass
+        # on the lanes (section comment).
+        # The rounds at the walk's two ends hold rows no query of the tile
+        # attends: past its last (stale pool state) or under its lowest
+        # window (the window group's scratch block): anything, NaN
+        # included. Zeroed where they lie, K and V, they add 0 + NEG_INF to
+        # a logit and 0 to a sum; a round between holds the slot's own.
+        @pl.when((first < lo) | (first + rows > max_len))
+        def _ends():
+            at = jax.lax.broadcasted_iota(
+                jnp.int32, (rows, kv_lanes), 0) + first
+            own = (at >= lo) & (at < max_len)
+            k_buf[buf] = jnp.where(own, k_buf[buf], 0.0)
+            v_buf[buf] = jnp.where(own, v_buf[buf], 0.0)
+
+        key_pos = jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block), 0) + first
+        seen = key_pos < lens_t
+        if window:
+            seen &= key_pos >= lens_t - window
+        bias = jnp.where(seen, 0.0, NEG_INF)
+
+        @pl.loop(0, heads * b // block)
+        def _block(r):
+            at = r * block  # the pass's first stacked row
+            kv = head_lanes(at // (group * b))
+            cols = pl.ds(pl.multiple_of(at, block), block)
             logits = jax.lax.dot_general(
-                q_ref[:, sl], k_buf[buf, :, kv], (((1,), (1,)), ((), ())),
+                k_buf[buf, :, kv], qs_ref[cols, :], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            ) * scale  # (b, rows)
-            logits = jnp.where(mask, logits, NEG_INF)
-            v = jnp.where(v_live, v_buf[buf, :, kv], 0.0)
-            m_prev = m_ref[h]
-            m_new = jnp.maximum(m_prev, logits.max(axis=-1))
-            p = jnp.exp(logits - m_new[:, None])
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[h] = l_ref[h] * alpha + p.sum(axis=-1)
-            acc_ref[:, sl] = (acc_ref[:, sl] * alpha[:, None]
-                              + jax.lax.dot_general(
-                                  p.astype(v.dtype), v,
-                                  (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32))
-            m_ref[h] = m_new
+            ) * to_log2 + bias  # (rows, block)
+            m_prev = m_ref[:, cols]
+            m_new = jnp.maximum(m_prev, logits.max(axis=0, keepdims=True))
+            p = jnp.exp2(logits - m_new)
+            alpha = jnp.exp2(m_prev - m_new)
+            l_ref[:, cols] = l_ref[:, cols] * alpha + p.sum(axis=0,
+                                                            keepdims=True)
+            v = v_buf[buf, :, kv]
+            acc_ref[:, cols] = acc_ref[:, cols] * alpha + jax.lax.dot_general(
+                v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[:, cols] = m_new
 
     # a dead row (length 0) met nothing but masked logits, NEG_INF all, so
     # its p was 1 for every key of every round: it gives 0, as the
     # single-query kernel does. A live row's l >= exp(0) = 1.
     alive = lengths > 0
+    if group == 1:
+        @pl.loop(0, heads)
+        def _finish(h):
+            sl = head_lanes(h)
+            o_ref[:, sl] = jnp.where(
+                alive,
+                acc_ref[:, sl] / jnp.maximum(l_ref[h], 1e-30)[:, None],
+                0.0).astype(o_ref.dtype)
+        return
 
-    @pl.loop(0, heads)
-    def _finish(h):
-        sl = pl.ds(pl.multiple_of(h * head_dim, head_dim), head_dim)
-        o_ref[:, sl] = jnp.where(
-            alive, acc_ref[:, sl] / jnp.maximum(l_ref[h], 1e-30)[:, None],
-            0.0).astype(o_ref.dtype)
+    per = max(1, 128 // b)  # heads a 128-lane tile of the stacked order
+
+    @pl.loop(0, heads // per)
+    def _finish_stacked(i):
+        cols = pl.ds(pl.multiple_of(i * per * b, per * b), per * b)
+        out = (acc_ref[:, cols] / jnp.maximum(l_ref[:, cols], 1e-30)).T
+        for u in range(per):
+            o_ref[:, head_lanes(i * per + u)] = jnp.where(
+                alive, out[u * b:(u + 1) * b], 0.0).astype(o_ref.dtype)
 
 
-def _paged_chunk_query_tile(b: int) -> tuple[int, int]:
+def _paged_chunk_query_tile(b: int, group: int = 1) -> tuple[int, int]:
     """(rows a query tile, tiles) for a chunk of b rows: whole (16, 128)
     tiles of bf16, `_PAGED_CHUNK_QUERY_ROWS` at the most; the rows this
-    adds are dead (length 0)."""
-    tq = min(-(-b // 16) * 16, _PAGED_CHUNK_QUERY_ROWS)
+    adds are dead (length 0). Under grouped heads a power of two, and
+    `group` x the tile's rows whole 128-lane tiles: the stacked order of
+    the kernel, which a pass cuts at whole heads and whole tiles."""
+    if group == 1:
+        tq = min(-(-b // 16) * 16, _PAGED_CHUNK_QUERY_ROWS)
+    else:
+        tq = max(16, 128 // math.gcd(group, 128))
+        while tq < min(b, _PAGED_CHUNK_QUERY_ROWS):
+            tq *= 2
     return tq, -(-b // tq)
 
 
@@ -2055,12 +2203,16 @@ def _paged_chunk_tile(b: int, embed: int, kv_width: int, num_heads: int,
     that is not the whole row is whole 128-lane tiles of it."""
     head_dim = embed // num_heads
     kv_heads = kv_width // head_dim
-    tq, _ = _paged_chunk_query_tile(b)
+    group = num_heads // kv_heads
+    tq, _ = _paged_chunk_query_tile(b, group)
     for hk in range(kv_heads, 0, -1):
         if kv_heads % hk or (hk != kv_heads and not interpret
                              and hk * head_dim % 128):
             continue
-        need = (tq * hk * (embed // kv_heads) * (4 * itemsize + 4)
+        # the query and output tiles, twice each, the accumulator, the
+        # rounds and, under grouped heads, the stacked queries
+        need = (tq * hk * group * head_dim
+                * ((5 if group > 1 else 4) * itemsize + 4)
                 + 4 * round_rows * hk * head_dim * itemsize)
         if need <= _PAGED_CHUNK_VMEM:
             return hk
@@ -2080,17 +2232,19 @@ def paged_chunk_gate(b: int, cache_rows: int, block_size: int, embed: int,
             b, embed, kv_width, num_heads,
             _paged_round_pages(block_size) * block_size, itemsize,
             interpret) is None:
-        gate = (f"a query tile of {_paged_chunk_query_tile(b)[0]} rows x "
+        tq = _paged_chunk_query_tile(b, num_heads // kv_heads)[0]
+        gate = (f"a query tile of {tq} rows x "
                 f"{embed // kv_heads} lanes and its rounds take more than "
                 f"{_PAGED_CHUNK_VMEM} bytes of VMEM")
     return gate
 
 
 @functools.partial(
-    jax.jit, static_argnames=("num_heads", "scale", "pages", "interpret"))
+    jax.jit, static_argnames=("num_heads", "scale", "pages", "interpret",
+                              "window"))
 def _paged_chunk_call(table_row, lengths, q, pool_k, pool_v, *,
                       num_heads: int, scale: float, pages: int,
-                      interpret: bool):
+                      interpret: bool, window: int = 0):
     """The kernel launch (shapes already gated), jitted for the memory
     space constraint as _paged_decode_call is. q: (b, H·hd)."""
     b, e = q.shape
@@ -2098,7 +2252,7 @@ def _paged_chunk_call(table_row, lengths, q, pool_k, pool_v, *,
     d = e // num_heads
     group = e // e_kv
     rows = pages * bs
-    tq, q_tiles = _paged_chunk_query_tile(b)
+    tq, q_tiles = _paged_chunk_query_tile(b, group)
     hk = _paged_chunk_tile(b, e, e_kv, num_heads, rows,
                            pool_k.dtype.itemsize, interpret)
     pad = tq * q_tiles - b
@@ -2111,49 +2265,74 @@ def _paged_chunk_call(table_row, lengths, q, pool_k, pool_v, *,
         pool_k, pool_v = (pltpu.with_memory_space_constraint(p, pltpu.HBM)
                           for p in (pool_k, pool_v))
     heads = hk * group
-    qspec = pl.BlockSpec((tq, heads * d), lambda i, j, tbl, mx: (i, j))
+    by_tile = lengths.reshape(q_tiles, tq)
+    prefetch = [table_row, by_tile.max(axis=1)]
+    if window:
+        # the lowest key a live row of each tile attends (a dead tile runs
+        # no round)
+        shortest = jnp.where(by_tile > 0, by_tile,
+                             jnp.iinfo(jnp.int32).max).min(axis=1)
+        prefetch.append(jnp.maximum(shortest - window, 0))
+    qspec = pl.BlockSpec((tq, heads * d), lambda i, j, *_: (i, j))
     pool_spec = pl.BlockSpec(memory_space=pltpu.HBM)
+    extra, extra_specs = [], []
+    if group == 1:  # a maximum and a sum a head a row, rows on the lanes
+        scratch = [pltpu.VMEM((heads, tq), jnp.float32)] * 2 + [
+            pltpu.VMEM((tq, heads * d), jnp.float32)]
+    else:
+        # the (head, row) pairs stacked along the lanes: maxima, sums and
+        # the accumulator so, the queries with them on the rows, and a
+        # pass's lengths along the lanes
+        block = _paged_chunk_block_lanes(group, tq)
+        scratch = [pltpu.VMEM((1, heads * tq), jnp.float32)] * 2 + [
+            pltpu.VMEM((d, heads * tq), jnp.float32),
+            pltpu.VMEM((heads * tq, d), q.dtype)]
+        extra = [jnp.tile(by_tile[:, None], (1, 1, block // tq))]
+        extra_specs = [pl.BlockSpec((None, 1, block),
+                                    lambda i, j, *_: (i, 0, 0))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetch),
         grid=(q_tiles, e_kv // (hk * d)),
-        in_specs=[pl.BlockSpec((tq, 1), lambda i, j, tbl, mx: (i, 0)),
-                  qspec, pool_spec, pool_spec],
+        in_specs=[pl.BlockSpec((tq, 1), lambda i, j, *_: (i, 0)),
+                  qspec, pool_spec, pool_spec, *extra_specs],
         out_specs=qspec,
         scratch_shapes=[
             pltpu.VMEM((2, rows, hk * d), pool_k.dtype),
             pltpu.VMEM((2, rows, hk * d), pool_v.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((heads, tq), jnp.float32),
-            pltpu.VMEM((heads, tq), jnp.float32),
-            pltpu.VMEM((tq, heads * d), jnp.float32),
+            *scratch,
         ],
     )
+    name = "flash_attention_paged_chunk"
+    if window:  # named apart, as the decode kernel's walk is
+        name += "_window"
     out = pl.pallas_call(
         functools.partial(_paged_chunk_kernel, scale=scale, head_dim=d,
-                          group=group),
+                          group=group, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
-        name=("flash_attention_paged_chunk_grouped" if group > 1
-              else "flash_attention_paged_chunk"),
-    )(table_row, lengths.reshape(q_tiles, tq).max(axis=1), lengths[:, None],
-      q, pool_k, pool_v)
+        name=name + ("_grouped" if group > 1 else ""),
+    )(*prefetch, lengths[:, None], q, pool_k, pool_v, *extra)
     return out[:b]
 
 
 def paged_flash_chunk_attention(
     q, pool_k, pool_v, table_row, lengths, *, num_heads: int,
     scale: float | None = None, num_kv_heads: int | None = None,
+    window: int = 0,
 ):
     """Multi-query attention of ONE prefill chunk over a paged KV pool. q:
     (b, 1, H·hd), the chunk's rows; pool_k/v: (num_blocks, block_size,
     KV·hd); table_row: (W,) int32, the page-table row they all read
     through; lengths: (b,) int32 live-key counts a row (`start + i + 1`
     for the chunk's token i once its K and V are in the pool, 0 for a dead
-    padding row, whose output is 0). Equal to paged_flash_decode_attention
+    padding row, whose output is 0). `window` > 0: a row attends its
+    nearest `window` keys, and a query tile walks from the page that holds
+    the lowest of them. Equal to paged_flash_decode_attention
     on the same rows under b copies of the table row, which is what a
     shape the kernel cannot tile gets (paged_chunk_gate): never the
     reference where the single-query kernel would serve, since a row
@@ -2171,11 +2350,13 @@ def paged_flash_chunk_attention(
                         pool_k.dtype.itemsize, interpret) is not None:
         return paged_flash_decode_attention(
             q, pool_k, pool_v, jnp.broadcast_to(table_row, (b, W)), lengths,
-            num_heads=num_heads, scale=scale, num_kv_heads=num_kv_heads)
+            num_heads=num_heads, scale=scale, num_kv_heads=num_kv_heads,
+            window=window)
     return _paged_chunk_call(
         table_row.astype(jnp.int32), lengths.astype(jnp.int32), q[:, 0],
         pool_k, pool_v, num_heads=num_heads, scale=scale,
-        pages=_paged_round_pages(bs), interpret=interpret)[:, None]
+        pages=_paged_round_pages(bs), interpret=interpret,
+        window=window)[:, None]
 
 
 def flash_attention(

@@ -300,17 +300,24 @@ def paged_rows_run_kernel(p: PagedIncMultiHeadAttentionParams, mesh,
                 jax.default_backend() != "tpu", p.front.v_width) is None)
 
 
+def _chunk_tiled_in_xla(front: AttentionFrontEnd) -> bool:
+    """Whether a layer's chunk rows go through the tile loop in XLA
+    (kernels/flash_attention.paged_chunk_attention_tiled): under a sink, or
+    key and value heads of two sizes, which the chunk kernel does not take.
+    A window it does (a first round and a second bound on its mask)."""
+    return bool(front.sink) or front.v_head_dim != front.head_dim
+
+
 def _chunk_gate(p: PagedIncMultiHeadAttentionParams, b: int,
                 itemsize: int) -> str | None:
     """Why the b rows past `chunk_from` of a call the paged kernel serves
     cannot go through the multi-query chunk kernel, or None."""
     from ..kernels.flash_attention import paged_chunk_gate
 
-    if not p.front.plain_core:
+    if _chunk_tiled_in_xla(p.front):
         return (f"key and value heads of {p.front.head_dim} / "
-                f"{p.front.v_head_dim}, window {p.front.window}, sink "
-                f"{p.front.sink}: the chunk kernel's head loop slices one "
-                f"head size and attends the whole past")
+                f"{p.front.v_head_dim}, sink {p.front.sink}: the chunk "
+                f"kernel slices one head size and has no sink")
     return paged_chunk_gate(
         b, p.blocks_per_slot * p.block_size, p.block_size,
         p.num_heads * p.front.head_dim, p.front.kv_width, p.num_heads,
@@ -329,13 +336,12 @@ def paged_chunk_query_tile(p: PagedIncMultiHeadAttentionParams, mesh,
 
     if p.chunk_from is None or not paged_rows_run_kernel(p, mesh, itemsize):
         return None
-    if not p.front.plain_core:
-        # the tile loop in XLA reads the chunk's context once for all of
-        # its rows (kernels/flash_attention.paged_chunk_attention_tiled)
+    if _chunk_tiled_in_xla(p.front):
+        # that loop reads the chunk's context once for all of its rows
         return b
     if _chunk_gate(p, b, itemsize) is not None:
         return None
-    return _paged_chunk_query_tile(b)[0]
+    return _paged_chunk_query_tile(b, p.num_heads // p.front.kv_heads)[0]
 
 
 def _paged_mha_infer(p: PagedIncMultiHeadAttentionParams, in_shapes):
@@ -418,29 +424,30 @@ def _paged_mha_forward(p: PagedIncMultiHeadAttentionParams, inputs, weights,
 
         pools = pk.astype(q.dtype), pv.astype(q.dtype)
         lengths = jnp.where(live[:, 0], pos_c[:, 0] + 1, 0)
-        kw = dict(num_heads=H, scale=scale, num_kv_heads=kv_heads)
-        if not p.front.plain_core:
-            kw.update(window=window, sink=sink)
+        kw = dict(num_heads=H, scale=scale, num_kv_heads=kv_heads,
+                  window=window)
         # the slots' rows, each under its own table row; where rows past
         # them are a chunk's and its kernel takes them, those under ONE
         # table row in one multi-query call (every row's K and V are in
-        # the pool by now); a layer that kernel cannot tile (a window, a
-        # sink, key and value heads of two sizes) takes the chunk through
-        # the tile loop in XLA, which reads its context once as well;
-        # else the same single-query kernel, row by row
+        # the pool by now); a layer that kernel cannot tile (a sink, key
+        # and value heads of two sizes) takes the chunk through the tile
+        # loop in XLA, which reads its context once as well; else the
+        # same single-query kernel, row by row
         n = slots if p.chunk_from is None else min(slots, p.chunk_from)
-        if (n < slots and p.front.plain_core
+        tiled = _chunk_tiled_in_xla(p.front)
+        if (n < slots and not tiled
                 and _chunk_gate(p, slots - n, q.dtype.itemsize) is not None):
             n = slots
         with jax.named_scope(p.front.attend_scope):
             out = paged_flash_decode_attention(
-                q[:n], *pools, page_table[:n], lengths[:n], **kw)
-            if n < slots and p.front.plain_core:
+                q[:n], *pools, page_table[:n], lengths[:n], sink=sink, **kw)
+            if n < slots and not tiled:
                 out = jnp.concatenate([out, paged_flash_chunk_attention(
                     q[n:], *pools, page_table[n], lengths[n:], **kw)])
             elif n < slots:
                 out = jnp.concatenate([out, paged_chunk_attention_tiled(
-                    q[n:], *pools, page_table[n], lengths[n:] - 1, **kw)])
+                    q[n:], *pools, page_table[n], lengths[n:] - 1, sink=sink,
+                    **kw)])
     else:
         # reference path (CPU tier-1 + the kernel's numerics oracle):
         # gather each slot's logical cache view from the pool, then run

@@ -3,9 +3,15 @@ read without a chip.
 
     JAX_PLATFORMS=cpu python scripts/kernel_bundles.py --batch 8 --seq 1024 \
         --width 1024 --heads 16 [--bwd] [--block 512]
+    JAX_PLATFORMS=cpu python scripts/kernel_bundles.py --paged-chunk 8 \
+        --batch 256 --seq 33536 --width 16384 --heads 128 --block 256 \
+        [--window 4096]
 
-Compiles `flash_attention_packed` for a described v5e (as
-tests/test_chip_compile.py does) with libtpu's LLO dump on, and prints, for
+Compiles `flash_attention_packed` (or, with `--paged-chunk KV_HEADS`, the
+paged chunk kernel for a chunk of `--batch` rows over a cache of `--seq`
+rows in blocks of `--block`: `cmdap-serve-agentmix`'s shapes above) for a
+described v5e (as tests/test_chip_compile.py does) with libtpu's LLO dump
+on, and prints, for
 every Pallas kernel in the program, the VLIW bundles of its final schedule
 by loop depth (depth 1: a grid step; deeper: the loops inside the body) with
 what fills them: MXU pushes, result pops, VPU and EUP operations, vector
@@ -56,7 +62,18 @@ else:
     fn = lambda *a: fa._flash_packed_vjp_fwd(*a, {heads}, True,
                                              ({width} // {heads}) ** -0.5,
                                              {block}, {block})[0]
-jax.jit(fn).lower(x, x, x).compile()
+if {paged_chunk}:
+    s = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=x.sharding)
+    d, pages = {width} // {heads}, {seq} // {block}
+    pool = s(pages + 1, {block}, {paged_chunk} * d)
+    jax.jit(lambda *a: fa.paged_flash_chunk_attention(
+        *a, num_heads={heads}, num_kv_heads={paged_chunk},
+        **(dict(window={window}) if {window} else {{}}))).lower(
+        s({batch}, 1, {width}), pool, pool, s(pages, dtype=jnp.int32),
+        s({batch}, dtype=jnp.int32)).compile()
+else:
+    jax.jit(fn).lower(x, x, x).compile()
 """
 
 BUNDLE = re.compile(r"^\s*(?:0x[0-9a-f]+|\d+)\s+(?:[A-Z]{2})?:?\s*(>*)\s*\{(.*)\}")
@@ -100,6 +117,8 @@ def main() -> int:
     ap.add_argument("--heads", type=int, default=16)
     ap.add_argument("--block", type=int, default=512)
     ap.add_argument("--bwd", action="store_true")
+    ap.add_argument("--paged-chunk", type=int, default=0, metavar="KV_HEADS")
+    ap.add_argument("--window", type=int, default=0)
     ap.add_argument("--min-bundles", type=int, default=20)
     opts = ap.parse_args()
     with tempfile.TemporaryDirectory() as dump:

@@ -478,9 +478,9 @@ def test_paged_attention_at_command_a_plus_widths(tpu, kind, rows):
     layers, no position in the global one; pools of 1,800 (768) blocks of
     256 rows of 1,024, page tables 131 wide; 32 decoding rows through the
     single-query kernel (grouped; the window walk named apart), and the
-    same with a chunk of 256 riding as rows: the global layer's through the
-    grouped chunk kernel, the window layers' through the tile loop in XLA.
-    No step copies a pool."""
+    same with a chunk of 256 riding as rows through ONE call of the grouped
+    chunk kernel, the window layers' under its window walk (named apart as
+    well). No step copies a pool."""
     import re
 
     from flexflow_tpu.ops.attention import AttentionFrontEnd
@@ -504,8 +504,9 @@ def test_paged_attention_at_command_a_plus_widths(tpu, kind, rows):
     text = compiled.as_text()
     want = {("flash_attention_paged_decode_window_grouped" if window
              else "flash_attention_paged_decode_grouped"): 1}
-    if rows > 32 and not window:
-        want["flash_attention_paged_chunk_grouped"] = 1
+    if rows > 32:
+        want["flash_attention_paged_chunk_window_grouped" if window
+             else "flash_attention_paged_chunk_grouped"] = 1
     assert pallas_kernels(text) == want
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
     assert not re.findall(rf"= bf16\[{blocks},256,\d+\]\S* copy\(", text)

@@ -312,7 +312,8 @@ def test_the_gates_admit_what_the_kernels_can_tile():
     # as before: heads of 128 pass, heads of 64 do not
     assert fa.paged_decode_gate(4096, 16, 2048, 16, 2, False) is None
     assert "head_dim 64" in fa.paged_decode_gate(4096, 16, 1024, 16, 2, False)
-    # a chunk's rows of a layer with a window take the tile loop, by name
+    # a chunk's rows of a layer with a sink and key and value heads of two
+    # sizes take the tile loop, by name; its window alone would not
     from flexflow_tpu.ops import inc_attention as inc
     from flexflow_tpu.ops.attention import AttentionFrontEnd
 
@@ -321,8 +322,13 @@ def test_the_gates_admit_what_the_kernels_can_tile():
                               window=128, sink=True)
     p = inc.PagedIncMultiHeadAttentionParams(front, 33536, 128, 512,
                                              impl="flash", chunk_from=32)
-    assert "window 128" in inc._chunk_gate(p, 256, 2)
+    assert "192 / 128, sink True" in inc._chunk_gate(p, 256, 2)
     assert inc.paged_chunk_query_tile(p, None, 2, 256) == 256
+    windowed = inc.PagedIncMultiHeadAttentionParams(
+        AttentionFrontEnd(4096, 64, False, 1e4, num_kv_heads=8,
+                          head_size=128, window=128),
+        33536, 128, 512, impl="flash", chunk_from=32)
+    assert inc._chunk_gate(windowed, 256, 2) is None
     assert p.cache_row_widths == {"pool_k": 1536, "pool_v": 1024}
 
 
