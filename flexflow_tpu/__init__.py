@@ -6,6 +6,12 @@ GSPMD sharding over an ICI mesh, collectives instead of task-based data
 movement. See SURVEY.md for the capability map against the reference.
 """
 
+import time as _time
+
+# the `import` phase of the start-up record (telemetry/startup.py): what
+# this package's own imports cost, from here to the last line
+_IMPORT_T0 = _time.perf_counter()
+
 from .config import FFConfig, FFIterationConfig
 from .fftype import (
     ActiMode,
@@ -39,3 +45,5 @@ from .optimizer import AdamOptimizer, Optimizer, SGDOptimizer
 from .tensor import ParallelDim, ParallelTensor, ParallelTensorShape, Tensor
 
 __version__ = "0.1.0"
+
+telemetry.startup.complete("import", _IMPORT_T0, _time.perf_counter())

@@ -230,7 +230,7 @@ def verify_plan(model, cost_model=None) -> AnalysisResult:
     from .. import telemetry
     from ..telemetry import log as fflog
 
-    with telemetry.span("compile.verify"):
+    with telemetry.phase("compile.verify"):
         ctx = context_for_model(model, cost_model=cost_model)
         result = run_analysis(model.graph, model.mesh, ctx)
     model._analysis = result

@@ -800,7 +800,11 @@ class FFModel:
                 # time-to-first-step accounting: the fit summary reports
                 # first-step completion relative to this instant
                 tel.note_compile_start(t_compile0)
-            with telemetry.span("compile"):
+            with telemetry.phase(
+                    "compile",
+                    comp_mode=("inference"
+                               if comp_mode == CompMode.COMP_MODE_INFERENCE
+                               else "training")):
                 self._compile_impl(optimizer, loss_type, metrics, comp_mode)
             if tel is not None:
                 # the COMPILED outcome (a mesh-shape search may have
@@ -848,51 +852,55 @@ class FFModel:
         self.config.computation_mode = comp_mode
 
         # --- create_operators_from_layers
-        g = Graph()
-        tensor_to_out = {}  # Tensor guid -> (OpNode, out idx)
-        for t in self._input_tensors:
-            node = OpNode(OT.OP_INPUT, None, name=t.name)
-            shape = ParallelTensorShape.from_shape(t.dims, t.dtype)
-            pt = ParallelTensor(shape, name=t.name)
-            node.outputs = [pt]
-            g.add_node(node)
-            tensor_to_out[t.tensor_guid] = (node, 0)
+        with telemetry.phase("compile.graph"):
+            g = Graph()
+            tensor_to_out = {}  # Tensor guid -> (OpNode, out idx)
+            for t in self._input_tensors:
+                node = OpNode(OT.OP_INPUT, None, name=t.name)
+                shape = ParallelTensorShape.from_shape(t.dims, t.dtype)
+                pt = ParallelTensor(shape, name=t.name)
+                node.outputs = [pt]
+                g.add_node(node)
+                tensor_to_out[t.tensor_guid] = (node, 0)
 
-        guid_to_node: dict[int, OpNode] = {}
-        self._weight_alias: dict[str, str] = {}  # tied node name -> owner
-        for layer in self.layers:
-            node = OpNode(layer.op_type, layer.params, name=layer.name,
-                          layer_guid=layer.layer_guid,
-                          initializers=layer.initializers)
-            g.add_node(node)
-            guid_to_node[layer.layer_guid] = node
-            for dst_idx, t_in in enumerate(layer.inputs):
-                src_node, src_idx = tensor_to_out[t_in.tensor_guid]
-                g.add_edge(src_node, node, src_idx, dst_idx)
-                node.inputs.append(src_node.outputs[src_idx])
-            in_shapes = [t.dims for t in layer.inputs]
-            node.weight_specs = node.op_def.weights(layer.params, in_shapes)
-            if layer.shared_layer_guid >= 0:
-                # tied weights: this node reads the source node's parameter
-                # set; the executor creates no variables for it and autodiff
-                # sums gradients across all uses (reference shared_op)
-                src = guid_to_node.get(layer.shared_layer_guid)
-                if src is None:
-                    raise ValueError(
-                        f"{layer.name}: shared_op layer must be built "
-                        f"before the layer sharing it")
-                # (`_add_layer` held each of its weights to the source's,
-                # by name and shape, when the tie was made)
-                node.weight_source = src.name
-                self._weight_alias[node.name] = src.name
-            for i, t_out in enumerate(layer.outputs):
-                shape = ParallelTensorShape.from_shape(t_out.dims, t_out.dtype)
-                pt = ParallelTensor(shape, name=t_out.name)
-                pt.owner_op = node
-                pt.owner_idx = i
-                node.outputs.append(pt)
-                tensor_to_out[t_out.tensor_guid] = (node, i)
-        self.graph = g
+            guid_to_node: dict[int, OpNode] = {}
+            self._weight_alias: dict[str, str] = {}  # tied node name -> owner
+            for layer in self.layers:
+                node = OpNode(layer.op_type, layer.params, name=layer.name,
+                              layer_guid=layer.layer_guid,
+                              initializers=layer.initializers)
+                g.add_node(node)
+                guid_to_node[layer.layer_guid] = node
+                for dst_idx, t_in in enumerate(layer.inputs):
+                    src_node, src_idx = tensor_to_out[t_in.tensor_guid]
+                    g.add_edge(src_node, node, src_idx, dst_idx)
+                    node.inputs.append(src_node.outputs[src_idx])
+                in_shapes = [t.dims for t in layer.inputs]
+                node.weight_specs = node.op_def.weights(
+                    layer.params, in_shapes)
+                if layer.shared_layer_guid >= 0:
+                    # tied weights: this node reads the source node's
+                    # parameter set; the executor creates no variables for
+                    # it and autodiff sums gradients across all uses
+                    # (reference shared_op)
+                    src = guid_to_node.get(layer.shared_layer_guid)
+                    if src is None:
+                        raise ValueError(
+                            f"{layer.name}: shared_op layer must be built "
+                            f"before the layer sharing it")
+                    # (`_add_layer` held each of its weights to the source's,
+                    # by name and shape, when the tie was made)
+                    node.weight_source = src.name
+                    self._weight_alias[node.name] = src.name
+                for i, t_out in enumerate(layer.outputs):
+                    shape = ParallelTensorShape.from_shape(
+                        t_out.dims, t_out.dtype)
+                    pt = ParallelTensor(shape, name=t_out.name)
+                    pt.owner_op = node
+                    pt.owner_idx = i
+                    node.outputs.append(pt)
+                    tensor_to_out[t_out.tensor_guid] = (node, i)
+            self.graph = g
 
         # --- mesh + strategy
         self.mesh = self._build_mesh(self.config.mesh_shape())
@@ -995,7 +1003,7 @@ class FFModel:
                 if _calibrated[0] or self.config.search_calibrate <= 0:
                     return
                 _calibrated[0] = True
-                with telemetry.span("compile.calibrate"):
+                with telemetry.phase("compile.calibrate"):
                     cost_model.calibrate_graph(
                         g, top_k=self.config.search_calibrate)
                     # ring-capable axes: measure the real ppermute hop so
@@ -1099,7 +1107,7 @@ class FFModel:
                                replay_names=orig_names)
                     return strategy
 
-                with telemetry.span("compile.search", mode="multihost"):
+                with telemetry.phase("compile.search", mode="multihost"):
                     self._strategy = run_search_on_host0(_search)
                 if self._plan_source == "none":
                     # host 0 knows whether the plan was searched or served
@@ -1150,7 +1158,7 @@ class FFModel:
                         self.config.machine_model_file, mesh)
                 _calibrate()
                 orig_names = {n.name for n in g.topo_order()}
-                with telemetry.span("compile.search", mode="mesh_shapes"):
+                with telemetry.phase("compile.search", mode="mesh_shapes"):
                     shape, g, choice, us, _ = search_mesh_shapes(
                         g, n_devices, self.config, axes=search_axes,
                         chip=machine.chip,
@@ -1174,7 +1182,7 @@ class FFModel:
             else:
                 _calibrate()
                 orig_names = {n.name for n in g.topo_order()}
-                with telemetry.span("compile.search", mode="joint"):
+                with telemetry.phase("compile.search", mode="joint"):
                     g, choice, us = joint_graph_optimize(
                         g, self.mesh, self.config, cost_model)
                 self.graph = g
@@ -1261,50 +1269,52 @@ class FFModel:
         # report / drift monitor price.
         from .search.unity import choose_update_sharding
 
-        if search_cost_model is None and self._warmstart is not None:
-            # no local search ran (warm-start plan hit / checkpoint /
-            # import / dp fallback): price the decision with the SAME
-            # persisted calibration a cold --calibrate run consumed —
-            # a roofline-only cost model could flip the auto decision
-            # between a cold run and a warm restart of the identical job
-            # (parity with the replayed strategy report, explain.py)
-            from .search.cost_model import CostModel
-            from .search.machine_model import machine_model_for_mesh
+        with telemetry.phase("compile.update_sharding"):
+            if search_cost_model is None and self._warmstart is not None:
+                # no local search ran (warm-start plan hit / checkpoint /
+                # import / dp fallback): price the decision with the SAME
+                # persisted calibration a cold --calibrate run consumed —
+                # a roofline-only cost model could flip the auto decision
+                # between a cold run and a warm restart of the identical job
+                # (parity with the replayed strategy report, explain.py)
+                from .search.cost_model import CostModel
+                from .search.machine_model import machine_model_for_mesh
 
-            search_cost_model = CostModel(
-                machine_model_for_mesh(
-                    self.mesh, num_hosts=self.config.num_nodes),
+                search_cost_model = CostModel(
+                    machine_model_for_mesh(
+                        self.mesh, num_hosts=self.config.num_nodes),
+                    opt_slots=self.optimizer.num_slots)
+                self._warmstart.calibration_db.load_into(search_cost_model)
+            self._update_sharding = choose_update_sharding(
+                g, self.mesh, self.config, cost_model=search_cost_model,
                 opt_slots=self.optimizer.num_slots)
-            self._warmstart.calibration_db.load_into(search_cost_model)
-        self._update_sharding = choose_update_sharding(
-            g, self.mesh, self.config, cost_model=search_cost_model,
-            opt_slots=self.optimizer.num_slots)
-        if jax.process_count() > 1:
-            # the auto verdict prices with process-divergent cost models
-            # (calibration + the warm-start DB live on process 0 only) and
-            # its thresholds can land on opposite sides across hosts —
-            # adopt the coordinator's decision everywhere so every process
-            # pins the same update layout into the one jitted step
-            from .distributed import broadcast_json, is_coordinator
+            if jax.process_count() > 1:
+                # the auto verdict prices with process-divergent cost models
+                # (calibration + the warm-start DB live on process 0 only) and
+                # its thresholds can land on opposite sides across hosts —
+                # adopt the coordinator's decision everywhere so every process
+                # pins the same update layout into the one jitted step
+                from .distributed import broadcast_json, is_coordinator
 
-            self._update_sharding = broadcast_json(
-                self._update_sharding if is_coordinator() else None)
-            if search_cost_model is not None:
-                # keep the local cost model pricing the ADOPTED mode (the
-                # strategy report / drift monitor must describe what runs)
-                search_cost_model.update_sharding = (
-                    self._update_sharding["enabled"])
-                search_cost_model.param_gather = (
-                    self._update_sharding.get("stage", 0) == 3)
-                search_cost_model.overlap_update = (
-                    self._update_sharding["enabled"]
-                    and bool(self.config.overlap_collectives))
+                self._update_sharding = broadcast_json(
+                    self._update_sharding if is_coordinator() else None)
+                if search_cost_model is not None:
+                    # keep the local cost model pricing the ADOPTED mode (the
+                    # strategy report / drift monitor must describe what runs)
+                    search_cost_model.update_sharding = (
+                        self._update_sharding["enabled"])
+                    search_cost_model.param_gather = (
+                        self._update_sharding.get("stage", 0) == 3)
+                    search_cost_model.overlap_update = (
+                        self._update_sharding["enabled"]
+                        and bool(self.config.overlap_collectives))
 
-        self.executor = Executor(
-            g, self.mesh, self.config, self.loss_type, self.metrics,
-            self.optimizer, logits_node, self.label_spec,
-            update_sharding=self._update_sharding,
-        )
+        with telemetry.phase("compile.executor"):
+            self.executor = Executor(
+                g, self.mesh, self.config, self.loss_type, self.metrics,
+                self.optimizer, logits_node, self.label_spec,
+                update_sharding=self._update_sharding,
+            )
         # adopt the REALIZED record (the executor resolves the decision
         # into per-weight specs and may widen shards/axes beyond the dp
         # default, e.g. over `seq`): manifests, the strategy report, and
@@ -1337,23 +1347,26 @@ class FFModel:
         if self.config.spmd_barrier:
             from .analysis import spmd
 
-            with telemetry.span("compile.spmd_barrier"):
+            with telemetry.phase("compile.spmd_barrier"):
                 self._spmd_barrier = spmd.fingerprint_barrier(self)
             telemetry.event("spmd_barrier", **self._spmd_barrier)
-        self._rng = jax.random.key(self.config.seed)
-        self._params, self._state = self.executor.init_variables(
-            self._rng, self._shared_variables)
-        self._shared_variables = None
-        # optimizer slots inherit the (possibly update-sharded) param
-        # placement via zeros_like; place_update_sharded is the explicit
-        # guarantee (momentum-off scalar slots pass through untouched)
-        # fresh-init placement of just-built zeros at compile — not a
-        # plan transition, nothing pre-existing to verify a mapping for
-        self._opt_slots = self.executor.place_update_sharded(  # fflint: ok unverified_transition
-            self.executor.replicate(self.optimizer.init(self._params)))
-        self._state = self.executor.replicate(self._state) if self._state else self._state
-        self._step = self.executor.replicate(jnp.zeros((), jnp.int32))
-        self._counters = self.executor.replicate(self.metrics.zero_counters())
+        with telemetry.phase("compile.init"):
+            self._rng = jax.random.key(self.config.seed)
+            self._params, self._state = self.executor.init_variables(
+                self._rng, self._shared_variables)
+            self._shared_variables = None
+            # optimizer slots inherit the (possibly update-sharded) param
+            # placement via zeros_like; place_update_sharded is the explicit
+            # guarantee (momentum-off scalar slots pass through untouched)
+            # fresh-init placement of just-built zeros at compile — not a
+            # plan transition, nothing pre-existing to verify a mapping for
+            self._opt_slots = self.executor.place_update_sharded(  # fflint: ok unverified_transition
+                self.executor.replicate(self.optimizer.init(self._params)))
+            self._state = (self.executor.replicate(self._state)
+                           if self._state else self._state)
+            self._step = self.executor.replicate(jnp.zeros((), jnp.int32))
+            self._counters = self.executor.replicate(
+                self.metrics.zero_counters())
         # --- ffpulse goodput anchor: cost-model forward FLOPs summed over
         # the compiled graph (x3 for fwd+bwd, the standard training
         # estimate) against the aggregate peak of the mesh's chips (the
@@ -1380,7 +1393,6 @@ class FFModel:
                                * num_chips),
                 "num_chips": num_chips,
             }
-            telemetry.event("goodput_anchor", **self._goodput_anchor)
         self._compiled = True
 
     def _assign_strategy(self):
@@ -1893,7 +1905,7 @@ class FFModel:
                         f"this model's live progress (epoch {abs_epoch} < "
                         f"{self._epoch_base}) — ignored", stacklevel=2)
                 else:
-                    with telemetry.span("resume.restore", path=path):
+                    with telemetry.phase("resume.restore", path=path):
                         resil.restore_path(path)
                     start_epoch = abs_epoch - self._epoch_base
                     # the batch offset sticks to its ABSOLUTE epoch: when
@@ -1952,6 +1964,8 @@ class FFModel:
                 "fit", steps=max(0, epochs - start_epoch) * num_batches,
                 batch_size=batch_size)
             fit_span.__enter__()
+            # a program built again from here on is a recompile
+            telemetry.startup.steps_began()
             try:
                 for epoch in range(start_epoch, epochs):
                     abs_e = self._epoch_base + epoch

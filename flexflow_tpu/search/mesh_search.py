@@ -120,21 +120,12 @@ def search_mesh_shapes(
             # the reason, so an every-candidate failure (a search bug, not
             # an unshardable graph) surfaces with diagnostics
             skipped.append((dict(sizes), str(e)))
-            telemetry.event("mesh_candidate", shape=dict(sizes),
-                            skipped=str(e))
             continue
         t, mem = us.evaluate(choice)
         cost = us._memory_penalized(t, mem)
         results.append((dict(sizes), cost))
         if best is None or cost < best[4]:
             best = (dict(sizes), g, choice, us, cost)
-        # per-candidate record: cost + running best — the mesh-shape half
-        # of the best-cost-so-far curve
-        telemetry.event("mesh_candidate", shape=dict(sizes), cost_s=cost,
-                        best_cost_s=best[4], evals=us.evals,
-                        cache_hits=us.cache_hits)
-        telemetry.counter("mesh_search.best_cost_ms",
-                          {"cost": best[4] * 1e3})
     if best is None:
         detail = "; ".join(f"{s}: {r}" for s, r in skipped[:4])
         raise ValueError(

@@ -1486,7 +1486,6 @@ def best_first_search(
     pq: list = [(best_cost, next(counter), graph)]
     seen = {graph.hash()}
     pops = 0
-    evaluated = 0
     with telemetry.span("search.best_first", budget=budget):
         while pq and pops < budget:
             cost, _, g = heapq.heappop(pq)
@@ -1507,17 +1506,10 @@ def best_first_search(
                         nc, npayload = cost_fn(ng)
                     except ValueError:
                         continue
-                    evaluated += 1
                     if nc < best_cost:
                         best_g, best_cost, best_payload = ng, nc, npayload
-                        # best-cost-so-far curve across rewritten candidates
-                        telemetry.counter(
-                            "search.best_cost_ms",
-                            {"cost": best_cost * 1e3})
                     if nc < best_cost * alpha:
                         heapq.heappush(pq, (nc, next(counter), ng))
-    telemetry.event("search_candidates", candidates=evaluated, pops=pops,
-                    best_cost_s=best_cost)
     return best_g, best_cost, best_payload
 
 

@@ -581,8 +581,6 @@ class UnitySearch:
 
         with telemetry.span("unity.dp", nodes=len(self.order)):
             choice = self._run_dp()
-        telemetry.counter("unity.search_effort", {
-            "evals": self.evals, "cache_hits": self.cache_hits})
         return choice
 
     def _run_dp(self) -> dict:

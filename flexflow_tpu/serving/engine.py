@@ -211,16 +211,22 @@ class ServingEngine:
         self.telemetry = model._telemetry
         with self._active():
             t0 = time.perf_counter()
-            with telemetry.span("serve.compile", slots=spec.slots):
-                self.decode_model, self.max_seq_len = build_decode_model(
-                    model, spec)
-                self.adopted = adopt_params(self.decode_model, model)
-                self._step_fn = (
-                    self.decode_model.executor.build_decode_step())
-            # what the built graph's layers keep from token to token, as
-            # their ops declare it: sizes, groups and refusals read this
-            states = self._states = decode_states(self.decode_model)
-            at_rest = _at_rest(self.decode_model, states)
+            with telemetry.phase("serve.compile", slots=spec.slots):
+                with telemetry.phase("serve.graph"):
+                    self.decode_model, self.max_seq_len = (
+                        build_decode_model(model, spec))
+                with telemetry.phase("serve.adopt"):
+                    self.adopted = adopt_params(self.decode_model, model)
+                with telemetry.phase("serve.step_fn"):
+                    self._step_fn = (
+                        self.decode_model.executor.build_decode_step())
+                # what the built graph's layers keep from token to token,
+                # as their ops declare it: sizes, groups and refusals read
+                # this
+                states = self._states = decode_states(self.decode_model)
+                at_rest = _at_rest(self.decode_model, states)
+                with telemetry.phase("serve.pool"):
+                    self._build_pool(spec, states, at_rest)
             telemetry.event(
                 "serve.compile",
                 duration_s=time.perf_counter() - t0,
@@ -238,45 +244,6 @@ class ServingEngine:
             spec.slots, self.max_seq_len)
         self.num_chips = int(self.decode_model.mesh.devices.size)
         self._rng = None  # lazily split jax PRNG for sampling steps
-        # paged layout: host-side block manager + the donated COW copy
-        # executable; pool geometry comes from the BUILT op (resolve_
-        # pool_blocks ran inside build_decode_model)
-        self.block_manager = None
-        self._copy_fn = None
-        self._inject_fn = None  # lazily built KV-handoff landing pad
-        # the cache's groups (serving/paged.py), {layer: its declaration}
-        # each: the global one, and the pools of the layers that read the
-        # window group's table; both empty in the contiguous layout
-        self._groups = [{n: s for n, s in states.items()
-                         if s.blocks and bool(s.window) == windowed}
-                        for windowed in (False, True)]
-        self._window_nodes = list(self._groups[1])
-        # decoding slot-steps scheduled with the context inside the window
-        self._under_window = 0
-        # bytes one block holds over the layers of (the global group, the
-        # window group), as the pools are stored
-        self._block_bytes = tuple(
-            sum(s.bytes_of(BY_BLOCK) for s in group.values())
-            for group in self._groups)
-        # [(layer, its keys' pool, its values')] of the layers the KV
-        # handoff carries: the two leaves each declares by block
-        self._handoff_leaves = sorted(
-            (n, *s.names(BY_BLOCK)) for group in self._groups
-            for n, s in group.items() if HANDOFF not in s.cannot)
-        if spec.kv_layout == "paged":
-            windowed = list(self._groups[1].values())
-            s = next(iter((self._groups[0] or self._groups[1]).values()))
-            self.block_manager = BlockManager(
-                s.blocks, s.block_size, -(-self.max_seq_len // s.block_size),
-                sharing=spec.prefix_sharing,
-                cross_time=bool(spec.prefix_cache),
-                window_blocks=windowed[0].blocks if windowed else 0,
-                window=max((w.window for w in windowed), default=0),
-                window_span=spec.prefill_chunk)
-            self._build_copy_fns()
-        self._kv_itemsize = at_rest["kv_stored_itemsize"]
-        self._chunk_rows = self._rows_serve_chunks()
-        self._chunk_tiles: dict[int, Optional[int]] = {}
         # what a step's spans say of sparse latent attention
         # (docs/observability.md): the positions a row attends at the
         # most; and the expert layers, whose counts stats() reads
@@ -405,6 +372,49 @@ class ServingEngine:
         self.replan_decisions: list[dict] = []
         if getattr(cfg, "elastic", False):
             self.enable_autoscale()
+
+    def _build_pool(self, spec, states, at_rest):
+        """The cache's groups, the block manager over them and the
+        pools' copy programs (the `serve.pool` phase)."""
+        # paged layout: host-side block manager + the donated COW copy
+        # executable; pool geometry comes from the BUILT op (resolve_
+        # pool_blocks ran inside build_decode_model)
+        self.block_manager = None
+        self._copy_fn = None
+        self._inject_fn = None  # lazily built KV-handoff landing pad
+        # the cache's groups (serving/paged.py), {layer: its declaration}
+        # each: the global one, and the pools of the layers that read the
+        # window group's table; both empty in the contiguous layout
+        self._groups = [{n: s for n, s in states.items()
+                         if s.blocks and bool(s.window) == windowed}
+                        for windowed in (False, True)]
+        self._window_nodes = list(self._groups[1])
+        # decoding slot-steps scheduled with the context inside the window
+        self._under_window = 0
+        # bytes one block holds over the layers of (the global group, the
+        # window group), as the pools are stored
+        self._block_bytes = tuple(
+            sum(s.bytes_of(BY_BLOCK) for s in group.values())
+            for group in self._groups)
+        # [(layer, its keys' pool, its values')] of the layers the KV
+        # handoff carries: the two leaves each declares by block
+        self._handoff_leaves = sorted(
+            (n, *s.names(BY_BLOCK)) for group in self._groups
+            for n, s in group.items() if HANDOFF not in s.cannot)
+        if spec.kv_layout == "paged":
+            windowed = list(self._groups[1].values())
+            s = next(iter((self._groups[0] or self._groups[1]).values()))
+            self.block_manager = BlockManager(
+                s.blocks, s.block_size, -(-self.max_seq_len // s.block_size),
+                sharing=spec.prefix_sharing,
+                cross_time=bool(spec.prefix_cache),
+                window_blocks=windowed[0].blocks if windowed else 0,
+                window=max((w.window for w in windowed), default=0),
+                window_span=spec.prefill_chunk)
+            self._build_copy_fns()
+        self._kv_itemsize = at_rest["kv_stored_itemsize"]
+        self._chunk_rows = self._rows_serve_chunks()
+        self._chunk_tiles: dict[int, Optional[int]] = {}
 
     def _build_copy_fns(self):
         """The donated copy-on-write programs, one a cache group: block
@@ -700,6 +710,9 @@ class ServingEngine:
                 xs[self._token_input] = self._feed(
                     xs[self._token_input], self._sampled, step.from_sampled)
                 if self._rng is None:
+                    # the engine's first dispatch: a program built again
+                    # from here on is a recompile (telemetry/startup.py)
+                    telemetry.startup.steps_began()
                     self._rng = jax.random.key(dec.config.seed)
                 self._rng, sub = jax.random.split(self._rng)
             temp = np.zeros((self.spec.slots,), np.float32)

@@ -1,6 +1,6 @@
 """Run-wide observability: tracer spans, structured metrics, leveled logs.
 
-Three coordinated pieces (docs/observability.md):
+Four coordinated pieces (docs/observability.md):
 
 1. **Tracer** (tracer.py) — host-side span/counter/instant events dumped as
    Chrome trace-event JSON (Perfetto / chrome://tracing), plus an opt-in
@@ -17,6 +17,11 @@ Three coordinated pieces (docs/observability.md):
    program's spans sit on the host plane of that trace, on the device
    timeline's clock; with none running the annotation is inert (about
    half a microsecond).
+4. **The start-up record** (startup.py) — session-less and always on:
+   `phase` is `span` plus the same interval into one process-wide record
+   of the cold path, which also holds every program JAX traces, lowers
+   and builds. For call sites that run a bounded number of times a
+   process; a step's spans stay `span`.
 
 Enable with `--telemetry-dir DIR` (FFConfig), `model.enable_telemetry(DIR)`,
 or the keras `Telemetry` callback; read back via `model.get_telemetry()`.
@@ -24,11 +29,13 @@ or the keras `Telemetry` callback; read back via `model.get_telemetry()`.
 
 from __future__ import annotations
 
+import time
 from typing import Optional
 
 from jax.profiler import TraceAnnotation
 
 from . import log  # noqa: F401  (flexflow_tpu.telemetry.log)
+from . import startup  # the start-up record; registers its build listener
 from .metrics import MetricsRegistry  # noqa: F401  (re-export)
 from .recorder import MetricsRecorder, read_jsonl
 from .session import TelemetrySession
@@ -44,7 +51,7 @@ __all__ = [
     "Tracer", "MetricsRecorder", "MetricsRegistry", "TelemetrySession",
     "read_jsonl", "log",
     "activate", "deactivate", "active_session",
-    "span", "instant", "counter", "event",
+    "span", "phase", "startup", "instant", "counter", "event",
     "inc", "observe", "set_gauge",
 ]
 
@@ -127,6 +134,35 @@ def span(name: str, **args):
     if s is None:
         return annotation
     return _SessionSpan(annotation, s.tracer.span(name, **args))
+
+
+class _Phase:
+    """A span whose interval also goes into the start-up record."""
+
+    __slots__ = ("spanned", "name", "args", "t0")
+
+    def __init__(self, spanned, name, args):
+        self.spanned = spanned
+        self.name = name
+        self.args = args
+        self.t0 = 0.0
+
+    def __enter__(self):
+        self.t0 = time.perf_counter()
+        self.spanned.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.spanned.__exit__(exc_type, exc, tb)
+        startup.complete(self.name, self.t0, time.perf_counter(), self.args)
+        return False
+
+
+def phase(name: str, **args):
+    """`span`, and the same interval into the start-up record
+    (startup.py). Cold path only: compile, serve(), a restore."""
+    return _Phase(span(name, **args), name,
+                  {k: v for k, v in args.items() if isinstance(v, _SCALARS)})
 
 
 def instant(name: str, **args):

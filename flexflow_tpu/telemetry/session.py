@@ -4,6 +4,8 @@ Artifacts under `--telemetry-dir`:
 
     <dir>/trace.json      Chrome trace-event JSON (Perfetto / chrome://tracing)
     <dir>/metrics.jsonl   structured run metrics (recorder.py schema)
+    <dir>/startup_trace.json   the process's start-up record (startup.py),
+                          written when the session closes
 
 The session owns the step-time accounting (EMA, percentile summary,
 examples/sec) so the fit loop only reports raw timings. `flush()` rewrites
@@ -17,6 +19,7 @@ import os
 import time
 from typing import Optional
 
+from . import startup
 from .metrics import MetricsRegistry, merge_snapshots, percentile_from_hist
 from .recorder import MetricsRecorder, git_sha
 from .tracer import Tracer
@@ -255,6 +258,10 @@ class TelemetrySession:
                 if self._train_seconds > 0 else 0.0)
         if self._time_to_first_step is not None:
             fields["time_to_first_step_s"] = self._time_to_first_step
+        # where that time went: the start-up record's phases by self time
+        # and its three kinds of build (startup.py): the record the
+        # benchmark's `setup_*` metrics read
+        fields.update(startup.summary())
         dropped = self.tracer.dropped
         if dropped:
             # a capped trace is NOT a complete trace: say so in the summary
@@ -292,6 +299,7 @@ class TelemetrySession:
             except Exception:
                 pass
         self.flush()
+        startup.dump(os.path.join(self.directory, "startup_trace.json"))
         self.recorder.close()
         self._closed = True
 

@@ -52,10 +52,12 @@ class _Span:
 
 
 class Tracer:
-    def __init__(self, pid: int = 0, max_events: int = 500_000):
+    def __init__(self, pid: int = 0, max_events: int = 500_000,
+                 t0: Optional[float] = None):
         self.pid = int(pid)
         self.max_events = int(max_events)
-        self._t0 = time.perf_counter()
+        # the `perf_counter` instant that is the trace's zero
+        self._t0 = time.perf_counter() if t0 is None else float(t0)
         self._lock = threading.Lock()
         self._events: list[dict] = []
         self._dropped = 0
@@ -127,6 +129,18 @@ class Tracer:
             "ts": self._us(time.perf_counter()),
             "args": {k: float(v) for k, v in values.items()},
         })
+
+    # ------------------------------------------------------------ read
+
+    def intervals(self) -> list[tuple]:
+        """The completed spans as `(name, t0, t1, thread id, args)`, t0
+        and t1 on `perf_counter`, in the order they were recorded (a
+        span is recorded when it ends)."""
+        with self._lock:
+            events = [e for e in self._events if e["ph"] == "X"]
+        return [(e["name"], self._t0 + e["ts"] / 1e6,
+                 self._t0 + (e["ts"] + e["dur"]) / 1e6, e["tid"],
+                 e.get("args") or {}) for e in events]
 
     # ------------------------------------------------------------ dump
 

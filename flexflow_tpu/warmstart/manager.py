@@ -93,7 +93,7 @@ class WarmStartManager:
         """Load the calibration DB, run (miss-only) calibration, and
         compute this compile's full fingerprint. Returns the full
         fingerprint and stashes both on the manager."""
-        with telemetry.span("warmstart.calibration_load"):
+        with telemetry.phase("warmstart.calibration_load"):
             self.calibration_loaded = self.calibration_db.load_into(
                 cost_model)
         calibrate_fn()
@@ -132,7 +132,7 @@ class WarmStartManager:
 
         if self.full_fp is None or not is_coordinator():
             return
-        with telemetry.span("warmstart.store"):
+        with telemetry.phase("warmstart.store"):
             self.plan_cache.store(
                 self.full_fp, Strategy(overrides or {}).to_json(),
                 mesh_axes, structural_fingerprint=self.structural_fp or "",
@@ -231,7 +231,7 @@ def restore_plan(model, graph, cost_model, calibrate_fn):
     model._plan_fingerprint = sfp
 
     # 1) the interrupted run's own plan, recorded in its checkpoint
-    with telemetry.span("warmstart.plan_lookup", layer="checkpoint"):
+    with telemetry.phase("warmstart.plan_lookup", layer="checkpoint"):
         ck = _checkpoint_plan(model, sfp, graph)
     if ck is not None:
         overrides, mesh_axes = ck
@@ -252,7 +252,7 @@ def restore_plan(model, graph, cost_model, calibrate_fn):
     warm._cost_model = cost_model
     warm.prepare(graph, cost_model, calibrate_fn)
     stats = getattr(cost_model, "calib_stats", None) or {}
-    with telemetry.span("warmstart.plan_lookup", layer="cache"):
+    with telemetry.phase("warmstart.plan_lookup", layer="cache"):
         hit = warm.lookup_plan(graph)
     telemetry.counter("warmstart.calibration", {
         "loaded": warm.calibration_loaded,
