@@ -1452,8 +1452,8 @@ def flash_decode_attention(
 # — a trip count read from the scalar-prefetched lengths, so a page past the
 # slot's cursor is never a step and never a DMA. A round copies whole pool
 # rows (block_size, H·hd) — 64 KB contiguous at 16 x 2048 bf16 — for about
-# 128 cache rows of K and of V into one of two VMEM buffers, the next round
-# in flight while this one is computed, and takes ALL heads in one pass: a
+# 1 MiB of K and V into one of two VMEM buffers, the next round in flight
+# while this one is computed, and takes ALL heads in one pass: a
 # block-diagonal query (H, H·hd), row h holding head h's lanes, makes the
 # logits (H, rows) one matmul over the full row and p·V (H, H·hd) another;
 # each head keeps its own lanes of its row at the end. The MXU does H times
@@ -1464,13 +1464,31 @@ def flash_decode_attention(
 # past the cursor. c13b-serve-chat, 24 calls an iteration: 39.8 ms at 2 %
 # of the HBM roofline before, 1.5 ms at 51 % after (PERF.md section 6, PR 26).
 #
+# A round is sized by its bytes (_paged_round_pages): the fewest pages whose
+# K + V bytes reach _PAGED_ROUND_BYTES, whatever a row's width; under a
+# window no more than the pages the window spans, never more than the table
+# is wide or than two rounds of _PAGED_ROUND_VMEM hold. A round costs the
+# time HBM needs for its bytes and some 0.2 us besides that do not depend on
+# them (the wait on its semaphores before any compute can start, the start
+# of the next round's DMAs, the loop and its masks), so the share of the HBM
+# floor follows the bytes a round: 320 KiB (128 rows of MiMo-V2-Flash's
+# global layers, 2,560 B a row) 0.650 us against a floor of 0.400, 61 %;
+# 512 KiB (128 rows of 4,096 B) 0.889 against 0.640, 72 %; 1 MiB (256 such
+# rows) 1.506 against 1.280, 85 %, and 86.6 % in cmdap-serve-agentmix; 1 MiB
+# in 8 pages of 16 rows of 8,192 B is c13b-serve-chat's. Until PR 52 a round
+# was 128 rows whatever their width. MiMo's global shapes alone on a v5e at
+# 1 / 2 / 3 / 4 / 8 pages of 320 KiB a round: 0.650 / 0.484 / 0.457 / 0.458 /
+# 0.467 us for each 128 rows, 61.5 / 82.7 / 87.6 / 87.3 / 85.7 % of the
+# floor (scripts/paged_grouped_bench.py --pages; PERF.md section 6, PR 52):
+# past 1 MiB nothing is left to gain and a slot's dead tail pages grow.
+#
 # A row is a slot's one query. A prefill chunk's tokens can ride as rows of
 # this kernel too, each under a copy of its slot's table row, and did from
 # PR 28 to PR 33; every such row walks the slot's pages from page 0, so the
 # op now hands a chunk's rows to the multi-query kernel of the next section
 # and this one keeps them only where that kernel's gate refuses the bucket.
 
-_PAGED_ROUND_ROWS = 128  # cache rows a DMA round, whatever the block size
+_PAGED_ROUND_BYTES = 1 << 20  # K + V bytes a DMA round, whatever a row's
 # the two double-buffered K and V rounds may take this much of the 16 MiB
 # a Mosaic kernel gets by default; the rest is the compiler's temporaries
 _PAGED_ROUND_VMEM = 8 << 20
@@ -1744,8 +1762,20 @@ def _paged_decode_call(table, lengths, q, pool_k, pool_v, sink=None, *,
     )(table, lengths, q, *extra, pool_k, pool_v).reshape(slots, 1, -1)
 
 
-def _paged_round_pages(block_size: int) -> int:
-    return max(1, _PAGED_ROUND_ROWS // block_size)
+def _paged_round_pages(block_size: int, k_row_bytes: int, v_row_bytes: int,
+                       table_pages: int, window: int = 0) -> int:
+    """Pages a DMA round copies from pools of this geometry (the section
+    comment's rule): the fewest whose K + V bytes reach `_PAGED_ROUND_BYTES`,
+    at most what two rounds of `_PAGED_ROUND_VMEM` hold, the table's width
+    and, under a `window`, the pages it spans; at least one. The gates
+    reckon their VMEM from this answer and the calls size their buffers by
+    it."""
+    page_bytes = block_size * (k_row_bytes + v_row_bytes)
+    pages = min(-(-_PAGED_ROUND_BYTES // page_bytes),
+                _PAGED_ROUND_VMEM // (2 * page_bytes), table_pages)
+    if window:
+        pages = min(pages, window // block_size)
+    return max(1, pages)
 
 
 def paged_decode_gate(cache_rows: int, block_size: int, embed: int,
@@ -1773,7 +1803,9 @@ def paged_decode_gate(cache_rows: int, block_size: int, embed: int,
     if gate is None and block_size % 8 != 0:
         gate = f"block_size {block_size} % 8 != 0"
     if gate is None:
-        rows = _paged_round_pages(block_size) * block_size
+        rows = block_size * _paged_round_pages(
+            block_size, embed * itemsize, v_embed * itemsize,
+            cache_rows // block_size)
         round_bytes = 2 * rows * (embed + v_embed) * itemsize
         if round_bytes > _PAGED_ROUND_VMEM:
             gate = (f"two rounds of {rows} rows x {embed} + {v_embed} lanes "
@@ -1795,7 +1827,9 @@ def paged_flash_decode_attention(
     cannot tile): rows may share a table row and outnumber the slots. One
     grid step a row: the
     body walks the row's live pages through the scalar-prefetched table,
-    whole pool rows DMA'd from HBM a round of ~128 cache rows at a time,
+    whole pool rows DMA'd from HBM a round of about 1 MiB of K and V at a
+    time (as many pages as that takes at the pools' row widths: a round's
+    fixed cost is paid once a MiB, not once 128 rows; _paged_round_pages),
     all heads in one pass (see the section comment). `window` > 0: a row
     attends its nearest `window` keys and walks only the pages that hold
     them; `sink` (H,): one more logit a head in the softmax's denominator.
@@ -1828,7 +1862,10 @@ def paged_flash_decode_attention(
     return _paged_decode_call(
         page_table.astype(jnp.int32), lengths.astype(jnp.int32), q, pool_k,
         pool_v, sink, num_heads=num_heads, scale=scale,
-        pages=_paged_round_pages(bs), interpret=interpret, window=window)
+        pages=_paged_round_pages(
+            bs, pool_k.shape[-1] * pool_k.dtype.itemsize,
+            pool_v.shape[-1] * pool_v.dtype.itemsize, W, window),
+        interpret=interpret, window=window)
 
 
 def paged_chunk_attention_tiled(q, pool_k, pool_v, table_row, positions, *,
@@ -2219,6 +2256,16 @@ def _paged_chunk_tile(b: int, embed: int, kv_width: int, num_heads: int,
     return None
 
 
+def _paged_chunk_round_pages(block_size: int, kv_width: int,
+                             itemsize: int, table_pages: int) -> int:
+    """Pages a round of the chunk kernel copies: what the single-query
+    kernel's rule gives for the pools' whole rows, though a round here
+    holds one KV-head tile's lanes of them (untuned: at the shapes the
+    benchmark's cells run it answers what 128 rows a round did)."""
+    return _paged_round_pages(block_size, kv_width * itemsize,
+                              kv_width * itemsize, table_pages)
+
+
 def paged_chunk_gate(b: int, cache_rows: int, block_size: int, embed: int,
                      kv_width: int, num_heads: int, itemsize: int,
                      interpret: bool) -> str | None:
@@ -2230,8 +2277,9 @@ def paged_chunk_gate(b: int, cache_rows: int, block_size: int, embed: int,
                              itemsize, interpret)
     if gate is None and _paged_chunk_tile(
             b, embed, kv_width, num_heads,
-            _paged_round_pages(block_size) * block_size, itemsize,
-            interpret) is None:
+            block_size * _paged_chunk_round_pages(
+                block_size, kv_width, itemsize, cache_rows // block_size),
+            itemsize, interpret) is None:
         tq = _paged_chunk_query_tile(b, num_heads // kv_heads)[0]
         gate = (f"a query tile of {tq} rows x "
                 f"{embed // kv_heads} lanes and its rounds take more than "
@@ -2355,8 +2403,9 @@ def paged_flash_chunk_attention(
     return _paged_chunk_call(
         table_row.astype(jnp.int32), lengths.astype(jnp.int32), q[:, 0],
         pool_k, pool_v, num_heads=num_heads, scale=scale,
-        pages=_paged_round_pages(bs), interpret=interpret,
-        window=window)[:, None]
+        pages=_paged_chunk_round_pages(bs, pool_k.shape[-1],
+                                       pool_k.dtype.itemsize, W),
+        interpret=interpret, window=window)[:, None]
 
 
 def flash_attention(

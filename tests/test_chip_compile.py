@@ -661,23 +661,26 @@ def test_decode_head_dim_64_takes_the_reference_and_says_so(tpu, layout):
 
 def test_paged_decode_too_wide_for_vmem_takes_the_reference_and_says_so(tpu):
     """The paged kernel keeps two rounds of whole K and V pool rows in
-    VMEM. 64 heads of 128 in f32 is 16 MiB of them, all a Mosaic kernel
-    gets: that shape goes to the reference by a gate that names the
-    bytes, where the same row in bf16 (8 MiB) still takes the kernel."""
+    VMEM, and a round is as many pages as fit there (_paged_round_pages):
+    64 heads of 128 in blocks of 16 take the kernel in bf16 and, a page a
+    round, in f32. One page is the least a round can be: in blocks of 128
+    two such pages in f32 are 16 MiB, all a Mosaic kernel gets, and that
+    shape goes to the reference by a gate that names the bytes."""
     s = _on(tpu[0])
     slots, heads, e = 4, 64, 64 * 128
     fn = lambda q, pk, pv, tbl, n: fa.paged_flash_decode_attention(  # noqa: E731
         q, pk, pv, tbl, n, num_heads=heads)
 
-    def args(dtype):
-        pool = s((slots * 8 + 1, 16, e), dtype)
+    def args(dtype, bs=16):
+        pool = s((slots * 8 + 1, bs, e), dtype)
         return (s((slots, 1, e), dtype), pool, pool,
                 s((slots, 8), jnp.int32), s((slots,), jnp.int32))
 
-    assert _kernels(fn, *args(jnp.bfloat16)) == {
-        "flash_attention_paged_decode": 1}
+    for dtype in (jnp.bfloat16, jnp.float32):
+        assert _kernels(fn, *args(dtype)) == {
+            "flash_attention_paged_decode": 1}
     with pytest.warns(KernelFallbackWarning, match=r"bytes of VMEM"):
-        kernels = _kernels(fn, *args(jnp.float32))
+        kernels = _kernels(fn, *args(jnp.float32, 128))
     assert not kernels
 
 

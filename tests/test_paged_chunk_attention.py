@@ -94,6 +94,17 @@ def _calls(fn, *args) -> dict:
     return found
 
 
+@pytest.fixture
+def rounds_of_128_rows(monkeypatch):
+    """The cases below are laid out around rounds of 128 rows (a chunk that
+    crosses a round, a window whose walk leaves round 0 out), which is what
+    the rule of bytes gives at c13b-serve-chat's rows, 8 pages of 16. At
+    these tests' narrow rows it answers the whole table, one round (the
+    tests that do not ask for this fixture run so)."""
+    monkeypatch.setattr(fa, "_paged_round_pages",
+                        lambda block_size, *a, **kw: max(1, 128 // block_size))
+
+
 # a chunk of 32 at 200 over 16 blocks of 16 (rounds of 128 rows), under
 # each kind of window: shorter than `start` (the walk leaves out round 0),
 # longer than the whole context, and beginning inside the first round
@@ -124,6 +135,7 @@ _CASES += [(32, 30, 200, 16, 16, 8, 2, 64, 40),     # group 4, round 0 left
 _IDS += ["window-short-group4", "window-two-query-tiles-group8"]
 
 
+@pytest.mark.usefixtures("rounds_of_128_rows")
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("b,n,start,bs,W,H,KV,hd,window", _CASES, ids=_IDS)
 def test_chunk_kernel_matches_the_oracle_and_the_single_query_kernel(
@@ -151,6 +163,29 @@ def test_chunk_kernel_matches_the_oracle_and_the_single_query_kernel(
     assert not out[~live].any()
 
 
+@pytest.mark.parametrize("b,n,start,bs,W,H,KV,hd,window,chunk,single", [
+    (32, 32, 120, 16, 16, 2, 2, 128, 0, 16, 16),  # 4,096 B a row: 16 pages
+    (32, 30, 200, 16, 16, 16, 1, 64, 40, 16, 2),  # a window of 2 pages
+    (32, 20, 100, 16, 24, 8, 2, 64, 0, 24, 24),   # narrow rows: the table
+])
+def test_chunk_kernel_under_rounds_of_the_rules_own_size(
+        b, n, start, bs, W, H, KV, hd, window, chunk, single):
+    """The same three ways where a round is what the rule of bytes gives
+    each kernel (`chunk`, `single`: its pages): the chunk kernel the rows'
+    whole bytes, the single-query kernel no more than its window's pages."""
+    case = _chunk_case(b, n, start, bs, W, H, KV, hd, "float32",
+                       window=window)
+    assert fa._paged_chunk_round_pages(bs, KV * hd, 4, W) == chunk
+    assert fa._paged_round_pages(bs, KV * hd * 4, KV * hd * 4, W,
+                                 window) == single
+    out, one_by_one, ref = _three_ways(*case, H, KV, window)
+    live = np.asarray(case[-1]) > 0
+    np.testing.assert_allclose(out[live], ref[live], rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(out, one_by_one, rtol=2e-5, atol=2e-5)
+    assert not out[~live].any()
+
+
+@pytest.mark.usefixtures("rounds_of_128_rows")
 def test_a_window_walk_starts_at_each_query_tiles_own_round():
     """The lowest key a query tile attends is its own: of two tiles of a
     chunk of 200 at 140 under a window of 100, over rounds of 128 rows,
@@ -184,6 +219,7 @@ def test_lengths_need_not_be_consecutive():
     np.testing.assert_allclose(out, single, rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.usefixtures("rounds_of_128_rows")
 def test_a_split_head_tile_reads_its_own_lanes(monkeypatch):
     """Where all the KV heads' buffers do not fit, a grid step takes some
     of them and the query heads that read them, from its own lanes of the

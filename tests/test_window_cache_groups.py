@@ -225,25 +225,84 @@ def pools(kv, dk, dv, blocks, bs, dtype=jnp.float32):
             jnp.asarray(RNG.normal(size=(blocks, bs, kv * dv)), dtype))
 
 
-@pytest.mark.parametrize("heads, kv, dk, dv, window, sink", [
-    (8, 4, 192, 128, 0, False),     # a global layer's widths, KV heads cut
-    (8, 4, 192, 128, 128, True),    # a window layer's
-    (8, 4, 192, 128, 128, False),
-    (8, 4, 192, 128, 0, True),
-    (8, 2, 24, 16, 20, True),       # the test model's
-    (4, 4, 16, 16, 20, True),       # one head size, no groups
-    (4, 2, 128, 128, 0, False),     # what the kernel was: grouped
-    (4, 4, 128, 128, 0, False),     # and not
+@pytest.mark.parametrize("block_size, k_row, v_row, width, window, pages", [
+    (16, 4096, 4096, 40, 0, 8),        # c13b-serve-chat: 16 heads of 128
+    (256, 2048, 2048, 131, 0, 1),      # cmdap-serve-agentmix, global
+    (256, 2048, 2048, 131, 4096, 1),   # and window: 1 MiB a block
+    (256, 2048, 2048, 66, 0, 1),       # solar2-serve-reason's softmax layer
+    (128, 1536, 1024, 262, 0, 4),      # mimo2f-serve-longdoc, global
+    (128, 3072, 2048, 262, 128, 1),    # and window: the window's one page,
+    (128, 3072, 2048, 262, 0, 2),      # where its bytes alone would ask two
+    (128, 2048, 2048, 262, 0, 2),      # cmdap's rows on blocks of 128
+    (16, 48 * 4, 32 * 4, 12, 0, 12),   # never more than the table is wide
+    (128, 1 << 15, 1 << 15, 8, 0, 1),  # a page past the target: one
+])
+def test_a_round_is_sized_by_its_bytes(block_size, k_row, v_row, width,
+                                       window, pages):
+    """About `_PAGED_ROUND_BYTES` of K + V a round, whatever a row's width
+    (rows are bytes here: lanes x itemsize)."""
+    assert fa._paged_round_pages(block_size, k_row, v_row, width,
+                                 window) == pages
+
+
+def test_two_rounds_fit_the_kernels_vmem(monkeypatch):
+    """The rule never answers more pages than two double-buffered rounds
+    of `_PAGED_ROUND_VMEM` hold, and the gate reckons from the same answer:
+    it refuses only a page that does not fit alone."""
+    mimo = (128, 1536, 1024, 262)
+    assert fa._paged_round_pages(*mimo) == 4
+    monkeypatch.setattr(fa, "_PAGED_ROUND_VMEM", 2 << 20)
+    assert fa._paged_round_pages(*mimo) == 3     # 2 x 3 x 320 KiB
+    assert fa.paged_decode_gate(33536, 128, 768, 4, 2, False, 512) is None
+    monkeypatch.setattr(fa, "_PAGED_ROUND_VMEM", 512 << 10)
+    assert fa._paged_round_pages(*mimo) == 1
+    gate = fa.paged_decode_gate(33536, 128, 768, 4, 2, False, 512)
+    assert "two rounds of 128 rows" in gate and "VMEM" in gate
+
+
+# a table's width and its rows' lengths, by block size. Blocks of 16: an
+# empty slot, one key, inside the first page, several pages, full. Blocks
+# of 128 under rounds of 2 and of 4 pages, a table of 9: lengths that end
+# in each page of rounds [4, 8) and [4, 6), [6, 8), and a full table, whose
+# last round holds page 8 and re-reads it for the pages past the table
+GEOMETRY = {16: (12, [0, 1, 37, 150, 192]),
+            128: (9, [0, 1, 515, 740, 832, 1024, 1152])}
+
+
+@pytest.mark.parametrize("heads, kv, dk, dv, window, sink, bs, pages", [
+    (8, 4, 192, 128, 0, False, 16, 12),   # a global layer's widths, KV heads
+    (8, 4, 192, 128, 128, True, 16, 8),   # cut; a window layer's
+    (8, 4, 192, 128, 128, False, 16, 8),
+    (8, 4, 192, 128, 0, True, 16, 12),
+    (8, 2, 24, 16, 20, True, 16, 1),      # the test model's
+    (4, 4, 16, 16, 20, True, 16, 1),      # one head size, no groups
+    (4, 2, 128, 128, 0, False, 16, 12),   # what the kernel was: grouped
+    (4, 4, 128, 128, 0, False, 16, 12),   # and not
+    # rounds of several blocks of 128: a row of 5,120 B (MiMo's window
+    # layer's, in float32) is 2 pages a round, one of 2,560 B 4
+    (8, 4, 192, 128, 0, False, 128, 2),
+    (8, 4, 192, 128, 0, True, 128, 2),
+    (8, 2, 192, 128, 0, False, 128, 4),
+    (4, 2, 128, 128, 0, False, 128, 4),
+    # a window whose first key lies in a round's second page: key 440 of
+    # the row of 740 under rounds of 256, key 232 of the row of 832 under
+    # rounds of 512; and one that spans fewer pages than the bytes ask
+    (8, 4, 192, 128, 300, True, 128, 2),
+    (8, 2, 192, 128, 600, True, 128, 4),
+    (8, 2, 192, 128, 300, False, 128, 2),
 ])
 def test_the_paged_decode_kernel_is_its_einsum_oracle(heads, kv, dk, dv,
-                                                      window, sink):
-    bs, W, slots = 16, 12, 5
+                                                      window, sink, bs,
+                                                      pages):
+    W, lengths = GEOMETRY[bs]
+    slots = len(lengths)
     pk, pv = pools(kv, dk, dv, slots * W + 1, bs)
+    assert fa._paged_round_pages(bs, kv * dk * 4, kv * dv * 4, W,
+                                 window) == pages
     q = jnp.asarray(RNG.normal(size=(slots, 1, heads * dk)), jnp.float32)
     table = jnp.asarray(
         1 + RNG.permutation(slots * W).reshape(slots, W), jnp.int32)
-    # an empty slot, one key, inside the first round, several rounds, full
-    lengths = jnp.asarray([0, 1, 37, 150, W * bs], jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
     bias = (jnp.asarray(RNG.normal(size=(heads,)) * 2, jnp.float32)
             if sink else None)
     got = fa.paged_flash_decode_attention(
