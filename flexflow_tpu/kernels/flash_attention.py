@@ -1307,7 +1307,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *refs,
 def decode_attention_reference(q, k, v, positions, *, num_heads: int,
                                scale: float | None = None,
                                num_kv_heads: int | None = None,
-                               window: int = 0, sink=None, key_pos=None):
+                               window: int = 0, sink=None, key_pos=None,
+                               return_lse: bool = False):
     """Reference einsum attention over a KV cache — the CPU serving path
     and the decode kernel's numerics oracle. q: (slots, q_len, H·hd) new
     queries, k/v: (slots, S, H·hd) cache (new rows already written),
@@ -1332,7 +1333,10 @@ def decode_attention_reference(q, k, v, positions, *, num_heads: int,
     nearest `window` keys only, its own among them; `sink` (H,): one more
     logit a head in the denominator, which gives no value. `key_pos`
     (slots, S): the cache rows' positions where they are not 0 .. S-1 (a
-    slice of the cache)."""
+    slice of the cache). `return_lse` (no sink): (output, lse (slots,
+    q_len, H) float32), a row's log-sum-exp over the keys it attends, with
+    which a caller joins sets of keys under one softmax; NEG_INF, and an
+    output of 0, where a row attends none."""
     slots, q_len, e = q.shape
     s_k = k.shape[1]
     h = num_heads
@@ -1365,6 +1369,15 @@ def decode_attention_reference(q, k, v, positions, *, num_heads: int,
     probs = softmax_with_sink(
         logits, None if sink is None else sink[None, :, None, None])
     out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(q.dtype), vh)
+    if return_lse:
+        if sink is not None:
+            raise NotImplementedError(
+                "decode_attention_reference(return_lse=True) takes no sink")
+        some = mask.any(axis=-1)
+        out = jnp.where(some[..., None], out, 0)
+        lse = jnp.where(some, jax.nn.logsumexp(logits, axis=-1), NEG_INF)
+        return (out.transpose(0, 2, 1, 3).reshape(slots, q_len, -1),
+                lse.transpose(0, 2, 1))
     return out.transpose(0, 2, 1, 3).reshape(slots, q_len, -1)
 
 
@@ -1517,7 +1530,7 @@ def _paged_round_copies(block_of, k_hbm, v_hbm, k_buf, v_buf, sem, c, buf,
 
 def _paged_decode_kernel(tbl_ref, len_ref, q_ref, *refs, scale: float,
                          group: int = 0, window: int = 0, sink: bool = False,
-                         head_dim: int = 0):
+                         head_dim: int = 0, lse: bool = False):
     """`group` > 0: grouped keys and values, `group` query heads reading
     one KV head. The pool's row is then kv_heads * head_dim wide and the
     queries come as (heads, head_dim): row h of the block-diagonal query
@@ -1539,12 +1552,16 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, *refs, scale: float,
     starts at the round that holds key `length - window` and earlier keys
     of that round are masked. `sink`: a (heads, 1) float32 input that
     joins the running maximum and sum before the first round and never
-    the accumulator. All static; at their defaults the body is what it
-    was."""
+    the accumulator. `lse`: a second output (1, heads, 128) float32, a
+    head's log-sum-exp of its scaled logits on every lane (NEG_INF where
+    the row attended nothing): what merges this call's softmax with
+    another's over other keys. All static; at their defaults the body is
+    what it was."""
     refs = list(refs)
     sink_ref = refs.pop(0) if sink else None
     spread_ref, own_ref = (refs.pop(0), refs.pop(0)) if head_dim else (None,
                                                                        None)
+    lse_ref = refs.pop(3) if lse else None
     k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, acc_ref = refs
     s = pl.program_id(0)
     length = len_ref[s]
@@ -1636,7 +1653,11 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, *refs, scale: float,
     else:
         carry = (jnp.full((heads, 1), -jnp.inf, jnp.float32),
                  jnp.zeros((heads, 1), jnp.float32))
-    _, l = jax.lax.fori_loop(c0, n_rounds, round_, carry)
+    m, l = jax.lax.fori_loop(c0, n_rounds, round_, carry)
+    if lse_ref is not None:
+        lse_ref[0] = jnp.broadcast_to(
+            jnp.where(l > 0.0, m + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF),
+            lse_ref.shape[1:])
     # length == 0 (empty slot) ⇒ no round ran, l == 0; the clamp keeps the
     # dead row finite without touching live rows, whose l >= exp(0) = 1
     out = jnp.where(own, acc_ref[...] / jnp.maximum(l, 1e-30), 0.0)
@@ -1652,13 +1673,15 @@ def paged_decode_attention_reference(q, pool_k, pool_v, page_table,
                                      positions, *, num_heads: int,
                                      scale: float | None = None,
                                      num_kv_heads: int | None = None,
-                                     window: int = 0, sink=None):
+                                     window: int = 0, sink=None,
+                                     return_lse: bool = False):
     """Einsum oracle for the paged decode kernel (and the CPU serving
     path, via ops/inc_attention.py): gather each slot's logical cache
     view from the pool through its page table, then run the contiguous
     reference. q: (slots, q_len, H·hd); pool_k/v: (num_blocks, bs, H·hd);
     page_table: (slots, W) int32; positions: (slots, q_len) int32 (query
-    row i attends logical rows [0, positions[s, i]]; negative = dead)."""
+    row i attends logical rows [0, positions[s, i]]; negative = dead).
+    `return_lse`: decode_attention_reference's."""
     slots = q.shape[0]
     W = page_table.shape[1]
     bs = pool_k.shape[1]
@@ -1667,7 +1690,8 @@ def paged_decode_attention_reference(q, pool_k, pool_v, page_table,
     return decode_attention_reference(q, kc, vc, positions,
                                       num_heads=num_heads, scale=scale,
                                       num_kv_heads=num_kv_heads,
-                                      window=window, sink=sink)
+                                      window=window, sink=sink,
+                                      return_lse=return_lse)
 
 
 def _spread_constants(num_heads: int, head_dim: int, kv_heads: int, dtype):
@@ -1687,10 +1711,10 @@ def _spread_constants(num_heads: int, head_dim: int, kv_heads: int, dtype):
 
 @functools.partial(
     jax.jit, static_argnames=("num_heads", "scale", "pages", "interpret",
-                              "window"))
+                              "window", "lse"))
 def _paged_decode_call(table, lengths, q, pool_k, pool_v, sink=None, *,
                        num_heads: int, scale: float, pages: int,
-                       interpret: bool, window: int = 0):
+                       interpret: bool, window: int = 0, lse: bool = False):
     """The kernel launch (shapes already gated). Jitted so that the memory
     space constraint, which has no eager form, also serves a caller outside
     jit; inside one it is traced inline. A pool row narrower than the
@@ -1733,12 +1757,19 @@ def _paged_decode_call(table, lengths, q, pool_k, pool_v, sink=None, *,
         static["window"] = window
     qspec = pl.BlockSpec((1,) + qshape[1:], lambda s, tbl, ln: (s, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pltpu.HBM)
+    out_specs = pl.BlockSpec((1,) + oshape[1:], lambda s, tbl, ln: (s, 0, 0))
+    out_shape = jax.ShapeDtypeStruct(oshape, q.dtype)
+    if lse:  # a head's log-sum-exp on every lane of a row of its own
+        static["lse"] = True
+        out_specs = [out_specs, pl.BlockSpec(
+            (1, num_heads, 128), lambda s, tbl, ln: (s, 0, 0))]
+        out_shape = [out_shape, jax.ShapeDtypeStruct(
+            (slots, num_heads, 128), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(slots,),
         in_specs=[qspec, *extra_specs, pool_spec, pool_spec],
-        out_specs=pl.BlockSpec((1,) + oshape[1:],
-                               lambda s, tbl, ln: (s, 0, 0)),
+        out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((2, pages * bs, e_kv), pool_k.dtype),
             pltpu.VMEM((2, pages * bs, e_v), pool_v.dtype),
@@ -1749,17 +1780,20 @@ def _paged_decode_call(table, lengths, q, pool_k, pool_v, sink=None, *,
     name = "flash_attention_paged_decode"
     if window:  # named apart, as `_grouped` is: a trace tells the kinds
         name += "_window"
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=scale, group=group,
                           **static),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(oshape, q.dtype),
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
-        name=name + ("_grouped" if group else ""),
-    )(table, lengths, q, *extra, pool_k, pool_v).reshape(slots, 1, -1)
+        name=name + ("_grouped" if group else "") + ("_lse" if lse else ""),
+    )(table, lengths, q, *extra, pool_k, pool_v)
+    if lse:
+        return out[0].reshape(slots, 1, -1), out[1][:, :, 0]
+    return out.reshape(slots, 1, -1)
 
 
 def _paged_round_pages(block_size: int, k_row_bytes: int, v_row_bytes: int,
@@ -1816,7 +1850,7 @@ def paged_decode_gate(cache_rows: int, block_size: int, embed: int,
 def paged_flash_decode_attention(
     q, pool_k, pool_v, page_table, lengths, *, num_heads: int,
     scale: float | None = None, num_kv_heads: int | None = None,
-    window: int = 0, sink=None,
+    window: int = 0, sink=None, return_lse: bool = False,
 ):
     """Single-query decode attention over a paged KV pool. q: (rows, 1,
     H·hd); pool_k: (num_blocks, block_size, KV·hd), pool_v: (num_blocks,
@@ -1833,9 +1867,12 @@ def paged_flash_decode_attention(
     all heads in one pass (see the section comment). `window` > 0: a row
     attends its nearest `window` keys and walks only the pages that hold
     them; `sink` (H,): one more logit a head in the softmax's denominator.
-    The output is (rows, 1, H·hd_v). Shapes the kernel
-    can't tile on hardware take the gather + einsum reference, with a
-    KernelFallbackWarning on a TPU (the CPU serving path routes there
+    The output is (rows, 1, H·hd_v). `return_lse` (one head count and
+    size, no window, no sink): (output, lse (rows, H) float32), a head's
+    log-sum-exp of its scaled logits, NEG_INF where `lengths` is 0; two
+    calls over two sets of keys merge into one softmax by it. Shapes the
+    kernel can't tile on hardware take the gather + einsum reference, with
+    a KernelFallbackWarning on a TPU (the CPU serving path routes there
     directly)."""
     _, q_len, e = q.shape
     if q_len != 1:
@@ -1848,6 +1885,11 @@ def paged_flash_decode_attention(
         scale = 1.0 / math.sqrt(e // num_heads)
     interpret = jax.default_backend() != "tpu"
     kv_heads = num_kv_heads or num_heads
+    if return_lse and (window or sink is not None or kv_heads != num_heads
+                       or pool_v.shape[-1] != e):
+        raise NotImplementedError(
+            "paged_flash_decode_attention(return_lse=True) takes one head "
+            "count and size, no window and no sink")
     gate = paged_decode_gate(W * bs, bs, pool_k.shape[-1], kv_heads,
                              pool_k.dtype.itemsize, interpret,
                              pool_v.shape[-1])
@@ -1855,17 +1897,18 @@ def paged_flash_decode_attention(
         warn_reference("paged_flash_decode_attention",
                        (q.shape, pool_k.shape), gate)
         positions = (lengths.astype(jnp.int32) - 1)[:, None]
-        return paged_decode_attention_reference(
+        got = paged_decode_attention_reference(
             q, pool_k, pool_v, page_table, positions,
             num_heads=num_heads, scale=scale, num_kv_heads=kv_heads,
-            window=window, sink=sink)
+            window=window, sink=sink, return_lse=return_lse)
+        return (got[0], got[1][:, 0]) if return_lse else got
     return _paged_decode_call(
         page_table.astype(jnp.int32), lengths.astype(jnp.int32), q, pool_k,
         pool_v, sink, num_heads=num_heads, scale=scale,
         pages=_paged_round_pages(
             bs, pool_k.shape[-1] * pool_k.dtype.itemsize,
             pool_v.shape[-1] * pool_v.dtype.itemsize, W, window),
-        interpret=interpret, window=window)
+        interpret=interpret, window=window, lse=return_lse)
 
 
 def paged_chunk_attention_tiled(q, pool_k, pool_v, table_row, positions, *,
@@ -2033,7 +2076,8 @@ def _paged_chunk_block_lanes(group: int, tq: int) -> int:
 
 
 def _paged_chunk_kernel(tbl_ref, max_ref, *refs, scale: float,
-                        head_dim: int, group: int, window: int = 0):
+                        head_dim: int, group: int, window: int = 0,
+                        lse: bool = False):
     """One (query tile, KV-head tile) of a chunk (section comment). q_ref /
     o_ref: (tile's rows, tile's query lanes); len_ref: (tile's rows, 1);
     max_ref: the largest length of each query tile; k_buf / v_buf: (2,
@@ -2045,12 +2089,17 @@ def _paged_chunk_kernel(tbl_ref, max_ref, *refs, scale: float,
     stacked), acc_ref (head_dim, stacked), and lens_t_ref (1, lanes a pass)
     the rows' lengths along the lanes. `window` > 0: a row attends its
     nearest `window` keys, and lo_ref (after max_ref) is the lowest key any
-    live row of each query tile attends."""
+    live row of each query tile attends. `lse` (group 1): a second output
+    (tile's query heads, tile's rows) float32, a row's log-sum-exp of its
+    scaled logits a head, as `_paged_decode_kernel`'s (at NEG_INF or under
+    where the row attended nothing)."""
     refs = iter(refs)
     lo_ref = next(refs) if window else None
     len_ref, q_ref, k_hbm, v_hbm = (next(refs) for _ in range(4))
     lens_t_ref = next(refs) if group > 1 else None
-    o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref, *qs_ref = refs
+    o_ref = next(refs)
+    lse_ref = next(refs) if lse else None
+    k_buf, v_buf, sem, m_ref, l_ref, acc_ref, *qs_ref = refs
     width = tbl_ref.shape[0]
     rows, kv_lanes = k_buf.shape[1:]
     b = q_ref.shape[0]
@@ -2203,6 +2252,9 @@ def _paged_chunk_kernel(tbl_ref, max_ref, *refs, scale: float,
                 alive,
                 acc_ref[:, sl] / jnp.maximum(l_ref[h], 1e-30)[:, None],
                 0.0).astype(o_ref.dtype)
+            if lse_ref is not None:
+                lse_ref[h] = m_ref[h] + jnp.log(
+                    jnp.maximum(l_ref[h], 1e-30))
         return
 
     per = max(1, 128 // b)  # heads a 128-lane tile of the stacked order
@@ -2289,10 +2341,10 @@ def paged_chunk_gate(b: int, cache_rows: int, block_size: int, embed: int,
 
 @functools.partial(
     jax.jit, static_argnames=("num_heads", "scale", "pages", "interpret",
-                              "window"))
+                              "window", "lse"))
 def _paged_chunk_call(table_row, lengths, q, pool_k, pool_v, *,
                       num_heads: int, scale: float, pages: int,
-                      interpret: bool, window: int = 0):
+                      interpret: bool, window: int = 0, lse: bool = False):
     """The kernel launch (shapes already gated), jitted for the memory
     space constraint as _paged_decode_call is. q: (b, H·hd)."""
     b, e = q.shape
@@ -2338,12 +2390,18 @@ def _paged_chunk_call(table_row, lengths, q, pool_k, pool_v, *,
         extra = [jnp.tile(by_tile[:, None], (1, 1, block // tq))]
         extra_specs = [pl.BlockSpec((None, 1, block),
                                     lambda i, j, *_: (i, 0, 0))]
+    out_specs, out_shape = qspec, jax.ShapeDtypeStruct(q.shape, q.dtype)
+    if lse:  # (heads, rows): a head's rows along the lanes, as m and l lie
+        out_specs = [qspec, pl.BlockSpec((heads, tq),
+                                         lambda i, j, *_: (j, i))]
+        out_shape = [out_shape, jax.ShapeDtypeStruct(
+            (num_heads, q.shape[0]), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(q_tiles, e_kv // (hk * d)),
         in_specs=[pl.BlockSpec((tq, 1), lambda i, j, *_: (i, 0)),
                   qspec, pool_spec, pool_spec, *extra_specs],
-        out_specs=qspec,
+        out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((2, rows, hk * d), pool_k.dtype),
             pltpu.VMEM((2, rows, hk * d), pool_v.dtype),
@@ -2356,22 +2414,26 @@ def _paged_chunk_call(table_row, lengths, q, pool_k, pool_v, *,
         name += "_window"
     out = pl.pallas_call(
         functools.partial(_paged_chunk_kernel, scale=scale, head_dim=d,
-                          group=group, window=window),
+                          group=group, window=window,
+                          **({"lse": True} if lse else {})),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
-        name=name + ("_grouped" if group > 1 else ""),
+        name=(name + ("_grouped" if group > 1 else "")
+              + ("_lse" if lse else "")),
     )(*prefetch, lengths[:, None], q, pool_k, pool_v, *extra)
+    if lse:
+        return out[0][:b], jnp.maximum(out[1].T[:b], NEG_INF)
     return out[:b]
 
 
 def paged_flash_chunk_attention(
     q, pool_k, pool_v, table_row, lengths, *, num_heads: int,
     scale: float | None = None, num_kv_heads: int | None = None,
-    window: int = 0,
+    window: int = 0, return_lse: bool = False,
 ):
     """Multi-query attention of ONE prefill chunk over a paged KV pool. q:
     (b, 1, H·hd), the chunk's rows; pool_k/v: (num_blocks, block_size,
@@ -2384,7 +2446,10 @@ def paged_flash_chunk_attention(
     on the same rows under b copies of the table row, which is what a
     shape the kernel cannot tile gets (paged_chunk_gate): never the
     reference where the single-query kernel would serve, since a row
-    through the reference gathers a whole logical cache."""
+    through the reference gathers a whole logical cache. The rows' lengths
+    need not follow one another (a row of length 0 attends nothing).
+    `return_lse` (as many KV heads as query heads, no window): (output,
+    lse (b, H) float32) as paged_flash_decode_attention's."""
     b, q_len, e = q.shape
     if q_len != 1:
         raise ValueError(
@@ -2393,19 +2458,26 @@ def paged_flash_chunk_attention(
     W = table_row.shape[0]
     if scale is None:
         scale = 1.0 / math.sqrt(e // num_heads)
+    if return_lse and (window or pool_k.shape[-1] != e):
+        raise NotImplementedError(
+            "paged_flash_chunk_attention(return_lse=True) takes as many KV "
+            "heads as query heads and no window")
     interpret = jax.default_backend() != "tpu"
     if paged_chunk_gate(b, W * bs, bs, e, pool_k.shape[-1], num_heads,
                         pool_k.dtype.itemsize, interpret) is not None:
         return paged_flash_decode_attention(
             q, pool_k, pool_v, jnp.broadcast_to(table_row, (b, W)), lengths,
             num_heads=num_heads, scale=scale, num_kv_heads=num_kv_heads,
-            window=window)
-    return _paged_chunk_call(
+            window=window, return_lse=return_lse)
+    out = _paged_chunk_call(
         table_row.astype(jnp.int32), lengths.astype(jnp.int32), q[:, 0],
         pool_k, pool_v, num_heads=num_heads, scale=scale,
         pages=_paged_chunk_round_pages(bs, pool_k.shape[-1],
                                        pool_k.dtype.itemsize, W),
-        interpret=interpret, window=window)[:, None]
+        interpret=interpret, window=window, lse=return_lse)
+    if return_lse:
+        return out[0][:, None], out[1]
+    return out[:, None]
 
 
 def flash_attention(

@@ -47,7 +47,7 @@ from .fftype import (
     PoolType,
     RegularizerMode,
 )
-from .initializer import Initializer
+from .initializer import Initializer, UniformInitializer
 from .layer import Layer
 from .loss import loss_value
 from .machine import AXIS_DATA, AXIS_MODEL, AXIS_PIPE, MachineView, build_mesh
@@ -311,6 +311,7 @@ class FFModel:
         bias_initializer: Optional[Initializer] = None,
         kernel_regularizer: RegularizerMode = RegularizerMode.REG_MODE_NONE,
         name: str = "",
+        float32_out: bool = False,
     ) -> Tensor:
         # tied to an embedding, the layer reads the table as it lies,
         # (out_dim, in_dim): ops/core.LinearParams.kernel_transposed
@@ -318,7 +319,8 @@ class FFModel:
         p = LinearParams(out_dim, use_bias, ActiMode(activation), data_type,
                          kernel_transposed=(
                              getattr(tied, "op_type", None)
-                             == OT.OP_EMBEDDING))
+                             == OT.OP_EMBEDDING),
+                         float32_out=float32_out)
         inits = {}
         if kernel_initializer is not None:
             inits["kernel"] = kernel_initializer
@@ -390,10 +392,15 @@ class FFModel:
                                data_type=input.dtype).outputs[0]
 
     def rms_norm(self, input: Tensor, eps: float = 1e-5,
-                 name: str = "") -> Tensor:
-        """RMSNorm over the last dim with a learned scale, no bias."""
-        return self._add_layer(OT.OP_RMSNORM, RMSNormParams(eps), [input],
-                               name, data_type=input.dtype).outputs[0]
+                 name: str = "", unit_offset: bool = False,
+                 narrow_out: bool = False) -> Tensor:
+        """RMSNorm over the last dim with a learned scale, no bias;
+        `unit_offset`: the scale is 1 + g with g learned from zeros;
+        `narrow_out`: the output in the compute dtype where the input is
+        wider (ops/core.RMSNormParams)."""
+        return self._add_layer(
+            OT.OP_RMSNORM, RMSNormParams(eps, unit_offset, narrow_out),
+            [input], name, data_type=input.dtype).outputs[0]
 
     def batch_matmul(
         self,
@@ -422,8 +429,10 @@ class FFModel:
         shared_op=None,
         kernel_initializer: Optional[Initializer] = None,
         name: str = "",
+        float32_out: bool = False,
     ) -> Tensor:
-        p = EmbeddingParams(num_entries, out_dim, AggrMode(aggr), dtype)
+        p = EmbeddingParams(num_entries, out_dim, AggrMode(aggr), dtype,
+                            float32_out)
         inits = {"kernel": kernel_initializer} if kernel_initializer else {}
         return self._add_layer(OT.OP_EMBEDDING, p, [input], name, inits,
                                dtype, shared_op=shared_op).outputs[0]
@@ -465,6 +474,7 @@ class FFModel:
         value_scale: float = 1.0,
         sink_initializer: Optional[Initializer] = None,
         rope_interleaved: bool = False,
+        summary_chunk: int = 0,
     ) -> Tensor:
         """`rope_theta` > 0 rotates q and k by the (batch, seq) int
         `positions`; `qk_norm` RMS-normalises the q and k projections
@@ -480,7 +490,10 @@ class FFModel:
         head to the softmax's denominator (drawn by `sink_initializer`);
         `value_scale` multiplies the values; `rope_interleaved` rotates
         lanes 2j and 2j + 1 as a pair where the default pairs j and
-        j + d / 2 (ops/attention.AttentionFrontEnd)."""
+        j + d / 2; `summary_chunk` > 0 aligns the `window` and has a row
+        attend, beside it, one learned summary of every `summary_chunk`
+        keys of the windows before (`phi` and `mu_k`, a vector a head, drawn
+        uniformly within head_dim^-0.5) (ops/attention.AttentionFrontEnd)."""
         if impl not in ("xla", "flash", "ring"):
             raise ValueError(
                 f"multihead_attention impl must be xla|flash|ring, got {impl!r}"
@@ -492,13 +505,17 @@ class FFModel:
                                   qk_norm, qk_norm_eps, num_kv_heads,
                                   head_dim, output_gate, index, v_head_dim,
                                   rope_dim, window, sink, value_scale,
-                                  rope_interleaved)
+                                  rope_interleaved, summary_chunk)
         p = MultiHeadAttentionParams(front, kdim, vdim, dropout, add_bias_kv,
                                      add_zero_attn, causal, impl)
         inits = ({} if kernel_initializer is None
                  else dict.fromkeys(front.matrices, kernel_initializer))
         if sink and sink_initializer is not None:
             inits["sink"] = sink_initializer
+        if summary_chunk:
+            r = front.head_dim ** -0.5
+            inits.update(dict.fromkeys(
+                ("phi", "mu_k"), UniformInitializer(min_val=-r, max_val=r)))
         inputs = [query, key, value]
         if positions is not None:
             inputs.append(positions)

@@ -20,6 +20,7 @@ from .transformer import (
     build_transformer_lm_pipelined,
     command_a_plus_lm_config,
     deepseek_v32_lm_config,
+    evabyte_lm_config,
     keye_vl2_lm_config,
     mimo_v2_flash_lm_config,
     mistral_small4_lm_config,

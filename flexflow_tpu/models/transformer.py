@@ -160,6 +160,17 @@ class TransformerLMConfig:
     # the embedding is drawn around this mean (a residual stream of zero
     # mean, which a random stack's is, cannot tell LayerNorm from RMSNorm)
     embedding_mean: float = 0.0
+    # EvaByte (`evabyte_lm_config`): `swa` may carry a `summary_chunk`
+    # (EVA attention: the window is then aligned, and a row attends one
+    # learned summary a chunk of the windows before it, `phi` and `mu_k`
+    # drawn uniformly within head_dim^-0.5); `norm_unit_offset`: an
+    # RMSNorm's scale is 1 + g, g from zeros; `fp32_residual`: the
+    # embedding's rows and every residual add are float32 under a narrower
+    # compute dtype, and a norm hands the matmuls that dtype;
+    # `fp32_logits`: the head's output is float32 likewise
+    norm_unit_offset: bool = False
+    fp32_residual: bool = False
+    fp32_logits: bool = False
 
     def layer_kind(self, i: int) -> str:
         return self.layer_pattern[i] if self.layer_pattern else self.attention
@@ -582,6 +593,59 @@ def command_a_plus_lm_config(config: dict, *, sequence_length: int,
         embedding_range=embedding_range, embedding_mean=embedding_mean)
 
 
+def evabyte_lm_config(config: dict, *, sequence_length: int,
+                      attention_impl: str = "xla") -> TransformerLMConfig:
+    """EvaByte from the keys of its published config.json (`model_type:
+    evabyte`; models/evabyte_reference.py writes the equations out and says
+    what the keys leave open): a byte vocabulary, every layer EVA attention
+    (`attention_class: eva`: an aligned window of `window_size` exact keys
+    beside one learned summary for every `chunk_size` keys of the windows
+    already closed, one softmax) over as many KV heads as query heads,
+    RoPE over the whole head, SwiGLU, RMSNorm scaled by 1 + g
+    (`norm_add_unit_offset`), the residual stream in float32
+    (`fp32_skip_add`) and float32 logits (`fp32_logits`), an untied head:
+    head 0 of the `num_pred_heads` prediction heads, the others read the
+    same final state and are not built. Every matrix and the embedding
+    N(0, `init_std`), `phi` and `mu_k` uniform within head_dim^-0.5."""
+    hidden, heads = config["hidden_size"], config["num_attention_heads"]
+    if config.get("num_chunks") is not None:
+        raise NotImplementedError(
+            f"evabyte_lm_config: num_chunks {config['num_chunks']!r} (a "
+            f"fixed number of chunks a window) is not built: chunks are "
+            f"chunk_size keys each")
+    if config["window_size"] % config["chunk_size"]:
+        raise NotImplementedError(
+            f"evabyte_lm_config: chunk_size {config['chunk_size']} does not "
+            f"divide window_size {config['window_size']}")
+    if config.get("rope_scaling") is not None:
+        raise NotImplementedError(
+            f"evabyte_lm_config: rope_scaling {config['rope_scaling']!r} is "
+            f"not built")
+    for key, built in (("attention_class", "eva"), ("attention_bias", False),
+                       ("num_key_value_heads", heads),
+                       ("hidden_act", "silu"),
+                       ("tie_word_embeddings", False)):
+        if config.get(key, built) != built:
+            raise NotImplementedError(
+                f"evabyte_lm_config builds {key} {built!r}, got "
+                f"{config[key]!r}")
+    return TransformerLMConfig(
+        vocab_size=config["vocab_size"], hidden_size=hidden,
+        num_heads=heads, num_layers=config["num_hidden_layers"],
+        sequence_length=sequence_length, attention_impl=attention_impl,
+        norm="rmsnorm", norm_eps=config["rms_norm_eps"],
+        norm_unit_offset=bool(config.get("norm_add_unit_offset", False)),
+        position="rope", rope_theta=float(config["rope_theta"]),
+        attention_bias=False,
+        layer_pattern=("swa",) * config["num_hidden_layers"],
+        swa=dict(window=config["window_size"],
+                 summary_chunk=config["chunk_size"]),
+        mlp="swiglu", intermediate_size=config["intermediate_size"],
+        fp32_residual=bool(config.get("fp32_skip_add", False)),
+        fp32_logits=bool(config.get("fp32_logits", False)),
+        initializer_range=float(config["init_std"]))
+
+
 def _norm_initializer(stddev: float, mean: float = 0.0):
     from ..initializer import NormInitializer
 
@@ -590,7 +654,9 @@ def _norm_initializer(stddev: float, mean: float = 0.0):
 
 def _lm_norm(ff, c: TransformerLMConfig, h, name: str):
     if c.norm == "rmsnorm":
-        return ff.rms_norm(h, c.norm_eps, name=name)
+        return ff.rms_norm(h, c.norm_eps, name=name,
+                           unit_offset=c.norm_unit_offset,
+                           narrow_out=c.fp32_residual)
     return ff.layer_norm(h, [2], eps=c.norm_eps, name=name,
                          bias=c.norm_bias)
 
@@ -674,7 +740,7 @@ def _lm_trunk(ff, c: TransformerLMConfig, h, pos, wte=None):
         return ff.dense(h, c.vocab_size, use_bias=False, name="lm_head",
                         shared_op=wte)
     return ff.dense(h, c.vocab_size, use_bias=False, name="lm_head",
-                    kernel_initializer=init)
+                    kernel_initializer=init, float32_out=c.fp32_logits)
 
 
 def build_transformer_lm(ff, config: TransformerLMConfig | None = None,
@@ -690,7 +756,8 @@ def build_transformer_lm(ff, config: TransformerLMConfig | None = None,
         tokens, c.vocab_size, c.hidden_size, name="wte",
         kernel_initializer=(None if not embedding_range else
                             _norm_initializer(embedding_range,
-                                              c.embedding_mean)))
+                                              c.embedding_mean)),
+        float32_out=c.fp32_residual)
     pos = ff.create_tensor((bs, c.sequence_length), DataType.DT_INT32,
                            name="positions")
     if c.position == "learned":  # rotary positions go to the attention ops

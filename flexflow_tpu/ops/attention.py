@@ -129,6 +129,16 @@ class AttentionFrontEnd:
     # `position_embedding_type: rope_gptj`) where the default pairs lanes j
     # and j + d / 2; the frequencies are the same, theta^(-2j/d)
     rope_interleaved: bool = False
+    # EVA attention (Zheng et al., arXiv:2302.04542, as EvaByte runs it;
+    # models/evabyte_reference.py writes the equations out): > 0 makes
+    # `window` ALIGNED (a row at t attends the exact keys from
+    # window * (t // window) on) and has it attend, under the same softmax,
+    # one learned summary for every `summary_chunk` keys of the windows
+    # already closed: softmax_m(k_m . phi)-weighted sums of a chunk's
+    # rotated keys (+ `mu_k`) and of its values, `phi` and `mu_k` a vector
+    # a head. A serving layer keeps the exact rows in the window group and
+    # the summaries, a row a chunk, in the global one (serving/paged.py)
+    summary_chunk: int = 0
 
     # the four every attention layer has; `wg` joins them under
     # `output_gate`, the indexer's three under `index` (`matrices`)
@@ -149,6 +159,16 @@ class AttentionFrontEnd:
                 "AttentionFrontEnd.index selects over a layer's whole past "
                 "with keys and values of one size: no window, sink or "
                 "v_head_size beside it")
+        if self.summary_chunk and (
+                not self.window or self.window % self.summary_chunk
+                or self.sink or self.index is not None
+                or self.v_head_size or self.kv_heads != self.num_heads):
+            raise ValueError(
+                f"AttentionFrontEnd.summary_chunk summarises the chunks of "
+                f"an aligned `window` it divides, over as many KV heads as "
+                f"query heads of one size, with no sink and no indexer; got "
+                f"chunk {self.summary_chunk}, window {self.window}, "
+                f"{self.kv_heads} KV heads under {self.num_heads}")
         if self.rope_dim % 2 or self.rope_dim > self.head_dim:
             raise ValueError(
                 f"AttentionFrontEnd.rope_dim is an even number of a head's "
@@ -173,9 +193,11 @@ class AttentionFrontEnd:
     @property
     def kind(self) -> str:
         """The prefix of the layer's trace scopes (docs/observability.md):
-        `gsa` under a learned selection, `swa` under a window, `gqa`
+        `gsa` under a learned selection, `eva` under an aligned window
+        beside chunk summaries, `swa` under a sliding window, `gqa`
         otherwise."""
-        return "gsa" if self.index else "swa" if self.window else "gqa"
+        return ("gsa" if self.index else "eva" if self.summary_chunk
+                else "swa" if self.window else "gqa")
 
     def scope(self, part: str):
         """The trace scope of a part of the layer, `qkv` or `out`, under
@@ -245,6 +267,14 @@ class AttentionFrontEnd:
         nor a block freed behind an advanced cursor to rewind to."""
         if self.index is not None:
             return SELECTION_CANNOT
+        if self.summary_chunk:
+            return dict.fromkeys(
+                (HANDOFF, REWIND, QUERIES),
+                "layers that attend an aligned window beside chunk "
+                "summaries ({layer}, ...): their exact rows are kept for the "
+                "current window only and a summary is written once its chunk "
+                "is whole, which is neither handed off, rolled back nor "
+                "scored by a multi-token call")
         if self.window:
             return dict.fromkeys(
                 (HANDOFF, REWIND),
@@ -284,6 +314,9 @@ class AttentionFrontEnd:
                    for b, n in (("bq", Q), ("bk", KV), ("bv", V), ("bo", E))]
         if self.sink:
             ws.append(WeightSpec("sink", (self.num_heads,), f, "zeros"))
+        if self.summary_chunk:
+            ws += [WeightSpec(name, (self.kv_heads, self.head_dim), f,
+                              "uniform") for name in ("phi", "mu_k")]
         if self.qk_norm:
             ws += [WeightSpec(g, (n,), f, "ones")
                    for g, n in zip(("q_norm", "k_norm"),
@@ -341,6 +374,45 @@ class AttentionFrontEnd:
         return jnp.concatenate(
             [turn(xh[..., :dr], angles), xh[..., dr:]],
             axis=-1).reshape(x.shape)
+
+    def summaries(self, weights, k, v):
+        """The summaries of whole chunks: k, v (.., summary_chunk, heads *
+        head_dim), a chunk's rotated keys and its values, give (ksum, vsum)
+        (.., heads * head_dim) in their dtype. The sums are taken in
+        float32 and rounded once."""
+        lead, heads = k.shape[:-1], (self.kv_heads, self.head_dim)
+        kf = k.astype(jnp.float32).reshape(lead + heads)
+        vf = v.astype(jnp.float32).reshape(lead + heads)
+        phi = weights["phi"].astype(jnp.float32)
+        a = jax.nn.softmax(jnp.einsum("...mhd,hd->...mh", kf, phi), axis=-2)
+        ksum = (jnp.einsum("...mh,...mhd->...hd", a, kf)
+                + weights["mu_k"].astype(jnp.float32))
+        vsum = jnp.einsum("...mh,...mhd->...hd", a, vf)
+        wide = lead[:-1] + (-1,)
+        return (ksum.reshape(wide).astype(k.dtype),
+                vsum.reshape(wide).astype(v.dtype))
+
+    def rows_attended(self, position: int) -> tuple:
+        """(exact rows, summary rows) a row at `position` attends under
+        `summary_chunk`."""
+        return (position % self.window + 1,
+                position // self.window * (self.window // self.summary_chunk))
+
+    def step_counts(self, positions) -> dict:
+        """What one such layer reads and writes for a step's decoding rows
+        at `positions` (DecodeState.step_counts): the exact and summary
+        rows they attend, the summaries their chunks complete, the rows
+        that open a window (the slot gives the closed one's blocks back)."""
+        k = self.kind
+        rows = [self.rows_attended(t) for t in positions]
+        return {
+            f"{k}_exact_rows": sum(r[0] for r in rows),
+            f"{k}_summary_rows": sum(r[1] for r in rows),
+            f"{k}_summaries_written": sum(
+                t % self.summary_chunk == self.summary_chunk - 1
+                for t in positions),
+            f"{k}_rollovers": sum(t > 0 and t % self.window == 0
+                                  for t in positions)}
 
     def index_inputs(self, ctx, weights, x, positions):
         """What the learned selection of x (batch, seq, hidden) at
@@ -529,11 +601,50 @@ def sdpa_xla(q, k, v, *, causal: bool, scale: float, mask=None,
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
+def eva_attention_xla(front: AttentionFrontEnd, weights, q, k, v,
+                      scale: float):
+    """The training-shaped core under `summary_chunk`, in XLA: q, k, v
+    (batch, seq, heads * head_dim), k rotated. The windows are a batch
+    dimension for the exact keys (causal inside a window); every row also
+    scores every chunk's summary, masked to the chunks of the windows
+    before its own; one softmax over both, float32."""
+    b, s, e = q.shape
+    H, W, C = front.num_heads, front.window, front.summary_chunk
+    pad = -s % W
+    if pad:
+        q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0))) for t in (q, k, v))
+    nw, nc = (s + pad) // W, (s + pad) // C
+    with jax.named_scope("eva.summarise"):
+        ksum, vsum = front.summaries(
+            weights, k.reshape(b, nc, C, e), v.reshape(b, nc, C, e))
+    with jax.named_scope(front.attend_scope):
+        qh, kh, vh = (t.reshape(b, nw, W, H, -1) for t in (q, k, v))
+        ksum, vsum = (t.reshape(b, nc, H, -1) for t in (ksum, vsum))
+        exact = jnp.einsum("bwqhd,bwkhd->bwhqk", qh, kh,
+                           preferred_element_type=jnp.float32) * scale
+        exact = jnp.where(jnp.tril(jnp.ones((W, W), bool)), exact, -1e30)
+        summ = jnp.einsum("bwqhd,bchd->bwhqc", qh, ksum,
+                          preferred_element_type=jnp.float32) * scale
+        closed = (jnp.arange(nc)[None] // (W // C)) < jnp.arange(nw)[:, None]
+        summ = jnp.where(closed[None, :, None, None], summ, -1e30)
+        probs = jax.nn.softmax(jnp.concatenate([exact, summ], axis=-1),
+                               axis=-1).astype(q.dtype)
+        out = (jnp.einsum("bwhqk,bwkhd->bwqhd", probs[..., :W], vh)
+               + jnp.einsum("bwhqc,bchd->bwqhd", probs[..., W:], vsum))
+    return out.reshape(b, s + pad, -1)[:, :s]
+
+
 def _mha_forward(p: MultiHeadAttentionParams, inputs, weights, state, ctx):
     front = p.front
     H = front.num_heads
     q, k, v = front.qkv(ctx, weights, *inputs)  # positions fourth, with RoPE
     scale = 1.0 / math.sqrt(front.head_dim)
+    if front.summary_chunk:
+        if not p.causal:
+            raise NotImplementedError(
+                "attention with chunk summaries is causal self-attention")
+        out = eva_attention_xla(front, weights, q, k, v, scale)
+        return [front.output(ctx, weights, out, inputs[0])], state
     group = H // front.kv_heads
     impl = p.impl
     mask = None
@@ -656,17 +767,24 @@ def _mha_decode_layer(layer, ctx):
             raise NotImplementedError(
                 f"{layer.name}: attention under a learned selection is "
                 f"served from the paged pool only (kv_layout='paged')")
+        if p.front.summary_chunk:
+            raise NotImplementedError(
+                f"{layer.name}: attention beside chunk summaries is served "
+                f"from the paged pool only (kv_layout='paged')")
         return (OT.OP_INC_MULTIHEAD_ATTENTION,
                 IncMultiHeadAttentionParams(p.front, ctx.max_seq,
                                             impl=ctx.impl,
                                             cache_dtype=ctx.at_rest),
                 ("positions",))
     windowed = bool(p.front.window)
+    both = bool(p.front.summary_chunk)  # leaves in both cache groups
     return (OT.OP_PAGED_INC_MULTIHEAD_ATTENTION,
             PagedIncMultiHeadAttentionParams(
                 p.front, ctx.max_seq, ctx.block_size,
-                ctx.window_blocks if windowed else ctx.blocks, impl=ctx.impl,
-                cache_dtype=ctx.at_rest, chunk_from=ctx.slots),
+                ctx.window_blocks if windowed and not both else ctx.blocks,
+                impl=ctx.impl, cache_dtype=ctx.at_rest, chunk_from=ctx.slots,
+                window_blocks=ctx.window_blocks if both else 0),
+            ("positions", "page_table", "page_table_w") if both else
             ("positions", "page_table_w" if windowed else "page_table"))
 
 

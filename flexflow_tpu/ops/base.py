@@ -93,6 +93,13 @@ class StateLeaf:
     index: str    # BY_BLOCK | BY_POSITION | BY_SLOT | LAST_CALL
     width: tuple  # numbers a token (by block) or a slot (the others) holds
     dtype: DataType
+    # a BY_BLOCK leaf's cache group (serving/paged.py): 0 the global one,
+    # 1 the window group, whose pool, table and block ids are its own
+    group: int = 0
+    # positions a row of a BY_BLOCK leaf stands for: logical block j still
+    # covers positions [j * block_size, (j + 1) * block_size), in
+    # block_size // every rows
+    every: int = 1
 
 
 @dataclass(frozen=True)
@@ -103,9 +110,15 @@ class DecodeState:
 
     leaves: tuple
     slots: int = 0       # slots the by-slot leaves hold; 0: a call's rows
-    blocks: int = 0      # blocks of the pool, the scratch block too
-    block_size: int = 0
-    window: int = 0      # > 0: reads the window group's table, this far back
+    # blocks of the global group's pool and of the window group's, the
+    # scratch block too: what the leaves of each group are allocated over
+    blocks: int = 0
+    window_blocks: int = 0
+    block_size: int = 0  # positions a block covers, whatever the group
+    window: int = 0      # > 0: the window group's leaves are read this far back
+    # the window is aligned: a row at t reads from window * (t // window)
+    # on, where the default (sliding) reads its nearest `window` rows
+    window_aligned: bool = False
     selected: int = 0    # positions a row attends at the most; 0: all
     # {what the state cannot follow: what is said of the first such layer,
     # which completes "cannot serve a graph with", {layer} its name}
@@ -115,22 +128,31 @@ class DecodeState:
     # kernel then takes, (mesh, itemsize, rows) -> int or None; None: never
     chunk_as_rows: Optional[Callable] = None
     chunk_query_tile: Optional[Callable] = None
+    # what one such layer reads and writes in a step, for the step's span
+    # and the engine's totals: (positions of the step's decoding rows) ->
+    # {counter: number}; None: nothing beyond what the engine counts
+    step_counts: Optional[Callable] = None
 
-    def names(self, *indexes) -> tuple:
-        return tuple(l.name for l in self.leaves if l.index in indexes)
+    def names(self, *indexes, group: Optional[int] = None) -> tuple:
+        """The leaves of those indexes, of one cache group where given."""
+        return tuple(l.name for l in self.leaves if l.index in indexes
+                     and group in (None, l.group))
 
     def weight_specs(self, rows: int) -> list:
         """The leaves as allocated, for a call of `rows` rows."""
         return [WeightSpec(
-            l.name, ((self.blocks, self.block_size) if l.index == BY_BLOCK
+            l.name, ((self.window_blocks if l.group else self.blocks,
+                      self.block_size // l.every) if l.index == BY_BLOCK
                      else (self.slots or rows,)) + l.width,
             l.dtype, "zeros", trainable=False) for l in self.leaves]
 
-    def bytes_of(self, index: str) -> int:
-        """Bytes one block (BY_BLOCK) or one slot (BY_SLOT) holds."""
-        rows = self.block_size if index == BY_BLOCK else 1
-        return sum(rows * math.prod(l.width) * size_of_datatype(l.dtype)
-                   for l in self.leaves if l.index == index)
+    def bytes_of(self, index: str, group: Optional[int] = None) -> int:
+        """Bytes one block (BY_BLOCK; of one cache group where given) or
+        one slot (BY_SLOT) holds."""
+        return sum((self.block_size // l.every if index == BY_BLOCK else 1)
+                   * math.prod(l.width) * size_of_datatype(l.dtype)
+                   for l in self.leaves
+                   if l.index == index and group in (None, l.group))
 
 
 @dataclass(frozen=True)

@@ -55,6 +55,9 @@ class LinearParams:
     # of an embedding's table, which a head tied to it reads as it lies
     # (FFModel.dense(shared_op=<the embedding>)); False = (in, out)
     kernel_transposed: bool = False
+    # the output stays in the matmul's float32 accumulator where the input
+    # is narrower (a head whose logits are float32 under bf16 weights)
+    float32_out: bool = False
 
 
 def _linear_infer(p: LinearParams, in_shapes):
@@ -81,7 +84,8 @@ def _linear_forward(p: LinearParams, inputs, weights, state, ctx):
             preferred_element_type=jnp.float32)
     else:
         y = jnp.dot(xm, km, preferred_element_type=jnp.float32)
-    y = y.astype(x.dtype)
+    if not p.float32_out:
+        y = y.astype(x.dtype)
     if p.use_bias:
         y = y + weights["bias"]
     return [apply_activation(y, p.activation)], state
@@ -340,23 +344,34 @@ register_op(OpDef(OT.OP_LAYERNORM, _ln_infer, _ln_forward, _ln_weights))
 @dataclass(frozen=True)
 class RMSNormParams:
     eps: float = 1e-5
+    # the learned `scale` is g of a scale of 1 + g, and starts at zeros
+    unit_offset: bool = False
+    # the output in the dtype the scale comes in (the compute dtype) where
+    # the input is wider: the norm of a float32 residual stream whose
+    # matmuls are bf16
+    narrow_out: bool = False
 
 
-def rms_norm(x, scale, eps: float):
+def rms_norm(x, scale, eps: float, out_dtype=None):
     """x * rsqrt(mean(x^2) + eps) * scale over the last dim: fp32
-    statistics and affine, one cast back to the activation dtype."""
+    statistics and affine, one cast back to the activation dtype (or to
+    `out_dtype`)."""
     xf = x.astype(jnp.float32)
     y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+    return (y * scale.astype(jnp.float32)).astype(out_dtype or x.dtype)
 
 
 def _rms_weights(p: RMSNormParams, in_shapes):
     return [WeightSpec("scale", (in_shapes[0][-1],), DataType.DT_FLOAT,
-                       "ones")]
+                       "zeros" if p.unit_offset else "ones")]
 
 
 def _rms_forward(p: RMSNormParams, inputs, weights, state, ctx):
-    return [rms_norm(inputs[0], weights["scale"], p.eps)], state
+    scale = weights["scale"]
+    return [rms_norm(
+        inputs[0],
+        scale.astype(jnp.float32) + 1.0 if p.unit_offset else scale, p.eps,
+        scale.dtype if p.narrow_out else None)], state
 
 
 register_op(OpDef(OT.OP_RMSNORM, _ln_infer, _rms_forward, _rms_weights))
@@ -454,6 +469,9 @@ class EmbeddingParams:
     out_channels: int
     aggr: AggrMode = AggrMode.AGGR_MODE_NONE
     data_type: DataType = DataType.DT_FLOAT
+    # the rows come out float32 whatever the table rests in: the start of
+    # a residual stream held in float32 under bf16 weights
+    float32_out: bool = False
 
 
 def _embedding_infer(p: EmbeddingParams, in_shapes):
@@ -482,6 +500,8 @@ def _embedding_forward(p: EmbeddingParams, inputs, weights, state, ctx):
         emb = jnp.sum(emb, axis=-2)
     elif p.aggr == AggrMode.AGGR_MODE_AVG:
         emb = jnp.mean(emb, axis=-2)
+    if p.float32_out:
+        emb = emb.astype(jnp.float32)
     return [emb], state
 
 

@@ -260,6 +260,11 @@ class PagedIncMultiHeadAttentionParams(FrontEndFields):
     # of the decode graph, serving/decode_graph.py sets it to the slots).
     # None: every row stands alone under its own table row
     chunk_from: int | None = None
+    # blocks of the window group's pool where the layer keeps leaves in
+    # both groups (a front end with `summary_chunk`: `num_blocks` is then
+    # the global group's); 0: all of its leaves are in one group, of
+    # `num_blocks` blocks
+    window_blocks: int = 0
 
     @property
     def blocks_per_slot(self) -> int:
@@ -345,7 +350,15 @@ def paged_chunk_query_tile(p: PagedIncMultiHeadAttentionParams, mesh,
 
 
 def _paged_mha_infer(p: PagedIncMultiHeadAttentionParams, in_shapes):
-    x, positions, page_table = in_shapes
+    x, positions, page_table = in_shapes[:3]
+    if p.front.summary_chunk and (x[1] != 1 or p.block_size
+                                  % p.front.summary_chunk
+                                  or p.front.window % p.block_size):
+        raise NotImplementedError(
+            f"paged attention beside chunk summaries takes single-query "
+            f"rows (rows, 1, hidden) over blocks that hold whole chunks of "
+            f"{p.front.summary_chunk} and divide the window of "
+            f"{p.front.window}, got q_len {x[1]}, block_size {p.block_size}")
     if p.selected and x[1] != 1:
         raise NotImplementedError(
             f"paged attention under a learned selection takes "
@@ -363,8 +376,18 @@ def _paged_mha_state(p: PagedIncMultiHeadAttentionParams) -> DecodeState:
     # the block pool: ONE tensor per layer shared by every slot (a block
     # mapped into N page tables is stored once — the prefix-sharing win),
     # so per-chip accounting counts it once, not per slot
-    leaves = [StateLeaf(name, BY_BLOCK, (width,), p.cache_dtype)
+    f = p.front
+    windowed = int(bool(f.window))
+    leaves = [StateLeaf(name, BY_BLOCK, (width,), p.cache_dtype,
+                        group=windowed)
               for name, width in p.cache_row_widths.items()]
+    if f.summary_chunk:
+        # a summary row for every `summary_chunk` positions, for the whole
+        # context: the global group's, beside the exact rows of the window
+        leaves += [StateLeaf(name, BY_BLOCK, (f.kv_width,), p.cache_dtype,
+                             every=f.summary_chunk)
+                   for name in ("pool_ksum", "pool_vsum")]
+    both = bool(f.summary_chunk)
     if p.selected:
         # the positions the slots' rows attended in the last call (-1
         # where a row had fewer), as ops/latent_attention.py keeps them
@@ -373,17 +396,25 @@ def _paged_mha_state(p: PagedIncMultiHeadAttentionParams) -> DecodeState:
     # attention under a learned selection takes a chunk as rows only: it
     # gathers the chunk's keys once for all of them
     return DecodeState(
-        tuple(leaves), slots=p.chunk_from or 0, blocks=p.num_blocks,
-        block_size=p.block_size, window=p.front.window, selected=p.selected,
+        tuple(leaves), slots=p.chunk_from or 0,
+        blocks=p.num_blocks if both or not windowed else 0,
+        window_blocks=(p.window_blocks if both
+                       else p.num_blocks if windowed else 0),
+        block_size=p.block_size, window=f.window, window_aligned=both,
+        selected=p.selected,
+        # a chunk rides as rows where a row is what the core takes (a
+        # learned selection, summaries) or where the paged kernel serves
         chunk_as_rows=lambda mesh, itemsize: bool(
-            p.selected or paged_rows_run_kernel(p, mesh, itemsize)),
-        chunk_query_tile=partial(paged_chunk_query_tile, p),
-        cannot=p.front.cannot_follow)
+            p.selected or both or paged_rows_run_kernel(p, mesh, itemsize)),
+        chunk_query_tile=(None if both
+                          else partial(paged_chunk_query_tile, p)),
+        step_counts=f.step_counts if both else None,
+        cannot=f.cannot_follow)
 
 
 def _paged_mha_forward(p: PagedIncMultiHeadAttentionParams, inputs, weights,
                        state, ctx):
-    x, positions, page_table = inputs
+    x, positions, page_table = inputs[:3]
     slots = x.shape[0]
     H = p.num_heads
     kv_heads = p.front.kv_heads
@@ -391,6 +422,9 @@ def _paged_mha_forward(p: PagedIncMultiHeadAttentionParams, inputs, weights,
     W = p.blocks_per_slot
     q, k, v = p.front.qkv(ctx, weights, x, x, x, positions)
     scale = 1.0 / math.sqrt(p.front.head_dim)
+    if p.front.summary_chunk:
+        return _paged_summary_forward(p, x, positions, page_table, inputs[3],
+                                      q, k, v, weights, ctx)
     if p.selected:
         return _paged_selected_forward(p, x, positions, page_table, q, k, v,
                                        weights, ctx)
@@ -536,6 +570,125 @@ def _paged_selected_forward(p: PagedIncMultiHeadAttentionParams, x,
     return [out], new_state
 
 
+def _paged_summary_forward(p: PagedIncMultiHeadAttentionParams, x,
+                           positions, table, table_w, q, k, v, weights, ctx):
+    """The paged op beside chunk summaries (`front.summary_chunk` > 0), on
+    single-query rows, a slot's or a prefill chunk's alike (each under its
+    own table rows): every row writes its exact k and v into the window
+    group's pool; a row that completes a chunk reads the chunk's rows back
+    (cached ones and this call's) and writes their summary into the global
+    group's pool, a row a chunk; then every row attends, under one softmax,
+    the exact rows from its window's start on and the summaries of the
+    windows before it. On a TPU the two sets are two calls of the paged
+    decode kernel (the window's pages under a table shifted to its start;
+    the summary pool as pages of block_size / chunk rows) merged by their
+    log-sum-exp, and a chunk's rows three calls of the chunk kernel merged
+    likewise; elsewhere the same two sets in jax.numpy, merged the same
+    way."""
+    f, bs = p.front, p.block_size
+    W, C = f.window, f.summary_chunk
+    rows = x.shape[0]
+    pos = positions[:, 0].astype(jnp.int32)
+    table, table_w = table.astype(jnp.int32), table_w.astype(jnp.int32)
+    live = (pos >= 0) & (pos < p.max_seq_len)
+    pos_c = jnp.where(live, pos, 0)
+    lb, off = (pos_c // bs)[:, None], jnp.where(live, pos_c % bs, 0)
+
+    def block_of(tbl, ok):  # dead rows write zeros into the scratch block
+        return jnp.where(ok, jnp.take_along_axis(tbl, lb, axis=1)[:, 0], 0)
+
+    pk, pv = weights["pool_k"], weights["pool_v"]
+    phys = block_of(table_w, live)
+    pk = pk.at[phys, off].set(
+        jnp.where(live[:, None], k[:, 0], 0.0).astype(pk.dtype))
+    pv = pv.at[phys, off].set(
+        jnp.where(live[:, None], v[:, 0], 0.0).astype(pv.dtype))
+
+    sk, sv = weights["pool_ksum"], weights["pool_vsum"]
+    with jax.named_scope("eva.summarise"):
+        # the chunk a row completes lies in one block (block_size % C == 0)
+        # and this call's rows of it are in the pool by now
+        done = live & (pos_c % C == C - 1)
+        first = jnp.where(done, off - (C - 1), 0)
+
+        def chunk_rows(pool):
+            return jax.vmap(lambda b, o: jax.lax.dynamic_slice(
+                pool, (b, o, 0), (1, C, pool.shape[-1]))[0])(phys, first)
+
+        ksum, vsum = f.summaries(weights, chunk_rows(pk), chunk_rows(pv))
+        gphys, goff = block_of(table, done), jnp.where(done, off // C, 0)
+        sk = sk.at[gphys, goff].set(
+            jnp.where(done[:, None], ksum, 0.0).astype(sk.dtype))
+        sv = sv.at[gphys, goff].set(
+            jnp.where(done[:, None], vsum, 0.0).astype(sv.dtype))
+
+    window = pos_c // W
+    n_exact = jnp.where(live, pos_c % W + 1, 0)
+    n_sum = jnp.where(live, window * (W // C), 0)
+    # the pages of a row's own window, as a table of their own
+    pages = (window * (W // bs))[:, None] + jnp.arange(W // bs)[None]
+    table_x = jnp.take_along_axis(
+        table_w, jnp.minimum(pages, table_w.shape[1] - 1), axis=1)
+    scale = 1.0 / math.sqrt(f.head_dim)
+    from ..kernels.flash_attention import (
+        paged_decode_attention_reference, paged_flash_chunk_attention,
+        paged_flash_decode_attention,
+    )
+
+    kernel = _use_decode_kernel("paged_inc_multihead_attention", p.impl,
+                                q.shape, ctx)
+    kw = dict(num_heads=p.num_heads, scale=scale)
+    exact, summed = (pk, pv), (sk, sv)
+
+    def rows_attend(pools, tables, counts, at):
+        """(output, lse (rows, heads)) of the rows `at` over the first
+        `counts` rows of their logical caches: the paged decode kernel, or
+        its jax.numpy form (the CPU path, and the kernel's oracle)."""
+        if kernel:
+            return paged_flash_decode_attention(
+                q[at], *pools, tables[at], counts[at], return_lse=True, **kw)
+        out, lse = paged_decode_attention_reference(
+            q[at], *pools, tables[at], counts[at][:, None] - 1,
+            return_lse=True, **kw)
+        return out, lse[:, 0]
+
+    def one_softmax(*sets):
+        """Sets of keys, each its (output, lse), as the one softmax over
+        all of them: a set's share of a row's head is exp(lse)."""
+        share = jax.nn.softmax(jnp.stack([lse for _, lse in sets]), axis=0)
+        share = jnp.repeat(share, f.head_dim, axis=-1)[:, :, None]
+        return sum(o.astype(jnp.float32) * part
+                   for (o, _), part in zip(sets, share)).astype(q.dtype)
+
+    # the slots' rows, each under its own table rows. Where the kernel
+    # serves, the rows past them are ONE chunk's under one table row a
+    # group (`chunk_from`) and go through the multi-query kernel, which
+    # reads their context once: the summaries, the window of the chunk's
+    # first row, and the next window (the rows of a chunk that straddles
+    # a boundary; no row otherwise, and a tile without a live row runs no
+    # round)
+    n = rows
+    if kernel and p.chunk_from is not None:
+        n = min(rows, p.chunk_from)
+    with jax.named_scope(f.attend_scope):
+        out = one_softmax(rows_attend(exact, table_x, n_exact, slice(n)),
+                          rows_attend(summed, table, n_sum, slice(n)))
+        if n < rows:
+            first = window[n]
+            sets = [paged_flash_chunk_attention(
+                q[n:], *summed, table[n], n_sum[n:], return_lse=True, **kw)]
+            for w in (first, first + 1):
+                at = jnp.minimum(w * (W // bs) + jnp.arange(W // bs),
+                                 table_w.shape[1] - 1)
+                sets.append(paged_flash_chunk_attention(
+                    q[n:], *exact, table_w[n][at],
+                    jnp.where(window[n:] == w, n_exact[n:], 0),
+                    return_lse=True, **kw))
+            out = jnp.concatenate([out, one_softmax(*sets)])
+    return [f.output(ctx, weights, out, x)], {
+        "pool_k": pk, "pool_v": pv, "pool_ksum": sk, "pool_vsum": sv}
+
+
 def _paged_mha_flops(p: PagedIncMultiHeadAttentionParams, in_shapes,
                      out_shapes):
     cached = p.blocks_per_slot * p.block_size
@@ -553,4 +706,5 @@ register_op(OpDef(OT.OP_PAGED_INC_MULTIHEAD_ATTENTION, _paged_mha_infer,
                   state=_paged_mha_state,
                   state_leaves=dict(pool_k=BY_BLOCK, pool_v=BY_BLOCK,
                                     pool_kv=BY_BLOCK, pool_i=BY_BLOCK,
+                                    pool_ksum=BY_BLOCK, pool_vsum=BY_BLOCK,
                                     sel_rows=LAST_CALL)))

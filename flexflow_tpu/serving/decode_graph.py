@@ -192,7 +192,8 @@ def resolve_pool_blocks(model, spec: ServingSpec, max_seq: int,
     if window:
         window_blocks = spec.kv_window_blocks or min(
             capacity, 2 * spec.slots * window_slot_blocks(
-                window, spec.prefill_chunk, bs) + 1)
+                window, spec.prefill_chunk, bs,
+                any(s.window_aligned for s in states)) + 1)
     if spec.kv_num_blocks:
         return spec.kv_num_blocks, window_blocks
     import jax.numpy as jnp
@@ -213,9 +214,8 @@ def resolve_pool_blocks(model, spec: ServingSpec, max_seq: int,
                   else w.dtype.itemsize)
         for ws in model._params.values() for w in ws.values())
 
-    block, block_w = (sum(s.bytes_of(BY_BLOCK) for s in states
-                          if bool(s.window) == group)
-                      for group in (False, True))
+    block, block_w = (sum(s.bytes_of(BY_BLOCK, group) for s in states)
+                      for group in (0, 1))
     if block <= 0:
         return capacity, window_blocks
     budget = (0.9 * hbm - weight_bytes - window_blocks * block_w

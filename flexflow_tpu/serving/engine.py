@@ -385,32 +385,41 @@ class ServingEngine:
         # the cache's groups (serving/paged.py), {layer: its declaration}
         # each: the global one, and the pools of the layers that read the
         # window group's table; both empty in the contiguous layout
+        # (a group is a fact of a leaf: a layer with leaves in both is in
+        # both)
         self._groups = [{n: s for n, s in states.items()
-                         if s.blocks and bool(s.window) == windowed}
-                        for windowed in (False, True)]
+                         if s.names(BY_BLOCK, group=g)} for g in (0, 1)]
         self._window_nodes = list(self._groups[1])
         # decoding slot-steps scheduled with the context inside the window
         self._under_window = 0
+        # what a layer says it reads and writes in a step beyond what the
+        # engine counts (DecodeState.step_counts; the layers that say so
+        # are alike), and the measured window's totals of it
+        self._step_counts = next(
+            (s.step_counts for s in states.values() if s.step_counts), None)
+        self._counted: dict = {}
         # bytes one block holds over the layers of (the global group, the
         # window group), as the pools are stored
         self._block_bytes = tuple(
-            sum(s.bytes_of(BY_BLOCK) for s in group.values())
-            for group in self._groups)
+            sum(s.bytes_of(BY_BLOCK, g) for s in group.values())
+            for g, group in enumerate(self._groups))
         # [(layer, its keys' pool, its values')] of the layers the KV
         # handoff carries: the two leaves each declares by block
         self._handoff_leaves = sorted(
-            (n, *s.names(BY_BLOCK)) for group in self._groups
-            for n, s in group.items() if HANDOFF not in s.cannot)
+            (n, *s.names(BY_BLOCK)) for n, s in states.items()
+            if s.names(BY_BLOCK) and HANDOFF not in s.cannot)
         if spec.kv_layout == "paged":
             windowed = list(self._groups[1].values())
             s = next(iter((self._groups[0] or self._groups[1]).values()))
             self.block_manager = BlockManager(
-                s.blocks, s.block_size, -(-self.max_seq_len // s.block_size),
+                s.blocks or s.window_blocks, s.block_size,
+                -(-self.max_seq_len // s.block_size),
                 sharing=spec.prefix_sharing,
                 cross_time=bool(spec.prefix_cache),
-                window_blocks=windowed[0].blocks if windowed else 0,
+                window_blocks=windowed[0].window_blocks if windowed else 0,
                 window=max((w.window for w in windowed), default=0),
-                window_span=spec.prefill_chunk)
+                window_span=spec.prefill_chunk,
+                window_aligned=any(w.window_aligned for w in windowed))
             self._build_copy_fns()
         self._kv_itemsize = at_rest["kv_stored_itemsize"]
         self._chunk_rows = self._rows_serve_chunks()
@@ -421,8 +430,8 @@ class ServingEngine:
         ids are a group's own (executor.build_block_copy)."""
         self._copy_fn, self._copy_fn_w = (
             self.decode_model.executor.build_block_copy(
-                {n: s.names(BY_BLOCK) for n, s in group.items()})
-            for group in self._groups)
+                {n: s.names(BY_BLOCK, group=g) for n, s in group.items()})
+            for g, group in enumerate(self._groups))
 
     def _rows_serve_chunks(self) -> bool:
         """Whether a step that carries a prefill chunk is laid out as
@@ -1241,7 +1250,8 @@ class ServingEngine:
             if self._window_nodes:
                 # rows a window layer's attention reads: a row's window,
                 # or its context where that is shorter
-                window = self.block_manager.window.window
+                w = self.block_manager.window
+                window = w.window
                 # decoding slots whose context is still inside the
                 # window: a window layer reads all of it, and the slot
                 # has given no block back yet
@@ -1249,8 +1259,9 @@ class ServingEngine:
                 self._under_window += under
                 load.update(
                     window_rows=int(
-                        sum(min(s.length + 1, window) for s in decoding)
-                        + (sum(min(t + 1, window)
+                        sum(s.length + 1 - w.first_row(s.length)
+                            for s in decoding)
+                        + (sum(t + 1 - w.first_row(t)
                                for t in range(start, start + n))
                            if pre is not None else 0)),
                     under_window=under,
@@ -1258,6 +1269,11 @@ class ServingEngine:
                     # blocks the steps before it gave back
                     window_blocks_freed=(
                         self.block_manager.stats.window_blocks_freed))
+            if self._step_counts:
+                counts = self._step_counts([s.length for s in decoding])
+                for name, value in counts.items():
+                    self._counted[name] = self._counted.get(name, 0) + value
+                load.update(counts)
             if self._sel_cap:
                 # a layer's indexer scores every cached row of every live
                 # row's context; its attention reads the selected ones.
@@ -1489,6 +1505,7 @@ class ServingEngine:
         self._row_steps = 0
         self._chunk_kernel_steps = 0
         self._under_window = 0
+        self._counted = {}
         self._state_resets = 0
         self._device_s = 0.0
         self._last_wall_s = 0.0
@@ -1646,6 +1663,7 @@ class ServingEngine:
                     "kv_window_pool_bytes":
                         w.blocks_held * self._block_bytes[1],
                 })
+        out.update(self._counted)
         if ttfts:
             out["ttft_p50_s"] = float(np.percentile(np.asarray(ttfts), 50))
             out["ttft_max_s"] = float(max(ttfts))

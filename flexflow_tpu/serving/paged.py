@@ -117,11 +117,17 @@ class PagedStats:
         return self.shared_tokens / self.prompt_tokens
 
 
-def window_slot_blocks(window: int, span: int, block_size: int) -> int:
+def window_slot_blocks(window: int, span: int, block_size: int,
+                       aligned: bool = False) -> int:
     """Blocks of the window group a slot may hold at once: those of the
     window before a step's first row and of its `span` rows, one more
     where a block boundary falls inside, one for a copy-on-write in
-    flight."""
+    flight. Under an `aligned` window: the whole window of a step's first
+    row, the blocks its `span` rows may take of the next one (their first
+    block begins with that window: no boundary falls inside), one for a
+    copy-on-write in flight."""
+    if aligned:
+        return -(-window // block_size) + -(-max(1, span) // block_size) + 1
     return -(-(window - 1 + max(1, span)) // block_size) + 2
 
 
@@ -133,15 +139,23 @@ class WindowGroup:
     as `window`."""
 
     def __init__(self, num_blocks: int, block_size: int, window: int,
-                 span: int, make_room):
+                 span: int, make_room, aligned: bool = False):
         if num_blocks < 2:
             raise ValueError(
                 f"need >= 2 window blocks (scratch + 1), got {num_blocks}")
+        if aligned and window % block_size:
+            raise ValueError(
+                f"an aligned window of {window} rows needs blocks that "
+                f"divide it, got block_size {block_size}")
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.window = int(window)
+        # aligned: a row at t reads from window * (t // window) on (the
+        # layers declare it, DecodeState.window_aligned); else its nearest
+        # `window` rows
+        self.aligned = bool(aligned)
         self.slot_blocks = window_slot_blocks(self.window, int(span),
-                                              self.block_size)
+                                              self.block_size, self.aligned)
         self._free = list(range(self.num_blocks - 1, 0, -1))
         self._refcount: dict[int, int] = {}   # live mappings + cache pins
         self._mapped: dict[int, int] = {}     # live mappings alone
@@ -164,9 +178,15 @@ class WindowGroup:
         """Blocks a live slot maps or the cache pins."""
         return len(self._refcount)
 
+    def first_row(self, position: int) -> int:
+        """The first row a row at `position` reads."""
+        if self.aligned:
+            return position // self.window * self.window
+        return max(position - self.window + 1, 0)
+
     def first_block(self, position: int) -> int:
         """The first logical block a row at `position` reads."""
-        return max(position - self.window + 1, 0) // self.block_size
+        return self.first_row(position) // self.block_size
 
     def table(self, slot: int, width: int) -> list[int]:
         t = self._tables.get(slot, [])
@@ -305,11 +325,12 @@ class BlockManager:
     def __init__(self, num_blocks: int, block_size: int, table_width: int,
                  sharing: bool = True, cross_time: bool = False,
                  window_blocks: int = 0, window: int = 0,
-                 window_span: int = 1):
+                 window_span: int = 1, window_aligned: bool = False):
         """`window_blocks` > 0: the graph has layers that attend a window
         of `window` keys; their rows live in a window group of that many
         blocks (module docstring), a step writing at most `window_span`
-        rows of a slot."""
+        rows of a slot. `window_aligned`: the window begins at a multiple
+        of `window` and does not slide (WindowGroup.aligned)."""
         if num_blocks < 2:
             raise ValueError(
                 f"need >= 2 blocks (scratch + 1 allocatable), got "
@@ -347,7 +368,8 @@ class BlockManager:
         self._wpin_tick: dict[int, int] = {}
         if window_blocks:
             self.window = WindowGroup(window_blocks, block_size, window,
-                                      window_span, self._drop_window_pin)
+                                      window_span, self._drop_window_pin,
+                                      window_aligned)
 
     # ------------------------------------------------------------ queries
 
@@ -559,16 +581,41 @@ class BlockManager:
         if w is None or not covered:
             return covered, blocks
         bs = self.block_size
+
+        def held(upto):
+            return upto > 0 and all(
+                blocks[lb] in self._wpins
+                for lb in range(w.first_block(upto), (upto - 1) // bs + 1))
+
         at = min(covered, len(prompt) - 1)
+        if w.aligned and not held(at) and blocks[-1] not in self._wpins:
+            # the extent ends inside a node that gave its window block up
+            # (a finished request's tail that begins as this prompt's
+            # does): a sibling that holds one and begins so too, the
+            # history's own end, is the end to continue from (an aligned
+            # window's histories pin their last blocks alone, so a block
+            # boundary further back is no end; a sliding window's match
+            # falls back to one, as it did)
+            covered, blocks = self._pinned_end(prompt, covered, blocks)
+            at = min(covered, len(prompt) - 1)
         for upto in (at, *range((at - 1) // bs * bs, 0, -bs)):
-            if upto > 0 and all(
-                    blocks[lb] in self._wpins
-                    for lb in range(w.first_block(upto),
-                                    (upto - 1) // bs + 1)):
+            if held(upto):
                 if upto == at:
                     return covered, blocks
                 return upto, blocks[:upto // bs]  # a block boundary
         return 0, []
+
+    def _pinned_end(self, prompt, covered: int, blocks: list):
+        """(covered, blocks) with the match's last node exchanged for the
+        sibling that holds a window block and shares the longest run with
+        the prompt there; as they were where there is none."""
+        lo = (len(blocks) - 1) * self.block_size
+        shared, block = self.cache.sibling(
+            blocks[-1], prompt[lo:lo + self.block_size],
+            self._wpins.__contains__)
+        if not shared:
+            return covered, blocks
+        return lo + shared, blocks[:-1] + [block]
 
     # ------------------------------------------------------------ intake
 
@@ -697,10 +744,16 @@ class BlockManager:
             # the window blocks the slot holds at its prompt's end go
             # beside the nodes of the same rows (new ones and incumbents
             # that had none): the extent is matchable where they are
+            # (under an aligned window only those a continuation of the
+            # whole prompt reads: a prompt that ends where a window does
+            # pins none)
             wtable = self.window.table(slot, self.table_width)
+            keep = (self.window.first_block(len(prompt))
+                    if self.window.aligned else 0)
             for lb, node in enumerate(self.cache.path(prompt)):
                 wblk = wtable[lb]
-                if (wblk != SCRATCH_BLOCK and node.block not in self._wpins
+                if (lb >= keep and wblk != SCRATCH_BLOCK
+                        and node.block not in self._wpins
                         and wblk not in self.window._pinned):
                     self._wpins[node.block] = wblk
                     self._wpin_tick[node.block] = node.last_used

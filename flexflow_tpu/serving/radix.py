@@ -128,6 +128,17 @@ class RadixPrefixCache:
             node = best
         return covered, blocks
 
+    def sibling(self, block: int, run, ok) -> tuple:
+        """(tokens shared, block) of the node beside the one that pins
+        `block` (itself among them) whose block `ok(block)` accepts and
+        whose run shares the longest start with `run`; (0, None) where no
+        accepted one shares a token."""
+        run = tuple(run)
+        return max(((_common_len(c.run, run), c.block)
+                    for c in self._pinned[block].parent.children
+                    if ok(c.block)),
+                   key=lambda found: found[0], default=(0, None))
+
     def path(self, prompt) -> list:
         """The nodes whose runs are exactly `prompt`'s blocks, in logical
         order, as far as the tree holds them: what `insert(prompt, ..)`

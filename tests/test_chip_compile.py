@@ -512,6 +512,63 @@ def test_paged_attention_at_command_a_plus_widths(tpu, kind, rows):
     assert not re.findall(rf"= bf16\[{blocks},256,\d+\]\S* copy\(", text)
 
 
+@pytest.mark.parametrize("rows", [16, 16 + 256])
+def test_paged_attention_at_evabyte_widths(tpu, rows):
+    """A layer of `evabyte-serve-bytedocs` as the decode graph runs it: 32
+    query heads of 128 on as many KV heads, RoPE over the whole head, an
+    aligned window of 2,048 exact keys beside a summary for every 16; the
+    exact rows in the window group's pool (232 blocks of 256 rows of 4,096),
+    the summaries in the global group's (1,200 blocks of 16 rows), page
+    tables 118 wide; 16 decoding rows, and the same with a chunk of 256
+    riding as rows: the slots' rows through two calls of the single-query
+    kernel (the window's pages, the summary pages) that hand their
+    log-sum-exp to one merge, the chunk's through three of the chunk kernel
+    (its first row's window, the next, the summaries) merged likewise. No
+    step copies a pool."""
+    import re
+
+    from flexflow_tpu.fftype import DataType, OperatorType as OT
+    from flexflow_tpu.ops import inc_attention as inc
+    from flexflow_tpu.ops.attention import AttentionFrontEnd
+    from flexflow_tpu.ops.base import OpContext, get_op_def
+
+    s = _on(tpu[0])
+    front = AttentionFrontEnd(4096, 32, use_bias=False, rope_theta=1e5,
+                              window=2048, summary_chunk=16)
+    p = inc.PagedIncMultiHeadAttentionParams(
+        front, 30208, 256, 1200, impl="flash",
+        cache_dtype=DataType.DT_BFLOAT16, chunk_from=16, window_blocks=232)
+    op = get_op_def(OT.OP_PAGED_INC_MULTIHEAD_ATTENTION)
+    state = op.state(p)
+    assert {l.name: (l.group, l.every) for l in state.leaves} == {
+        "pool_k": (1, 1), "pool_v": (1, 1), "pool_ksum": (0, 16),
+        "pool_vsum": (0, 16)}
+    assert (state.blocks, state.window_blocks, state.window_aligned) == (
+        1200, 232, True)
+
+    def layer(weights, x, positions, table, table_w):
+        (y,), new = op.forward(p, [x, positions, table, table_w], weights,
+                               None, OpContext(training=False, mesh=None))
+        return y, new
+
+    specs = op.weights(p, [(rows, 1, 4096), (rows, 1), (rows, 118),
+                           (rows, 118)])
+    weights = {w.name: s(w.shape, jnp.bfloat16) for w in specs}
+    assert weights["pool_k"].shape == (232, 256, 4096)
+    assert weights["pool_ksum"].shape == (1200, 16, 4096)
+    assert weights["phi"].shape == (32, 128)
+    compiled = jax.jit(layer, donate_argnums=(0,)).lower(
+        weights, s((rows, 1, 4096)), s((rows, 1), jnp.int32),
+        s((rows, 118), jnp.int32), s((rows, 118), jnp.int32)).compile()
+    text = compiled.as_text()
+    want = {"flash_attention_paged_decode_lse": 2}
+    if rows > 16:
+        want["flash_attention_paged_chunk_lse"] = 3
+    assert pallas_kernels(text) == want
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+    assert not re.findall(r"= bf16\[(232,256|1200,16),\d+\]\S* copy\(", text)
+
+
 @pytest.mark.parametrize("chunk", [128, 16])
 def test_paged_chunk_kernel_at_c13b_widths(tpu, chunk):
     """`c13b-serve-chat`'s chunk step as the decode graph runs a layer of

@@ -120,10 +120,17 @@ def test_what_is_declared_is_what_is_allocated_and_what_is_priced(
     monkeypatch.setattr(
         machine_model, "machine_model_for_mesh",
         lambda mesh, **kw: type("M", (), {"chip": Chip})())
-    group = {name: bool(s.window) for name, s in states.items() if s.blocks}
-    block = [per_lead(dec._state[n][leaf.name] for n, w in group.items()
-                      if w == g for leaf in states[n].leaves
-                      if leaf.index == BY_BLOCK) for g in (False, True)]
+    # the group is a fact of a leaf (a window layer's leaves are the window
+    # group's, of its declaration's `window_blocks` blocks)
+    block = [per_lead(dec._state[n][leaf.name] for n, s in states.items()
+                      for leaf in s.leaves
+                      if leaf.index == BY_BLOCK and leaf.group == g)
+             for g in (0, 1)]
+    for s in states.values():
+        for leaf in s.leaves:
+            if leaf.index == BY_BLOCK:
+                assert (s.window_blocks if leaf.group else s.blocks) > 0
+                assert bool(s.window) == bool(leaf.group)
     assert tuple(block) == eng._block_bytes
     slot = per_lead(dec._state[n][leaf.name] for n, s in states.items()
                     for leaf in s.leaves if leaf.index == BY_SLOT)
@@ -190,7 +197,8 @@ def test_a_mistake_in_a_declaration_is_not_priced_as_capacity_parity(
 
 def test_the_leaf_tuples_are_what_the_registered_ops_declare(served):
     pools = decode_graph.POOL_LEAVES
-    assert set(pools) == {"pool_k", "pool_v", "pool_kv", "pool_c", "pool_i"}
+    assert set(pools) == {"pool_k", "pool_v", "pool_kv", "pool_c", "pool_i",
+                          "pool_ksum", "pool_vsum"}
     assert set(decode_graph.KV_LEAVES) == {*pools, "cache_k", "cache_v"}
     assert len(set(pools)) == len(pools)
     with pytest.raises(AttributeError):
