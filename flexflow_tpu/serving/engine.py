@@ -24,7 +24,11 @@ which positions, which chunk rides along and which blocks are written are
 known when step n is dispatched (`_advance`: `length`, `prefill_pos`, the
 prefill -> decode turn, `register_prompt`, and whether the token in
 flight is the request's last by length); the token ids stay on the device
-(`_build_token_feed`). What needs them waits for the fetch (`_bookkeep`:
+(`_build_token_feed`). A step crosses to the device three times: one put
+of one packed array, one program (`feed`) that takes it apart in front of
+the step, and the token vector's copy back, started at the launch
+(`_stage_step`, `_packing`; docs/serving.md has the array's layout). What
+needs the token values waits for the fetch (`_bookkeep`:
 `generated`, EOS, the latency stamps, `decode_tokens`, `prefill_calls`,
 completion, block release). An end by EOS is learnt one step late: the
 row already dispatched for the request writes one cache row inside its
@@ -167,6 +171,35 @@ class _Step:
     sampled: object = None  # (rows,) tokens: the device's, or a host
     #                         step function's NumPy array
     at_once: bool = False   # nothing may be dispatched before its fetch
+    # `sampled_row` on the device, where the step's rows are not the
+    # slots (a chunk as rows): `_keep` files its samples by slot
+    filed: object = None
+
+
+@dataclasses.dataclass(frozen=True)
+class _Packing:
+    """How one step shape crosses to the device: where each host array
+    of the step lies in the ONE int32 array that is put, and the program
+    that takes it apart there (`ServingEngine._packing`)."""
+
+    fields: dict     # {name: (its words, a slice; its shape)}
+    size: int        # words
+    feed: object     # the jitted program in front of the step
+
+
+def _uncommitted(x):
+    """A one-device program's output as an array committed to no device,
+    the same buffer: what `jnp.asarray` of a host array gives. JAX
+    commits every output of a program that read one committed array, and
+    keys a jitted function's lowerings by which arguments are committed;
+    `read_idx`, the key and the temperatures reach the step uncommitted
+    wherever it is lowered ahead of its first call (the benchmark's jobs,
+    `_stage_inputs`' callers), so that is how the step loop hands them
+    over, or the step would be lowered and compiled a second time."""
+    from jax._src.array import ArrayImpl
+
+    return ArrayImpl(x.aval, x.sharding, x._arrays, committed=False,
+                     _skip_checks=True)
 
 
 class ServingEngine:
@@ -306,6 +339,10 @@ class ServingEngine:
         # run accounting (stats())
         self._steps = 0  # device steps dispatched
         self._steps_ahead = 0  # of those, while the one before was unfetched
+        # host-to-device puts and device programs issued to stage those
+        # steps, before each one's own launch
+        self._stage_puts = 0
+        self._stage_programs = 0
         self._rows_discarded = 0  # rows whose request had ended by EOS
         self._decode_iterations = 0
         self._decode_tokens = 0
@@ -669,69 +706,197 @@ class ServingEngine:
             return dec.executor.shard_batch(xs, specs)
 
     def _build_token_feed(self):
-        """The sampled tokens' way from one step to the next without the
-        host: `_sampled`, the last token sampled for each slot, on the
-        device; `_keep`, which files a step's samples into it by slot;
-        `_feed`, which writes them into the next step's token column
-        where the host does not hold them yet. Each program's shape is
-        that of one step (its rows), never of a pair of steps: it is
-        compiled when that shape's step program first runs."""
+        """What the step loop keeps on the device between steps, and the
+        programs beside the step (docs/serving.md, "One step in
+        flight"). `_sampled`: the tokens the step before sampled, by
+        slot, which is that step's own vector wherever its rows are the
+        slots (a step that only decodes, the rectangle); `_keep` files
+        by slot the samples of a chunk step laid out as rows, whose
+        vector is the step's own length, so that the program in front of
+        the NEXT step has that step's shape and never a pair's.
+        `_packings`: a step shape's packed array and its `feed` program
+        (`_packing`), compiled when that shape's step first runs."""
         import jax
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec
 
+        mesh = self.decode_model.executor.mesh
+        whole = NamedSharding(mesh, PartitionSpec())
+
+        def keep(step_sampled, row):
+            return step_sampled[jnp.maximum(row, 0)]
+
+        self._keep = jax.jit(keep, out_shardings=whole)
+        self._sampled = jax.device_put(
+            np.zeros((self.spec.slots,), np.int32), whole)
+        self._packings: dict[tuple, _Packing] = {}
+        if self._rng is not None:
+            # a new mesh: the key's chain goes on from the host's copy
+            self._rng = jax.device_put(jax.random.wrap_key_data(
+                np.asarray(jax.random.key_data(self._rng))), whole)
+
+    def _packing(self, rows: int, q: int) -> _Packing:
+        """The packed array's layout for a step of `rows` x `q` and the
+        program that unpacks it, built once a shape from the feeds the
+        decode graph declares (what `_stage_inputs` reads). The array,
+        int32 words: the token stream and the positions (rows x q each),
+        a row of each page table the graph reads a row, `state_slot`,
+        `read_idx` and the temperatures' float32 bits (a word a row
+        each), then a word a slot of `from_sampled` and, where the rows
+        are not the slots (a chunk as rows), of `sampled_row`.
+
+        The program, `feed(packed, sampled, rng)`: the feeds as the dict
+        the step reads, each placed as `_stage_inputs` places it, the
+        constant feeds made where they are read, the token column taken
+        from `sampled` where `from_sampled` says; `read_idx`; the key's
+        split, `rng, sub = split(rng)`, `sub` as its data; the
+        temperatures; `rng`; `sampled_row` (or None)."""
+        packing = self._packings.get((rows, q))
+        if packing is not None:
+            return packing
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import (
+            NamedSharding, PartitionSpec, SingleDeviceSharding)
+
+        from ..fftype import dtype_to_jnp
+
         dec, slots = self.decode_model, self.spec.slots
         mesh = dec.executor.mesh
         whole = NamedSharding(mesh, PartitionSpec())
-        # as _stage_inputs places the token stream: the step program
-        # compiled for its arrays is the one these are run by
-        staged = NamedSharding(
-            mesh, dec._input_partition_spec(self._token_input)
-            or PartitionSpec())
+        shapes = {self._token_input: (rows, q), "positions": (rows, q)}
+        if self.block_manager is not None:
+            width = self.block_manager.table_width
+            shapes["page_table"] = (rows, width)
+            if self.block_manager.window is not None:
+                shapes["page_table_w"] = (rows, width)
+        if self._state_bytes_slot:
+            shapes["state_slot"] = (rows, 1)
+        feeds = tuple(shapes)
+        shapes.update(read_idx=(rows,), temp=(rows,),
+                      from_sampled=(slots,))
+        if rows > slots:
+            shapes["sampled_row"] = (slots,)
+        fields, size = {}, 0
+        for name, shape in shapes.items():
+            words = int(np.prod(shape))
+            fields[name] = (slice(size, size + words), shape)
+            size += words
+        consts = {
+            name: ((rows, q) + tuple(dims[2:]), dtype_to_jnp(dtype), value)
+            for name, (dims, dtype, value) in self._const_inputs.items()}
+        token_input = self._token_input
 
-        def feed(tokens, sampled, from_sampled):
-            column = jnp.where(from_sampled, sampled, tokens[:slots, 0])
-            return tokens.at[:slots, 0].set(column)
+        def feed(packed, sampled, rng):
+            def take(name):
+                words, shape = fields[name]
+                return packed[words].reshape(shape)
 
-        def keep(sampled, step_sampled, row):
-            return jnp.where(row >= 0, step_sampled[jnp.maximum(row, 0)],
-                             sampled)
+            xs = {name: take(name) for name in feeds}
+            tokens = xs[token_input]
+            xs[token_input] = tokens.at[:slots, 0].set(jnp.where(
+                take("from_sampled") != 0, sampled, tokens[:slots, 0]))
+            for name, (shape, dtype, value) in consts.items():
+                xs[name] = jnp.full(shape, value, dtype)
+            rng, sub = jax.random.split(rng)
+            temp = jax.lax.bitcast_convert_type(take("temp"), jnp.float32)
+            return (xs, take("read_idx"), jax.random.key_data(sub), temp,
+                    rng, take("sampled_row") if rows > slots else None)
 
-        self._feed = jax.jit(feed, out_shardings=staged)
-        self._keep = jax.jit(keep, out_shardings=whole)
-        self._sampled = jax.device_put(np.zeros((slots,), np.int32), whole)
+        # the feeds as `_stage_inputs` places them; what the step takes
+        # beside them lies on the mesh's one device (`_uncommitted`) or,
+        # on a mesh of several, whole on each
+        staged = {name: NamedSharding(
+            mesh, dec._input_partition_spec(name) or PartitionSpec())
+            for name in (*feeds, *consts)}
+        beside = (SingleDeviceSharding(mesh.devices.flat[0])
+                  if self.num_chips == 1 else whole)
+        packing = self._packings[rows, q] = _Packing(
+            fields, size, jax.jit(feed, out_shardings=(
+                staged, beside, beside, beside, whole, whole)))
+        return packing
+
+    def _pack(self, step: _Step, packing: _Packing) -> np.ndarray:
+        """The step's host arrays as the one array that is put."""
+        slots = self.spec.slots
+        rows = step.tokens.shape[0]
+        row_slots = step.row_slots
+        packed = np.empty((packing.size,), np.int32)
+
+        def view(name):
+            words, shape = packing.fields[name]
+            return packed[words].reshape(shape)
+
+        view(self._token_input)[:] = step.tokens
+        view("positions")[:] = step.positions
+        if self.block_manager is not None:
+            mgr = self.block_manager
+            tables = [("page_table", mgr.table)]
+            if mgr.window is not None:
+                tables.append(("page_table_w", mgr.window_table))
+            for name, table_of in tables:
+                table = view(name)
+                table[:slots] = [table_of(i) for i in range(slots)]
+                if row_slots is not None:
+                    table[slots:] = table[row_slots[slots:]]
+        if self._state_bytes_slot:
+            view("state_slot")[:, 0] = (
+                np.arange(rows) if row_slots is None else row_slots)
+        view("read_idx")[:] = step.read_idx
+        temp = np.zeros((slots,), np.float32)
+        for s in self.scheduler.active_slots:
+            temp[s.index] = s.request.temperature
+        if row_slots is not None:
+            temp = temp[row_slots]
+        view("temp")[:] = temp.view(np.int32)
+        view("from_sampled")[:] = step.from_sampled
+        if row_slots is not None:
+            view("sampled_row")[:] = step.sampled_row
+        return packed
+
+    def _stage_step(self, step: _Step) -> tuple:
+        """A step's crossing to the device: ONE put, of everything the
+        host knows about the step in one array, and ONE program, which
+        takes it apart where the step reads it (`_packing`). -> `(xs,
+        read_idx, sub, temp)`, the step's arguments, array for array what
+        `_stage_inputs`, the select of the sampled tokens and the key's
+        split gave one by one. The spans are `serve.stage`'s parts:
+        `build` (the pack), `put`, `feed` (the program)."""
+        import jax
+
+        tag = step.tag
+        with telemetry.span("serve.stage", part="build", **tag):
+            packing = self._packing(*step.tokens.shape)
+            packed = self._pack(step, packing)
+        with telemetry.span("serve.stage", part="put", **tag):
+            packed = jax.device_put(packed)
+            self._stage_puts += 1
+        with telemetry.span("serve.stage", part="feed", **tag):
+            if self._rng is None:
+                # the engine's first dispatch: a program built again
+                # from here on is a recompile (telemetry/startup.py)
+                telemetry.startup.steps_began()
+                # placed as `feed` hands it back: one build a step shape
+                self._rng = jax.device_put(
+                    jax.random.key(self.decode_model.config.seed),
+                    self._sampled.sharding)
+            xs, read_idx, sub, temp, self._rng, step.filed = packing.feed(
+                packed, self._sampled, self._rng)
+            self._stage_programs += 1
+            if self.num_chips == 1:
+                read_idx, sub, temp = map(_uncommitted, (read_idx, sub, temp))
+            sub = jax.random.wrap_key_data(sub)
+        return xs, read_idx, sub, temp
 
     def _dispatch(self, step: _Step):
         """Stage one decode-graph call's inputs with their searched
         shardings and launch the donated step; its samples (a row samples
         at its slot's temperature) stay where the step function left
-        them until `_fetch`."""
-        import jax
-        import jax.numpy as jnp
-
-        dec, tag = self.decode_model, step.tag
-        with telemetry.span("serve.stage", **tag):
-            xs = self._stage_inputs(step.tokens, step.positions,
-                                    step.row_slots, tag)
-            # the small device programs: the select that takes a decoding
-            # slot's token from the device, the rng split
-            with telemetry.span("serve.stage", part="feed", **tag):
-                xs[self._token_input] = self._feed(
-                    xs[self._token_input], self._sampled, step.from_sampled)
-                if self._rng is None:
-                    # the engine's first dispatch: a program built again
-                    # from here on is a recompile (telemetry/startup.py)
-                    telemetry.startup.steps_began()
-                    self._rng = jax.random.key(dec.config.seed)
-                self._rng, sub = jax.random.split(self._rng)
-            temp = np.zeros((self.spec.slots,), np.float32)
-            for s in self.scheduler.active_slots:
-                temp[s.index] = s.request.temperature
-            if step.row_slots is not None:
-                temp = temp[step.row_slots]
-            with telemetry.span("serve.stage", part="put", **tag):
-                read_idx = jnp.asarray(step.read_idx, jnp.int32)
-                temp = jnp.asarray(temp)
+        them until `_fetch`, and their copy to the host starts here."""
+        dec = self.decode_model
+        # what the staging below issues before the step's own launch
+        with telemetry.span("serve.stage", puts=1, programs=1, **step.tag):
+            xs, read_idx, sub, temp = self._stage_step(step)
         step.step_fn = self._step_fn
         step.dispatched_t = time.perf_counter()
         with telemetry.span("serve.dispatch", **step.launch):
@@ -743,8 +908,9 @@ class ServingEngine:
             step.at_once = (isinstance(step.sampled, np.ndarray)
                             or bool(dec.config.sanitize_numerics))
             if not step.at_once:
-                self._sampled = self._keep(self._sampled, step.sampled,
-                                           step.sampled_row)
+                step.sampled.copy_to_host_async()
+                self._sampled = (step.sampled if step.filed is None
+                                 else self._keep(step.sampled, step.filed))
 
     def _fetch(self, step: _Step, ahead: bool) -> np.ndarray:
         """The sampled token of every row of a dispatched step, on the
@@ -1231,7 +1397,7 @@ class ServingEngine:
                     if tile else n * start + n * (n + 1) // 2)
             for s in decoding:
                 # the token the step in flight samples for the slot is
-                # not on the host yet: `_feed` takes it from the device
+                # not on the host yet: `feed` takes it from the device
                 if s.ahead:
                     from_sampled[s.index] = True
                 else:
@@ -1497,6 +1663,8 @@ class ServingEngine:
         self.scheduler.completed.clear()
         self._steps = 0
         self._steps_ahead = 0
+        self._stage_puts = 0
+        self._stage_programs = 0
         self._rows_discarded = 0
         self._decode_iterations = 0
         self._decode_tokens = 0
@@ -1580,6 +1748,10 @@ class ServingEngine:
             # request that had ended by EOS, their tokens dropped
             "iterations": self._steps,
             "steps_ahead": self._steps_ahead,
+            # what staging those steps crossed to the device with, before
+            # each one's own launch: one put and one program a step
+            "stage_puts": self._stage_puts,
+            "stage_programs": self._stage_programs,
             "rows_discarded": self._rows_discarded,
             "decode_iterations": self._decode_iterations,
             "decode_tokens": self._decode_tokens,

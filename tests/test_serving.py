@@ -64,18 +64,18 @@ def _build_rows_lm():
 
 
 def _staged_shapes(eng):
-    """Record the (tokens shape, page-table shape) of every call `eng`
-    stages from here on."""
-    shapes, stage = [], eng._stage_inputs
+    """Record the (tokens shape, page-table shape) of every step `eng`
+    stages from here on, as the step's program is handed them."""
+    shapes, stage = [], eng._stage_step
 
-    def spy(tokens, positions, *rest):
-        xs = stage(tokens, positions, *rest)
-        table = xs.get("page_table")
-        shapes.append((tokens.shape,
+    def spy(step):
+        staged = stage(step)
+        table = staged[0].get("page_table")
+        shapes.append((staged[0][eng._token_input].shape,
                        None if table is None else table.shape))
-        return xs
+        return staged
 
-    eng._stage_inputs = spy
+    eng._stage_step = spy
     return shapes
 
 

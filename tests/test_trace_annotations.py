@@ -232,8 +232,8 @@ def test_every_span_of_a_step_carries_its_id(tmp_path, layout, at_once):
             [*once, call[0][0]])
         (stage,) = whole(named(mine, "ff/serve.stage"))
         parts = [s for s in named(mine, "ff/serve.stage") if "part" in s[3]]
-        assert [p[3]["part"] for p in parts] == [
-            "build", "put", "feed", "put"]
+        assert [p[3]["part"] for p in parts] == ["build", "put", "feed"]
+        assert (stage[3]["puts"], stage[3]["programs"]) == (1, 1)
         assert all(inside(p, stage) for p in parts)
         for a, b in zip(parts, parts[1:]):          # disjoint, in order
             assert a[2] <= b[1]
