@@ -233,6 +233,10 @@ class OperatorType(enum.IntEnum):
     # training-shaped op, and its decode op over per-slot recurrent state
     OP_GATED_DELTA_ATTENTION = enum.auto()
     OP_GATED_DELTA_ATTENTION_DECODE = enum.auto()
+    # the selective state-space layer (Mamba-1; ops/ssm.py): the
+    # training-shaped op, and its decode op over per-slot recurrent state
+    OP_SELECTIVE_SSM = enum.auto()
+    OP_SELECTIVE_SSM_DECODE = enum.auto()
 
 
 PARALLEL_OP_TYPES = frozenset(

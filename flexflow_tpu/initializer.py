@@ -73,6 +73,17 @@ class NormInitializer(Initializer):
         return self.mean + self.stddev * jax.random.normal(key, shape, dtype)
 
 
+@dataclass
+class LogRangeInitializer(Initializer):
+    """log(1), log(2), .. log(n) along the first axis of (n, channels),
+    the same a channel: the start of a state-space layer's A_log (A =
+    -exp(A_log) is then -1 .. -n, a state's n decay rates a channel)."""
+
+    def __call__(self, key, shape, dtype):
+        steps = jnp.log(jnp.arange(1, shape[0] + 1, dtype=jnp.float32))
+        return jnp.broadcast_to(steps[:, None], shape).astype(dtype)
+
+
 _BY_NAME = {
     "glorot_uniform": GlorotUniformInitializer(),
     "zeros": ZeroInitializer(),

@@ -552,6 +552,18 @@ class FFModel:
             [input], name, front.initializers(kernel_initializer),
             input.dtype).outputs[0]
 
+    def mamba(self, input: Tensor, front,
+              kernel_initializer: Optional[Initializer] = None,
+              name: str = "") -> Tensor:
+        """A causal selective state-space (Mamba-1) layer on (batch, seq,
+        hidden); `front` is an ops.ssm.MambaFrontEnd (ops/ssm.py, which
+        this call imports: no other graph pays for it)."""
+        from .ops.ssm import SelectiveSSMParams
+
+        return self._add_layer(
+            OT.OP_SELECTIVE_SSM, SelectiveSSMParams(front), [input], name,
+            front.initializers(kernel_initializer), input.dtype).outputs[0]
+
     def concat(self, tensors: Sequence[Tensor], axis: int, name: str = "") -> Tensor:
         p = ConcatParams(axis, len(tensors))
         return self._add_layer(OT.OP_CONCAT, p, list(tensors), name,

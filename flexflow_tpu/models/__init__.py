@@ -21,6 +21,7 @@ from .transformer import (
     command_a_plus_lm_config,
     deepseek_v32_lm_config,
     evabyte_lm_config,
+    jamba_lm_config,
     keye_vl2_lm_config,
     mimo_v2_flash_lm_config,
     mistral_small4_lm_config,

@@ -171,6 +171,9 @@ class TransformerLMConfig:
     norm_unit_offset: bool = False
     fp32_residual: bool = False
     fp32_logits: bool = False
+    # Jamba (`jamba_lm_config`): `layer_pattern` "mamba" = a selective
+    # state-space layer built from `mamba` (an ops.ssm.MambaFrontEnd)
+    mamba: Optional[object] = None
 
     def layer_kind(self, i: int) -> str:
         return self.layer_pattern[i] if self.layer_pattern else self.attention
@@ -192,10 +195,11 @@ class TransformerLMConfig:
         if self.layer_pattern is not None:
             self.layer_pattern = tuple(self.layer_pattern)
             if (len(self.layer_pattern) != self.num_layers
-                    or set(self.layer_pattern) - {"mha", "delta", "swa"}):
+                    or set(self.layer_pattern) - {"mha", "delta", "swa",
+                                                  "mamba"}):
                 raise ValueError(
                     f"TransformerLMConfig.layer_pattern names one kind "
-                    f"('mha' | 'delta' | 'swa') for each of the "
+                    f"('mha' | 'delta' | 'swa' | 'mamba') for each of the "
                     f"{self.num_layers} layers, got {self.layer_pattern!r}")
             if "swa" in self.layer_pattern and not (self.swa or {}).get(
                     "window"):
@@ -206,6 +210,10 @@ class TransformerLMConfig:
                 raise ValueError(
                     "TransformerLMConfig.layer_pattern 'delta' needs "
                     "`delta` (a DeltaFrontEnd)")
+            if "mamba" in self.layer_pattern and self.mamba is None:
+                raise ValueError(
+                    "TransformerLMConfig.layer_pattern 'mamba' needs "
+                    "`mamba` (a MambaFrontEnd)")
 
 
 def olmoe_lm_config(**sizes) -> TransformerLMConfig:
@@ -646,6 +654,51 @@ def evabyte_lm_config(config: dict, *, sequence_length: int,
         initializer_range=float(config["init_std"]))
 
 
+def jamba_lm_config(config: dict, *, sequence_length: int,
+                    attention_impl: str = "xla",
+                    initializer_range: float = 0.02) -> TransformerLMConfig:
+    """Jamba from the keys of its published config.json (`model_type:
+    jamba`; models/jamba2_reference.py writes the equations out and says
+    what the keys leave open): layer i is causal softmax attention where i
+    mod `attn_layer_period` = `attn_layer_offset` (grouped keys and
+    values, no bias, no position anywhere) and a selective state-space
+    (Mamba-1) layer elsewhere, with RMSNorms inside it over dt, B and C;
+    every layer a SiLU-gated MLP of `intermediate_size` (`num_experts` 1:
+    no routed experts), RMSNorm before each, the head tied to the
+    embedding where `tie_word_embeddings` says so."""
+    from ..ops.ssm import MambaFrontEnd
+
+    for key, built in (("num_experts", 1), ("mamba_proj_bias", False),
+                       ("hidden_act", "silu")):
+        if config.get(key, built) != built:
+            raise NotImplementedError(
+                f"jamba_lm_config builds {key} {built!r}, got "
+                f"{config[key]!r}")
+    layers, hidden = config["num_hidden_layers"], config["hidden_size"]
+    period, offset = config["attn_layer_period"], config["attn_layer_offset"]
+    heads = config["num_attention_heads"]
+    return TransformerLMConfig(
+        vocab_size=config["vocab_size"], hidden_size=hidden,
+        num_heads=heads, num_layers=layers,
+        sequence_length=sequence_length, attention_impl=attention_impl,
+        norm="rmsnorm", norm_eps=config["rms_norm_eps"], position="none",
+        attention_bias=False,
+        num_kv_heads=config["num_key_value_heads"],
+        head_dim=config.get("head_dim") or hidden // heads,
+        layer_pattern=tuple("mha" if i % period == offset else "mamba"
+                            for i in range(layers)),
+        mamba=MambaFrontEnd(
+            embed_dim=hidden, inner=config["mamba_expand"] * hidden,
+            state_size=config["mamba_d_state"],
+            dt_rank=config["mamba_dt_rank"],
+            conv_kernel=config["mamba_d_conv"],
+            conv_bias=bool(config["mamba_conv_bias"]),
+            norm_eps=config["rms_norm_eps"]),
+        mlp="swiglu", intermediate_size=config["intermediate_size"],
+        tie_embeddings=bool(config["tie_word_embeddings"]),
+        initializer_range=initializer_range)
+
+
 def _norm_initializer(stddev: float, mean: float = 0.0):
     from ..initializer import NormInitializer
 
@@ -677,6 +730,9 @@ def _lm_trunk(ff, c: TransformerLMConfig, h, pos, wte=None):
         if c.layer_kind(i) == "delta":
             a = ff.gated_delta_attention(n, c.delta, kernel_initializer=init,
                                          name=f"{p}attn")
+        elif c.layer_kind(i) == "mamba":
+            a = ff.mamba(n, c.mamba, kernel_initializer=init,
+                         name=f"{p}attn")
         elif c.attention == "latent":
             a = ff.latent_attention(n, pos, c.latent, kernel_initializer=init,
                                     name=f"{p}attn")

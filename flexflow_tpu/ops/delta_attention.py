@@ -17,20 +17,16 @@ of fixed size: a state S (heads, d, d) float32 and the convolution's last
 - OP_GATED_DELTA_ATTENTION_DECODE, the decode op: state leaves `state_s`
   (slots, heads, d, d) float32 and `state_conv` (slots, conv_kernel - 1,
   3 heads d), indexed by SLOT, not by page. Its rows follow the serving
-  engine's two layouts of a step (serving/engine.py):
-    the rectangle (slots, q): row i is slot i's next q tokens in order;
-    rows (slots + q, 1): rows [0, slots) are one token of their own slot,
-    rows [slots, slots + q) are q consecutive tokens of ONE slot, the one
-    the `state_slot` input names for them, run in order from that slot's
-    state and written back to it.
-  A token is live where 0 <= position < max_seq_len; live tokens lead
-  their row. A dead token leaves the state as it is. A row whose first
-  live token is at position 0 is a request's first: it starts from the
-  zero state and an empty convolution window, so a slot given to a new
-  request is reset inside the step's program. One token a row runs the
-  Pallas kernel (kernels/delta_rule.py) where its gate allows, state
-  aliased in place; so does a chunk, whose state stays in VMEM over its
-  tokens (timed on the chip against the `lax.scan`: PERF.md section 6).
+  engine's two layouts of a step (ops/recurrent.decode_rows, which the
+  selective state-space layer shares: the rectangle, or the slots' rows
+  and then one chunk's rows run in order from the slot `state_slot`
+  names). A dead token leaves the state as it is. A row whose first live
+  token is at position 0 starts from the zero state and an empty
+  convolution window, so a slot given to a new request is reset inside
+  the step's program. One token a row runs the Pallas kernel
+  (kernels/delta_rule.py) where its gate allows, state aliased in place;
+  so does a chunk, whose state stays in VMEM over its tokens (timed on
+  the chip against the `lax.scan`: PERF.md section 6).
 """
 
 from __future__ import annotations
@@ -42,11 +38,12 @@ import jax.numpy as jnp
 
 from ..fftype import DataType, OperatorType as OT
 from .attention import proj
-from .base import (
-    BY_SLOT, HANDOFF, PREFIX, REWIND, DecodeState, OpDef, StateLeaf,
-    WeightSpec, register_op,
-)
+from .base import BY_SLOT, DecodeState, OpDef, WeightSpec, register_op
 from .core import rms_norm
+from .recurrent import (
+    causal_conv, conv_window, decode_rows, infer_shapes, next_tail,
+    slot_state,
+)
 
 
 @dataclass(frozen=True)
@@ -123,11 +120,7 @@ class DeltaFrontEnd:
         conv_kernel - 1 + tokens, 3 width) holds each row's earlier
         inputs before its tokens; (rows, tokens, 3 width) float32."""
         with jax.named_scope("kda.conv"):
-            taps = weights["conv"].astype(jnp.float32)
-            wf = window.astype(jnp.float32)
-            y = sum(taps[i] * wf[:, i:i + tokens]
-                    for i in range(self.conv_kernel))
-            return y * jax.nn.sigmoid(y)
+            return causal_conv(weights["conv"], window, tokens)
 
     def heads(self, qkv):
         """q (l2-normalised, times d^-0.5), k (l2-normalised), v, each
@@ -188,17 +181,13 @@ def run_sequences(f: DeltaFrontEnd, ctx, weights, x, live, keep, state,
     hidden), the new state and the new tail: a row's last conv_kernel - 1
     inputs up to its last live token."""
     tokens = x.shape[1]
-    u = f.qkv_in(ctx, weights, x)
-    tail = jnp.where(keep[:, None, None], tail.astype(u.dtype), 0)
-    window = jnp.concatenate([tail, u], axis=1)
+    window = conv_window(tail, f.qkv_in(ctx, weights, x), keep)
     q, k, v = f.heads(f.conv(weights, window, tokens))
     alpha, beta = f.gates(ctx, weights, x)
     with jax.named_scope("kda.state"):
         o, state = update(state, q, k, v, alpha, beta, live, keep)
-    n_live = jnp.sum(live, axis=1).astype(jnp.int32)
-    at = n_live[:, None] + jnp.arange(f.conv_kernel - 1)
-    tail = jnp.take_along_axis(window, at[:, :, None], axis=1)
-    return f.output(ctx, weights, o, x), state, tail
+    return (f.output(ctx, weights, o, x), state,
+            next_tail(window, live, f.conv_kernel))
 
 
 # ------------------------------------------------------------ training-shaped
@@ -209,11 +198,6 @@ class GatedDeltaAttentionParams:
 
     embed_dim = property(lambda self: self.front.embed_dim)
     num_heads = property(lambda self: self.front.num_heads)
-
-
-def _delta_infer(p, in_shapes):
-    x = in_shapes[0]
-    return [tuple(x[:-1]) + (p.front.embed_dim,)]
 
 
 def _delta_weights(p: GatedDeltaAttentionParams, in_shapes):
@@ -249,7 +233,7 @@ def _delta_decode_layer(layer, ctx):
             ("positions", "state_slot"))
 
 
-register_op(OpDef(OT.OP_GATED_DELTA_ATTENTION, _delta_infer, _delta_forward,
+register_op(OpDef(OT.OP_GATED_DELTA_ATTENTION, infer_shapes, _delta_forward,
                   _delta_weights, _delta_flops,
                   decode_layer=_delta_decode_layer))
 
@@ -278,54 +262,24 @@ def _delta_decode_state(p: GatedDeltaDecodeParams) -> DecodeState:
     not paged, not shareable block by block, reset when a slot's row starts
     a request (position 0)."""
     f = p.front
-    return DecodeState(
-        (StateLeaf("state_s", BY_SLOT, (f.num_heads, f.head_dim, f.head_dim),
-                   DataType.DT_FLOAT),
-         StateLeaf("state_conv", BY_SLOT, (f.conv_kernel - 1, 3 * f.width),
-                   p.cache_dtype)),
-        slots=p.slots, cannot=dict.fromkeys(
-            (HANDOFF, REWIND, PREFIX),
-            "recurrent layers (gated delta-rule attention: {layer}, ...): "
-            "their per-slot state is neither rewound nor handed off, nor "
-            "kept at a cached prefix's end"))
+    return slot_state(
+        (("state_s", (f.num_heads, f.head_dim, f.head_dim),
+          DataType.DT_FLOAT),
+         ("state_conv", (f.conv_kernel - 1, 3 * f.width), p.cache_dtype)),
+        p.slots, "gated delta-rule attention")
 
 
 def _delta_decode_forward(p: GatedDeltaDecodeParams, inputs, weights, state,
                           ctx):
     from ..kernels.delta_rule import delta_rule_update
 
-    f = p.front
-    x, positions, state_slot = inputs
-    rows, q_len, _ = x.shape
-    positions = positions.astype(jnp.int32)
-    live = (positions >= 0) & (positions < p.max_seq_len)
-    # a row that starts a request starts from nothing
-    keep = ~(live[:, 0] & (positions[:, 0] == 0))
-    S, tail = weights["state_s"], weights["state_conv"]
-    n = p.slots
-    if rows < n or (rows > n and q_len != 1):
-        raise ValueError(
-            f"gated delta attention: a call has the {n} slots' rows, and "
-            f"past them single-query rows of one chunk; got ({rows}, "
-            f"{q_len})")
-
     def run(x, live, keep, S, tail):
-        return run_sequences(f, ctx, weights, x, live, keep, S, tail,
+        return run_sequences(p.front, ctx, weights, x, live, keep, S, tail,
                              delta_rule_update)
 
-    y, S, tail_n = run(x[:n], live[:n], keep[:n], S, tail)
-    tail = tail_n.astype(tail.dtype)
-    if rows > n:
-        # one chunk: its tokens in order from its slot's state
-        c = state_slot[n, 0].astype(jnp.int32)
-        y_c, S_c, tail_c = run(
-            x[n:, 0][None], live[n:, 0][None], keep[n][None],
-            jax.lax.dynamic_index_in_dim(S, c, keepdims=True),
-            jax.lax.dynamic_index_in_dim(tail, c, keepdims=True))
-        S = jax.lax.dynamic_update_index_in_dim(S, S_c[0], c, axis=0)
-        tail = jax.lax.dynamic_update_index_in_dim(
-            tail, tail_c[0].astype(tail.dtype), c, axis=0)
-        y = jnp.concatenate([y, y_c[0][:, None]], axis=0)
+    y, (S, tail) = decode_rows(
+        "gated delta attention", p.slots, p.max_seq_len, inputs,
+        (weights["state_s"], weights["state_conv"]), run)
     return [y], {"state_s": S, "state_conv": tail}
 
 
@@ -335,7 +289,7 @@ def _delta_decode_flops(p: GatedDeltaDecodeParams, in_shapes, out_shapes):
             + p.front.state_flops(rows * q_len))
 
 
-register_op(OpDef(OT.OP_GATED_DELTA_ATTENTION_DECODE, _delta_infer,
+register_op(OpDef(OT.OP_GATED_DELTA_ATTENTION_DECODE, infer_shapes,
                   _delta_decode_forward, _delta_weights,
                   _delta_decode_flops, state=_delta_decode_state,
                   state_leaves=dict(state_s=BY_SLOT, state_conv=BY_SLOT)))

@@ -652,6 +652,67 @@ def test_delta_rule_decode_layer_at_solar_open2_widths(tpu, rows):
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
 
 
+@pytest.mark.parametrize("chunk", [0, 1024, 512, 8])
+def test_multi_query_paged_kernels_at_jamba2_widths(tpu, chunk):
+    """`jamba2-serve-shortchat`'s two softmax layers: 20 query heads of
+    128 over ONE KV head (a group of 20, no multiple of 8 sublanes; a pool
+    row of 128 lanes), blocks of 256, tables 6 wide; 256 slots through the
+    grouped single-query kernel and a chunk of 512 (or the smallest
+    bucket) through the grouped chunk kernel."""
+    from flexflow_tpu.ops.attention import AttentionFrontEnd
+
+    s = _on(tpu[0])
+    front = AttentionFrontEnd(2560, 20, use_bias=False, num_kv_heads=1,
+                              head_size=128)
+    p, op, layer = _paged_attention_layer(front, 1536, 256, 1600, slots=256)
+    rows = 256 + chunk
+    specs = op.weights(p, [(rows, 1, 2560), (rows, 1), (rows, 6)])
+    kernels = _kernels(
+        layer, {w.name: s(w.shape) for w in specs}, s((rows, 1, 2560)),
+        s((rows, 1), jnp.int32), s((rows, 6), jnp.int32))
+    assert kernels == {
+        "flash_attention_paged_decode_grouped": 1,
+        **({"flash_attention_paged_chunk_grouped": 1} if chunk else {})}
+
+
+@pytest.mark.parametrize("rows", [256, 256 + 1024, 256 + 512, 256 + 8])
+def test_selective_ssm_decode_layer_at_jamba2_widths(tpu, rows):
+    """One state-space layer of `jamba2-serve-shortchat` as the decode
+    graph runs it: 256 slots of 16 x 5,120 float32 state, one token a
+    slot, and the same with a chunk of 512 (or 8) as rows of one slot. The
+    state update is the Pallas kernel (twice with a chunk: the slots',
+    then the chunk's from its slot's state), the state aliased in place:
+    the step needs under 0.5 GB beside its 84 MB of state."""
+    from flexflow_tpu.fftype import DataType, OperatorType as OT
+    from flexflow_tpu.ops import ssm
+    from flexflow_tpu.ops.base import OpContext, get_op_def
+
+    s = _on(tpu[0])
+    p = ssm.SelectiveSSMDecodeParams(
+        ssm.MambaFrontEnd(2560, 5120, 16, 160), slots=256, max_seq_len=1536,
+        cache_dtype=DataType.DT_BFLOAT16)
+    op = get_op_def(OT.OP_SELECTIVE_SSM_DECODE)
+    specs = op.weights(p, [(rows, 1, 2560)])
+    weights = {w.name: s(w.shape) for w in specs if w.trainable}
+    state = {w.name: s(w.shape, jnp.float32 if w.name == "state_h"
+                       else jnp.bfloat16)
+             for w in specs if not w.trainable}
+    assert state["state_h"].shape == (256, 16, 5120)
+
+    def layer(state, weights, x, positions, state_slot):
+        (y,), state = op.forward(
+            p, [x, positions, state_slot], {**weights, **state}, None,
+            OpContext(training=False, mesh=None))
+        return y, state
+
+    compiled = jax.jit(layer, donate_argnums=(0,)).lower(
+        state, weights, s((rows, 1, 2560)), s((rows, 1), jnp.int32),
+        s((rows, 1), jnp.int32)).compile()
+    assert pallas_kernels(compiled.as_text()) == {
+        "selective_scan_update": 1 if rows == 256 else 2}
+    assert compiled.memory_analysis().temp_size_in_bytes < 5e8
+
+
 def test_contiguous_decode_head_dim_128(tpu):
     """The contiguous decode kernel at the engine's real cache shape
     (slots, max_seq + 1, E): max_seq + 1 is odd, so the last kv block is

@@ -26,6 +26,7 @@ from flexflow_tpu.serving.decode_graph import (
     ServingSpec, decode_states, refuse, resolve_pool_blocks,
 )
 
+import test_jamba2 as jamba
 import test_keye_vl2 as keye
 import test_latent_attention as latent
 import test_mimo_v2_flash as mimo
@@ -45,6 +46,7 @@ KINDS = {
     "gpt2-contiguous": (gpt2, dict(slots=3, max_seq_len=40, prefill_chunk=8,
                                    kv_layout="contiguous")),
     "gqa-gate+delta": (solar.build, PAGED),
+    "mqa+ssm": (jamba.build, PAGED),
     "latent+indexer": (latent.build, dict(PAGED, max_seq_len=24)),
     "gqa+indexer": (keye.build, PAGED),
     "window+global": (mimo.build, PAGED),
@@ -57,6 +59,8 @@ REFUSED = {
     "gpt2-paged": {}, "gpt2-contiguous": {},
     "gqa-gate+delta": dict.fromkeys((HANDOFF, REWIND, PREFIX),
                                     ("l1_attn", RECURRENT)),
+    "mqa+ssm": dict.fromkeys((HANDOFF, REWIND, PREFIX),
+                             ("l0_attn", RECURRENT)),
     "latent+indexer": dict.fromkeys((HANDOFF, QUERIES),
                                     ("l0_attn", SELECTION)),
     "gqa+indexer": dict.fromkeys((HANDOFF, QUERIES), ("l0_attn", SELECTION)),
@@ -212,8 +216,8 @@ SOURCES = sorted(
 
 
 @pytest.mark.parametrize("pattern", [
-    r"OP_[A-Z_]*ATTENTION",
-    r"""["'](pool_[kvci]|pool_kv|state_s|state_conv|cache_[kv])["']""",
+    r"OP_[A-Z_]*(ATTENTION|SSM)",
+    r"""["'](pool_[kvci]|pool_kv|state_[sh]|state_conv|cache_[kv])["']""",
     r"\b(refuse_recurrent|refuse_indexed|refuse_windowed|PAGED_OPS|"
     r"slot_state_bytes|recurrent_layers|indexed_layers|window_layers)\b",
     r"def cache_row_widths",
