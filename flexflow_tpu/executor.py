@@ -187,6 +187,11 @@ class Executor:
         self._eval_step = None
         self._forward_fn = None
         self._decode_step = None  # serving decode executable (serving/)
+        # the graph's row-wise tail, found once (decode_tail), and what
+        # the graph was made to serve (a DecodeContext: its slots, its
+        # max_seq), where serving/decode_graph.py made it
+        self._decode_tail: Optional[tuple] = None
+        self.decode_context = None
         # chunked (lax.scan) train steps keyed by chunk length — the
         # pipelined engine's fused multi-step dispatch (engine/)
         self._chunk_steps: dict[int, Any] = {}
@@ -612,26 +617,27 @@ class Executor:
     # ------------------------------------------------------------ apply
 
     def _apply(self, params, state, inputs, *, training, rng,
-               seq_length=-1, step=None):
+               seq_length=-1, step=None, upto_tail: bool = False):
         """Run the PCG forward. Returns (logits, new_state, aux_loss).
         `step` (traced int or None) feeds the sanitizer probes and the
         fault injector so localization carries the exact step inside
-        chunked lax.scan dispatches too."""
-        if self.sanitize_numerics:
-            from . import sanitize
+        chunked lax.scan dispatches too. `upto_tail` stops in front of
+        the graph's row-wise tail (`decode_tail`) and returns the tail's
+        input in the logits' place: `_apply_tail` then runs the tail on
+        rows of it."""
+        tail_nodes, cut = (self.decode_tail() if upto_tail
+                           else ((), (self.logits_node.guid, 0)))
+        skip = {n.guid for n in tail_nodes}
         vals: dict[tuple[int, int], Any] = {}
         new_state = {k: dict(v) for k, v in state.items()}
         aux_loss = 0.0
         for topo_idx, node in enumerate(self.order):
+            if node.guid in skip:
+                continue
             if node.op_type in (OT.OP_INPUT, OT.OP_WEIGHT, OT.OP_NOOP):
                 if node.op_type == OT.OP_INPUT:
-                    x = inputs[node.name]
-                    spec = node.outputs[0].partition_spec()
-                    if _spec_nontrivial(spec):
-                        x = jax.lax.with_sharding_constraint(
-                            x, NamedSharding(self.mesh, spec)
-                        )
-                    vals[(node.guid, 0)] = x
+                    vals[(node.guid, 0)] = self._placed(
+                        inputs[node.name], node.outputs[0].partition_spec())
                 elif self.graph.in_edges[node.guid]:
                     src, sidx = self.graph.producer(node, 0)
                     vals[(node.guid, 0)] = vals[(src.guid, sidx)]
@@ -640,76 +646,135 @@ class Executor:
             ins = [None] * len(self.graph.in_edges[node.guid])
             for e in self.graph.in_edges[node.guid]:
                 ins[e.dst_idx] = vals[(e.src, e.src_idx)]
-
-            # tied weights read the source node's parameter set; autodiff
-            # then sums every use's gradient into that one set
-            wsrc = getattr(node, "weight_source", None) or node.name
-            p_own = params.get(wsrc, {})
-            ctx = OpContext(
-                training=training,
-                rng=_stable_fold(rng, node.name) if rng is not None else None,
-                seq_length=seq_length,
-                profiling=self.config.profiling,
-                mesh=self.mesh,
-                out_spec=(node.outputs[0].partition_spec()
-                          if node.outputs else None),
-                weight_axes=node.weight_axes,
-                matmul_dtype=self.matmul_dtype,
-                overlap_collectives=self.config.overlap_collectives,
-            )
-            op_state = new_state.get(node.name)
-            # named_scope labels the op in XLA profiles (the analog of the
-            # reference's per-op profiling prints, linear_kernels.cu:95-117)
-            with jax.named_scope(node.name):
-                if self.gather_specs:
-                    # stage 3 (ZeRO-3/FSDP): the weights that rest sharded
-                    # over the update axes come to their compute placement
-                    # here, once a step; the backward reads the same
-                    # gathered value, in the compute dtype
-                    p_own = {k: (self._gather_with_vjp(wsrc, k)(v)
-                                 if (wsrc, k) in self.gather_specs else v)
-                             for k, v in p_own.items()}
-                weights = {}
-                # bf16 cast at the consumer: each node casts only its
-                # own weights, so XLA fuses the downcast into the
-                # first use instead of writing a model-sized bf16
-                # copy to HBM up front (state stays uncast — ops own
-                # their fp32-statistics handling). An inference
-                # compile's parameters rest in the compute dtype
-                # (rest_dtypes): nothing is cast
-                weights.update(self._cast_compute(p_own))
-                weights.update(new_state.get(wsrc, {}))
-                outs, op_state = node.op_def.forward(
-                    node.params, ins, weights, op_state, ctx
-                )
-            if op_state:
-                op_state = dict(op_state)
-                aux = op_state.pop("aux_loss", None)
-                if aux is not None:
-                    aux_loss = aux_loss + aux
-                if op_state:
-                    cur = new_state.setdefault(node.name, {})
-                    cur.update(op_state)
-
+            outs, aux = self._run_node(
+                node, topo_idx, ins, params, new_state, training=training,
+                rng=rng, seq_length=seq_length, step=step)
+            if aux is not None:
+                aux_loss = aux_loss + aux
             for i, out in enumerate(outs):
-                if i < len(node.outputs):
-                    spec = node.outputs[i].partition_spec()
-                    if _spec_nontrivial(spec):
-                        out = jax.lax.with_sharding_constraint(
-                            out, NamedSharding(self.mesh, spec)
-                        )
-                if i == 0 and self._numeric_fault is not None:
-                    out = self._maybe_poison(out, node.name, step, "fwd")
-                if self.sanitize_numerics:
-                    label = (node.name if i == 0
-                             else f"{node.name}#out{i}")
-                    out = sanitize.probe(out, step, label, topo_idx)
-                if i == 0 and self._numeric_fault is not None:
-                    out = self._maybe_poison(out, node.name, step, "bwd")
                 vals[(node.guid, i)] = out
 
-        logits = vals[(self.logits_node.guid, 0)]
-        return logits, new_state, aux_loss
+        return vals[cut], new_state, aux_loss
+
+    def _apply_tail(self, params, rows):
+        """The graph's row-wise tail (`decode_tail`) on `rows`, some rows
+        of what `_apply(upto_tail=True)` returned, one in the place of a
+        declared row or more: the logits of those rows. The tail keeps
+        no state and a serving step runs it outside training."""
+        for node in self.decode_tail()[0]:
+            (rows, *_), _ = self._run_node(
+                node, self.order.index(node), [rows], params, {},
+                training=False, rng=None, seq_length=-1, step=None,
+                any_rows=True)
+        return rows
+
+    def decode_tail(self) -> tuple:
+        """(the nodes of the graph's row-wise tail in the order they run,
+        the (guid, output) their first reads): back from the logits node
+        through nodes that read one value, are read by the next alone,
+        keep nothing from call to call and act on each row by itself
+        (`OpDef.row_wise`), so that the tail of some rows is those rows
+        of the tail. `ln_f` -> `lm_head` of an LM's trunk; () and the
+        logits themselves where the last op is not row-wise."""
+        if self._decode_tail is None:
+            graph, node, sidx, tail = self.graph, self.logits_node, 0, []
+            while (node.op_type not in (OT.OP_INPUT, OT.OP_WEIGHT,
+                                        OT.OP_NOOP)
+                   and len(graph.in_edges[node.guid]) == 1
+                   and len(graph.out_edges[node.guid]) == (1 if tail else 0)
+                   and all(ws.trainable for ws in node.weight_specs)
+                   and node.op_def.row_wise(
+                       node.params,
+                       [t.shape.logical_shape for t in node.inputs])):
+                tail.append(node)
+                node, sidx = graph.producer(node, 0)
+            self._decode_tail = (tuple(reversed(tail)), (node.guid, sidx))
+        return self._decode_tail
+
+    def _placed(self, x, spec: PartitionSpec, any_rows: bool = False):
+        """`x` pinned to the plan's placement of it; with `any_rows`, a
+        number of rows the axes of dim 0 do not divide stays whole."""
+        if not _spec_nontrivial(spec):
+            return x
+        if any_rows and spec[0] is not None:
+            axes = spec[0] if isinstance(spec[0], tuple) else (spec[0],)
+            if x.shape[0] % int(np.prod([self.mesh.shape[a]
+                                         for a in axes])):
+                spec = PartitionSpec(None, *spec[1:])
+        return jax.lax.with_sharding_constraint(
+            x, NamedSharding(self.mesh, spec))
+
+    def _run_node(self, node, topo_idx, ins, params, new_state, *,
+                  training, rng, seq_length, step, any_rows=False):
+        """One compute node of the forward: (its outputs as the plan
+        places them, its auxiliary loss or None); what it keeps from call
+        to call goes into `new_state`."""
+        if self.sanitize_numerics:
+            from . import sanitize
+        # tied weights read the source node's parameter set; autodiff
+        # then sums every use's gradient into that one set
+        wsrc = getattr(node, "weight_source", None) or node.name
+        p_own = params.get(wsrc, {})
+        ctx = OpContext(
+            training=training,
+            rng=_stable_fold(rng, node.name) if rng is not None else None,
+            seq_length=seq_length,
+            profiling=self.config.profiling,
+            mesh=self.mesh,
+            out_spec=(node.outputs[0].partition_spec()
+                      if node.outputs else None),
+            weight_axes=node.weight_axes,
+            matmul_dtype=self.matmul_dtype,
+            overlap_collectives=self.config.overlap_collectives,
+        )
+        op_state = new_state.get(node.name)
+        # named_scope labels the op in XLA profiles (the analog of the
+        # reference's per-op profiling prints, linear_kernels.cu:95-117)
+        with jax.named_scope(node.name):
+            if self.gather_specs:
+                # stage 3 (ZeRO-3/FSDP): the weights that rest sharded
+                # over the update axes come to their compute placement
+                # here, once a step; the backward reads the same
+                # gathered value, in the compute dtype
+                p_own = {k: (self._gather_with_vjp(wsrc, k)(v)
+                             if (wsrc, k) in self.gather_specs else v)
+                         for k, v in p_own.items()}
+            weights = {}
+            # bf16 cast at the consumer: each node casts only its
+            # own weights, so XLA fuses the downcast into the
+            # first use instead of writing a model-sized bf16
+            # copy to HBM up front (state stays uncast — ops own
+            # their fp32-statistics handling). An inference
+            # compile's parameters rest in the compute dtype
+            # (rest_dtypes): nothing is cast
+            weights.update(self._cast_compute(p_own))
+            weights.update(new_state.get(wsrc, {}))
+            outs, op_state = node.op_def.forward(
+                node.params, ins, weights, op_state, ctx
+            )
+        aux = None
+        if op_state:
+            op_state = dict(op_state)
+            aux = op_state.pop("aux_loss", None)
+            if op_state:
+                cur = new_state.setdefault(node.name, {})
+                cur.update(op_state)
+
+        placed = []
+        for i, out in enumerate(outs):
+            if i < len(node.outputs):
+                out = self._placed(out, node.outputs[i].partition_spec(),
+                                   any_rows)
+            if i == 0 and self._numeric_fault is not None:
+                out = self._maybe_poison(out, node.name, step, "fwd")
+            if self.sanitize_numerics:
+                label = (node.name if i == 0
+                         else f"{node.name}#out{i}")
+                out = sanitize.probe(out, step, label, topo_idx)
+            if i == 0 and self._numeric_fault is not None:
+                out = self._maybe_poison(out, node.name, step, "bwd")
+            placed.append(out)
+        return placed, aux
 
     # ------------------------------------------------------------ steps
 
@@ -825,29 +890,71 @@ class Executor:
     def build_decode_step(self):
         """ONE serving iteration as a donated executable: forward the
         decode graph (incremental attention reads+writes the KV-cache
-        state threaded through `state`), then sample the next token per
-        slot from the logits row `read_idx` names — argmax where
-        `temperature[slot] == 0`, Gumbel sampling otherwise, in the same
-        program so only the (slots,) token vector crosses the host
-        boundary. Donating `state` updates the cache in place on backends
-        that support donation (the TPU serving hot loop allocates nothing
+        state threaded through `state`) up to its row-wise tail
+        (`decode_tail`: the final norm and the vocabulary head), then
+        run the tail on the rows a token is read from and sample from
+        them — argmax where `temperature[row] == 0`, Gumbel sampling
+        otherwise, in the same program so only the (rows,) token vector
+        crosses the host boundary. Which rows those are follows from the
+        call's shape beside the slots the graph was made for
+        (`decode_context`; a graph made otherwise: every row a slot's):
+
+          - (slots, 1), a step that only decodes: every row, nothing
+            gathered in front of the tail;
+          - (slots, q), the rectangle: row `read_idx[slot]` of each slot;
+          - (slots + bucket, 1), a chunk as rows past the slots': the
+            slots' rows and ONE more, the chunk's last live row, found
+            from the call's own `positions` (a dead row carries the
+            scratch position, the graph's max_seq). `sampled` has the
+            drawn token at those rows and 0 at the others.
+
+        Donating `state` updates the cache in place on backends that
+        support donation (the TPU serving hot loop allocates nothing
         per token). Distinct q_len values (decode=1, prefill buckets)
         retrace into their own cached executables — the length-bucketed
         executable set falls out of jit's shape specialization."""
+        served = self.decode_context
 
         def decode_step(params, state, x_inputs, read_idx, rng, temperature):
-            logits, new_state, _ = self._apply(
+            hidden, new_state, _ = self._apply(
                 params, state,
                 self._cast_compute(x_inputs), training=False, rng=None,
+                upto_tail=True,
             )
-            slots = logits.shape[0]
-            sel = logits[jnp.arange(slots), read_idx]  # (slots, vocab)
-            sel = sel.astype(jnp.float32)
+            rows, q = hidden.shape[:2]
+            slots = served.slots if served is not None else rows
+            last = None  # the chunk's last live row, where it rides as rows
+            if rows > slots:
+                live = jnp.sum(x_inputs["positions"][slots:, 0]
+                               < served.max_seq)
+                last = slots + jnp.maximum(live - 1, 0)
+
+                def sampled_rows(x):
+                    return jnp.concatenate([
+                        x[:slots],
+                        jax.lax.dynamic_slice_in_dim(x, last, 1)])
+
+                hidden, temperature = map(sampled_rows,
+                                          (hidden, temperature))
+            elif q > 1:
+                hidden = hidden[jnp.arange(rows), read_idx][:, None]
+            sel = self._apply_tail(params, hidden)
+            # a step that only decodes gathers by `read_idx` behind the
+            # head: the gather keeps XLA from fusing the sampler into the
+            # head's matmul, and the two apart read 0.01-0.05 ms a step
+            # faster on a v5e than the fused form (PERF.md, PR 56)
+            sel = (sel[:, 0] if rows > slots or q > 1
+                   else sel[jnp.arange(rows), read_idx])
+            sel = sel.astype(jnp.float32)  # (slots [+ 1], vocab)
             t = temperature.astype(jnp.float32)[:, None]
             gumbel = jax.random.gumbel(rng, sel.shape, jnp.float32)
             noisy = jnp.where(t > 0.0,
                               sel / jnp.maximum(t, 1e-6) + gumbel, sel)
             next_tok = jnp.argmax(noisy, axis=-1).astype(jnp.int32)
+            if last is not None:
+                next_tok = jax.lax.dynamic_update_slice_in_dim(
+                    jnp.zeros((rows,), jnp.int32).at[:slots].set(
+                        next_tok[:slots]), next_tok[slots:], last, 0)
             return self._pin_at_rest(
                 self._restore_state_dtypes(new_state)), next_tok
 

@@ -189,6 +189,12 @@ class OpDef:
         # and {name: index} of every leaf a declaration of it may hold
         state: Optional[Callable] = None,
         state_leaves: Optional[dict] = None,
+        # whether the op acts on each row alone over the last axis, so
+        # that rows gathered in front of it are the rows of its output:
+        # True, or (params, in_shapes) -> bool where it is a fact of the
+        # layer (a serving step runs such a tail of the graph on the rows
+        # it samples from: Executor.build_decode_step)
+        row_wise=False,
     ):
         self.op_type = op_type
         self.infer_shapes = infer_shapes
@@ -205,6 +211,8 @@ class OpDef:
             lambda layer, ctx: (layer.op_type, layer.params, ()))
         self.state = state
         self.state_leaves = state_leaves or {}
+        self.row_wise = row_wise if callable(row_wise) else (
+            lambda params, in_shapes: row_wise)
 
 
 def _default_flops(params, in_shapes, out_shapes) -> float:

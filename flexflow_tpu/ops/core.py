@@ -96,7 +96,8 @@ def _linear_flops(p: LinearParams, in_shapes, out_shapes):
     return 2.0 * math.prod(x) * p.out_channels
 
 
-register_op(OpDef(OT.OP_LINEAR, _linear_infer, _linear_forward, _linear_weights, _linear_flops))
+register_op(OpDef(OT.OP_LINEAR, _linear_infer, _linear_forward, _linear_weights,
+                  _linear_flops, row_wise=True))
 
 
 # ---------------------------------------------------------------- Conv2D
@@ -336,7 +337,13 @@ def _ln_forward(p: LayerNormParams, inputs, weights, state, ctx):
     return [y.astype(x.dtype)], state
 
 
-register_op(OpDef(OT.OP_LAYERNORM, _ln_infer, _ln_forward, _ln_weights))
+def _ln_row_wise(p: LayerNormParams, in_shapes):
+    rank = len(in_shapes[0])
+    return tuple(a % rank for a in p.axes) == (rank - 1,)
+
+
+register_op(OpDef(OT.OP_LAYERNORM, _ln_infer, _ln_forward, _ln_weights,
+                  row_wise=_ln_row_wise))
 
 
 # ---------------------------------------------------------------- RMSNorm
@@ -374,7 +381,8 @@ def _rms_forward(p: RMSNormParams, inputs, weights, state, ctx):
         scale.dtype if p.narrow_out else None)], state
 
 
-register_op(OpDef(OT.OP_RMSNORM, _ln_infer, _rms_forward, _rms_weights))
+register_op(OpDef(OT.OP_RMSNORM, _ln_infer, _rms_forward, _rms_weights,
+                  row_wise=True))
 
 
 # ---------------------------------------------------------------- Softmax
@@ -419,7 +427,9 @@ def _dropout_forward(p: DropoutParams, inputs, weights, state, ctx):
     return [jnp.where(mask, x / keep, 0.0).astype(x.dtype)], state
 
 
-register_op(OpDef(OT.OP_DROPOUT, _dropout_infer, _dropout_forward))
+# row-wise as a serving step runs it: the identity outside training
+register_op(OpDef(OT.OP_DROPOUT, _dropout_infer, _dropout_forward,
+                  row_wise=True))
 
 
 # ---------------------------------------------------------------- BatchMatmul

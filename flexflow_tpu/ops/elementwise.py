@@ -98,7 +98,7 @@ def _binary_forward(params, inputs, weights, state, ctx):
 
 
 for _ot in list(_UNARY_FNS) + list(_SCALAR_FNS):
-    register_op(OpDef(_ot, _unary_infer, _unary_forward))
+    register_op(OpDef(_ot, _unary_infer, _unary_forward, row_wise=True))
 
 for _ot in _BINARY_FNS:
     register_op(OpDef(_ot, _binary_infer, _binary_forward))

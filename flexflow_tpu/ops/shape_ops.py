@@ -151,7 +151,7 @@ def _cast_forward(p: CastParams, inputs, weights, state, ctx):
     return [inputs[0].astype(dtype_to_jnp(p.dtype))], state
 
 
-register_op(OpDef(OT.OP_CAST, _cast_infer, _cast_forward))
+register_op(OpDef(OT.OP_CAST, _cast_infer, _cast_forward, row_wise=True))
 
 
 # ---------------------------------------------------------------- Gather

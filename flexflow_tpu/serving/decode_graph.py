@@ -332,6 +332,9 @@ def build_decode_model(model, spec: ServingSpec):
     dec.compile(optimizer=SGDOptimizer(lr=0.0),
                 loss_type=LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY,
                 comp_mode=CompMode.COMP_MODE_INFERENCE)
+    # what the step reads its layout from (Executor.build_decode_step):
+    # the call's rows beside these slots, a dead row's position, max_seq
+    dec.executor.decode_context = ctx
     return dec, max_seq
 
 

@@ -351,6 +351,10 @@ class ServingEngine:
         self._row_steps = 0  # of those, the ones laid out as rows
         # and of those, the ones whose chunk the paged chunk kernel took
         self._chunk_kernel_steps = 0
+        # rows of the dispatched steps, and those that went through the
+        # graph's row-wise tail (the final norm and the head)
+        self._step_rows = 0
+        self._head_rows = 0
         self._device_s = 0.0
         self._last_step_device_s = 0.0  # most recent device call's wall
         # ffpulse metrics plane: engine-owned registry so serving metrics
@@ -480,6 +484,15 @@ class ServingEngine:
         return all(
             s.chunk_as_rows and s.chunk_as_rows(mesh, self._kv_itemsize)
             for group in self._groups for s in group.values())
+
+    def _head_rows_of(self, rows: int, q: int) -> int:
+        """Rows of a `(rows, q)` step that go through the graph's
+        row-wise tail, the final norm and the head
+        (Executor.build_decode_step): the slots' and a chunk's last one;
+        every row where the graph has no such tail."""
+        if not self.decode_model.executor.decode_tail()[0]:
+            return rows * q
+        return self.spec.slots + (rows > self.spec.slots)
 
     def _chunk_query_tile(self, b: int) -> Optional[int]:
         """Query rows a tile of the paged chunk kernel takes of a chunk
@@ -1276,6 +1289,8 @@ class ServingEngine:
                 self._step_ids += 1
                 self._steps += 1
                 self._steps_ahead += prev is not None
+                self._step_rows += step.tokens.size
+                self._head_rows += step.span[1]["head_rows"]
                 self._dispatch(step)
                 with telemetry.span("serve.advance", **step.tag):
                     self._advance(step)
@@ -1410,7 +1425,8 @@ class ServingEngine:
             # dispatched or when it is fetched (`_span_of`)
             load = dict(kv_rows=int(kv_rows),
                         kv_itemsize=self._kv_itemsize,
-                        admitted=len(admitted), pending=sched.queue_depth)
+                        admitted=len(admitted), pending=sched.queue_depth,
+                        head_rows=self._head_rows_of(rows, q))
             if by_rows:
                 load.update(rows=rows, kv_rows_walked=int(kv_rows_walked))
             if self._window_nodes:
@@ -1672,6 +1688,8 @@ class ServingEngine:
         self._prefill_calls = 0
         self._row_steps = 0
         self._chunk_kernel_steps = 0
+        self._step_rows = 0
+        self._head_rows = 0
         self._under_window = 0
         self._counted = {}
         self._state_resets = 0
@@ -1761,6 +1779,10 @@ class ServingEngine:
             # them where the paged kernel serves the rows, else none
             "row_steps": self._row_steps,
             "chunk_kernel_steps": self._chunk_kernel_steps,
+            # the rows those steps carried, and the rows that went on
+            # through the final norm, the head and the sampler
+            "step_rows": self._step_rows,
+            "head_rows": self._head_rows,
             "wall_s": wall,
             "device_s": self._device_s,
             "plan_source": self.decode_model._plan_source,
