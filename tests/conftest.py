@@ -10,7 +10,7 @@ tier-1 command sets it; it is defaulted here so a bare `pytest` does the
 same). Pallas kernels run in interpret mode on this backend; what only the
 TPU's compiler can refuse is covered by tests/test_chip_compile.py, which
 compiles the main path's kernels ahead of time for a described v5e. Nothing
-here runs on a chip — that is chip_smoke.py's job.
+here runs on a chip — that is benchmarks/run.py's job.
 """
 
 import os

@@ -3,11 +3,15 @@ tests/python_interface_test.sh): the one-command runner trains a zoo model
 in both AE modes on the virtual mesh, prints machine-readable results, and
 enforces the MNIST accuracy gate."""
 
+import os
 import sys
+
+# scripts/ lies beside tests/, and only tests/ is on pytest's path
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
 
 
 def test_ae_runner_mlp_both_modes():
-    sys.path.insert(0, "/root/repo")
     from scripts.run_ae import run_one
 
     dp = run_one("mlp", "dp", batch=64, epochs=2)
@@ -28,6 +32,6 @@ def test_ae_runner_rejects_unknown_model():
 
     p = subprocess.run(
         [sys.executable, "scripts/run_ae.py", "--models", "nope"],
-        capture_output=True, text=True, cwd="/root/repo")
+        capture_output=True, text=True, cwd=ROOT)
     assert p.returncode != 0
     assert "unknown model" in p.stderr

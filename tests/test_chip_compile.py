@@ -931,7 +931,7 @@ def test_stage3_step_gathers_each_weight_once_in_bf16(topology, monkeypatch):
     ff = FFModel(FFConfig())
     cfg = TransformerLMConfig(
         vocab_size=2048, hidden_size=1024, num_heads=8, num_layers=3,
-        sequence_length=256, attention_impl="xla")
+        sequence_length=64, attention_impl="xla")
     build_transformer_lm(ff, cfg, batch_size=4)
     ff.compile(optimizer=AdamOptimizer(),
                loss_type=LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY)
@@ -939,7 +939,7 @@ def test_stage3_step_gathers_each_weight_once_in_bf16(topology, monkeypatch):
     assert len(weights) == 53
 
     found = _param_gathers(
-        _step_text_for_four_chips(ff, topology, monkeypatch, 4, 256))
+        _step_text_for_four_chips(ff, topology, monkeypatch, 4, 64))
     assert set(found["executed"]) == weights
     assert set(found["executed"].values()) == {1}, found["executed"]
     assert set(found["dtypes"]) == {"bf16"}, found["dtypes"]

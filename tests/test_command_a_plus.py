@@ -30,6 +30,7 @@ from flexflow_tpu.ops.base import OpContext, get_op_def
 
 # logits of a sequence through the decode graph's hand-made tables of both
 # groups: the helper is the sibling's, it reads nothing of the model
+import small_lms  # noqa: E402
 from test_mimo_v2_flash_serving import decode_graph_logits  # noqa: E402
 
 # hidden 64; 8 query heads of 16 (a query projection of 128) on 2 KV heads;
@@ -386,8 +387,10 @@ def test_the_tie_goes_through_compile_and_through_the_decode_graph(model):
 # ------------------------------------------------------------------- serving
 
 def serve(ff, **kw):
-    return ff.serve(**{**dict(slots=3, max_seq_len=SEQ, prefill_chunk=8,
-                              kv_block_size=4, kv_num_blocks=48), **kw})
+    """The shared engine of these options (tests/small_lms.py), as new."""
+    return small_lms.engine(ff, **{**dict(
+        slots=3, max_seq_len=SEQ, prefill_chunk=8, kv_block_size=4,
+        kv_num_blocks=48), **kw})
 
 
 def is_greedy(ff, prompt, reply) -> bool:

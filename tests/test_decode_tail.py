@@ -14,6 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import small_lms
+
 SLOTS, MAX_SEQ, VOCAB = 3, 32, 64
 SERVE = dict(slots=SLOTS, max_seq_len=MAX_SEQ, prefill_chunk=8,
              kv_layout="paged", kv_block_size=4)
@@ -308,15 +310,13 @@ def test_apply_as_the_benchmark_calls_it_returns_every_row(engine):
 
 
 @pytest.mark.parametrize("layout", ["rectangle", "rows"])
-def test_stats_count_the_rows_through_the_head(layout):
+def test_stats_count_the_rows_through_the_head(layout, monkeypatch):
     """A closed loop of three requests: `step_rows` sums the rows of the
     dispatched steps, `head_rows` the rows that went through the tail,
     and the step's span carries its own."""
-    from test_serving import ROWS, ROWS_SEQ, _build_rows_lm
-
     if layout == "rows":
-        eng = _build_rows_lm().serve(slots=2, max_seq_len=ROWS_SEQ,
-                                     prefill_chunk=8, **ROWS)
+        eng = small_lms.engine(small_lms.build_rows_lm(), slots=2,
+                               prefill_chunk=8, **small_lms.ROWS)
     else:
         eng = _engine("untied")
     slots = eng.spec.slots
@@ -329,7 +329,7 @@ def test_stats_count_the_rows_through_the_head(layout):
             shapes.append((step.tokens.shape, step.span[1]["head_rows"]))
         return step
 
-    eng._schedule = spy
+    monkeypatch.setattr(eng, "_schedule", spy)
     got = eng.generate([PROMPT, PROMPT[:3], PROMPT[2:9]], max_new_tokens=4)
     assert all(len(g) == 4 for g in got)
     stats = eng.stats()

@@ -28,6 +28,7 @@ from flexflow_tpu.serving.paged import BlockManager, window_slot_blocks
 
 # logits of a sequence through the decode graph's hand-made tables of both
 # groups: the helper is the sibling's, it reads nothing of the model
+import small_lms  # noqa: E402
 from test_mimo_v2_flash_serving import decode_graph_logits  # noqa: E402
 
 PUBLISHED = dict(
@@ -111,7 +112,8 @@ def forward(ff, tokens):
 
 
 def serve(ff, **kw):
-    return ff.serve(**{**dict(
+    """The shared engine of these options (tests/small_lms.py), as new."""
+    return small_lms.engine(ff, **{**dict(
         slots=3, max_seq_len=SEQ, prefill_chunk=8, kv_block_size=8,
         kv_num_blocks=40, kv_window_blocks=40, max_new_tokens=4), **kw})
 
@@ -363,6 +365,7 @@ def test_the_kernels_two_calls_and_their_merge_are_the_reference():
     ff = build(config, seq=2048, batch=1)
     toks = np.random.default_rng(3).integers(0, 67, (600,)).astype(np.int32)
     want = ref.forward(getter(ff), toks, config, row_block=128)
+    # serve(): the model is this test's alone
     eng = ff.serve(slots=2, max_seq_len=2048, prefill_chunk=48,
                    kv_block_size=128, kv_num_blocks=40, kv_window_blocks=40,
                    max_new_tokens=4, impl="flash")
@@ -379,7 +382,8 @@ def test_a_prefix_from_the_radix_cache_gives_a_cold_prefills_tokens(
     for length in (32, 37):
         history = [int(t) for t in tokens[0][:length]]
         prompt = history + [int(t) for t in tokens[1][:9]]
-        warm = serve(model, max_new_tokens=12)
+        cold = serve(model, max_new_tokens=12).generate([prompt])[0]
+        warm = serve(model, max_new_tokens=12)      # as new: nothing cached
         warm.generate([history], max_new_tokens=1)
         mgr = warm.block_manager
         pinned = len(mgr._wpins)
@@ -387,7 +391,6 @@ def test_a_prefix_from_the_radix_cache_gives_a_cold_prefills_tokens(
         assert mgr.match_prefix(prompt) == length
         got = warm.generate([prompt])[0]
         assert warm.stats()["prefix_shared_tokens"] == length
-        cold = serve(model, max_new_tokens=12).generate([prompt])[0]
         assert got == cold
         # teacher-forced: every token is the argmax of the row before it
         logits = ref.forward(getter(model), prompt + got[:-1], TINY).logits

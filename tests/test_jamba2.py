@@ -22,6 +22,7 @@ from flexflow_tpu.models import (
 )
 
 # the same vocabulary of 97 and the same drive of the decode graph
+import small_lms
 from test_solar_open2 import decode_logits, error, getter, prompts
 
 # hidden 64, inner 128, state 16, dt rank 8, 4 taps; 4 query heads over 1
@@ -65,22 +66,26 @@ def model():
     return build()
 
 
+SERVE = dict(slots=3, max_seq_len=SEQ, prefill_chunk=8, kv_block_size=4,
+             kv_num_blocks=40)
+
+
 def serve(ff, **kw):
-    return ff.serve(**{**dict(slots=3, max_seq_len=SEQ, prefill_chunk=8,
-                              kv_block_size=4, kv_num_blocks=40), **kw})
+    """The shared engine of these options (tests/small_lms.py), as new."""
+    return small_lms.engine(ff, **{**SERVE, **kw})
 
 
-def greedy_by_the_reference(ff, prompt, new, config=TINY, seq=SEQ):
-    """The reference's own greedy continuation, a full forward a token
-    (padded to one length: causal, the tail is unseen; one compile)."""
-    out = list(prompt)
-    for _ in range(new):
-        padded = np.zeros((seq,), np.int32)
-        padded[:len(out)] = out
-        logits, _ = ref.forward(getter(ff), padded, config,
-                                rows=[len(out) - 1])
-        out.append(int(np.argmax(logits[0])))
-    return out[len(prompt):]
+def is_greedy(ff, prompt, reply, new, config=TINY, seq=SEQ) -> bool:
+    """Whether `reply` is the reference's own greedy continuation of
+    `prompt` by `new` tokens: one forward over both (padded to one length:
+    causal, a row's logits depend on no later token; one compile), each
+    reply token the argmax of the row before it."""
+    padded = np.zeros((seq,), np.int32)
+    padded[:len(prompt) + new - 1] = [*prompt, *reply[:-1]]
+    logits, _ = ref.forward(
+        getter(ff), padded, config,
+        rows=range(len(prompt) - 1, len(prompt) + new - 1))
+    return np.argmax(logits, axis=-1).tolist() == reply
 
 
 # ------------------------------------------------------------------ the scan
@@ -288,7 +293,8 @@ def test_prefill_in_chunks_then_decode_gives_the_references_logits(model):
     and 3, then 6 decoded tokens; every call's last row against the
     reference's full forward over the whole sequence, and the slot's h and
     convolution tail against the reference's after the last token."""
-    engine = serve(model)
+    # serve(): a new engine, whose other slots no call has touched
+    engine = model.serve(**SERVE)
     prompt = prompts(1, [19])[0]
     rows, call = decode_logits(engine, prompt, [8, 8, 3], 0)
     seq = list(prompt)
@@ -318,8 +324,8 @@ def test_serve_decodes_what_the_reference_decodes(model):
     # six state-space layers: 16 x 128 float32 and 3 x 128 a slot
     assert st["state_bytes"] == 3 * 6 * (16 * 128 * 4 + 3 * 128 * 4)
     for prompt in prompts(2, [19, 5]):
-        assert engine.generate([prompt], max_new_tokens=6)[0] == \
-            greedy_by_the_reference(model, prompt, 6)
+        (reply,) = engine.generate([prompt], max_new_tokens=6)
+        assert is_greedy(model, prompt, reply, 6)
     assert engine.stats()["state_resets"] == 2
     assert not engine.spec.prefix_cache and not engine.spec.prefix_sharing
 
@@ -333,7 +339,7 @@ def test_an_interleaved_batch_equals_each_request_alone(model):
     together = serve(model).generate(ps, max_new_tokens=7)
     assert together[3] == serve(model).generate([ps[3]], max_new_tokens=7)[0]
     for p, got in zip(ps, together):
-        assert got == greedy_by_the_reference(model, p, 7)
+        assert is_greedy(model, p, got, 7)
 
 
 def test_a_reused_slot_starts_from_nothing(model):
@@ -346,9 +352,10 @@ def test_a_reused_slot_starts_from_nothing(model):
     engine.run_until_drained()
     assert engine.stats()["steps_ahead"] > 0
     assert engine.stats()["state_resets"] == 2
-    fresh = serve(model, slots=1, kv_num_blocks=12)
+    # serve(): a new engine, whose slot has held nothing
+    fresh = model.serve(**{**SERVE, "slots": 1, "kv_num_blocks": 12})
     assert second.generated == fresh.generate([b], max_new_tokens=5)[0]
-    assert first.generated == greedy_by_the_reference(model, a, 5)
+    assert is_greedy(model, a, first.generated, 5)
 
 
 def test_a_chunk_as_rows_equals_the_rectangle():
@@ -363,8 +370,9 @@ def test_a_chunk_as_rows_equals_the_rectangle():
                mamba_expand=1, mamba_dt_rank=4, intermediate_size=64)
     ff = build(big, seq=128, batch=1)
     ps = prompts(3, [13, 21, 6], seed=5)
-    kw = dict(slots=2, max_seq_len=128, prefill_chunk=8, kv_block_size=8,
+    kw = dict(slots=2, max_seq_len=128, prefill_chunk=8, kv_block_size=16,
               kv_num_blocks=40)
+    # serve(), twice: the model is this test's alone
     rows = ff.serve(impl="flash", **kw)
     assert rows._chunk_rows
     got = rows.generate(ps, max_new_tokens=4)
@@ -372,7 +380,7 @@ def test_a_chunk_as_rows_equals_the_rectangle():
     rect = ff.serve(impl="xla", **kw)
     assert not rect._chunk_rows
     assert got == rect.generate(ps, max_new_tokens=4)
-    assert got[1] == greedy_by_the_reference(ff, ps[1], 4, big, 128)
+    assert is_greedy(ff, ps[1], got[1], 4, big, 128)
 
 
 def test_a_steps_span_carries_the_state_it_moves(model):

@@ -21,6 +21,7 @@ from flexflow_tpu.models import (
 )
 from flexflow_tpu.ops.attention import AttentionFrontEnd, Indexer
 from flexflow_tpu.ops.base import OpContext, get_op_def
+from small_lms import engine
 
 # hidden 64; 4 query heads over 2 KV heads of 16; an indexer of 4 heads of
 # 8 that keeps 12 positions; 16 experts of 24, 4 a token, none shared
@@ -79,8 +80,9 @@ def error(got, want):
 
 
 def serve(ff, **kw):
-    return ff.serve(**{**dict(slots=3, max_seq_len=SEQ, prefill_chunk=8,
-                              kv_block_size=4, kv_num_blocks=48), **kw})
+    """The shared engine of these options (tests/small_lms.py), as new."""
+    return engine(ff, **{**dict(slots=3, max_seq_len=SEQ, prefill_chunk=8,
+                                kv_block_size=4, kv_num_blocks=48), **kw})
 
 
 def forward(ff, tokens):
@@ -333,34 +335,39 @@ def test_a_context_of_topk_or_fewer_equals_the_grouped_paged_op(impl):
                for w in specs_p if not w.trainable}
     table = (1 + np.arange(4 * width, dtype=np.int32)).reshape(4, width)
     # fill four sequences' caches to 56, 4, 30 and 63 rows, a token a call
+    # (of one program an op: the calls have one shape)
     lengths = [56, 4, 30, 63]
     xs = rng.normal(size=(4, 72, embed)).astype(np.float32)
 
-    def call(p, state, x, positions, row_table):
-        (y,), new = op.forward(
-            p, [jnp.asarray(x)[:, None], jnp.asarray(positions)[:, None],
-                jnp.asarray(row_table)],
-            {**{k: v for k, v in weights.items()
-                if k in {w.name for w in (specs_i if p is p_i else specs_p)}},
-             **state}, None, ctx)
-        return np.asarray(y[:, 0]), {**state, **new}
+    def program(p, specs):
+        mine = {w.name: weights[w.name] for w in specs if w.trainable}
 
+        @jax.jit
+        def run(state, x, positions, row_table):
+            (y,), new = op.forward(
+                p, [x[:, None], positions[:, None], row_table],
+                {**mine, **state}, None, ctx)
+            return y[:, 0], {**state, **new}
+
+        def call(state, x, positions, row_table):
+            y, state = run(state, jnp.asarray(x), jnp.asarray(positions),
+                           jnp.asarray(row_table))
+            return np.asarray(y), state
+
+        return call
+
+    call_i, call_p = program(p_i, specs_i), program(p_p, specs_p)
     for t in range(max(lengths)):
         pos = np.array([t if t < n else 10**6 for n in lengths], np.int32)
-        for p, name in ((p_i, "i"), (p_p, "p")):
-            state = state_i if name == "i" else state_p
-            _, state = call(p, state, xs[:, t], pos, table)
-            if name == "i":
-                state_i = state
-            else:
-                state_p = state
+        _, state_i = call_i(state_i, xs[:, t], pos, table)
+        _, state_p = call_p(state_p, xs[:, t], pos, table)
     # one step: slots 0-2 decode at their next position, the chunk's 8
     # rows continue sequence 3 from 63 to 70 (its first row sees 64)
     x = np.concatenate([xs[:3, 64], xs[3, 63:71]])
     pos = np.array([56, 4, 30, *range(63, 71)], np.int32)
     row_table = table[[0, 1, 2] + [3] * chunk]
-    got, state_i = call(p_i, state_i, x, pos, row_table)
-    want, _ = call(p_p, state_p, x, pos, row_table)
+    got, state_i = call_i(state_i, x, pos, row_table)
+    want, _ = call_p(state_p, x, pos, row_table)
     scale = np.max(np.abs(want))
     assert np.max(np.abs(got[:4] - want[:4])) < 1e-5 * scale
     # the chunk's later rows see 65 to 71 positions and keep 64 of them
@@ -396,11 +403,11 @@ def test_a_prefix_cache_hit_gives_the_logits_a_miss_gives(model, tokens):
 def test_an_interleaved_batch_equals_each_request_alone(model):
     rng = np.random.default_rng(7)
     ps = [rng.integers(0, 97, n).tolist() for n in (19, 3, 27, 8, 14)]
-    together = serve(model).generate(ps, max_new_tokens=7)
+    eng = serve(model)
+    together = eng.generate(ps, max_new_tokens=7)
+    assert eng.stats()["moe_dropped"] == 0
     for p, got in zip(ps, together):
         assert got == serve(model).generate([p], max_new_tokens=7)[0]
-    stats = serve(model).stats()
-    assert stats["moe_dropped"] == 0
 
 
 # ------------------------------------------------------------ the front end
@@ -557,11 +564,11 @@ def test_what_the_indexer_pool_cannot_follow_is_refused(model, how):
         with pytest.raises(NotImplementedError, match="paged pool only"):
             serve(model, kv_layout="contiguous")
     else:
-        engine = serve(model)
+        eng = serve(model)
         with pytest.raises(NotImplementedError, match=match) as e:
             if how == "extract_kv":
-                engine.extract_kv(0, 4)
+                eng.extract_kv(0, 4)
             else:
-                engine.admit_prefilled(None, 0, None, None)
+                eng.admit_prefilled(None, 0, None, None)
         assert "serving/engine.py" in str(e.value) and "l0_attn" in str(
             e.value)

@@ -19,6 +19,7 @@ from flexflow_tpu.models import (
     TransformerLMConfig, build_transformer_lm, deepseek_v32_lm_config,
     deepseek_v32_reference as ref,
 )
+import small_lms
 
 # hidden 64, 4 heads, latent 32, rotary 8, indexer 2 x 16, top-k 8, 16
 # experts in 4 groups of which 2 are kept, one dense layer and two expert
@@ -133,9 +134,10 @@ def test_absorbed_equals_expanded(model, tokens):
 
 
 def serve(ff, **kw):
+    """The shared engine of these options (tests/small_lms.py), as new."""
     spec = dict(slots=4, max_seq_len=32, prefill_chunk=8, kv_block_size=4,
                 kv_num_blocks=64)
-    return ff.serve(**{**spec, **kw})
+    return small_lms.engine(ff, **{**spec, **kw})
 
 
 def decode_graph_logits(eng, seq, split, slot=1):

@@ -16,6 +16,7 @@ import pytest
 from flexflow_tpu.fftype import OperatorType as OT
 from flexflow_tpu.models import mimo_v2_flash_reference as ref
 
+from small_lms import engine
 from test_mimo_v2_flash import SEQ, TINY, TOL, build, error, getter
 
 
@@ -25,16 +26,17 @@ def model():
 
 
 def serve(ff, **kw):
-    return ff.serve(**{**dict(slots=3, max_seq_len=SEQ, prefill_chunk=8,
-                              kv_block_size=4, kv_num_blocks=48), **kw})
+    """The shared engine of these options (tests/small_lms.py), as new."""
+    return engine(ff, **{**dict(slots=3, max_seq_len=SEQ, prefill_chunk=8,
+                                kv_block_size=4, kv_num_blocks=48), **kw})
 
 
-def greedy(ff, prompt, new, config=TINY):
-    seq = list(prompt)
-    for _ in range(new):
-        logits, _ = ref.forward(getter(ff), seq, config)
-        seq.append(int(np.argmax(logits[-1])))
-    return seq[len(prompt):]
+def is_greedy(ff, prompt, reply) -> bool:
+    """Whether `reply` is the reference's greedy continuation of `prompt`:
+    one forward over both (a row's logits depend on no later token), each
+    reply token the argmax of the row before it."""
+    logits, _ = ref.forward(getter(ff), [*prompt, *reply[:-1]], TINY)
+    return np.argmax(logits[len(prompt) - 1:], axis=-1).tolist() == reply
 
 
 def decode_graph_logits(eng, seq, split, slot=1, held=None):
@@ -188,7 +190,7 @@ def generated(model):
     prompts = [rng.integers(0, 97, n).tolist() for n in (21, 9, 30)]
     out = eng.generate(prompts, max_new_tokens=8)
     for p, o in zip(prompts, out):
-        assert o == greedy(model, p, 8)
+        assert len(o) == 8 and is_greedy(model, p, o)
     mgr = eng.block_manager
     mgr.window.check_invariants()
     st = eng.stats()
@@ -219,7 +221,7 @@ def test_a_prompt_hits_its_cached_prefix_over_both_groups(model):
     before = eng.stats()["prefix_hit_tokens"]
     follow = prompts[0] + [5, 6, 7]
     out = eng.generate([follow], max_new_tokens=4)
-    assert out[0] == greedy(model, follow, 4)
+    assert len(out[0]) == 4 and is_greedy(model, follow, out[0])
     assert eng.scheduler.completed[-1].matched_prefix_len == 21
     assert eng.stats()["prefix_hit_tokens"] - before == 21
     # the shared tail block (rows 20..) was copied in both groups
@@ -273,11 +275,11 @@ def test_the_scopes_name_the_two_kinds_of_core(model):
 def test_the_contiguous_layout_serves_under_the_band(model):
     """No window group there: the cache holds every row, the einsum masks
     the band. Said in docs/serving.md."""
-    eng = model.serve(slots=2, max_seq_len=SEQ, prefill_chunk=8,
-                      kv_layout="contiguous")
+    eng = engine(model, slots=2, max_seq_len=SEQ, prefill_chunk=8,
+                 kv_layout="contiguous")
     prompt = np.random.default_rng(4).integers(0, 97, 17).tolist()
-    assert eng.generate([prompt], max_new_tokens=6)[0] == greedy(
-        model, prompt, 6)
+    (reply,) = eng.generate([prompt], max_new_tokens=6)
+    assert len(reply) == 6 and is_greedy(model, prompt, reply)
     state = eng.decode_model._state["l1_attn"]
     assert state["cache_k"].shape == (2, SEQ + 1, 48)
     assert state["cache_v"].shape == (2, SEQ + 1, 32)

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import small_lms
 import test_latent_attention as dsv32
 from flexflow_tpu.fftype import DataType, OperatorType as OT
 from flexflow_tpu.models import (
@@ -56,9 +57,10 @@ def tokens():
 
 
 def serve(ff, **kw):
+    """The shared engine of these options (tests/small_lms.py), as new."""
     spec = dict(slots=4, max_seq_len=32, prefill_chunk=8, kv_block_size=4,
                 kv_num_blocks=64)
-    return ff.serve(**{**spec, **kw})
+    return small_lms.engine(ff, **{**spec, **kw})
 
 
 def test_the_query_scale_takes_three_values_inside_a_sequence():

@@ -16,6 +16,7 @@ from flexflow_tpu.fftype import DataType, OperatorType as OT
 from flexflow_tpu.ops import inc_attention as inc
 from flexflow_tpu.ops.attention import AttentionFrontEnd
 from flexflow_tpu.ops.base import OpContext, get_op_def
+from small_lms import ROWS, build_rows_lm, engine
 
 fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
 
@@ -95,26 +96,30 @@ def _calls(fn, *args) -> dict:
 
 
 @pytest.fixture
-def rounds_of_128_rows(monkeypatch):
-    """The cases below are laid out around rounds of 128 rows (a chunk that
-    crosses a round, a window whose walk leaves round 0 out), which is what
-    the rule of bytes gives at c13b-serve-chat's rows, 8 pages of 16. At
-    these tests' narrow rows it answers the whole table, one round (the
-    tests that do not ask for this fixture run so)."""
+def rounds_of_64_rows(monkeypatch):
+    """The cases below are laid out around rounds of 64 rows (a chunk that
+    crosses a round, a window whose walk leaves the first rounds out). At
+    these tests' narrow rows the rule of bytes answers the whole table, one
+    round (the tests that do not ask for this fixture run so; at
+    c13b-serve-chat's rows it gives 128, 8 pages of 16, which
+    tests/test_chip_compile.py compiles). Four pages of 16 and not eight:
+    the interpreter's lowering of a kernel is paid by the page, a DMA each
+    for keys and values, and these cases are most of this file's time."""
     monkeypatch.setattr(fa, "_paged_round_pages",
-                        lambda block_size, *a, **kw: max(1, 128 // block_size))
+                        lambda block_size, *a, **kw: max(1, 64 // block_size))
 
 
-# a chunk of 32 at 200 over 16 blocks of 16 (rounds of 128 rows), under
-# each kind of window: shorter than `start` (the walk leaves out round 0),
-# longer than the whole context, and beginning inside the first round
+# a chunk of 32 at 200 over 16 blocks of 16 (rounds of 64 rows), under
+# each kind of window: shorter than `start` (the walk leaves out rounds 0
+# and 1), longer than the whole context, and beginning inside the first
+# round
 _WINDOWS = {"short": (200, 40), "long": (200, 1000), "inside": (100, 60)}
 _CASES = [
     (16, 16, 0, 16, 16, 2, 2, 128, 0),     # a prompt's first chunk
     (32, 32, 37, 16, 16, 2, 2, 128, 0),    # start no multiple of a block
     (32, 32, 120, 16, 16, 2, 2, 128, 0),   # the chunk crosses a round
     (32, 20, 100, 16, 16, 2, 2, 128, 0),   # n < b: dead padding rows
-    (16, 9, 200, 8, 32, 4, 4, 64, 0),      # blocks of 8, 16 pages a round
+    (16, 9, 200, 8, 32, 4, 4, 64, 0),      # blocks of 8, 8 pages a round
     (16, 16, 250, 256, 2, 2, 2, 128, 0),   # blocks of 256: a page a round
     (16, 11, 130, 16, 16, 16, 2, 64, 0),   # group 8: 16 query heads, 2 KV
     (32, 32, 300, 256, 2, 8, 1, 128, 0),   # group 8 over blocks of 256
@@ -130,12 +135,12 @@ for _kind, (_start, _window) in _WINDOWS.items():
     for _group in (1, 16):
         _CASES.append((32, 30, _start, 16, 16, 16, 16 // _group, 64, _window))
         _IDS.append(f"window-{_kind}-group{_group}")
-_CASES += [(32, 30, 200, 16, 16, 8, 2, 64, 40),     # group 4, round 0 left
+_CASES += [(32, 30, 200, 16, 16, 8, 2, 64, 40),     # group 4, rounds left
            (256, 200, 140, 16, 32, 8, 1, 64, 100)]  # two tiles, two firsts
 _IDS += ["window-short-group4", "window-two-query-tiles-group8"]
 
 
-@pytest.mark.usefixtures("rounds_of_128_rows")
+@pytest.mark.usefixtures("rounds_of_64_rows")
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("b,n,start,bs,W,H,KV,hd,window", _CASES, ids=_IDS)
 def test_chunk_kernel_matches_the_oracle_and_the_single_query_kernel(
@@ -185,17 +190,17 @@ def test_chunk_kernel_under_rounds_of_the_rules_own_size(
     assert not out[~live].any()
 
 
-@pytest.mark.usefixtures("rounds_of_128_rows")
+@pytest.mark.usefixtures("rounds_of_64_rows")
 def test_a_window_walk_starts_at_each_query_tiles_own_round():
     """The lowest key a query tile attends is its own: of two tiles of a
-    chunk of 200 at 140 under a window of 100, over rounds of 128 rows,
-    the first starts at round 0 (key 41) and the second at round 1 (key
+    chunk of 200 at 140 under a window of 100, over rounds of 64 rows,
+    the first starts at round 0 (key 41) and the second at round 2 (key
     169), and the kernel is named for its walk."""
     case = _chunk_case(256, 200, 140, 16, 32, 8, 1, 64, "float32",
                        window=100)
     lengths = np.asarray(case[-1]).reshape(2, 128)
     lo = [int(np.maximum(t[t > 0].min() - 100, 0)) for t in lengths]
-    assert [x // 128 for x in lo] == [0, 1]
+    assert [x // 64 for x in lo] == [0, 2]
     assert _calls(lambda *a: fa.paged_flash_chunk_attention(
         *a, num_heads=8, num_kv_heads=1, window=100), *case) == {
             "flash_attention_paged_chunk_window_grouped": 1}
@@ -219,7 +224,7 @@ def test_lengths_need_not_be_consecutive():
     np.testing.assert_allclose(out, single, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.usefixtures("rounds_of_128_rows")
+@pytest.mark.usefixtures("rounds_of_64_rows")
 def test_a_split_head_tile_reads_its_own_lanes(monkeypatch):
     """Where all the KV heads' buffers do not fit, a grid step takes some
     of them and the query heads that read them, from its own lanes of the
@@ -410,15 +415,11 @@ def test_the_engines_question_has_the_ops_answer():
 
 # -------------------------------------------------------------- the engine
 
-def _rows_engine(**kw):
-    from test_serving import ROWS, _build_rows_lm
-
-    return _build_rows_lm().serve(slots=2, max_new_tokens=3,
-                                  prefill_chunk=4, prefix_sharing=False,
-                                  **ROWS, **kw)
+ROWS_ENGINE = dict(slots=2, max_new_tokens=3, prefill_chunk=4,
+                   prefix_sharing=False, **ROWS)
 
 
-def _chunk_spans(eng, prompts):
+def _chunk_spans(eng, prompts, monkeypatch):
     """The `serve.prefill` span arguments of the chunk steps `prompts`
     take, as `_schedule` makes them."""
     spans, schedule = [], eng._schedule
@@ -429,7 +430,7 @@ def _chunk_spans(eng, prompts):
             spans.append(step.span[1])
         return step
 
-    eng._schedule = spy
+    monkeypatch.setattr(eng, "_schedule", spy)
     out = eng.generate(prompts)
     return out, spans
 
@@ -437,12 +438,12 @@ def _chunk_spans(eng, prompts):
 PROMPTS = [[3, 7, 11, 2, 5], [5, 2]]
 
 
-def test_engine_counts_the_steps_the_chunk_kernel_took():
+def test_engine_counts_the_steps_the_chunk_kernel_took(monkeypatch):
     """Every chunk step laid out as rows had its chunk's rows in one call
     of the chunk kernel, and its span counts the context rows once."""
-    eng = _rows_engine()
+    eng = engine(build_rows_lm(), **ROWS_ENGINE)
     assert eng._chunk_rows and eng._chunk_query_tile(4) == 16
-    out, spans = _chunk_spans(eng, PROMPTS)
+    out, spans = _chunk_spans(eng, PROMPTS, monkeypatch)
     st = eng.stats()
     assert st["chunk_kernel_steps"] == st["row_steps"] == 3
     assert [s["kv_rows_walked"] for s in spans] == [
@@ -451,7 +452,8 @@ def test_engine_counts_the_steps_the_chunk_kernel_took():
     assert eng.stats()["chunk_kernel_steps"] == 0
 
 
-def test_window_layers_beside_a_global_one_give_the_engine_one_answer():
+def test_window_layers_beside_a_global_one_give_the_engine_one_answer(
+        monkeypatch):
     """Three window layers and a global one (Command A+'s order, 8 query
     heads on 2 KV heads, a window of 8): every layer's chunk rows go
     through the chunk kernel, so the graph has ONE query tile, the steps
@@ -461,15 +463,15 @@ def test_window_layers_beside_a_global_one_give_the_engine_one_answer():
 
     ff = build(seq=128, batch=1)
     kw = dict(slots=2, max_new_tokens=3, max_seq_len=128, prefill_chunk=4,
-              prefix_sharing=False, kv_layout="paged", kv_block_size=8)
+              prefix_sharing=False, kv_layout="paged", kv_block_size=16)
     prompts = [[3, 7, 11, 2, 5, 9, 4, 8, 1, 6, 2, 3], [5, 2]]
-    want = ff.serve(**kw).generate(prompts)
-    eng = ff.serve(impl="flash", **kw)
+    want = engine(ff, **kw).generate(prompts)
+    eng = engine(ff, impl="flash", **kw)
     tiles = {s.chunk_query_tile(None, 4, 4)
              for group in eng._groups for s in group.values()}
     assert tiles == {32} and len(eng._groups) == 2
     assert eng._chunk_rows and eng._chunk_query_tile(4) == 32
-    out, spans = _chunk_spans(eng, prompts)
+    out, spans = _chunk_spans(eng, prompts, monkeypatch)
     assert out == want
     st = eng.stats()
     assert st["chunk_kernel_steps"] == st["row_steps"] == len(spans) >= 3
@@ -482,11 +484,13 @@ def test_engine_runs_refused_chunks_through_the_single_query_kernel(
     """A bucket the chunk kernel's gate refuses: the chunk still rides as
     rows, through the single-query kernel, the count stays 0, the span
     says what those rows walk, and the tokens are the same."""
-    want = _rows_engine().generate(PROMPTS)
+    ff = build_rows_lm()
+    want = engine(ff, **ROWS_ENGINE).generate(PROMPTS)
     monkeypatch.setattr(fa, "_PAGED_CHUNK_VMEM", 1000)
-    eng = _rows_engine()
+    # serve(): its buckets are traced under the budget patched above
+    eng = ff.serve(**ROWS_ENGINE)
     assert eng._chunk_rows and eng._chunk_query_tile(4) is None
-    out, spans = _chunk_spans(eng, PROMPTS)
+    out, spans = _chunk_spans(eng, PROMPTS, monkeypatch)
     assert out == want
     st = eng.stats()
     assert (st["chunk_kernel_steps"], st["row_steps"]) == (0, 3)

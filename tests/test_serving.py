@@ -19,78 +19,13 @@ kernel-parity tests, which run the kernels in interpret mode):
     UnitySearch.evaluate calls, zero joint_graph_optimize calls.
 """
 
-import sys
-
 import numpy as np
 import pytest
 
-
-def _lm_config(sequence_length=32):
-    from flexflow_tpu.models import TransformerLMConfig
-
-    return TransformerLMConfig(
-        vocab_size=64, hidden_size=32, num_heads=4, num_layers=2,
-        sequence_length=sequence_length, attention_impl="xla")
-
-
-def _build_lm(mesh=(1, 1, 1, 1), batch=8, argv=(), sequence_length=32):
-    sys.argv = ["test"] + list(argv)
-    from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
-    from flexflow_tpu.models import build_transformer_lm
-
-    cfg = FFConfig()
-    if cfg.mesh_axis_sizes is None:
-        cfg.mesh_axis_sizes = mesh
-    cfg.batch_size = batch
-    ff = FFModel(cfg)
-    build_transformer_lm(ff, _lm_config(sequence_length), batch_size=batch)
-    ff.compile(optimizer=SGDOptimizer(lr=0.01),
-               loss_type=LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY)
-    return ff
-
-
-# A chunk step's two batch layouts (docs/serving.md). The engine lays a
-# chunk out as single-query ROWS where the paged decode kernel serves a
-# (rows, 1) call: impl="flash" asks for the kernel on the CPU too (the
-# interpreter runs it), and it takes a cache of at least 128 rows in
-# blocks of a multiple of 8. Everywhere else the RECTANGLE (slots, q)
-# stays, as under the default impl on the CPU.
-ROWS_SEQ = 128
-ROWS = dict(impl="flash", kv_layout="paged", kv_block_size=8)
-
-
-def _build_rows_lm():
-    return _build_lm(batch=1, sequence_length=ROWS_SEQ)
-
-
-def _staged_shapes(eng):
-    """Record the (tokens shape, page-table shape) of every step `eng`
-    stages from here on, as the step's program is handed them."""
-    shapes, stage = [], eng._stage_step
-
-    def spy(step):
-        staged = stage(step)
-        table = staged[0].get("page_table")
-        shapes.append((staged[0][eng._token_input].shape,
-                       None if table is None else table.shape))
-        return staged
-
-    eng._stage_step = spy
-    return shapes
-
-
-def _complete_every_step_at_once(eng):
-    """Make `eng` the synchronous loop: a step function that hands its
-    tokens back on the host (a NumPy array) has its step completed
-    before the call that dispatched it returns (docs/serving.md)."""
-    step = eng._step_fn
-
-    def on_the_host(*args):
-        state, sampled = step(*args)
-        return state, np.asarray(sampled)
-
-    eng._step_fn = on_the_host
-    return eng
+from small_lms import (
+    PROMPTS, ROWS, SearchSpy, build_lm, build_rows_lm, engine, lm_config,
+    new_lm, staged_shapes,
+)
 
 
 def _teacher_argmax(ff, sequence):
@@ -106,15 +41,12 @@ def _teacher_argmax(ff, sequence):
     return np.asarray(jax.device_get(logits)).argmax(-1)[0]
 
 
-PROMPTS = [[3, 7, 11, 2, 5], [5, 2], [1, 9, 30, 30, 12, 4, 8], [60, 1, 2]]
-
-
 def test_greedy_decode_parity_vs_teacher_forced():
     """Every greedy-decoded token equals the training forward's argmax at
     that position, for prompts long and short of the prefill chunk (so
     both the bucketed prefill and the q=1 decode path are checked)."""
-    ff = _build_lm(batch=1)
-    eng = ff.serve(slots=2, max_new_tokens=8, prefill_chunk=4)
+    ff = build_lm(batch=1)
+    eng = engine(ff, slots=2, max_new_tokens=8, prefill_chunk=4)
     for prompt in PROMPTS:
         (gen,) = eng.generate([prompt])
         assert len(gen) == 8
@@ -125,7 +57,7 @@ def test_greedy_decode_parity_vs_teacher_forced():
 
 
 @pytest.mark.parametrize("layout", ["rectangle", "rows"])
-def test_continuous_batching_invariance(layout):
+def test_continuous_batching_invariance(layout, monkeypatch):
     """Interleaved batch == sequential single-request runs, token for
     token. Six requests through two slots forces mid-run admission and
     slot reuse (stale cache rows from the previous resident must never
@@ -135,14 +67,14 @@ def test_continuous_batching_invariance(layout):
     rows, the tokens are the rectangle engine's and the teacher-forced
     forward's, and the rectangle engine staged (slots, q) calls only."""
     rows = layout == "rows"
-    ff = _build_rows_lm() if rows else _build_lm(batch=1)
+    ff = build_rows_lm() if rows else build_lm(batch=1)
     kw = dict(slots=2, max_new_tokens=6, prefill_chunk=4,
               **(ROWS if rows else {}))
     prompts = PROMPTS + [[2, 4, 6, 8], list(range(1, 17))]
 
-    eng = ff.serve(**kw)
+    eng = engine(ff, **kw)
     assert eng._chunk_rows == rows
-    shapes = _staged_shapes(eng)
+    shapes = staged_shapes(eng, monkeypatch)
     interleaved = eng.generate(prompts)
     assert eng.scheduler.drained
     # two slots, six requests: admissions happened while others decoded
@@ -158,11 +90,11 @@ def test_continuous_batching_invariance(layout):
         assert st["row_steps"] == 0
         assert set(shapes) == {((2, q), (2, W)) for q in (1, 2, 4)}
 
-    solo_eng = ff.serve(**kw)
-    solo = [solo_eng.generate([p])[0] for p in prompts]
+    eng = engine(ff, **kw)     # as new: the interleaved run's blocks are gone
+    solo = [eng.generate([p])[0] for p in prompts]
     assert interleaved == solo
     if rows:
-        rect = ff.serve(**{**kw, "impl": "xla"})
+        rect = engine(ff, **{**kw, "impl": "xla"})
         assert not rect._chunk_rows
         assert rect.generate(prompts) == interleaved
         assert rect.stats()["row_steps"] == 0
@@ -193,11 +125,13 @@ def test_kv_cache_sharding_roundtrip_tp_mesh():
             }}
         return strat
 
-    ff1 = _build_lm(mesh=(1, 1, 1, 1), batch=1)
-    eng1 = ff1.serve(slots=4, max_new_tokens=5, prefill_chunk=4)
-    want = eng1.generate(PROMPTS[:2])
+    ff1 = build_lm(mesh=(1, 1, 1, 1), batch=1)
+    want = engine(ff1, slots=4, max_new_tokens=5,
+                  prefill_chunk=4).generate(PROMPTS[:2])
 
-    ff = _build_lm(mesh=(2, 2, 1, 1), batch=8)
+    ff = build_lm(mesh=(2, 2, 1, 1), batch=8)
+    # serve(), here and for the contiguous layout below: a strategy of
+    # dicts is no key for the shared engines
     eng = ff.serve(slots=4, max_new_tokens=5, prefill_chunk=4,
                    strategy=attn_strategy({
                        "pool_k": P(None, None, "model"),
@@ -224,8 +158,8 @@ def test_kv_cache_sharding_roundtrip_tp_mesh():
 def test_eos_and_max_len_completion():
     """All three completion rules: eos (stop token sampled), max_tokens
     (budget), and length (KV cache full)."""
-    ff = _build_lm(batch=1)
-    eng = ff.serve(slots=2, max_new_tokens=10, prefill_chunk=4)
+    ff = build_lm(batch=1)
+    eng = engine(ff, slots=2, max_new_tokens=10, prefill_chunk=4)
     prompt = PROMPTS[0]
     # discover what greedy generates, then replay with its second token
     # as the stop token
@@ -243,8 +177,8 @@ def test_eos_and_max_len_completion():
 
     # cache capacity: prompt of 6 into an 8-row cache leaves room to feed
     # 2 generated tokens back; the 3rd sampled token cannot be fed
-    small = ff.serve(slots=2, max_new_tokens=10, prefill_chunk=4,
-                     max_seq_len=8)
+    small = engine(ff, slots=2, max_new_tokens=10, prefill_chunk=4,
+                   max_seq_len=8)
     req3 = small.submit([1, 2, 3, 4, 5, 6])
     small.run_until_drained()
     assert req3.finish_reason == "length"
@@ -254,56 +188,24 @@ def test_eos_and_max_len_completion():
         small.submit(list(range(9)))
 
 
-class _SearchSpy:
-    """Counts UnitySearch.evaluate + joint_graph_optimize calls (the
-    test_warmstart.py hook, reused for the serving acceptance check)."""
-
-    def __enter__(self):
-        import flexflow_tpu.search.joint as joint
-        import flexflow_tpu.search.unity as unity
-
-        self.evals = 0
-        self.searches = 0
-        self._unity, self._joint = unity, joint
-        self._orig_eval = unity.UnitySearch.evaluate
-        self._orig_opt = joint.joint_graph_optimize
-        spy = self
-
-        def eval_spy(us, *a, **kw):
-            spy.evals += 1
-            return spy._orig_eval(us, *a, **kw)
-
-        def opt_spy(*a, **kw):
-            spy.searches += 1
-            return spy._orig_opt(*a, **kw)
-
-        unity.UnitySearch.evaluate = eval_spy
-        joint.joint_graph_optimize = opt_spy
-        return self
-
-    def __exit__(self, *exc):
-        self._unity.UnitySearch.evaluate = self._orig_eval
-        self._joint.joint_graph_optimize = self._orig_opt
-        return False
-
-
 def test_serving_warmstart_plan_cache_hit(tmp_path):
     """Second serving compile of the same (model, slots, max_seq, mesh)
     against one --warmstart-dir: plan_source=cache, 0 evaluate calls,
     0 searches, and token-identical output (the acceptance criterion)."""
     ws = str(tmp_path / "ws")
-    ff = _build_lm(mesh=(2, 4, 1, 1), batch=8,
-                   argv=["--only-data-parallel"])
+    ff = build_lm(mesh=(2, 4, 1, 1), batch=8,
+                  argv=["--only-data-parallel"])
     ov = dict(only_data_parallel=False, search_budget=4,
               enable_parameter_parallel=True,
               enable_attribute_parallel=True, warmstart_dir=ws)
     kw = dict(slots=8, max_new_tokens=4, prefill_chunk=4,
               config_overrides=ov)
+    # serve() throughout: each compile's plan source is what is asserted
     eng1 = ff.serve(**kw)
     assert eng1.decode_model._plan_source == "search"
     out1 = eng1.generate(PROMPTS[:2])
 
-    with _SearchSpy() as spy:
+    with SearchSpy() as spy:
         eng2 = ff.serve(**kw)
     assert spy.searches == 0, "serving plan-cache hit must not re-search"
     assert spy.evals == 0, "serving plan-cache hit must cost 0 evaluations"
@@ -312,7 +214,7 @@ def test_serving_warmstart_plan_cache_hit(tmp_path):
 
     # a different bucket geometry (slots) is a different decode graph —
     # it must NOT be served by the cached plan
-    with _SearchSpy() as spy:
+    with SearchSpy() as spy:
         eng3 = ff.serve(slots=4, max_new_tokens=4, prefill_chunk=4,
                         config_overrides=ov)
     assert eng3.decode_model._plan_source == "search"
@@ -323,7 +225,7 @@ def test_serving_telemetry_artifacts(tmp_path):
     """With a telemetry session attached, serving emits the serve.compile
     event (plan_source), per-request serve.request events with TTFT, and
     a serve.summary with requests/s/chip + decode tokens/s/chip."""
-    ff = _build_lm(batch=1)
+    ff = new_lm(batch=1)    # its own: the session is the model's
     ff.enable_telemetry(str(tmp_path / "tel"))
     eng = ff.serve(slots=2, max_new_tokens=4, prefill_chunk=4)
     eng.generate(PROMPTS[:3])
@@ -365,8 +267,8 @@ def test_decode_replay_signature(layout):
     from flexflow_tpu.fftype import OperatorType as OT
     from flexflow_tpu.serving import ServingSpec, build_decode_model
 
-    c = _lm_config()
-    ff = _build_lm(batch=1)
+    c = lm_config()
+    ff = build_lm(batch=1)
     dec, max_seq = build_decode_model(
         ff, ServingSpec(slots=2, kv_layout=layout))
     assert max_seq == c.sequence_length == 32
@@ -408,14 +310,14 @@ def test_paged_token_identical_to_contiguous():
     """The full continuous-batching run — ragged prompts, mid-run
     admission, slot reuse — is token-identical between the paged and
     contiguous layouts (the tentpole acceptance criterion)."""
-    ff = _build_lm(batch=1)
+    ff = build_lm(batch=1)
     prompts = PROMPTS + [[2, 4, 6, 8]]
-    paged = ff.serve(slots=2, max_new_tokens=6, prefill_chunk=4,
-                     kv_layout="paged")
+    paged = engine(ff, slots=2, max_new_tokens=6, prefill_chunk=4,
+                   kv_layout="paged")
     assert paged.block_manager is not None
     out_paged = paged.generate(prompts)
-    contig = ff.serve(slots=2, max_new_tokens=6, prefill_chunk=4,
-                      kv_layout="contiguous")
+    contig = engine(ff, slots=2, max_new_tokens=6, prefill_chunk=4,
+                    kv_layout="contiguous")
     assert contig.block_manager is None
     assert out_paged == contig.generate(prompts)
     # every completed request released its blocks exactly
@@ -432,18 +334,19 @@ def test_paged_cow_divergence_after_shared_prefix(layout):
     the copy lands before a step whose chunk rows all carry the copied
     table row."""
     rows = layout == "rows"
-    ff = _build_rows_lm() if rows else _build_lm(batch=1)
+    ff = build_rows_lm() if rows else build_lm(batch=1)
     bs = ROWS["kv_block_size"] if rows else 4
     kw = dict(slots=2, max_new_tokens=5, prefill_chunk=4,
               kv_layout="paged", kv_block_size=bs,
               **({"impl": "flash"} if rows else {}))
-    base = [3, 7, 11, 2, 5, 9, 13, 1, 17, 40, 6, 22, 8, 51, 33, 4]
+    base = [3, 7, 11, 2, 5, 9, 13, 1, 17, 40, 6, 22, 8, 51, 33, 4,
+            19, 44, 10, 27, 36, 15, 58, 23, 47, 12, 29, 61, 14, 38, 21, 50]
     # a block and a half shared: one full block + a registered PARTIAL
     # tail; the second prompt extends the prefix INSIDE that partial
     # block, so its first tail write must COW it
     shared = base[:bs + bs // 2]
     prompts = [list(shared), shared + [31, 32]]
-    eng = ff.serve(**kw)
+    eng = engine(ff, **kw)
     assert eng._chunk_rows == rows
     out = eng.generate(prompts)
     st = eng.block_manager.stats
@@ -453,24 +356,22 @@ def test_paged_cow_divergence_after_shared_prefix(layout):
         "divergence inside a shared block must copy-on-write"
     assert eng.stats()["chunk_kernel_steps"] == eng.stats()["row_steps"] == (
         eng.stats()["prefill_calls"] if rows else 0)
-    contig = ff.serve(slots=2, max_new_tokens=5, prefill_chunk=4,
-                      kv_layout="contiguous")
-    assert out == contig.generate(prompts)
+    contig = dict(slots=2, max_new_tokens=5, prefill_chunk=4,
+                  kv_layout="contiguous")
+    assert out == engine(ff, **contig).generate(prompts)
 
     # identical block-aligned prompts too (the N-users-one-system-prompt
     # case): the whole prompt is shared; only the final token is
     # recomputed and its write COWs the one block it lands in
     aligned = base[:2 * bs]  # 2 full blocks
-    eng2 = ff.serve(**kw)
+    eng2 = engine(ff, **kw)     # as new: the prompts above are forgotten
     same = [list(aligned), list(aligned)]
     out2 = eng2.generate(same)
     assert out2[0] == out2[1]
     st2 = eng2.block_manager.stats
     assert st2.shared_tokens >= len(aligned) - 1
     assert st2.cow_copies >= 1
-    contig2 = ff.serve(slots=2, max_new_tokens=5, prefill_chunk=4,
-                       kv_layout="contiguous")
-    assert out2 == contig2.generate(same)
+    assert out2 == engine(ff, **contig).generate(same)
 
 
 def test_paged_refcount_exact_reclamation():
@@ -500,9 +401,9 @@ def test_paged_refcount_exact_reclamation():
 
     # engine-level: a drained engine's pool is empty, and a second wave
     # reuses the reclaimed blocks without growth
-    ff = _build_lm(batch=1)
-    eng = ff.serve(slots=2, max_new_tokens=4, prefill_chunk=4,
-                   kv_layout="paged", kv_block_size=4)
+    ff = build_lm(batch=1)
+    eng = engine(ff, slots=2, max_new_tokens=4, prefill_chunk=4,
+                 kv_layout="paged", kv_block_size=4)
     eng.generate(PROMPTS)
     mgr = eng.block_manager
     assert mgr.blocks_in_use == 0
@@ -522,10 +423,10 @@ def test_chunked_prefill_interleaves_with_decode(layout):
     laid out as rows, where `_prefill_calls` still counts a chunk step
     once)."""
     rows = layout == "rows"
-    ff = _build_rows_lm() if rows else _build_lm(batch=1)
+    ff = build_rows_lm() if rows else build_lm(batch=1)
     kw = dict(slots=2, max_new_tokens=10, prefill_chunk=4,
               **(ROWS if rows else {"kv_layout": layout}))
-    eng = ff.serve(**kw)
+    eng = engine(ff, **kw)
     assert eng._chunk_rows == rows
     short = eng.submit(PROMPTS[0])
     # drive until the short request is decoding
@@ -555,7 +456,7 @@ def test_chunked_prefill_interleaves_with_decode(layout):
     assert eng.stats()["chunk_kernel_steps"] == eng.stats()["row_steps"] == (
         eng.stats()["prefill_calls"] if rows else 0)
 
-    solo = ff.serve(**kw)
+    solo = engine(ff, **kw)
     assert solo.generate([PROMPTS[0]])[0] == short.generated
     assert solo.generate([list(range(1, 17))])[0] == long_req.generated
 
@@ -571,8 +472,8 @@ def test_rows_engine_keeps_the_surface_the_benchmark_calls():
 
     from flexflow_tpu.serving.paged import SCRATCH_BLOCK, CopyPlan
 
-    ff = _build_rows_lm()
-    eng = ff.serve(slots=2, max_new_tokens=2, prefill_chunk=4, **ROWS)
+    ff = build_rows_lm()
+    eng = engine(ff, slots=2, max_new_tokens=2, prefill_chunk=4, **ROWS)
     assert eng._chunk_rows
     slots, W = 2, eng.block_manager.table_width
     for width in (1, 4, 7):
@@ -655,27 +556,28 @@ def test_paged_warmstart_layout_fingerprint(tmp_path):
     NEVER share a plan address (a paged compile after a contiguous one
     still searches)."""
     ws = str(tmp_path / "ws")
-    ff = _build_lm(mesh=(2, 4, 1, 1), batch=8,
-                   argv=["--only-data-parallel"])
+    ff = build_lm(mesh=(2, 4, 1, 1), batch=8,
+                  argv=["--only-data-parallel"])
     ov = dict(only_data_parallel=False, search_budget=4,
               enable_parameter_parallel=True,
               enable_attribute_parallel=True, warmstart_dir=ws)
     kw = dict(slots=8, max_new_tokens=4, prefill_chunk=4,
               config_overrides=ov)
 
+    # serve() throughout: each compile's plan source is what is asserted
     paged1 = ff.serve(kv_layout="paged", **kw)
     assert paged1.decode_model._plan_source == "search"
     out1 = paged1.generate(PROMPTS[:2])
 
     # the contiguous compile must MISS the paged entry (fresh search) ...
-    with _SearchSpy() as spy:
+    with SearchSpy() as spy:
         contig1 = ff.serve(kv_layout="contiguous", **kw)
     assert contig1.decode_model._plan_source == "search"
     assert spy.searches == 1
     assert contig1.generate(PROMPTS[:2]) == out1
 
     # ... while each layout's OWN second compile is a zero-eval hit
-    with _SearchSpy() as spy:
+    with SearchSpy() as spy:
         paged2 = ff.serve(kv_layout="paged", **kw)
         contig2 = ff.serve(kv_layout="contiguous", **kw)
     assert spy.searches == 0 and spy.evals == 0
@@ -688,11 +590,11 @@ def test_paged_pool_exhaustion_blocks_admission():
     """A pool too small for two resident requests head-blocks admission
     (FCFS) instead of failing mid-decode: the second request waits for
     the first to release its blocks, and completions stay correct."""
-    ff = _build_lm(batch=1)
+    ff = build_lm(batch=1)
     # 4 blocks + scratch: one request (prompt 5 + 3 new = 2 blocks @ bs=4
     # + COW slack) fits, two do not
-    eng = ff.serve(slots=2, max_new_tokens=3, prefill_chunk=4,
-                   kv_layout="paged", kv_block_size=4, kv_num_blocks=5)
+    eng = engine(ff, slots=2, max_new_tokens=3, prefill_chunk=4,
+                 kv_layout="paged", kv_block_size=4, kv_num_blocks=5)
     r1 = eng.submit(PROMPTS[0])
     r2 = eng.submit(PROMPTS[2])
     eng.step()
@@ -700,8 +602,8 @@ def test_paged_pool_exhaustion_blocks_admission():
         "pool pressure must keep the second request queued"
     eng.run_until_drained()
     assert r1.finished and r2.finished
-    solo = ff.serve(slots=2, max_new_tokens=3, prefill_chunk=4,
-                    kv_layout="contiguous")
+    solo = engine(ff, slots=2, max_new_tokens=3, prefill_chunk=4,
+                  kv_layout="contiguous")
     assert [r1.generated, r2.generated] == solo.generate(
         [PROMPTS[0], PROMPTS[2]])
 
@@ -723,8 +625,8 @@ def test_paged_analysis_coverage():
     assert table["build_block_copy"] == (0,)
     assert not donation.registry_problems()
 
-    ff = _build_lm(batch=1)
-    c = _lm_config()
+    ff = build_lm(batch=1)
+    c = lm_config()
     bs = 8
     dec4, _ = build_decode_model(ff, ServingSpec(
         slots=4, kv_layout="paged", kv_block_size=bs, kv_num_blocks=9))

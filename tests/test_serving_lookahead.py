@@ -22,8 +22,8 @@ What has to hold, on the CPU mesh:
 import numpy as np
 import pytest
 
-from test_serving import (
-    ROWS, _build_lm, _build_rows_lm, _complete_every_step_at_once,
+from small_lms import (
+    ROWS, build_lm, build_rows_lm, complete_every_step_at_once, engine,
 )
 
 BASE = [3, 7, 11, 2, 5, 9, 1, 4]
@@ -38,8 +38,8 @@ LAYOUTS = {"rows": ROWS, "contiguous": {"kv_layout": "contiguous"},
 
 
 def _engine(layout="paged", argv=(), **kw):
-    ff = (_build_rows_lm() if layout == "rows"
-          else _build_lm(batch=1, argv=argv))
+    ff = (build_rows_lm() if layout == "rows"
+          else build_lm(batch=1, argv=argv))
     return ff, {"slots": 3, "max_new_tokens": 6, "prefill_chunk": 4,
                 **LAYOUTS[layout], **kw}
 
@@ -52,32 +52,35 @@ def _step_until_in_flight(eng, calls=3):
 
 
 @pytest.mark.parametrize("layout", ["paged", "contiguous", "rows"])
-def test_streams_are_those_of_the_loop_that_completes_every_step(layout):
+def test_streams_are_those_of_the_loop_that_completes_every_step(
+        layout, monkeypatch):
     ff, opts = _engine(layout)
-    eng = ff.serve(**opts)
+    eng = engine(ff, **opts)
     reqs = [eng.submit(p) for p in MIXED]       # more requests than slots
     eng.run_until_drained()
-    at_once = _complete_every_step_at_once(ff.serve(**opts))
+    got = eng.stats()
+    if layout != "contiguous":
+        eng.block_manager.check_invariants()
+    at_once = complete_every_step_at_once(engine(ff, **opts), monkeypatch)
     want = at_once.generate(MIXED)
     assert [r.generated for r in reqs] == want
     assert {r.finish_reason for r in reqs} == {"max_tokens"}
-    got, ref = eng.stats(), at_once.stats()
+    ref = at_once.stats()
     assert got["decode_tokens"] == ref["decode_tokens"] == 6 * len(MIXED)
     assert got["prefill_tokens"] == ref["prefill_tokens"]
     assert got["steps_ahead"] > 0 and ref["steps_ahead"] == 0
     if layout != "contiguous":
         assert got["prefix_hit_tokens"] > 0 and got["cow_copies"] > 0
-        eng.block_manager.check_invariants()
 
 
-def test_a_row_that_outran_an_eos_is_discarded():
+def test_a_row_that_outran_an_eos_is_discarded(monkeypatch):
     ff, opts = _engine(slots=1, max_new_tokens=8)
     prompt, other = BASE + [6], [5, 2, 8]
-    stream, after = ff.serve(**opts).generate([prompt, other])
+    stream, after = engine(ff, **opts).generate([prompt, other])
     # a token the stream first shows in its middle: by then the slot
     # decodes, and the request is not at its last token by length
     k = next(i for i in range(1, 6) if stream[i] not in stream[:i])
-    eng = ff.serve(**opts)
+    eng = engine(ff, **opts)
     req = eng.submit(prompt, eos_id=stream[k])
     nxt = eng.submit(other)                     # the slot's next owner
     eng.run_until_drained()
@@ -96,15 +99,15 @@ def test_a_row_that_outran_an_eos_is_discarded():
     assert again.generated == stream[:k + 1]
     assert eng.stats()["rows_discarded"] == 2
     # completed at once, no row outruns anything
-    sync = _complete_every_step_at_once(ff.serve(**opts))
+    sync = complete_every_step_at_once(engine(ff, **opts), monkeypatch)
     assert sync.generate([prompt], eos_id=stream[k]) == [stream[:k + 1]]
     assert sync.stats()["rows_discarded"] == 0
 
 
 @pytest.mark.parametrize("layout", ["paged", "contiguous"])
-def test_an_end_by_length_is_known_at_dispatch(layout):
+def test_an_end_by_length_is_known_at_dispatch(layout, monkeypatch):
     ff, opts = _engine(layout, slots=2)
-    eng = ff.serve(**opts)
+    eng = engine(ff, **opts)
     budgets = [1, 2, 3, 6, 1, 4]
     reqs = [eng.submit(p, max_new_tokens=n)
             for p, n in zip(MIXED, budgets)]
@@ -119,8 +122,9 @@ def test_an_end_by_length_is_known_at_dispatch(layout):
                 decoded[rid] = decoded.get(rid, 0) + 1
         return step(*args)
 
-    eng._step_fn = spy
-    eng.run_until_drained()
+    with monkeypatch.context() as patched:
+        patched.setattr(eng, "_step_fn", spy)
+        eng.run_until_drained()
     assert [len(r.generated) for r in reqs] == budgets
     assert {r.finish_reason for r in reqs} == {"max_tokens"}
     assert (full.finish_reason, len(full.generated)) == ("length", 2)
@@ -130,19 +134,20 @@ def test_an_end_by_length_is_known_at_dispatch(layout):
         assert decoded.get(r.request_id, 0) == len(r.generated) - 1
     st = eng.stats()
     assert st["rows_discarded"] == 0 and st["steps_ahead"] > 0
-    at_once = _complete_every_step_at_once(ff.serve(**opts))
+    at_once = complete_every_step_at_once(engine(ff, **opts), monkeypatch)
     assert at_once.generate([full.prompt], max_new_tokens=9) == [
         full.generated]
 
 
 @pytest.mark.parametrize("mode", ["in_flight", "at_once", "sanitize"])
-def test_steps_ahead_says_how_often_a_step_was_left_in_flight(mode):
+def test_steps_ahead_says_how_often_a_step_was_left_in_flight(
+        mode, monkeypatch):
     ff, opts = _engine(
         argv=["--sanitize-numerics"] if mode == "sanitize" else (),
         slots=2, max_new_tokens=24)
-    eng = ff.serve(**opts)
+    eng = engine(ff, **opts)
     if mode == "at_once":
-        _complete_every_step_at_once(eng)
+        complete_every_step_at_once(eng, monkeypatch)
     eng.generate(MIXED[:4])
     st = eng.stats()
     assert st["iterations"] >= 48
@@ -175,11 +180,12 @@ def _leaves_nothing_in_flight(eng, reqs, what):
 @pytest.mark.parametrize("what", [
     "stats", "reset_stats", "metrics_summary", "extract_kv",
     "apply_copies", "profile_step"])
-def test_reading_decode_state_completes_the_step_in_flight(what):
+def test_reading_decode_state_completes_the_step_in_flight(
+        what, monkeypatch):
     from flexflow_tpu.serving.paged import SCRATCH_BLOCK, CopyPlan
 
     ff, opts = _engine(slots=2, max_new_tokens=3)
-    eng = ff.serve(**opts)
+    eng = engine(ff, **opts)
     reqs = [eng.submit(p) for p in ([5, 2], [60], BASE)]
     _leaves_nothing_in_flight(eng, reqs, {
         "stats": lambda e: e.stats(),
@@ -192,14 +198,14 @@ def test_reading_decode_state_completes_the_step_in_flight(what):
     }[what])
     eng.run_until_drained()
     assert eng._in_flight is None
-    want = _complete_every_step_at_once(ff.serve(**opts)).generate(
-        [r.prompt for r in reqs])
+    want = complete_every_step_at_once(
+        engine(ff, **opts), monkeypatch).generate([r.prompt for r in reqs])
     assert [r.generated for r in reqs] == want
 
 
 def test_run_until_drained_drains_the_last_step():
     ff, opts = _engine(slots=2, max_new_tokens=4)
-    eng = ff.serve(**opts)
+    eng = engine(ff, **opts)
     reqs = [eng.submit(p) for p in MIXED[:3]]
     done = eng.run_until_drained(max_iterations=4)  # stopped mid-run
     assert eng._in_flight is None
@@ -212,11 +218,11 @@ def test_run_until_drained_drains_the_last_step():
 
 
 def test_replan_mesh_completes_the_step_in_flight():
-    ff = _build_lm(mesh=(1, 1, 1, 1), batch=2,
-                   argv=["--elastic-min-devices", "1"])
+    ff = build_lm(mesh=(1, 1, 1, 1), batch=2,
+                  argv=["--elastic-min-devices", "1"])
     opts = dict(slots=2, max_new_tokens=8, prefill_chunk=4)
-    want = ff.serve(**opts).generate(MIXED[:2])
-    eng = ff.serve(**opts)
+    want = engine(ff, **opts).generate(MIXED[:2])
+    eng = ff.serve(**opts)      # its own: it moves to another mesh
     reqs = [eng.submit(p) for p in MIXED[:2]]
     _step_until_in_flight(eng, calls=5)
     eng.replan_mesh((2, 1, 1, 1))
@@ -232,7 +238,7 @@ def test_admit_prefilled_completes_the_step_in_flight():
 
     ff, opts = _engine(slots=2, max_new_tokens=5, prefix_cache=False,
                        prefix_sharing=False)
-    eng = ff.serve(**opts)
+    eng = engine(ff, **opts)
     first = eng.submit(BASE)
     _step_until_in_flight(eng, calls=4)
     ks, vs = eng.extract_kv(0, len(BASE))       # the prompt's rows
@@ -250,8 +256,9 @@ def test_a_speculative_round_starts_and_ends_with_nothing_in_flight():
     from test_speculative import _build_lm as build, _force_speculation
 
     ff = build()
-    base = ff.serve(slots=2, max_new_tokens=8,
-                    prefill_chunk=4).generate(MIXED[:3])
+    base = engine(ff, slots=2, max_new_tokens=8,
+                  prefill_chunk=4).generate(MIXED[:3])
+    # serve(): a speculative engine holds a drafter's engine of its own
     eng = ff.serve(speculate=True, draft_model=build(), slots=2,
                    max_new_tokens=8, prefill_chunk=4)
     _force_speculation(eng)
@@ -278,12 +285,13 @@ def test_a_speculative_round_starts_and_ends_with_nothing_in_flight():
 
 
 @pytest.mark.parametrize("swapped", ["from_the_start", "in_mid_flight"])
-def test_a_host_step_function_finds_the_scheduler_current(swapped):
+def test_a_host_step_function_finds_the_scheduler_current(
+        swapped, monkeypatch):
     """benchmarks/jobs/serve_sessions.py replays served streams through
     the engine with `_step_fn` swapped for a host function that reads
     `scheduler.slots` and `len(request.generated)` when it is called."""
     ff, opts = _engine(slots=2, max_new_tokens=6)
-    eng = ff.serve(**opts)
+    eng = engine(ff, **opts)
     reqs = [eng.submit(p) for p in MIXED[:4]]
     if swapped == "in_mid_flight":
         _step_until_in_flight(eng, calls=5)
@@ -305,9 +313,10 @@ def test_a_host_step_function_finds_the_scheduler_current(swapped):
         state, sampled = step(params, state, xs, *rest)
         return state, np.asarray(sampled)
 
-    eng._step_fn = on_the_host
-    eng.run_until_drained()
+    with monkeypatch.context() as patched:
+        patched.setattr(eng, "_step_fn", on_the_host)
+        eng.run_until_drained()
     assert calls
-    want = _complete_every_step_at_once(ff.serve(**opts)).generate(
-        [r.prompt for r in reqs])
+    want = complete_every_step_at_once(
+        engine(ff, **opts), monkeypatch).generate([r.prompt for r in reqs])
     assert [r.generated for r in reqs] == want

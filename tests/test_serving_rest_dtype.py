@@ -21,29 +21,34 @@ import numpy as np
 import pytest
 
 from flexflow_tpu.serving.decode_graph import KV_LEAVES
-from test_serving import PROMPTS, ROWS, ROWS_SEQ, _build_lm, _staged_shapes
+from small_lms import (
+    PROMPTS, ROWS, ROWS_SEQ, build_lm, engine, new_lm, staged_shapes,
+)
 
 BF16 = ["--dtype", "bf16"]
 CASES = {  # the trained model's sequence length, serve()'s options
     "paged": (32, dict(kv_layout="paged")),
     "contiguous": (32, dict(kv_layout="contiguous")),
     # impl="flash": single-query calls go through the paged kernel (the
-    # interpreter runs it here), so a chunk can ride as rows
-    "paged-kernel": (ROWS_SEQ, ROWS),
+    # interpreter runs it here), so a chunk can ride as rows; a table of
+    # four pages, since the two formulations lower every bucket twice and
+    # the interpreter's lowering is paid by the page
+    "paged-kernel": (ROWS_SEQ, {**ROWS, "kv_block_size": 32}),
 }
 
 
-def _lm(case, argv=BF16):
+def _lm(case, argv=BF16, build=build_lm):
     sequence_length, serve_kw = CASES[case]
-    ff = _build_lm(batch=1, argv=argv, sequence_length=sequence_length)
+    ff = build(batch=1, argv=argv, sequence_length=sequence_length)
     return ff, dict(slots=2, max_new_tokens=6, prefill_chunk=4, **serve_kw)
 
 
-def _cast_at_use(eng, ff):
-    """Turn `eng` into the parent's formulation, from outside: its decode
-    model holds fp32 copies of the trained masters and an fp32 cache, and
-    its executor declares every leaf fp32, so each step casts the weights
-    at first use and hands the cache back as fp32."""
+def _cast_at_use(ff, kw):
+    """An engine of its own, turned into the parent's formulation from
+    outside: its decode model holds fp32 copies of the trained masters and
+    an fp32 cache, and its executor declares every leaf fp32, so each step
+    casts the weights at first use and hands the cache back as fp32."""
+    eng = ff.serve(**kw)
     dec = eng.decode_model
     ex = dec.executor
     ex.rest_dtypes = {k: jnp.float32 if jnp.issubdtype(v, jnp.floating)
@@ -103,7 +108,8 @@ def test_steps_are_bitwise_the_cast_at_use_formulation(case):
     the bit, and the same cache contents, as fp32 masters cast at use
     over an fp32 cache."""
     ff, kw = _lm(case)
-    rest, at_use = ff.serve(**kw), _cast_at_use(ff.serve(**kw), ff)
+    # serve(): the whole cache is compared below, from all zeros on
+    rest, at_use = ff.serve(**kw), _cast_at_use(ff, kw)
     assert _leaf_dtypes(rest.decode_model._params) == {"bfloat16"}
     assert _leaf_dtypes(rest.decode_model._state) == {"bfloat16"}
     assert _leaf_dtypes(at_use.decode_model._params) == {"float32"}
@@ -148,7 +154,7 @@ def test_steps_are_bitwise_the_cast_at_use_formulation(case):
 @pytest.mark.parametrize("case", list(CASES))
 def test_generate_gives_the_cast_at_use_tokens(case):
     ff, kw = _lm(case)
-    rest, at_use = ff.serve(**kw), _cast_at_use(ff.serve(**kw), ff)
+    rest, at_use = engine(ff, **kw), _cast_at_use(ff, kw)
     got = rest.generate(PROMPTS)
     assert got == at_use.generate(PROMPTS)
     assert all(len(g) == 6 for g in got)
@@ -164,7 +170,7 @@ def test_decode_model_rests_in_the_compute_dtype(layout, argv, want):
     fp32 without it; the trained model's masters are fp32 either way and
     the decode model's copies are its own."""
     ff, kw = _lm(layout, argv)
-    eng = ff.serve(**kw)
+    eng = engine(ff, **kw)
     dec = eng.decode_model
     assert _leaf_dtypes(dec._params) == {want}
     assert _leaf_dtypes(dec._state) == {want}
@@ -184,7 +190,8 @@ def test_serve_compile_event_says_what_rests_where(tmp_path):
     from flexflow_tpu.telemetry import read_jsonl
 
     for argv, itemsize in ((BF16, 2), ([], 4)):
-        ff, kw = _lm("paged", argv)
+        # a model of its own: the session is the model's
+        ff, kw = _lm("paged", argv, build=new_lm)
         ff.enable_telemetry(str(tmp_path / f"tel{itemsize}"))
         eng = ff.serve(**kw)
         eng.telemetry.close()
@@ -219,16 +226,17 @@ def _assert_state_as_declared(dec):
 
 
 @pytest.mark.parametrize("case", ["paged", "contiguous", "paged-kernel"])
-def test_state_keeps_its_declared_dtype_and_no_step_retraces(case):
+def test_state_keeps_its_declared_dtype_and_no_step_retraces(
+        case, monkeypatch):
     """Decode steps, chunk steps (rectangles and rows), block copies and
     a KV inject: every state leaf is left in its declared dtype and the
     step holds one executable per staged shape."""
     from flexflow_tpu.serving.paged import SCRATCH_BLOCK, CopyPlan
 
     ff, kw = _lm(case)
-    eng = ff.serve(**kw)
+    eng = ff.serve(**kw)    # its own: its executables are counted from none
     dec = eng.decode_model
-    shapes = _staged_shapes(eng)
+    shapes = staged_shapes(eng, monkeypatch)
     _assert_state_as_declared(dec)
     eng.generate(PROMPTS)
     _assert_state_as_declared(dec)
@@ -257,8 +265,9 @@ def test_verify_step_keeps_declared_dtypes():
     from test_speculative import _force_speculation
 
     ff, kw = _lm("paged")
-    base = ff.serve(**kw).generate(PROMPTS)
-    dff, _ = _lm("paged")
+    base = engine(ff, **kw).generate(PROMPTS)
+    dff, _ = _lm("paged", build=new_lm)
+    # serve(): a speculative engine holds a drafter's engine of its own
     eng = ff.serve(speculate=True, draft_model=dff, **kw)
     _force_speculation(eng)
     assert eng.generate(PROMPTS) == base

@@ -23,7 +23,7 @@ import pytest
 from flexflow_tpu import telemetry
 from flexflow_tpu.serving.paged import BlockManager
 
-from test_serving import _build_lm, _complete_every_step_at_once
+from small_lms import build_lm, complete_every_step_at_once, engine
 
 SERVE = dict(slots=2, max_seq_len=32, prefill_chunk=4, kv_layout="paged",
              kv_block_size=4)
@@ -42,12 +42,12 @@ LAYOUTS = ("paged", "rows", "window", "state")
 
 @pytest.fixture(scope="module")
 def model():
-    return _build_lm(batch=2, sequence_length=32, argv=["--seed", "11"])
+    return build_lm(batch=2, sequence_length=32, argv=["--seed", "11"])
 
 
 def serve(ff, layout="paged", **kw):
     """An engine of the plain LM with the facts of `layout` (the module's
-    docstring)."""
+    docstring): a new one, since those facts are set on it from outside."""
     eng = ff.serve(**{**SERVE, **kw})
     if layout == "rows":
         eng._chunk_rows = True
@@ -144,21 +144,13 @@ def test_the_packed_path_stages_what_the_plain_one_does(model, layout):
     assert np.asarray(xs["tokens"])[:, 0].tolist() == [41, 17]
 
 
-@pytest.fixture(scope="module")
-def engine(model):
-    # nothing a request leaves in the cache shortens the next one's
-    # prefill: a run's steps, and so its keys, are a fresh engine's
-    return serve(model, prefix_cache=False)
-
-
 @pytest.fixture
-def fresh(engine):
-    """The module's engine as it was built: drained, its key's chain at
-    the start."""
-    assert engine.scheduler.drained
-    engine._rng = None
-    engine.reset_stats()
-    return engine
+def fresh(model):
+    """The shared engine of SERVE (tests/small_lms.py) as it was built:
+    drained, its key's chain at the start. Without the prefix cache:
+    nothing a request leaves in it shortens the next one's prefill, so a
+    run's steps, and so its keys, are a new engine's."""
+    return engine(model, **SERVE, prefix_cache=False)
 
 
 def test_the_step_is_handed_the_parents_chain_of_keys(model, fresh):
@@ -189,13 +181,9 @@ def test_generate_gives_the_parents_tokens(fresh, temperature):
                           temperature=temperature) == PARENT[temperature]
 
 
-def test_a_host_function_in_the_steps_place_still_drains(fresh):
-    step = fresh._step_fn
-    eng = _complete_every_step_at_once(fresh)
-    try:
-        assert eng.generate(PROMPTS, max_new_tokens=6) == PARENT[0.0]
-    finally:
-        eng._step_fn = step
+def test_a_host_function_in_the_steps_place_still_drains(fresh, monkeypatch):
+    eng = complete_every_step_at_once(fresh, monkeypatch)
+    assert eng.generate(PROMPTS, max_new_tokens=6) == PARENT[0.0]
     assert eng.stats()["steps_ahead"] == 0
 
 
@@ -235,7 +223,7 @@ def test_the_step_lowered_from_plain_staging_is_the_one_a_call_finds(
 
 
 def test_on_two_devices_the_feeds_lie_where_the_search_put_them():
-    ff = _build_lm(mesh=(2, 1, 1, 1), batch=2, sequence_length=32)
+    ff = build_lm(mesh=(2, 1, 1, 1), batch=2, sequence_length=32)
     eng = serve(ff)
     dec, mesh = eng.decode_model, eng.decode_model.executor.mesh
     assert mesh.devices.size == 2

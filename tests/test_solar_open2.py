@@ -20,6 +20,7 @@ from flexflow_tpu.kernels import delta_rule as dr
 from flexflow_tpu.models import (
     build_transformer_lm, solar_open2_lm_config, solar_open2_reference as ref,
 )
+import small_lms
 
 # hidden 64; softmax layers 4 query heads over 2 KV heads of 16, gated, no
 # positions; delta-rule layers 4 heads of 16; 16 experts of 24, 4 a token,
@@ -74,9 +75,13 @@ def error(got, want):
     return float(np.max(np.abs(np.asarray(got) - want)) / np.max(np.abs(want)))
 
 
+SERVE = dict(slots=3, max_seq_len=SEQ, prefill_chunk=8, kv_block_size=4,
+             kv_num_blocks=40)
+
+
 def serve(ff, **kw):
-    return ff.serve(**{**dict(slots=3, max_seq_len=SEQ, prefill_chunk=8,
-                              kv_block_size=4, kv_num_blocks=40), **kw})
+    """The shared engine of these options (tests/small_lms.py), as new."""
+    return small_lms.engine(ff, **{**SERVE, **kw})
 
 
 def prompts(n, lengths, seed=0):
@@ -84,17 +89,17 @@ def prompts(n, lengths, seed=0):
     return [rng.integers(0, 97, int(l)).tolist() for l in lengths[:n]]
 
 
-def greedy_by_the_reference(ff, prompt, new):
-    """The reference's own greedy continuation, a full forward a token
-    (padded to one length: causal, the tail is unseen; one compile)."""
-    seq = list(prompt)
-    for _ in range(new):
-        padded = np.zeros((SEQ,), np.int32)
-        padded[:len(seq)] = seq
-        logits, _ = ref.forward(getter(ff), padded, TINY,
-                                rows=[len(seq) - 1])
-        seq.append(int(np.argmax(logits[0])))
-    return seq[len(prompt):]
+def is_greedy(ff, prompt, reply, new) -> bool:
+    """Whether `reply` is the reference's own greedy continuation of
+    `prompt` by `new` tokens: one forward over both (padded to one length:
+    causal, a row's logits depend on no later token; one compile), each
+    reply token the argmax of the row before it."""
+    padded = np.zeros((SEQ,), np.int32)
+    padded[:len(prompt) + new - 1] = [*prompt, *reply[:-1]]
+    logits, _ = ref.forward(
+        getter(ff), padded, TINY,
+        rows=range(len(prompt) - 1, len(prompt) + new - 1))
+    return np.argmax(logits, axis=-1).tolist() == reply
 
 
 # ------------------------------------------------------------------ the rule
@@ -301,8 +306,8 @@ def test_serve_decodes_what_the_reference_decodes(model):
     # three delta-rule layers: 4 heads x 16 x 16 float32 and 3 x 192 a slot
     assert st["state_bytes"] == 3 * 3 * (4 * 16 * 16 * 4 + 3 * 192 * 4)
     for prompt in prompts(2, [19, 5]):
-        assert engine.generate([prompt], max_new_tokens=6)[0] == \
-            greedy_by_the_reference(model, prompt, 6)
+        (reply,) = engine.generate([prompt], max_new_tokens=6)
+        assert is_greedy(model, prompt, reply, 6)
     assert engine.stats()["state_resets"] == 2
     assert not engine.spec.prefix_cache and not engine.spec.prefix_sharing
 
@@ -316,7 +321,7 @@ def test_an_interleaved_batch_equals_each_request_alone(model):
     together = serve(model).generate(ps, max_new_tokens=7)
     assert together[3] == serve(model).generate([ps[3]], max_new_tokens=7)[0]
     for p, got in zip(ps, together):
-        assert got == greedy_by_the_reference(model, p, 7)
+        assert is_greedy(model, p, got, 7)
 
 
 def test_a_reused_slot_starts_from_nothing(model):
@@ -329,9 +334,10 @@ def test_a_reused_slot_starts_from_nothing(model):
     engine.run_until_drained()
     assert engine.stats()["steps_ahead"] > 0
     assert engine.stats()["state_resets"] == 2
-    fresh = serve(model, slots=1, kv_num_blocks=12)
+    # serve(): a new engine, whose slot has held nothing
+    fresh = model.serve(**{**SERVE, "slots": 1, "kv_num_blocks": 12})
     assert second.generated == fresh.generate([b], max_new_tokens=5)[0]
-    assert first.generated == greedy_by_the_reference(model, a, 5)
+    assert is_greedy(model, a, first.generated, 5)
 
 
 def test_a_chunk_as_rows_equals_the_rectangle():
@@ -347,8 +353,9 @@ def test_a_chunk_as_rows_equals_the_rectangle():
                n_routed_experts=8, num_experts_per_tok=2)
     ff = build(big, seq=128, batch=1)
     ps = prompts(3, [13, 21, 6], seed=5)
-    kw = dict(slots=2, max_seq_len=128, prefill_chunk=8, kv_block_size=8,
+    kw = dict(slots=2, max_seq_len=128, prefill_chunk=8, kv_block_size=16,
               kv_num_blocks=40)
+    # serve(), twice: the model is this test's alone
     rows = ff.serve(impl="flash", **kw)
     assert rows._chunk_rows
     got = rows.generate(ps, max_new_tokens=4)

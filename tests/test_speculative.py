@@ -24,7 +24,7 @@ import sys
 
 import pytest
 
-from test_serving import _SearchSpy
+from small_lms import SearchSpy
 
 PROMPTS = [[3, 7, 11, 2, 5], [5, 2], [1, 9, 30, 30, 12, 4, 8], [60, 1, 2]]
 
@@ -236,7 +236,7 @@ def test_spec_warmstart_role_keyed_plan_cache(tmp_path):
     assert eng1.drafter.engine.decode_model._plan_source == "search"
     out1 = eng1.generate(PROMPTS[:2])
 
-    with _SearchSpy() as spy:
+    with SearchSpy() as spy:
         eng2 = ff.serve(speculate=True, draft_model=dff, **kw)
     assert spy.searches == 0, "speculative re-serve must not re-search"
     assert spy.evals == 0, "speculative re-serve must cost 0 evaluations"

@@ -17,8 +17,8 @@ import pytest
 from flexflow_tpu import telemetry
 from flexflow_tpu.telemetry.tracer import Tracer
 
-from test_serving import (
-    ROWS, _build_lm, _build_rows_lm, _complete_every_step_at_once,
+from small_lms import (
+    ROWS, build_lm, build_rows_lm, complete_every_step_at_once, engine,
 )
 from test_telemetry import _build_mlp, _train_data
 
@@ -97,8 +97,8 @@ def test_fit_spans_reach_the_profilers_host_plane(tmp_path):
 
 @pytest.mark.parametrize("at_once", [True, False])
 @pytest.mark.parametrize("layout", ["rectangle", "rows"])
-def test_engine_phases_reach_the_profilers_host_plane(tmp_path, layout,
-                                                      at_once):
+def test_engine_phases_reach_the_profilers_host_plane(
+        tmp_path, monkeypatch, layout, at_once):
     """Two requests on two slots, prompts of 5 and 2 tokens, 3 new tokens
     each, chunks of 4: five steps, whose `kv_rows` (the context rows
     the step's attention must read) are counted by hand below, the same
@@ -111,13 +111,13 @@ def test_engine_phases_reach_the_profilers_host_plane(tmp_path, layout,
     is: that span covers the next step's schedule, stage and dispatch
     and its own fetch."""
     rows = layout == "rows"
-    ff = _build_rows_lm() if rows else _build_lm(batch=1)
-    eng = ff.serve(slots=2, max_new_tokens=3, prefill_chunk=4,
-                   prefix_sharing=False,
-                   **(ROWS if rows else {"kv_block_size": 4}))
+    ff = build_rows_lm() if rows else build_lm(batch=1)
+    eng = engine(ff, slots=2, max_new_tokens=3, prefill_chunk=4,
+                 prefix_sharing=False,
+                 **(ROWS if rows else {"kv_block_size": 4}))
     assert eng._chunk_rows == rows
     if at_once:
-        _complete_every_step_at_once(eng)
+        complete_every_step_at_once(eng, monkeypatch)
     eng.generate([[9, 8, 7]])                       # compiles
     first = eng._iterations
     with traced(tmp_path / "trace"):
@@ -194,7 +194,8 @@ def test_engine_phases_reach_the_profilers_host_plane(tmp_path, layout,
 
 @pytest.mark.parametrize("at_once", [True, False])
 @pytest.mark.parametrize("layout", ["rectangle", "rows"])
-def test_every_span_of_a_step_carries_its_id(tmp_path, layout, at_once):
+def test_every_span_of_a_step_carries_its_id(tmp_path, monkeypatch, layout,
+                                             at_once):
     """Three requests on two slots (the third is admitted when the first
     ends, and the first ends by EOS), chunks of 4, then the drain: a step
     gets its id when it is scheduled, the ids are consecutive, every
@@ -202,12 +203,12 @@ def test_every_span_of_a_step_carries_its_id(tmp_path, layout, at_once):
     `serve.stage` (spans of its name with a `part`) lie inside it, and
     `serve.dispatch` says what the step ran."""
     rows = layout == "rows"
-    ff = _build_rows_lm() if rows else _build_lm(batch=1)
-    eng = ff.serve(slots=2, max_new_tokens=4, prefill_chunk=4,
-                   prefix_sharing=False,
-                   **(ROWS if rows else {"kv_block_size": 4}))
+    ff = build_rows_lm() if rows else build_lm(batch=1)
+    eng = engine(ff, slots=2, max_new_tokens=4, prefill_chunk=4,
+                 prefix_sharing=False,
+                 **(ROWS if rows else {"kv_block_size": 4}))
     if at_once:
-        _complete_every_step_at_once(eng)
+        complete_every_step_at_once(eng, monkeypatch)
     prompts = [[3, 7, 11, 2, 5], [5, 2], [9, 8, 7]]
     (alone,) = eng.generate(prompts[:1])            # compiles
     before = eng._step_ids
@@ -263,16 +264,16 @@ def test_kv_itemsize_is_what_attention_reads():
     """Under --dtype bf16 the decode graph declares its pool in the compute
     dtype (serving/decode_graph.py), so attention reads the pool as it
     lies: two bytes an element are stored and two are read."""
-    ff = _build_lm(batch=1, argv=["--dtype", "bf16"])
-    eng = ff.serve(slots=2, max_new_tokens=2, prefill_chunk=4)
+    ff = build_lm(batch=1, argv=["--dtype", "bf16"])
+    eng = engine(ff, slots=2, max_new_tokens=2, prefill_chunk=4)
     (pool,) = {ws["pool_k"].dtype.itemsize
                for ws in eng.decode_model._state.values() if "pool_k" in ws}
     assert (pool, eng._kv_itemsize) == (2, 2)
 
 
 def test_an_idle_step_opens_no_iteration(tmp_path):
-    ff = _build_lm(batch=1)
-    eng = ff.serve(slots=2, max_new_tokens=2, prefill_chunk=4)
+    ff = build_lm(batch=1)
+    eng = engine(ff, slots=2, max_new_tokens=2, prefill_chunk=4)
     before = eng._iterations
     with traced(tmp_path / "trace"):
         assert eng.step() == []
