@@ -1462,11 +1462,14 @@ def flash_decode_attention(
 #
 # The kernel body walks the page table itself. The grid is over slots only;
 # the pool stays in HBM and the body loops cdiv(length, rows a round) times
-# — a trip count read from the scalar-prefetched lengths, so a page past the
-# slot's cursor is never a step and never a DMA. A round copies whole pool
-# rows (block_size, H·hd) — 64 KB contiguous at 16 x 2048 bf16 — for about
-# 1 MiB of K and V into one of two VMEM buffers, the next round in flight
-# while this one is computed, and takes ALL heads in one pass: a
+# — a trip count read from the scalar-prefetched lengths, so a round past
+# the slot's cursor is never a step — and a round copies the pages that hold
+# a key its row attends and no other (a loop over them, `copies`): a page
+# past the cursor, or wholly behind a window, is never a DMA, and what its
+# place in the buffer holds is masked as key and as value. A round copies
+# whole pool rows (block_size, H·hd) — 64 KB contiguous at 16 x 2048 bf16 —
+# for about 1 MiB of K and V into one of two VMEM buffers, the next round in
+# flight while this one is computed, and takes ALL heads in one pass: a
 # block-diagonal query (H, H·hd), row h holding head h's lanes, makes the
 # logits (H, rows) one matmul over the full row and p·V (H, H·hd) another;
 # each head keeps its own lanes of its row at the end. The MXU does H times
@@ -1495,6 +1498,22 @@ def flash_decode_attention(
 # floor (scripts/paged_grouped_bench.py --pages; PERF.md section 6, PR 52):
 # past 1 MiB nothing is left to gain and a slot's dead tail pages grow.
 #
+# The walk's DMAs follow the CALL, not the grid step (PR 59). The grid runs
+# in order (`dimension_semantics=("arbitrary",)`: a v5e has one TensorCore
+# and gives nothing up; on a chip with two, a "parallel" grid would split
+# the rows between them and each half would need its own cold start). A
+# row's last round starts the next live row's first round, from THAT row's
+# length and window, into the other buffer; a dead row (length 0) has no
+# round and starts its successor's at its end; only the call's first row
+# starts its own. The two buffers alternate over the whole call, and the one
+# a row's first round lies in is carried from grid step to grid step in
+# SMEM (`base_ref`). Before, every row started its own first round and
+# waited for it with nothing to do, and a round copied all of its pages
+# whatever the row's length: at c13b-serve-chat's contexts (16 rows of 1-5
+# rounds of 8 pages, mean 224 keys) a row's one exposed round was a third
+# to a half of its time and a row of 140 keys read 2 MiB for 1.1 attended
+# (PERF.md section 6, PR 59, has what each half was worth on the chip).
+#
 # A row is a slot's one query. A prefill chunk's tokens can ride as rows of
 # this kernel too, each under a copy of its slot's table row, and did from
 # PR 28 to PR 33; every such row walks the slot's pages from page 0, so the
@@ -1509,7 +1528,9 @@ _PAGED_ROUND_VMEM = 8 << 20
 
 def _paged_round_copies(block_of, k_hbm, v_hbm, k_buf, v_buf, sem, c, buf,
                         lanes=None):
-    """A round's 2·pages DMAs: logical pages [c·pages, (c+1)·pages) of one
+    """A round's 2·pages DMAs of the chunk kernel (the decode kernel's
+    are its own `copies`, a loop over a row's live pages): logical pages
+    [c·pages, (c+1)·pages) of one
     page-table row, K and V, into buffer `buf`. `block_of(page)` is the
     table's entry for a logical page; a tail page past the table's width
     re-reads the last entry (the caller clamps) and its rows are masked.
@@ -1556,35 +1577,72 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, *refs, scale: float,
     head's log-sum-exp of its scaled logits on every lane (NEG_INF where
     the row attended nothing): what merges this call's softmax with
     another's over other keys. All static; at their defaults the body is
-    what it was."""
+    what it was.
+
+    Every variant shares one DMA schedule (section comment): `copies(row,
+    c, buf, act)` starts, or waits for, the live pages of a row's round,
+    and `base_ref` (SMEM, one int32) carries the buffer this row's first
+    round was started into by the row before."""
     refs = list(refs)
     sink_ref = refs.pop(0) if sink else None
     spread_ref, own_ref = (refs.pop(0), refs.pop(0)) if head_dim else (None,
                                                                        None)
     lse_ref = refs.pop(3) if lse else None
-    k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, acc_ref = refs
+    k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, base_ref, acc_ref = refs
     s = pl.program_id(0)
-    length = len_ref[s]
-    width = tbl_ref.shape[1]
+    n_rows, width = tbl_ref.shape
+    block = k_hbm.shape[1]
     rows = k_buf.shape[1]
+    pages = rows // block
+    length = len_ref[s]
     n_rounds = pl.cdiv(length, rows)
-    # the round a window's first key lies in
-    c0 = jnp.maximum(length - window, 0) // rows if window else 0
     heads, e = acc_ref.shape        # e: the value pool's row
     e_k = k_buf.shape[-1]           # the key pool's
     if not head_dim:
         head_dim = q_ref.shape[-1] if group else e // heads
     v_dim = e // (e_k // head_dim)
 
-    def copies(c, buf):
-        return _paged_round_copies(
-            lambda page: tbl_ref[s, jnp.minimum(page, width - 1)],
-            k_hbm, v_hbm, k_buf, v_buf, sem, c, buf)
+    def first_key(row):  # the lowest key `row` attends
+        return jnp.maximum(len_ref[row] - window, 0) if window else 0
 
-    @pl.when(n_rounds > 0)
+    def first_round(row):  # the round that key lies in
+        return first_key(row) // rows
+
+    def copies(row, c, buf, act: str):
+        """Start, or wait for, the DMAs of `row`'s round c: logical pages
+        [c·pages, (c+1)·pages) of its table row, K and V, into buffer
+        `buf`, those that hold a key the row attends and no other (a loop,
+        not `pages` copies of the body: the kernel is lowered once a layer
+        in every bucket program)."""
+        lo = jnp.maximum(first_key(row) // block, c * pages)
+        hi = jnp.minimum(jnp.minimum(pl.cdiv(len_ref[row], block), width),
+                         (c + 1) * pages)
+
+        @pl.loop(lo, hi)
+        def _page(p):
+            blk = tbl_ref[row, p]
+            dst = pl.ds(pl.multiple_of((p - c * pages) * block, block),
+                        block)
+            for i, (hbm, vmem) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                dma = pltpu.make_async_copy(
+                    hbm.at[blk], vmem.at[buf, dst], sem.at[i, buf])
+                getattr(dma, act)()
+
+    # a row's first round is started by the row before it, beside that
+    # row's last round (the grid runs in order): the buffers alternate over
+    # the whole call, and `base` is the one this row's first round lies in
+    @pl.when(s == 0)
+    def _origin():
+        base_ref[0] = 0
+
+    base = base_ref[0]
+    c0 = first_round(s)
+    nxt = jnp.minimum(s + 1, n_rows - 1)
+    has_next = (s + 1 < n_rows) & (len_ref[nxt] > 0)
+
+    @pl.when((s == 0) & (n_rounds > 0))
     def _first():
-        for dma in copies(c0, c0 % 2):
-            dma.start()
+        copies(s, c0, base, "start")
 
     # row h of the block-diagonal query holds q's lanes of head h
     head = jax.lax.broadcasted_iota(jnp.int32, (heads, e), 0)
@@ -1609,15 +1667,17 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, *refs, scale: float,
 
     def round_(c, carry):
         m_prev, l_prev = carry
-        buf = c % 2
+        buf = (base + c - c0) % 2
 
         @pl.when(c + 1 < n_rounds)
         def _next():
-            for dma in copies(c + 1, 1 - buf):
-                dma.start()
+            copies(s, c + 1, 1 - buf, "start")
 
-        for dma in copies(c, buf):
-            dma.wait()
+        @pl.when((c + 1 == n_rounds) & has_next)
+        def _next_row():
+            copies(nxt, first_round(nxt), 1 - buf, "start")
+
+        copies(s, c, buf, "wait")
         k = k_buf[buf]  # (rows, e_k): logical rows [c·rows, (c+1)·rows)
         v = v_buf[buf]
         logits = jax.lax.dot_general(
@@ -1654,6 +1714,12 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, *refs, scale: float,
         carry = (jnp.full((heads, 1), -jnp.inf, jnp.float32),
                  jnp.zeros((heads, 1), jnp.float32))
     m, l = jax.lax.fori_loop(c0, n_rounds, round_, carry)
+
+    @pl.when((n_rounds == 0) & has_next)
+    def _dead_row():  # no last round to start the next row's beside
+        copies(nxt, first_round(nxt), base, "start")
+
+    base_ref[0] = (base + n_rounds - c0) % 2
     if lse_ref is not None:
         lse_ref[0] = jnp.broadcast_to(
             jnp.where(l > 0.0, m + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF),
@@ -1774,6 +1840,7 @@ def _paged_decode_call(table, lengths, q, pool_k, pool_v, sink=None, *,
             pltpu.VMEM((2, pages * bs, e_kv), pool_k.dtype),
             pltpu.VMEM((2, pages * bs, e_v), pool_v.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((num_heads, e_v), jnp.float32),
         ],
     )
@@ -1786,7 +1853,7 @@ def _paged_decode_call(table, lengths, q, pool_k, pool_v, sink=None, *,
         grid_spec=grid_spec,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
         name=name + ("_grouped" if group else "") + ("_lse" if lse else ""),
@@ -1794,6 +1861,22 @@ def _paged_decode_call(table, lengths, q, pool_k, pool_v, sink=None, *,
     if lse:
         return out[0].reshape(slots, 1, -1), out[1][:, :, 0]
     return out.reshape(slots, 1, -1)
+
+
+def paged_walk_counts(lengths, block_size: int) -> tuple[int, int]:
+    """What the paged decode kernel's walk does in one call over rows of
+    these `lengths` (0: a dead row), reckoned on the host by the body's
+    rule: (cache rows its DMAs copy, a row's live pages x the block's
+    rows; live rows whose first round the live row before them started
+    beside its own last one, so that only its bytes are waited for). A
+    live row behind a dead one, and the call's first, wait for a cold
+    start."""
+    import numpy as np
+
+    n = np.asarray(lengths, np.int64)
+    live = n > 0
+    return (int((-(-n // block_size)).sum()) * block_size,
+            int((live[:-1] & live[1:]).sum()))
 
 
 def _paged_round_pages(block_size: int, k_row_bytes: int, v_row_bytes: int,
@@ -1859,12 +1942,16 @@ def paged_flash_decode_attention(
     counts. A row is a slot's one query, or one token of a prefill chunk
     carrying its slot's page-table row (a chunk paged_flash_chunk_attention
     cannot tile): rows may share a table row and outnumber the slots. One
-    grid step a row: the
+    grid step a row, in order: the
     body walks the row's live pages through the scalar-prefetched table,
     whole pool rows DMA'd from HBM a round of about 1 MiB of K and V at a
     time (as many pages as that takes at the pools' row widths: a round's
     fixed cost is paid once a MiB, not once 128 rows; _paged_round_pages),
-    all heads in one pass (see the section comment). `window` > 0: a row
+    all heads in one pass (see the section comment). A page that holds no
+    key the row attends is not copied, and a row's last round starts the
+    next live row's first, so only the call's first round is waited for
+    with nothing to do (`paged_walk_counts` reckons both on the host).
+    `window` > 0: a row
     attends its nearest `window` keys and walks only the pages that hold
     them; `sink` (H,): one more logit a head in the softmax's denominator.
     The output is (rows, 1, H·hd_v). `return_lse` (one head count and

@@ -128,6 +128,10 @@ class DecodeState:
     # kernel then takes, (mesh, itemsize, rows) -> int or None; None: never
     chunk_as_rows: Optional[Callable] = None
     chunk_query_tile: Optional[Callable] = None
+    # whether a row of a (rows, 1) call reads its whole context by the paged
+    # decode kernel's walk of its table row, (mesh, itemsize) -> bool; None:
+    # never (the engine counts what the walk copies by this)
+    rows_walk: Optional[Callable] = None
     # what one such layer reads and writes in a step, for the step's span
     # and the engine's totals: (positions of the step's decoding rows) ->
     # {counter: number}; None: nothing beyond what the engine counts

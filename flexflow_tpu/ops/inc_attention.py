@@ -408,6 +408,8 @@ def _paged_mha_state(p: PagedIncMultiHeadAttentionParams) -> DecodeState:
             p.selected or both or paged_rows_run_kernel(p, mesh, itemsize)),
         chunk_query_tile=(None if both
                           else partial(paged_chunk_query_tile, p)),
+        rows_walk=(None if both or p.selected or windowed
+                   else partial(paged_rows_run_kernel, p)),
         step_counts=f.step_counts if both else None,
         cannot=f.cannot_follow)
 

@@ -464,6 +464,7 @@ class ServingEngine:
             self._build_copy_fns()
         self._kv_itemsize = at_rest["kv_stored_itemsize"]
         self._chunk_rows = self._rows_serve_chunks()
+        self._walk_block = self._rows_walk_block()
         self._chunk_tiles: dict[int, Optional[int]] = {}
 
     def _build_copy_fns(self):
@@ -484,6 +485,27 @@ class ServingEngine:
         return all(
             s.chunk_as_rows and s.chunk_as_rows(mesh, self._kv_itemsize)
             for group in self._groups for s in group.values())
+
+    def _rows_walk_block(self) -> int:
+        """Cache rows a page holds where the slots' rows read their whole
+        context by the paged decode kernel's walk in every layer of the
+        global group that keeps it (`DecodeState.rows_walk`, asked as
+        `_rows_serve_chunks` asks), else 0: what a step's
+        `kv_rows_copied` and `rows_handed` are counted by."""
+        layers = list(self._groups[0].values())
+        if not self._chunk_rows or not layers:
+            return 0
+        mesh = self.decode_model.executor.mesh
+        walk = all(s.rows_walk and s.rows_walk(mesh, self._kv_itemsize)
+                   for s in layers)
+        return self.block_manager.block_size if walk else 0
+
+    def _count(self, load: dict, **counts):
+        """Counts of the step being scheduled: onto its span's `load`,
+        and into the measured window's totals (`stats()`)."""
+        for name, value in counts.items():
+            self._counted[name] = self._counted.get(name, 0) + value
+        load.update(counts)
 
     def _head_rows_of(self, rows: int, q: int) -> int:
         """Rows of a `(rows, q)` step that go through the graph's
@@ -609,6 +631,7 @@ class ServingEngine:
             self._inject_fn = None  # rebuilt lazily on the new executor
             self._build_token_feed()
             self._chunk_rows = self._rows_serve_chunks()
+            self._walk_block = self._rows_walk_block()
             self._chunk_tiles = {}
             self.num_chips = int(new_dec.mesh.devices.size)
             trans = new_dec._transition or {}
@@ -1429,6 +1452,17 @@ class ServingEngine:
                         head_rows=self._head_rows_of(rows, q))
             if by_rows:
                 load.update(rows=rows, kv_rows_walked=int(kv_rows_walked))
+            if self._walk_block:
+                # the single-query kernel's call: the slots' rows, and a
+                # chunk's where the chunk kernel does not take them (a
+                # dead row stands at the scratch position)
+                from ..kernels.flash_attention import paged_walk_counts
+
+                at = positions[:rows if by_rows and not tile else slots, 0]
+                copied, handed = paged_walk_counts(
+                    np.where(at < self.max_seq_len, at + 1, 0),
+                    self._walk_block)
+                self._count(load, kv_rows_copied=copied, rows_handed=handed)
             if self._window_nodes:
                 # rows a window layer's attention reads: a row's window,
                 # or its context where that is shorter
@@ -1452,10 +1486,8 @@ class ServingEngine:
                     window_blocks_freed=(
                         self.block_manager.stats.window_blocks_freed))
             if self._step_counts:
-                counts = self._step_counts([s.length for s in decoding])
-                for name, value in counts.items():
-                    self._counted[name] = self._counted.get(name, 0) + value
-                load.update(counts)
+                self._count(
+                    load, **self._step_counts([s.length for s in decoding]))
             if self._sel_cap:
                 # a layer's indexer scores every cached row of every live
                 # row's context; its attention reads the selected ones.

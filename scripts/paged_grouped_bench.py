@@ -1,4 +1,4 @@
-"""The grouped paged decode kernel alone, on the chip, at a cell's shapes.
+"""The paged decode kernel alone, on the chip, at a cell's shapes.
 
 `--cell cmdap` (`cmdap-serve-agentmix`): 32 rows of 128 query heads of 128
 on 8 KV heads that read the cell's 32 histories (2-31 k rows, 354 k in all)
@@ -11,8 +11,13 @@ all) whole on 4 KV heads, as a global layer does, or their last 128 rows on
 8 KV heads under a sink, as a window layer does, from pools of 128-row
 blocks.
 
+`--cell c13b` (`c13b-serve-chat`): 16 rows of 16 heads of 128, ungrouped,
+that read the cell's kind of context (a prompt of 32-512 tokens, log-uniform,
+and 0-128 of a reply: 1-5 rounds of 8 pages of 16 rows a row) from a table
+40 pages wide, 24 calls in one program as a step's 24 layers make them.
+
     chiprun -- python scripts/paged_grouped_bench.py [--cell mimo2f]
-        [--blocks 128,256] [--pages 1,2,4,8]
+        [--blocks 128,256] [--pages 1,2,4,8] [--chain 24]
 
 Prints, for each block size and kind, the pages and the K + V bytes of a DMA
 round, the kernel's time a call, a round and each 128 rows, its share of the
@@ -39,9 +44,15 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 # heads, key and value head sizes, the histories' strata and the rows a
-# request adds to one, the table's rows, the block sizes run by default, and
-# each kind's (KV heads, window, sink)
+# request adds to one (a number, or the bounds of a draw a row), the table's
+# rows, the block sizes run by default, each kind's (KV heads, window, sink),
+# and, where they are not 32 and 1, the call's rows and the calls chained in
+# one program
 CELLS = {
+    "c13b": dict(
+        heads=16, d=128, d_v=128, history=(32, 512), more=(0, 128),
+        max_seq=640, blocks="16", kinds={"plain": (16, 0, False)},
+        rows=16, chain=24),
     "cmdap": dict(
         heads=128, d=128, d_v=128, history=(2048, 32768), more=300,
         max_seq=33536, blocks="128,256",
@@ -61,6 +72,9 @@ def main() -> int:
                     help="pages a round to run in turn (the rule's alone "
                          "by default)")
     ap.add_argument("--calls", type=int, default=20)
+    ap.add_argument("--chain", type=int, default=None,
+                    help="calls in one program, each one's queries taken "
+                         "from the last one's output (the cell's by default)")
     opts = ap.parse_args()
 
     import jax
@@ -78,10 +92,17 @@ def main() -> int:
     cell = CELLS[opts.cell]
     heads, d, d_v = cell["heads"], cell["d"], cell["d_v"]
     lo, hi = cell["history"]
-    lengths = [x + cell["more"] for x in traffic.quantiles(
-        {"dist": "log_uniform", "min": lo, "max": hi}, 32)]
-    rows = len(lengths)
+    rows = cell.get("rows", 32)
+    chain = opts.chain or cell.get("chain", 1)
     rng = np.random.default_rng(0)
+    lengths = traffic.quantiles(
+        {"dist": "log_uniform", "min": lo, "max": hi}, rows)
+    more = cell["more"]
+    if isinstance(more, tuple):
+        # slots at every stage of a reply, in no order of length
+        lengths = rng.permutation(lengths)
+        more = rng.integers(more[0], more[1] + 1, rows)
+    lengths = [int(x) for x in np.asarray(lengths) + more]
     q = jnp.asarray(rng.normal(size=(rows, 1, heads * d)), jnp.bfloat16)
     n = jnp.asarray(lengths, jnp.int32)
     scale = 1.0 / math.sqrt(d)
@@ -89,14 +110,22 @@ def main() -> int:
     def timed(fn, *args):
         # (the pools come as arguments: closed over, a jit holds their
         # hundreds of MB as constants and compiles for a minute)
-        fn = jax.jit(fn)
-        fn(*args).block_until_ready()
+        def chained(table, n, q, *rest):
+            # `chain` calls in one program, as a step's layers are: a
+            # call's queries wait for the call before it
+            for _ in range(chain):
+                out = fn(table, n, q, *rest)
+                q = q + (out[:, :, :1] * 0).astype(q.dtype)
+            return out
+
+        run = jax.jit(chained)
+        run(*args).block_until_ready()
         took = []
         for _ in range(5):
             t0 = time.perf_counter()
-            outs = [fn(*args) for _ in range(opts.calls)]
+            outs = [run(*args) for _ in range(opts.calls)]
             outs[-1].block_until_ready()
-            took.append((time.perf_counter() - t0) / opts.calls)
+            took.append((time.perf_counter() - t0) / opts.calls / chain)
         return float(np.median(took))
 
     for bs in map(int, (opts.blocks or cell["blocks"]).split(",")):

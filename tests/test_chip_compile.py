@@ -755,6 +755,71 @@ def test_paged_decode_head_dim_128(tpu, slots, heads, width, block_size,
     assert kernels == {"flash_attention_paged_decode": 1}
 
 
+def _pallas_calls(jaxpr):
+    """The `pallas_call` equations of a jaxpr, those of its calls too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                yield from _pallas_calls(inner)
+
+
+# a cell's single-query call: rows, query heads, KV heads, key and value
+# head, block, table width, pool blocks, window, sink, lse, the kernel
+_DECODE = "flash_attention_paged_decode"
+WALKS = {
+    "c13b": (16, 16, 16, 128, 128, 16, 40, 641, 0, False, False, _DECODE),
+    "solar2": (128, 64, 8, 128, 128, 256, 17, 1600, 0, False, False,
+               _DECODE + "_grouped"),
+    "mimo2f-global": (32, 64, 4, 192, 128, 128, 262, 5400, 0, False, False,
+                      _DECODE + "_grouped"),
+    "mimo2f-window": (32, 64, 8, 192, 128, 128, 262, 512, 128, True, False,
+                      _DECODE + "_window_grouped"),
+    "cmdap-global": (32, 128, 8, 128, 128, 256, 131, 1800, 0, False, False,
+                     _DECODE + "_grouped"),
+    "cmdap-window": (32, 128, 8, 128, 128, 256, 131, 768, 4096, False, False,
+                     _DECODE + "_window_grouped"),
+    # EvaByte's two walks a row: its window's 8 pages, its summaries
+    "evabyte-exact": (16, 32, 32, 128, 128, 256, 8, 232, 0, False, True,
+                      _DECODE + "_lse"),
+    "evabyte-summaries": (16, 32, 32, 128, 128, 16, 118, 1200, 0, False,
+                          True, _DECODE + "_lse"),
+    "jamba2": (256, 20, 1, 128, 128, 256, 6, 1600, 0, False, False,
+               _DECODE + "_grouped"),
+}
+
+
+@pytest.mark.parametrize("cell", list(WALKS))
+def test_the_paged_decode_walk_lowers_in_order_at_every_cells_shapes(
+        tpu, cell):
+    """The walk's DMAs follow the call (a row's last round starts the
+    next row's first; a round copies its row's live pages in a loop): the
+    grid is declared to run in order, the buffer a row starts in is
+    carried in SMEM, and Mosaic takes the body at the shapes of each of
+    the seven serving cells that run it, under the name their per-layer
+    readers look for."""
+    (rows, heads, kv, dk, dv, bs, width, blocks, window, sink, lse,
+     name) = WALKS[cell]
+    s = _on(tpu[0])
+
+    def fn(q, pk, pv, tbl, n, bias=None):
+        return fa.paged_flash_decode_attention(
+            q, pk, pv, tbl, n, num_heads=heads, num_kv_heads=kv,
+            window=window, sink=bias, return_lse=lse)
+
+    shapes = [s((rows, 1, heads * dk)), s((blocks, bs, kv * dk)),
+              s((blocks, bs, kv * dv)), s((rows, width), jnp.int32),
+              s((rows,), jnp.int32)]
+    if sink:
+        shapes.append(s((heads,), jnp.float32))
+    call, = _pallas_calls(jax.make_jaxpr(fn)(*shapes).jaxpr)
+    assert call.params["compiler_params"]["mosaic_tpu"] \
+        .dimension_semantics == ("arbitrary",)
+    assert _kernels(fn, *shapes) == {name: 1}
+
+
 @pytest.mark.parametrize("layout", ["contiguous", "paged"])
 def test_decode_head_dim_64_takes_the_reference_and_says_so(tpu, layout):
     """Every zoo tier but lm-xxl-fsdp has head_dim 64, where heads cannot

@@ -448,8 +448,17 @@ def test_engine_counts_the_steps_the_chunk_kernel_took(monkeypatch):
     assert st["chunk_kernel_steps"] == st["row_steps"] == 3
     assert [s["kv_rows_walked"] for s in spans] == [
         s["kv_rows"] for s in spans] == [4, 5, 8]
+    # what the single-query kernel's walk copies (whole pages of 16 rows)
+    # and the live rows whose first round the live row before started: no
+    # slot decodes beside the first prompt's chunks; its 6 rows beside the
+    # second's chunk; then slots of 7 and 3 rows, and the second's 4 alone
+    assert eng._walk_block == 16
+    assert [(s["kv_rows_copied"], s["rows_handed"]) for s in spans] == [
+        (0, 0), (0, 0), (16, 0)]
+    assert (st["kv_rows_copied"], st["rows_handed"]) == (16 + 32 + 16, 1)
     eng.reset_stats()
     assert eng.stats()["chunk_kernel_steps"] == 0
+    assert "kv_rows_copied" not in eng.stats()  # counted from a step on
 
 
 def test_window_layers_beside_a_global_one_give_the_engine_one_answer(
@@ -498,3 +507,7 @@ def test_engine_runs_refused_chunks_through_the_single_query_kernel(
     # first request's 6
     assert [s["kv_rows_walked"] for s in spans] == [10, 5, 9]
     assert [s["kv_rows"] for s in spans] == [4, 5, 8]
+    # each of those rows copies its own page and, behind a live row, is
+    # handed its first round: 4 rows; 1; the first slot's and the chunk's 2
+    assert [(s["kv_rows_copied"], s["rows_handed"]) for s in spans] == [
+        (64, 3), (16, 0), (48, 1)]
