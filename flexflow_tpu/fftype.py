@@ -237,6 +237,9 @@ class OperatorType(enum.IntEnum):
     # training-shaped op, and its decode op over per-slot recurrent state
     OP_SELECTIVE_SSM = enum.auto()
     OP_SELECTIVE_SSM_DECODE = enum.auto()
+    # the gated short convolution (LFM2's conv mixer; ops/short_conv.py),
+    # training-shaped; it has no decode op
+    OP_SHORT_CONV = enum.auto()
 
 
 PARALLEL_OP_TYPES = frozenset(

@@ -2,7 +2,10 @@
 
 `grouped_matmul(lhs (m, k), rhs (g, k, n), group_sizes (g,))` multiplies
 the first `group_sizes[0]` rows of `lhs` by `rhs[0]`, the next
-`group_sizes[1]` by `rhs[1]`, and so on; rows past the sum give zeros. It
+`group_sizes[1]` by `rhs[1]`, and so on; rows past the sum give zeros
+under `ragged_dot` and are left UNWRITTEN by the Pallas kernel, forward and
+dX alike (whoever sorts rows there masks what it reads of them:
+ops/moe.py, `picked` and `_gather_sorted`'s `live`). It
 is the matmul of a token-routed expert layer after its dispatch has sorted
 the assignments by expert: no capacity and no padding, so nothing is
 dropped.

@@ -564,6 +564,19 @@ class FFModel:
             OT.OP_SELECTIVE_SSM, SelectiveSSMParams(front), [input], name,
             front.initializers(kernel_initializer), input.dtype).outputs[0]
 
+    def short_conv(self, input: Tensor, front,
+                   kernel_initializer: Optional[Initializer] = None,
+                   name: str = "") -> Tensor:
+        """A gated short causal convolution (LFM2's conv mixer) on (batch,
+        seq, hidden); `front` is an ops.short_conv.ShortConvFrontEnd
+        (ops/short_conv.py, which this call imports: no other graph pays
+        for it)."""
+        from .ops.short_conv import ShortConvParams
+
+        return self._add_layer(
+            OT.OP_SHORT_CONV, ShortConvParams(front), [input], name,
+            front.initializers(kernel_initializer), input.dtype).outputs[0]
+
     def concat(self, tensors: Sequence[Tensor], axis: int, name: str = "") -> Tensor:
         p = ConcatParams(axis, len(tensors))
         return self._add_layer(OT.OP_CONCAT, p, list(tensors), name,

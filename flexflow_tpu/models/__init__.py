@@ -23,6 +23,7 @@ from .transformer import (
     evabyte_lm_config,
     jamba_lm_config,
     keye_vl2_lm_config,
+    lfm2_moe_lm_config,
     mimo_v2_flash_lm_config,
     mistral_small4_lm_config,
     olmoe_lm_config,

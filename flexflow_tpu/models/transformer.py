@@ -62,6 +62,18 @@ def build_transformer(ff, config: TransformerConfig | None = None,
     return input, t
 
 
+# `layer_pattern`'s kinds: {kind: (the TransformerLMConfig field that holds
+# what `_lm_trunk` builds the kind from, what that is)}; (None, ..) = the
+# model's own fields. `__post_init__` checks against it and `_lm_trunk`
+# dispatches on it, so a kind the trunk does not build cannot pass the
+# check and a new kind is one entry
+LAYER_KINDS = {"mha": (None, "the model's attention"),
+               "swa": ("swa", "the window layers' arguments, a window"),
+               "delta": ("delta", "a DeltaFrontEnd"),
+               "mamba": ("mamba", "a MambaFrontEnd"),
+               "conv": ("conv", "a ShortConvFrontEnd")}
+
+
 @dataclass
 class TransformerLMConfig:
     """Flagship decoder-only LM (TPU-native; exceeds reference capability —
@@ -174,6 +186,9 @@ class TransformerLMConfig:
     # Jamba (`jamba_lm_config`): `layer_pattern` "mamba" = a selective
     # state-space layer built from `mamba` (an ops.ssm.MambaFrontEnd)
     mamba: Optional[object] = None
+    # LFM2 (`lfm2_moe_lm_config`): `layer_pattern` "conv" = a gated short
+    # convolution built from `conv` (an ops.short_conv.ShortConvFrontEnd)
+    conv: Optional[object] = None
 
     def layer_kind(self, i: int) -> str:
         return self.layer_pattern[i] if self.layer_pattern else self.attention
@@ -195,25 +210,22 @@ class TransformerLMConfig:
         if self.layer_pattern is not None:
             self.layer_pattern = tuple(self.layer_pattern)
             if (len(self.layer_pattern) != self.num_layers
-                    or set(self.layer_pattern) - {"mha", "delta", "swa",
-                                                  "mamba"}):
+                    or set(self.layer_pattern) - set(LAYER_KINDS)):
                 raise ValueError(
                     f"TransformerLMConfig.layer_pattern names one kind "
-                    f"('mha' | 'delta' | 'swa' | 'mamba') for each of the "
+                    f"({' | '.join(map(repr, LAYER_KINDS))}) for each of the "
                     f"{self.num_layers} layers, got {self.layer_pattern!r}")
             if "swa" in self.layer_pattern and not (self.swa or {}).get(
                     "window"):
                 raise ValueError(
                     "TransformerLMConfig.layer_pattern 'swa' needs `swa` "
                     "with a window")
-            if "delta" in self.layer_pattern and self.delta is None:
-                raise ValueError(
-                    "TransformerLMConfig.layer_pattern 'delta' needs "
-                    "`delta` (a DeltaFrontEnd)")
-            if "mamba" in self.layer_pattern and self.mamba is None:
-                raise ValueError(
-                    "TransformerLMConfig.layer_pattern 'mamba' needs "
-                    "`mamba` (a MambaFrontEnd)")
+            for kind in set(self.layer_pattern) - {"swa"}:
+                field, what = LAYER_KINDS[kind]
+                if field and getattr(self, field) is None:
+                    raise ValueError(
+                        f"TransformerLMConfig.layer_pattern {kind!r} needs "
+                        f"`{field}` ({what})")
 
 
 def olmoe_lm_config(**sizes) -> TransformerLMConfig:
@@ -699,6 +711,70 @@ def jamba_lm_config(config: dict, *, sequence_length: int,
         initializer_range=initializer_range)
 
 
+def lfm2_moe_lm_config(config: dict, *, sequence_length: int,
+                       attention_impl: str = "xla",
+                       initializer_range: float = 0.02,
+                       embedding_range: float = 0.0) -> TransformerLMConfig:
+    """LFM2-MoE from the keys of its published config.json (`model_type:
+    lfm2_moe`; models/lfm2_moe_reference.py writes the equations out and
+    says what the keys leave open): `layer_types` "conv" is a gated short
+    convolution of `conv_L_cache` taps a channel, "full_attention" causal
+    softmax attention with grouped keys and values, an RMSNorm over each q
+    and k head and RoPE; the first `num_dense_layers` layers carry a
+    SiLU-gated MLP of `intermediate_size`, the others `num_experts` routed
+    experts of `moe_intermediate_size` under a sigmoid router (a bias for
+    the choice only where `use_expert_bias`, gates renormalised over the
+    chosen with 1e-6 in the sum where `norm_topk_prob`), no shared expert;
+    the head tied to the embedding (`tie_word_embeddings`, true where the
+    key is left out, as the family's default). A cut configuration states
+    `experts_held` / `experts_routed` as DeepSeek-V3.2's does
+    (`num_experts` then counts the experts held). The router's bias is
+    zeros, as the family initialises it."""
+    from ..ops.short_conv import ShortConvFrontEnd
+
+    layers, hidden = config["num_hidden_layers"], config["hidden_size"]
+    kinds = tuple(config["layer_types"][:layers])
+    known = {"conv": "conv", "full_attention": "mha"}
+    if len(kinds) != layers or set(kinds) - set(known):
+        raise NotImplementedError(
+            f"lfm2_moe_lm_config: layer_types names 'conv' or "
+            f"'full_attention' for each of the {layers} layers, got {kinds}")
+    for key, built in (("conv_bias", False), ("hidden_act", "silu"),
+                       ("attention_bias", False)):
+        if config.get(key, built) != built:
+            raise NotImplementedError(
+                f"lfm2_moe_lm_config builds {key} {built!r}, got "
+                f"{config[key]!r}")
+    heads = config["num_attention_heads"]
+    held = config.get("experts_held")
+    return TransformerLMConfig(
+        vocab_size=config["vocab_size"], hidden_size=hidden,
+        num_heads=heads, num_layers=layers,
+        sequence_length=sequence_length, attention_impl=attention_impl,
+        norm="rmsnorm", norm_eps=config["norm_eps"], position="rope",
+        rope_theta=float(config["rope_theta"]), attention_bias=False,
+        qk_norm="head", num_kv_heads=config["num_key_value_heads"],
+        head_dim=config.get("head_dim") or hidden // heads,
+        layer_pattern=tuple(known[kind] for kind in kinds),
+        conv=ShortConvFrontEnd(embed_dim=hidden,
+                               conv_kernel=config["conv_L_cache"]),
+        mlp="moe", intermediate_size=config["intermediate_size"],
+        first_k_dense=config["num_dense_layers"],
+        num_experts=config.get("experts_routed", config["num_experts"]),
+        num_experts_per_tok=config["num_experts_per_tok"],
+        moe_intermediate_size=config["moe_intermediate_size"],
+        moe_routing=dict(
+            scoring="sigmoid", n_group=1, topk_group=1,
+            norm_topk_prob=bool(config["norm_topk_prob"]),
+            norm_topk_eps=1e-6,
+            routed_scaling_factor=float(config["routed_scaling_factor"]),
+            correction_bias=bool(config["use_expert_bias"]),
+            experts_held=None if held is None else tuple(held)),
+        tie_embeddings=bool(config.get("tie_word_embeddings", True)),
+        initializer_range=initializer_range,
+        embedding_range=embedding_range, router_bias_range=0.0)
+
+
 def _norm_initializer(stddev: float, mean: float = 0.0):
     from ..initializer import NormInitializer
 
@@ -724,15 +800,18 @@ def _lm_trunk(ff, c: TransformerLMConfig, h, pos, wte=None):
     rope = c.position == "rope"
     init = (_norm_initializer(c.initializer_range)
             if c.initializer_range else None)
+    # the kinds built from a front end of their own; "mha" and "swa" are
+    # the model's attention under the kind's arguments
+    recurrent = {"delta": ff.gated_delta_attention, "mamba": ff.mamba,
+                 "conv": ff.short_conv}
+    assert set(recurrent) | {"mha", "swa"} == set(LAYER_KINDS)
     for i in range(c.num_layers):
         p = f"l{i}_"
         n = _lm_norm(ff, c, h, f"{p}ln1")
-        if c.layer_kind(i) == "delta":
-            a = ff.gated_delta_attention(n, c.delta, kernel_initializer=init,
-                                         name=f"{p}attn")
-        elif c.layer_kind(i) == "mamba":
-            a = ff.mamba(n, c.mamba, kernel_initializer=init,
-                         name=f"{p}attn")
+        kind = c.layer_kind(i)
+        if kind in recurrent:
+            a = recurrent[kind](n, getattr(c, LAYER_KINDS[kind][0]),
+                                kernel_initializer=init, name=f"{p}attn")
         elif c.attention == "latent":
             a = ff.latent_attention(n, pos, c.latent, kernel_initializer=init,
                                     name=f"{p}attn")
@@ -742,7 +821,7 @@ def _lm_trunk(ff, c: TransformerLMConfig, h, pos, wte=None):
                 num_kv_heads=c.num_kv_heads, head_dim=c.head_dim,
                 v_head_dim=c.v_head_dim, rope_dim=c.rope_dim,
                 value_scale=c.value_scale)
-            if c.layer_kind(i) == "swa":
+            if kind == "swa":
                 front.update(c.swa)
             a = ff.multihead_attention(
                 n, n, n, c.hidden_size, c.num_heads, bias=c.attention_bias,

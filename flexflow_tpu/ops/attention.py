@@ -16,7 +16,9 @@ placement:
 
   - "xla":    plain einsum softmax(QK^T)V — XLA fuses well for short seqs
   - "flash":  the packed Pallas kernels (kernels/flash_attention.py), run
-    per shard of the plan — O(seq) memory, no head-transpose relayout
+    per shard of the plan — O(seq) memory, no head-transpose relayout; a
+    layer with grouped keys and values repeats them to the query heads in
+    front of the kernels
   - "ring":   shard_map ring attention over the `seq` mesh axis
     (parallel/ring_attention.py) — the long-context path the reference lacks
     (SURVEY §5: no ring/Ulysses in FlexFlow)
@@ -24,6 +26,7 @@ placement:
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from dataclasses import dataclass
@@ -666,11 +669,30 @@ def _mha_forward(p: MultiHeadAttentionParams, inputs, weights, state, ctx):
     if front.window and not p.causal:
         raise NotImplementedError(
             "attention with a window is causal self-attention")
-    if (group > 1 or not front.plain_core) and impl != "xla":
+    repeat_kv = group > 1 and front.plain_core and impl == "flash"
+    if repeat_kv:
+        # grouped heads of one size over the whole past: the keys and
+        # values of a KV head repeated for the `group` query heads that
+        # read it, in the projections' packed layout, in front of the
+        # unchanged packed kernels (query head i reads KV head i // group,
+        # so a head-parallel shard's keys stay its own); the repeat's
+        # transpose sums dK and dV over the group. The kernel then reads
+        # `group` times the K and V it needs: the kernel-native form, block
+        # index maps that read head i // group, is still missing
+        def repeat(t):
+            b, s, _ = t.shape
+            t = t.reshape(b, s, front.kv_heads, 1, front.head_dim)
+            return jnp.broadcast_to(
+                t, (b, s, front.kv_heads, group, front.head_dim)
+            ).reshape(b, s, front.q_width)
+
+        with jax.named_scope("gqa.repeat"):
+            k, v = repeat(k), repeat(v)
+    elif (group > 1 or not front.plain_core) and impl != "xla":
         # the packed and ring kernels select q, k and v heads by one lane
-        # offset and attend a row's whole past: one head count, one head
-        # size, no band in their tile classes, no sink. Such a layer takes
-        # the einsum (no training cell runs one)
+        # offset and attend a row's whole past: one head count (but for
+        # the repeat above), one head size, no band in their tile classes,
+        # no sink. Such a layer takes the einsum
         from ..kernels.dispatch import warn_reference
 
         warn_reference("multihead_attention", tuple(q.shape),
@@ -698,11 +720,16 @@ def _mha_forward(p: MultiHeadAttentionParams, inputs, weights, state, ctx):
         if H % shards_of(ctx.mesh, head_ax):
             head_ax = None
         spec = PartitionSpec(batch_ax, None, head_ax)
-        out = per_shard(
+        attend = per_shard(
             functools.partial(flash_attention_packed,
                               num_heads=H // shards_of(ctx.mesh, head_ax),
                               causal=p.causal, scale=scale),
-            ctx.mesh, (spec, spec, spec), spec)(q, k, v)
+            ctx.mesh, (spec, spec, spec), spec)
+        # the scope the grouped layer's core has on every path; an
+        # ungrouped layer's call and its compiled text stay as they were
+        with (jax.named_scope(front.attend_scope) if repeat_kv
+              else contextlib.nullcontext()):
+            out = attend(q, k, v)
         return [front.output(ctx, weights, out, inputs[0])], state
 
     def split_heads(x, heads):

@@ -369,6 +369,9 @@ class MoEMLPParams:
     topk_group: int = 1
     norm_topk_prob: bool = False
     routed_scaling_factor: float = 1.0
+    # added to the chosen gates' sum before `norm_topk_prob` divides by it
+    # (LFM2-MoE's 1e-6); 0.0 = the plain sum, and the program it was
+    norm_topk_eps: float = 0.0
     # width of a SiLU-gated expert every token passes through, added to
     # the routed sum; 0 = none
     shared_intermediate_size: int = 0
@@ -465,6 +468,12 @@ def moe_route(x, router, k: int):
     return weights, ids.astype(jnp.int32), probs
 
 
+def _gate_sum(gates, p: MoEMLPParams):
+    """What `norm_topk_prob` divides the chosen gates by."""
+    total = jnp.sum(gates, axis=-1, keepdims=True)
+    return total + p.norm_topk_eps if p.norm_topk_eps else total
+
+
 def moe_route_sigmoid(x, router, bias, p: MoEMLPParams):
     """(gate weights (t, k) float32, expert ids (t, k) int32, scores (t,
     n)) of DeepSeek-V3's Gate (MoEMLPParams.scoring)."""
@@ -483,7 +492,7 @@ def moe_route_sigmoid(x, router, bias, p: MoEMLPParams):
     _, ids = jax.lax.top_k(biased, k)
     gates = jnp.take_along_axis(scores, ids, axis=-1)
     if p.norm_topk_prob:
-        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        gates = gates / _gate_sum(gates, p)
     return gates * p.routed_scaling_factor, ids.astype(jnp.int32), scores
 
 
@@ -511,21 +520,27 @@ def moe_sort(ids, num_experts: int):
 
 
 @jax.custom_vjp
-def _gather_sorted(x, order, position):
+def _gather_sorted(x, order, position, live=None):
     """Rows of tokens x (t, d) in sorted-assignment order (t*k, d). The
     backward is a gather too (each token's k rows by `position`, summed),
-    not a scatter-add over repeated rows."""
+    not a scatter-add over repeated rows. `live`, where given, is the
+    number of sorted rows some expert computes (a held share's: the rows
+    behind them belong to experts held elsewhere): the grouped matmuls
+    leave the cotangent of the rows past it unwritten, so the backward
+    takes it as zero there. The forward is the same with and without."""
     return x[order // position.shape[1]]
 
 
-def _gather_sorted_fwd(x, order, position):
-    return _gather_sorted(x, order, position), (order, position)
+def _gather_sorted_fwd(x, order, position, live=None):
+    return _gather_sorted(x, order, position), (order, position, live)
 
 
 def _gather_sorted_bwd(res, g):
-    order, position = res
+    order, position, live = res
+    if live is not None:
+        g = jnp.where((jnp.arange(g.shape[0]) < live)[:, None], g, 0)
     return (jnp.sum(g[position].astype(jnp.float32), axis=1).astype(g.dtype),
-            None, None)
+            None, None, None)
 
 
 _gather_sorted.defvjp(_gather_sorted_fwd, _gather_sorted_bwd)
@@ -565,7 +580,7 @@ def _moe_mlp_forward(p: MoEMLPParams, inputs, weights, state, ctx):
         else:
             gates, ids, probs = moe_route(x, weights["router"], k)
             if p.norm_topk_prob:
-                gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+                gates = gates / _gate_sum(gates, p)
             if p.routed_scaling_factor != 1.0:
                 gates = gates * p.routed_scaling_factor
     with jax.named_scope("moe.dispatch"):
@@ -579,7 +594,9 @@ def _moe_mlp_forward(p: MoEMLPParams, inputs, weights, state, ctx):
             order, position, group_sizes = moe_sort(
                 jnp.where(here, ids - first, held), held + 1)
             group_sizes = group_sizes[:held]
-        rows = _gather_sorted(x, order, position)
+        rows = _gather_sorted(
+            x, order, position,
+            None if p.experts_held is None else jnp.sum(group_sizes))
     with jax.named_scope("moe.experts"):
         gate = grouped_matmul(rows, weights["gate"].astype(x.dtype),
                               group_sizes, ctx.mesh)

@@ -6,7 +6,8 @@ two layouts of a step alike.
 
 - The convolution over a tail kept a slot: `conv_window` puts a row's
   earlier inputs before its tokens (none where the row starts a request),
-  `causal_conv` is the SiLU of the taps over it, `next_tail` the row's last
+  `causal_conv` is the SiLU of the taps over it (the taps' sum as it is
+  under `activation=None`: ops/short_conv.py), `next_tail` the row's last
   `taps - 1` inputs up to its last live token.
 - `decode_rows`, the decode op's forward over its state leaves: which
   tokens are live, which rows start from nothing, the slots' rows and then
@@ -36,15 +37,20 @@ def conv_window(tail, u, keep):
     return jnp.concatenate([tail, u], axis=1)
 
 
-def causal_conv(taps, window, tokens: int, bias=None):
+def causal_conv(taps, window, tokens: int, bias=None, activation="silu"):
     """SiLU of the causal depthwise convolution of `taps` (taps, width)
     over `window` (conv_window), plus `bias` (width,) where there is one;
-    (rows, tokens, width) float32."""
+    (rows, tokens, width) float32. `activation` None: the sum as it is."""
     taps = taps.astype(jnp.float32)
     wf = window.astype(jnp.float32)
     y = sum(taps[i] * wf[:, i:i + tokens] for i in range(taps.shape[0]))
     if bias is not None:
         y = y + bias.astype(jnp.float32)
+    if activation is None:
+        return y
+    if activation != "silu":
+        raise ValueError(f"causal_conv: activation is 'silu' or None, got "
+                         f"{activation!r}")
     return y * jax.nn.sigmoid(y)
 
 

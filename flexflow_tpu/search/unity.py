@@ -248,6 +248,18 @@ class UnitySearch:
                                          batch_axes=self.batch_axes))
                 assign[1] = (AXIS_SEQ,)
                 out.append(NodeConfig("sp", tuple(assign)))
+        elif node.op_type == OT.OP_SHORT_CONV:
+            p = node.params
+            if allow_attr and p.front.channel_parallel_ok(self.model_deg):
+                # the channels split: B, C and x of a channel lie together
+                # (`w_in` by column), the taps go with their channel and
+                # `w_out`, by row, leaves partial sums
+                out.append(NodeConfig(
+                    "tp_sconv",
+                    _dp_assign(ndim, batch_ok, batch_axes=self.batch_axes),
+                    p.front.channel_parallel(AXIS_MODEL),
+                    psum_axes=(AXIS_MODEL,),
+                ))
         elif node.op_type == OT.OP_CONV2D and allow_attr and ndim == 4:
             # channel/attribute-parallel conv (NCHW dim 1 over `model`,
             # OIHW kernel dim 0 sharded) — the conv sibling of tp_attn

@@ -713,6 +713,48 @@ def test_selective_ssm_decode_layer_at_jamba2_widths(tpu, rows):
     assert compiled.memory_analysis().temp_size_in_bytes < 5e8
 
 
+def test_grouped_attention_trains_through_the_packed_kernels_at_lfm2_widths(
+        tpu):
+    """A `full_attention` layer of `lfm2-train-8k`, forward and backward:
+    32 query heads on 8 KV heads of 64, an RMSNorm a head, RoPE, one
+    sequence of 8,192 tokens in bf16 under impl "flash". The KV heads are
+    repeated in front of `gpt2m-train-1k`'s grouped-narrow-head kernels,
+    nothing falls back to the einsum, and the compiled text holds no array
+    of (heads, 8,192, 8,192) scores (8.6 GB in float32): the step needs
+    under 1 GB beside its operands."""
+    import warnings
+
+    from flexflow_tpu.fftype import OperatorType as OT
+    from flexflow_tpu.ops import attention as attn_ops
+    from flexflow_tpu.ops.base import OpContext, get_op_def
+
+    s = _on(tpu[0])
+    front = attn_ops.AttentionFrontEnd(
+        embed_dim=2048, num_heads=32, use_bias=False, rope_theta=1e6,
+        qk_norm="head", num_kv_heads=8)
+    p = attn_ops.MultiHeadAttentionParams(front, causal=True, impl="flash")
+    op = get_op_def(OT.OP_MULTIHEAD_ATTENTION)
+    weights = {w.name: s(w.shape, jnp.float32)
+               for w in op.weights(p, [(1, 8192, 2048)] * 3)}
+    assert weights["wk"].shape == (2048, 512)
+
+    def loss(weights, x, positions):
+        weights = jax.tree.map(lambda a: a.astype(jnp.bfloat16), weights)
+        (y,), _ = op.forward(p, [x, x, x, positions], weights, {},
+                             OpContext(training=True, mesh=None))
+        return jnp.sum(y.astype(jnp.float32))
+
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", KernelFallbackWarning)
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            weights, s((1, 8192, 2048)), s((1, 8192), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert pallas_kernels(text) == {"flash_attention_fwd_packed_grouped": 1,
+                                    "flash_attention_bwd_packed_grouped": 1}
+    assert not re.search(r"\[(\d+,)*8192,8192\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
 def test_contiguous_decode_head_dim_128(tpu):
     """The contiguous decode kernel at the engine's real cache shape
     (slots, max_seq + 1, E): max_seq + 1 is odd, so the last kv block is
