@@ -29,6 +29,7 @@ TPU design notes:
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass, replace
 from typing import Optional
@@ -427,6 +428,12 @@ def _moe_mlp_weights(p: MoEMLPParams, in_shapes):
         extra += [WeightSpec(name, (), DataType.DT_INT32, "zeros",
                              trainable=False)
                   for name in ("assignments_total", "dropped_total")]
+        if _by_slabs(p, tokens * p.num_experts_per_tok):
+            # the row slabs of the sorted order the last forward ran
+            # (docs/observability.md): x SLAB / (tokens x k) is the share
+            # of the sort this layer touched
+            extra.append(WeightSpec("slabs_run", (), DataType.DT_INT32,
+                                    "zeros", trainable=False))
     # "normal" is N(0, 0.02), transformers' initializer_range: glorot over
     # a stacked (n, d, f) weight would count the experts into the fans
     return [
@@ -565,6 +572,227 @@ def _gather_back_bwd(res, g):
 _gather_back.defvjp(_gather_back_fwd, _gather_back_bwd)
 
 
+# A held share's sorted order is live prefix first: rows [0, live) belong
+# to held experts and the grouped matmuls stop there. Where the sort is
+# long the layer's other passes in sorted order stop there too
+# (`_held_live`): they run in slabs of SLAB rows, ceil(live / SLAB) of
+# them, a count the device decides each step (a `while`: no host round
+# trip, one program at any load, nothing dropped). Rows past the last slab
+# are never written; a row inside it and past `live` is whatever the slab
+# made of unwritten kernel output. The token-order gathers mask what they
+# read of either, by `position < live`.
+# A multiple of the grouped matmul's row tile. On a v5e at (65536, 2048) a
+# quarter live, the layer alone: PERF.md section 6, PR 62
+SLAB = 2048
+# the sort is worth a loop from this many slabs on: under it (a serving
+# step's few thousand assignments) a loop's fixed cost a pass buys nothing
+MIN_SLABS = 4
+
+
+def _by_slabs(p: MoEMLPParams, m: int) -> bool:
+    """Whether a layer of `m` sorted rows runs its sorted-order passes over
+    the live prefix alone."""
+    return p.experts_held is not None and m >= MIN_SLABS * SLAB
+
+
+def _unwritten(like, after, mesh):
+    """An array of `like`'s shape and dtype that nothing has written, there
+    no sooner than `after` is: on one TPU device a Pallas call that reads
+    nothing of `after`, allocates its output and leaves it, as megablox's
+    `gmm` leaves the rows past its groups; zeros elsewhere. A loop over the
+    live slabs so starts from no memset. (`lax.empty` is such a buffer
+    too, but an instruction with no operand: XLA's TPU scheduler puts it
+    first, and every layer's backward buffers then lie allocated through
+    the whole step, 5.8 GB of them in `lfm2-train-8k`.)"""
+    if jax.default_backend() != "tpu" or (mesh is not None and mesh.size > 1):
+        return jnp.zeros(like.shape, like.dtype)
+    from jax.experimental import pallas as pl
+
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        lambda after, out: None, in_specs=[anywhere], out_specs=anywhere,
+        out_shape=jax.ShapeDtypeStruct(like.shape, like.dtype),
+        name="moe_unwritten")(after)
+
+
+def _slab(a, start):
+    return jax.lax.dynamic_slice_in_dim(a, start, SLAB)
+
+
+def _slabs_under(live):
+    return (live + SLAB - 1) // SLAB
+
+
+def _over_live_slabs(live, rows_of, like, *operands, mesh=None):
+    """An unwritten array a `jax.ShapeDtypeStruct` of `like` (one or a
+    tuple) with `rows_of(start, *operands)`'s (SLAB, .) rows written at
+    every slab start under `live`. Where SLAB does not divide the rows the
+    last slab starts SLAB short of the end, inside the slab before: every
+    pass writes a row from its operands alone, so a row written twice is
+    written the same (where it does, a start is i x SLAB and nothing else:
+    a clamp hides from XLA that the start is a whole tile, and the slab's
+    update then runs apart from the pass that makes it, 1.6 ms a layer in
+    `lfm2-train-8k`). The operands are whole arrays the slabs read their
+    rows from, held behind a barrier: XLA's TPU pipeline otherwise sinks
+    the elementwise pass that made one into the loop's body and runs it
+    whole in every slab."""
+    operands = jax.lax.optimization_barrier(operands)
+    # (an operand each: two calls alike are one to XLA, and one buffer)
+    leaves, tree = jax.tree.flatten(like)
+    out = tree.unflatten([_unwritten(o, operands[i], mesh)
+                          for i, o in enumerate(leaves)])
+    rows = leaves[0].shape[0]
+
+    def body(i, out):
+        start = i * SLAB
+        if rows % SLAB:
+            start = jnp.minimum(start, rows - SLAB)
+        return jax.tree.map(
+            lambda o, r: jax.lax.dynamic_update_slice_in_dim(o, r, start, 0),
+            out, rows_of(start, *operands))
+
+    return jax.lax.fori_loop(0, _slabs_under(live), body, out)
+
+
+def _silu_gate(gate, up):
+    return (jax.nn.silu(gate.astype(jnp.float32))
+            * up.astype(jnp.float32)).astype(gate.dtype)
+
+
+def _sorted_rows(x, order, live, k: int, mesh):
+    """Rows [0, live) of tokens x (t, d) in sorted-assignment order."""
+    with jax.named_scope("moe.dispatch"):
+        return _over_live_slabs(
+            live, lambda at, x, order: x[_slab(order, at) // k],
+            jax.ShapeDtypeStruct((order.shape[0], x.shape[1]), x.dtype),
+            x, order, mesh=mesh)
+
+
+def _silu_gate_live(gate, up, live, mesh):
+    with jax.named_scope("moe.experts"):
+        return _over_live_slabs(
+            live, lambda at, gate, up: _silu_gate(_slab(gate, at),
+                                                  _slab(up, at)),
+            jax.ShapeDtypeStruct(gate.shape, gate.dtype), gate, up,
+            mesh=mesh)
+
+
+def _expert_matmul(lhs, w, sizes, mesh):
+    from ..kernels.grouped_matmul import grouped_matmul
+
+    with jax.named_scope("moe.experts"):
+        return grouped_matmul(lhs, w.astype(lhs.dtype), sizes, mesh)
+
+
+def _expert_matmul_vjp(lhs, w, sizes, mesh, g):
+    """(d lhs, d w) of `_expert_matmul` under its cotangent g: the grouped
+    matmul's own backward (`gmm` for d lhs, `tgmm` for d w); the forward it
+    is linearised at has no reader and is dropped."""
+    return jax.vjp(lambda a, b: _expert_matmul(a, b, sizes, mesh), lhs,
+                   w)[1](g)
+
+
+# (jitted: the five layers of a step, and the step's several programs,
+# trace the forward and the backward once, not once each)
+@functools.partial(jax.jit, static_argnums=(0,))
+def _held_live_fwd(mesh, x, gates, w_gate, w_up, w_down, order, position,
+                   sizes):
+    k, live = position.shape[1], jnp.sum(sizes)
+    rows = _sorted_rows(x, order, live, k, mesh)
+    gate = _expert_matmul(rows, w_gate, sizes, mesh)
+    up = _expert_matmul(rows, w_up, sizes, mesh)
+    out = _expert_matmul(_silu_gate_live(gate, up, live, mesh), w_down, sizes,
+                         mesh)
+    with jax.named_scope("moe.combine"):
+        # token order, all of it, a choice a plane: (k, t, d) has no short
+        # dimension under the tiles, (t, k, d) is laid out again on the way
+        # to its sum. A held choice's row is under `live`; the others' are
+        # whatever is there
+        places = position.T
+        picked = jnp.where((places < live)[..., None], out[places], 0)
+        y = jnp.sum(gates.T[..., None] * picked.astype(jnp.float32), axis=0)
+    # a loop's result is nothing XLA can make again when memory is short,
+    # as it does the whole length's gathers and SiLU gates: of the whole-
+    # length arrays `gate`, `up` and `picked` alone wait for the backward,
+    # which runs the live slabs of `rows` and `hidden` again
+    return y, (x, gates, w_gate, w_up, w_down, order, places, sizes, gate,
+               up, picked)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_live(mesh, x, gates, w_gate, w_up, w_down, order, position, sizes):
+    """The held experts' gate-weighted sum (t, d) float32 of tokens x
+    (t, d) under `gates` (t, k), zero where the choice is held elsewhere:
+    what `_gather_sorted`, the three grouped matmuls round the SiLU gate,
+    `_gather_back` and the weighted sum give, with every pass in sorted
+    order run over the slabs under the groups' sum, forward and backward
+    (hand-written: a loop whose trip count the device decides has no
+    reverse mode of JAX's own)."""
+    return _held_live_fwd(mesh, x, gates, w_gate, w_up, w_down, order,
+                          position, sizes)[0]
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _held_live_bwd(mesh, res, dy):
+    (x, gates, w_gate, w_up, w_down, order, places, sizes, gate, up,
+     picked) = res
+    k, live, f32 = places.shape[0], jnp.sum(sizes), jnp.float32
+    held = places < live
+    # what the backward makes again it makes from dy's side of this
+    # barrier: XLA otherwise finds the forward's two loops equal to the
+    # backward's and keeps their results for it, `rows` and `hidden` of
+    # every layer from its forward to its backward
+    x, order, gate, up, dy = jax.lax.optimization_barrier(
+        (x, order, gate, up, dy))
+    with jax.named_scope("moe.combine"):
+        # `out`'s cotangent in sorted order: a row's gate times its token's
+        # row of dy. The (t, k, d) product JAX's reverse mode gathers from
+        # (float32, and once more under the other tiling: 1 GB a layer, a
+        # quarter of it read) is never made
+        def weighted(at, dy, flat, order):
+            at = _slab(order, at)
+            return (flat[at][:, None] * dy[at // k]).astype(x.dtype)
+
+        d_out = _over_live_slabs(
+            live, weighted,
+            jax.ShapeDtypeStruct((order.shape[0], x.shape[1]), x.dtype),
+            dy, gates.reshape(-1), order, mesh=mesh)
+        # (`picked` is zero where the choice is held elsewhere)
+        d_gates = jnp.sum(picked.astype(f32) * dy[None], axis=-1).T
+    d_hidden, d_w_down = _expert_matmul_vjp(
+        _silu_gate_live(gate, up, live, mesh), w_down, sizes, mesh, d_out)
+
+    def gate_backward(at, d_hidden, gate, up):
+        return jax.vjp(_silu_gate, _slab(gate, at), _slab(up, at))[1](
+            _slab(d_hidden, at))
+
+    with jax.named_scope("moe.experts"):
+        like = jax.ShapeDtypeStruct(gate.shape, gate.dtype)
+        d_gate, d_up = _over_live_slabs(live, gate_backward, (like, like),
+                                        d_hidden, gate, up, mesh=mesh)
+    rows = _sorted_rows(x, order, live, k, mesh)
+    by_gate, d_w_gate = _expert_matmul_vjp(rows, w_gate, sizes, mesh, d_gate)
+    by_up, d_w_up = _expert_matmul_vjp(rows, w_up, sizes, mesh, d_up)
+    with jax.named_scope("moe.dispatch"):
+        d_rows = _over_live_slabs(
+            live, lambda at, by_gate, by_up: (_slab(by_gate, at)
+                                              + _slab(by_up, at)),
+            jax.ShapeDtypeStruct(by_gate.shape, by_gate.dtype), by_gate,
+            by_up, mesh=mesh)
+        d_x = jnp.sum(jnp.where(held[..., None], d_rows[places], 0)
+                      .astype(f32), axis=0).astype(x.dtype)
+    # the layer's backward ends here, all of it: XLA's scheduler otherwise
+    # leaves the weights' matmuls for later and their operands (d_gate,
+    # d_up, d_out, rows, hidden: 1.2 GB a layer) allocated until then,
+    # five layers' at once
+    return (*jax.lax.optimization_barrier(
+        (d_x, d_gates.astype(gates.dtype), d_w_gate, d_w_up, d_w_down)),
+        None, None, None)
+
+
+_held_live.defvjp(_held_live_fwd, _held_live_bwd)
+
+
 def _moe_mlp_forward(p: MoEMLPParams, inputs, weights, state, ctx):
     from ..kernels.grouped_matmul import grouped_matmul
 
@@ -594,24 +822,30 @@ def _moe_mlp_forward(p: MoEMLPParams, inputs, weights, state, ctx):
             order, position, group_sizes = moe_sort(
                 jnp.where(here, ids - first, held), held + 1)
             group_sizes = group_sizes[:held]
-        rows = _gather_sorted(
-            x, order, position,
-            None if p.experts_held is None else jnp.sum(group_sizes))
-    with jax.named_scope("moe.experts"):
-        gate = grouped_matmul(rows, weights["gate"].astype(x.dtype),
-                              group_sizes, ctx.mesh)
-        up = grouped_matmul(rows, weights["up"].astype(x.dtype),
-                            group_sizes, ctx.mesh)
-        hidden = (jax.nn.silu(gate.astype(jnp.float32))
-                  * up.astype(jnp.float32)).astype(x.dtype)
-        out = grouped_matmul(hidden, weights["down"].astype(x.dtype),
-                             group_sizes, ctx.mesh)
-    with jax.named_scope("moe.combine"):
-        picked = _gather_back(out, order, position)
-        if p.experts_held is not None:
-            # rows past the groups' sum are whatever the kernel left there
-            picked = jnp.where(here[..., None], picked, 0.0)
-        y = jnp.sum(gates[..., None] * picked.astype(jnp.float32), axis=1)
+    slabs = _by_slabs(p, order.shape[0])
+    if slabs:
+        y = _held_live(ctx.mesh, x, gates, weights["gate"], weights["up"],
+                       weights["down"], order, position, group_sizes)
+    else:
+        with jax.named_scope("moe.dispatch"):
+            rows = _gather_sorted(
+                x, order, position,
+                None if p.experts_held is None else jnp.sum(group_sizes))
+        with jax.named_scope("moe.experts"):
+            gate = grouped_matmul(rows, weights["gate"].astype(x.dtype),
+                                  group_sizes, ctx.mesh)
+            up = grouped_matmul(rows, weights["up"].astype(x.dtype),
+                                group_sizes, ctx.mesh)
+            out = grouped_matmul(_silu_gate(gate, up),
+                                 weights["down"].astype(x.dtype),
+                                 group_sizes, ctx.mesh)
+        with jax.named_scope("moe.combine"):
+            picked = _gather_back(out, order, position)
+            if p.experts_held is not None:
+                # rows past the groups' sum are whatever the kernel left
+                picked = jnp.where(here[..., None], picked, 0.0)
+            y = jnp.sum(gates[..., None] * picked.astype(jnp.float32),
+                        axis=1)
     if p.shared_intermediate_size:
         with jax.named_scope("moe.shared"):
             def dot(a, w):
@@ -649,6 +883,12 @@ def _moe_mlp_forward(p: MoEMLPParams, inputs, weights, state, ctx):
                                       + computed.astype(jnp.int32))
         state["dropped_total"] = (weights.get("dropped_total", 0)
                                   + (wanted - computed).astype(jnp.int32))
+        if "slabs_run" in weights:
+            # (a leaf of the layer as built: a forward at other rows than
+            # the build's keeps the state's tree, 0 where it ran no slab)
+            state["slabs_run"] = (
+                _slabs_under(computed).astype(jnp.int32) if slabs
+                else jnp.zeros((), jnp.int32))
     if p.aux_loss_coef:
         state["aux_loss"] = p.aux_loss_coef * load_balancing_loss(
             probs, group_sizes)
