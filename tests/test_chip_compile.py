@@ -809,6 +809,58 @@ def _held_expert_layers(tpu, layers):
             weights, s((2, t // 2, d))).compile()
 
 
+def test_short_conv_layer_keeps_its_middle_in_vmem_at_lfm2_widths(tpu):
+    """A `conv` layer of `lfm2-train-8k`, forward and backward with every
+    gradient: two sequences of 8,192 tokens, 2,048 channels, 3 taps, bf16
+    under float32 masters. The middle is the two Pallas kernels and nothing
+    falls back; both lie in `sconv.conv` for the cell's readers (the
+    backward's scope is opened inside the rule), the matmuls round them in
+    `sconv.proj` and `sconv.out`; and NO instruction of the layer writes a
+    float32 array of (rows, tokens, channels): XLA's own program of the jnp
+    form wrote eight a layer (the window, the taps' sum, both again, three
+    shares of dc and their sum: 134 MB each, PERF.md section 6, PR 63), so
+    the compiler or refactor that brings them back is seen here."""
+    import warnings
+
+    from benchmarks import lfm2_events
+    from flexflow_tpu.fftype import OperatorType as OT
+    from flexflow_tpu.ops.base import OpContext, get_op_def
+    from flexflow_tpu.ops.short_conv import ShortConvFrontEnd, ShortConvParams
+
+    s = _on(tpu[0])
+    rows, tokens, channels = 2, 8192, 2048
+    p = ShortConvParams(ShortConvFrontEnd(embed_dim=channels, conv_kernel=3))
+    op = get_op_def(OT.OP_SHORT_CONV)
+    weights = {w.name: s(w.shape, jnp.float32)
+               for w in op.weights(p, [(rows, tokens, channels)])}
+
+    def loss(weights, x):
+        weights = jax.tree.map(lambda a: a.astype(jnp.bfloat16), weights)
+        (y,), _ = op.forward(p, [x], weights, {},
+                             OpContext(training=True, mesh=None))
+        return jnp.sum(y.astype(jnp.float32))
+
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", KernelFallbackWarning)
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            weights, s((rows, tokens, channels))).compile().as_text()
+    assert pallas_kernels(text) == {"short_conv_fwd": 1, "short_conv_bwd": 1}
+    scope = dict(map(tuple, lfm2_events.scoped_instructions(text)))
+    kernels = {name: of for name, of in scope.items()
+               if name.startswith("short_conv_")}
+    assert sorted(re.sub(r"\.\d+$", "", k) for k in kernels) == [
+        "short_conv_bwd", "short_conv_fwd"]
+    assert set(kernels.values()) == {"sconv.conv"}
+    assert {"sconv.proj", "sconv.conv", "sconv.out"} == set(scope.values())
+    found = _instructions(text)
+    assert len(found) > 10
+    whole = rows * tokens * channels
+    for computation, name, shape, opcode, operands, op_name in found:
+        for dims in re.findall(r"f32\[([\d,]+)\]", shape):
+            assert np.prod([int(d) for d in dims.split(",")]) < whole, (
+                name, shape, op_name)
+
+
 def test_held_expert_layer_runs_its_live_prefix_at_lfm2_widths(tpu):
     """An expert layer of `lfm2-train-8k`, forward and backward. The
     sorted-order passes are seven loops whose trip count the device
